@@ -37,9 +37,7 @@ from torchft_tpu import HostCommunicator, Manager
 from torchft_tpu.data import BatchIterator, DistributedSampler
 from torchft_tpu.models import ResNet18
 from torchft_tpu.parallel import FTTrainer
-from torchft_tpu.utils import apply_platform_env
-
-apply_platform_env()  # TORCHFT_PLATFORM=cpu forces the CPU backend
+from torchft_tpu.utils import enable_compile_cache
 
 logging.basicConfig(level=logging.INFO)
 logger = logging.getLogger("train_ddp")
@@ -54,6 +52,7 @@ def make_dataset(n: int = 4096):
 
 
 def main() -> None:
+    enable_compile_cache()
     replica_group = int(os.environ.get("REPLICA_GROUP_ID", 0))
     num_groups = int(os.environ.get("NUM_REPLICA_GROUPS", 2))
     total_steps = int(os.environ.get("TOTAL_STEPS", 200))

@@ -17,7 +17,7 @@ local demo, a virtual CPU mesh):
     # terminal k ∈ {0, 1}
     REPLICA_GROUP_ID=$k NUM_REPLICA_GROUPS=2 \
     TORCHFT_LIGHTHOUSE=localhost:29510 \
-    JAX_PLATFORMS=cpu TORCHFT_PLATFORM=cpu \
+    JAX_PLATFORMS=cpu \
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
     python examples/train_lm.py
 
@@ -28,29 +28,27 @@ own mesh), and the groups converge in lockstep.
 
 from __future__ import annotations
 
+import dataclasses
 import logging
 import os
 import time
 
-from torchft_tpu.utils import apply_platform_env
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+from jax.sharding import NamedSharding
 
-apply_platform_env()  # TORCHFT_PLATFORM=cpu forces the CPU backend
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-import numpy as np  # noqa: E402
-import optax  # noqa: E402
-from jax.sharding import NamedSharding  # noqa: E402
-
-from torchft_tpu import HostCommunicator, Manager, chaos  # noqa: E402
-from torchft_tpu.data import (DistributedSampler, ElasticLoader,  # noqa: E402
+from torchft_tpu import HostCommunicator, Manager, chaos
+from torchft_tpu.data import (DistributedSampler, ElasticLoader,
                               ElasticSampler, StatefulLoader,
                               TokenFileDataset)
-from torchft_tpu.models import (Transformer, TransformerConfig,  # noqa: E402
+from torchft_tpu.models import (Transformer, TransformerConfig,
                                 chunked_causal_lm_loss, tiny_config,
                                 tp_rules)
-from torchft_tpu.parallel import (FTTrainer, batch_spec,  # noqa: E402
+from torchft_tpu.parallel import (FTTrainer, batch_spec,
                                   combined_shardings, make_mesh)
+from torchft_tpu.utils import enable_compile_cache
 
 logging.basicConfig(level=logging.INFO)
 logger = logging.getLogger("train_lm")
@@ -58,8 +56,8 @@ logger = logging.getLogger("train_lm")
 
 def make_config() -> TransformerConfig:
     """Size from env; defaults to a demo-scale model that fits anywhere.
-    On real TPU slices, swap in e.g. ``llama2_7b_config()`` and the flash
-    kernel (``attention_fn=flash_attention``) — the loop is unchanged."""
+    ``MODEL=7b`` is Llama-2 7B with the flash kernel; ``main`` re-wraps
+    the kernel for the group's mesh once that exists."""
     if os.environ.get("MODEL", "tiny") == "tiny":
         return tiny_config(max_seq_len=128)
     from torchft_tpu.models import llama2_7b_config
@@ -69,6 +67,7 @@ def make_config() -> TransformerConfig:
 
 
 def main() -> None:
+    enable_compile_cache()
     replica_group = int(os.environ.get("REPLICA_GROUP_ID", 0))
     num_groups = int(os.environ.get("NUM_REPLICA_GROUPS", 2))
     total_steps = int(os.environ.get("TOTAL_STEPS", 50))
@@ -82,13 +81,20 @@ def main() -> None:
     overlap = int(os.environ.get("OVERLAP_STEPS", 0))
 
     cfg = make_config()
-    model = Transformer(cfg)
 
     # The group's own mesh: shard params over fsdp, projections over tp.
     n_dev = jax.device_count()
     tp = 2 if n_dev % 2 == 0 and cfg.num_heads % 2 == 0 else 1
     mesh = make_mesh({"fsdp": n_dev // tp, "tp": tp})
     logger.info("group %d mesh: %s", replica_group, dict(mesh.shape))
+    if cfg.attention_fn is not None:
+        # XLA cannot partition a Mosaic kernel: inside the sharded jit it
+        # runs under a shard_map over this group's mesh.
+        from torchft_tpu.ops import sharded_flash_attention
+
+        cfg = dataclasses.replace(
+            cfg, attention_fn=sharded_flash_attention(mesh))
+    model = Transformer(cfg)
 
     # Storage-backed corpus: TOKENS_FILE points at a flat token .npy (your
     # real pretraining data); otherwise a synthetic one is materialized

@@ -54,15 +54,14 @@ from torchft_tpu.data import DistributedSampler
 from torchft_tpu.models import MLP
 from torchft_tpu.parallel import FTTrainer, make_mesh
 from torchft_tpu.parallel.sharding import batch_spec, combined_shardings
-from torchft_tpu.utils import apply_platform_env
-
-apply_platform_env()  # TORCHFT_PLATFORM=cpu forces the CPU backend
+from torchft_tpu.utils import enable_compile_cache
 
 logging.basicConfig(level=logging.INFO)
 logger = logging.getLogger("train_pod")
 
 
 def main() -> None:
+    enable_compile_cache()
     # ---------------------------------------------------------- topology
     replica_group = int(os.environ.get("REPLICA_GROUP_ID", 0))
     num_groups = int(os.environ.get("NUM_REPLICA_GROUPS", 1))

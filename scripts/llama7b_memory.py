@@ -50,7 +50,6 @@ def main() -> int:
 
     from torchft_tpu.models import llama2_7b_config, Transformer
     from torchft_tpu.models.transformer import chunked_causal_lm_loss
-    from torchft_tpu.ops import flash_attention
     from torchft_tpu.parallel.sharding import (batch_spec,
                                                infer_fsdp_sharding)
     from jax.sharding import Mesh, NamedSharding
@@ -69,21 +68,14 @@ def main() -> int:
     mesh = Mesh(np.array(devices).reshape(N_DEVICES), ("fsdp",))
 
     # Mosaic (Pallas) kernels cannot be auto-partitioned by the SPMD
-    # partitioner; wrap flash attention in a shard_map over the batch axis
-    # (per-chip batch 1, full sequence — no collectives inside).
-    from jax import shard_map
-    from jax.sharding import PartitionSpec as P
+    # partitioner: the library's wrapper runs the kernel in a shard_map
+    # over the batch axis (per-chip batch 1, full sequence — no
+    # collectives inside). interpret=False: this process's own backend is
+    # the CPU, the program is compiled for the described chips.
+    from torchft_tpu.ops import sharded_flash_attention
 
-    def sharded_flash(q, k, v, causal=True):
-        if q.shape[0] % N_DEVICES:  # abstract-init trace (batch 1)
-            return flash_attention(q, k, v, causal)
-        return shard_map(
-            lambda q_, k_, v_: flash_attention(q_, k_, v_, causal),
-            mesh=mesh, in_specs=(P("fsdp"),) * 3, out_specs=P("fsdp"),
-            check_vma=False,
-        )(q, k, v)
-
-    cfg = llama2_7b_config(attention_fn=sharded_flash)
+    cfg = llama2_7b_config(
+        attention_fn=sharded_flash_attention(mesh, interpret=False))
     model = Transformer(cfg)
     tokens_shape = jax.ShapeDtypeStruct((GLOBAL_BATCH, SEQ), jnp.int32)
 

@@ -319,17 +319,23 @@ class TestFusedBwdHardware:
     nqb>=4 gate is empirical; interpret mode can't catch a Mosaic
     pipelining race — see flash_attention.py's safety contract).
 
-    Marked slow as well as nightly: the subprocess probes for a REAL
-    TPU with JAX_PLATFORMS unset, and on a TPU-less box the plugin's
-    driver-connect retries burn minutes of wall clock before the check
-    exits 75 (skip) — that probe must never sit in the per-commit
-    tier-1 budget (this rides `scripts/test.sh nightly`, -m "nightly
-    or slow", as the module docstring promises)."""
+    Nightly, and slow as well so that it never sits in the per-commit
+    tier-1 budget: the child process runs with JAX_PLATFORMS unset and
+    initialises whatever accelerator the machine has, and decides by
+    exit code (75 = no TPU, which is a skip HERE; ``chip_smoke.py`` runs
+    the same comparison in-process, where no TPU is a failure). A chip
+    belongs to one process, so the child is started only from a parent
+    that holds no TPU backend — the suite's parent is pinned to the CPU
+    by conftest.py."""
 
     def test_fused_matches_split_on_hardware(self):
         import subprocess
         import sys as _sys
 
+        assert jax.default_backend() == "cpu", (
+            "this process holds an accelerator; the child could not get "
+            "the chip — run python -m torchft_tpu.ops.fused_bwd_check "
+            "directly instead")
         env = dict(os.environ)
         # Undo the suite's forced-CPU config so the subprocess can see a
         # real TPU if one is attached.

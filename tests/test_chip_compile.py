@@ -1,0 +1,253 @@
+"""Ahead-of-time compiles for the chip that is not attached here.
+
+The TPU compiler is installed in this sandbox and compiles for a *described*
+v5e 2x2 host (section 2 of the on-chip-measurement guide): what it refuses
+here, the chip would refuse too — a kernel that cannot be partitioned, a
+program that does not fit 16 GB — and it costs no chip time. These are the
+programs ``chip_smoke.py`` runs at its real sizes: Llama-2-7B widths, depth
+cut to 2 (adamw, one group) or 1 (sgd, two groups).
+
+This is the only file that describes a TPU topology, and it does so inside a
+module-scoped fixture: only one process may hold the TPU library, so the call
+must never run while a module is imported or collected. A compile that passes
+is not a chip run.
+"""
+
+from __future__ import annotations
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+
+from torchft_tpu.models import (Transformer, chunked_causal_lm_loss,
+                                llama2_7b_config, tp_rules)
+from torchft_tpu.ops import flash_attention, sharded_flash_attention
+from torchft_tpu.parallel import batch_spec, combined_shardings
+
+GiB = 2 ** 30
+# What a v5e chip can hand out: 16 GB of HBM less the runtime's reserve
+# (the compiler's own refusal quotes 15.75G).
+HBM_BYTES = int(15.75 * GiB)
+SEQ = 4096
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here: skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A program compiled for a described chip is written to the persistent
+    # cache but cannot be read back without the chip: keep it out.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _shaped(tree, sharding):
+    """Shapes with a sharding (one for all leaves, or a matching tree)."""
+    if not isinstance(sharding, jax.sharding.Sharding):
+        return jax.tree_util.tree_map(
+            lambda l, s: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=s),
+            tree, sharding)
+    return jax.tree_util.tree_map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=sharding),
+        tree)
+
+
+def _footprint(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+
+
+def _model(depth: int, attention_fn):
+    model = Transformer(llama2_7b_config(
+        num_layers=depth, attention_fn=attention_fn, remat=True))
+
+    def loss_fn(params, batch):
+        hidden = model.apply(params, batch["tokens"], return_hidden=True)
+        return chunked_causal_lm_loss(
+            hidden, params["params"]["lm_head"]["kernel"], batch["tokens"])
+
+    shapes = jax.eval_shape(
+        lambda k: model.init(k, jnp.zeros((1, 8), jnp.int32)),
+        jax.random.key(0))
+    return loss_fn, shapes
+
+
+def _compiled_flash():
+    return functools.partial(flash_attention, interpret=False)
+
+
+def _trainer_programs(loss_fn, tx):
+    """The three programs FTTrainer / FTOptimizer jit (parallel/step.py,
+    optim.py), spelled the same way."""
+    def fwd_bwd(p, batch):
+        return jax.value_and_grad(loss_fn)(p, batch)
+
+    def fused(p, o, batch):  # single-group step: NOT donated
+        loss, grads = fwd_bwd(p, batch)
+        updates, new_o = tx.update(grads, o, p)
+        return loss, optax.apply_updates(p, updates), new_o
+
+    def update(p, o, grads):
+        updates, new_o = tx.update(grads, o, p)
+        return optax.apply_updates(p, updates), new_o
+
+    return (jax.jit(fwd_bwd), jax.jit(fused),
+            jax.jit(update, donate_argnums=(0, 1)))
+
+
+@pytest.mark.parametrize("fused_bwd", ["1", "0"], ids=["fused", "split"])
+def test_flash_fwd_bwd_compiles_as_a_kernel(one_chip, monkeypatch,
+                                            fused_bwd):
+    """[1, 4096, 32, 128] bf16 causal (Llama-2-7B heads), both backward
+    spellings: a Mosaic custom call, not interpreted XLA ops."""
+    monkeypatch.setenv("TORCHFT_FLASH_FUSED_BWD", fused_bwd)
+    x = jax.ShapeDtypeStruct((1, SEQ, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True,
+                               interpret=False).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    # forward + one fused backward kernel, or forward + dq + dk/dv
+    assert text.count("tpu_custom_call") >= (2 if fused_bwd == "1" else 3)
+
+
+def test_one_group_step_depth2_fits_the_chip(one_chip):
+    """Phase 2: the single-group fused step holds the old and the new
+    params + adam state at once (it is not donated), which at depth 2 is
+    within a quarter GiB of the chip; the donated update is far below."""
+    loss_fn, pshape = _model(2, _compiled_flash())
+    tx = optax.adamw(3e-4)
+    p = _shaped(pshape, one_chip)
+    o = _shaped(jax.eval_shape(tx.init, pshape), one_chip)
+    batch = {"tokens": jax.ShapeDtypeStruct((1, SEQ), jnp.int32,
+                                            sharding=one_chip)}
+    _, fused, update = _trainer_programs(loss_fn, tx)
+    c = fused.lower(p, o, batch).compile()
+    assert "tpu_custom_call" in c.as_text()
+    assert 14 * GiB < _footprint(c) < HBM_BYTES
+    assert _footprint(update.lower(p, o, p).compile()) < 11 * GiB
+
+
+def test_two_group_programs_depth1_fit_twice(one_chip):
+    """Phase 3: two groups share the chip, so fwd_bwd (params in, grads
+    out) plus the averaged grads coming back must fit twice over."""
+    loss_fn, pshape = _model(1, _compiled_flash())
+    tx = optax.sgd(1e-3)
+    p = _shaped(pshape, one_chip)
+    o = _shaped(jax.eval_shape(tx.init, pshape), one_chip)
+    batch = {"tokens": jax.ShapeDtypeStruct((1, SEQ), jnp.int32,
+                                            sharding=one_chip)}
+    fwd_bwd, _, update = _trainer_programs(loss_fn, tx)
+    grads_bytes = sum(int(np.prod(l.shape)) * 4
+                      for l in jax.tree_util.tree_leaves(pshape))
+    step = _footprint(fwd_bwd.lower(p, batch).compile()) + grads_bytes
+    assert 2 * step < HBM_BYTES
+    assert _footprint(update.lower(p, o, p).compile()) < 2 * grads_bytes + GiB
+
+
+def test_boundary_programs_over_the_depth2_state(one_chip):
+    """The attestation digest over params + adam state (7.45 GiB) and the
+    int8 quantize-pack of the largest leaf (the 500 MB embedding): their
+    ravel/concatenate must not need a second copy of what they read."""
+    from torchft_tpu import manager as manager_mod
+
+    _, pshape = _model(2, None)
+    state = {"params": pshape,
+             "opt_state": jax.eval_shape(optax.adamw(3e-4).init, pshape)}
+    leaves = jax.tree_util.tree_leaves(_shaped(state, one_chip))
+    state_bytes = sum(int(np.prod(l.shape)) * l.dtype.itemsize
+                      for l in leaves)
+    assert state_bytes > 7 * GiB
+
+    # The jitted functions live behind wrappers that execute; reach them
+    # by running each once on a few elements.
+    tiny = [jnp.zeros((8,), jnp.float32)]
+    manager_mod._attest_device_words(tiny)
+    attest = manager_mod._ATTEST_FNS["attest"]
+    m = attest.lower(leaves).compile().memory_analysis()
+    assert m.temp_size_in_bytes < 64 * 2 ** 20
+    assert m.output_size_in_bytes <= 1024
+
+    manager_mod._device_quantize_pack(tiny, jnp.zeros((8,), jnp.float32))
+    (quant,) = [f for f in manager_mod._DEV_QUANT_FNS.values()]
+    big = max(jax.tree_util.tree_leaves(_shaped(pshape, one_chip)),
+              key=lambda l: int(np.prod(l.shape)))
+    n = int(np.prod(big.shape))
+    assert n * 4 >= 500 * 2 ** 20
+    res = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
+    c = quant.lower([big], res).compile()
+    # leaf + residual in, int8 payload + new residual out, and temporaries
+    # of about three more leaves (6.25x the leaf in all, 3.05 GiB): it
+    # fits beside two groups' depth-1 params and grads, not much more.
+    assert _footprint(c) < 7 * n * 4
+
+
+def test_sharded_group_step_compiles_for_four_chips(topo):
+    """--chips 4 (a): the fused step over a fsdp=2 x tp=2 mesh of the
+    described chips, flash attention through the library's shard_map
+    wrapper. Each chip holds about a quarter of params + adam state."""
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("fsdp", "tp"))
+    loss_fn, pshape = _model(
+        2, sharded_flash_attention(mesh, interpret=False))
+    tx = optax.adamw(3e-4)
+    oshape = jax.eval_shape(tx.init, pshape)
+    p = _shaped(pshape, combined_shardings(pshape, mesh, tp_rules()))
+    o = _shaped(oshape, combined_shardings(oshape, mesh, tp_rules()))
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (2, SEQ // 2), jnp.int32,
+        sharding=NamedSharding(mesh, batch_spec(mesh,
+                                                data_axes=("fsdp",))))}
+    _, fused, _ = _trainer_programs(loss_fn, tx)
+    c = fused.lower(p, o, batch).compile()
+    assert "tpu_custom_call" in c.as_text()
+    state_bytes = sum(int(np.prod(l.shape)) * l.dtype.itemsize
+                      for l in jax.tree_util.tree_leaves((pshape, oshape)))
+    per_chip_args = c.memory_analysis().argument_size_in_bytes
+    assert per_chip_args < 1.3 * state_bytes / 4
+    assert _footprint(c) < HBM_BYTES
+
+
+def test_bare_kernel_in_a_sharded_jit_is_refused(topo):
+    """Mosaic kernels cannot be partitioned automatically. The bare kernel
+    under a jit sharded over several chips is refused by jax itself, which
+    says to wrap it in a shard_map: ``sharded_flash_attention`` (above) is
+    that wrapping, and ``flash_attention``'s docstring says so."""
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("fsdp", "tp"))
+    x = jax.ShapeDtypeStruct(
+        (2, SEQ, 32, 128), jnp.bfloat16,
+        sharding=NamedSharding(mesh, jax.sharding.PartitionSpec(
+            "fsdp", None, "tp", None)))
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True,
+                               interpret=False).astype(jnp.float32).sum()
+
+    with pytest.raises(NotImplementedError,
+                       match="cannot be automatically partitioned"):
+        jax.jit(jax.grad(loss)).lower(x, x, x).compile()

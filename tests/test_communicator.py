@@ -208,6 +208,39 @@ class TestHostCommunicator:
         for c in comms:
             c.shutdown()
 
+    def test_worker_lets_go_of_a_finished_op(self, store):
+        """Once an op has resolved and its caller dropped it, nothing in
+        the comm may keep it alive: a done-callback closes over the step's
+        gradient leaves and their average, so a worker frame holding the
+        last op pins two gradient trees on the device until the NEXT
+        exchange (3.5 GiB a group at Llama-2-7B widths, seen on the
+        chip)."""
+        import gc
+        import weakref
+
+        addr = store.address()
+        comms = [HostCommunicator(timeout_sec=30) for _ in range(2)]
+
+        class Payload:
+            pass
+
+        refs = []
+
+        def run(rank):
+            comm = comms[rank]
+            comm.configure(f"{addr}/q1", rank, 2)
+            held = Payload()
+            refs.append(weakref.ref(held))
+            fut = comm.allreduce({"a": np.ones(4, np.float32)})
+            fut.add_done_callback(lambda f, held=held: None)
+            fut.result(timeout=30)
+
+        _run_ranks(2, run)
+        gc.collect()
+        assert [r() for r in refs] == [None, None]
+        for c in comms:
+            c.shutdown()
+
     def test_allreduce_config_skew_fails_fast(self, store):
         # Mismatched (bucket_bytes, wire_dtype) across groups would wedge
         # every bucketed ring collective on mismatched collective counts;

@@ -140,7 +140,7 @@ class TestFastPathNative:
 
         lh = Lighthouse(bind="127.0.0.1:0", min_replicas=2,
                         join_timeout_ms=2000, quorum_tick_ms=10,
-                        heartbeat_fresh_ms=300)
+                        heartbeat_fresh_ms=300, fast_path=True)
         servers, clients = [], []
         try:
             for gid in ("ga", "gb"):
@@ -192,7 +192,7 @@ class TestFastPathNative:
 
         lh = Lighthouse(bind="127.0.0.1:0", min_replicas=2,
                         join_timeout_ms=2000, quorum_tick_ms=10,
-                        heartbeat_fresh_ms=400)
+                        heartbeat_fresh_ms=400, fast_path=True)
         servers, clients = [], []
         try:
             for gid in ("ga", "gb"):

@@ -116,7 +116,8 @@ DOCUMENTED_KEYS = frozenset([
     # state attestation (docs/design/state_attestation.md): commit-
     # boundary digest accounting, the quarantine latch + ladder
     # counters, and the digest kernel's trace-time tripwire
-    "sdc_digests_total", "sdc_digest_ms_total", "sdc_quarantined",
+    "sdc_digests_total", "sdc_digest_failures", "sdc_digest_ms_total",
+    "sdc_quarantined",
     "sdc_quarantines_total", "sdc_quarantine_clears_total",
     "sdc_reheals_total", "sdc_refusals_total", "sdc_chaos_flips_total",
     "sdc_digest_cache_misses",

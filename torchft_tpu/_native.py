@@ -66,26 +66,31 @@ def _load() -> ctypes.CDLL:
         try:
             _build_native()
         except Exception as e:  # noqa: BLE001
-            # Installed wheels ship a prebuilt .so whose mtime can trail
-            # the packaged sources (install order), and the site-packages
-            # tree may be read-only / compiler-less — the shipped library
-            # matches its shipped sources by construction, so use it.
             # Without any library at all, the failure is real.
             if not os.path.exists(_LIB_PATH):
                 raise RuntimeError(
                     "torchft_tpu native core missing and in-place build "
                     f"failed ({e}); install from a wheel or make "
                     "cmake+ninja+protobuf available") from e
+            # A writable checkout whose sources are newer than the .so
+            # was edited (or freshly checked out next to an old build):
+            # loading the old library would cross a stale ABI, so the
+            # failed rebuild is the error.
+            if os.access(_CORE_DIR, os.W_OK):
+                detail = getattr(e, "stderr", b"") or b""
+                raise RuntimeError(
+                    "torchft_tpu: C++ sources are newer than "
+                    f"{_LIB_PATH} and rebuilding it failed ({e}): "
+                    f"{detail[-2000:].decode(errors='replace')}") from e
             import logging
 
-            # Warning, not debug: if the sources were genuinely edited
-            # (dev tree without a toolchain) this loads a stale ABI, and a
-            # later crash would otherwise point nowhere near the cause.
+            # Read-only install (site-packages): wheels ship a prebuilt
+            # .so whose mtime can trail the packaged sources (install
+            # order); it matches its shipped sources by construction.
             logging.getLogger(__name__).warning(
                 "torchft_tpu: C++ sources look newer than the built core "
-                "but rebuilding failed (%s); loading existing %s — if you "
-                "edited the C++ sources, fix the toolchain and rebuild, "
-                "or calls may cross a stale ABI", e, _LIB_PATH)
+                "but rebuilding failed (%s) in a read-only install; "
+                "loading the shipped %s", e, _LIB_PATH)
     lib = ctypes.CDLL(_LIB_PATH)
 
     c = ctypes.c_char_p
@@ -247,7 +252,7 @@ class Lighthouse:
                  heartbeat_grace_factor: int = 4,
                  eviction_staleness_factor: int = 3,
                  auth_token: str = "",
-                 fast_path: bool = True,
+                 fast_path: bool = False,
                  standby_of: str = "",
                  replicate_ms: int = 100,
                  join_window_ms: int = 0,
@@ -274,9 +279,15 @@ class Lighthouse:
         quorum is provably live (beats within the eviction staleness
         bound) and no joiner is pending, a Quorum RPC returns the cached
         decision with a bumped epoch immediately instead of parking in the
-        tick-loop rendezvous. Any membership delta falls back to the slow
-        path, so quorum semantics are unchanged. False restores strict
-        reference behavior.
+        tick-loop rendezvous. OFF by default: the cached decision carries
+        the OTHER members' steps as of their previous request, so the
+        first member to ask in a round sees its peers one step behind,
+        counts itself the only participant (``max_world_size`` 1) and
+        divides the ring's sum by 1 — replica groups then diverge (seen
+        on the first run against the native lighthouse: two lockstep
+        groups were bitwise apart after step 2). Until the fast serve
+        carries current steps, turn it on only for control-plane
+        measurements that run no training (bench ``quorum_latency_vs_n``).
 
         ``standby_of``: non-empty = run as a WARM STANDBY of the primary
         lighthouse at this address — replicate its quorum state every

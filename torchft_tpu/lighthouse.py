@@ -47,10 +47,12 @@ def main(argv: list[str] | None = None) -> None:
                         help="shared job secret forwarded in dashboard "
                         "Kill RPCs (env TORCHFT_AUTH_TOKEN)")
     parser.add_argument("--no-fast-path", action="store_true",
-                        help="disable the membership-unchanged quorum fast "
-                        "path (cached decision + bumped epoch; see "
-                        "docs/design/control_plane.md) — every Quorum RPC "
-                        "then parks in the tick-loop rendezvous")
+                        help="accepted for existing launch scripts; the "
+                        "membership-unchanged quorum fast path is off "
+                        "(docs/design/control_plane.md: it serves peers' "
+                        "steps one round stale, which makes training "
+                        "groups miscount participants) and this CLI has "
+                        "no switch that turns it on")
     parser.add_argument("--standby-of", default="",
                         help="run as a WARM STANDBY of the primary "
                         "lighthouse at this host:port: replicate its "
@@ -108,7 +110,6 @@ def main(argv: list[str] | None = None) -> None:
         heartbeat_grace_factor=args.heartbeat_grace_factor,
         eviction_staleness_factor=args.eviction_staleness_factor,
         auth_token=args.auth_token,
-        fast_path=not args.no_fast_path,
         standby_of=args.standby_of,
         replicate_ms=args.replicate_ms,
         join_window_ms=args.join_window_ms,

@@ -25,8 +25,8 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from torchft_tpu.parallel._shard_map_compat import shard_map
 
 
 def stack_layer_params(params: Any, num_layers: int, pp: int,

@@ -26,8 +26,8 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from torchft_tpu.parallel._shard_map_compat import shard_map
 
 NEG_INF = -1e30
 

@@ -125,17 +125,35 @@ def combined_shardings(
     min_size: int = 1024,
     strict: bool = True,
 ) -> Any:
-    """TP rules where they match, automatic FSDP everywhere else — the
-    standard 3D (dp × fsdp × tp) parameter layout. A leaf matched by a rule
-    keeps the rule's spec; unmatched leaves get
-    :func:`infer_fsdp_sharding`'s placement (or replication when the mesh
-    has no ``fsdp`` axis).
+    """TP rules where they match, automatic FSDP on top and everywhere
+    else — the standard 3D (dp × fsdp × tp) parameter layout. A leaf
+    matched by a rule keeps the rule's axes and is additionally split over
+    ``fsdp_axis`` along its largest still-unsharded divisible dim (so on an
+    ``fsdp × tp`` mesh every chip holds ``1/(fsdp·tp)`` of a projection,
+    not ``1/tp``); unmatched leaves get :func:`infer_fsdp_sharding`'s
+    placement. Without an ``fsdp`` axis in the mesh, rules apply alone and
+    the rest is replicated.
 
     ``strict=False`` (the degraded-mode re-derivation,
     :func:`degraded_shardings`): a rule whose axes no longer divide a
     dim FALLS BACK to the unmatched path (inferred FSDP, which itself
     replicates non-divisible leaves) instead of raising."""
     unmatched = object()  # sentinel (None would vanish from the pytree)
+    has_fsdp = fsdp_axis in mesh.axis_names and mesh.shape[fsdp_axis] > 1
+
+    def with_fsdp(leaf, spec):
+        shape = np.shape(leaf)
+        used = {a for e in spec if e is not None
+                for a in ((e,) if isinstance(e, str) else e)}
+        if (not has_fsdp or fsdp_axis in used
+                or int(np.prod(shape or (1,))) < min_size):
+            return spec
+        entries = list(spec) + [None] * (len(shape) - len(spec))
+        for d in np.argsort(shape)[::-1]:
+            if entries[d] is None and shape[d] % mesh.shape[fsdp_axis] == 0:
+                entries[d] = fsdp_axis
+                return PartitionSpec(*entries)
+        return spec
 
     def mark(path, leaf):
         p = path_str(path)
@@ -147,11 +165,11 @@ def combined_shardings(
                     if strict:
                         raise
                     return unmatched  # rule no longer fits: fall back
-                return NamedSharding(mesh, spec)
+                return NamedSharding(mesh, with_fsdp(leaf, spec))
         return unmatched
 
     ruled = jax.tree_util.tree_map_with_path(mark, tree)
-    if fsdp_axis in mesh.axis_names and mesh.shape[fsdp_axis] > 1:
+    if has_fsdp:
         fsdp = infer_fsdp_sharding(tree, mesh, fsdp_axis, min_size)
     else:
         fsdp = jax.tree_util.tree_map(
