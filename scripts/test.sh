@@ -2,9 +2,10 @@
 # Local test runner, mirroring CI (reference scripts/test.sh: cargo test +
 # pytest; here: cmake/ninja C++ tests + tiered pytest).
 #
-# Tiers, each with its wall clock printed (round-3 verdict weak #2: a
-# suite must FIT the box it is judged/CI'd on — budget: unit < 2 min,
-# everything < 8 min on 1-2 cores):
+# Tiers, each with its wall clock printed. Measured (PR 27, 8 CPU
+# cores): all of tests/ with -m 'not slow' on six xdist workers takes
+# 156-167 s of wall and about 750 s of summed test time; the tiers
+# below run in one process each and were not timed:
 #   core   — C++ control-plane tests
 #   unit   — protocol/state-machine/IO tests, no heavy compiles
 #   heavy  — pallas-interpret kernels + sharded-jit parallelism tests

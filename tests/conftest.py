@@ -7,7 +7,13 @@ trick, mirroring the reference's thread-based integration tests,
 /root/reference/torchft/manager_integ_test.py:144-154).
 """
 
+import contextlib
+import faulthandler
 import os
+import signal
+import sys
+
+import pytest
 
 # Ask for the CPU with 8 virtual devices before any test touches a JAX
 # backend. The env vars are also set for the subprocesses tests spawn.
@@ -44,9 +50,66 @@ def native_available() -> bool:
 
 def requires_native():
     """Skipif marker for tests needing the native control plane."""
-    import pytest
-
     return pytest.mark.skipif(
         not native_available(),
         reason="native control-plane library unavailable "
                "(no C++ toolchain)")
+
+
+# Every test's own time limit. The longest honest tier-1 test takes
+# about a minute beside five busy workers; a test that runs past this
+# is waiting on something that will not come, and one such test must
+# not cost the whole run its clock.
+TEST_LIMIT_S = 150.0
+_REFIRE_S = 5.0
+
+
+# The stack dump's file: stderr as it was before pytest's capture took
+# fd 2 (capture is suspended while plugins are configured), so the dump
+# reaches the log even when the test never returns to be reported.
+_REAL_STDERR = sys.__stderr__
+
+
+def pytest_configure(config):
+    global _REAL_STDERR
+    _REAL_STDERR = os.fdopen(os.dup(sys.__stderr__.fileno()), "w")
+
+
+@contextlib.contextmanager
+def time_limit(seconds, name):
+    """Fail the enclosed block, naming ``name``, once it has run for
+    ``seconds``: SIGALRM raises pytest's failure on the main thread, and
+    again every few seconds until the block has unwound, because a
+    ``finally`` or ``__exit__`` on the way out may join the very threads
+    that hang. Shortly before (at nine tenths of the limit: at the same
+    instant the dump would race the unwinding test and show pytest's
+    stack, not the test's) faulthandler dumps every thread's stack to
+    stderr, so the log says where it hung. What it cannot do: Python
+    runs a signal handler between bytecodes, so a main thread inside a
+    native call is interrupted only when the call returns (the stack
+    dump still comes on time). Main thread only; it suspends an
+    enclosing limit's alarm and re-arms what was left of it on exit (the
+    enclosing stack dump is not re-armed)."""
+
+    def on_alarm(signum, frame):
+        pytest.fail(f"{name} ran past its limit of {seconds:g} s",
+                    pytrace=True)
+
+    old_handler = signal.signal(signal.SIGALRM, on_alarm)
+    faulthandler.dump_traceback_later(0.9 * seconds, file=_REAL_STDERR)
+    outer_left, _ = signal.setitimer(signal.ITIMER_REAL, seconds,
+                                     _REFIRE_S)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        faulthandler.cancel_dump_traceback_later()
+        signal.signal(signal.SIGALRM, old_handler)
+        if outer_left:
+            signal.setitimer(signal.ITIMER_REAL, outer_left, _REFIRE_S)
+
+
+@pytest.fixture(autouse=True)
+def _test_time_limit(request):
+    with time_limit(TEST_LIMIT_S, request.node.nodeid):
+        yield

@@ -23,7 +23,7 @@ contract); its unit matrix lives in ``_core/core_test.cc`` and the
 native parity round rides nightly.
 """
 
-import threading
+import functools
 import urllib.error
 import urllib.request
 from unittest.mock import MagicMock
@@ -33,13 +33,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import mockplane
+from mockplane import FAKE_STORE_ADDR, FakeStore
 from torchft_tpu import chaos, fleet, serialization
-from torchft_tpu._native import QuorumResult
 from torchft_tpu.chaos import ChaosSchedule, EndpointChaos
 from torchft_tpu.checkpointing import CheckpointServer
-from torchft_tpu.communicator import DummyCommunicator
 from torchft_tpu.fleet import FleetAggregator, StepDigest
-from torchft_tpu.manager import (_PACK_STATS, Manager, _addr_base,
+from torchft_tpu.manager import (_PACK_STATS, _addr_base,
                                  _attest_device_words)
 from torchft_tpu.policy import PhasedChaos
 
@@ -56,56 +56,12 @@ def mk_digest(rid, step=5, wall=100.0, healing=False, capacity=1.0,
                       trace_addr=trace_addr)
 
 
-def quorum_result(quorum_id=1, recover_manager_address="m:1",
-                  store_address="s:1", max_step=1, max_rank=0,
-                  max_world_size=3, replica_rank=0,
-                  replica_world_size=3, heal=False, **kw):
-    return QuorumResult(
-        quorum_id=quorum_id,
-        recover_manager_address=recover_manager_address,
-        store_address=store_address, max_step=max_step,
-        max_rank=max_rank, max_world_size=max_world_size,
-        replica_rank=replica_rank,
-        replica_world_size=replica_world_size, heal=heal, **kw)
-
-
-def make_manager(client=None, replica_id="sdc0", **kw):
-    if client is None:
-        client = MagicMock()
-        client.quorum.return_value = quorum_result()
-        client.should_commit.return_value = True
-    return Manager(
-        comm=DummyCommunicator(),
-        load_state_dict=kw.pop("load_state_dict", MagicMock()),
-        state_dict=kw.pop("state_dict",
-                          lambda: {"w": np.arange(8, dtype=np.float32)}),
-        min_replica_size=1,
-        use_async_quorum=False,
-        rank=0, world_size=1,
-        replica_id=replica_id,
-        _manager_client=client,
-        **kw,
-    )
-
-
-class FakeStore:
-    """Dict-backed stand-in for the native StoreClient (same shape the
-    churn tests inject via ``Manager._healset_store``)."""
-
-    def __init__(self):
-        self.kv = {}
-        self.lock = threading.Lock()
-
-    def set(self, key, value):
-        with self.lock:
-            self.kv[key] = value if isinstance(value, bytes) \
-                else str(value).encode()
-
-    def get(self, key, timeout_ms=0):
-        with self.lock:
-            if key not in self.kv:
-                raise KeyError(key)
-            return self.kv[key]
+quorum_result = functools.partial(
+    mockplane.quorum_result, max_world_size=3, replica_world_size=3)
+make_manager = functools.partial(
+    mockplane.make_manager, quorum=quorum_result(), min_replica_size=1,
+    use_async_quorum=False, replica_id="sdc0",
+    state_dict=lambda: {"w": np.arange(8, dtype=np.float32)})
 
 
 # ------------------------------------------------------- digest kernel
@@ -459,14 +415,13 @@ class TestDonorAdmission:
             m.shutdown()
 
     def test_healset_donors_filter_quarantined(self):
-        m = make_manager()
         store = FakeStore()
         store.set("torchft/healset/1", b"3:http://bad:1/checkpoint/3")
         store.set("torchft/healset/2", b"3:http://live:1/checkpoint/3")
-        m._healset_store = ("s:1", store)
+        m = make_manager(store=store)
         self._quarantine_bases(m, "http://bad:1")
         try:
-            q = quorum_result(max_step=3, replica_rank=0)
+            q = quorum_result(store_address=FAKE_STORE_ADDR, max_step=3)
             donors = m._healset_donors(q, "http://primary:1/checkpoint/3")
             assert donors == ["http://primary:1/checkpoint/3",
                               "http://live:1/checkpoint/3"]
@@ -474,13 +429,12 @@ class TestDonorAdmission:
             m.shutdown()
 
     def test_ram_peer_bases_filter_quarantined_and_tombstoned(self):
-        m = make_manager()
         store = FakeStore()
         store.set("torchft/healset/1", b"-1:")  # withdrawn
         store.set("torchft/healset/2", b"4:http://bad:1/checkpoint/4")
         store.set("torchft/healset/3", b"4:http://live:1/checkpoint/4")
-        m._healset_store = ("s:1", store)
-        m._last_round_facts = ("s:1", 0, 4)
+        m = make_manager(store=store)
+        m._last_round_facts = (FAKE_STORE_ADDR, 0, 4)
         self._quarantine_bases(m, "http://bad:1")
         try:
             assert m._ram_peer_bases() == ["http://live:1"]
@@ -519,9 +473,8 @@ class TestQuarantineLadder:
     def test_latch_enters_the_full_ladder(self):
         store = FakeStore()
         store.set("torchft/healset/0", b"1:http://me:1/checkpoint/1")
-        m = make_manager()
-        m._healset_store = ("s:1", store)
-        m._last_round_facts = ("s:1", 0, 3)
+        m = make_manager(store=store)
+        m._last_round_facts = (FAKE_STORE_ADDR, 0, 3)
         m._flight = MagicMock()
         try:
             m._consume_fleet_hint(self._verdict(
@@ -626,13 +579,14 @@ class TestQuarantineLadder:
         store = FakeStore()
         store.set("torchft/healset/1", b"-1:")
         store.set("torchft/healset/2", b"1:http://bad:1/checkpoint/1")
-        m = make_manager()
-        m._healset_store = ("s:1", store)
+        m = make_manager(store=store)
         with m._metrics_lock:
             m._sdc_quarantined = True
             m._sdc_quarantined_bases = {"http://bad:1"}
         try:
-            m._sdc_reheal(quorum_result(recover_manager_address=""))
+            m._sdc_reheal(quorum_result(
+                recover_manager_address="",
+                store_address=FAKE_STORE_ADDR))
             assert m._sdc_quarantined
             assert m._pending_state_dict is None
             assert m.metrics()["sdc_reheals_total"] == 1.0
@@ -789,15 +743,15 @@ class SdcSoakHarness:
             client.should_commit.return_value = True
             self.clients[rid] = client
             m = make_manager(client=client, replica_id=rid,
-                             state_dict=lambda _c=cell: _c)
+                             state_dict=lambda _c=cell: _c,
+                             store=self.store)
             m._user_load_state_dict = \
                 lambda s, _c=cell: (_c.clear(), _c.update(s))
-            m._healset_store = ("s:1", self.store)
             self.mgrs[rid] = m
 
     def _qr(self, rank, step, **kw):
-        return quorum_result(max_step=step, max_rank=2, replica_rank=rank,
-                             **kw)
+        return quorum_result(store_address=FAKE_STORE_ADDR, max_step=step,
+                             max_rank=2, replica_rank=rank, **kw)
 
     def round(self, r):
         """One commit boundary across the fleet; returns the aggregate."""

@@ -18,8 +18,9 @@ from torchft_tpu.chaos import (ChaosCommunicator, ChaosSchedule, Decision,
 from torchft_tpu.communicator import CommunicatorError, DummyCommunicator
 from torchft_tpu.retry import RetryPolicy, RetryStats
 
-
 import conftest
+from mockplane import (FAKE_STORE_ADDR, FakeStore, make_manager,
+                       quorum_result)
 
 requires_native = conftest.requires_native()
 
@@ -394,24 +395,14 @@ class TestPoisonedRingRecovery:
     recovery prefix keyed by (quorum_id, max_step)."""
 
     def _make_manager(self, comm, client):
-        from unittest.mock import MagicMock
-
-        from torchft_tpu.manager import Manager
-
-        return Manager(
-            comm=comm, load_state_dict=MagicMock(),
-            state_dict=lambda: {}, min_replica_size=1,
-            use_async_quorum=False, rank=0, world_size=1,
-            replica_id="poison", _manager_client=client)
+        # The fake store keeps the rendezvous prefix a non-empty address.
+        return make_manager(
+            client, comm, store=FakeStore(), state_dict=lambda: {},
+            min_replica_size=1, use_async_quorum=False)
 
     def _quorum(self, qid, max_step):
-        from torchft_tpu._native import QuorumResult
-
-        return QuorumResult(
-            quorum_id=qid, recover_manager_address="m:1",
-            store_address="s:1", max_step=max_step, max_rank=0,
-            max_world_size=2, replica_rank=0, replica_world_size=2,
-            heal=False)
+        return quorum_result(store_address=FAKE_STORE_ADDR, quorum_id=qid,
+                             max_step=max_step)
 
     def test_comm_error_forces_recovery_rendezvous(self):
         from unittest.mock import MagicMock
@@ -432,12 +423,12 @@ class TestPoisonedRingRecovery:
         m = self._make_manager(comm, client)
         try:
             m.step()
-            assert comm.prefixes == ["s:1/torchft/7/0"]
+            assert comm.prefixes == [f"{FAKE_STORE_ADDR}/torchft/7/0"]
             # Transient ring failure: membership unchanged, ring dead.
             m.report_error(CommunicatorError("connection reset by peer"))
             assert not m.should_commit()
             m.step()  # same quorum id → recovery prefix, not a no-op
-            assert comm.prefixes[-1] == "s:1/torchft/7.r3/0"
+            assert comm.prefixes[-1] == f"{FAKE_STORE_ADDR}/torchft/7.r3/0"
             # Poison cleared by the successful rebuild: the next same-
             # quorum round reconfigures nothing.
             client.should_commit.return_value = True
@@ -496,8 +487,8 @@ class TestPoisonedRingRecovery:
             with pytest.raises(CommunicatorError):
                 m.step()          # sync mode surfaces the failed round
             m.step()              # retried: poison still set → try again
-            assert comm.prefixes[-2:] == ["s:1/torchft/9.r4/0",
-                                          "s:1/torchft/9.r4/0"]
+            assert comm.prefixes[-2:] == \
+                [f"{FAKE_STORE_ADDR}/torchft/9.r4/0"] * 2
         finally:
             m.shutdown()
 

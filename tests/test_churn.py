@@ -22,6 +22,7 @@ goodput at graceful churn, bitwise convergence through membership
 drift) is native-gated and rides nightly.
 """
 
+import functools
 import os
 import signal
 import threading
@@ -32,82 +33,22 @@ import numpy as np
 import pytest
 
 import conftest
+import mockplane
+from mockplane import (FAKE_STORE_ADDR, FakeStore, boundary, mock_client,
+                       quorum_result)
 from torchft_tpu import chaos
-from torchft_tpu._native import QuorumResult
 from torchft_tpu.chaos import ChaosSchedule, ChurnOrchestrator, EndpointChaos
 from torchft_tpu.checkpointing import CheckpointServer
-from torchft_tpu.communicator import DummyCommunicator
-from torchft_tpu.manager import Manager, PreemptedExit
+from torchft_tpu.manager import PreemptedExit
 
 requires_native = conftest.requires_native()
 
 pytestmark = pytest.mark.churn
 
 
-def quorum_result(
-    quorum_id=1,
-    recover_manager_address="manager:1234",
-    store_address="s:1",
-    max_step=1,
-    max_rank=0,
-    max_world_size=2,
-    replica_rank=0,
-    replica_world_size=2,
-    heal=False,
-):
-    return QuorumResult(
-        quorum_id=quorum_id,
-        recover_manager_address=recover_manager_address,
-        store_address=store_address,
-        max_step=max_step,
-        max_rank=max_rank,
-        max_world_size=max_world_size,
-        replica_rank=replica_rank,
-        replica_world_size=replica_world_size,
-        heal=heal,
-    )
-
-
-def make_manager(client, comm=None, min_replica_size=1, **kwargs):
-    return Manager(
-        comm=comm or DummyCommunicator(),
-        load_state_dict=kwargs.pop("load_state_dict", MagicMock()),
-        state_dict=kwargs.pop("state_dict",
-                              lambda: {"w": np.ones(4, np.float32)}),
-        min_replica_size=min_replica_size,
-        rank=0,
-        world_size=1,
-        replica_id=kwargs.pop("replica_id", "churntest"),
-        _manager_client=client,
-        **kwargs,
-    )
-
-
-def boundary(m, tree=None):
-    m.step()
-    m.allreduce(tree if tree is not None
-                else {"g": np.ones(4, np.float32)}).result()
-    return m.should_commit()
-
-
-class FakeStore:
-    """Dict-backed stand-in for the native StoreClient, injectable via
-    the Manager's per-address store-client cache."""
-
-    def __init__(self):
-        self.kv = {}
-        self.lock = threading.Lock()
-
-    def set(self, key, value):
-        with self.lock:
-            self.kv[key] = value if isinstance(value, bytes) \
-                else str(value).encode()
-
-    def get(self, key, timeout_ms=0):
-        with self.lock:
-            if key not in self.kv:
-                raise KeyError(key)
-            return self.kv[key]
+make_manager = functools.partial(
+    mockplane.make_manager, min_replica_size=1,
+    state_dict=lambda: {"w": np.ones(4, np.float32)})
 
 
 # ------------------------------------------------------ ChurnOrchestrator
@@ -208,19 +149,16 @@ class TestChurnOrchestrator:
 
 class TestPreemptionDrain:
     def participant_client(self, **kw):
-        client = MagicMock()
-        client.quorum.return_value = quorum_result(**kw)
-        client.should_commit.return_value = True
-        return client
+        return mock_client(quorum_result(**kw))
 
     def test_happy_path_drain_sequence(self, tmp_path):
         from torchft_tpu import checkpoint_io
         from torchft_tpu.checkpoint_io import AsyncCheckpointer
 
-        client = self.participant_client(replica_rank=1, max_rank=1)
+        client = self.participant_client(
+            store_address=FAKE_STORE_ADDR, replica_rank=1, max_rank=1)
         store = FakeStore()
-        m = make_manager(client)
-        m._healset_store = ("s:1", store)  # inject the quorum store
+        m = make_manager(client, store=store)
         writer = AsyncCheckpointer()
         m.set_durable_target(writer, str(tmp_path))
         pub = MagicMock()
@@ -280,9 +218,9 @@ class TestPreemptionDrain:
         store = FakeStore()
         store.set("torchft/healset/1", b"-1:")
         store.set("torchft/healset/2", b"3:http://live:1/checkpoint/3")
-        m = make_manager(client)
-        m._healset_store = ("s:1", store)
-        q = quorum_result(max_step=3, max_world_size=3, replica_rank=0)
+        m = make_manager(client, store=store)
+        q = quorum_result(store_address=FAKE_STORE_ADDR, max_step=3,
+                          max_world_size=3)
         donors = m._healset_donors(q, "http://primary:1/checkpoint/3")
         assert donors == ["http://primary:1/checkpoint/3",
                           "http://live:1/checkpoint/3"]
@@ -858,8 +796,9 @@ class TestGracefulReclaimDrive:
     def _leaver_client(self):
         client = MagicMock()
         client.quorum.side_effect = [
-            quorum_result(quorum_id=1, max_rank=1, max_world_size=2,
-                          replica_rank=1, replica_world_size=2, max_step=s)
+            quorum_result(store_address=FAKE_STORE_ADDR, quorum_id=1,
+                          max_rank=1, max_world_size=2, replica_rank=1,
+                          replica_world_size=2, max_step=s)
             for s in range(1, self.K_TOGETHER + 1)
         ]
         client.should_commit.side_effect = \
@@ -888,8 +827,8 @@ class TestGracefulReclaimDrive:
         comm_a.configure = configure_a
 
         m_a = make_manager(client_a, comm=comm_a, replica_id="groupA")
-        m_b = make_manager(client_b, comm=comm_b, replica_id="groupB")
-        m_b._healset_store = ("s:1", store)
+        m_b = make_manager(client_b, comm=comm_b, replica_id="groupB",
+                           store=store)
         from torchft_tpu.checkpoint_io import AsyncCheckpointer
 
         m_b.set_durable_target(AsyncCheckpointer(), str(tmp_path))
@@ -1053,7 +992,9 @@ class TestJoinStormAdmission:
             bind="127.0.0.1:0", min_replicas=1,
             join_timeout_ms=150,  # a window-less cut per joiner's pace
             quorum_tick_ms=10, heartbeat_fresh_ms=400,
-            eviction_staleness_factor=3, join_window_ms=800)
+            # The window holds every arrival of a wave (0.5 s of
+            # staggered starts) with a second to spare on a loaded host.
+            eviction_staleness_factor=3, join_window_ms=1500)
         servers, clients = [], []
         try:
             seed = self._mk_group(lh.address(), "seed", servers, clients)
@@ -1062,14 +1003,14 @@ class TestJoinStormAdmission:
                              timeout_ms=60_000)
             assert q0.replica_world_size == 1
 
-            def wave(tag, k, seed_step):
-                results = [None] * (k + 1)
-                threads = []
+            def wave(tag, k, step):
+                members = list(clients)  # everyone admitted so far
+                results = [None] * (k + len(members))
 
-                def seed_join(idx):
-                    results[idx] = seed.quorum(
-                        rank=0, step=seed_step,
-                        checkpoint_server_addr="ckpt-seed",
+                def rejoin(c, idx):
+                    results[idx] = c.quorum(
+                        rank=0, step=step,
+                        checkpoint_server_addr=f"ckpt-{idx}",
                         timeout_ms=60_000)
 
                 def joiner(i, idx):
@@ -1080,32 +1021,37 @@ class TestJoinStormAdmission:
                         checkpoint_server_addr=f"ckpt-{tag}{i}",
                         timeout_ms=60_000)
 
-                for i in range(k):
-                    threads.append(threading.Thread(target=joiner,
-                                                    args=(i, i)))
-                # The seed's re-join starts AFTER a few joiners are in
-                # flight: a joiner-less instant would serve it from the
-                # fast path (solo membership, world 1) before the storm
-                # even opens the window.
-                threads.insert(3, threading.Thread(target=seed_join,
-                                                   args=(k,)))
-                for t in threads:
+                threads = [threading.Thread(target=joiner, args=(i, i))
+                           for i in range(k)]
+                for t in threads[:3]:
                     t.start()
                     # Staggered past join_timeout_ms in total: without
                     # the window these arrivals would cut several rounds.
                     time.sleep(0.06)
-                for t in threads:
+                # The members' re-joins (a live group asks every step)
+                # start AFTER a few joiners are in flight: before the
+                # first joiner the unchanged membership would cut at
+                # once, and the storm would meet a round of its own.
+                rejoins = [threading.Thread(target=rejoin, args=(c, k + j))
+                           for j, c in enumerate(members)]
+                for t in rejoins:
+                    t.start()
+                for t in threads[3:]:
+                    t.start()
+                    time.sleep(0.06)
+                for t in threads + rejoins:
                     t.join(timeout=60)
                     assert not t.is_alive()
                 return results
 
             world0 = 1
-            r1 = wave("a", 8, seed_step=2)
+            r1 = wave("a", 8, step=2)
             assert all(r is not None for r in r1)
             assert {r.quorum_id for r in r1} == {q0.quorum_id + 1}
             assert {r.replica_world_size for r in r1} == {world0 + 8}
 
-            r2 = wave("b", 8, seed_step=3)
+            r2 = wave("b", 8, step=3)
+            assert all(r is not None for r in r2)
             assert {r.quorum_id for r in r2} == {q0.quorum_id + 2}
             assert {r.replica_world_size for r in r2} == {world0 + 16}
 

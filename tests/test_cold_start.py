@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from test_manager import make_manager, quorum_result
+from mockplane import make_manager, quorum_result
 from torchft_tpu import chaos as chaos_mod
 from torchft_tpu import checkpoint_io as cio
 from torchft_tpu.chaos import ChaosSchedule, EndpointChaos, parse_spec

@@ -19,6 +19,7 @@ epoch monotonicity, fast-vs-slow decision identity) lives in
 torchft_tpu/_core/core_test.cc.
 """
 
+import functools
 import os
 import signal
 import subprocess
@@ -34,8 +35,7 @@ import optax
 import pytest
 
 import conftest
-from torchft_tpu._native import QuorumResult
-from torchft_tpu.communicator import DummyCommunicator
+from mockplane import make_manager, quorum_result
 from torchft_tpu.manager import Manager, _LatencyReservoir
 
 requires_native = conftest.requires_native()
@@ -71,19 +71,8 @@ class TestLatencyReservoir:
 # --------------------------------------------------- manager-side accounting
 
 
-def _quorum_result(step=1, fast_path=False, epoch=0):
-    return QuorumResult(
-        quorum_id=7, recover_manager_address="m:1", store_address="s:1",
-        max_step=step, max_rank=0, max_world_size=2, replica_rank=0,
-        replica_world_size=2, heal=False, fast_path=fast_path, epoch=epoch)
-
-
-def _make_manager(client):
-    return Manager(
-        comm=DummyCommunicator(), load_state_dict=MagicMock(),
-        state_dict=lambda: {"w": np.ones(2)}, min_replica_size=1,
-        use_async_quorum=False, rank=0, world_size=1,
-        replica_id="cp_test", _manager_client=client)
+_make_manager = functools.partial(
+    make_manager, min_replica_size=1, use_async_quorum=False)
 
 
 @pytest.mark.control_plane
@@ -91,9 +80,12 @@ class TestManagerControlPlaneMetrics:
     def test_fast_slow_round_split_and_epoch(self):
         client = MagicMock()
         client.quorum.side_effect = [
-            _quorum_result(step=1, fast_path=False, epoch=100),
-            _quorum_result(step=2, fast_path=True, epoch=101),
-            _quorum_result(step=3, fast_path=True, epoch=103),
+            quorum_result(quorum_id=7, max_step=1, fast_path=False,
+                          epoch=100),
+            quorum_result(quorum_id=7, max_step=2, fast_path=True,
+                          epoch=101),
+            quorum_result(quorum_id=7, max_step=3, fast_path=True,
+                          epoch=103),
         ]
         m = _make_manager(client)
         for _ in range(3):
@@ -119,7 +111,7 @@ class TestManagerControlPlaneMetrics:
         q.replica_rank = 0
         q.max_rank = 0
         q.heal = False
-        q.store_address = "s:1"
+        q.store_address = ""
         m = _make_manager(client)
         m.step()
         mx = m.metrics()

@@ -17,6 +17,7 @@ gate, bench row ``degraded_goodput_ab``) needs the native control
 plane and rides ``nightly``+``slow``.
 """
 
+import functools
 import threading
 from concurrent.futures import Future
 from unittest.mock import MagicMock
@@ -25,8 +26,9 @@ import numpy as np
 import pytest
 
 import conftest
+import mockplane
+from mockplane import FAKE_STORE_ADDR
 from torchft_tpu import chaos
-from torchft_tpu._native import QuorumResult
 from torchft_tpu.backends.host import HostCommunicator, _Ring
 from torchft_tpu.communicator import (CommunicatorError,
                                       DummyCommunicator, Int8Wire,
@@ -42,47 +44,11 @@ requires_native = conftest.requires_native()
 # --------------------------------------------------------------- helpers
 
 
-def quorum_result(
-    quorum_id=1,
-    recover_manager_address="manager1:1234",
-    store_address="",
-    max_step=1,
-    max_rank=0,
-    max_world_size=1,
-    replica_rank=0,
-    replica_world_size=1,
-    heal=False,
-):
-    return QuorumResult(
-        quorum_id=quorum_id,
-        recover_manager_address=recover_manager_address,
-        store_address=store_address,
-        max_step=max_step,
-        max_rank=max_rank,
-        max_world_size=max_world_size,
-        replica_rank=replica_rank,
-        replica_world_size=replica_world_size,
-        heal=heal,
-    )
-
-
-def make_manager(client=None, comm=None, **kwargs):
-    if client is None:
-        client = MagicMock()
-        client.quorum.return_value = quorum_result()
-        client.should_commit.return_value = True
-    return Manager(
-        comm=comm or DummyCommunicator(),
-        load_state_dict=kwargs.pop("load_state_dict", MagicMock()),
-        state_dict=kwargs.pop("state_dict", lambda: {"w": np.ones(2)}),
-        min_replica_size=kwargs.pop("min_replica_size", 1),
-        rank=0,
-        world_size=1,
-        replica_id=kwargs.pop("replica_id", "degradetest"),
-        degraded_mode=kwargs.pop("degraded_mode", True),
-        _manager_client=client,
-        **kwargs,
-    )
+quorum_result = functools.partial(
+    mockplane.quorum_result, max_world_size=1, replica_world_size=1)
+make_manager = functools.partial(
+    mockplane.make_manager, quorum=quorum_result(), min_replica_size=1,
+    degraded_mode=True)
 
 
 def weighted_oracle(xs, weights, dtype=np.float32):
@@ -554,11 +520,10 @@ class TestManagerDegradedLifecycle:
 
     def test_capacity_advertised_on_quorum_store(self):
         store = MagicMock()
-        m = make_manager()
+        m = make_manager(store=store)
         try:
-            m._healset_store = ("fake:0", store)
             m.request_degrade(0.25)
-            q = quorum_result(store_address="fake:0", max_world_size=2,
+            q = quorum_result(store_address=FAKE_STORE_ADDR, max_world_size=2,
                               replica_world_size=2, replica_rank=1)
             m._publish_capacity(q)
             store.set.assert_called_with(

@@ -17,6 +17,7 @@ soak) are gated on the toolchain and ride nightly — the C++ unit
 matrix itself lives in ``_core/core_test.cc``.
 """
 
+import functools
 import json
 import os
 import sys
@@ -30,14 +31,13 @@ import numpy as np
 import pytest
 
 import conftest
+import mockplane
+from mockplane import quorum_result
 from torchft_tpu import fleet, tracing
-from torchft_tpu._native import QuorumResult
-from torchft_tpu.communicator import DummyCommunicator
 from torchft_tpu.fleet import (FleetAggregator, SLOConfig, SLOEngine,
                                StepDigest, attribute_stage,
                                format_fleet_table, resolve_trace_addrs,
                                robust_zscores, status_prometheus)
-from torchft_tpu.manager import Manager
 
 pytestmark = pytest.mark.fleet
 
@@ -54,35 +54,17 @@ def mk_digest(rid, wall, step=5, fetch=0.0, ring=0.0, put=0.0,
                       capacity_fraction=capacity, **kw)
 
 
-def hint(fleet_p95_ms=0.0, straggler_score=0.0, fleet_groups=0,
-         straggler_stage="", straggler_id="", slo_breach=""):
-    """A QuorumResult carrying only the fleet-hint fields the
-    consumption path reads (the rest is a minimal valid quorum)."""
-    return QuorumResult(
-        quorum_id=1, recover_manager_address="m:1", store_address="",
-        max_step=1, max_rank=0, max_world_size=1, replica_rank=0,
-        replica_world_size=1, heal=False,
-        fleet_p95_ms=fleet_p95_ms, straggler_score=straggler_score,
-        fleet_groups=fleet_groups, straggler_stage=straggler_stage,
-        straggler_id=straggler_id, slo_breach=slo_breach)
+def hint(**fleet_fields):
+    """A one-group quorum carrying only the fleet-hint fields the
+    consumption path reads."""
+    return quorum_result(max_world_size=1, replica_world_size=1,
+                         **fleet_fields)
 
 
-def make_manager(client=None, replica_id="fleet0", **kw):
-    if client is None:
-        client = MagicMock()
-        client.quorum.return_value = hint()
-        client.should_commit.return_value = True
-    return Manager(
-        comm=DummyCommunicator(),
-        load_state_dict=MagicMock(),
-        state_dict=lambda: {"w": np.arange(8, dtype=np.float32)},
-        min_replica_size=1,
-        use_async_quorum=False,
-        rank=0, world_size=1,
-        replica_id=replica_id,
-        _manager_client=client,
-        **kw,
-    )
+make_manager = functools.partial(
+    mockplane.make_manager, quorum=hint(), min_replica_size=1,
+    use_async_quorum=False,
+    state_dict=lambda: {"w": np.arange(8, dtype=np.float32)})
 
 
 # ------------------------------------------------------- straggler math

@@ -279,7 +279,7 @@ class TestDiLoCoIntegration:
         quorum-agreed fragment (not one derived from its stale local
         step), heal, and land bit-identical anchors. Guards the
         fragment-id-from-pre-quorum-step bug."""
-        total = 6
+        joint_rounds = 6  # after the rejoiner has healed
         lh = Lighthouse(bind="127.0.0.1:0", min_replicas=1,
                         join_timeout_ms=500, quorum_tick_ms=20)
 
@@ -306,11 +306,22 @@ class TestDiLoCoIntegration:
                 fragments=2,
             )
 
+        # The last round, named by the rejoiner once it has healed. A
+        # survivor that stopped at a round fixed beforehand could be
+        # through with it before the rejoiner is back (its restart
+        # compiles), and leave it nobody to heal from.
+        stop_at = {"round": None}
+
+        def running(t):
+            last = stop_at["round"]
+            return last is None or t.manager.current_step() < last
+
         def survivor():
             t = make(0)
             target = jnp.full(4, 1.0)
             try:
-                while t.manager.current_step() < total:
+                while running(t):
+                    assert t.manager.current_step() < 20_000
                     t.train_step(target)
                 t.flush()
                 return jax.device_get(t.anchor)
@@ -327,10 +338,15 @@ class TestDiLoCoIntegration:
                 t.shutdown()  # dies
             t = make(1)  # restart: fresh params, must rejoin + heal
             try:
-                while t.manager.current_step() < total:
+                while running(t):
                     t.train_step(target)
+                    if (stop_at["round"] is None
+                            and t.manager.metrics()["heal_count"] >= 1):
+                        # healed, so in lockstep with the survivor from
+                        # here on: both end a few joint rounds later
+                        stop_at["round"] = \
+                            t.manager.current_step() + joint_rounds
                 t.flush()
-                assert t.manager.metrics()["heal_count"] >= 1
                 return jax.device_get(t.anchor)
             finally:
                 t.shutdown()

@@ -15,53 +15,11 @@ import numpy as np
 import pytest
 
 import conftest
-from torchft_tpu._native import QuorumResult
+from mockplane import make_manager, quorum_result
 from torchft_tpu.communicator import DummyCommunicator
 from torchft_tpu.manager import Manager, WorldSizeMode, _derive_schedule
 
 requires_native = conftest.requires_native()
-
-
-def quorum_result(
-    quorum_id=1,
-    recover_manager_address="manager:1234",
-    store_address="store:1234",
-    max_step=1,
-    max_rank=0,
-    max_world_size=2,
-    replica_rank=0,
-    replica_world_size=2,
-    heal=False,
-):
-    return QuorumResult(
-        quorum_id=quorum_id,
-        recover_manager_address=recover_manager_address,
-        store_address=store_address,
-        max_step=max_step,
-        max_rank=max_rank,
-        max_world_size=max_world_size,
-        replica_rank=replica_rank,
-        replica_world_size=replica_world_size,
-        heal=heal,
-    )
-
-
-def make_manager(client, comm=None, use_async_quorum=True,
-                 min_replica_size=2, world_size_mode=WorldSizeMode.DYNAMIC,
-                 load_state_dict=None, state_dict=None, **kwargs):
-    return Manager(
-        comm=comm or DummyCommunicator(),
-        load_state_dict=load_state_dict or MagicMock(),
-        state_dict=state_dict or (lambda: {"w": np.ones(2)}),
-        min_replica_size=min_replica_size,
-        use_async_quorum=use_async_quorum,
-        world_size_mode=world_size_mode,
-        rank=0,
-        world_size=1,
-        replica_id="testgroup",
-        _manager_client=client,
-        **kwargs,
-    )
 
 
 class TestManagerHappyPath:

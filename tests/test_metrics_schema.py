@@ -17,13 +17,9 @@ Three frozen surfaces:
   metadata), required context tags on every span).
 """
 
-from unittest.mock import MagicMock
-
-import numpy as np
 import pytest
 
-from torchft_tpu import DummyCommunicator
-from torchft_tpu.manager import Manager
+from mockplane import make_manager
 from torchft_tpu import tracing
 
 pytestmark = pytest.mark.obs
@@ -161,19 +157,6 @@ DOCUMENTED_INFO_KEYS = frozenset([
 REQUIRED_TRACE_TAGS = frozenset(tracing.CONTEXT_TAGS)
 
 
-def make_manager():
-    return Manager(
-        comm=DummyCommunicator(),
-        load_state_dict=MagicMock(),
-        state_dict=lambda: {"w": np.ones(2)},
-        min_replica_size=2,
-        rank=0,
-        world_size=1,
-        replica_id="metrics-schema",
-        _manager_client=MagicMock(),
-    )
-
-
 class TestMetricsSchema:
     def test_every_documented_key_present(self):
         m = make_manager()
@@ -284,7 +267,7 @@ class TestPrometheusExposition:
     and the string diagnostics render as ONE torchft_info sample."""
 
     def test_documented_names_render(self):
-        m = make_manager()
+        m = make_manager(replica_id="metrics-schema")
         try:
             text = tracing.prometheus_text(
                 m.metrics(), m.metrics_info(),

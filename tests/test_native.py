@@ -6,6 +6,7 @@ through the same bindings the Manager runtime uses.
 """
 
 import threading
+import time
 
 import pytest
 
@@ -89,6 +90,42 @@ def test_two_group_quorum_and_heal():
         m_a.shutdown()
         m_b.shutdown()
     finally:
+        lh.shutdown()
+
+
+def test_checkpoint_address_is_served_while_the_quorum_forms():
+    """A healer learns of a quorum when its donor does, and may ask the
+    donor's manager for the checkpoint address before the donor has
+    processed its own lighthouse response: the address is registered
+    when the rank's request arrives, not when the round returns."""
+    lh = Lighthouse(bind="127.0.0.1:0", min_replicas=2, join_timeout_ms=100,
+                    quorum_tick_ms=10)
+    servers = [ManagerServer(f"group_{n}", lh.address(), bind="127.0.0.1:0",
+                             world_size=1) for n in "ab"]
+    try:
+        threads = [threading.Thread(
+            target=ManagerClient(m.address()).quorum,
+            kwargs=dict(rank=0, step=1, timeout_ms=10_000,
+                        checkpoint_server_addr=f"ckpt_{n}"))
+            for m, n in zip(servers, "ab")]
+        threads[0].start()  # parks: the quorum needs group_b too
+        healer = ManagerClient(servers[0].address())
+        deadline = time.monotonic() + 10
+        while True:
+            try:
+                assert healer.checkpoint_address(0) == "ckpt_a"
+                break
+            except RuntimeError:  # the request has not reached it yet
+                assert time.monotonic() < deadline
+                time.sleep(0.01)
+        assert threads[0].is_alive()  # answered while still forming
+        threads[1].start()
+        for t in threads:
+            t.join(timeout=30)
+            assert not t.is_alive()
+    finally:
+        for m in servers:
+            m.shutdown()
         lh.shutdown()
 
 

@@ -43,8 +43,8 @@ import jax.numpy as jnp
 import optax
 
 import conftest  # noqa: F401  (forces the CPU platform)
-from test_manager import (_make_test_rings, _wired_comm, make_manager,
-                          quorum_result)
+from mockplane import make_manager, quorum_result
+from test_manager import _make_test_rings, _wired_comm
 from torchft_tpu.backends.host import HostCommunicator
 from torchft_tpu.communicator import DummyCommunicator
 from torchft_tpu.manager import (Manager, _PACK_STATS, _pack_leaves,

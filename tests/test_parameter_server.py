@@ -31,8 +31,12 @@ class EchoPS(ParameterServer):
     def forward(self, session_id, comm):
         try:
             comm.broadcast(self.weights, root=0).result(timeout=30)
-            averaged = comm.allreduce(dict(self.weights),
-                                      op="mean").result(timeout=30)
+            # A copy of each leaf: allreduce consumes a contiguous 1-D
+            # leaf (it reduces in place), and concurrent sessions would
+            # otherwise fold into the one shared array.
+            averaged = comm.allreduce(
+                {k: v.copy() for k, v in self.weights.items()},
+                op="mean").result(timeout=30)
             with self._lock:
                 self.weights = averaged
                 self.sessions_served += 1

@@ -15,6 +15,7 @@ rides ``nightly``+``slow`` like the other soaks and needs the native
 control plane.
 """
 
+import functools
 import threading
 from unittest.mock import MagicMock
 
@@ -22,11 +23,12 @@ import numpy as np
 import pytest
 
 import conftest
-from torchft_tpu._native import QuorumResult
+import mockplane
+from mockplane import (FAKE_STORE_ADDR, FakeStore, boundary, mock_client,
+                       quorum_result)
 from torchft_tpu.backends.host import HostCommunicator
 from torchft_tpu.communicator import (CommunicatorError, DummyCommunicator,
                                       Int8Wire)
-from torchft_tpu.manager import Manager
 from torchft_tpu.policy import (LADDER, POLICIES, AdaptiveTrainer,
                                 FTPolicy, PolicyController)
 
@@ -36,71 +38,7 @@ pytestmark = pytest.mark.policy
 # --------------------------------------------------------------- helpers
 
 
-def quorum_result(
-    quorum_id=1,
-    recover_manager_address="manager1:1234",
-    store_address="",
-    max_step=1,
-    max_rank=0,
-    max_world_size=2,
-    replica_rank=0,
-    replica_world_size=2,
-    heal=False,
-):
-    return QuorumResult(
-        quorum_id=quorum_id,
-        recover_manager_address=recover_manager_address,
-        store_address=store_address,
-        max_step=max_step,
-        max_rank=max_rank,
-        max_world_size=max_world_size,
-        replica_rank=replica_rank,
-        replica_world_size=replica_world_size,
-        heal=heal,
-    )
-
-
-def make_manager(client, comm=None, min_replica_size=1, **kwargs):
-    return Manager(
-        comm=comm or DummyCommunicator(),
-        load_state_dict=kwargs.pop("load_state_dict", MagicMock()),
-        state_dict=kwargs.pop("state_dict", lambda: {"w": np.ones(2)}),
-        min_replica_size=min_replica_size,
-        rank=0,
-        world_size=1,
-        replica_id=kwargs.pop("replica_id", "policytest"),
-        _manager_client=client,
-        **kwargs,
-    )
-
-
-def boundary(m, tree=None):
-    """One scripted step/allreduce/vote boundary; returns the vote."""
-    m.step()
-    m.allreduce(tree if tree is not None
-                else {"g": np.ones(4, np.float32)}).result()
-    return m.should_commit()
-
-
-class FakeStore:
-    """Dict-backed stand-in for the native StoreClient (set/get of the
-    policy decision + healset keys), injectable via the Manager's
-    per-address store-client cache."""
-
-    def __init__(self):
-        self.kv = {}
-        self.lock = threading.Lock()
-
-    def set(self, key, value):
-        with self.lock:
-            self.kv[key] = value if isinstance(value, bytes) \
-                else str(value).encode()
-
-    def get(self, key, timeout_ms=0):
-        with self.lock:
-            if key not in self.kv:
-                raise KeyError(key)
-            return self.kv[key]
+make_manager = functools.partial(mockplane.make_manager, min_replica_size=1)
 
 
 # --------------------------------------------------------------- FTPolicy
@@ -663,17 +601,14 @@ class TestPolicyCoordination:
                                         relax_after=3, cooldown=1)
         ms = []
         for rank in range(2):
-            client = MagicMock()
-            client.quorum.return_value = quorum_result(
-                store_address="fake:0", max_rank=rank,
-                replica_rank=rank)
-            client.should_commit.return_value = True
+            client = mock_client(quorum_result(
+                store_address=FAKE_STORE_ADDR, max_rank=rank,
+                replica_rank=rank))
             m = make_manager(client,
                              comm=DummyCommunicator(world_size=2),
-                             replica_id=f"coord{rank}",
+                             replica_id=f"coord{rank}", store=store,
                              policy_controller=PolicyController(
                                  **ctl_kwargs))
-            m._healset_store = ("fake:0", store)  # inject the fake
             ms.append((m, client))
         return ms
 
@@ -707,7 +642,7 @@ class TestPolicyCoordination:
             fc.should_commit.return_value = False
             # Someone in the quorum is healing: max_world < replica_world.
             dc.quorum.return_value = quorum_result(
-                store_address="fake:0", max_rank=0, replica_rank=0,
+                store_address=FAKE_STORE_ADDR, max_rank=0, replica_rank=0,
                 max_world_size=1, replica_world_size=2)
             for _ in range(4):
                 boundary(decider)
@@ -719,7 +654,7 @@ class TestPolicyCoordination:
             # Heal finished: the deferred switch lands at the next
             # boundary.
             dc.quorum.return_value = quorum_result(
-                store_address="fake:0", max_rank=0, replica_rank=0)
+                store_address=FAKE_STORE_ADDR, max_rank=0, replica_rank=0)
             boundary(decider)
             assert decider.policy().name != "overlap-bf16"
             boundary(follower)
@@ -840,22 +775,20 @@ class TestTwoGroupTransitionsLockstep:
 
             client = MagicMock()
             client.quorum.return_value = quorum_result(
-                store_address="fake:0", max_rank=rank,
+                store_address=FAKE_STORE_ADDR, max_rank=rank,
                 replica_rank=rank)
             client.should_commit.side_effect = vote
             trainer = AdaptiveTrainer(
                 loss_fn=loss_fn, tx=optax.sgd(0.05),
                 params={"w": np.full((6, 2), 0.1, np.float32)},
-                manager_factory=lambda load, save: Manager(
-                    comm=_PairComm(hub, rank), load_state_dict=load,
-                    state_dict=save, min_replica_size=1, rank=0,
-                    world_size=1, replica_id=f"pair{rank}",
-                    _manager_client=client,
+                manager_factory=lambda load, save: make_manager(
+                    client, comm=_PairComm(hub, rank), store=store,
+                    load_state_dict=load, state_dict=save,
+                    replica_id=f"pair{rank}",
                     policy_controller=PolicyController(
                         ladder=ladder, window=4, escalate_failures=2,
                         relax_after=4, cooldown=1)),
                 jit=False)
-            trainer.manager._healset_store = ("fake:0", store)
             snaps = []
             names = []
             try:
@@ -942,11 +875,9 @@ class TestAdaptiveTrainerModes:
         trainer = AdaptiveTrainer(
             loss_fn=loss_fn, tx=optax.sgd(0.1),
             params={"w": np.zeros(4, np.float32)},
-            manager_factory=lambda load, save: Manager(
-                comm=DummyCommunicator(), load_state_dict=load,
-                state_dict=save, min_replica_size=1, rank=0,
-                world_size=1, replica_id="adaptive",
-                _manager_client=client, **kwargs),
+            manager_factory=lambda load, save: make_manager(
+                client, load_state_dict=load, state_dict=save,
+                replica_id="adaptive", **kwargs),
             jit=False)
         return trainer, client
 
@@ -1025,11 +956,9 @@ class TestDiLoCoSetSyncEvery:
         trainer = getattr(local_sgd, cls)(
             loss_fn=loss_fn, inner_tx=optax.sgd(0.1),
             params={"w": np.zeros(2, np.float32)},
-            manager_factory=lambda load, save: Manager(
-                comm=DummyCommunicator(), load_state_dict=load,
-                state_dict=save, min_replica_size=1, rank=0,
-                world_size=1, replica_id="diloco",
-                _manager_client=client),
+            manager_factory=lambda load, save: make_manager(
+                client, load_state_dict=load, state_dict=save,
+                replica_id="diloco"),
             jit=False, **kw)
         return trainer
 

@@ -17,12 +17,12 @@ the nightly tier in tests/test_churn.py."""
 import os
 import threading
 import time
-from unittest.mock import MagicMock
 
 import numpy as np
 import pytest
 
-from test_manager import make_manager, quorum_result
+from mockplane import (FAKE_STORE_ADDR, FakeStore, boundary, make_manager,
+                       mock_client, quorum_result)
 from torchft_tpu import chaos as chaos_mod
 from torchft_tpu import checkpoint_io as cio
 from torchft_tpu import ram_ckpt
@@ -392,40 +392,14 @@ class TestRamChaos:
 # ----------------------------------------------------- Manager coupling
 
 
-class FakeStore:
-    """Dict-backed stand-in for the native StoreClient, injected via
-    the Manager's per-address store-client cache."""
-
-    def __init__(self):
-        self.kv = {}
-        self.lock = threading.Lock()
-
-    def set(self, key, value):
-        with self.lock:
-            self.kv[key] = value if isinstance(value, bytes) \
-                else str(value).encode()
-
-    def get(self, key, timeout_ms=0):
-        with self.lock:
-            if key not in self.kv:
-                raise KeyError(key)
-            return self.kv[key]
-
-
 def ram_manager(peers=1, state=None, **kw):
-    client = MagicMock()
-    client.quorum.return_value = quorum_result(store_address="fake:1")
-    client.should_commit.return_value = True
+    client = mock_client(quorum_result(store_address=FAKE_STORE_ADDR))
     st = (state if state is not None
           else {"w": np.arange(8, dtype=np.float32)})
     m = make_manager(client, use_async_quorum=False, min_replica_size=1,
                      load_state_dict=lambda s: st.update(s),
                      state_dict=lambda: st,
-                     ram_ckpt_peers=peers, **kw)
-    # Pre-seed the per-address store-client cache so healset
-    # publication/discovery against "fake:1" never dials a native
-    # client (the churn tests' injection idiom).
-    m._healset_store = ("fake:1", FakeStore())
+                     ram_ckpt_peers=peers, store=FakeStore(), **kw)
     return m, client, st
 
 
@@ -433,12 +407,6 @@ def wire_peer(m, srv, rank=1, step=1):
     fs = m._healset_store[1]
     fs.set(f"torchft/healset/{rank}", f"{step}:{srv.address()}".encode())
     return fs
-
-
-def boundary(m):
-    m.step()
-    m.allreduce({"g": np.ones(4, np.float32)}).result()
-    return m.should_commit()
 
 
 class TestManagerRamTier:

@@ -19,6 +19,7 @@ The PhasedChaos stable -> storm -> stable shrink-then-restore soak
 (the zero-flap acceptance gate) rides ``nightly``+``slow``.
 """
 
+import functools
 import threading
 import time
 from unittest.mock import MagicMock
@@ -27,12 +28,14 @@ import numpy as np
 import pytest
 
 import conftest  # noqa: F401 — repo-standard path/env setup
+import mockplane
+from mockplane import (FAKE_STORE_ADDR, FakeStore, boundary, mock_client,
+                       quorum_result)
 from torchft_tpu import chaos, fleet
-from torchft_tpu._native import QuorumResult
 from torchft_tpu.backends.host import HostCommunicator, _Ring
 from torchft_tpu.communicator import DummyCommunicator
 from torchft_tpu.data import ElasticSampler, _reports_samples
-from torchft_tpu.manager import _REBALANCE_KEY, Manager
+from torchft_tpu.manager import _REBALANCE_KEY
 
 pytestmark = pytest.mark.rebalance
 
@@ -40,74 +43,9 @@ pytestmark = pytest.mark.rebalance
 # --------------------------------------------------------------- helpers
 
 
-def quorum_result(
-    quorum_id=1,
-    recover_manager_address="manager1:1234",
-    store_address="",
-    max_step=1,
-    max_rank=0,
-    max_world_size=2,
-    replica_rank=0,
-    replica_world_size=2,
-    heal=False,
-    rebalance_table="",
-):
-    return QuorumResult(
-        quorum_id=quorum_id,
-        recover_manager_address=recover_manager_address,
-        store_address=store_address,
-        max_step=max_step,
-        max_rank=max_rank,
-        max_world_size=max_world_size,
-        replica_rank=replica_rank,
-        replica_world_size=replica_world_size,
-        heal=heal,
-        rebalance_table=rebalance_table,
-    )
-
-
-def make_manager(client, comm=None, min_replica_size=1, **kwargs):
-    return Manager(
-        comm=comm or DummyCommunicator(),
-        load_state_dict=kwargs.pop("load_state_dict", MagicMock()),
-        state_dict=kwargs.pop("state_dict", lambda: {"w": np.ones(2)}),
-        min_replica_size=min_replica_size,
-        rank=0,
-        world_size=1,
-        replica_id=kwargs.pop("replica_id", "rebaltest"),
-        rebalance=kwargs.pop("rebalance", True),
-        _manager_client=client,
-        **kwargs,
-    )
-
-
-def boundary(m, tree=None):
-    """One scripted step/allreduce/vote boundary; returns the vote."""
-    m.step()
-    m.allreduce(tree if tree is not None
-                else {"g": np.ones(4, np.float32)}).result()
-    return m.should_commit()
-
-
-class FakeStore:
-    """Dict-backed stand-in for the native StoreClient, injectable via
-    the Manager's per-address store-client cache (test_policy.py's
-    coordination harness, reused for the rebalance key)."""
-
-    def __init__(self):
-        self.kv = {}
-        self.lock = threading.Lock()
-
-    def set(self, key, value):
-        with self.lock:
-            self.kv[key] = value if isinstance(value, bytes) \
-                else str(value).encode()
-
-    def get(self, key, timeout_ms=0):
-        with self.lock:
-            if key not in self.kv:
-                raise KeyError(key)
-            return self.kv[key]
+make_manager = functools.partial(
+    mockplane.make_manager, min_replica_size=1, replica_id="rebaltest",
+    rebalance=True)
 
 
 class BrokenStore(FakeStore):
@@ -499,16 +437,13 @@ class TestManagerAdoption:
         policy-coordination harness with the rebalance key."""
         ms = []
         for rank in range(2):
-            client = MagicMock()
-            client.quorum.return_value = quorum_result(
-                store_address="fake:0", max_rank=rank,
+            client = mock_client(quorum_result(
+                store_address=FAKE_STORE_ADDR, max_rank=rank,
                 replica_rank=rank,
-                rebalance_table=decider_table if rank == 0 else "")
-            client.should_commit.return_value = True
+                rebalance_table=decider_table if rank == 0 else ""))
             m = make_manager(client,
                              comm=DummyCommunicator(world_size=2),
-                             replica_id=f"reb{rank}")
-            m._healset_store = ("fake:0", store)  # inject the fake
+                             replica_id=f"reb{rank}", store=store)
             ms.append(m)
         return ms
 
@@ -551,7 +486,7 @@ class TestManagerAdoption:
             # The follower's own hint says shrink; the coordinated read
             # is authoritative and it failed -> no adoption either way.
             ms[1]._client.quorum.return_value = quorum_result(
-                store_address="fake:0", max_rank=1, replica_rank=1,
+                store_address=FAKE_STORE_ADDR, max_rank=1, replica_rank=1,
                 rebalance_table="reb1=0.5000")
             for m in ms:
                 boundary(m)

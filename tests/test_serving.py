@@ -30,7 +30,7 @@ from unittest.mock import MagicMock
 import numpy as np
 import pytest
 
-from test_manager import make_manager, quorum_result
+from mockplane import make_manager, quorum_result
 from torchft_tpu import chaos as chaos_mod
 from torchft_tpu.chaos import ChaosSchedule, EndpointChaos
 from torchft_tpu.checkpointing import CheckpointServer, _ConnectionPool

@@ -17,8 +17,8 @@ import numpy as np
 import optax
 import pytest
 
-from test_manager import (_make_test_rings, _wired_comm, make_manager,
-                          quorum_result)
+from mockplane import make_manager, quorum_result
+from test_manager import _make_test_rings, _wired_comm
 from torchft_tpu import chaos
 from torchft_tpu.backends.host import HostCommunicator, _Ring
 from torchft_tpu.chaos import ChaosSchedule, EndpointChaos

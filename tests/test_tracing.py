@@ -10,6 +10,7 @@ whose spans attribute the abort to the fault, with
 ``scripts/tracefleet.py`` merging both groups' live ``/trace.json``
 into one timeline."""
 
+import functools
 import json
 import os
 import sys
@@ -20,44 +21,21 @@ from unittest.mock import MagicMock
 import numpy as np
 import pytest
 
+import mockplane
+from mockplane import quorum_result
 from torchft_tpu import tracing
-from torchft_tpu._native import QuorumResult
 from torchft_tpu.communicator import (CommunicatorError,
                                       DummyCommunicator)
-from torchft_tpu.manager import Manager
 
 pytestmark = pytest.mark.obs
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def quorum_result(quorum_id=1, max_step=1, replica_rank=0, max_rank=0,
-                  replica_world_size=2, max_world_size=2, heal=False,
-                  store_address=""):
-    return QuorumResult(
-        quorum_id=quorum_id, recover_manager_address="manager1:1234",
-        store_address=store_address, max_step=max_step,
-        max_rank=max_rank, max_world_size=max_world_size,
-        replica_rank=replica_rank,
-        replica_world_size=replica_world_size, heal=heal)
-
-
-def make_manager(client=None, comm=None, replica_id="obs0", **kw):
-    if client is None:
-        client = MagicMock()
-        client.quorum.return_value = quorum_result()
-        client.should_commit.return_value = True
-    return Manager(
-        comm=comm or DummyCommunicator(),
-        load_state_dict=MagicMock(),
-        state_dict=lambda: {"w": np.arange(8, dtype=np.float32)},
-        min_replica_size=1,
-        use_async_quorum=False,
-        rank=0, world_size=1,
-        replica_id=replica_id,
-        _manager_client=client,
-        **kw,
-    )
+make_manager = functools.partial(
+    mockplane.make_manager, min_replica_size=1, use_async_quorum=False,
+    replica_id="obs0",
+    state_dict=lambda: {"w": np.arange(8, dtype=np.float32)})
 
 
 # ------------------------------------------------------------- span ring

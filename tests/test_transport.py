@@ -32,14 +32,13 @@ import socket
 import threading
 import time
 from concurrent.futures import Future
-from unittest.mock import MagicMock
 
 import numpy as np
 import pytest
 
 import conftest
+from mockplane import make_manager, quorum_result
 from torchft_tpu import policy as policy_mod
-from torchft_tpu._native import QuorumResult
 from torchft_tpu.backends.host import (HostCommunicator, _HierTopo,
                                        _Ring)
 from torchft_tpu.communicator import (CommunicatorError,
@@ -47,7 +46,7 @@ from torchft_tpu.communicator import (CommunicatorError,
                                       ErrorSwallowingCommunicator,
                                       Int8Wire)
 from torchft_tpu.communicator import shard_bounds
-from torchft_tpu.manager import Manager, _device_quantize_pack
+from torchft_tpu.manager import _device_quantize_pack
 
 pytestmark = pytest.mark.transport
 
@@ -220,14 +219,6 @@ class TestDeviceQuantizePack:
 # --------------------------------------- manager-level device-quant A/B
 
 
-def quorum_result(replica_rank=0, replica_world_size=2):
-    return QuorumResult(
-        quorum_id=1, recover_manager_address="manager1:1234",
-        store_address="", max_step=1, max_rank=replica_rank,
-        max_world_size=replica_world_size, replica_rank=replica_rank,
-        replica_world_size=replica_world_size, heal=False)
-
-
 class _FoldHub:
     """Two-rank wire-op rendezvous folding RAW contributions in
     canonical rank order — the host ring's unweighted int8/wire fold
@@ -289,16 +280,13 @@ def _int8_policy():
     return next(p for p in policy_mod.LADDER if p.name == "sync-int8")
 
 
-def _make_manager(comm, rank, device_quantize):
-    client = MagicMock()
-    client.quorum.return_value = quorum_result(replica_rank=rank)
-    client.should_commit.return_value = True
-    return Manager(
-        comm=comm, load_state_dict=MagicMock(),
-        state_dict=lambda: {"w": np.ones(2)}, min_replica_size=2,
-        rank=0, world_size=1, replica_id=f"devq{rank}",
-        policy=_int8_policy(), device_quantize=device_quantize,
-        _manager_client=client)
+def _devq_manager(comm, rank, device_quantize, world=2):
+    return make_manager(
+        comm=comm, min_replica_size=world, replica_id=f"devq{rank}",
+        quorum=quorum_result(max_rank=rank, replica_rank=rank,
+                             max_world_size=world,
+                             replica_world_size=world),
+        policy=_int8_policy(), device_quantize=device_quantize)
 
 
 def _run_pair(device_quantize, steps=4, shapes=((61, 17), (3_001,))):
@@ -315,7 +303,7 @@ def _run_pair(device_quantize, steps=4, shapes=((61, 17), (3_001,))):
     errors = []
 
     def run_group(rank):
-        m = _make_manager(_FoldComm(hub, rank), rank, device_quantize)
+        m = _devq_manager(_FoldComm(hub, rank), rank, device_quantize)
         try:
             for step in range(steps):
                 rng = np.random.default_rng(100 * rank + step)
@@ -398,7 +386,7 @@ class TestManagerDeviceQuant:
         errors = []
 
         def run_group(rank):
-            m = _make_manager(_FoldComm(hub, rank), rank, True)
+            m = _devq_manager(_FoldComm(hub, rank), rank, True)
             try:
                 for step, size in enumerate((5_000, 5_000, 7_777)):
                     g = {"w": jnp.asarray(
@@ -435,7 +423,7 @@ class TestManagerDeviceQuant:
         assert seen[2] == (1, 1)
 
     def test_policy_switch_clears_device_residuals(self):
-        m = _make_manager(DummyCommunicator(), 0, True)
+        m = _devq_manager(DummyCommunicator(), 0, True)
         try:
             m._dev_residuals[("fp", 0, 0)] = np.zeros(4, np.float32)
             m._install_policy(
@@ -853,7 +841,7 @@ class TestTopologyAccessors:
         assert "hier_leader" in tracing.STAGES
 
     def test_manager_metrics_carry_hier_keys(self):
-        m = _make_manager(DummyCommunicator(), 0, True)
+        m = _devq_manager(DummyCommunicator(), 0, True)
         try:
             mx = m.metrics()
             assert mx["hier_intra_bytes_total"] == 0.0
@@ -913,20 +901,8 @@ class TestManagerHierEndToEnd:
         barrier = threading.Barrier(world)
 
         def run(rank):
-            client = MagicMock()
-            client.quorum.return_value = QuorumResult(
-                quorum_id=1, recover_manager_address="m:1",
-                store_address="", max_step=1, max_rank=rank,
-                max_world_size=world, replica_rank=rank,
-                replica_world_size=world, heal=False)
-            client.should_commit.return_value = True
-            m = Manager(
-                comm=comms[rank], load_state_dict=MagicMock(),
-                state_dict=lambda: {"w": np.ones(2)},
-                min_replica_size=world, rank=0, world_size=1,
-                replica_id=f"e2e{rank}", policy=_int8_policy(),
-                device_quantize=device_quantize,
-                _manager_client=client)
+            m = _devq_manager(comms[rank], rank, device_quantize,
+                              world=world)
             try:
                 for step in range(steps):
                     rng = np.random.default_rng(1000 * rank + step)

@@ -379,6 +379,11 @@ bool ManagerServer::handle_quorum(const ManagerQuorumRequest& r,
   quorum_rounds_.erase(quorum_rounds_.begin(),
                        quorum_rounds_.lower_bound(r.step() - 8));
   round->joined[r.rank()] = r.checkpoint_server_addr();
+  // Healers may ask at once: a peer learns of the new quorum from the
+  // lighthouse as soon as we do, and can reach kManagerCheckpointAddress
+  // before our own response is processed below. An address registered
+  // only then is "no checkpoint address for rank N" to that healer.
+  checkpoint_addrs_[r.rank()] = r.checkpoint_server_addr();
 
   if (round->done) {
     // Client retry after a lost response: idempotent replay.

@@ -1875,8 +1875,10 @@ class Manager:
     def _store_client(self, addr: str) -> Optional[Any]:
         """StoreClient for the quorum's shared store (the same store the
         ring rendezvous rides), cached per address — shared by the
-        healset advertisement and the policy decision key. None when the
-        native client is unavailable (mocked control planes)."""
+        healset advertisement and the policy decision key. None for an
+        empty address, which is what mocked control planes pass; any
+        other address is dialled, for up to ``timeout_ms`` when nothing
+        answers, and only a client that connected is cached."""
         if not addr:
             return None
         if self._healset_store is not None \
