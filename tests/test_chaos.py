@@ -227,6 +227,84 @@ class TestChaosSocket:
             peer.close()
 
 
+class TestRingSegmentShortRead:
+    """A short read inside the exact ring's per-segment fold (the path
+    the bench-smoke chaos tier peppers): the op fails, the source it
+    folded FROM is untouched, read-only or not, and the kept accumulator
+    it folded INTO is neither lent nor kept."""
+
+    @pytest.mark.parametrize("writable", [False, True],
+                             ids=["readonly", "writable"])
+    def test_short_read_mid_chunk_spares_the_source(self, writable):
+        from torchft_tpu.backends.host import HostCommunicator, _Ring
+
+        f32 = np.dtype(np.float32)
+        sched = ChaosSchedule(seed=0, intensity=0.0, endpoints={
+            "ring": EndpointChaos(short_rate=1.0, max_faults=1)})
+        pairs = [socket.socketpair() for _ in range(2)]
+        for a, b in pairs:
+            a.settimeout(10)
+            b.settimeout(10)
+        rings = [
+            _Ring(pairs[0][0], chaos.wrap_socket(pairs[1][1], "ring", sched),
+                  socket.socket()),
+            _Ring(pairs[1][0], pairs[0][1], socket.socket())]
+        comms = [HostCommunicator(timeout_sec=10) for _ in range(2)]
+        for r, c in enumerate(comms):
+            c._rank, c._world = r, 2
+
+        def grad(step, rank):
+            return np.random.default_rng([step, rank]).normal(
+                size=300_001).astype(np.float32)
+
+        srcs = [grad(1, r) for r in range(2)]
+        srcs[0].flags.writeable = writable
+
+        def peer():
+            try:
+                res = comms[1]._do_allreduce_wire(
+                    rings[1], [grad(0, 1)], [f32], "sum")
+                comms[1].release_wire_buffers(res)
+                comms[1]._do_allreduce_wire(rings[1], [srcs[1]], [f32],
+                                            "sum")
+            except Exception:  # noqa: BLE001 — rank 0's ring is reset
+                pass
+
+        t = threading.Thread(target=peer)
+        t.start()
+        c = comms[0]
+        try:
+            # A clean step leaves one accumulator kept.
+            res = c._do_allreduce_wire(rings[0], [grad(0, 0)], [f32], "sum")
+            assert res[0].tobytes() == (grad(0, 0) + grad(0, 1)).tobytes()
+            c.release_wire_buffers(res)
+            del res
+            # The storm starts after the op's handshake: the first
+            # segment of the first chunk is the read that comes short.
+            preamble = c._wire_preamble
+
+            def then_storm(*a, **kw):
+                out = preamble(*a, **kw)
+                sched.set_intensity(1.0)
+                return out
+
+            c._wire_preamble = then_storm
+            with pytest.raises(ConnectionResetError, match="short read"):
+                c._do_allreduce_wire(rings[0], [srcs[0]], [f32], "sum")
+            assert srcs[0].flags.writeable == writable
+            assert srcs[0].tobytes() == grad(1, 0).tobytes()
+            assert len(c._accum_lent) == 0
+            assert not any(c._accum_free.values())
+            assert c.accum_counters() == (0.0, 1.0, 1.0)
+        finally:
+            for ring in rings:
+                ring.close()
+            t.join(timeout=20)
+            for cc in comms:
+                cc.shutdown()
+        assert not t.is_alive()
+
+
 class TestChaosCommunicator:
     def _scripted(self, fault, phase):
         class One(ChaosSchedule):

@@ -122,10 +122,17 @@ class TestErrorSwallowing:
         assert isinstance(comm.error(), CommunicatorError)
         np.testing.assert_array_equal(out[0], wire)
 
-    def test_wire_contract_forwarded_inward(self):
+    @pytest.mark.parametrize("wrapper", ["swallowing", "managed", "chaos"])
+    def test_wire_contract_forwarded_inward(self, wrapper):
         """Wrappers must forward allreduce_wire / ring_bytes_total to the
         wrapped backend — a wrapper falling back to the ABC default would
-        silently upcast before the ring and double the wire bytes."""
+        silently upcast before the ring and double the wire bytes — and
+        the hand-back of the ring's accumulators with its counters: a
+        wrapper that swallowed those would silently put every step's
+        fold back into fresh pages."""
+        from torchft_tpu.chaos import ChaosCommunicator, ChaosSchedule
+        from torchft_tpu.communicator import ManagedCommunicator
+
         calls = {}
 
         class Inner(DummyCommunicator):
@@ -136,11 +143,30 @@ class TestErrorSwallowing:
             def ring_bytes_total(self):
                 return 123.0
 
-        comm = ErrorSwallowingCommunicator(Inner())
-        comm.allreduce_wire([np.ones(2, np.float32)],
-                            ["float32"]).result(timeout=5)
+            def release_wire_buffers(self, buffers):
+                calls["released"] = buffers
+
+            def accum_counters(self):
+                return (1.0, 2.0, 3.0)
+
+        comm = {
+            "swallowing": ErrorSwallowingCommunicator,
+            "managed": lambda c: ManagedCommunicator(_StubManager(c)),
+            "chaos": lambda c: ChaosCommunicator(
+                c, ChaosSchedule(seed=0, endpoints={})),
+        }[wrapper](Inner())
+        out = comm.allreduce_wire([np.ones(2, np.float32)],
+                                  ["float32"]).result(timeout=5)
         assert calls["wire"] == (1, ["float32"])
         assert comm.ring_bytes_total() == 123.0
+        comm.release_wire_buffers(out)
+        assert calls["released"] is out
+        comm.release_wire_buffers(None)
+        assert calls["released"] is None
+        assert comm.accum_counters() == (1.0, 2.0, 3.0)
+        # a backend that keeps nothing: the defaults
+        assert DummyCommunicator().accum_counters() == (0.0, 0.0, 0.0)
+        assert DummyCommunicator().release_wire_buffers(out) is None
 
 
 def _run_ranks(world_size, fn):
@@ -353,6 +379,57 @@ class TestHostCommunicator:
 
         for out in _run_ranks(2, run2):
             np.testing.assert_allclose(out["g"], np.full(4, 2.0))
+        for c in comms:
+            c.shutdown()
+
+    def test_reconfigure_drops_kept_accumulators(self, store):
+        """2 -> 3 -> 2 ranks: every configure starts with nothing kept,
+        a result from before it is not taken back, and each world's sums
+        are bitwise the exact ring's."""
+        from torchft_tpu.backends.host import _fold_exact_ring_order
+
+        addr = store.address()
+        comms = [HostCommunicator(timeout_sec=30) for _ in range(3)]
+        stale = [None] * 3
+        f32 = np.dtype(np.float32)
+
+        def x(epoch, step, rank):
+            a = np.random.default_rng([epoch, step, rank]).normal(
+                size=10_007).astype(np.float32)
+            a.flags.writeable = False
+            return a
+
+        def epoch(e, world):
+            def go(rank):
+                c = comms[rank]
+                c.configure(f"{addr}/acc{e}", rank, world)
+                assert not c._accum_free and not len(c._accum_lent)
+                if stale[rank] is not None:
+                    c.release_wire_buffers(stale[rank])
+                    assert not c._accum_free
+                outs = []
+                for step in range(2):
+                    res = c.allreduce_wire(
+                        [x(e, step, rank)], ["float32"]).result(timeout=30)
+                    outs.append(res[0].copy())
+                    if step == 0:
+                        c.release_wire_buffers(res)
+                    else:
+                        stale[rank] = res
+                return outs, c.accum_counters()
+
+            for rank, (outs, counters) in enumerate(_run_ranks(world, go)):
+                for step in range(2):
+                    want = _fold_exact_ring_order(
+                        [x(e, step, q) for q in range(world)], f32, world)
+                    assert outs[step].tobytes() == want.tobytes()
+                # an allocation and a reuse for every epoch this rank saw
+                seen = e + 1 if rank < 2 else 1
+                assert counters == (0.0, float(seen), float(seen))
+
+        epoch(0, 2)
+        epoch(1, 3)
+        epoch(2, 2)
         for c in comms:
             c.shutdown()
 
@@ -877,3 +954,213 @@ class TestWireRingTransport:
             np.testing.assert_array_equal(o[2], ints * 3)     # int exact
         for c in comms:
             c.shutdown()
+
+
+def _inplace_ring_oracle(ring, rank, world, acc):
+    """The exact ring as it was while it reduced in place (the parent of
+    the out-of-place fold: reduce-scatter ``acc[c] += recv`` per segment,
+    then allgather into ``acc``), kept here as the reference the fold
+    must equal bit for bit. Reduces ``acc`` (writable, this rank's own
+    copy) in place and returns it."""
+    from torchft_tpu.backends.host import (_SEG_BYTES, _as_bytes,
+                                           _recv_exact_into)
+    from torchft_tpu.communicator import shard_bounds
+
+    acc_bytes = _as_bytes(acc)
+    bounds = shard_bounds(acc.size, world)
+    itemsize = acc.itemsize
+
+    def chunk(i):
+        i %= world
+        return acc[bounds[i]:bounds[i + 1]]
+
+    def chunk_bytes(i):
+        i %= world
+        return acc_bytes[bounds[i] * itemsize:bounds[i + 1] * itemsize]
+
+    scratch = memoryview(bytearray(_SEG_BYTES))
+    for step in range(world - 1):
+        fut = ring.send_async(chunk_bytes(rank - step))
+        recv_c = chunk(rank - step - 1)
+        nbytes = recv_c.size * itemsize
+        off = 0
+        while off < nbytes:
+            k = min(_SEG_BYTES, nbytes - off)
+            seg = scratch[:k]
+            _recv_exact_into(ring.prev_sock, seg)
+            lo = off // itemsize
+            recv_c[lo:lo + k // itemsize] += np.frombuffer(
+                seg, dtype=acc.dtype)
+            off += k
+        fut.result()
+    for step in range(world - 1):
+        fut = ring.send_async(chunk_bytes(rank + 1 - step))
+        _recv_exact_into(ring.prev_sock, chunk_bytes(rank - step))
+        fut.result()
+    return acc
+
+
+def _contribution(seed, rank, size, writable=True):
+    a = np.random.default_rng([seed, rank]).normal(size=size).astype(
+        np.float32)
+    a.flags.writeable = writable
+    return a
+
+
+_F32 = np.dtype(np.float32)
+
+
+class TestOutOfPlaceExactRing:
+    """The exact f32 ring folds OUT OF PLACE, from a source it never
+    writes (``jax.device_get`` hands the Manager read-only arrays) into
+    an accumulator that lives across steps once the caller hands it back
+    (``release_wire_buffers``). Same sockets, sender thread and segment
+    loop as the wire-ring tests above."""
+
+    _run = TestWireRingTransport._run
+
+    # 3: smaller than the world of four (empty chunks); 10_007: a chunk
+    # under one 256 KB segment, not divisible; 300_001: several segments
+    # a chunk with a ragged last one.
+    @pytest.mark.parametrize("writable", [False, True],
+                             ids=["readonly", "writable"])
+    @pytest.mark.parametrize("size", [3, 10_007, 300_001])
+    @pytest.mark.parametrize("world", [2, 3, 4])
+    def test_fold_equals_the_in_place_ring(self, world, size, writable):
+        want, _ = self._run(world, lambda c, ring, r: _inplace_ring_oracle(
+            ring, r, world, _contribution(7, r, size)))
+        srcs = [_contribution(7, r, size, writable) for r in range(world)]
+        got, comms = self._run(world, lambda c, ring, r: c._do_allreduce_wire(
+            ring, [srcs[r]], [_F32], "sum"))
+        for r in range(world):
+            assert got[r][0].tobytes() == want[r].tobytes()
+            assert got[r][0].tobytes() == got[0][0].tobytes()
+            # the source is never written, read-only or not
+            assert srcs[r].flags.writeable == writable
+            assert srcs[r].tobytes() == _contribution(7, r, size).tobytes()
+            assert not np.shares_memory(got[r][0], srcs[r])
+            # (bytes copied, accumulators reused, accumulators allocated)
+            assert comms[r].accum_counters() == (0.0, 0.0, 1.0)
+            comms[r].shutdown()
+
+    @pytest.mark.parametrize("world", [2, 3, 4])
+    def test_second_step_allocates_nothing(self, world):
+        from torchft_tpu.backends.host import _fold_exact_ring_order
+
+        sizes = [10_007, 70_001, 10_007]   # two chunks share a key
+        xs = [[[_contribution(step, r, n, False) for n in sizes]
+               for r in range(world)] for step in range(3)]
+
+        def fn(c, ring, r):
+            seen, outs = [], []
+            for step in range(3):
+                res = c._do_allreduce_wire(ring, xs[step][r],
+                                           [_F32] * len(sizes), "sum")
+                outs.append([a.copy() for a in res])
+                seen.append((sorted(map(id, res)), c.accum_counters()))
+                c.release_wire_buffers(res)
+                del res
+            return seen, outs
+
+        out, comms = self._run(world, fn)
+        for r in range(world):
+            seen, outs = out[r]
+            assert [s[1] for s in seen] == [
+                (0.0, 0.0, 3.0), (0.0, 3.0, 3.0), (0.0, 6.0, 3.0)]
+            assert seen[0][0] == seen[1][0] == seen[2][0]  # the same memory
+            for step in range(3):
+                for k in range(len(sizes)):
+                    want = _fold_exact_ring_order(
+                        [xs[step][q][k] for q in range(world)], _F32, world)
+                    assert outs[step][k].tobytes() == want.tobytes()
+            comms[r].shutdown()
+
+    def test_result_is_not_overwritten_while_the_caller_holds_it(self):
+        """The put stage reads a result after the op resolved (an H2D
+        transfer). Until it hands the buffer back, the next step's ring
+        must fold somewhere else."""
+        xs = [[_contribution(step, r, 50_003, False) for r in range(2)]
+              for step in range(3)]
+
+        def fn(c, ring, r):
+            held = c._do_allreduce_wire(ring, [xs[0][r]], [_F32], "sum")
+            before = held[0].tobytes()
+            nxt = c._do_allreduce_wire(ring, [xs[1][r]], [_F32], "sum")
+            assert nxt[0] is not held[0]
+            assert held[0].tobytes() == before == \
+                (xs[0][0] + xs[0][1]).tobytes()
+            assert c.accum_counters() == (0.0, 0.0, 2.0)
+            # both back: the third step reuses one of them
+            c.release_wire_buffers(held)
+            c.release_wire_buffers(nxt)
+            c.release_wire_buffers(nxt)            # twice is once
+            c.release_wire_buffers([xs[2][r], np.zeros(50_003, np.float32)])
+            third = c._do_allreduce_wire(ring, [xs[2][r]], [_F32], "sum")
+            assert third[0] is held[0] or third[0] is nxt[0]
+            assert c.accum_counters() == (0.0, 1.0, 2.0)
+            assert sum(map(len, c._accum_free.values())) == 1
+            return third[0]
+
+        out, comms = self._run(2, fn)
+        for o in out:
+            assert o.tobytes() == (xs[2][0] + xs[2][1]).tobytes()
+        for c in comms:
+            c.shutdown()
+            assert not c._accum_free and not len(c._accum_lent)
+
+    def test_aborted_op_leaves_nothing_marked(self):
+        """A peer that closes mid-ring fails the op; its accumulator is
+        neither lent nor kept, and the source is untouched."""
+        x = _contribution(0, 0, 300_001, False)
+        y = _contribution(1, 0, 300_001, False)
+
+        def fn(c, ring, r):
+            first = c._do_allreduce_wire(ring, [x], [_F32], "sum")
+            c.release_wire_buffers(first)
+            del first
+            if r == 1:
+                # the handshake passes, then the peer is gone mid-ring
+                c._wire_preamble(ring, "ar", [y], [_F32])
+                ring.close()
+                return None
+            with pytest.raises((CommunicatorError, OSError)):
+                c._do_allreduce_wire(ring, [y], [_F32], "sum")
+            return (len(c._accum_lent), dict(c._accum_free),
+                    c.accum_counters())
+
+        out, comms = self._run(2, fn)
+        lent, free, counters = out[0]
+        assert lent == 0 and not any(free.values())
+        assert counters == (0.0, 1.0, 1.0)   # it did take the kept one
+        assert y.tobytes() == _contribution(1, 0, 300_001).tobytes()
+        for c in comms:
+            c.shutdown()
+
+    def test_non_contiguous_source_is_the_one_counted_copy(self):
+        base = [_contribution(3, r, 20_000) for r in range(2)]
+        out, comms = self._run(2, lambda c, ring, r: c._do_allreduce_wire(
+            ring, [base[r][::2]], [_F32], "sum"))
+        for r in range(2):
+            assert out[r][0].tobytes() == \
+                (base[0][::2] + base[1][::2]).tobytes()
+            assert comms[r].accum_counters() == (40_000.0, 0.0, 1.0)
+            comms[r].shutdown()
+
+    @pytest.mark.parametrize("world", [2, 3])
+    def test_reduce_scatter_keeps_its_accumulator_inside(self, world):
+        from torchft_tpu.backends.host import _fold_exact_ring_order
+
+        xs = [_contribution(5, r, 10_007, False) for r in range(world)]
+
+        def fn(c, ring, r):
+            a = c._do_reduce_scatter_wire(ring, [xs[r]], [_F32], "sum")
+            b = c._do_reduce_scatter_wire(ring, [xs[r]], [_F32], "sum")
+            return a[0], b[0]
+
+        out, comms = self._run(world, fn)
+        for r in range(world):
+            want = _fold_exact_ring_order(xs, _F32, world, stripe=r)
+            assert out[r][0].tobytes() == out[r][1].tobytes() == \
+                want.tobytes()
+            assert comms[r].accum_counters() == (0.0, 1.0, 1.0)
+            comms[r].shutdown()

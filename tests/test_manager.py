@@ -964,12 +964,15 @@ class TestWireRingPipelined:
     transport, mocked control plane): the tier-1 spelling of the
     numerics guarantees that don't need the native store."""
 
-    def _run(self, world, tree_fn, **mkw):
+    def _run_steps(self, world, trees, **mkw):
+        """One Manager a rank over pre-wired rings; ``trees[step](rank)``
+        is each step's gradient tree. Returns, per rank and step, the
+        result (kept alive to the end) and ``Manager.metrics()``."""
         import threading as _t
 
         rings = _make_test_rings(world)
-        results = [None] * world
-        metrics = [None] * world
+        results = [[] for _ in range(world)]
+        metrics = [[] for _ in range(world)]
         errors = []
 
         def run(rank):
@@ -982,13 +985,14 @@ class TestWireRingPipelined:
                              comm=_wired_comm(rings[rank], rank, world),
                              min_replica_size=world, **mkw)
             try:
-                m.step()
-                results[rank] = m.allreduce(tree_fn(rank)).result(
-                    timeout=30)
-                err = m.errored()
-                assert err is None, err
-                assert m.should_commit()
-                metrics[rank] = m.metrics()
+                for tree_fn in trees:
+                    m.step()
+                    results[rank].append(
+                        m.allreduce(tree_fn(rank)).result(timeout=30))
+                    err = m.errored()
+                    assert err is None, err
+                    assert m.should_commit()
+                    metrics[rank].append(m.metrics())
             except Exception as e:  # noqa: BLE001
                 errors.append(e)
             finally:
@@ -998,13 +1002,17 @@ class TestWireRingPipelined:
         for t in threads:
             t.start()
         for t in threads:
-            t.join(timeout=60)
+            t.join(timeout=90)
         alive = [t for t in threads if t.is_alive()]
         for r in rings:
             r.close()
         assert not alive, "pipelined allreduce deadlocked"
         assert not errors, errors
         return results, metrics
+
+    def _run(self, world, tree_fn, **mkw):
+        results, metrics = self._run_steps(world, [tree_fn], **mkw)
+        return [r[0] for r in results], [m[0] for m in metrics]
 
     BASE = {
         "a": np.random.default_rng(0).normal(size=(257, 3)).astype(
@@ -1157,3 +1165,94 @@ class TestWireRingPipelined:
                                       self.BASE["b"])
         np.testing.assert_array_equal(np.asarray(results[1]["g"]),
                                       self.BASE["b"])
+
+    # ---- the ring's accumulators live across steps (release after put)
+
+    STEP_KEYS = ("allreduce_host_copy_bytes_total",
+                 "allreduce_accum_reuse_total",
+                 "allreduce_accum_alloc_total")
+
+    def _accum_steps(self, world, trees, **mkw):
+        """:meth:`_run_steps`, with each step's metrics cut to
+        ``STEP_KEYS``."""
+        results, metrics = self._run_steps(world, trees, **mkw)
+        return results, [[tuple(mx[k] for k in self.STEP_KEYS)
+                          for mx in steps] for steps in metrics]
+
+    @pytest.mark.parametrize("world", [2, 3])
+    def test_steady_steps_reuse_and_keep_earlier_results(self, world):
+        """Device leaves, four buckets a step. From the second step of a
+        signature on nothing is allocated and nothing copied; every
+        step's result, held while later steps fold into the same
+        accumulators, stays bitwise what the exact ring gives."""
+        import jax.numpy as jnp
+        from torchft_tpu.backends.host import _fold_exact_ring_order
+
+        # distinct sizes: two chunks of one size may share a buffer
+        # within a step, when the first's put ends before the second's
+        # ring begins
+        shapes = {"a": (257, 3), "b": (1000,), "c": (1001,), "d": (40, 9)}
+
+        def host(step, rank):
+            return {k: np.random.default_rng([step, rank, i]).normal(
+                size=s).astype(np.float32)
+                for i, (k, s) in enumerate(shapes.items())}
+
+        def tree(step):
+            return lambda rank: jax.tree_util.tree_map(
+                jnp.asarray, host(step, rank))
+
+        n_steps = 4
+        results, counters = self._accum_steps(
+            world, [tree(s) for s in range(n_steps)],
+            allreduce_bucket_bytes=1024)
+        ops = len(shapes)  # a bucket a leaf at this bucket size
+        for rank in range(world):
+            assert counters[rank] == [
+                (0.0, float(ops * s), float(ops)) for s in range(n_steps)]
+            for step in range(n_steps):
+                for k, shape in shapes.items():
+                    want = _fold_exact_ring_order(
+                        [host(step, q)[k].ravel() for q in range(world)],
+                        np.dtype(np.float32), world)
+                    want = (want / world).astype(np.float32).reshape(shape)
+                    np.testing.assert_array_equal(
+                        np.asarray(results[rank][step][k]), want)
+
+    def test_changed_gradient_signature_drops_the_buffers(self):
+        """A, A, B, B, A: each change of signature starts from nothing
+        kept (its chunks have other sizes), and stays bitwise right."""
+        import jax.numpy as jnp
+
+        def tree(step, n):
+            def fn(rank):
+                return {"g": jnp.asarray(np.random.default_rng(
+                    [step, rank]).normal(size=n).astype(np.float32))}
+            return fn
+
+        sizes = [5000, 5000, 7001, 7001, 5000]
+        results, counters = self._accum_steps(
+            2, [tree(s, n) for s, n in enumerate(sizes)])
+        for rank in range(2):
+            assert counters[rank] == [(0.0, 0.0, 1.0), (0.0, 1.0, 1.0),
+                                      (0.0, 1.0, 2.0), (0.0, 2.0, 2.0),
+                                      (0.0, 2.0, 3.0)]
+            for step, n in enumerate(sizes):
+                g = [np.random.default_rng([step, q]).normal(
+                    size=n).astype(np.float32) for q in range(2)]
+                np.testing.assert_array_equal(
+                    np.asarray(results[rank][step]["g"]),
+                    ((g[0] + g[1]) / 2).astype(np.float32))
+
+    def test_host_leaves_count_their_assembly_copy(self):
+        """A chunk of host-native leaves is assembled into one ring
+        buffer on the host: the one host-to-host copy this path still
+        makes, counted in bytes."""
+        def tree(rank):
+            return {"h": np.full(3000, rank + 1.0, np.float32)}
+
+        results, counters = self._accum_steps(2, [tree, tree])
+        for rank in range(2):
+            assert [c[0] for c in counters[rank]] == [12_000.0, 24_000.0]
+            np.testing.assert_array_equal(
+                results[rank][1]["h"], np.full(3000, 1.5, np.float32))

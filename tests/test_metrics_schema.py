@@ -51,6 +51,13 @@ DOCUMENTED_KEYS = frozenset([
     # (docs/design/hier_transport.md)
     "allreduce_d2h_wire_bytes_total",
     "hier_intra_bytes_total", "hier_leader",
+    # the exact ring's accumulators across steps
+    # (docs/design/allreduce_pipeline.md): bytes copied host-to-host
+    # between the fetch and the ring [bytes]; accumulators taken from
+    # the kept set / freshly allocated [count, one per exact chunk of a
+    # wire op]
+    "allreduce_host_copy_bytes_total",
+    "allreduce_accum_reuse_total", "allreduce_accum_alloc_total",
     # cross-step overlap engine
     "allreduce_hidden_ms_total", "allreduce_drain_wait_ms_total",
     "allreduce_inflight", "overlap_steps_deferred",

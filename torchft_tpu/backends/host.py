@@ -29,6 +29,7 @@ import socket
 import struct
 import threading
 import time
+import weakref
 from concurrent.futures import Future
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -149,9 +150,10 @@ class _HierTopo:
 
 
 def _as_bytes(arr: np.ndarray) -> memoryview:
-    """Writable byte view of a contiguous array. Routed through a uint8
-    view because numpy's buffer protocol rejects custom dtypes (ml_dtypes
-    bfloat16 — exactly the wire dtype this transport exists to carry)."""
+    """Byte view of a contiguous array, writable where the array is (the
+    ring's source need not be). Routed through a uint8 view because
+    numpy's buffer protocol rejects custom dtypes (ml_dtypes bfloat16 —
+    exactly the wire dtype this transport exists to carry)."""
     return memoryview(arr.view(np.uint8)).cast("B")
 
 
@@ -266,6 +268,16 @@ class HostCommunicator(Communicator):
         # on its own (Manager surfaces it as
         # allreduce_int8_ring_bytes_total).
         self._ring_bytes_int8 = 0.0
+        # Exact-ring accumulators that live across steps (_take_accum):
+        # `free` holds what callers handed back, by (dtype, size); `lent`
+        # knows, weakly, the results an op resolved to, so only those
+        # come back. Both under _lock; the counters are the op worker's.
+        self._accum_free: Dict[Tuple[str, int], List[np.ndarray]] = {}
+        self._accum_lent: "weakref.WeakValueDictionary[int, np.ndarray]" \
+            = weakref.WeakValueDictionary()
+        self._accum_reuse = 0.0
+        self._accum_alloc = 0.0
+        self._host_copy_bytes = 0.0
         self._epoch = 0
         self._lock = threading.Lock()
         self._ops: "queue.Queue[Optional[Tuple]]" = queue.Queue()
@@ -321,6 +333,7 @@ class HostCommunicator(Communicator):
             old_hier, self._hier = self._hier, None
             self._epoch += 1
             epoch = self._epoch
+            self._drop_accums()
         if old is not None:
             old.close()
         if old_hier is not None:
@@ -843,18 +856,18 @@ class HostCommunicator(Communicator):
         out: List[Optional[np.ndarray]] = [None] * len(arrs)
         for dtype_str, idxs in by_dtype.items():
             if (len(idxs) == 1 and arrs[idxs[0]].ndim == 1
-                    and arrs[idxs[0]].flags.c_contiguous
-                    and arrs[idxs[0]].flags.writeable):
-                # A single already-contiguous 1-D leaf IS the ring
-                # buffer: skip the redundant np.concatenate memcpy (the
-                # shape every packed-chunk caller hits) and reduce in
-                # place — allowed by the Communicator.allreduce
-                # ownership contract (such leaves are consumed).
+                    and arrs[idxs[0]].flags.c_contiguous):
+                # A single already-contiguous 1-D leaf IS the ring's
+                # source: no np.concatenate memcpy (the shape every
+                # packed-chunk caller hits). The fold reads it and
+                # writes an accumulator of its own.
                 flat = arrs[idxs[0]]
+                acc = self._take_accum(flat)
             else:
-                flat = np.concatenate(
+                # The concat is this op's own scratch: fold in place.
+                flat = acc = np.concatenate(
                     [arrs[i].reshape(-1) for i in idxs])
-            reduced = self._ring_allreduce_buffer(ring, flat)
+            reduced = self._ring_allreduce_buffer(ring, flat, acc)
             if op == "mean":
                 if np.issubdtype(reduced.dtype, np.inexact):
                     reduced /= self._world
@@ -867,9 +880,67 @@ class HostCommunicator(Communicator):
                 pos += n
         return jax.tree_util.tree_unflatten(treedef, out)
 
-    def _ring_allreduce_buffer(self, ring: _Ring,
-                               flat: np.ndarray) -> np.ndarray:
-        """Bandwidth-optimal ring allreduce: reduce-scatter + allgather.
+    def _take_accum(self, like: np.ndarray) -> np.ndarray:
+        """An accumulator for one exact-ring op over a buffer of
+        ``like``'s dtype and size: one that a caller handed back
+        (:meth:`release_wire_buffers`) if there is one, else a fresh
+        ``np.empty``. The ring writes every element before it reads it,
+        so the contents do not matter. A buffer is in one place at a
+        time: kept here, inside an op, or with the caller as a result;
+        the ring can therefore never write memory a caller still reads.
+        Above glibc's mmap threshold a fresh buffer is never-touched
+        pages, a fault a page at the ring's pace: reuse is what takes
+        the first touch of gradient-sized memory off the op worker."""
+        with self._lock:
+            free = self._accum_free.get((like.dtype.str, like.size))
+            acc = free.pop() if free else None
+        if acc is None:
+            self._accum_alloc += 1
+            return np.empty(like.size, like.dtype)
+        self._accum_reuse += 1
+        return acc
+
+    def _drop_accums(self) -> None:
+        """Forget every kept accumulator (caller holds ``_lock``)."""
+        self._accum_free.clear()
+        self._accum_lent.clear()
+
+    def _keep_accum(self, acc: np.ndarray) -> None:
+        """Keep ``acc`` for a later op (caller holds ``_lock``)."""
+        self._accum_free.setdefault(
+            (acc.dtype.str, acc.size), []).append(acc)
+
+    def release_wire_buffers(self, buffers: Optional[Sequence[Any]]
+                             ) -> None:
+        with self._lock:
+            if buffers is None:
+                self._drop_accums()
+                return
+            for b in buffers:
+                # Only what an exact-ring op of this epoch resolved to:
+                # anything else (a wire or int8 path's fresh array, a
+                # result from before a reconfigure) is the caller's.
+                if self._accum_lent.pop(id(b), None) is b:
+                    self._keep_accum(b)
+
+    def accum_counters(self) -> Tuple[float, float, float]:
+        return (self._host_copy_bytes, self._accum_reuse,
+                self._accum_alloc)
+
+    def _ring_source(self, buf: Any) -> np.ndarray:
+        """One wire buffer as the contiguous 1-D array the ring reads
+        (read-only or not: the ring never writes its source). A view,
+        unless ``buf`` was not C-contiguous: that copy is counted."""
+        a = np.asarray(buf)
+        flat = np.ravel(a)
+        if not np.may_share_memory(flat, a):
+            self._host_copy_bytes += flat.nbytes
+        return flat
+
+    def _ring_allreduce_buffer(self, ring: _Ring, src: np.ndarray,
+                               acc: np.ndarray) -> np.ndarray:
+        """Bandwidth-optimal ring allreduce: reduce-scatter + allgather,
+        from ``src`` into ``acc`` (:meth:`_ring_reduce_scatter_phase`).
 
         Each ring step is fully pipelined: the outbound chunk streams from
         the persistent sender thread while this thread receives the inbound
@@ -882,7 +953,7 @@ class HostCommunicator(Communicator):
         """
         n = self._world
         rank = self._rank
-        acc, chunk_bytes = self._ring_reduce_scatter_phase(ring, flat)
+        chunk_bytes = self._ring_reduce_scatter_phase(ring, src, acc)
         for step in range(n - 1):
             send_view = chunk_bytes(rank + 1 - step)
             self._ring_bytes += len(send_view)
@@ -891,59 +962,67 @@ class HostCommunicator(Communicator):
             fut.result()
         return acc
 
-    def _ring_reduce_scatter_phase(self, ring: _Ring, flat: np.ndarray):
+    def _ring_reduce_scatter_phase(self, ring: _Ring, src: np.ndarray,
+                                   acc: np.ndarray):
         """The reduce-scatter half of the exact ring, factored out so the
         reduce-scatter collective can reuse it UNCHANGED — identical fold
         order is what makes the reduce-scatter path's stripes bitwise
         equal to the allreduce path's. After the phase, this rank's chunk
         ``(rank + 1) % world`` of ``acc`` holds its fully-reduced values.
-        Returns ``(acc, chunk_bytes)`` where ``chunk_bytes(i)`` is the
-        byte view of canonical chunk ``i % world``."""
+        Returns ``chunk_bytes``, where ``chunk_bytes(i)`` is the byte view
+        of canonical chunk ``i % world`` of ``acc``.
+
+        The fold is OUT OF PLACE: ``src`` (contiguous, 1-D) is this
+        rank's contribution and is only read, so it may be read-only
+        (what ``jax.device_get`` returns); ``acc`` (same dtype and size)
+        needs no contents. Every chunk but ``rank`` is written exactly
+        once, as ``src chunk + received partial sum`` — the operands and
+        order of an in-place ``acc[c] += recv`` on a copy of ``src``, so
+        the sums are bitwise those — and chunk ``rank`` is only sent
+        (from ``src``) until the allgather, or the reduce-scatter's
+        shift hop, overwrites it. ``acc`` may be ``src`` itself where
+        the caller owns it (a fresh concat or upcast): the same loop then
+        folds in place."""
         n = self._world
         rank = self._rank
-        # Reduces in place: `flat` is either a fresh per-dtype concat or
-        # a caller-owned packed chunk (consumed per the allreduce
-        # ownership contract), so no defensive copy on the hot path.
-        acc = flat if flat.flags.c_contiguous else np.ascontiguousarray(flat)
-        acc_bytes = _as_bytes(acc)
+        src_bytes, acc_bytes = _as_bytes(src), _as_bytes(acc)
         bounds = shard_bounds(acc.size, n)
         itemsize = acc.itemsize
 
-        def chunk(i: int) -> np.ndarray:
+        def chunk_bytes(i: int, of: memoryview = acc_bytes) -> memoryview:
             i %= n
-            return acc[bounds[i]:bounds[i + 1]]
-
-        def chunk_bytes(i: int) -> memoryview:
-            i %= n
-            return acc_bytes[bounds[i] * itemsize:bounds[i + 1] * itemsize]
+            return of[bounds[i] * itemsize:bounds[i + 1] * itemsize]
 
         # Scratch for inbound reduce segments, reused across steps.
         scratch = bytearray(_SEG_BYTES)
         scratch_view = memoryview(scratch)
 
         for step in range(n - 1):
-            # Chunks of the contiguous 1-D accumulator are contiguous
-            # views: the sender streams directly from acc (the chunk being
-            # sent is never the one being reduced this step).
-            send_view = chunk_bytes(rank - step)
+            # The chunk being sent is never the one folded this step:
+            # the first goes out from the source as it stands, each
+            # later one is the chunk folded into acc the step before.
+            send_view = chunk_bytes(rank - step,
+                                    acc_bytes if step else src_bytes)
             self._ring_bytes += len(send_view)
             fut = ring.send_async(send_view)
-            recv_c = chunk(rank - step - 1)
-            nbytes = recv_c.size * itemsize
+            c = (rank - step - 1) % n
+            mine = src[bounds[c]:bounds[c + 1]]
+            out = acc[bounds[c]:bounds[c + 1]]
+            nbytes = out.size * itemsize
             off = 0
             while off < nbytes:
                 k = min(_SEG_BYTES, nbytes - off)
                 seg = scratch_view[:k]
                 _recv_exact_into(ring.prev_sock, seg)
-                lo = off // itemsize
-                recv_c[lo:lo + k // itemsize] += np.frombuffer(
-                    seg, dtype=acc.dtype)
+                lo, hi = off // itemsize, (off + k) // itemsize
+                np.add(mine[lo:hi], np.frombuffer(seg, dtype=acc.dtype),
+                       out=out[lo:hi])
                 off += k
             fut.result()
-        return acc, chunk_bytes
+        return chunk_bytes
 
     def _ring_reduce_scatter_buffer(self, ring: _Ring,
-                                    flat: np.ndarray) -> np.ndarray:
+                                    src: np.ndarray) -> np.ndarray:
         """Exact reduce-scatter: the ring's reduce-scatter phase plus ONE
         ownership-shift hop, so rank ``r`` returns canonical stripe ``r``
         (the :func:`~torchft_tpu.communicator.shard_bounds` segment) —
@@ -955,7 +1034,8 @@ class HostCommunicator(Communicator):
         at world 2, →half as n grows; the real 1/n win here is fold
         compute and the optimizer stage that follows."""
         n, rank = self._world, self._rank
-        acc, chunk_bytes = self._ring_reduce_scatter_phase(ring, flat)
+        acc = self._take_accum(src)
+        chunk_bytes = self._ring_reduce_scatter_phase(ring, src, acc)
         # After the phase rank r owns chunk (r+1); one hop moves each
         # owned chunk to its canonical rank: prev owns exactly chunk
         # `rank`, so receive it straight into place while streaming our
@@ -966,7 +1046,11 @@ class HostCommunicator(Communicator):
         _recv_exact_into(ring.prev_sock, chunk_bytes(rank))
         fut.result()
         bounds = shard_bounds(acc.size, n)
-        return np.array(acc[bounds[rank]:bounds[rank + 1]])
+        stripe = np.array(acc[bounds[rank]:bounds[rank + 1]])
+        # The accumulator never left this op: keep it for the next.
+        with self._lock:
+            self._keep_accum(acc)
+        return stripe
 
     @staticmethod
     def _wire_desc_key(op: str, buffers: List[Any],
@@ -1087,6 +1171,7 @@ class HostCommunicator(Communicator):
                                                    weights)
                 for buf, orig in zip(buffers, origs)]
         out: List[np.ndarray] = []
+        lent: List[np.ndarray] = []
         for buf, orig in zip(buffers, origs):
             if isinstance(buf, Int8Wire):
                 reduced = self._ring_allreduce_int8(ring, buf, orig)
@@ -1094,18 +1179,15 @@ class HostCommunicator(Communicator):
                     reduced /= self._world
                 out.append(reduced)
                 continue
-            a = np.ravel(np.asarray(buf))
-            if not a.flags.c_contiguous:
-                a = np.ascontiguousarray(a)
+            a = self._ring_source(buf)
             if a.dtype == orig:
-                if not a.flags.writeable:
-                    # device_get can hand back a read-only view of the
-                    # transfer buffer; the exact ring accumulates in
-                    # place, so that one case pays a copy. (The wire
-                    # path below only ever READS its buffer.)
-                    a = np.array(a)
-                # Uncompressed chunk: the standard in-place exact ring.
-                reduced = self._ring_allreduce_buffer(ring, a)
+                # Uncompressed chunk: the exact ring, folded out of
+                # place. device_get's arrays are read-only, and nothing
+                # here writes `a`; the accumulator is one the caller
+                # handed back after an earlier step, where there is one.
+                reduced = self._ring_allreduce_buffer(
+                    ring, a, self._take_accum(a))
+                lent.append(reduced)
             else:
                 reduced = self._ring_allreduce_wire(ring, a, orig)
             if op == "mean":
@@ -1114,6 +1196,12 @@ class HostCommunicator(Communicator):
                 else:
                     reduced //= self._world
             out.append(reduced)
+        # Lent only now that the whole op has its result: an op that
+        # failed mid-ring leaves nothing marked, its accumulators are
+        # garbage like any other local.
+        with self._lock:
+            for r in lent:
+                self._accum_lent[id(r)] = r
         return out
 
     def _ring_allreduce_wire(self, ring: _Ring, wire_buf: np.ndarray,
@@ -1143,7 +1231,8 @@ class HostCommunicator(Communicator):
         n, rank = self._world, self._rank
         wdt = wire_buf.dtype
         if n * wdt.itemsize > 2 * orig.itemsize:
-            return self._ring_allreduce_buffer(ring, wire_buf.astype(orig))
+            up = wire_buf.astype(orig)  # this op's own: fold in place
+            return self._ring_allreduce_buffer(ring, up, up)
         size = wire_buf.size
         nbytes = size * wdt.itemsize
         send_view = _as_bytes(np.ascontiguousarray(wire_buf))
@@ -1397,12 +1486,8 @@ class HostCommunicator(Communicator):
                     shard /= self._world
                 out.append(shard)
                 continue
-            a = np.ravel(np.asarray(buf))
-            if not a.flags.c_contiguous:
-                a = np.ascontiguousarray(a)
+            a = self._ring_source(buf)
             if a.dtype == orig:
-                if not a.flags.writeable:
-                    a = np.array(a)  # exact phase reduces in place
                 shard = self._ring_reduce_scatter_buffer(ring, a)
             else:
                 shard = self._ring_reduce_scatter_wire(ring, a, orig)
@@ -1855,6 +1940,7 @@ class HostCommunicator(Communicator):
         with self._lock:
             ring, self._ring = self._ring, None
             topo, self._hier = self._hier, None
+            self._drop_accums()
         if ring is not None:
             ring.close()
         if topo is not None:

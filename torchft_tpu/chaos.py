@@ -1141,6 +1141,12 @@ class ChaosCommunicator(Communicator):
     def set_wire_weight(self, weight: int) -> None:
         self._comm.set_wire_weight(weight)
 
+    def release_wire_buffers(self, buffers: Any) -> None:
+        self._comm.release_wire_buffers(buffers)
+
+    def accum_counters(self) -> Any:
+        return self._comm.accum_counters()
+
     def ring_bytes_total(self) -> float:
         return self._comm.ring_bytes_total()
 

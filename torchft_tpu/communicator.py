@@ -310,10 +310,10 @@ class Communicator(ABC):
     def allreduce(self, tree: Any, op: str = "sum") -> Future:
         """Sum (or mean) a pytree of numpy arrays across the world.
 
-        Ownership: leaves that are already contiguous 1-D buffers may be
-        reduced **in place** (backends skip the defensive concat/copy on
-        that hot-path shape) — callers must treat inputs as consumed and
-        use only the resolved result."""
+        Ownership: a backend may reduce a contiguous 1-D leaf **in
+        place**, so callers treat inputs as consumed and use only the
+        resolved result. (The host backend no longer does: its exact ring
+        reads the leaf and writes an accumulator of its own.)"""
 
     def allreduce_wire(self, buffers: Sequence[Any],
                        orig_dtypes: Sequence[Any],
@@ -325,8 +325,13 @@ class Communicator(ABC):
         narrow *wire* dtype (== the accumulator dtype when uncompressed);
         ``orig_dtypes[k]`` names the full-precision accumulator dtype the
         reduced result must come back in. Resolves to a list of 1-D numpy
-        arrays in the accumulator dtypes. Buffers are consumed: backends
-        may reduce them in place.
+        arrays in the accumulator dtypes, which the caller owns. Buffers
+        may be read-only (``jax.device_get`` returns such): the host
+        backend only reads them, and its exact ring folds into a separate
+        accumulator — one the caller handed back after an earlier op
+        (:meth:`release_wire_buffers`) where there is one, else a fresh
+        one. Other backends may still reduce a writable buffer in place,
+        so treat the inputs as consumed.
 
         The default upcasts locally and reuses :meth:`allreduce` — wire
         compression then only thins the device->host leg, the pre-wire-
@@ -355,8 +360,10 @@ class Communicator(ABC):
         stripe (:class:`~torchft_tpu.backends.host.HostCommunicator`;
         half the wire bytes at world 2), cutting fold compute — and the
         optimizer stage that follows — to ~1/world.
-        Buffers are consumed, like :meth:`allreduce_wire`. Wrappers MUST
-        forward — falling back to the default silently restores
+        Buffers are only read by the host backend and consumed by the
+        contract, like :meth:`allreduce_wire`; the resolved stripes are
+        fresh copies (the accumulator stays inside the backend). Wrappers
+        MUST forward — falling back to the default silently restores
         full-allreduce ring traffic."""
         fut = self.allreduce_wire(buffers, orig_dtypes, op)
         rank, world = self.rank(), max(self.size(), 1)
@@ -374,6 +381,29 @@ class Communicator(ABC):
 
         fut.add_done_callback(relay)
         return out
+
+    def release_wire_buffers(self, buffers: Optional[Sequence[Any]]
+                             ) -> None:
+        """Hand back arrays that :meth:`allreduce_wire` resolved to, once
+        NOTHING reads them any more (a transfer that reads one
+        asynchronously has finished): a backend that keeps its exact
+        ring's accumulators across ops folds a later op into the same
+        memory instead of into freshly mapped pages. ``None`` drops what
+        it keeps (the Manager says so when the gradient signature
+        changes). Optional: a caller that never calls this gets a fresh
+        accumulator every op. Arrays the backend did not lend are
+        ignored; the default keeps nothing. Wrappers MUST forward."""
+
+    def accum_counters(self) -> Tuple[float, float, float]:
+        """``(host_copy_bytes, accum_reuse, accum_alloc)`` of the exact
+        ring, cumulative: bytes of wire buffers copied on the host before
+        the ring could read them (a non-contiguous buffer), and
+        accumulators taken from the kept set / freshly allocated (counts,
+        one per exact chunk of a wire op). Surfaced by the Manager as
+        ``allreduce_host_copy_bytes_total``,
+        ``allreduce_accum_reuse_total``, ``allreduce_accum_alloc_total``.
+        Wrappers MUST forward."""
+        return (0.0, 0.0, 0.0)
 
     def ring_bytes_total(self) -> float:
         """Cumulative allreduce payload bytes this rank has *sent* over
@@ -710,6 +740,13 @@ class ErrorSwallowingCommunicator(Communicator):
     def set_wire_weight(self, weight: int) -> None:
         self._comm.set_wire_weight(weight)
 
+    def release_wire_buffers(self, buffers: Optional[Sequence[Any]]
+                             ) -> None:
+        self._comm.release_wire_buffers(buffers)
+
+    def accum_counters(self) -> Tuple[float, float, float]:
+        return self._comm.accum_counters()
+
     def ring_bytes_total(self) -> float:
         return self._comm.ring_bytes_total()
 
@@ -854,6 +891,13 @@ class ManagedCommunicator(Communicator):
 
     def set_wire_weight(self, weight: int) -> None:
         self._comm.set_wire_weight(weight)
+
+    def release_wire_buffers(self, buffers: Optional[Sequence[Any]]
+                             ) -> None:
+        self._comm.release_wire_buffers(buffers)
+
+    def accum_counters(self) -> Tuple[float, float, float]:
+        return self._comm.accum_counters()
 
     def ring_bytes_total(self) -> float:
         return self._comm.ring_bytes_total()

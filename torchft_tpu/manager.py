@@ -681,6 +681,13 @@ class Manager:
             # own name so the devquant A/B and bench fetch accounting
             # never conflate "bytes fetched" with "payload represented".
             "allreduce_d2h_wire_bytes_total": 0.0,
+            # Bytes of gradient copied host-to-host between the fetch
+            # and the ring's first send (bytes): assembling a mixed
+            # host/device chunk or the hostcast leg here, a
+            # non-contiguous wire buffer in the backend (merged in
+            # metrics() with the accumulator counts). 0 where every
+            # leaf is on the device: the ring reads the fetched buffer.
+            "allreduce_host_copy_bytes_total": 0.0,
             # Cross-step overlap engine (docs/design/overlap.md):
             # hidden = comm wall that ran concurrently with the caller's
             # compute between dispatch and drain (the ms the engine
@@ -946,6 +953,9 @@ class Manager:
         # by (treedef, leaf metadata, bucket_bytes, wire_dtype) — see
         # _get_schedule().
         self._sched_cache: Dict[tuple, _AllreduceSchedule] = {}
+        # Fingerprint of the schedule whose reduced buffers go back to
+        # the communicator after the put (_host_allreduce_pipelined).
+        self._accum_sig = ""
         # Attached durable-checkpoint writer (save_durable); its save
         # counters and last error ride metrics()/metrics.json.
         self._ckpt_writer: Optional[Any] = None
@@ -2071,7 +2081,9 @@ class Manager:
                               END-TO-END, upcasting received segments into
                               a full-precision accumulator during the fold
                               (backends/host.py); uncompressed chunks take
-                              the exact in-place ring;
+                              the exact ring, which reads the fetched
+                              buffer and folds into an accumulator kept
+                              across steps (handed back after stage 4);
             put thread:    4. device scale/put — one H2D transfer of the
                               reduced buffer, then a cached jitted
                               1/n-scale + split + reshape on device
@@ -2114,6 +2126,13 @@ class Manager:
         ar_t0 = time.perf_counter()
         self._set_wire_tag()
         sched = self._get_schedule(treedef, leaves)
+        # The communicator keeps the exact ring's accumulators across
+        # steps when they are handed back (release_wire_buffers); they
+        # fit one gradient signature, so another one starts afresh.
+        release = getattr(self._comm, "release_wire_buffers", None)
+        if release is not None and sched.fingerprint != self._accum_sig:
+            self._accum_sig = sched.fingerprint
+            release(None)
         agg: Future = Future()
         out_leaves: list = [None] * len(leaves)
         lock = threading.Lock()
@@ -2139,6 +2158,17 @@ class Manager:
                                                      leaves, n)
                 self._record(allreduce_put_ms_total=(
                     time.perf_counter() - put_t0) * 1e3)
+                if release is not None:
+                    # The put read `reduced` through an H2D transfer
+                    # that may still be running (on the CPU backend it
+                    # may alias the memory instead); its outputs are
+                    # fresh arrays (scale + split). Once they are ready
+                    # nothing reads `reduced` any more and the next
+                    # step's ring may fold into it. Before `pending`
+                    # falls, so a step ends with its buffers back.
+                    jax.block_until_ready(scaled)
+                    if sched.fingerprint == self._accum_sig:
+                        release(reduced)
                 with lock:
                     for i, a in scaled.items():
                         out_leaves[i] = a
@@ -2514,7 +2544,7 @@ class Manager:
         got = iter(jax.device_get(
             [p for _, _, p, _ in recs if p is not None]))
         bufs = []
-        d2h = 0
+        d2h = copied = 0
         for c, dev, packed, kind in recs:
             fetched = None
             if packed is not None:
@@ -2532,20 +2562,23 @@ class Manager:
                     # A/B leg: full-precision fetch, wire cast here on
                     # the host (the serialized pre-optimization cost).
                     fetched = fetched.astype(c.wire)
+                    copied += fetched.nbytes
                 elif fetched.dtype != c.wire:
                     # Non-native wire dtype crossed D2H as its canonical
                     # uint carrier (_transfer_dtype); view the bits back
                     # — zero-copy, bitwise identical.
                     fetched = fetched.view(c.wire)
                 if len(dev) == len(c.idx):
-                    # device_get returns a fresh host buffer this rank
-                    # owns — handed to the ring as-is (it reduces in
-                    # place; no concat, no upcast copy).
+                    # device_get's host buffer is READ-ONLY (jax marks
+                    # it so); it goes to the ring as it is: the ring
+                    # only reads it and folds into an accumulator of
+                    # its own. No concat, no upcast, no copy.
                     bufs.append(np.ascontiguousarray(fetched))
                     continue
             # Mixed / host-only chunk: scatter the packed device parts
             # and the wire-cast host leaves into one fresh ring buffer.
             buf = np.empty(c.total, c.wire)
+            copied += buf.nbytes
             offsets = np.cumsum([0] + c.sizes)
             dev_pos = {j for j, _ in dev}
             fpos = 0
@@ -2559,6 +2592,8 @@ class Manager:
                     seg[:] = np.ravel(np.asarray(leaves[i])).astype(
                         c.wire, copy=False)
             bufs.append(buf)
+        if copied:
+            self._record(allreduce_host_copy_bytes_total=float(copied))
         return bufs, d2h
 
     # alias matching the reference's gradient-specific spelling
@@ -4233,6 +4268,21 @@ class Manager:
         int8_bytes = getattr(self._comm, "int8_ring_bytes_total", None)
         out["allreduce_int8_ring_bytes_total"] = (
             float(int8_bytes()) if int8_bytes is not None else 0.0)
+        # The exact ring's accumulators (Communicator.accum_counters):
+        # host bytes copied before the ring could read a buffer (bytes,
+        # added to this Manager's own), and accumulators taken from the
+        # kept set / freshly allocated (counts; one per exact chunk of
+        # a wire op). After the first step of a gradient signature,
+        # alloc must stop growing: every fresh gradient-sized buffer is
+        # first-touched on the comm thread at the ring's cost.
+        counters = getattr(self._comm, "accum_counters", None)
+        try:
+            copied, reuse, alloc = map(float, counters())
+        except (TypeError, ValueError):  # bare duck-typed / mocked comms
+            copied = reuse = alloc = 0.0
+        out["allreduce_host_copy_bytes_total"] += copied
+        out["allreduce_accum_reuse_total"] = reuse
+        out["allreduce_accum_alloc_total"] = alloc
         # Hierarchical-transport legs (docs/design/hier_transport.md):
         # loopback intra-host bytes (traffic that stopped crossing the
         # DCN ring) and whether this rank leads its host's star. 0 on
