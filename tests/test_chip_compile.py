@@ -251,3 +251,39 @@ def test_bare_kernel_in_a_sharded_jit_is_refused(topo):
     with pytest.raises(NotImplementedError,
                        match="cannot be automatically partitioned"):
         jax.jit(jax.grad(loss)).lower(x, x, x).compile()
+
+
+def test_trinity_mini_cell_step_fits_the_chip(one_chip):
+    """``trinity-mini.steady-1g-8k`` as ``benchmarks/`` builds it: the fused
+    one-group step (not donated) at the published widths, 5 layers, 8 of 128
+    experts held and the cell's own batch of 8192-token sequences, adamw.
+    Windowed and full flash kernels and the grouped matmuls compile as
+    Mosaic custom calls, and the step fits with the room the driver's
+    oracle needs beside it for one more seeded tree."""
+    import sys
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from harness import spec
+
+    cell = spec.Cell("trinity-mini.steady-1g-8k")
+    cfg, seq, batch = (cell.config, int(cell.mix["seq"]),
+                       int(cell.mix["batch_per_group"]))
+    builder = spec.model_of(cfg)
+    loss_fn = builder.make_loss_fn(cfg, seq, interpret=False)
+    pshape = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s, jnp.float32),
+        builder.param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))
+    tx = optax.adamw(3e-4)
+    p = _shaped(pshape, one_chip)
+    o = _shaped(jax.eval_shape(tx.init, pshape), one_chip)
+    tokens = {"tokens": jax.ShapeDtypeStruct((batch, seq), jnp.int32,
+                                             sharding=one_chip)}
+    _, fused, _ = _trainer_programs(loss_fn, tx)
+    c = fused.lower(p, o, tokens).compile()
+    text = c.as_text()
+    assert "flash_fwd_window" in text and "gmm" in text
+    tree = 4 * builder.param_count(cfg)
+    assert 6 * tree < _footprint(c) < HBM_BYTES - tree

@@ -4253,6 +4253,10 @@ class Manager:
             float(self._manager_server.lighthouse_redials())
             if self._manager_server is not None else 0.0)
         out.update(self._retry_stats.snapshot())
+        # Totals the jitted programs counted themselves (tracing.
+        # count_in_program: routed pairs, pairs on held experts, ...);
+        # process-wide, absent until a program counted one.
+        out.update(tracing_mod.program_counters())
         # Bytes that actually crossed the TCP ring, counted by the
         # backend at its send sites (halved vs allreduce_wire_bytes_total
         # under bf16 wire at world 2 — the per-leg observability the

@@ -1,5 +1,5 @@
 from torchft_tpu.models.mlp import MLP
-from torchft_tpu.models.moe import MoEMLP, ep_rules
+from torchft_tpu.models.moe import MoEMLP, RoutedMoEMLP, ep_rules
 from torchft_tpu.models.resnet import ResNet, ResNet18, ResNet34, ResNet50
 from torchft_tpu.models.transformer import (
     Transformer,
@@ -17,6 +17,7 @@ from torchft_tpu.models.transformer import (
 __all__ = [
     "MLP",
     "MoEMLP",
+    "RoutedMoEMLP",
     "ep_rules",
     "moe_lm_loss",
     "ResNet",

@@ -16,7 +16,7 @@ TPU-first design:
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Optional
+from typing import Any, Callable, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -38,10 +38,40 @@ class TransformerConfig:
     # attention impl: None → plain softmax attention; otherwise a callable
     # (q, k, v, causal) -> out, e.g. ring attention under shard_map.
     attention_fn: Optional[Callable] = None
-    # Mixture-of-experts: num_experts > 0 replaces the dense MLP with a
-    # routed MoEMLP (expert dim shards over the "ep" mesh axis).
+    # Mixture-of-experts: num_experts > 0 replaces the dense MLP with an
+    # expert layer of models/moe.py: ``moe_dispatch="dense"`` is MoEMLP
+    # (every expert computes every token; the expert dim shards over the
+    # "ep" mesh axis), ``"routed"`` is RoutedMoEMLP (token-expert pairs
+    # grouped by expert; ``moe_held=(first, count)`` names the share of the
+    # experts this model holds, None = all).
     moe_experts: int = 0
     moe_top_k: int = 2
+    moe_dispatch: str = "dense"
+    moe_dim: Optional[int] = None        # an expert's width; None → mlp_dim
+    moe_held: Optional[Tuple[int, int]] = None
+    moe_shared_dim: int = 0              # the shared expert's width; 0: none
+    moe_score: str = "sigmoid"           # routed dispatch only
+    moe_route_norm: bool = True
+    moe_route_scale: float = 1.0
+    moe_dense_layers: int = 0            # leading layers that keep a dense MLP
+    moe_interpret: Optional[bool] = None  # routed: Pallas interpreted (None:
+    #                                       off a TPU)
+    # Layers of different kinds. ``layer_types[i]`` is "full_attention" or
+    # "sliding_attention" (key j visible to query i iff 0 <= i - j <
+    # ``sliding_window``); None = every layer full. ``rope_full_layers=False``
+    # leaves rotary off the full-attention layers.
+    layer_types: Optional[Tuple[str, ...]] = None
+    sliding_window: Optional[int] = None
+    rope_full_layers: bool = True
+    # The attention block's options, all off in the Llama recipe: a stated
+    # head size (None → embed_dim / num_heads), RMSNorm of queries and keys
+    # per head, a sigmoid gate on the attention output, a second norm on
+    # each sub-block's output (four a layer), the embedding times √embed_dim.
+    attn_head_dim: Optional[int] = None
+    qk_norm: bool = False
+    attn_gate: bool = False
+    sandwich_norm: bool = False
+    embed_scale: bool = False
     # Per-layer rematerialization (jax.checkpoint): trade ~30% backward
     # FLOPs for O(num_layers) fewer live activations — the standard move
     # for long-context / big-batch training on HBM-bound chips.
@@ -53,7 +83,13 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.embed_dim // self.num_heads
+        return self.attn_head_dim or self.embed_dim // self.num_heads
+
+    def layer_type(self, i: int) -> str:
+        return self.layer_types[i] if self.layer_types else FULL
+
+    def is_moe_layer(self, i: int) -> bool:
+        return self.moe_experts > 0 and i >= self.moe_dense_layers
 
     @property
     def mlp_dim(self) -> int:
@@ -61,6 +97,9 @@ class TransformerConfig:
             return self.hidden_dim
         h = int(self.embed_dim * 8 / 3)
         return (h + 127) // 128 * 128
+
+
+FULL, SLIDING = "full_attention", "sliding_attention"
 
 
 class RMSNorm(nn.Module):
@@ -89,9 +128,11 @@ def rotary(x: jnp.ndarray, positions: jnp.ndarray,
     return out.astype(x.dtype)
 
 
-def plain_attention(q, k, v, causal: bool = True):
+def plain_attention(q, k, v, causal: bool = True,
+                    window: Optional[int] = None):
     """Reference softmax attention; q: [B, S, H, D], k/v may carry fewer
-    (GQA) heads — repeated here (f32 softmax)."""
+    (GQA) heads — repeated here (f32 softmax). ``window`` (causal only):
+    key j is visible to query i iff 0 <= i - j < window."""
     if k.shape[2] != q.shape[2]:
         rep = q.shape[2] // k.shape[2]
         k = jnp.repeat(k, rep, axis=2)
@@ -102,7 +143,12 @@ def plain_attention(q, k, v, causal: bool = True):
     if causal:
         s_q, s_k = q.shape[1], k.shape[1]
         mask = jnp.tril(jnp.ones((s_q, s_k), dtype=bool), k=s_k - s_q)
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((s_q, s_k), dtype=bool),
+                              k=s_k - s_q - int(window))
         logits = jnp.where(mask, logits, -1e30)
+    elif window is not None:
+        raise ValueError("a window needs causal=True")
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
@@ -112,18 +158,29 @@ plain_attention.supports_gqa = True
 
 class Attention(nn.Module):
     cfg: TransformerConfig
+    kind: str = FULL
 
     @nn.compact
     def __call__(self, x, positions):
         cfg = self.cfg
+        if self.kind not in (FULL, SLIDING):
+            raise ValueError(f"unknown layer type {self.kind!r}")
+        sliding = self.kind == SLIDING
+        if sliding and not cfg.sliding_window:
+            raise ValueError("a sliding_attention layer needs "
+                             "sliding_window")
         B, S, _ = x.shape
         dense = lambda feats, name: nn.DenseGeneral(  # noqa: E731
             feats, axis=-1, use_bias=False, dtype=cfg.dtype, name=name)
         q = dense((cfg.num_heads, cfg.head_dim), "q")(x)
         k = dense((cfg.kv_heads, cfg.head_dim), "k")(x)
         v = dense((cfg.kv_heads, cfg.head_dim), "v")(x)
-        q = rotary(q, positions, cfg.rope_theta)
-        k = rotary(k, positions, cfg.rope_theta)
+        if cfg.qk_norm:
+            q = RMSNorm(name="q_norm")(q)
+            k = RMSNorm(name="k_norm")(k)
+        if sliding or cfg.rope_full_layers:
+            q = rotary(q, positions, cfg.rope_theta)
+            k = rotary(k, positions, cfg.rope_theta)
         attn = cfg.attention_fn or plain_attention
         if (cfg.kv_heads != cfg.num_heads
                 and not getattr(attn, "supports_gqa", False)):
@@ -133,8 +190,16 @@ class Attention(nn.Module):
             rep = cfg.num_heads // cfg.kv_heads
             k = jnp.repeat(k, rep, axis=2)
             v = jnp.repeat(v, rep, axis=2)
-        out = attn(q, k, v, True)
+        if sliding:
+            out = attn(q, k, v, True, window=int(cfg.sliding_window))
+        else:
+            out = attn(q, k, v, True)
         out = out.reshape(B, S, cfg.num_heads * cfg.head_dim)
+        if cfg.attn_gate:
+            gate = nn.Dense(cfg.num_heads * cfg.head_dim, use_bias=False,
+                            dtype=cfg.dtype, name="gate")(x)
+            out = out * jax.nn.sigmoid(
+                gate.astype(jnp.float32)).astype(out.dtype)
         return nn.DenseGeneral(cfg.embed_dim, use_bias=False,
                                dtype=cfg.dtype, name="o")(out)
 
@@ -154,23 +219,52 @@ class MLPBlock(nn.Module):
 
 
 class DecoderLayer(nn.Module):
+    """One layer: attention of ``kind`` and a dense or (``moe``) an expert
+    MLP, pre-norm; with ``cfg.sandwich_norm`` each sub-block's output is
+    normed again before it joins the stream. Returns the stream, and with a
+    routed expert layer ``(stream, that layer's int32[3] counts)``."""
+
     cfg: TransformerConfig
+    kind: str = FULL
+    moe: bool = False
 
     @nn.compact
     def __call__(self, x, positions):
         cfg = self.cfg
-        x = x + Attention(cfg, name="attn")(
+        a = Attention(cfg, kind=self.kind, name="attn")(
             RMSNorm(name="attn_norm")(x), positions)
-        if cfg.moe_experts > 0:
+        if cfg.sandwich_norm:
+            a = RMSNorm(name="post_attn_norm")(a)
+        x = x + a
+        routed = self.moe and cfg.moe_dispatch == "routed"
+        if routed:
+            from torchft_tpu.models.moe import RoutedMoEMLP
+
+            mlp = RoutedMoEMLP(
+                num_experts=cfg.moe_experts, top_k=cfg.moe_top_k,
+                mlp_dim=cfg.moe_dim or cfg.mlp_dim, held=cfg.moe_held,
+                shared_dim=cfg.moe_shared_dim, score=cfg.moe_score,
+                route_norm=cfg.moe_route_norm,
+                route_scale=cfg.moe_route_scale, dtype=cfg.dtype,
+                interpret=cfg.moe_interpret, name="moe")
+        elif self.moe and cfg.moe_dispatch == "dense":
             from torchft_tpu.models.moe import MoEMLP
 
             mlp = MoEMLP(num_experts=cfg.moe_experts,
-                         mlp_dim=cfg.mlp_dim, top_k=cfg.moe_top_k,
+                         mlp_dim=cfg.moe_dim or cfg.mlp_dim,
+                         top_k=cfg.moe_top_k,
                          dtype=cfg.dtype, name="moe")
+        elif self.moe:
+            raise ValueError(f"unknown moe_dispatch {cfg.moe_dispatch!r}")
         else:
             mlp = MLPBlock(cfg, name="mlp")
-        x = x + mlp(RMSNorm(name="mlp_norm")(x))
-        return x
+        u = RMSNorm(name="mlp_norm")(x)
+        # A routed layer's counts leave the (rematerialised) layer as
+        # values: Transformer counts them once a step.
+        m, stats = mlp(u, return_stats=True) if routed else (mlp(u), None)
+        if cfg.sandwich_norm:
+            m = RMSNorm(name="post_mlp_norm")(m)
+        return (x + m, stats) if routed else x + m
 
 
 class Transformer(nn.Module):
@@ -186,12 +280,27 @@ class Transformer(nn.Module):
         cfg = self.cfg
         x = nn.Embed(cfg.vocab_size, cfg.embed_dim,
                      dtype=cfg.dtype, name="embed")(tokens)
+        if cfg.embed_scale:
+            x = x * jnp.asarray(cfg.embed_dim ** 0.5, x.dtype)
+        if cfg.layer_types and len(cfg.layer_types) != cfg.num_layers:
+            raise ValueError(f"{len(cfg.layer_types)} layer_types for "
+                             f"{cfg.num_layers} layers")
         positions = jnp.broadcast_to(
             jnp.arange(tokens.shape[1]), tokens.shape)
         layer_cls = (nn.remat(DecoderLayer, prevent_cse=False)
                      if cfg.remat else DecoderLayer)
+        moe_stats = None
         for i in range(cfg.num_layers):
-            x = layer_cls(cfg, name=f"layer_{i}")(x, positions)
+            x = layer_cls(cfg, kind=cfg.layer_type(i),
+                          moe=cfg.is_moe_layer(i),
+                          name=f"layer_{i}")(x, positions)
+            if isinstance(x, tuple):
+                x, stats = x
+                moe_stats = stats if moe_stats is None else moe_stats + stats
+        if moe_stats is not None:
+            from torchft_tpu.models.moe import count_moe_stats
+
+            count_moe_stats(moe_stats)
         x = RMSNorm(name="final_norm")(x)
         if return_hidden:
             return x
@@ -254,6 +363,7 @@ def tp_rules() -> list:
     """
     return [
         (r"attn/[qkv]/kernel", P(None, "tp", None)),
+        (r"attn/gate/kernel", P(None, "tp")),
         (r"attn/o/kernel", P("tp", None)),
         (r"mlp/(gate|up)/kernel", P(None, "tp")),
         (r"mlp/down/kernel", P("tp", None)),

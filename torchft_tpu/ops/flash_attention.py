@@ -78,7 +78,8 @@ def _resolve_interpret(interpret: Optional[bool]) -> bool:
     return bool(interpret)
 
 
-def _block_visibility(qi, ki, bq, bk, offset, causal, shift_ref):
+def _block_visibility(qi, ki, bq, bk, offset, causal, shift_ref,
+                      window=None):
     """Block-level mask bounds shared by every kernel (forward, split
     backward, fused backward) so their masking can never desynchronize.
 
@@ -87,7 +88,13 @@ def _block_visibility(qi, ki, bq, bk, offset, causal, shift_ref):
     traced ``shift_ref`` (ring attention) slides the boundary as data —
     the bounds stay scalar compares either way, so fully-masked blocks
     are skipped and fully-visible blocks take the unmasked path even
-    when the mask VALUES are traced."""
+    when the mask VALUES are traced.
+
+    ``window`` (static, causal only) adds the lower bound beside the
+    causal upper one: key ``j`` is visible to query ``i`` iff
+    ``0 <= i - j < window``. Over a block ``i - j`` takes every value from
+    ``min_i - max_j`` to ``max_i - min_j``, so blocks wholly left of the
+    window are skipped like blocks right of the diagonal."""
     if shift_ref is not None:
         shift = shift_ref[0, 0]
         diag_ok = (qi * bq + bq - 1 + offset + shift >= ki * bk)
@@ -95,10 +102,23 @@ def _block_visibility(qi, ki, bq, bk, offset, causal, shift_ref):
     elif causal:
         diag_ok = (qi * bq + bq - 1 + offset >= ki * bk)
         full_vis = (qi * bq + offset >= ki * bk + bk - 1)
+        if window is not None:
+            diag_ok = jnp.logical_and(
+                diag_ok, qi * bq + offset - (ki * bk + bk - 1) < window)
+            full_vis = jnp.logical_and(
+                full_vis, qi * bq + bq - 1 + offset - ki * bk < window)
     else:
         diag_ok = True
         full_vis = True
     return diag_ok, full_vis
+
+
+def _visible(q_pos, k_pos, window):
+    """The element mask: causal, and inside the window where there is
+    one."""
+    if window is None:
+        return q_pos >= k_pos
+    return jnp.logical_and(q_pos >= k_pos, q_pos - k_pos < window)
 
 
 def _dual_instantiate(compute, causal, shift_ref, diag_ok, full_vis):
@@ -120,7 +140,7 @@ def _dual_instantiate(compute, causal, shift_ref, diag_ok, full_vis):
 
 
 def _fwd_kernel(*refs, causal: bool, scale: float, nkb: int, offset: int,
-                dynamic_shift: bool):
+                dynamic_shift: bool, window: Optional[int] = None):
     if dynamic_shift:
         q_ref, k_ref, v_ref, shift_ref, o_ref, lse_ref, \
             m_ref, l_ref, acc_ref = refs
@@ -139,7 +159,7 @@ def _fwd_kernel(*refs, causal: bool, scale: float, nkb: int, offset: int,
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     diag_ok, full_vis = _block_visibility(
-        qi, ki, bq, bk, offset, causal, shift_ref)
+        qi, ki, bq, bk, offset, causal, shift_ref, window)
 
     def _softmax_update(logits, v):
         m_prev = m_ref[:]
@@ -177,7 +197,8 @@ def _fwd_kernel(*refs, causal: bool, scale: float, nkb: int, offset: int,
                 # k_pos. shift=0 → diagonal causal; shift >= s_k → full
                 # attention; shift <= -s_q → fully blocked.
                 q_pos = q_pos + shift_ref[0, 0]
-            logits = jnp.where(q_pos >= k_pos, logits, NEG_INF)
+            logits = jnp.where(_visible(q_pos, k_pos, window), logits,
+                               NEG_INF)
         _softmax_update(logits, v)
 
     _dual_instantiate(_compute, causal, shift_ref, diag_ok, full_vis)
@@ -191,6 +212,25 @@ def _fwd_kernel(*refs, causal: bool, scale: float, nkb: int, offset: int,
         lse = m_ref[:] + jnp.log(jnp.maximum(l_ref[:], 1e-30))
         lse_ref[0] = jax.lax.broadcast_in_dim(
             lse[:, 0], lse_ref.shape[1:], (0,))
+
+
+def _check_window(window: Optional[int], causal: bool,
+                  dynamic_shift: bool) -> None:
+    if window is None:
+        return
+    if not causal or dynamic_shift:
+        raise ValueError("a window bounds the causal mask from below: it "
+                         "needs causal=True and no traced shift")
+    if int(window) < 1:
+        raise ValueError(f"window must be at least 1, got {window}")
+
+
+def _kernel_name(base: str, window: Optional[int]) -> Optional[str]:
+    """The windowed ``pallas_call``'s own name, by which a profile tells it
+    from the full kernel. The full kernel keeps none: XLA names a custom
+    call after the innermost scope, a ``name`` is one more scope, and the
+    benchmark finds the full kernel by its caller's (``%attn``)."""
+    return None if window is None else f"{base}_window"
 
 
 def _auto_block(seq: int, cap: int = 1024) -> int:
@@ -216,7 +256,8 @@ def _auto_block(seq: int, cap: int = 1024) -> int:
 def _flash_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                causal: bool, block_q: Optional[int], block_k: Optional[int],
                interpret: bool,
-               shift: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+               shift: Optional[jnp.ndarray] = None,
+               window: Optional[int] = None) -> jnp.ndarray:
     b, s, h, d = q.shape
     h_kv = k.shape[2]
     assert h % h_kv == 0, f"num_heads {h} not a multiple of kv heads {h_kv}"
@@ -229,6 +270,7 @@ def _flash_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     block_q = block_q or _auto_block(s, cap=cap)
     block_k = block_k or _auto_block(k.shape[1], cap=cap)
     dynamic_shift = shift is not None
+    _check_window(window, causal, dynamic_shift)
 
     def to_bh(x):
         bh = x.shape[0] * x.shape[2]
@@ -266,7 +308,7 @@ def _flash_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, causal=causal, scale=scale,
                           nkb=nkb, offset=sk - s,
-                          dynamic_shift=dynamic_shift),
+                          dynamic_shift=dynamic_shift, window=window),
         out_shape=[
             jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
             # Row stats ride in [bh, s, 128] with the value broadcast over
@@ -288,13 +330,15 @@ def _flash_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.VMEM((block_q, d), jnp.float32),   # output accumulator
         ],
         interpret=interpret,
+        name=_kernel_name("flash_fwd", window),
     )(*inputs)
     return out.reshape(b, h, s, d).transpose(0, 2, 1, 3), lse[:, :, 0]
 
 
 def _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     qi, ki, causal: bool, scale: float, offset: int,
-                    shift_ref=None, apply_mask: bool = True):
+                    shift_ref=None, apply_mask: bool = True,
+                    window: Optional[int] = None):
     """Shared backward recompute: rebuild the probability tile from
     (q, k, lse) under the same end-aligned causal mask as the forward and
     form ds = p * (dp - delta). Used by both the dq and dk/dv kernels so
@@ -318,7 +362,7 @@ def _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             jnp.int32, (1, bk), 1)
         if shift_ref is not None:
             q_pos = q_pos + shift_ref[0, 0]
-        logits = jnp.where(q_pos >= k_pos, logits, NEG_INF)
+        logits = jnp.where(_visible(q_pos, k_pos, window), logits, NEG_INF)
     lse_row = jnp.max(lse_ref[0], axis=1, keepdims=True)
     p = jnp.exp(logits - lse_row)                     # exact softmax
     dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
@@ -328,7 +372,8 @@ def _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_dq_kernel(*refs, causal: bool, scale: float, nkb: int,
-                   offset: int, dynamic_shift: bool):
+                   offset: int, dynamic_shift: bool,
+                   window: Optional[int] = None):
     if dynamic_shift:
         q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, shift_ref, \
             dq_ref, acc_ref = refs
@@ -346,13 +391,13 @@ def _bwd_dq_kernel(*refs, causal: bool, scale: float, nkb: int,
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     diag_ok, full_vis = _block_visibility(
-        qi, ki, bq, bk, offset, causal, shift_ref)
+        qi, ki, bq, bk, offset, causal, shift_ref, window)
 
     def _compute(apply_mask: bool):
         _, ds, _, k, _ = _recompute_p_ds(
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             qi, ki, causal, scale, offset, shift_ref,
-            apply_mask=apply_mask)
+            apply_mask=apply_mask, window=window)
         acc_ref[:] += jnp.dot(ds.astype(k.dtype), k,
                               preferred_element_type=jnp.float32) * scale
 
@@ -364,7 +409,8 @@ def _bwd_dq_kernel(*refs, causal: bool, scale: float, nkb: int,
 
 
 def _bwd_dkdv_kernel(*refs, causal: bool, scale: float, nqb: int,
-                     offset: int, dynamic_shift: bool):
+                     offset: int, dynamic_shift: bool,
+                     window: Optional[int] = None):
     if dynamic_shift:
         q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, shift_ref, \
             dk_ref, dv_ref, dk_acc, dv_acc = refs
@@ -383,13 +429,13 @@ def _bwd_dkdv_kernel(*refs, causal: bool, scale: float, nqb: int,
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     diag_ok, full_vis = _block_visibility(
-        qi, ki, bq, bk, offset, causal, shift_ref)
+        qi, ki, bq, bk, offset, causal, shift_ref, window)
 
     def _compute(apply_mask: bool):
         p, ds, q, _, do = _recompute_p_ds(
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             qi, ki, causal, scale, offset, shift_ref,
-            apply_mask=apply_mask)
+            apply_mask=apply_mask, window=window)
         dv_acc[:] += jnp.dot(p.astype(do.dtype).T, do,
                              preferred_element_type=jnp.float32)
         dk_acc[:] += jnp.dot(ds.astype(q.dtype).T, q,
@@ -404,7 +450,8 @@ def _bwd_dkdv_kernel(*refs, causal: bool, scale: float, nqb: int,
 
 
 def _bwd_fused_kernel(*refs, causal: bool, scale: float, nqb: int,
-                      offset: int, dynamic_shift: bool):
+                      offset: int, dynamic_shift: bool,
+                      window: Optional[int] = None):
     """One backward kernel for dq, dk AND dv.
 
     The split kernels each recompute (logits, p, dp, ds) per block — the
@@ -439,13 +486,13 @@ def _bwd_fused_kernel(*refs, causal: bool, scale: float, nqb: int,
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     diag_ok, full_vis = _block_visibility(
-        qi, ki, bq, bk, offset, causal, shift_ref)
+        qi, ki, bq, bk, offset, causal, shift_ref, window)
 
     def _compute(apply_mask: bool):
         p, ds, q, k, do = _recompute_p_ds(
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             qi, ki, causal, scale, offset, shift_ref,
-            apply_mask=apply_mask)
+            apply_mask=apply_mask, window=window)
         dv_acc[:] += jnp.dot(p.astype(do.dtype).T, do,
                              preferred_element_type=jnp.float32)
         dk_acc[:] += jnp.dot(ds.astype(q.dtype).T, q,
@@ -471,7 +518,7 @@ def _bwd_fused_kernel(*refs, causal: bool, scale: float, nqb: int,
 
 def _flash_bwd(q, k, v, out, lse, g, causal: bool, block_q: Optional[int],
                block_k: Optional[int], interpret: bool, shift=None,
-               g_lse=None):
+               g_lse=None, window: Optional[int] = None):
     b, s, h, d = q.shape
     h_kv = k.shape[2]
     rep = h // h_kv
@@ -508,6 +555,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal: bool, block_q: Optional[int],
     lse_l = jnp.broadcast_to(lse[:, :, None], (b * h, s, _LANES))
 
     dynamic_shift = shift is not None
+    _check_window(window, causal, dynamic_shift)
     q_spec = pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0))
     k_spec = pl.BlockSpec((1, block_k, d),
                           lambda bh, i, j: (kv_row(bh), j, 0))
@@ -582,7 +630,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal: bool, block_q: Optional[int],
         dk, dv, dq = pl.pallas_call(
             functools.partial(_bwd_fused_kernel, causal=causal,
                               scale=scale, nqb=nqb, offset=offset,
-                              dynamic_shift=dynamic_shift),
+                              dynamic_shift=dynamic_shift, window=window),
             out_shape=[
                 jax.ShapeDtypeStruct((b * h, sk, d), k.dtype),
                 jax.ShapeDtypeStruct((b * h, sk, d), v.dtype),
@@ -597,19 +645,21 @@ def _flash_bwd(q, k, v, out, lse, g, causal: bool, block_q: Optional[int],
             ],
             input_output_aliases={6: 2},  # dq buffer: read-modify-write
             interpret=interpret,
+            name=_kernel_name("flash_bwd", window),
         )(*inputs2)
         return pack(dq.astype(q.dtype), dk, dv)
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, causal=causal, scale=scale,
                           nkb=nkb, offset=offset,
-                          dynamic_shift=dynamic_shift),
+                          dynamic_shift=dynamic_shift, window=window),
         out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
         grid=(b * h, nqb, nkb),
         in_specs=in_specs,
         out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name=_kernel_name("flash_bwd_dq", window),
     )(*inputs)
 
     # dk/dv: k-block outer, q-block innermost (sequential accumulation).
@@ -625,7 +675,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal: bool, block_q: Optional[int],
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkdv_kernel, causal=causal, scale=scale,
                           nqb=nqb, offset=offset,
-                          dynamic_shift=dynamic_shift),
+                          dynamic_shift=dynamic_shift, window=window),
         out_shape=[
             jax.ShapeDtypeStruct((b * h, sk, d), k.dtype),
             jax.ShapeDtypeStruct((b * h, sk, d), v.dtype),
@@ -638,29 +688,35 @@ def _flash_bwd(q, k, v, out, lse, g, causal: bool, block_q: Optional[int],
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name=_kernel_name("flash_bwd_dkdv", window),
     )(*inputs2)
 
     return pack(dq, dk, dv)
 
 
-def _reference(q, k, v, causal):
+def _reference(q, k, v, causal, window=None):
     scale = q.shape[-1] ** -0.5
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
     if causal:
         s_q, s_k = q.shape[1], k.shape[1]
         mask = jnp.tril(jnp.ones((s_q, s_k), dtype=bool), k=s_k - s_q)
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((s_q, s_k), dtype=bool),
+                              k=s_k - s_q - int(window))
         logits = jnp.where(mask, logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash_core(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                 causal: bool = True, block_q: Optional[int] = None,
                 block_k: Optional[int] = None,
-                interpret: bool = False) -> jnp.ndarray:
-    out, _ = _flash_fwd(q, k, v, causal, block_q, block_k, interpret)
+                interpret: bool = False,
+                window: Optional[int] = None) -> jnp.ndarray:
+    out, _ = _flash_fwd(q, k, v, causal, block_q, block_k, interpret,
+                        window=window)
     return out
 
 
@@ -688,7 +744,8 @@ def _seq_pad(s_q: int, s_k: int) -> int:
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     causal: bool = True, block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    interpret: Optional[bool] = None) -> jnp.ndarray:
+                    interpret: Optional[bool] = None,
+                    window: Optional[int] = None) -> jnp.ndarray:
     """Flash attention. q: [B, S, H, D]; k/v: [B, S_k, H_kv, D] with H_kv
     dividing H — GQA/MQA kv heads are shared via kernel index maps, never
     materialized with a repeat. ``block_q/block_k=None`` auto-picks the
@@ -708,17 +765,26 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     exact rather than relying on Mosaic's implicit handling. Only the
     causal path pads (padded keys would corrupt non-causal rows); passing
     EITHER block size explicitly bypasses padding, and the blocks must
-    then divide the unpadded lengths."""
+    then divide the unpadded lengths.
+
+    ``window`` (causal only): key ``j`` is visible to query ``i`` iff
+    ``0 <= i - j < window`` (positions end-aligned as for the causal mask);
+    blocks wholly outside the window are skipped in the forward and in both
+    backward paths. ``None`` is full causal attention and traces the kernels
+    exactly as before the argument existed."""
     interpret = _resolve_interpret(interpret)
+    window = None if window is None else int(window)
     s, sk = q.shape[1], k.shape[1]
     if block_q is not None or block_k is not None:
         # Any explicit block bypasses padding entirely: the caller is
         # tiling by hand, and the kernel's divisibility assert should
         # speak about THEIR lengths, not internally padded ones.
-        return _flash_core(q, k, v, causal, block_q, block_k, interpret)
+        return _flash_core(q, k, v, causal, block_q, block_k, interpret,
+                           window)
     delta = _seq_pad(s, sk)
     if delta == 0:
-        return _flash_core(q, k, v, causal, block_q, block_k, interpret)
+        return _flash_core(q, k, v, causal, block_q, block_k, interpret,
+                           window)
     if not causal:
         # ValueError, not assert: under `python -O` an assert is stripped
         # and the zero-padding below would silently include padded keys in
@@ -729,21 +795,22 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             f"to a multiple of 8 (<=1024) or 128 and mask externally")
     pad = ((0, 0), (0, delta), (0, 0), (0, 0))
     out = _flash_core(jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad),
-                      causal, block_q, block_k, interpret)
+                      causal, block_q, block_k, interpret, window)
     return out[:, :s]
 
 
-def _fwd_rule(q, k, v, causal, block_q, block_k, interpret):
-    out, lse = _flash_fwd(q, k, v, causal, block_q, block_k, interpret)
+def _fwd_rule(q, k, v, causal, block_q, block_k, interpret, window=None):
+    out, lse = _flash_fwd(q, k, v, causal, block_q, block_k, interpret,
+                          window=window)
     return out, (q, k, v, out, lse)
 
 
-def _bwd_rule(causal, block_q, block_k, interpret, res, g):
+def _bwd_rule(causal, block_q, block_k, interpret, window, res, g):
     # Blockwise Pallas backward: recompute p tiles from (q, k, lse), no
     # O(S^2) residuals or intermediates at any sequence length.
     q, k, v, out, lse = res
     return _flash_bwd(q, k, v, out, lse, g, causal, block_q, block_k,
-                      interpret)
+                      interpret, window=window)
 
 
 _flash_core.defvjp(_fwd_rule, _bwd_rule)
@@ -775,9 +842,10 @@ def sharded_flash_attention(mesh: Mesh,
     n_heads = mesh.shape[heads] if heads else 1
     spec = P(data or None, None, heads, None)
 
-    def attention(q, k, v, causal=True):
+    def attention(q, k, v, causal=True, window=None):
         if q.shape[0] % n_data:
-            return flash_attention(q, k, v, causal, interpret=interpret)
+            return flash_attention(q, k, v, causal, interpret=interpret,
+                                   window=window)
         if q.shape[2] % n_heads or k.shape[2] % n_heads:
             raise ValueError(
                 f"sharded_flash_attention: {q.shape[2]} query / "
@@ -785,7 +853,8 @@ def sharded_flash_attention(mesh: Mesh,
                 f"{head_axis}={n_heads}")
         return jax.shard_map(
             lambda q_, k_, v_: flash_attention(q_, k_, v_, causal,
-                                               interpret=interpret),
+                                               interpret=interpret,
+                                               window=window),
             mesh=mesh, in_specs=(spec,) * 3, out_specs=spec,
             check_vma=False)(q, k, v)
 

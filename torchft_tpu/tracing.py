@@ -88,6 +88,45 @@ def default_trace_steps() -> int:
         return 64
 
 
+# ------------------------------------------------------ program counters
+#
+# Totals counted by the jitted programs themselves (how many token-expert
+# pairs a step routed, how many landed on held experts): a value computed
+# on the device reaches the host through ``jax.debug.callback`` and is added
+# here. Process-wide: every Manager of the process reports the same totals
+# (``Manager.metrics()`` merges them). A program that holds a host callback
+# is not written to jax's persistent compile cache.
+
+_program_counters: Dict[str, float] = {}
+_program_counters_lock = threading.Lock()
+
+
+def add_program_counters(**values: Any) -> None:
+    """Host side: add ``values`` to the totals."""
+    with _program_counters_lock:
+        for key, value in values.items():
+            _program_counters[key] = (_program_counters.get(key, 0.0)
+                                      + float(value))
+
+
+def count_in_program(**values: Any) -> None:
+    """Inside a jitted program: add ``values`` (traced scalars or Python
+    numbers) to the totals each time the program runs. Call it outside
+    ``jax.checkpoint``: a rematerialised forward runs the callback again."""
+    import jax
+
+    keys = sorted(values)
+    jax.debug.callback(
+        lambda *vals: add_program_counters(**dict(zip(keys, vals))),
+        *[values[k] for k in keys])
+
+
+def program_counters() -> Dict[str, float]:
+    """A snapshot of the totals."""
+    with _program_counters_lock:
+        return dict(_program_counters)
+
+
 class _NoopSpan:
     """Shared do-nothing span for disabled tracers: ``span()`` on the
     hot path must cost one attribute read + one method call, nothing
