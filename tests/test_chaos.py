@@ -305,6 +305,81 @@ class TestRingSegmentShortRead:
         assert not t.is_alive()
 
 
+class TestSplitLeafShortRead:
+    """A short read on the ring while a split leaf is half through the
+    pipeline (``manager._SLICE_BYTES`` patched small, two Managers over
+    socketpair rings): the step aborts whole — the future resolves to
+    the caller's own tree, no leaf of it half-averaged — the error
+    latches and the vote is no."""
+
+    @pytest.mark.parametrize("clean_ops", [1, 2, 5],
+                             ids=["slice2of4", "slice3of4", "next_leaf"])
+    def test_short_read_mid_leaf_aborts_the_step_whole(self, clean_ops,
+                                                       monkeypatch):
+        import jax
+        import jax.numpy as jnp
+
+        import test_shard
+        import torchft_tpu.manager as manager_mod
+        from torchft_tpu.backends.host import _Ring
+
+        monkeypatch.setattr(manager_mod, "_SLICE_BYTES", 1024)
+        sched = ChaosSchedule(seed=0, intensity=0.0, endpoints={
+            "ring": EndpointChaos(short_rate=1.0, max_faults=1)})
+
+        def rings(world):
+            pairs = [socket.socketpair() for _ in range(world)]
+            return [
+                _Ring(pairs[0][0],
+                      chaos.wrap_socket(pairs[1][1], "ring", sched),
+                      socket.socket()),
+                _Ring(pairs[1][0], pairs[0][1], socket.socket())]
+
+        monkeypatch.setattr(test_shard, "_make_test_rings", rings)
+
+        def grads(rank):
+            return {"a": jnp.asarray(np.random.default_rng(rank).normal(
+                        size=(257, 3)).astype(np.float32)),      # 4 slices
+                    "b": np.random.default_rng(10 + rank).normal(
+                        size=(1000,)).astype(np.float32)}        # 4, host
+
+        def body(m, rank):
+            m.step()
+            m.wait_quorum()
+            if rank == 0:
+                # The storm starts after the handshake of the op that
+                # follows `clean_ops` clean ones: its first segment is
+                # the read that comes short.
+                comm, seen = m._comm, [0]
+                preamble = comm._wire_preamble
+
+                def then_storm(*a, **kw):
+                    out = preamble(*a, **kw)
+                    seen[0] += 1
+                    if seen[0] == clean_ops + 1:
+                        sched.set_intensity(1.0)
+                    return out
+
+                comm._wire_preamble = then_storm
+            tree = grads(rank)
+            got = m.allreduce(tree).result(timeout=60)
+            err = m.errored()
+            vote = m.should_commit()
+            return tree, got, err, vote, m.metrics()
+
+        out = test_shard._run_managers(
+            2, body, {"allreduce_bucket_bytes": 256}, echo_vote=True)
+        for rank, (tree, got, err, vote, mx) in enumerate(out):
+            assert err is not None and vote is False
+            # default=tree: the caller's own leaves, each one whole
+            assert jax.tree_util.tree_structure(got) == \
+                jax.tree_util.tree_structure(tree)
+            for k in tree:
+                assert got[k] is tree[k]
+            assert mx["allreduce_count"] == 0
+        assert "short read" in str(out[0][2])
+
+
 class TestChaosCommunicator:
     def _scripted(self, fault, phase):
         class One(ChaosSchedule):

@@ -208,6 +208,41 @@ def test_boundary_programs_over_the_depth2_state(one_chip):
     assert _footprint(c) < 7 * n * 4
 
 
+@pytest.mark.parametrize("shape", [(32000, 4096), (4096, 14336),
+                                   (92544, 2048)],
+                         ids=["embedding", "mlp", "internlm2_vocab"])
+def test_slice_programs_copy_no_leaf(one_chip, shape):
+    """The exchange's slice of a wide leaf (``_SLICE_BYTES``): the pack
+    cuts rows of the leaf and the put writes them into the donated
+    leaf-shaped buffer. Neither may need a temporary of the leaf's size
+    (a ravel of the whole leaf before the cut does: 500 MiB for the
+    embedding), or two groups' exchange no longer fits beside their
+    state."""
+    from torchft_tpu import manager as manager_mod
+
+    sched = manager_mod._derive_schedule(((shape, "float32"),), 4 << 20,
+                                         None)
+    leaf_bytes = int(np.prod(shape)) * 4
+    assert sched.slices[0] == -(-leaf_bytes // (
+        manager_mod._SLICE_BYTES // (shape[1] * 4) * shape[1] * 4))
+    full, tail = sched.chunks[0][0], sched.chunks[-1][0]
+    leaf = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    for c in (full, tail):
+        lead, _, count = c.rows
+        assert c.total * 4 <= manager_mod._SLICE_BYTES
+        pack = manager_mod._pack_fn("float32", lead, count)
+        m = pack.lower(leaf, scalar).compile().memory_analysis()
+        assert m.temp_size_in_bytes < 2 ** 20
+        assert m.output_size_in_bytes == c.total * 4
+        upd = jax.ShapeDtypeStruct((c.total,), jnp.float32,
+                                   sharding=one_chip)
+        put = manager_mod._put_slice(c)
+        m = put.lower(leaf, upd, scalar, scalar).compile().memory_analysis()
+        assert m.alias_size_in_bytes == leaf_bytes  # assembled in place
+        assert m.temp_size_in_bytes < 2 ** 20
+
+
 def test_sharded_group_step_compiles_for_four_chips(topo):
     """--chips 4 (a): the fused step over a fsdp=2 x tp=2 mesh of the
     described chips, flash attention through the library's shard_map

@@ -878,16 +878,22 @@ class TestSchedule:
         assert a.buckets == b.buckets
         for cs_a, cs_b in zip(a.chunks, b.chunks):
             for ca, cb in zip(cs_a, cs_b):
-                assert (ca.orig, ca.wire, ca.idx, ca.sizes, ca.shapes,
-                        ca.total) == (cb.orig, cb.wire, cb.idx, cb.sizes,
-                                      cb.shapes, cb.total)
-        # Geometry invariants: every leaf appears exactly once; 0-size
-        # leaves contribute 0 elements; chunk totals match their sizes.
+                assert (ca.orig, ca.wire, ca.idx, ca.offs, ca.sizes,
+                        ca.shapes, ca.total, ca.rows) == (
+                            cb.orig, cb.wire, cb.idx, cb.offs, cb.sizes,
+                            cb.shapes, cb.total, cb.rows)
+        # Geometry invariants: every leaf appears exactly once (none is
+        # wider than a slice here: tests/test_slices.py has those), as
+        # the entry (leaf, offset 0, its whole size); 0-size leaves
+        # contribute 0 elements; chunk totals match their sizes.
         seen = sorted(i for cs in a.chunks for c in cs for i in c.idx)
         assert seen == list(range(len(self.METAS)))
+        assert not a.slices
         for cs in a.chunks:
             for c in cs:
                 assert c.total == sum(c.sizes)
+                assert c.rows is None and not any(c.offs)
+                assert c.sizes == [int(np.prod(s)) for s in c.shapes]
         flat_sizes = {i: s for cs in a.chunks for c in cs
                       for i, s in zip(c.idx, c.sizes)}
         assert flat_sizes[2] == 0  # the (0, 5) leaf

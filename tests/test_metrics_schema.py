@@ -58,6 +58,9 @@ DOCUMENTED_KEYS = frozenset([
     # wire op]
     "allreduce_host_copy_bytes_total",
     "allreduce_accum_reuse_total", "allreduce_accum_alloc_total",
+    # wire ops handed to the ring [count, one a bucket], and those of
+    # them that were one slice of a leaf wider than a slice [count]
+    "allreduce_ring_ops_total", "allreduce_split_slices_total",
     # cross-step overlap engine
     "allreduce_hidden_ms_total", "allreduce_drain_wait_ms_total",
     "allreduce_inflight", "overlap_steps_deferred",

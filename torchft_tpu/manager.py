@@ -688,6 +688,11 @@ class Manager:
             # metrics() with the accumulator counts). 0 where every
             # leaf is on the device: the ring reads the fetched buffer.
             "allreduce_host_copy_bytes_total": 0.0,
+            # Wire ops handed to the ring (one a bucket), and how many
+            # of them were one slice of a leaf wider than _SLICE_BYTES:
+            # per committed step they say how often the cut engages.
+            "allreduce_ring_ops_total": 0.0,
+            "allreduce_split_slices_total": 0.0,
             # Cross-step overlap engine (docs/design/overlap.md):
             # hidden = comm wall that ran concurrently with the caller's
             # compute between dispatch and drain (the ms the engine
@@ -1388,13 +1393,16 @@ class Manager:
                 # a pre-v5 rank would quantize the same contribution
                 # to different bytes, so mixed builds must die at
                 # rendezvous rather than silently fold mismatched
-                # rungs.
+                # rungs. payload=wire-v6: leaves wider than
+                # _SLICE_BYTES cross the ring as slices, one op each —
+                # a pre-v6 rank would submit fewer, wider ops and the
+                # ring would wedge on mismatched op counts.
                 wire_fp = ("dynamic" if self._policy_aware
                            else str(self._wire_dtype))
                 setter(f"bucket_bytes={self._bucket_bytes};"
                        f"wire_dtype={wire_fp};"
                        f"degraded={int(self._degraded)};"
-                       f"payload=wire-v5")
+                       f"payload=wire-v6")
             reconf_t0 = time.perf_counter()
             self._comm.configure(
                 store_prefixed, q.replica_rank, q.replica_world_size
@@ -2054,10 +2062,14 @@ class Manager:
         pass per-DDP-bucket (torchft/ddp.py:47-65, manager.py:222-240). JAX
         grads materialize all at once when the jitted backward finishes, so
         the overlap available here is *between stages*: the grad pytree is
-        split into ~``allreduce_bucket_bytes`` buckets (sized in WIRE
-        bytes), each bucket's leaves packed on device into one contiguous
+        split into buckets (sized in WIRE bytes) — small leaves grouped
+        whole up to ~``allreduce_bucket_bytes``, a leaf wider than
+        ``_SLICE_BYTES`` cut into slices of at most that, one a bucket —
+        each bucket's entries packed on device into one contiguous
         wire-dtype buffer per (accumulator, wire) dtype pair, flowing
-        through four overlapped stages —
+        through four overlapped stages. What nothing overlaps is the
+        first bucket's fetch and the last one's put, so no bucket holds
+        more of a leaf than a slice —
 
             caller thread: 1. pack-dispatch — EVERY bucket's cached jitted
                               pack is dispatched up front and its D2H DMA
@@ -2087,7 +2099,10 @@ class Manager:
             put thread:    4. device scale/put — one H2D transfer of the
                               reduced buffer, then a cached jitted
                               1/n-scale + split + reshape on device
-                              (host-native leaves keep a host scale path).
+                              (host-native leaves keep a host scale path);
+                              a slice is scaled and written into its
+                              leaf's donated assembly buffer, and the
+                              leaf is handed out with its last slice.
 
         The bucket/chunk schedule and its pack/unpack executables are
         memoized on a (treedef, shapes, dtypes, bucket_bytes, wire_dtype)
@@ -2135,6 +2150,11 @@ class Manager:
             release(None)
         agg: Future = Future()
         out_leaves: list = [None] * len(leaves)
+        # Split leaves under assembly on the put thread: [buffer, slices
+        # still out]. A leaf enters out_leaves with its last slice, so
+        # an aborted step (default=tree) never shows half of one.
+        asm: Dict[int, list] = {i: [None, k]
+                                for i, k in sched.slices.items()}
         lock = threading.Lock()
         pending = [len(sched.buckets)]
 
@@ -2155,18 +2175,20 @@ class Manager:
                 put_t0 = time.perf_counter()
                 with self._tracer.span("put", chunks=len(chunks)):
                     scaled = self._put_bucket_chunks(chunks, reduced,
-                                                     leaves, n)
+                                                     leaves, n, asm)
                 self._record(allreduce_put_ms_total=(
                     time.perf_counter() - put_t0) * 1e3)
                 if release is not None:
                     # The put read `reduced` through an H2D transfer
                     # that may still be running (on the CPU backend it
                     # may alias the memory instead); its outputs are
-                    # fresh arrays (scale + split). Once they are ready
-                    # nothing reads `reduced` any more and the next
-                    # step's ring may fold into it. Before `pending`
-                    # falls, so a step ends with its buffers back.
-                    jax.block_until_ready(scaled)
+                    # fresh arrays (scale + split; a split leaf's
+                    # assembly buffer). Once they are ready nothing
+                    # reads `reduced` any more and the next step's ring
+                    # may fold into it. Before `pending` falls, so a
+                    # step ends with its buffers back.
+                    jax.block_until_ready(
+                        (scaled, [st[0] for st in asm.values()]))
                     if sched.fingerprint == self._accum_sig:
                         release(reduced)
                 with lock:
@@ -2219,7 +2241,9 @@ class Manager:
         # (~an extra grad-pytree of wire bytes at peak); jobs tight on
         # HBM can bound that with TORCHFT_ALLREDUCE_STAGE_AHEAD=<K>
         # (stage at most K buckets beyond the one being waited on,
-        # trading overlap for peak memory).
+        # trading overlap for peak memory). A bucket is a group of
+        # small leaves or one slice of a wide one, so K=0 holds one
+        # packed copy of at most max(bucket, slice) bytes at a time.
         n_buckets = len(sched.chunks)
         window = _stage_ahead_window()
         staged: list = [None] * n_buckets
@@ -2255,20 +2279,56 @@ class Manager:
                                                       bufs)
             else:
                 bufs = [_zero_wire_chunk(c, int8) for c in chunks]
+            self._count_ring_op(chunks)
             self._comm.allreduce_wire(
                 bufs, [str(c.orig) for c in chunks], op="sum"
             ).add_done_callback(on_bucket(chunks, time.perf_counter()))
 
         return self.wrap_future(agg, default=tree)
 
+    def _count_ring_op(self, chunks: list) -> None:
+        self._record(
+            allreduce_ring_ops_total=1,
+            allreduce_split_slices_total=int(chunks[0].rows is not None))
+
     def _put_bucket_chunks(self, chunks: list, reduced: list,
-                           leaves: list, n: int) -> Dict[int, Any]:
+                           leaves: list, n: int,
+                           asm: Dict[int, list]) -> Dict[int, Any]:
         """Put stage of one bucket: 1/n-scale each reduced chunk and
         place the leaves back (device leaves via the cached jitted
         unpack + one batched ``device_put``; host leaves scale on
-        host). Returns ``{flat leaf index: placed leaf}``."""
+        host). Returns ``{flat leaf index: placed leaf}`` for the
+        leaves this bucket COMPLETES: a slice of a split leaf goes into
+        that leaf's assembly buffer in ``asm`` (``{leaf index: [buffer,
+        slices still out]}``, this step's own), and the leaf is
+        returned with its last slice — never half-assembled."""
         scaled: Dict[int, Any] = {}
         for c, arr in zip(chunks, reduced):
+            if c.rows is not None:
+                i = c.idx[0]
+                leaf, st = leaves[i], asm[i]
+                if isinstance(leaf, jax.Array):
+                    # ONE H2D transfer of the reduced slice; the jitted
+                    # 1/n + write lands it in the donated leaf-shaped
+                    # buffer (leaf + one slice on the device, where the
+                    # whole-leaf put below holds twice the leaf).
+                    if st[0] is None:
+                        st[0] = jnp.zeros(c.shapes[0], c.orig,
+                                          device=leaf.sharding)
+                    st[0] = _put_slice(c)(
+                        st[0], np.ascontiguousarray(arr),
+                        np.int32(c.rows[1]), n)
+                else:
+                    if st[0] is None:
+                        st[0] = np.empty(c.shapes[0], c.orig)
+                    st[0].reshape(-1)[c.offs[0]:c.offs[0] + c.total] = (
+                        div_by_count(np.asarray(arr), n))
+                st[1] -= 1
+                if st[1] == 0:
+                    out = asm.pop(i)[0]
+                    scaled[i] = (jax.device_put(out, leaf.sharding)
+                                 if isinstance(leaf, jax.Array) else out)
+                continue
             if c.total and all(isinstance(leaves[i], jax.Array)
                                for i in c.idx):
                 # All-device chunk: ONE H2D transfer of the reduced
@@ -2414,7 +2474,8 @@ class Manager:
              str(np.dtype(getattr(leaf, "dtype", None)
                           or np.asarray(leaf).dtype)))
             for leaf in leaves)
-        key = (treedef, metas, self._bucket_bytes, str(self._wire_dtype))
+        key = (treedef, metas, self._bucket_bytes, str(self._wire_dtype),
+               _SLICE_BYTES)
         sched = self._sched_cache.get(key)
         if sched is None:
             # Tiny bound: a training loop has one or two grad signatures;
@@ -2467,7 +2528,12 @@ class Manager:
                     if res is None or int(np.shape(res)[0]) != c.total:
                         res = jnp.zeros(c.total, jnp.float32)
                     packed, new_res = _device_quantize_pack(
-                        [x for _, x in dev], res)
+                        [x for _, x in dev] if c.rows is None
+                        # A slice is cut first, in the leaf's dtype;
+                        # the quantizer then sees it as a small leaf.
+                        else [_pack_leaves([dev[0][1]], str(c.orig),
+                                           c.rows)],
+                        res)
                     # Banked at quantize time, exactly like the host
                     # path's _ef_residuals — an aborted step keeps its
                     # residual either way.
@@ -2482,7 +2548,7 @@ class Manager:
                         wire = c.orig
                         kind = "hostcast"
                     packed = _pack_leaves([x for _, x in dev],
-                                          str(wire))
+                                          str(wire), c.rows)
                     _start_copy_to_host(packed)
                 recs.append((c, dev, packed, kind))
             if dev_quant:
@@ -2589,8 +2655,9 @@ class Manager:
                     seg[:] = fetched[fpos:fpos + k]
                     fpos += k
                 else:
-                    seg[:] = np.ravel(np.asarray(leaves[i])).astype(
-                        c.wire, copy=False)
+                    seg[:] = np.ravel(np.asarray(leaves[i]))[
+                        c.offs[j]:c.offs[j] + c.sizes[j]].astype(
+                            c.wire, copy=False)
             bufs.append(buf)
         if copied:
             self._record(allreduce_host_copy_bytes_total=float(copied))
@@ -2741,6 +2808,7 @@ class Manager:
                                                       bufs)
             else:
                 bufs = [_zero_wire_chunk(c, int8) for c in chunks]
+            self._count_ring_op(chunks)
             self._comm.reduce_scatter_wire(
                 bufs, [str(c.orig) for c in chunks], op="sum"
             ).add_done_callback(
@@ -5183,7 +5251,7 @@ class Manager:
             self._store_server.shutdown()
 
 
-_PACK_FNS: Dict[str, Any] = {}
+_PACK_FNS: Dict[tuple, Any] = {}
 
 # Process-wide fetch-path health counters, surfaced per-Manager in
 # metrics() (the jit caches they instrument are process-wide too):
@@ -5194,6 +5262,9 @@ _PACK_FNS: Dict[str, Any] = {}
 #     the per-step-retrace failure mode BENCH_r05's bf16 fetch collapse
 #     was first suspected to be (ruled out by
 #     tests/test_overlap.py::TestPackFetchPath, which pins it at zero).
+#   put_cache_misses — TRACES of the split leaves' jitted put
+#     (_put_slice), same contract: two a split leaf shape, then none.
+#     Read by the tests only; not in metrics().
 #   d2h_async_fallbacks — buckets whose copy_to_host_async did NOT run
 #     (API absent or transient failure): their D2H serializes into the
 #     fetch-wait stage instead of overlapping the ring.
@@ -5203,6 +5274,7 @@ _PACK_FNS: Dict[str, Any] = {}
 #     signature; a climbing count means the digest is recompiling every
 #     boundary and its <2% overhead budget is gone.
 _PACK_STATS: Dict[str, int] = {"pack_cache_misses": 0,
+                               "put_cache_misses": 0,
                                "d2h_async_fallbacks": 0,
                                "sdc_digest_cache_misses": 0}
 # Incremented from concurrent Manager worker threads (and jit tracing);
@@ -5246,7 +5318,8 @@ def _transfer_dtype(wire: Any) -> Optional[np.dtype]:
     return np.dtype(f"u{d.itemsize}")
 
 
-def _pack_leaves(leaves: list, wire_dtype_str: str) -> Any:
+def _pack_leaves(leaves: list, wire_dtype_str: str,
+                 rows: Optional[tuple] = None) -> Any:
     """Pack device leaves into ONE contiguous 1-D device array in the
     wire dtype, via a cached jitted concat — so the subsequent
     ``device_get`` pays a single transfer round trip for the whole chunk
@@ -5256,25 +5329,50 @@ def _pack_leaves(leaves: list, wire_dtype_str: str) -> Any:
     uint carrier in the same fused dispatch so the transfer itself never
     leaves the runtime's raw-bytes fast path (:func:`_transfer_dtype`);
     :meth:`Manager._wait_bucket` views the bits back, a zero-copy
-    bitwise identity."""
-    fn = _PACK_FNS.get(wire_dtype_str)
+    bitwise identity.
+
+    ``rows = (lead, first row, row count)`` packs one slice of a single
+    split leaf instead (:func:`_row_view`): the first row is TRACED, so
+    a leaf costs one program for its full slices and one for its tail
+    however many slices it has, and the cut is made on the leaf's first
+    axis, so no leaf-sized copy is made on the way."""
+    if rows is None:
+        return _pack_fn(wire_dtype_str)(leaves)
+    lead, first, count = rows
+    return _pack_fn(wire_dtype_str, lead, count)(leaves[0],
+                                                 np.int32(first))
+
+
+def _pack_fn(wire_dtype_str: str, lead: Optional[int] = None,
+             count: int = 0) -> Any:
+    """The cached jitted pack behind :func:`_pack_leaves`: of a list of
+    whole leaves, or (``lead`` given) of ``count`` rows of one leaf
+    from a traced first row."""
+    key = (wire_dtype_str, lead, count)
+    fn = _PACK_FNS.get(key)
     if fn is None:
         wire = jnp.dtype(wire_dtype_str)
         carrier = _transfer_dtype(wire)
 
-        def pack(ls):
+        def pack(parts):
             # Trace-time side effect: runs when jit COMPILES this
             # signature, never on steady-state dispatch — i.e. it counts
             # pack-executable cache misses.
             _pack_stat_bump("pack_cache_misses")
             buf = jnp.concatenate(
-                [jnp.ravel(x).astype(wire) for x in ls])
+                [jnp.ravel(x).astype(wire) for x in parts])
             if carrier is not None:
                 buf = jax.lax.bitcast_convert_type(buf, carrier)
             return buf
 
-        fn = _PACK_FNS[wire_dtype_str] = jax.jit(pack)
-    return fn(leaves)
+        def pack_rows(x, first):
+            view = x.reshape((-1,) + x.shape[lead:])
+            return pack([jax.lax.dynamic_slice_in_dim(
+                view, first, count, axis=0)])
+
+        fn = _PACK_FNS[key] = jax.jit(pack if lead is None
+                                      else pack_rows)
+    return fn
 
 
 _ATTEST_FNS: Dict[str, Any] = {}
@@ -5508,33 +5606,47 @@ def _start_copy_to_host(arr: Any) -> None:
 
 
 class _ChunkPlan:
-    """Geometry of one packed ring chunk: the leaves (by flat index) that
-    concatenate into a single contiguous 1-D wire buffer of one
-    (accumulator, wire) dtype pair. Pure metadata, so every rank derives
-    identical plans; doubles as the cache key source for the chunk's
-    jitted unpack executable (:func:`_unpack_scale`)."""
+    """Geometry of one packed ring chunk: the entries ``(leaf flat index,
+    element offset, element count)`` that concatenate into a single
+    contiguous 1-D wire buffer of one (accumulator, wire) dtype pair.
+    A chunk either holds whole leaves (every offset 0, every count the
+    leaf's size; ``rows`` is None) or is ONE slice of a leaf wider than
+    :data:`_SLICE_BYTES`: ``rows = (lead, first row, row count)`` of the
+    leaf viewed as ``(-1,) + shape[lead:]`` (:func:`_row_view`), the
+    same elements as ``offs[0]`` / ``sizes[0]`` say. Pure metadata,
+    so every rank derives identical plans; doubles as the cache key
+    source for the chunk's jitted unpack executable
+    (:func:`_unpack_scale` / :func:`_put_slice`)."""
 
-    __slots__ = ("orig", "wire", "idx", "sizes", "shapes", "total")
+    __slots__ = ("orig", "wire", "idx", "offs", "sizes", "shapes", "total",
+                 "rows")
 
     def __init__(self, orig: np.dtype, wire: np.dtype) -> None:
         self.orig = orig
         self.wire = wire
         self.idx: list = []
+        self.offs: list = []
         self.sizes: list = []
         self.shapes: list = []
         self.total = 0
+        self.rows: Optional[tuple] = None
 
 
 class _AllreduceSchedule:
-    """Memoized bucket/chunk schedule for one grad-pytree signature."""
+    """Memoized bucket/chunk schedule for one grad-pytree signature.
+    ``buckets[b]`` lists bucket b's leaf indices (a split leaf's index
+    repeats, once a slice), ``chunks[b]`` its :class:`_ChunkPlan` s,
+    ``slices[i]`` how many slices leaf i was cut into (split leaves
+    only)."""
 
-    __slots__ = ("buckets", "chunks", "fingerprint")
+    __slots__ = ("buckets", "chunks", "fingerprint", "slices")
 
     def __init__(self, buckets: list, chunks: list,
-                 fingerprint: str) -> None:
+                 fingerprint: str, slices: Dict[int, int]) -> None:
         self.buckets = buckets
         self.chunks = chunks
         self.fingerprint = fingerprint
+        self.slices = slices
 
 
 def _wire_pair(dtype: Any, wire: Optional[np.dtype]) -> tuple:
@@ -5556,23 +5668,22 @@ def _derive_schedule(metas: tuple, bucket_bytes: int,
     boundaries. Buckets are sized in WIRE bytes (compressed sizes) so
     each bucket moves ~bucket_bytes over the D2H leg it amortizes;
     within a bucket, leaves group into one chunk per (accumulator, wire)
-    dtype pair in first-occurrence order. ``fingerprint`` is a stable
-    string of the resulting geometry (the cross-rank determinism test
-    compares it directly)."""
+    dtype pair in first-occurrence order. A leaf wider than
+    :data:`_SLICE_BYTES` on the wire is cut into consecutive slices, each
+    a bucket of one chunk (:func:`_make_buckets`). ``fingerprint`` is a
+    stable string of the resulting geometry, offsets included (the
+    cross-rank determinism test compares it directly)."""
     wire = np.dtype(wire_dtype) if wire_dtype is not None else None
     pairs = [_wire_pair(dt, wire) for _, dt in metas]
-    # `or 1` is advisory bucket sizing only (a scalar still costs a
-    # dispatch); the TRUE element counts below keep 0-size leaves at 0 —
-    # an `or 1` there would make participants' packed buffers one
-    # element longer than their sizes sum and wedge the ring.
-    adv = [int(np.prod(shape) or 1) * pairs[i][1].itemsize
-           for i, (shape, _) in enumerate(metas)]
-    buckets = _make_buckets(adv, bucket_bytes)
+    entries = _make_buckets([shape for shape, _ in metas],
+                            [p[1].itemsize for p in pairs],
+                            bucket_bytes, _SLICE_BYTES)
     chunks: list = []
-    for idx in buckets:
+    slices: Dict[int, int] = {}
+    for bucket in entries:
         by_key: Dict[tuple, _ChunkPlan] = {}
         cs: list = []
-        for i in idx:
+        for i, off, count, rows in bucket:
             orig, wdt = pairs[i]
             key = (str(orig), str(wdt))
             c = by_key.get(key)
@@ -5580,16 +5691,24 @@ def _derive_schedule(metas: tuple, bucket_bytes: int,
                 c = by_key[key] = _ChunkPlan(orig, wdt)
                 cs.append(c)
             c.idx.append(i)
-            c.sizes.append(int(np.prod(metas[i][0])))
+            c.offs.append(off)
+            c.sizes.append(count)
             c.shapes.append(tuple(metas[i][0]))
+            if rows is not None:  # a slice is alone in its bucket
+                c.rows = rows
+                slices[i] = slices.get(i, 0) + 1
         for c in cs:
             c.total = int(sum(c.sizes))
         chunks.append(cs)
-    fingerprint = "wire-v2|" + "|".join(
-        ";".join(f"{c.orig}:{c.wire}:{','.join(map(str, c.sizes))}"
-                 for c in cs)
+    fingerprint = "wire-v3|" + "|".join(
+        ";".join(
+            f"{c.orig}:{c.wire}:" + ",".join(
+                f"{i}@{off}+{n}"
+                for i, off, n in zip(c.idx, c.offs, c.sizes))
+            for c in cs)
         for cs in chunks)
-    return _AllreduceSchedule(buckets, chunks, fingerprint)
+    buckets = [[e[0] for e in bucket] for bucket in entries]
+    return _AllreduceSchedule(buckets, chunks, fingerprint, slices)
 
 
 _UNPACK_FNS: Dict[tuple, Any] = {}
@@ -5604,11 +5723,7 @@ def _unpack_scale(chunk: _ChunkPlan) -> Any:
     key = (str(chunk.orig), tuple(chunk.sizes), tuple(chunk.shapes))
     fn = _UNPACK_FNS.get(key)
     if fn is None:
-        if len(_UNPACK_FNS) >= 64:
-            # Same shape-churn bound as the schedule cache: a caller
-            # whose grad shapes change every step must not leak one
-            # jitted executable per geometry forever.
-            _UNPACK_FNS.clear()
+        _bound_unpack_fns()
         splits = np.cumsum(chunk.sizes)[:-1].tolist()
         shapes = tuple(chunk.shapes)
 
@@ -5619,6 +5734,42 @@ def _unpack_scale(chunk: _ChunkPlan) -> Any:
 
         fn = _UNPACK_FNS[key] = jax.jit(unpack)
     return fn
+
+
+def _put_slice(chunk: _ChunkPlan) -> Any:
+    """Cached jitted put of one slice of a split leaf: H2D the reduced
+    slice, 1/n, and write it into the leaf-shaped assembly buffer, which
+    is DONATED — the leaf is assembled in place as its slices arrive,
+    so the device never holds more than the leaf and one slice (the
+    whole-leaf put held the reduced copy and the scaled output, twice
+    the leaf). The first row and ``n`` are traced: two programs a leaf
+    shape (full slices, tail) whatever the slice count."""
+    shape = chunk.shapes[0]
+    lead, _, count = chunk.rows
+    key = (str(chunk.orig), shape, lead, count)
+    fn = _UNPACK_FNS.get(key)
+    if fn is None:
+        _bound_unpack_fns()
+
+        def put(buf, upd, first, n):
+            # Trace-time tripwire like the packs': a put that compiles
+            # after the first step of a gradient signature is a retrace.
+            _pack_stat_bump("put_cache_misses")
+            view = buf.reshape((-1,) + shape[lead:])
+            upd = div_by_count(upd, n).reshape((count,) + shape[lead:])
+            return jax.lax.dynamic_update_slice_in_dim(
+                view, upd, first, axis=0).reshape(shape)
+
+        fn = _UNPACK_FNS[key] = jax.jit(put, donate_argnums=0)
+    return fn
+
+
+def _bound_unpack_fns() -> None:
+    # Same shape-churn bound as the schedule cache: a caller whose grad
+    # shapes change every step must not leak one jitted executable per
+    # geometry forever.
+    if len(_UNPACK_FNS) >= 64:
+        _UNPACK_FNS.clear()
 
 
 class ShardedGrads:
@@ -5668,20 +5819,22 @@ class ShardedGrads:
             lo, hi = int(bd[self.rank]), int(bd[self.rank + 1])
             pieces = []
             off = 0
-            for i, size in zip(c.idx, c.sizes):
+            for i, start, size in zip(c.idx, c.offs, c.sizes):
                 a, b = max(lo, off), min(hi, off + size)
                 if a < b:
+                    # The entry is elements [start, start + size) of
+                    # its leaf (a whole leaf, or one slice of a split
+                    # one), sitting at [off, off + size) of the chunk.
+                    a, b = a - off + start, b - off + start
                     leaf = pleaves[i]
                     if isinstance(leaf, jax.Array):
                         # Slice on device: only this rank's 1/world of
                         # the leaf's bytes crosses D2H, not the whole
                         # leaf — the sharded update's memory/transfer
                         # win must hold on the params side too.
-                        pieces.append(np.asarray(
-                            jnp.ravel(leaf)[a - off:b - off]))
+                        pieces.append(np.asarray(jnp.ravel(leaf)[a:b]))
                     else:
-                        flat = np.ravel(np.asarray(leaf))
-                        pieces.append(flat[a - off:b - off])
+                        pieces.append(np.ravel(np.asarray(leaf))[a:b])
                 off += size
             out.append(
                 np.concatenate(pieces).astype(c.orig, copy=False)
@@ -5698,6 +5851,15 @@ class ShardedGrads:
         out_leaves = list(pleaves)
         put_idx: list = []
         put_vals: list = []
+
+        def place(i: int, val: np.ndarray) -> None:
+            if isinstance(pleaves[i], jax.Array):
+                put_idx.append(i)
+                put_vals.append(val)
+            else:
+                out_leaves[i] = val
+
+        split: Dict[int, np.ndarray] = {}  # leaves coming back in slices
         for k, c in enumerate(self.chunks):
             full = np.empty(c.total, c.orig)
             bd = shard_bounds(c.total, self.world)
@@ -5711,14 +5873,17 @@ class ShardedGrads:
                         f"for chunk {k}; geometry expects {want} — "
                         "mismatched shard_update config across groups?")
                 full[bd[r]:bd[r + 1]] = seg
+            if c.rows is not None:
+                whole = split.get(c.idx[0])
+                if whole is None:
+                    whole = split[c.idx[0]] = np.empty(c.shapes[0], c.orig)
+                whole.reshape(-1)[c.offs[0]:c.offs[0] + c.total] = full
+                continue
             parts = np.split(full, np.cumsum(c.sizes)[:-1])
             for i, shape, part in zip(c.idx, c.shapes, parts):
-                val = part.reshape(shape)
-                if isinstance(pleaves[i], jax.Array):
-                    put_idx.append(i)
-                    put_vals.append(val)
-                else:
-                    out_leaves[i] = val
+                place(i, part.reshape(shape))
+        for i, whole in split.items():
+            place(i, whole)
         if put_idx:
             placed = jax.device_put(
                 put_vals, [pleaves[i].sharding for i in put_idx])
@@ -5756,16 +5921,65 @@ def _zero_like(leaf: Any) -> np.ndarray:
     )
 
 
-def _make_buckets(sizes: list, bucket_bytes: int) -> list:
-    """Greedy split of per-leaf byte sizes into index buckets of >=
-    ``bucket_bytes`` each (except possibly the last), preserving leaf order
-    so every rank produces an identical bucket schedule."""
+# S: the most wire bytes one unit of the exchange pipeline (stage ->
+# fetch -> ring -> put) may hold of a single leaf. A leaf wider than
+# this is cut into slices (docs/design/allreduce_pipeline.md, "Slices"):
+# the first fetch and the last put, which nothing overlaps, shrink from
+# the widest leaf to one slice. A constant, not an option: every group
+# must cut alike. Its value is from a sweep on the chip (PERF.md, PR
+# 30): smaller slices pay a few ms an op, and from 32 MiB up the host
+# buffer a slice is fetched into is never-touched pages every time
+# (glibc's largest mmap threshold), a D2H at 0.8 GB/s instead of 4.7.
+_SLICE_BYTES = 24 << 20
+
+
+def _row_view(shape: tuple, itemsize: int, cap_bytes: int) -> tuple:
+    """How a leaf too wide for one slice is cut: ``(lead, rows per
+    slice)``. The leaf is viewed as ``(-1,) + shape[lead:]`` with as many
+    trailing axes kept whole as fit ``cap_bytes``, and a slice is a run
+    of rows of that view — merging leading axes and cutting the first
+    one moves no data on the device, where a ravel of the whole leaf is
+    a leaf-sized copy."""
+    cap = max(cap_bytes // itemsize, 1)
+    lead, row = len(shape), 1
+    while lead > 0 and row * shape[lead - 1] <= cap:
+        lead -= 1
+        row *= shape[lead]
+    return lead, cap // row
+
+
+def _make_buckets(shapes: list, itemsizes: list, bucket_bytes: int,
+                  slice_bytes: int) -> list:
+    """Greedy split of the flattened tree into buckets of entries
+    ``(leaf index, element offset, element count, rows)``, preserving
+    leaf order so every rank produces an identical schedule. Leaves of
+    at most ``slice_bytes`` (wire bytes) group whole (``rows`` None)
+    until a bucket holds >= ``bucket_bytes``; a wider leaf closes the
+    open bucket and becomes consecutive single-entry buckets, one a
+    slice of at most ``slice_bytes``: ``rows = (lead, first row, row
+    count)`` of :func:`_row_view`, the last one shorter."""
     buckets: list = []
     cur: list = []
     cur_bytes = 0
-    for i, nbytes in enumerate(sizes):
-        cur.append(i)
-        cur_bytes += int(nbytes)
+    for i, (shape, itemsize) in enumerate(zip(shapes, itemsizes)):
+        # TRUE element counts: a 0-size leaf stays at 0 (an `or 1` here
+        # would make participants' packed buffers one element longer
+        # than their sizes sum and wedge the ring); `or 1` is advisory
+        # bucket sizing only (a scalar still costs a dispatch).
+        n = int(np.prod(shape, dtype=np.int64))
+        if n * itemsize > slice_bytes:
+            if cur:
+                buckets.append(cur)
+                cur, cur_bytes = [], 0
+            lead, per = _row_view(tuple(shape), itemsize, slice_bytes)
+            row = int(np.prod(shape[lead:], dtype=np.int64))
+            for first in range(0, n // row, per):
+                count = min(per, n // row - first)
+                buckets.append([(i, first * row, count * row,
+                                 (lead, first, count))])
+            continue
+        cur.append((i, 0, n, None))
+        cur_bytes += (n or 1) * itemsize
         if cur_bytes >= bucket_bytes:
             buckets.append(cur)
             cur, cur_bytes = [], 0

@@ -162,9 +162,9 @@ class FTOptimizer:
         for c in chunks:
             buf = np.empty(c.total, c.orig)
             off = 0
-            for i, size in zip(c.idx, c.sizes):
-                buf[off:off + size] = np.ravel(
-                    np.asarray(leaves[i])).astype(c.orig, copy=False)
+            for i, start, size in zip(c.idx, c.offs, c.sizes):
+                buf[off:off + size] = np.ravel(np.asarray(leaves[i]))[
+                    start:start + size].astype(c.orig, copy=False)
                 off += size
             shards.append(buf)
         return ShardedGrads(chunks, shards, 0, 1, leaves, treedef)
