@@ -28,6 +28,8 @@ sys.path.insert(0, str(REPO))
 # plus the TPU-native additions.
 MODULES = [
     ("torchft_tpu.manager", "Per-step fault-tolerance state machine"),
+    ("torchft_tpu.exchange", "Cross-group gradient exchange (schedule, "
+                             "pack, stage, ring, put)"),
     ("torchft_tpu.communicator", "Resizable cross-group communicators"),
     ("torchft_tpu.backends.host", "Elastic host TCP ring backend"),
     ("torchft_tpu.backends.mesh", "On-device full-membership backend"),

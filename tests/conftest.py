@@ -28,6 +28,16 @@ from torchft_tpu.utils import force_cpu_devices  # noqa: E402
 
 force_cpu_devices(8)
 
+import threading  # noqa: E402
+from collections import Counter  # noqa: E402
+from concurrent.futures import ThreadPoolExecutor  # noqa: E402
+
+import jax  # noqa: E402
+
+from torchft_tpu.communicator import DummyCommunicator  # noqa: E402
+from torchft_tpu.exchange import GradExchange  # noqa: E402
+from torchft_tpu.tracing import Tracer  # noqa: E402
+
 
 _NATIVE_AVAILABLE = None
 
@@ -113,3 +123,55 @@ def time_limit(seconds, name):
 def _test_time_limit(request):
     with time_limit(TEST_LIMIT_S, request.node.nodeid):
         yield
+
+
+class ExchangeRig:
+    """One :class:`~torchft_tpu.exchange.GradExchange` with what a
+    ``Manager`` would lend it (a tracer, a counter sink, a put thread,
+    the gauge) and no ``Manager``, control plane or store: for tests of
+    the schedule, pack, stage, wait, put or the exchange as a whole."""
+
+    def __init__(self, comm=None, **kw):
+        self.tracer = Tracer(steps=8, enabled=True)
+        self.counters = Counter()
+        self.gauge = []
+        self._lock = threading.Lock()
+        self.executor = ThreadPoolExecutor(
+            max_workers=1, thread_name_prefix="allreduce_put")
+        args = dict(bucket_bytes=4 << 20, wire_dtype=None, wire_rung=0,
+                    device_quant=True)
+        args.update(kw)
+        self.x = GradExchange(
+            comm if comm is not None else DummyCommunicator(),
+            self.tracer, self.record, self.executor, self.gauge.append,
+            **args)
+
+    def record(self, **deltas):
+        with self._lock:
+            self.counters.update(deltas)
+
+    def run(self, op, tree, facts):
+        """One step of ``op`` ("allreduce" / "reduce_scatter"): the
+        result and what a failed step would have resolved to."""
+        leaves, treedef = jax.tree_util.tree_flatten(tree)
+        fut, default_fn = getattr(self.x, op)(facts, tree, leaves,
+                                              treedef)
+        return fut.result(timeout=60), default_fn
+
+    def close(self):
+        self.executor.shutdown(wait=True)
+
+
+@pytest.fixture
+def exchange_rig():
+    """``exchange_rig(comm=None, **GradExchange keywords)`` builds an
+    :class:`ExchangeRig`; every one is closed after the test."""
+    rigs = []
+
+    def make(comm=None, **kw):
+        rigs.append(ExchangeRig(comm, **kw))
+        return rigs[-1]
+
+    yield make
+    for r in rigs:
+        r.close()

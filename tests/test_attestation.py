@@ -39,7 +39,7 @@ from torchft_tpu import chaos, fleet, serialization
 from torchft_tpu.chaos import ChaosSchedule, EndpointChaos
 from torchft_tpu.checkpointing import CheckpointServer
 from torchft_tpu.fleet import FleetAggregator, StepDigest
-from torchft_tpu.manager import (_PACK_STATS, _addr_base,
+from torchft_tpu.manager import (_ATTEST_STATS, _addr_base,
                                  _attest_device_words)
 from torchft_tpu.policy import PhasedChaos
 
@@ -128,13 +128,13 @@ class TestDigestKernel:
         (trace-time bumps), not calls."""
         leaves = [jax.device_put(np.arange(11, dtype=np.float32))]
         _attest_device_words(leaves)  # warm (may or may not compile)
-        before = _PACK_STATS["sdc_digest_cache_misses"]
+        before = _ATTEST_STATS["sdc_digest_cache_misses"]
         for _ in range(5):
             _attest_device_words(leaves)  # cached: no new trace
-        assert _PACK_STATS["sdc_digest_cache_misses"] == before
+        assert _ATTEST_STATS["sdc_digest_cache_misses"] == before
         fresh = [jax.device_put(np.arange(13, dtype=np.float32))]
         _attest_device_words(fresh)  # new signature: exactly one trace
-        assert _PACK_STATS["sdc_digest_cache_misses"] == before + 1
+        assert _ATTEST_STATS["sdc_digest_cache_misses"] == before + 1
 
     def test_manager_digest_host_fallback_matches_reference(self):
         m = make_manager(

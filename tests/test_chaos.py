@@ -320,10 +320,10 @@ class TestSplitLeafShortRead:
         import jax.numpy as jnp
 
         import test_shard
-        import torchft_tpu.manager as manager_mod
+        import torchft_tpu.exchange as exchange_mod
         from torchft_tpu.backends.host import _Ring
 
-        monkeypatch.setattr(manager_mod, "_SLICE_BYTES", 1024)
+        monkeypatch.setattr(exchange_mod, "_SLICE_BYTES", 1024)
         sched = ChaosSchedule(seed=0, intensity=0.0, endpoints={
             "ring": EndpointChaos(short_rate=1.0, max_faults=1)})
 

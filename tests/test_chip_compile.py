@@ -175,6 +175,7 @@ def test_boundary_programs_over_the_depth2_state(one_chip):
     """The attestation digest over params + adam state (7.45 GiB) and the
     int8 quantize-pack of the largest leaf (the 500 MB embedding): their
     ravel/concatenate must not need a second copy of what they read."""
+    from torchft_tpu import exchange as exchange_mod
     from torchft_tpu import manager as manager_mod
 
     _, pshape = _model(2, None)
@@ -194,8 +195,8 @@ def test_boundary_programs_over_the_depth2_state(one_chip):
     assert m.temp_size_in_bytes < 64 * 2 ** 20
     assert m.output_size_in_bytes <= 1024
 
-    manager_mod._device_quantize_pack(tiny, jnp.zeros((8,), jnp.float32))
-    (quant,) = [f for f in manager_mod._DEV_QUANT_FNS.values()]
+    exchange_mod._device_quantize_pack(tiny, jnp.zeros((8,), jnp.float32))
+    (quant,) = [f for f in exchange_mod._DEV_QUANT_FNS.values()]
     big = max(jax.tree_util.tree_leaves(_shaped(pshape, one_chip)),
               key=lambda l: int(np.prod(l.shape)))
     n = int(np.prod(big.shape))
@@ -218,26 +219,26 @@ def test_slice_programs_copy_no_leaf(one_chip, shape):
     (a ravel of the whole leaf before the cut does: 500 MiB for the
     embedding), or two groups' exchange no longer fits beside their
     state."""
-    from torchft_tpu import manager as manager_mod
+    from torchft_tpu import exchange as exchange_mod
 
-    sched = manager_mod._derive_schedule(((shape, "float32"),), 4 << 20,
+    sched = exchange_mod._derive_schedule(((shape, "float32"),), 4 << 20,
                                          None)
     leaf_bytes = int(np.prod(shape)) * 4
     assert sched.slices[0] == -(-leaf_bytes // (
-        manager_mod._SLICE_BYTES // (shape[1] * 4) * shape[1] * 4))
+        exchange_mod._SLICE_BYTES // (shape[1] * 4) * shape[1] * 4))
     full, tail = sched.chunks[0][0], sched.chunks[-1][0]
     leaf = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
     scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     for c in (full, tail):
         lead, _, count = c.rows
-        assert c.total * 4 <= manager_mod._SLICE_BYTES
-        pack = manager_mod._pack_fn("float32", lead, count)
+        assert c.total * 4 <= exchange_mod._SLICE_BYTES
+        pack = exchange_mod._pack_fn("float32", lead, count)
         m = pack.lower(leaf, scalar).compile().memory_analysis()
         assert m.temp_size_in_bytes < 2 ** 20
         assert m.output_size_in_bytes == c.total * 4
         upd = jax.ShapeDtypeStruct((c.total,), jnp.float32,
                                    sharding=one_chip)
-        put = manager_mod._put_slice(c)
+        put = exchange_mod._put_slice(c)
         m = put.lower(leaf, upd, scalar, scalar).compile().memory_analysis()
         assert m.alias_size_in_bytes == leaf_bytes  # assembled in place
         assert m.temp_size_in_bytes < 2 ** 20

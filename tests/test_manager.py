@@ -17,7 +17,8 @@ import pytest
 import conftest
 from mockplane import make_manager, quorum_result
 from torchft_tpu.communicator import DummyCommunicator
-from torchft_tpu.manager import Manager, WorldSizeMode, _derive_schedule
+from torchft_tpu.exchange import _derive_schedule
+from torchft_tpu.manager import Manager, WorldSizeMode
 
 requires_native = conftest.requires_native()
 
@@ -910,7 +911,8 @@ class TestSchedule:
         assert any(str(c.wire) == "int64" for cs in wire.chunks
                    for c in cs)
 
-    def test_schedule_cached_across_participant_and_healer_views(self):
+    def test_schedule_cached_across_participant_and_healer_views(
+            self, exchange_rig):
         """Participant (device leaves), healer, and spare (host zero
         leaves) ranks must land on ONE cached schedule: the cache key is
         metadata-only, so the same object — hence byte-identical chunk
@@ -919,22 +921,15 @@ class TestSchedule:
 
         from torchft_tpu.manager import _zero_like
 
-        client = MagicMock()
-        client.quorum.return_value = quorum_result()
-        client.should_commit.return_value = True
-        m = make_manager(client, allreduce_bucket_bytes=64,
-                         allreduce_wire_dtype=jnp.bfloat16)
-        try:
-            tree = {"a": jnp.ones((9, 3), jnp.float32),
-                    "b": jnp.zeros((40,), jnp.float32)}
-            leaves, treedef = jax.tree_util.tree_flatten(tree)
-            healer_leaves = [_zero_like(x) for x in leaves]
-            s_part = m._get_schedule(treedef, leaves)
-            s_heal = m._get_schedule(treedef, healer_leaves)
-            assert s_part is s_heal  # one cache entry, identical geometry
-            assert m._get_schedule(treedef, leaves) is s_part  # steady state
-        finally:
-            m.shutdown()
+        x = exchange_rig(bucket_bytes=64, wire_dtype=jnp.bfloat16).x
+        tree = {"a": jnp.ones((9, 3), jnp.float32),
+                "b": jnp.zeros((40,), jnp.float32)}
+        leaves, treedef = jax.tree_util.tree_flatten(tree)
+        healer_leaves = [_zero_like(a) for a in leaves]
+        s_part = x.schedule(treedef, leaves)
+        s_heal = x.schedule(treedef, healer_leaves)
+        assert s_part is s_heal  # one cache entry, identical geometry
+        assert x.schedule(treedef, leaves) is s_part  # steady state
 
 
 def _make_test_rings(world):

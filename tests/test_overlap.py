@@ -47,8 +47,8 @@ from mockplane import make_manager, quorum_result
 from test_manager import _make_test_rings, _wired_comm
 from torchft_tpu.backends.host import HostCommunicator
 from torchft_tpu.communicator import DummyCommunicator
-from torchft_tpu.manager import (Manager, _PACK_STATS, _pack_leaves,
-                                 _transfer_dtype)
+from torchft_tpu.exchange import _pack_leaves, _transfer_dtype
+from torchft_tpu.manager import Manager
 from torchft_tpu.optim import DelayedOptimizer
 from torchft_tpu.parallel import FTTrainer
 

@@ -216,7 +216,7 @@ class TestInt8Quantizer:
                 out = m.allreduce({"g": step_vals.copy()}).result()
                 assert np.isfinite(np.asarray(out["g"])).all()
                 assert m.should_commit()
-            for r in m._ef_residuals.values():
+            for r in m._exchange._ef_residuals.values():
                 assert np.isfinite(r).all()
         finally:
             m.shutdown()
@@ -467,10 +467,10 @@ class TestManagerPolicy:
         try:
             assert m.set_policy(POLICIES["sync-int8"], reason="test")
             assert m.policy().name == "sync-int8"
-            assert m._wire_dtype is None
+            assert m._exchange.wire_dtype is None
             assert m.set_policy(POLICIES["overlap-bf16"])
             assert m.overlap_steps() == 1
-            assert str(m._wire_dtype) == "bfloat16"
+            assert str(m._exchange.wire_dtype) == "bfloat16"
             mx = m.metrics()
             assert mx["policy_switches_total"] == 2
             assert m.metrics_info()["policy_name"] == "overlap-bf16"

@@ -25,7 +25,8 @@ from torchft_tpu.chaos import ChaosSchedule, EndpointChaos
 from torchft_tpu.checkpointing import CheckpointServer
 from torchft_tpu.communicator import (Communicator, _slice_shards,
                                       shard_bounds)
-from torchft_tpu.manager import ShardedGrads, _stripe_seed
+from torchft_tpu.exchange import ShardedGrads
+from torchft_tpu.manager import _stripe_seed
 from torchft_tpu.optim import FTOptimizer
 
 pytestmark = pytest.mark.shard

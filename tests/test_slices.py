@@ -20,11 +20,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import torchft_tpu.manager as manager_mod
+import torchft_tpu.exchange as exchange_mod
 from torchft_tpu import policy as policy_mod
-from torchft_tpu.manager import (_PACK_STATS, Manager, ShardedGrads,
-                                 _derive_schedule, _row_view, _zero_like,
-                                 _zero_wire_chunk)
+from torchft_tpu.exchange import (_PACK_STATS, GradExchange, ShardedGrads,
+                                  _derive_schedule, _row_view,
+                                  _zero_wire_chunk)
+from torchft_tpu.manager import _zero_like
 
 from mockplane import make_manager
 from test_shard import _run_managers
@@ -34,7 +35,7 @@ SLICE = 1024  # bytes: what _SLICE_BYTES is patched to in this file
 
 @pytest.fixture
 def small_slices(monkeypatch):
-    monkeypatch.setattr(manager_mod, "_SLICE_BYTES", SLICE)
+    monkeypatch.setattr(exchange_mod, "_SLICE_BYTES", SLICE)
 
 
 # ------------------------------------------------------------ the schedule
@@ -141,14 +142,14 @@ class TestSliceSchedule:
         got = _derive_schedule(metas, 256, WIRES[wire])
         wdt = None if WIRES[wire] is None else np.dtype(WIRES[wire])
         adv = [int(np.prod(s) or 1)
-               * manager_mod._wire_pair(dt, wdt)[1].itemsize
+               * exchange_mod._wire_pair(dt, wdt)[1].itemsize
                for s, dt in metas]
         assert got.buckets == _old_make_buckets(adv, 256)
         assert not got.slices
         assert all(c.rows is None and not any(c.offs)
                    for cs in got.chunks for c in cs)
         # and the slice size is nothing to it
-        monkeypatch.setattr(manager_mod, "_SLICE_BYTES", 1 << 40)
+        monkeypatch.setattr(exchange_mod, "_SLICE_BYTES", 1 << 40)
         wide = _derive_schedule(metas, 256, WIRES[wire])
         assert wide.fingerprint == got.fingerprint
 
@@ -159,49 +160,42 @@ class TestSliceSchedule:
         again = _derive_schedule(metas, 256, None)
         assert split.fingerprint == again.fingerprint
         assert split.buckets == again.buckets
-        monkeypatch.setattr(manager_mod, "_SLICE_BYTES", 2 * SLICE)
+        monkeypatch.setattr(exchange_mod, "_SLICE_BYTES", 2 * SLICE)
         other = _derive_schedule(metas, 256, None)
-        monkeypatch.setattr(manager_mod, "_SLICE_BYTES", 1 << 40)
+        monkeypatch.setattr(exchange_mod, "_SLICE_BYTES", 1 << 40)
         unsplit = _derive_schedule(metas, 256, None)
         assert not unsplit.slices
         assert len({split.fingerprint, other.fingerprint,
                     unsplit.fingerprint}) == 3
 
     @pytest.mark.parametrize("wire", list(WIRES))
-    def test_every_role_derives_one_geometry(self, wire):
+    def test_every_role_derives_one_geometry(self, wire, exchange_rig):
         """Participant (device leaves), healer and spare (host zeros)
         land on ONE cached schedule, and the zero contribution of each
         chunk has the slice's length and wire dtype."""
-        mkw = {} if WIRES[wire] is None else {
-            "allreduce_wire_dtype": WIRES[wire]}
-        m = make_manager(allreduce_bucket_bytes=256, **mkw)
-        try:
-            tree = {"a": jnp.ones((257, 3), jnp.float32),
-                    "b": jnp.zeros((40,), jnp.float32),
-                    "i": jnp.arange(600, dtype=jnp.int32)}
-            leaves, treedef = jax.tree_util.tree_flatten(tree)
-            part = m._get_schedule(treedef, leaves)
-            heal = m._get_schedule(treedef, [_zero_like(x) for x in leaves])
-            assert part is heal
-            assert part.slices == {0: 4 if wire == "exact" else 2, 2: 3}
-            for cs in part.chunks:
-                for c in cs:
-                    z = _zero_wire_chunk(c, False)
-                    assert z.shape == (c.total,) and z.dtype == c.wire
-                    assert not z.any()
-        finally:
-            m.shutdown()
+        x = exchange_rig(bucket_bytes=256, wire_dtype=WIRES[wire]).x
+        tree = {"a": jnp.ones((257, 3), jnp.float32),
+                "b": jnp.zeros((40,), jnp.float32),
+                "i": jnp.arange(600, dtype=jnp.int32)}
+        leaves, treedef = jax.tree_util.tree_flatten(tree)
+        part = x.schedule(treedef, leaves)
+        heal = x.schedule(treedef, [_zero_like(x) for x in leaves])
+        assert part is heal
+        assert part.slices == {0: 4 if wire == "exact" else 2, 2: 3}
+        for cs in part.chunks:
+            for c in cs:
+                z = _zero_wire_chunk(c, False)
+                assert z.shape == (c.total,) and z.dtype == c.wire
+                assert not z.any()
 
-    def test_schedule_cache_is_keyed_by_the_slice_size(self, monkeypatch):
-        m = make_manager(allreduce_bucket_bytes=256)
-        try:
-            leaves, treedef = jax.tree_util.tree_flatten(
-                {"a": np.ones((257, 3), np.float32)})
-            split = m._get_schedule(treedef, leaves)
-            monkeypatch.setattr(manager_mod, "_SLICE_BYTES", 1 << 40)
-            assert m._get_schedule(treedef, leaves) is not split
-        finally:
-            m.shutdown()
+    def test_schedule_cache_is_keyed_by_the_slice_size(self, monkeypatch,
+                                                       exchange_rig):
+        x = exchange_rig(bucket_bytes=256).x
+        leaves, treedef = jax.tree_util.tree_flatten(
+            {"a": np.ones((257, 3), np.float32)})
+        split = x.schedule(treedef, leaves)
+        monkeypatch.setattr(exchange_mod, "_SLICE_BYTES", 1 << 40)
+        assert x.schedule(treedef, leaves) is not split
 
 
 # -------------------------------------------------- the pipeline, end to end
@@ -255,7 +249,7 @@ MKW = {"allreduce_bucket_bytes": 256}
 class TestSlicedAllreduce:
     @pytest.mark.parametrize("world", [2, 3, 4])
     def test_average_of_a_split_tree(self, world, monkeypatch):
-        monkeypatch.setattr(manager_mod, "_SLICE_BYTES", SLICE)
+        monkeypatch.setattr(exchange_mod, "_SLICE_BYTES", SLICE)
         out = _run_managers(world, _allreduce_body(), MKW)
         res = [o[0][0] for o in out]
         hosts = [_host_tree(r) for r in range(world)]
@@ -278,7 +272,7 @@ class TestSlicedAllreduce:
         if world > 2:
             return
         # Two groups: bitwise what the unsplit schedule gives (a + b).
-        monkeypatch.setattr(manager_mod, "_SLICE_BYTES", 1 << 40)
+        monkeypatch.setattr(exchange_mod, "_SLICE_BYTES", 1 << 40)
         whole = _run_managers(world, _allreduce_body(), MKW)
         assert whole[0][0][1]["allreduce_split_slices_total"] == 0
         assert whole[0][0][1]["allreduce_ring_ops_total"] < sched_ops
@@ -324,39 +318,35 @@ class TestSlicedAllreduce:
                 np.testing.assert_array_equal(per_step[s][0]["a"],
                                               out[0][s][0]["a"])
 
-    def test_two_programs_a_split_leaf(self, small_slices):
-        """One Manager, one thread, leaf shapes no other test uses: the
+    def test_two_programs_a_split_leaf(self, small_slices, exchange_rig):
+        """One exchange, one thread, leaf shapes no other test uses: the
         staging and the put of every slice trace two pack and two put
         programs a split leaf (full slices, tail) — one where the
         slices divide the leaf — however many slices it has."""
         tree = {"t": jnp.ones((263, 5), jnp.float32),     # 6 slices, tail
                 "u": jnp.ones((5, 256), jnp.float32),     # 5 slices, none
                 "v": jnp.ones((3001,), jnp.float32)}      # 12 slices, tail
-        m = make_manager(allreduce_bucket_bytes=256)
-        try:
-            leaves, treedef = jax.tree_util.tree_flatten(tree)
-            sched = m._get_schedule(treedef, leaves)
-            assert sched.slices == {0: 6, 1: 5, 2: 12}
-            before = dict(_PACK_STATS)
-            asm = {i: [None, k] for i, k in sched.slices.items()}
-            done = {}
-            for b, chunks in enumerate(sched.chunks):
-                recs = m._stage_bucket(chunks, leaves, bucket=b,
-                                       sched=sched)
-                bufs = m._wait_bucket(recs, leaves, bucket=b)
-                done.update(m._put_bucket_chunks(
-                    chunks, [np.array(x) for x in bufs], leaves, 1, asm))
-            assert _PACK_STATS["pack_cache_misses"] \
-                - before["pack_cache_misses"] == 2 + 1 + 2
-            assert _PACK_STATS["put_cache_misses"] \
-                - before["put_cache_misses"] == 2 + 1 + 2
-            assert not asm and sorted(done) == [0, 1, 2]
-            for i, leaf in enumerate(leaves):
-                np.testing.assert_array_equal(np.asarray(done[i]),
-                                              np.asarray(leaf))
-                assert done[i].sharding == leaf.sharding
-        finally:
-            m.shutdown()
+        x = exchange_rig(bucket_bytes=256).x
+        leaves, treedef = jax.tree_util.tree_flatten(tree)
+        sched = x.schedule(treedef, leaves)
+        assert sched.slices == {0: 6, 1: 5, 2: 12}
+        before = dict(_PACK_STATS)
+        asm = {i: [None, k] for i, k in sched.slices.items()}
+        done = {}
+        for b, chunks in enumerate(sched.chunks):
+            recs = x._stage_bucket(chunks, leaves, bucket=b, sched=sched)
+            bufs = x._wait_bucket(recs, leaves, bucket=b)
+            done.update(x._put_bucket_chunks(
+                chunks, [np.array(a) for a in bufs], leaves, 1, asm))
+        assert _PACK_STATS["pack_cache_misses"] \
+            - before["pack_cache_misses"] == 2 + 1 + 2
+        assert _PACK_STATS["put_cache_misses"] \
+            - before["put_cache_misses"] == 2 + 1 + 2
+        assert not asm and sorted(done) == [0, 1, 2]
+        for i, leaf in enumerate(leaves):
+            np.testing.assert_array_equal(np.asarray(done[i]),
+                                          np.asarray(leaf))
+            assert done[i].sharding == leaf.sharding
 
     @pytest.mark.parametrize("window", ["0", "1", None])
     def test_stage_ahead_counts_slices(self, window, small_slices,
@@ -370,7 +360,7 @@ class TestSlicedAllreduce:
         else:
             monkeypatch.setenv("TORCHFT_ALLREDUCE_STAGE_AHEAD", window)
         log = {}
-        stage, wait = Manager._stage_bucket, Manager._wait_bucket
+        stage, wait = GradExchange._stage_bucket, GradExchange._wait_bucket
 
         def staged(self, chunks, leaves, bucket=-1, **kw):
             recs = stage(self, chunks, leaves, bucket=bucket, **kw)
@@ -383,8 +373,8 @@ class TestSlicedAllreduce:
             log.setdefault(id(self), []).append(("wait", bucket, 0))
             return wait(self, recs, leaves, bucket=bucket)
 
-        monkeypatch.setattr(Manager, "_stage_bucket", staged)
-        monkeypatch.setattr(Manager, "_wait_bucket", waited)
+        monkeypatch.setattr(GradExchange, "_stage_bucket", staged)
+        monkeypatch.setattr(GradExchange, "_wait_bucket", waited)
         _run_managers(2, _allreduce_body(), MKW)
         assert len(log) == 2
         for events in log.values():
@@ -457,16 +447,11 @@ class TestSlicedShardedUpdate:
             np.testing.assert_array_equal(np.asarray(back[k]), v)
             assert isinstance(back[k], jax.Array) == (k not in HOST_KEYS)
 
-    def test_local_full_shards_follow_the_slices(self, small_slices):
-        import optax
-
-        from torchft_tpu.optim import FTOptimizer
-
+    def test_full_shards_follow_the_slices(self, small_slices):
         m = make_manager(allreduce_bucket_bytes=256, shard_update=True)
         try:
-            opt = FTOptimizer(m, optax.sgd(0.1), jit=False)
-            tree = _tree(0)
-            sg = opt._local_full_shards(tree)
+            sg = m.full_shards(_tree(0))
+            assert (sg.rank, sg.world) == (0, 1)
             flat = jax.tree_util.tree_leaves(_host_tree(0))
             assert any(c.rows is not None for c in sg.chunks)
             for c, shard in zip(sg.chunks, sg.shards):
@@ -498,9 +483,9 @@ class TestSlicedInt8:
                 assert m.should_commit()
                 outs.append(_np(got))
             leaves, treedef = jax.tree_util.tree_flatten(_tree(rank))
-            sched = m._get_schedule(treedef, leaves)
-            store = dict(m._dev_residuals)
-            store.update(m._ef_residuals)
+            sched = m._exchange.schedule(treedef, leaves)
+            store = dict(m._exchange._dev_residuals)
+            store.update(m._exchange._ef_residuals)
             sizes = {k[1:]: int(np.shape(v)[0]) for k, v in store.items()}
             assert all(k[0] == sched.fingerprint for k in store)
             return outs, sizes, sched

@@ -46,7 +46,7 @@ from torchft_tpu.communicator import (CommunicatorError,
                                       ErrorSwallowingCommunicator,
                                       Int8Wire)
 from torchft_tpu.communicator import shard_bounds
-from torchft_tpu.manager import _device_quantize_pack
+from torchft_tpu.exchange import _device_quantize_pack
 
 pytestmark = pytest.mark.transport
 
@@ -204,7 +204,7 @@ class TestDeviceQuantizePack:
         legs are bitwise interchangeable for bf16 too."""
         import jax.numpy as jnp
 
-        from torchft_tpu.manager import _pack_leaves
+        from torchft_tpu.exchange import _pack_leaves
 
         wdt = np.dtype(jnp.bfloat16)
         rng = np.random.default_rng(11)
@@ -320,8 +320,8 @@ def _run_pair(device_quantize, steps=4, shapes=((61, 17), (3_001,))):
                     {k: np.asarray(v) for k, v in avg.items()})
             metrics[rank] = m.metrics()
             internals[rank] = dict(
-                dev_residuals=len(m._dev_residuals),
-                ef_residuals=len(m._ef_residuals))
+                dev_residuals=len(m._exchange._dev_residuals),
+                ef_residuals=len(m._exchange._ef_residuals))
         except Exception as e:  # noqa: BLE001
             errors.append(e)
             try:
@@ -397,8 +397,8 @@ class TestManagerDeviceQuant:
                     m.allreduce(g).result()
                     assert m.should_commit()
                     if rank == 0:
-                        fps = {k[0] for k in m._dev_residuals}
-                        seen[step] = (len(m._dev_residuals),
+                        fps = {k[0] for k in m._exchange._dev_residuals}
+                        seen[step] = (len(m._exchange._dev_residuals),
                                       len(fps))
             except Exception as e:  # noqa: BLE001
                 errors.append(e)
@@ -425,11 +425,11 @@ class TestManagerDeviceQuant:
     def test_policy_switch_clears_device_residuals(self):
         m = _devq_manager(DummyCommunicator(), 0, True)
         try:
-            m._dev_residuals[("fp", 0, 0)] = np.zeros(4, np.float32)
+            m._exchange._dev_residuals[("fp", 0, 0)] = np.zeros(4, np.float32)
             m._install_policy(
                 next(p for p in policy_mod.LADDER
                      if p.name == "sync-bf16"), "test", "policy_switch")
-            assert not m._dev_residuals
+            assert not m._exchange._dev_residuals
         finally:
             m.shutdown()
 

@@ -52,7 +52,8 @@ import jax
 import numpy as np
 import optax
 
-from torchft_tpu.manager import Manager, ShardedGrads
+from torchft_tpu.exchange import ShardedGrads
+from torchft_tpu.manager import Manager
 
 
 class FTOptimizer:
@@ -131,7 +132,7 @@ class FTOptimizer:
         (reference optim.py:51-54).
 
         Sharded mode (``Manager(shard_update=True)``): ``grads`` is
-        usually a :class:`~torchft_tpu.manager.ShardedGrads` from
+        usually a :class:`~torchft_tpu.exchange.ShardedGrads` from
         :meth:`Manager.reduce_scatter` and the update runs on this
         rank's stripe only — see :meth:`_apply_sharded`. A plain tree in
         sharded mode (single-group fast path, on-device backend
@@ -143,31 +144,12 @@ class FTOptimizer:
             return self._apply_sharded(holder, grads)
         if self._shard_mode:
             return self._apply_sharded(holder,
-                                       self._local_full_shards(grads))
+                                       self.manager.full_shards(grads))
         committed = self.manager.should_commit()
         if committed:
             holder.params, holder.opt_state = self._update(
                 holder.params, holder.opt_state, grads)
         return committed
-
-    def _local_full_shards(self, grads: Any) -> ShardedGrads:
-        """World-1 :class:`ShardedGrads` spelling of a plain averaged
-        tree (the stripe is the whole flat chunk): keeps the sharded
-        optimizer's state/update spelling uniform when a step needed no
-        cross-group stripe (single-group fast path, device backends)."""
-        leaves, treedef = jax.tree_util.tree_flatten(grads)
-        sched = self.manager._get_schedule(treedef, leaves)
-        chunks = [c for cs in sched.chunks for c in cs]
-        shards = []
-        for c in chunks:
-            buf = np.empty(c.total, c.orig)
-            off = 0
-            for i, start, size in zip(c.idx, c.offs, c.sizes):
-                buf[off:off + size] = np.ravel(np.asarray(leaves[i]))[
-                    start:start + size].astype(c.orig, copy=False)
-                off += size
-            shards.append(buf)
-        return ShardedGrads(chunks, shards, 0, 1, leaves, treedef)
 
     def _apply_sharded(self, holder: Any, sg: ShardedGrads) -> bool:
         """ZeRO-style commit: heal-restore first, stripe update
