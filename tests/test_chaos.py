@@ -296,6 +296,10 @@ class TestRingSegmentShortRead:
             assert len(c._accum_lent) == 0
             assert not any(c._accum_free.values())
             assert c.accum_counters() == (0.0, 1.0, 1.0)
+            # rank 0 receives through a ChaosSocket: the Python loop,
+            # where the short read is injected (two steps of the clean
+            # op, none of the torn one)
+            assert c.ring_step_counters() == (0.0, 2.0)
         finally:
             for ring in rings:
                 ring.close()

@@ -149,6 +149,9 @@ class TestErrorSwallowing:
             def accum_counters(self):
                 return (1.0, 2.0, 3.0)
 
+            def ring_step_counters(self):
+                return (4.0, 5.0)
+
         comm = {
             "swallowing": ErrorSwallowingCommunicator,
             "managed": lambda c: ManagedCommunicator(_StubManager(c)),
@@ -164,8 +167,10 @@ class TestErrorSwallowing:
         comm.release_wire_buffers(None)
         assert calls["released"] is None
         assert comm.accum_counters() == (1.0, 2.0, 3.0)
+        assert comm.ring_step_counters() == (4.0, 5.0)
         # a backend that keeps nothing: the defaults
         assert DummyCommunicator().accum_counters() == (0.0, 0.0, 0.0)
+        assert DummyCommunicator().ring_step_counters() == (0.0, 0.0)
         assert DummyCommunicator().release_wire_buffers(out) is None
 
 
@@ -1000,14 +1005,40 @@ def _inplace_ring_oracle(ring, rank, world, acc):
     return acc
 
 
-def _contribution(seed, rank, size, writable=True):
-    a = np.random.default_rng([seed, rank]).normal(size=size).astype(
-        np.float32)
+def _contribution(seed, rank, size, writable=True, dtype=np.float32):
+    rng = np.random.default_rng([seed, rank])
+    dtype = np.dtype(dtype)
+    if dtype.kind == "i":
+        # the whole range, so sums wrap as numpy's do
+        info = np.iinfo(dtype)
+        a = rng.integers(info.min, info.max, size=size, dtype=dtype,
+                         endpoint=True)
+    else:
+        a = rng.normal(size=size).astype(dtype)
     a.flags.writeable = writable
     return a
 
 
 _F32 = np.dtype(np.float32)
+
+
+@pytest.fixture(params=["native", "python"])
+def executor(request, monkeypatch):
+    """Who runs the exact ring's inbound steps (host.py ``_inbound``):
+    the native core's one call a step, or the Python segment loop, which
+    a core without the entry points leaves as the only one."""
+    from torchft_tpu import _native
+
+    if request.param == "python":
+        monkeypatch.setattr(_native, "ring_core", lambda: None)
+    elif _native.ring_core() is None:
+        pytest.skip("native core unavailable (no C++ toolchain)")
+    return request.param
+
+
+def _steps(executor, n):
+    """``ring_step_counters()`` after ``n`` inbound steps."""
+    return (float(n), 0.0) if executor == "native" else (0.0, float(n))
 
 
 class TestOutOfPlaceExactRing:
@@ -1021,26 +1052,70 @@ class TestOutOfPlaceExactRing:
 
     # 3: smaller than the world of four (empty chunks); 10_007: a chunk
     # under one 256 KB segment, not divisible; 300_001: several segments
-    # a chunk with a ragged last one.
+    # a chunk with a ragged last one. Both executors of the fold against
+    # the in-place Python loop, bit for bit.
+    @pytest.mark.parametrize("dtype", ["float32", "float64"])
     @pytest.mark.parametrize("writable", [False, True],
                              ids=["readonly", "writable"])
     @pytest.mark.parametrize("size", [3, 10_007, 300_001])
     @pytest.mark.parametrize("world", [2, 3, 4])
-    def test_fold_equals_the_in_place_ring(self, world, size, writable):
+    def test_fold_equals_the_in_place_ring(self, world, size, writable,
+                                           dtype, executor):
         want, _ = self._run(world, lambda c, ring, r: _inplace_ring_oracle(
-            ring, r, world, _contribution(7, r, size)))
-        srcs = [_contribution(7, r, size, writable) for r in range(world)]
+            ring, r, world, _contribution(7, r, size, dtype=dtype)))
+        srcs = [_contribution(7, r, size, writable, dtype)
+                for r in range(world)]
         got, comms = self._run(world, lambda c, ring, r: c._do_allreduce_wire(
-            ring, [srcs[r]], [_F32], "sum"))
+            ring, [srcs[r]], [np.dtype(dtype)], "sum"))
         for r in range(world):
+            assert got[r][0].dtype == np.dtype(dtype)
             assert got[r][0].tobytes() == want[r].tobytes()
             assert got[r][0].tobytes() == got[0][0].tobytes()
             # the source is never written, read-only or not
             assert srcs[r].flags.writeable == writable
-            assert srcs[r].tobytes() == _contribution(7, r, size).tobytes()
+            assert srcs[r].tobytes() == _contribution(
+                7, r, size, dtype=dtype).tobytes()
             assert not np.shares_memory(got[r][0], srcs[r])
             # (bytes copied, accumulators reused, accumulators allocated)
             assert comms[r].accum_counters() == (0.0, 0.0, 1.0)
+            # world-1 folded and world-1 plain receives, empty chunks too
+            assert comms[r].ring_step_counters() == _steps(
+                executor, 2 * (world - 1))
+            comms[r].shutdown()
+
+    @pytest.mark.parametrize("dtype", ["float32", "float64", "int32",
+                                       "int64", "bfloat16"])
+    @pytest.mark.parametrize("world", [2, 3, 4])
+    def test_in_place_spelling_equals_the_python_loop(self, world, dtype,
+                                                      executor):
+        """``acc is src`` (the tree path's concat, the wire crossover's
+        upcast). Integers wrap as numpy's; a dtype the core does not
+        fold (ml_dtypes bfloat16) takes the Python loop whatever the
+        executor on offer, and the counters say so."""
+        import jax.numpy as jnp
+
+        dt = np.dtype(jnp.bfloat16 if dtype == "bfloat16" else dtype)
+
+        def own(r):
+            if dtype == "bfloat16":
+                return _contribution(9, r, 70_001).astype(dt)
+            return _contribution(9, r, 70_001, dtype=dt)
+
+        want, _ = self._run(world, lambda c, ring, r: _inplace_ring_oracle(
+            ring, r, world, own(r)))
+
+        def fn(c, ring, r):
+            buf = own(r)
+            out = c._ring_allreduce_buffer(ring, buf, buf)
+            assert out is buf
+            return out
+
+        got, comms = self._run(world, fn)
+        for r in range(world):
+            assert got[r].tobytes() == want[r].tobytes()
+            assert comms[r].ring_step_counters() == _steps(
+                "python" if dtype == "bfloat16" else executor,
+                2 * (world - 1))
             comms[r].shutdown()
 
     @pytest.mark.parametrize("world", [2, 3, 4])
@@ -1146,21 +1221,246 @@ class TestOutOfPlaceExactRing:
             assert comms[r].accum_counters() == (40_000.0, 0.0, 1.0)
             comms[r].shutdown()
 
-    @pytest.mark.parametrize("world", [2, 3])
-    def test_reduce_scatter_keeps_its_accumulator_inside(self, world):
+    @pytest.mark.parametrize("world", [2, 3, 4])
+    def test_reduce_scatter_keeps_its_accumulator_inside(self, world,
+                                                         executor):
         from torchft_tpu.backends.host import _fold_exact_ring_order
+        from torchft_tpu.communicator import shard_bounds
 
-        xs = [_contribution(5, r, 10_007, False) for r in range(world)]
+        # 300_001: several segments a chunk with a ragged last one
+        xs = [_contribution(5, r, 300_001, False) for r in range(world)]
 
         def fn(c, ring, r):
             a = c._do_reduce_scatter_wire(ring, [xs[r]], [_F32], "sum")
             b = c._do_reduce_scatter_wire(ring, [xs[r]], [_F32], "sum")
-            return a[0], b[0]
+            full = c._do_allreduce_wire(ring, [xs[r]], [_F32], "sum")
+            return a[0], b[0], full[0]
 
         out, comms = self._run(world, fn)
+        bounds = shard_bounds(300_001, world)
         for r in range(world):
             want = _fold_exact_ring_order(xs, _F32, world, stripe=r)
             assert out[r][0].tobytes() == out[r][1].tobytes() == \
                 want.tobytes()
-            assert comms[r].accum_counters() == (0.0, 1.0, 1.0)
+            # a stripe is bitwise that stripe of the allreduce
+            assert out[r][2][bounds[r]:bounds[r + 1]].tobytes() == \
+                want.tobytes()
+            # the allreduce took the accumulator the stripes kept inside
+            assert comms[r].accum_counters() == (0.0, 2.0, 1.0)
+            # world-1 folds and the shift hop a reduce-scatter
+            assert comms[r].ring_step_counters() == _steps(
+                executor, 2 * world + 2 * (world - 1))
             comms[r].shutdown()
+
+
+class TestExactRingExecutor:
+    """Which executor an exact ring op's inbound steps get (host.py
+    ``_inbound``: chosen from the socket's type, the accumulator's dtype
+    and the loaded core, no knob), that the counters say which, and that
+    a dead, stalled or reconfigured-away peer fails a step the same way
+    under both."""
+
+    _run = TestWireRingTransport._run
+
+    @pytest.mark.parametrize("world_size", [2, 3])
+    def test_plain_f32_ring_over_tcp_is_all_native(self, store, world_size):
+        """The deployed layout: rendezvous over the store, plain TCP
+        sockets, f32. 2·(world−1) native steps an op, no Python step."""
+        addr = store.address()
+        comms = [HostCommunicator(timeout_sec=30) for _ in range(world_size)]
+        xs = [_contribution(11, r, 300_001, False) for r in range(world_size)]
+
+        def run(rank):
+            c = comms[rank]
+            c.configure(f"{addr}/native", rank, world_size)
+            seen = []
+            for _ in range(3):
+                res = c.allreduce_wire([xs[rank]], ["float32"]).result(
+                    timeout=30)
+                seen.append((res[0].copy(), c.ring_step_counters()))
+                c.release_wire_buffers(res)
+            return seen
+
+        from torchft_tpu.backends.host import _fold_exact_ring_order
+
+        want = _fold_exact_ring_order(xs, _F32, world_size)
+        for seen in _run_ranks(world_size, run):
+            for op, (got, counters) in enumerate(seen, 1):
+                assert got.tobytes() == want.tobytes()
+                assert counters == (op * 2.0 * (world_size - 1), 0.0)
+        for c in comms:
+            c.shutdown()
+
+    def test_chaos_socket_ring_takes_the_python_loop(self):
+        """A ``ChaosSocket`` injects in ``recv_into``: only the Python
+        loop calls it, so a wrapped ring must keep that loop (every chaos
+        short-read test then injects where it did)."""
+        import socket as _socket
+
+        from torchft_tpu import chaos
+        from torchft_tpu.backends.host import _Ring
+        from torchft_tpu.chaos import ChaosSchedule, EndpointChaos
+
+        sched = ChaosSchedule(seed=0, intensity=0.0, endpoints={
+            "ring": EndpointChaos(short_rate=1.0)})
+        pairs = [_socket.socketpair() for _ in range(2)]
+        rings = [_Ring(pairs[r][0], chaos.wrap_socket(
+            pairs[(r - 1) % 2][1], "ring", sched), _socket.socket())
+            for r in range(2)]
+        assert all(isinstance(r.prev_sock, chaos.ChaosSocket) for r in rings)
+        comms = [HostCommunicator(timeout_sec=15) for _ in range(2)]
+        xs = [_contribution(12, r, 300_001, False) for r in range(2)]
+        out = [None, None]
+
+        def go(r):
+            comms[r]._rank, comms[r]._world = r, 2
+            out[r] = comms[r]._do_allreduce_wire(
+                rings[r], [xs[r]], [_F32], "sum")[0]
+
+        ts = [threading.Thread(target=go, args=(r,)) for r in range(2)]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(timeout=30)
+        for ring in rings:
+            ring.close()
+        for r in range(2):
+            assert out[r].tobytes() == (xs[0] + xs[1]).tobytes()
+            assert comms[r].ring_step_counters() == (0.0, 2.0)
+            comms[r].shutdown()
+
+    def _op_against_a_peer(self, peer, timeout=None):
+        """Rank 0's exact f32 op through its worker (errors mapped as a
+        caller sees them) over a world-2 socketpair ring, against a rank
+        1 that passes the op's handshake and then does ``peer(ring)``.
+        Returns ``(future, comm, finish)``."""
+        rings = _socketpair_rings(2)
+        if timeout is not None:
+            rings[0].prev_sock.settimeout(timeout)
+        comms = [HostCommunicator(timeout_sec=15) for _ in range(2)]
+        for r, c in enumerate(comms):
+            c._rank, c._world = r, 2
+        x = _contribution(13, 0, 300_001, False)
+
+        def rank1():
+            comms[1]._wire_preamble(rings[1], "ar", [x], [_F32])
+            peer(rings[1])
+
+        t = threading.Thread(target=rank1)
+        t.start()
+        comms[0]._ring = rings[0]
+        fut = comms[0].allreduce_wire([x], ["float32"])
+
+        def finish():
+            for ring in rings:
+                ring.close()
+            t.join(timeout=20)
+            assert not t.is_alive()
+            for c in comms:
+                c.shutdown()
+
+        return fut, comms[0], finish
+
+    def test_peer_that_closes_mid_chunk_fails_the_step(self, executor):
+        import time
+
+        def peer(ring):
+            # a third of a segment of the chunk, then gone
+            ring.next_sock.sendall(b"\0" * 80_000)
+            ring.close()
+
+        t0 = time.monotonic()
+        fut, c, finish = self._op_against_a_peer(peer, timeout=10)
+        try:
+            with pytest.raises(CommunicatorError,
+                               match="peer closed connection"):
+                fut.result(timeout=10)
+            assert time.monotonic() - t0 < 5
+            assert c.ring_step_counters() == (0.0, 0.0)  # none completed
+            assert not c._accum_free and not len(c._accum_lent)
+        finally:
+            finish()
+
+    def test_peer_that_stalls_times_the_step_out(self, executor):
+        import time
+
+        release = threading.Event()
+
+        def peer(ring):
+            ring.next_sock.sendall(b"\0" * 80_000)
+            release.wait(timeout=15)
+
+        t0 = time.monotonic()
+        fut, c, finish = self._op_against_a_peer(peer, timeout=0.5)
+        try:
+            with pytest.raises(CommunicatorError, match="timed out"):
+                fut.result(timeout=10)
+            assert 0.4 < time.monotonic() - t0 < 5
+        finally:
+            release.set()
+            finish()
+
+    def test_reconfigure_aborts_a_blocked_receive(self, executor):
+        """No socket timeout at all: only the ring's close (shutdown,
+        then close) can wake the step."""
+        import time
+
+        release = threading.Event()
+        fut, c, finish = self._op_against_a_peer(
+            lambda ring: release.wait(timeout=15))
+        try:
+            time.sleep(0.3)          # rank 0 is inside the receive by now
+            assert not fut.done()
+            t0 = time.monotonic()
+            c.configure("nowhere:0/solo", 0, 1)
+            with pytest.raises(CommunicatorError):
+                fut.result(timeout=10)
+            assert time.monotonic() - t0 < 5
+        finally:
+            release.set()
+            finish()
+
+    def test_other_threads_run_while_a_native_receive_is_blocked(self):
+        """ctypes releases the GIL for the whole call: were it held, this
+        thread could not send what the blocked receive waits for, and the
+        receive would run into its 5 s timeout."""
+        import socket as _socket
+        import time
+
+        from torchft_tpu import _native
+
+        core = _native.ring_core()
+        if core is None:
+            pytest.skip("native core unavailable (no C++ toolchain)")
+        a, b = _socket.socketpair()
+        a.settimeout(5)
+        mine = _contribution(14, 0, 100_000, False)
+        theirs = _contribution(14, 1, 100_000)
+        out = np.empty_like(theirs)
+        errors = []
+
+        def receive():
+            try:
+                _native.ring_recv(core, a.fileno(), out.ctypes.data,
+                                  out.nbytes, 5000, mine.ctypes.data,
+                                  _native.RING_FOLD_DTYPES["<f4"])
+            except Exception as e:  # noqa: BLE001
+                errors.append(e)
+
+        t0 = time.monotonic()
+        t = threading.Thread(target=receive)
+        t.start()
+        try:
+            time.sleep(0.2)          # blocked in poll() by now
+            ticks, until = 0, time.monotonic() + 0.2
+            while time.monotonic() < until:
+                ticks += 1
+            assert t.is_alive() and ticks > 1000
+            b.sendall(theirs.tobytes())
+            t.join(timeout=10)
+            assert not t.is_alive() and not errors, errors
+            assert time.monotonic() - t0 < 3
+            assert out.tobytes() == (mine + theirs).tobytes()
+        finally:
+            a.close()
+            b.close()

@@ -1220,6 +1220,31 @@ class TestWireRingPipelined:
                     np.testing.assert_array_equal(
                         np.asarray(results[rank][step][k]), want)
 
+    @pytest.mark.parametrize("world", [2, 3])
+    def test_ring_steps_are_published_by_executor(self, world):
+        """``allreduce_ring_native_steps_total`` /
+        ``allreduce_ring_python_steps_total``: 2·(world−1) inbound steps
+        a ring buffer, all of them the native core's over plain sockets
+        in f32 (all of them Python's where no core could be built)."""
+        import jax.numpy as jnp
+        from torchft_tpu import _native
+
+        def tree(rank):
+            return {k: jnp.full((n,), rank + 1.0, jnp.float32)
+                    for k, n in (("a", 700), ("b", 1000), ("c", 1001))}
+
+        _, metrics = self._run_steps(world, [tree] * 3,
+                                     allreduce_bucket_bytes=1024)
+        native = _native.ring_core() is not None
+        for rank in range(world):
+            for step, mx in enumerate(metrics[rank], 1):
+                steps = float(mx["allreduce_ring_ops_total"]
+                              * 2 * (world - 1))
+                assert mx["allreduce_ring_ops_total"] == 3 * step
+                assert (mx["allreduce_ring_native_steps_total"],
+                        mx["allreduce_ring_python_steps_total"]) == (
+                    (steps, 0.0) if native else (0.0, steps))
+
     def test_changed_gradient_signature_drops_the_buffers(self):
         """A, A, B, B, A: each change of signature starts from nothing
         kept (its chunks have other sizes), and stays bitwise right."""

@@ -61,6 +61,11 @@ DOCUMENTED_KEYS = frozenset([
     # wire ops handed to the ring [count, one a bucket], and those of
     # them that were one slice of a leaf wider than a slice [count]
     "allreduce_ring_ops_total", "allreduce_split_slices_total",
+    # inbound steps of the exact ring [count, one a chunk received:
+    # 2*(world-1) an allreduce buffer], by executor: one GIL-free call
+    # of the native core / the Python segment loop
+    "allreduce_ring_native_steps_total",
+    "allreduce_ring_python_steps_total",
     # cross-step overlap engine
     "allreduce_hidden_ms_total", "allreduce_drain_wait_ms_total",
     "allreduce_inflight", "overlap_steps_deferred",

@@ -15,6 +15,7 @@ The shared library is auto-built with cmake+ninja on first import if missing
 from __future__ import annotations
 
 import ctypes
+import functools
 import os
 import subprocess
 from dataclasses import dataclass
@@ -171,6 +172,17 @@ def _load() -> ctypes.CDLL:
     lib.tft_lighthouse_client_status.argtypes = [c, i64, ctypes.POINTER(vp),
                                                  ctypes.POINTER(vp)]
     lib.tft_lighthouse_client_status.restype = i32
+
+    # The exact ring's inbound step (ring.cc). A shipped library from
+    # before it has neither symbol: ring_core() then says so and the
+    # ring keeps its Python loop.
+    if hasattr(lib, "tft_ring_recv_fold"):
+        lib.tft_ring_recv_fold.argtypes = [
+            i32, vp, vp, ctypes.c_size_t, i32, i64, ctypes.POINTER(vp)]
+        lib.tft_ring_recv_fold.restype = i32
+        lib.tft_ring_recv_exact.argtypes = [
+            i32, vp, ctypes.c_size_t, i64, ctypes.POINTER(vp)]
+        lib.tft_ring_recv_exact.restype = i32
     return lib
 
 
@@ -241,6 +253,43 @@ def _check_handle(h, err: ctypes.c_void_p):
         msg = _take_str(err.value) if err.value else "unknown native error"
         raise NativeError(msg)
     return h
+
+
+# Element types ring.cc folds, by numpy ``dtype.str`` (little-endian
+# hosts; anything else, ml_dtypes bfloat16 included, is not here).
+RING_FOLD_DTYPES = {"<f4": 0, "<f8": 1, "<i4": 2, "<i8": 3}
+
+
+@functools.cache
+def ring_core() -> Optional[ctypes.CDLL]:
+    """The loaded core where it has the exact ring's receive-and-fold
+    entry points, else None: no toolchain to build it with, or a shipped
+    library from before them."""
+    try:
+        core = lib()
+    except (OSError, RuntimeError):
+        return None
+    return core if hasattr(core, "tft_ring_recv_fold") else None
+
+
+def ring_recv(core: ctypes.CDLL, fd: int, out: int, nbytes: int,
+              timeout_ms: int, mine: Optional[int] = None,
+              dtype: int = 0) -> None:
+    """Receive ``nbytes`` from socket ``fd`` in ONE foreign call (the GIL
+    is released for all of it): straight to address ``out``, or, given
+    ``mine``, as ``out[i] = mine[i] + received[i]`` over elements of
+    ``dtype`` (a :data:`RING_FOLD_DTYPES` code) a 256 KB piece at a time
+    as the bytes land; ``mine`` may equal ``out``. ``timeout_ms`` < 0
+    waits for ever. The caller keeps the memory behind both addresses
+    alive and ``fd`` open until this returns."""
+    err = ctypes.c_void_p()
+    if mine is None:
+        rc = core.tft_ring_recv_exact(fd, out, nbytes, timeout_ms,
+                                      ctypes.byref(err))
+    else:
+        rc = core.tft_ring_recv_fold(fd, mine, out, nbytes, dtype,
+                                     timeout_ms, ctypes.byref(err))
+    _check(rc, err)
 
 
 class Lighthouse:

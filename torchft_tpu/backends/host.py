@@ -31,12 +31,13 @@ import threading
 import time
 import weakref
 from concurrent.futures import Future
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import (Any, Callable, Dict, List, Optional, Sequence,
+                    Tuple)
 
 import numpy as np
 import jax
 
-from torchft_tpu import chaos, transport
+from torchft_tpu import _native, chaos, transport
 from torchft_tpu._native import StoreClient
 from torchft_tpu.communicator import (Communicator, CommunicatorError,
                                       Int8Wire, shard_bounds)
@@ -73,10 +74,15 @@ def _recv_exact_into(sock: socket.socket, view: memoryview) -> None:
         got += r
 
 
-# Pipeline segment for receive+reduce overlap. Large enough that the numpy
-# add amortizes its dispatch, small enough that the first add starts long
-# before the full chunk has crossed the wire; a power of two so every
-# segment boundary is element-aligned for any power-of-two itemsize.
+# Pipeline segment for receive+reduce overlap in the rings' Python loops
+# (the wire-dtype, int8, weighted and hierarchical spellings, and the exact
+# ring where the native core does not take the step: _python_inbound).
+# Large enough that the numpy add amortizes its dispatch, small enough that
+# the first add starts long before the full chunk has crossed the wire; a
+# power of two so every segment boundary is element-aligned for any
+# power-of-two itemsize. The native step (_core/ring.cc kPieceBytes) folds
+# pieces of the same size: what stays in L2 between the kernel's copy out
+# of the socket and the fold that reads it back.
 _SEG_BYTES = 1 << 18  # 256 KB
 
 
@@ -278,6 +284,12 @@ class HostCommunicator(Communicator):
         self._accum_reuse = 0.0
         self._accum_alloc = 0.0
         self._host_copy_bytes = 0.0
+        # Inbound steps of the exact ring (one a chunk received: world-1
+        # folded and world-1 plain an allreduce buffer), by who ran them:
+        # the native core in one GIL-free call, or the Python segment
+        # loop (_native_inbound / _python_inbound). The op worker's.
+        self._ring_native_steps = 0.0
+        self._ring_python_steps = 0.0
         self._epoch = 0
         self._lock = threading.Lock()
         self._ops: "queue.Queue[Optional[Tuple]]" = queue.Queue()
@@ -927,6 +939,9 @@ class HostCommunicator(Communicator):
         return (self._host_copy_bytes, self._accum_reuse,
                 self._accum_alloc)
 
+    def ring_step_counters(self) -> Tuple[float, float]:
+        return (self._ring_native_steps, self._ring_python_steps)
+
     def _ring_source(self, buf: Any) -> np.ndarray:
         """One wire buffer as the contiguous 1-D array the ring reads
         (read-only or not: the ring never writes its source). A view,
@@ -942,23 +957,24 @@ class HostCommunicator(Communicator):
         """Bandwidth-optimal ring allreduce: reduce-scatter + allgather,
         from ``src`` into ``acc`` (:meth:`_ring_reduce_scatter_phase`).
 
-        Each ring step is fully pipelined: the outbound chunk streams from
-        the persistent sender thread while this thread receives the inbound
-        chunk in ``_SEG_BYTES`` segments, folding each segment into the
-        accumulator as soon as it lands — the reduce overlaps the wire
-        (and, via kernel socket buffering, the wire keeps flowing during
-        the add) instead of waiting for the whole chunk. The allgather
-        phase needs no reduce, so segments are received zero-copy straight
-        into the accumulator's memory.
+        Each ring step is full duplex: the outbound chunk streams from
+        the persistent sender thread (one ``sendall``) while this thread
+        takes the inbound chunk through the op's inbound executor
+        (:meth:`_inbound`): a reduce-scatter step folds it into the
+        accumulator a 256 KB piece at a time as it lands, so the reduce
+        overlaps the wire (and, via kernel socket buffering, the wire
+        keeps flowing during the add); an allgather step needs no reduce
+        and receives straight into the accumulator's memory.
         """
         n = self._world
         rank = self._rank
-        chunk_bytes = self._ring_reduce_scatter_phase(ring, src, acc)
+        chunk_bytes, recv_chunk = self._ring_reduce_scatter_phase(
+            ring, src, acc)
         for step in range(n - 1):
             send_view = chunk_bytes(rank + 1 - step)
             self._ring_bytes += len(send_view)
             fut = ring.send_async(send_view)
-            _recv_exact_into(ring.prev_sock, chunk_bytes(rank - step))
+            recv_chunk(rank - step)
             fut.result()
         return acc
 
@@ -969,8 +985,11 @@ class HostCommunicator(Communicator):
         order is what makes the reduce-scatter path's stripes bitwise
         equal to the allreduce path's. After the phase, this rank's chunk
         ``(rank + 1) % world`` of ``acc`` holds its fully-reduced values.
-        Returns ``chunk_bytes``, where ``chunk_bytes(i)`` is the byte view
-        of canonical chunk ``i % world`` of ``acc``.
+        Returns ``(chunk_bytes, recv_chunk)``: ``chunk_bytes(i)`` is the
+        byte view of canonical chunk ``i % world`` of ``acc``, and
+        ``recv_chunk(i)`` receives that chunk from the previous neighbour
+        straight into place (the allgather's step, the reduce-scatter's
+        shift hop) through the same executor the phase folded with.
 
         The fold is OUT OF PLACE: ``src`` (contiguous, 1-D) is this
         rank's contribution and is only read, so it may be read-only
@@ -981,8 +1000,12 @@ class HostCommunicator(Communicator):
         the sums are bitwise those — and chunk ``rank`` is only sent
         (from ``src``) until the allgather, or the reduce-scatter's
         shift hop, overwrites it. ``acc`` may be ``src`` itself where
-        the caller owns it (a fresh concat or upcast): the same loop then
-        folds in place."""
+        the caller owns it (a fresh concat or upcast): the same fold then
+        runs in place.
+
+        Who runs the inbound steps is :meth:`_inbound`'s choice, from
+        what it can see (socket type, dtype, the loaded core): one
+        algorithm, two executors of the same adds in the same order."""
         n = self._world
         rank = self._rank
         src_bytes, acc_bytes = _as_bytes(src), _as_bytes(acc)
@@ -993,10 +1016,7 @@ class HostCommunicator(Communicator):
             i %= n
             return of[bounds[i] * itemsize:bounds[i + 1] * itemsize]
 
-        # Scratch for inbound reduce segments, reused across steps.
-        scratch = bytearray(_SEG_BYTES)
-        scratch_view = memoryview(scratch)
-
+        recv_chunk = self._inbound(ring.prev_sock, src, acc, bounds)
         for step in range(n - 1):
             # The chunk being sent is never the one folded this step:
             # the first goes out from the source as it stands, each
@@ -1005,21 +1025,113 @@ class HostCommunicator(Communicator):
                                     acc_bytes if step else src_bytes)
             self._ring_bytes += len(send_view)
             fut = ring.send_async(send_view)
-            c = (rank - step - 1) % n
-            mine = src[bounds[c]:bounds[c + 1]]
-            out = acc[bounds[c]:bounds[c + 1]]
-            nbytes = out.size * itemsize
-            off = 0
-            while off < nbytes:
-                k = min(_SEG_BYTES, nbytes - off)
-                seg = scratch_view[:k]
-                _recv_exact_into(ring.prev_sock, seg)
-                lo, hi = off // itemsize, (off + k) // itemsize
-                np.add(mine[lo:hi], np.frombuffer(seg, dtype=acc.dtype),
-                       out=out[lo:hi])
-                off += k
+            recv_chunk(rank - step - 1, fold=True)
             fut.result()
-        return chunk_bytes
+        return chunk_bytes, recv_chunk
+
+    def _inbound(self, sock: Any, src: np.ndarray, acc: np.ndarray,
+                 bounds: Sequence[int]) -> Callable[..., None]:
+        """The executor of one exact ring op's inbound steps over
+        ``acc``: ``recv(c, fold=False)`` receives canonical chunk
+        ``c % world`` from ``sock``, as ``acc[c] = src[c] + received``
+        when ``fold`` and straight into ``acc[c]`` otherwise, and counts
+        the step. Native when the socket is a plain ``socket.socket``
+        (a :class:`~torchft_tpu.chaos.ChaosSocket` injects its faults in
+        ``recv_into``, which only the Python loop calls), the dtype is
+        one the core folds (float32, float64, int32, int64; ml_dtypes
+        bfloat16 is not) and the loaded core has the entry points;
+        otherwise the Python loop, which is also the oracle the tests
+        hold the native step to, bit for bit."""
+        code = _native.RING_FOLD_DTYPES.get(acc.dtype.str)
+        core = (_native.ring_core()
+                if code is not None and type(sock) is socket.socket
+                else None)
+        if core is None:
+            return self._python_inbound(sock, src, acc, bounds)
+        return self._native_inbound(core, code, sock, src, acc, bounds)
+
+    def _python_inbound(self, sock: Any, src: np.ndarray, acc: np.ndarray,
+                        bounds: Sequence[int]) -> Callable[..., None]:
+        """:meth:`_inbound` in the interpreter: ``recv_into`` a
+        ``_SEG_BYTES`` scratch and one ``np.add`` a segment for a fold,
+        ``recv_into`` the accumulator's own memory otherwise."""
+        n, itemsize = self._world, acc.itemsize
+        acc_bytes = _as_bytes(acc)
+        # Scratch for inbound reduce segments, reused across steps.
+        scratch_view = memoryview(bytearray(_SEG_BYTES))
+
+        def recv(c: int, fold: bool = False) -> None:
+            c %= n
+            if fold:
+                mine = src[bounds[c]:bounds[c + 1]]
+                out = acc[bounds[c]:bounds[c + 1]]
+                nbytes = out.size * itemsize
+                off = 0
+                while off < nbytes:
+                    k = min(_SEG_BYTES, nbytes - off)
+                    seg = scratch_view[:k]
+                    _recv_exact_into(sock, seg)
+                    lo, hi = off // itemsize, (off + k) // itemsize
+                    np.add(mine[lo:hi],
+                           np.frombuffer(seg, dtype=acc.dtype),
+                           out=out[lo:hi])
+                    off += k
+            else:
+                _recv_exact_into(
+                    sock, acc_bytes[bounds[c] * itemsize:
+                                    bounds[c + 1] * itemsize])
+            self._ring_python_steps += 1
+
+        return recv
+
+    def _native_inbound(self, core: Any, code: int, sock: socket.socket,
+                        src: np.ndarray, acc: np.ndarray,
+                        bounds: Sequence[int]) -> Callable[..., None]:
+        """:meth:`_inbound` in the native core (``_core/ring.cc``): one
+        foreign call a ring step, the GIL released for all of it, so a
+        peer group's ring, the sender threads and the stage loops of the
+        same process run meanwhile. Errors come back as the
+        ``CommunicatorError`` s the Python loop raises ("peer closed
+        connection", "timed out", the errno's text)."""
+        if not (src.dtype == acc.dtype and src.size == acc.size
+                and src.flags.c_contiguous and acc.flags.c_contiguous
+                and acc.flags.writeable):
+            raise CommunicatorError(
+                "exact ring: source and accumulator must be contiguous, "
+                "of one dtype and size, the accumulator writable")
+        n, itemsize = self._world, acc.itemsize
+        # Addresses, not views: src may be read-only. The closure holds
+        # both arrays, so the memory outlives every call.
+        src_at, acc_at = src.ctypes.data, acc.ctypes.data
+
+        def recv(c: int, fold: bool = False) -> None:
+            c %= n
+            lo = int(bounds[c]) * itemsize
+            nbytes = int(bounds[c + 1]) * itemsize - lo
+            if nbytes:
+                tmo = sock.gettimeout()
+                # The call reads a descriptor of its own: a ring closed
+                # under it (abort, reconfigure) shuts the socket down,
+                # which wakes the receive whichever descriptor it waits
+                # on, but the number the socket object gives back can
+                # then be another connection's before the call returns.
+                try:
+                    fd = os.dup(sock.fileno())
+                except OSError as e:
+                    raise CommunicatorError(
+                        f"ring receive failed: {e}") from e
+                try:
+                    _native.ring_recv(
+                        core, fd, acc_at + lo, nbytes,
+                        -1 if tmo is None else int(tmo * 1000),
+                        src_at + lo if fold else None, code)
+                except _native.NativeError as e:
+                    raise CommunicatorError(str(e)) from e
+                finally:
+                    os.close(fd)
+            self._ring_native_steps += 1
+
+        return recv
 
     def _ring_reduce_scatter_buffer(self, ring: _Ring,
                                     src: np.ndarray) -> np.ndarray:
@@ -1035,7 +1147,8 @@ class HostCommunicator(Communicator):
         compute and the optimizer stage that follows."""
         n, rank = self._world, self._rank
         acc = self._take_accum(src)
-        chunk_bytes = self._ring_reduce_scatter_phase(ring, src, acc)
+        chunk_bytes, recv_chunk = self._ring_reduce_scatter_phase(
+            ring, src, acc)
         # After the phase rank r owns chunk (r+1); one hop moves each
         # owned chunk to its canonical rank: prev owns exactly chunk
         # `rank`, so receive it straight into place while streaming our
@@ -1043,7 +1156,7 @@ class HostCommunicator(Communicator):
         send_view = chunk_bytes(rank + 1)
         self._ring_bytes += len(send_view)
         fut = ring.send_async(send_view)
-        _recv_exact_into(ring.prev_sock, chunk_bytes(rank))
+        recv_chunk(rank)
         fut.result()
         bounds = shard_bounds(acc.size, n)
         stripe = np.array(acc[bounds[rank]:bounds[rank + 1]])

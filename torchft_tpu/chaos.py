@@ -1147,6 +1147,9 @@ class ChaosCommunicator(Communicator):
     def accum_counters(self) -> Any:
         return self._comm.accum_counters()
 
+    def ring_step_counters(self) -> Any:
+        return self._comm.ring_step_counters()
+
     def ring_bytes_total(self) -> float:
         return self._comm.ring_bytes_total()
 

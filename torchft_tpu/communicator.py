@@ -405,6 +405,17 @@ class Communicator(ABC):
         Wrappers MUST forward."""
         return (0.0, 0.0, 0.0)
 
+    def ring_step_counters(self) -> Tuple[float, float]:
+        """``(native_steps, python_steps)`` of the exact ring,
+        cumulative: inbound ring steps (one a chunk received, 2·(world−1)
+        an allreduce buffer) that ran as one GIL-free call of the native
+        core, and those that ran the Python segment loop (a chaos-wrapped
+        socket, a dtype the core does not fold, a core without the entry
+        points). Surfaced by the Manager as
+        ``allreduce_ring_native_steps_total`` /
+        ``allreduce_ring_python_steps_total``. Wrappers MUST forward."""
+        return (0.0, 0.0)
+
     def ring_bytes_total(self) -> float:
         """Cumulative allreduce payload bytes this rank has *sent* over
         the collective transport, surfaced by the Manager as
@@ -747,6 +758,9 @@ class ErrorSwallowingCommunicator(Communicator):
     def accum_counters(self) -> Tuple[float, float, float]:
         return self._comm.accum_counters()
 
+    def ring_step_counters(self) -> Tuple[float, float]:
+        return self._comm.ring_step_counters()
+
     def ring_bytes_total(self) -> float:
         return self._comm.ring_bytes_total()
 
@@ -898,6 +912,9 @@ class ManagedCommunicator(Communicator):
 
     def accum_counters(self) -> Tuple[float, float, float]:
         return self._comm.accum_counters()
+
+    def ring_step_counters(self) -> Tuple[float, float]:
+        return self._comm.ring_step_counters()
 
     def ring_bytes_total(self) -> float:
         return self._comm.ring_bytes_total()

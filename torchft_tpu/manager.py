@@ -3669,6 +3669,17 @@ class Manager:
         out["allreduce_host_copy_bytes_total"] += copied
         out["allreduce_accum_reuse_total"] = reuse
         out["allreduce_accum_alloc_total"] = alloc
+        # Who ran the exact ring's inbound steps
+        # (Communicator.ring_step_counters): the native core, one
+        # GIL-free call a step, or the Python segment loop. A healthy
+        # f32 ring over plain sockets counts no Python step.
+        steps = getattr(self._comm, "ring_step_counters", None)
+        try:
+            native, python = map(float, steps())
+        except (TypeError, ValueError):  # bare duck-typed / mocked comms
+            native = python = 0.0
+        out["allreduce_ring_native_steps_total"] = native
+        out["allreduce_ring_python_steps_total"] = python
         # Hierarchical-transport legs (docs/design/hier_transport.md):
         # loopback intra-host bytes (traffic that stopped crossing the
         # DCN ring) and whether this rank leads its host's star. 0 on
