@@ -137,6 +137,32 @@ def test_flash_fwd_bwd_compiles_as_a_kernel(one_chip, monkeypatch,
     assert text.count("tpu_custom_call") >= (2 if fused_bwd == "1" else 3)
 
 
+@pytest.mark.parametrize("fused_bwd", ["1", "0"], ids=["fused", "split"])
+def test_latent_flash_compiles_at_two_head_sizes(one_chip, monkeypatch,
+                                                 fused_bwd):
+    """[1, 8192, 32, 192] queries and keys against [1, 8192, 32, 128]
+    values, bf16 causal (the latent attention of ``joyai-llm-flash`` at its
+    cell's length): every kernel lowers with the narrower value blocks and
+    inside scoped VMEM, and carries its own ``_mla`` name."""
+    monkeypatch.setenv("TORCHFT_FLASH_FUSED_BWD", fused_bwd)
+    qk = jax.ShapeDtypeStruct((1, 8192, 32, 192), jnp.bfloat16,
+                              sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True,
+                               interpret=False).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        qk, qk, v).compile().as_text()
+    want = (("flash_fwd_mla", "flash_bwd_mla") if fused_bwd == "1" else
+            ("flash_fwd_mla", "flash_bwd_dq_mla", "flash_bwd_dkdv_mla"))
+    for name in want:
+        assert name in text
+    assert "bf16[32,8192,128]" in text and "bf16[32,8192,192]" in text
+
+
 def test_one_group_step_depth2_fits_the_chip(one_chip):
     """Phase 2: the single-group fused step holds the old and the new
     params + adam state at once (it is not donated), which at depth 2 is
@@ -289,13 +315,24 @@ def test_bare_kernel_in_a_sharded_jit_is_refused(topo):
         jax.jit(jax.grad(loss)).lower(x, x, x).compile()
 
 
-def test_trinity_mini_cell_step_fits_the_chip(one_chip):
-    """``trinity-mini.steady-1g-8k`` as ``benchmarks/`` builds it: the fused
-    one-group step (not donated) at the published widths, 5 layers, 8 of 128
-    experts held and the cell's own batch of 8192-token sequences, adamw.
-    Windowed and full flash kernels and the grouped matmuls compile as
-    Mosaic custom calls, and the step fits with the room the driver's
-    oracle needs beside it for one more seeded tree."""
+CELL_STEPS = {
+    "trinity-mini.steady-1g-8k": ("flash_fwd_window", "gmm"),
+    "joyai-llm-flash.steady-1g-8k": ("flash_fwd_mla", "flash_bwd_mla",
+                                     "gmm"),
+}
+
+
+@pytest.mark.parametrize("name", list(CELL_STEPS), ids=list(CELL_STEPS))
+def test_sparse_cells_step_fits_the_chip(one_chip, name):
+    """A sparse configuration's 8k cell as ``benchmarks/`` builds it: the
+    fused one-group step (not donated) at the published widths, the cut's
+    layers and held experts and the cell's own batch of 8192-token
+    sequences, adamw. ``trinity-mini``: windowed and full flash kernels;
+    ``joyai-llm-flash``: the 192/128 latent kernels (forward and the fused
+    backward) in four layers and the prediction module, two loss scans over
+    one head. The grouped matmuls compile as Mosaic custom calls, and the
+    step fits with the room the driver's oracle needs beside it for one
+    more seeded tree."""
     import sys
 
     bench = os.path.join(os.path.dirname(os.path.dirname(
@@ -304,7 +341,7 @@ def test_trinity_mini_cell_step_fits_the_chip(one_chip):
         sys.path.insert(0, bench)
     from harness import spec
 
-    cell = spec.Cell("trinity-mini.steady-1g-8k")
+    cell = spec.Cell(name)
     cfg, seq, batch = (cell.config, int(cell.mix["seq"]),
                        int(cell.mix["batch_per_group"]))
     builder = spec.model_of(cfg)
@@ -320,6 +357,7 @@ def test_trinity_mini_cell_step_fits_the_chip(one_chip):
     _, fused, _ = _trainer_programs(loss_fn, tx)
     c = fused.lower(p, o, tokens).compile()
     text = c.as_text()
-    assert "flash_fwd_window" in text and "gmm" in text
+    for kernel in CELL_STEPS[name]:
+        assert kernel in text
     tree = 4 * builder.param_count(cfg)
     assert 6 * tree < _footprint(c) < HBM_BYTES - tree

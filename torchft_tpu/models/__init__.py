@@ -10,16 +10,20 @@ from torchft_tpu.models.transformer import (
     llama2_13b_config,
     llama2_70b_config,
     moe_lm_loss,
+    mtp_causal_lm_loss,
     tiny_config,
     tp_rules,
 )
+from torchft_tpu.models.mla import LatentAttention
 
 __all__ = [
+    "LatentAttention",
     "MLP",
     "MoEMLP",
     "RoutedMoEMLP",
     "ep_rules",
     "moe_lm_loss",
+    "mtp_causal_lm_loss",
     "ResNet",
     "ResNet18",
     "ResNet34",

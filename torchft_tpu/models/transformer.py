@@ -72,6 +72,26 @@ class TransformerConfig:
     attn_gate: bool = False
     sandwich_norm: bool = False
     embed_scale: bool = False
+    rms_norm_eps: float = 1e-5
+    # Latent attention (models/mla.py), on when ``kv_lora_rank`` is set:
+    # queries through a ``q_lora_rank`` bottleneck, keys and values through
+    # one of ``kv_lora_rank`` (an RMSNorm inside each), a query/key head of
+    # ``qk_nope_head_dim`` dims without position and ``qk_rope_head_dim``
+    # rotary dims whose key is ONE head shared by every query head, and a
+    # value head of ``v_head_dim``. ``rope_interleave``: rotary turns the
+    # pairs (2i, 2i+1) and not (i, i + d/2).
+    q_lora_rank: Optional[int] = None
+    kv_lora_rank: Optional[int] = None
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_interleave: bool = False
+    # Multi-token prediction: ``mtp_layers`` (0 or 1) modules after the
+    # trunk, each a whole decoder layer of its own over [the next token's
+    # embedding ; the trunk's output], read through the trunk's embedding
+    # and head (``Transformer.__call__(..., return_mtp=True)``,
+    # :func:`mtp_causal_lm_loss`).
+    mtp_layers: int = 0
     # Per-layer rematerialization (jax.checkpoint): trade ~30% backward
     # FLOPs for O(num_layers) fewer live activations — the standard move
     # for long-context / big-batch training on HBM-bound chips.
@@ -102,6 +122,10 @@ class TransformerConfig:
 FULL, SLIDING = "full_attention", "sliding_attention"
 
 
+def _norm(cfg: "TransformerConfig", name: str) -> "RMSNorm":
+    return RMSNorm(eps=cfg.rms_norm_eps, name=name)
+
+
 class RMSNorm(nn.Module):
     eps: float = 1e-5
 
@@ -114,12 +138,18 @@ class RMSNorm(nn.Module):
         return (norm * scale).astype(x.dtype)
 
 
+def _rotary_angles(positions: jnp.ndarray, d: int,
+                   theta: float) -> jnp.ndarray:
+    """``position * theta ** (-2i / d)`` for the ``d / 2`` pairs: float32
+    [B, S, d/2]."""
+    freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    return positions[..., None].astype(jnp.float32) * freqs
+
+
 def rotary(x: jnp.ndarray, positions: jnp.ndarray,
            theta: float) -> jnp.ndarray:
     """Apply rotary position embedding. x: [B, S, H, D]."""
-    d = x.shape[-1]
-    freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    angles = positions[..., None].astype(jnp.float32) * freqs  # [B,S,D/2]
+    angles = _rotary_angles(positions, x.shape[-1], theta)
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
@@ -128,11 +158,30 @@ def rotary(x: jnp.ndarray, positions: jnp.ndarray,
     return out.astype(x.dtype)
 
 
+def rotary_interleaved(x: jnp.ndarray, positions: jnp.ndarray,
+                       theta: float) -> jnp.ndarray:
+    """Rotary over adjacent pairs. x: [B, S, H, D]: the pair
+    ``(x[2i], x[2i+1])`` turns by ``position * theta ** (-2i / D)``
+    (:func:`rotary` pairs ``i`` with ``i + D/2``). Written as
+    ``x * cos + swap(x) * sin`` with ``swap`` exchanging the two of a pair
+    (two lane rolls and a select), so no [.., D/2, 2] layout is made."""
+    d = x.shape[-1]
+    angles = _rotary_angles(positions, d, theta)
+    cos = jnp.repeat(jnp.cos(angles), 2, axis=-1)[:, :, None, :]
+    sin = jnp.repeat(jnp.sin(angles), 2, axis=-1)[:, :, None, :]
+    even = jnp.arange(d) % 2 == 0
+    x32 = x.astype(jnp.float32)
+    swapped = jnp.where(even, -jnp.roll(x32, -1, axis=-1),
+                        jnp.roll(x32, 1, axis=-1))
+    return (x32 * cos + swapped * sin).astype(x.dtype)
+
+
 def plain_attention(q, k, v, causal: bool = True,
                     window: Optional[int] = None):
     """Reference softmax attention; q: [B, S, H, D], k/v may carry fewer
-    (GQA) heads — repeated here (f32 softmax). ``window`` (causal only):
-    key j is visible to query i iff 0 <= i - j < window."""
+    (GQA) heads — repeated here (f32 softmax) — and v another head size
+    than q and k. ``window`` (causal only): key j is visible to query i
+    iff 0 <= i - j < window."""
     if k.shape[2] != q.shape[2]:
         rep = q.shape[2] // k.shape[2]
         k = jnp.repeat(k, rep, axis=2)
@@ -176,8 +225,8 @@ class Attention(nn.Module):
         k = dense((cfg.kv_heads, cfg.head_dim), "k")(x)
         v = dense((cfg.kv_heads, cfg.head_dim), "v")(x)
         if cfg.qk_norm:
-            q = RMSNorm(name="q_norm")(q)
-            k = RMSNorm(name="k_norm")(k)
+            q = _norm(cfg, "q_norm")(q)
+            k = _norm(cfg, "k_norm")(k)
         if sliding or cfg.rope_full_layers:
             q = rotary(q, positions, cfg.rope_theta)
             k = rotary(k, positions, cfg.rope_theta)
@@ -231,10 +280,15 @@ class DecoderLayer(nn.Module):
     @nn.compact
     def __call__(self, x, positions):
         cfg = self.cfg
-        a = Attention(cfg, kind=self.kind, name="attn")(
-            RMSNorm(name="attn_norm")(x), positions)
+        if cfg.kv_lora_rank:
+            from torchft_tpu.models.mla import LatentAttention
+
+            attention = LatentAttention(cfg, name="attn")
+        else:
+            attention = Attention(cfg, kind=self.kind, name="attn")
+        a = attention(_norm(cfg, "attn_norm")(x), positions)
         if cfg.sandwich_norm:
-            a = RMSNorm(name="post_attn_norm")(a)
+            a = _norm(cfg, "post_attn_norm")(a)
         x = x + a
         routed = self.moe and cfg.moe_dispatch == "routed"
         if routed:
@@ -258,28 +312,68 @@ class DecoderLayer(nn.Module):
             raise ValueError(f"unknown moe_dispatch {cfg.moe_dispatch!r}")
         else:
             mlp = MLPBlock(cfg, name="mlp")
-        u = RMSNorm(name="mlp_norm")(x)
+        u = _norm(cfg, "mlp_norm")(x)
         # A routed layer's counts leave the (rematerialised) layer as
         # values: Transformer counts them once a step.
         m, stats = mlp(u, return_stats=True) if routed else (mlp(u), None)
         if cfg.sandwich_norm:
-            m = RMSNorm(name="post_mlp_norm")(m)
+            m = _norm(cfg, "post_mlp_norm")(m)
         return (x + m, stats) if routed else x + m
+
+
+class MTPModule(nn.Module):
+    """One multi-token-prediction module: position ``i`` reads the trunk's
+    output there and the embedding of token ``i + 1`` (``next_embed``, the
+    trunk's own table), ``z = [N_e(embedding) ; N_h(hidden)] W`` (2E -> E),
+    one whole decoder layer with weights of its own, a final norm. What it
+    returns goes through the trunk's head against token ``i + 2``. Returns
+    ``(z, counts)``: a routed expert layer's int32[3] counts, else None."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, hidden, next_embed, positions):
+        cfg = self.cfg
+        z = jnp.concatenate([_norm(cfg, "embed_norm")(next_embed),
+                             _norm(cfg, "hidden_norm")(hidden)], axis=-1)
+        z = nn.Dense(cfg.embed_dim, use_bias=False, dtype=cfg.dtype,
+                     name="proj")(z)
+        layer_cls = (nn.remat(DecoderLayer, prevent_cse=False)
+                     if cfg.remat else DecoderLayer)
+        z = layer_cls(cfg, kind=FULL, moe=cfg.moe_experts > 0,
+                      name="block")(z, positions)
+        z, stats = z if isinstance(z, tuple) else (z, None)
+        return _norm(cfg, "final_norm")(z), stats
 
 
 class Transformer(nn.Module):
     cfg: TransformerConfig
 
     @nn.compact
-    def __call__(self, tokens, return_hidden: bool = False):
+    def __call__(self, tokens, return_hidden: bool = False,
+                 return_mtp: bool = False):
         """``return_hidden=True`` skips the LM head and returns the
         final-norm hidden states [B, S, E] — pair with
         :func:`chunked_causal_lm_loss` so the [B, S, vocab] logits tensor
         (the largest allocation in LM training; ~2 GB at B=16 S=2048
-        V=32k in f32) never materializes."""
+        V=32k in f32) never materializes.
+
+        ``return_mtp=True`` (a model with ``mtp_layers``) returns
+        ``(hidden, mtp_hidden, moe_stats)``: the trunk's final-norm states,
+        the prediction module's (position ``i`` predicts token ``i + 2``;
+        the last position has no next token and reads the first one's
+        embedding, which causal attention keeps from every other), and the
+        routed layers' summed counts (``None`` without routed layers),
+        which are then NOT counted here: the caller counts them with
+        whatever else it counts, in one callback
+        (:func:`mtp_causal_lm_loss`)."""
         cfg = self.cfg
-        x = nn.Embed(cfg.vocab_size, cfg.embed_dim,
-                     dtype=cfg.dtype, name="embed")(tokens)
+        if return_mtp and cfg.mtp_layers != 1:
+            raise ValueError("return_mtp needs a model with mtp_layers=1, "
+                             f"got {cfg.mtp_layers}")
+        embed = nn.Embed(cfg.vocab_size, cfg.embed_dim,
+                         dtype=cfg.dtype, name="embed")
+        x = embed(tokens)
         if cfg.embed_scale:
             x = x * jnp.asarray(cfg.embed_dim ** 0.5, x.dtype)
         if cfg.layer_types and len(cfg.layer_types) != cfg.num_layers:
@@ -297,11 +391,20 @@ class Transformer(nn.Module):
             if isinstance(x, tuple):
                 x, stats = x
                 moe_stats = stats if moe_stats is None else moe_stats + stats
-        if moe_stats is not None:
+        if moe_stats is not None and not return_mtp:
             from torchft_tpu.models.moe import count_moe_stats
 
             count_moe_stats(moe_stats)
-        x = RMSNorm(name="final_norm")(x)
+        x = _norm(cfg, "final_norm")(x)
+        if return_mtp:
+            nxt = embed(jnp.roll(tokens, -1, axis=1))
+            if cfg.embed_scale:
+                nxt = nxt * jnp.asarray(cfg.embed_dim ** 0.5, nxt.dtype)
+            with jax.named_scope("mtp"):
+                z, stats = MTPModule(cfg, name="mtp")(x, nxt, positions)
+            if stats is not None:
+                moe_stats = stats if moe_stats is None else moe_stats + stats
+            return x, z, moe_stats
         if return_hidden:
             return x
         # tied-untied head in f32 for stable loss
@@ -432,6 +535,35 @@ def chunked_causal_lm_loss(hidden: jnp.ndarray, head_kernel: jnp.ndarray,
     total, _ = jax.lax.scan(jax.checkpoint(body), jnp.float32(0.0),
                             (hc, tc, mc))
     return total / (b * s1)
+
+
+def mtp_causal_lm_loss(model: "Transformer", params: Any,
+                       tokens: jnp.ndarray, mtp_weight: float,
+                       chunk_size: int = 256) -> jnp.ndarray:
+    """``L_main + mtp_weight * L_mtp`` of a model with one multi-token
+    prediction module: the trunk's next-token loss and the module's loss
+    against the token after next (mean over the ``S - 2`` positions that
+    have one), both through :func:`chunked_causal_lm_loss` and the one
+    ``lm_head`` kernel, whose gradient is the sum of the two.
+
+    One host callback a step carries the routed layers' ``moe_*`` counts
+    (the module's layer included) and the two losses as
+    ``loss_main_micro_total`` / ``loss_mtp_micro_total`` (each loss x 1e6,
+    summed over steps) to :func:`tracing.program_counters`."""
+    from torchft_tpu import tracing
+    from torchft_tpu.models.moe import MOE_COUNTERS
+
+    hidden, mtp_hidden, stats = model.apply(params, tokens, return_mtp=True)
+    head = params["params"]["lm_head"]["kernel"]
+    main = chunked_causal_lm_loss(hidden, head, tokens, chunk_size)
+    with jax.named_scope("mtp"):
+        mtp = chunked_causal_lm_loss(mtp_hidden[:, :-1], head, tokens[:, 1:],
+                                     chunk_size)
+    counts = {} if stats is None else dict(zip(MOE_COUNTERS, stats))
+    tracing.count_in_program(
+        loss_main_micro_total=jax.lax.stop_gradient(main) * 1e6,
+        loss_mtp_micro_total=jax.lax.stop_gradient(mtp) * 1e6, **counts)
+    return main + mtp_weight * mtp
 
 
 def moe_lm_loss(model: "Transformer", params: Any,

@@ -59,6 +59,7 @@ from typing import Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
@@ -225,12 +226,18 @@ def _check_window(window: Optional[int], causal: bool,
         raise ValueError(f"window must be at least 1, got {window}")
 
 
-def _kernel_name(base: str, window: Optional[int]) -> Optional[str]:
+def _kernel_name(base: str, window: Optional[int],
+                 latent: bool = False) -> Optional[str]:
     """The windowed ``pallas_call``'s own name, by which a profile tells it
-    from the full kernel. The full kernel keeps none: XLA names a custom
-    call after the innermost scope, a ``name`` is one more scope, and the
-    benchmark finds the full kernel by its caller's (``%attn``)."""
-    return None if window is None else f"{base}_window"
+    from the full kernel, and the latent one's (a value head narrower than
+    the query/key head: ``_mla``). The full kernel with one head size keeps
+    none: XLA names a custom call after the innermost scope, a ``name`` is
+    one more scope, and the benchmark finds the full kernel by its caller's
+    (``%attn``)."""
+    if window is None and not latent:
+        return None
+    return base + ("_window" if window is not None else "") \
+        + ("_mla" if latent else "")
 
 
 def _auto_block(seq: int, cap: int = 1024) -> int:
@@ -253,14 +260,24 @@ def _auto_block(seq: int, cap: int = 1024) -> int:
     return seq if seq <= cap else 128
 
 
+def _to_bh(x: jnp.ndarray) -> jnp.ndarray:
+    """[B, S, H, D] -> [B*H, S, D], whatever the head size."""
+    b, s, h, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+
+
 def _flash_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                causal: bool, block_q: Optional[int], block_k: Optional[int],
                interpret: bool,
                shift: Optional[jnp.ndarray] = None,
                window: Optional[int] = None) -> jnp.ndarray:
     b, s, h, d = q.shape
+    d_v = v.shape[-1]     # the value head may be narrower (latent attention)
     h_kv = k.shape[2]
     assert h % h_kv == 0, f"num_heads {h} not a multiple of kv heads {h_kv}"
+    assert k.shape[-1] == d and v.shape[2] == h_kv, (
+        f"q/k head size {d} vs {k.shape[-1]}; k/v heads {h_kv} vs "
+        f"{v.shape[2]}")
     rep = h // h_kv
     scale = d ** -0.5
     # Wider heads need smaller tiles: the [bq, bk] f32 score/prob buffers
@@ -272,11 +289,7 @@ def _flash_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     dynamic_shift = shift is not None
     _check_window(window, causal, dynamic_shift)
 
-    def to_bh(x):
-        bh = x.shape[0] * x.shape[2]
-        return x.transpose(0, 2, 1, 3).reshape(bh, x.shape[1], d)
-
-    qh, kh, vh = to_bh(q), to_bh(k), to_bh(v)
+    qh, kh, vh = _to_bh(q), _to_bh(k), _to_bh(v)
     sk = kh.shape[1]
     assert not causal or sk >= s, (
         "causal flash_attention requires s_k >= s_q (queries are the last "
@@ -297,7 +310,8 @@ def _flash_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
         pl.BlockSpec((1, block_k, d), lambda bh, i, j: (kv_row(bh), j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda bh, i, j: (kv_row(bh), j, 0)),
+        pl.BlockSpec((1, block_k, d_v),
+                     lambda bh, i, j: (kv_row(bh), j, 0)),
     ]
     inputs = [qh, kh, vh]
     if dynamic_shift:
@@ -310,7 +324,7 @@ def _flash_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                           nkb=nkb, offset=sk - s,
                           dynamic_shift=dynamic_shift, window=window),
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, s, d_v), q.dtype),
             # Row stats ride in [bh, s, 128] with the value broadcast over
             # the 128 lanes — the TPU-friendly layout for per-row scalars
             # (same trick as jax.experimental.pallas.ops.tpu.flash_attention;
@@ -320,19 +334,33 @@ def _flash_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
+            pl.BlockSpec((1, block_q, d_v), lambda bh, i, j: (bh, i, 0)),
             pl.BlockSpec((1, block_q, _LANES),
                          lambda bh, i, j: (bh, i, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),   # running max
             pltpu.VMEM((block_q, 1), jnp.float32),   # running sum
-            pltpu.VMEM((block_q, d), jnp.float32),   # output accumulator
+            pltpu.VMEM((block_q, d_v), jnp.float32),  # output accumulator
         ],
         interpret=interpret,
-        name=_kernel_name("flash_fwd", window),
+        name=_kernel_name("flash_fwd", window, d_v != d),
     )(*inputs)
-    return out.reshape(b, h, s, d).transpose(0, 2, 1, 3), lse[:, :, 0]
+    lse = lse[:, :, 0]
+    if d_v != d:
+        # The kernel writes a row's logsumexp across all 128 lanes; one
+        # value a row is what the backward needs. The barrier ties the
+        # narrow [bh, s] copy to the output: it exists before anything
+        # reads the output, so it, and not the 128-times wider kernel
+        # output (128 MiB a layer at 32 heads x 8192), is what lives from a
+        # layer's forward to its backward; left alone, XLA fuses the slice
+        # into its consumer in the backward. Latent calls only: at one head
+        # size the program stays the one the accepted cells were measured
+        # on (there the barrier cost 0.25 % of a step and, by XLA's
+        # rescheduling, 90 MB more temporaries in the routed-expert step,
+        # which has none to spare; PERF.md, PR 33).
+        out, lse = jax.lax.optimization_barrier((out, lse))
+    return out.reshape(b, h, s, d_v).transpose(0, 2, 1, 3), lse
 
 
 def _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -520,19 +548,17 @@ def _flash_bwd(q, k, v, out, lse, g, causal: bool, block_q: Optional[int],
                block_k: Optional[int], interpret: bool, shift=None,
                g_lse=None, window: Optional[int] = None):
     b, s, h, d = q.shape
+    d_v = v.shape[-1]     # v, o, do and dv at the value head's size
+    latent = d_v != d
     h_kv = k.shape[2]
     rep = h // h_kv
     scale = d ** -0.5
 
-    def to_bh(x):
-        bh = x.shape[0] * x.shape[2]
-        return x.transpose(0, 2, 1, 3).reshape(bh, x.shape[1], d)
-
     def kv_row(bh):
         return (bh // h) * h_kv + (bh % h) // rep
 
-    qh, kh, vh = to_bh(q), to_bh(k), to_bh(v)
-    doh, oh = to_bh(g), to_bh(out)
+    qh, kh, vh = _to_bh(q), _to_bh(k), _to_bh(v)
+    doh, oh = _to_bh(g), _to_bh(out)
     sk = kh.shape[1]
     cap = 1024 if d <= 128 else 512  # see _flash_fwd's VMEM note
     block_q = min(block_q or _auto_block(s, cap=cap), s)
@@ -557,12 +583,15 @@ def _flash_bwd(q, k, v, out, lse, g, causal: bool, block_q: Optional[int],
     dynamic_shift = shift is not None
     _check_window(window, causal, dynamic_shift)
     q_spec = pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0))
+    do_spec = pl.BlockSpec((1, block_q, d_v), lambda bh, i, j: (bh, i, 0))
     k_spec = pl.BlockSpec((1, block_k, d),
+                          lambda bh, i, j: (kv_row(bh), j, 0))
+    v_spec = pl.BlockSpec((1, block_k, d_v),
                           lambda bh, i, j: (kv_row(bh), j, 0))
     row_spec = pl.BlockSpec((1, block_q, _LANES),
                             lambda bh, i, j: (bh, i, 0))
 
-    in_specs = [q_spec, k_spec, k_spec, q_spec, row_spec, row_spec]
+    in_specs = [q_spec, k_spec, v_spec, do_spec, row_spec, row_spec]
     inputs = [qh, kh, vh, doh, lse_l, delta_l]
     if dynamic_shift:
         shift_arr = jnp.broadcast_to(
@@ -573,19 +602,24 @@ def _flash_bwd(q, k, v, out, lse, g, causal: bool, block_q: Optional[int],
     # Specs in (bh, k-block, q-block) grid order + output reshapers,
     # shared by the fused kernel and the split dk/dv kernel.
     q_spec2 = pl.BlockSpec((1, block_q, d), lambda bh, j, i: (bh, i, 0))
+    do_spec2 = pl.BlockSpec((1, block_q, d_v), lambda bh, j, i: (bh, i, 0))
     k_in_spec2 = pl.BlockSpec((1, block_k, d),
                               lambda bh, j, i: (kv_row(bh), j, 0))
+    v_in_spec2 = pl.BlockSpec((1, block_k, d_v),
+                              lambda bh, j, i: (kv_row(bh), j, 0))
     k_out_spec2 = pl.BlockSpec((1, block_k, d), lambda bh, j, i: (bh, j, 0))
+    v_out_spec2 = pl.BlockSpec((1, block_k, d_v),
+                               lambda bh, j, i: (bh, j, 0))
     row_spec2 = pl.BlockSpec((1, block_q, _LANES),
                              lambda bh, j, i: (bh, i, 0))
 
     def from_bh(x, seq):
-        return x.reshape(b, h, seq, d).transpose(0, 2, 1, 3)
+        return x.reshape(b, h, seq, x.shape[-1]).transpose(0, 2, 1, 3)
 
     def kv_from_bh(x, seq):
         # [b*h, seq, d] per query head -> sum the rep heads sharing each
         # kv head -> [b, seq, h_kv, d]
-        x = x.reshape(b, h_kv, rep, seq, d)
+        x = x.reshape(b, h_kv, rep, seq, x.shape[-1])
         x = x.astype(jnp.float32).sum(axis=2)
         return x.transpose(0, 2, 1, 3).astype(k.dtype)
 
@@ -619,7 +653,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal: bool, block_q: Optional[int],
     import os
     fused_ok = os.environ.get("TORCHFT_FLASH_FUSED_BWD", "1") != "0"
     if nqb >= 4 and fused_ok and not interpret:
-        in_specs2 = [q_spec2, k_in_spec2, k_in_spec2, q_spec2, row_spec2,
+        in_specs2 = [q_spec2, k_in_spec2, v_in_spec2, do_spec2, row_spec2,
                      row_spec2, q_spec2]
         inputs2 = [qh, kh, vh, doh, lse_l, delta_l,
                    jnp.zeros((b * h, s, d), jnp.float32)]
@@ -633,19 +667,19 @@ def _flash_bwd(q, k, v, out, lse, g, causal: bool, block_q: Optional[int],
                               dynamic_shift=dynamic_shift, window=window),
             out_shape=[
                 jax.ShapeDtypeStruct((b * h, sk, d), k.dtype),
-                jax.ShapeDtypeStruct((b * h, sk, d), v.dtype),
+                jax.ShapeDtypeStruct((b * h, sk, d_v), v.dtype),
                 jax.ShapeDtypeStruct((b * h, s, d), jnp.float32),
             ],
             grid=(b * h, nkb, nqb),
             in_specs=in_specs2,
-            out_specs=[k_out_spec2, k_out_spec2, q_spec2],
+            out_specs=[k_out_spec2, v_out_spec2, q_spec2],
             scratch_shapes=[
                 pltpu.VMEM((block_k, d), jnp.float32),
-                pltpu.VMEM((block_k, d), jnp.float32),
+                pltpu.VMEM((block_k, d_v), jnp.float32),
             ],
             input_output_aliases={6: 2},  # dq buffer: read-modify-write
             interpret=interpret,
-            name=_kernel_name("flash_bwd", window),
+            name=_kernel_name("flash_bwd", window, latent),
         )(*inputs2)
         return pack(dq.astype(q.dtype), dk, dv)
 
@@ -659,14 +693,14 @@ def _flash_bwd(q, k, v, out, lse, g, causal: bool, block_q: Optional[int],
         out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
-        name=_kernel_name("flash_bwd_dq", window),
+        name=_kernel_name("flash_bwd_dq", window, latent),
     )(*inputs)
 
     # dk/dv: k-block outer, q-block innermost (sequential accumulation).
     # Outputs are per QUERY head (each grid row writes its own block, no
     # cross-row accumulation hazards); GQA reduces over the rep query
     # heads sharing a kv head afterwards, outside the kernel.
-    in_specs2 = [q_spec2, k_in_spec2, k_in_spec2, q_spec2, row_spec2,
+    in_specs2 = [q_spec2, k_in_spec2, v_in_spec2, do_spec2, row_spec2,
                  row_spec2]
     inputs2 = [qh, kh, vh, doh, lse_l, delta_l]
     if dynamic_shift:
@@ -678,17 +712,17 @@ def _flash_bwd(q, k, v, out, lse, g, causal: bool, block_q: Optional[int],
                           dynamic_shift=dynamic_shift, window=window),
         out_shape=[
             jax.ShapeDtypeStruct((b * h, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, sk, d), v.dtype),
+            jax.ShapeDtypeStruct((b * h, sk, d_v), v.dtype),
         ],
         grid=(b * h, nkb, nqb),
         in_specs=in_specs2,
-        out_specs=[k_out_spec2, k_out_spec2],
+        out_specs=[k_out_spec2, v_out_spec2],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d_v), jnp.float32),
         ],
         interpret=interpret,
-        name=_kernel_name("flash_bwd_dkdv", window),
+        name=_kernel_name("flash_bwd_dkdv", window, latent),
     )(*inputs2)
 
     return pack(dq, dk, dv)
@@ -746,9 +780,14 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
                     window: Optional[int] = None) -> jnp.ndarray:
-    """Flash attention. q: [B, S, H, D]; k/v: [B, S_k, H_kv, D] with H_kv
-    dividing H — GQA/MQA kv heads are shared via kernel index maps, never
-    materialized with a repeat. ``block_q/block_k=None`` auto-picks the
+    """Flash attention. q: [B, S, H, D]; k: [B, S_k, H_kv, D] and v:
+    [B, S_k, H_kv, D_v] with H_kv dividing H — GQA/MQA kv heads are shared
+    via kernel index maps, never materialized with a repeat. ``D_v`` may
+    differ from ``D`` (latent attention: 192-wide queries and keys, 128-wide
+    values): the scores scale by ``D ** -0.5``, the output is
+    [B, S, H, D_v], and v, o, do and dv move at their own width in every
+    kernel, with no padding; such a call's kernels are named ``*_mla``.
+    ``block_q/block_k=None`` auto-picks the
     largest power-of-two tile (<=1024) dividing the sequence;
     ``interpret=None`` compiles on a TPU backend and interprets elsewhere
     (:func:`_resolve_interpret`). A Mosaic kernel cannot be partitioned
@@ -799,9 +838,19 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     return out[:, :s]
 
 
+# Names (``jax.ad_checkpoint.checkpoint_name``) of the two residuals only the
+# kernel can make. A caller that rematerialises the attention's inputs
+# (``jax.checkpoint`` with ``save_only_these_names(*SAVED_NAMES)``, as
+# ``models/mla.py`` does for the keys and values it expands from a latent)
+# keeps these and runs no second forward kernel.
+SAVED_NAMES = ("flash_out", "flash_lse")
+
+
 def _fwd_rule(q, k, v, causal, block_q, block_k, interpret, window=None):
     out, lse = _flash_fwd(q, k, v, causal, block_q, block_k, interpret,
                           window=window)
+    out = checkpoint_name(out, SAVED_NAMES[0])
+    lse = checkpoint_name(lse, SAVED_NAMES[1])
     return out, (q, k, v, out, lse)
 
 
