@@ -1348,7 +1348,7 @@ class TestExactRingExecutor:
 
         t = threading.Thread(target=rank1)
         t.start()
-        comms[0]._ring = rings[0]
+        comms[0]._rings = [rings[0]]
         fut = comms[0].allreduce_wire([x], ["float32"])
 
         def finish():
@@ -1464,3 +1464,299 @@ class TestExactRingExecutor:
         finally:
             a.close()
             b.close()
+
+
+# ------------------------------------------------------------ ring lanes
+
+def _lane_comms(monkeypatch, world, lanes, timeout_sec=30, **kw):
+    """``world`` communicators built with ``lanes`` lanes each (the
+    module constant is read at construction)."""
+    from torchft_tpu.backends import host
+
+    with monkeypatch.context() as m:
+        m.setattr(host, "_RING_LANES", lanes)
+        return [HostCommunicator(timeout_sec=timeout_sec, **kw)
+                for _ in range(world)]
+
+
+# Sizes of the ops of one "step": chunks under a segment, ragged, empty
+# for some ranks of a world of four, and several segments wide.
+_LANE_OP_SIZES = [10_007, 3, 70_001, 10_007, 300_001, 257, 10_007]
+
+
+def _lane_buffers(kind, rank, op, size):
+    """Rank ``rank``'s wire buffer of op ``op``: ``(buffers, origs)``."""
+    import ml_dtypes
+
+    from torchft_tpu.communicator import Int8Wire
+
+    x = _contribution(100 + op, rank, size, writable=False)
+    if kind == "bf16":
+        return [x.astype(ml_dtypes.bfloat16)], ["float32"]
+    if kind == "int8":
+        return [Int8Wire.quantize(x)], ["float32"]
+    return [x], ["float32"]
+
+
+def _lane_step(comms, kind, rank):
+    """All of a step's ops submitted at once, as the stage loop does,
+    then every result: ``[bytes of op 0's result, ...]``."""
+    c = comms[rank]
+    if kind == "weighted":
+        c.set_wire_weight(rank + 1)
+    op_fn = (c.reduce_scatter_wire if kind == "reduce_scatter"
+             else c.allreduce_wire)
+    futs = [op_fn(*_lane_buffers(kind, rank, op, size))
+            for op, size in enumerate(_LANE_OP_SIZES)]
+    return [f.result(timeout=30)[0].tobytes() for f in futs]
+
+
+class TestRingLanes:
+    """The flat ring as lanes (host.py ``_RING_LANES``): socket pairs an
+    epoch, each with its own sender and op worker, wire ops dealt to
+    them by ordinal. What crosses a lane and how it folds is one lane's
+    to the bit; what changes is how many ops are on the wire at once."""
+
+    @pytest.mark.parametrize("kind", ["exact", "bf16", "int8", "weighted",
+                                      "reduce_scatter"])
+    @pytest.mark.parametrize("world", [2, 3, 4])
+    def test_results_are_bitwise_one_lanes(self, store, monkeypatch, world,
+                                           kind):
+        from torchft_tpu.backends import host
+
+        got = {}
+        for lanes in sorted({1, 2, host._RING_LANES}):
+            comms = _lane_comms(monkeypatch, world, lanes)
+
+            def run(rank):
+                comms[rank].configure(
+                    f"{store.address()}/bit{lanes}", rank, world)
+                assert comms[rank].ring_lane_counters()[0] == lanes
+                return _lane_step(comms, kind, rank)
+
+            got[lanes] = _run_ranks(world, run)
+            for c in comms:
+                c.shutdown()
+        assert all(g == got[1] for g in got.values())
+        if kind != "reduce_scatter":  # every rank holds the same sums
+            assert all(g == got[1][0] for g in got[1])
+
+    @pytest.mark.parametrize("lanes", [2, 3])
+    def test_lane_of_an_op_is_its_ordinal_mod_k(self, store, monkeypatch,
+                                                lanes):
+        """On every rank, with the other ops of a communicator (tree
+        allreduce, allgather, broadcast) in between, on lane 0, and not
+        counted."""
+        from torchft_tpu.tracing import Tracer
+
+        comms = _lane_comms(monkeypatch, 2, lanes)
+        tracers = [Tracer(steps=8, enabled=True) for _ in comms]
+        plan = ["allreduce_wire", "allgather", "allreduce_wire",
+                "reduce_scatter_wire", "allreduce", "allreduce_wire",
+                "broadcast", "allreduce_wire", "reduce_scatter_wire"]
+
+        def run(rank):
+            c = comms[rank]
+            c.set_tracer(tracers[rank])
+            c.configure(f"{store.address()}/ord", rank, 2)
+            x = np.full(1000, rank + 1.0, np.float32)
+            futs = []
+            for i, kind in enumerate(plan):
+                if kind.endswith("_wire"):
+                    # a size of its own an op: the span's order is told
+                    # apart by nothing else
+                    futs.append(getattr(c, kind)([x[:100 + i]],
+                                                 ["float32"]))
+                else:
+                    futs.append(getattr(c, kind)({"t": x}))
+            for f in futs:
+                f.result(timeout=30)
+
+        _run_ranks(2, run)
+        want, ordinal = [], 0
+        for kind in plan:
+            wire = kind.endswith("_wire")
+            want.append((kind, ordinal % lanes if wire else 0))
+            ordinal += wire
+        for tr in tracers:
+            spans = [s for s in tr.spans() if s["stage"] == "ring"]
+            assert all(s["world"] == 2 for s in spans)
+            for lane in range(lanes):   # in submission order on a lane
+                assert [s["kind"] for s in spans if s["lane"] == lane] \
+                    == [k for k, ln in want if ln == lane]
+        for c in comms:
+            assert c.ring_lane_counters()[0] == lanes
+            c.shutdown()
+
+    @pytest.mark.parametrize("how", ["reconfigure", "shutdown"])
+    def test_every_lane_is_failed_and_closed(self, store, monkeypatch, how):
+        """Ops in flight on every lane (blocked on a peer that never
+        submits) and more queued behind them: a reconfigure and a
+        shutdown settle every future with ``CommunicatorError``, and the
+        old epoch's threads end."""
+        import time
+
+        comms = _lane_comms(monkeypatch, 2, 3)
+
+        def run(rank):
+            comms[rank].configure(f"{store.address()}/fail", rank, 2)
+
+        _run_ranks(2, run)
+        c = comms[0]
+        rings = list(c._rings)
+        assert len(rings) == 3
+        x = np.ones(300_001, np.float32)
+        futs = [c.allreduce_wire([x], ["float32"]) for _ in range(9)]
+        time.sleep(0.3)   # a worker a lane is inside its preamble by now
+        assert not any(f.done() for f in futs)
+        t0 = time.monotonic()
+        if how == "reconfigure":
+            c.configure("nowhere:0/solo", 0, 1)
+        else:
+            c.shutdown()
+        for f in futs:
+            with pytest.raises(CommunicatorError):
+                f.result(timeout=10)
+        assert time.monotonic() - t0 < 5
+        for ring in rings:
+            ring._sender.join(timeout=5)
+            assert not ring._sender.is_alive()
+        if how == "reconfigure":
+            assert c.ring_lane_counters()[0] == 0 and not c._rings
+            assert all(w.is_alive() for w in c._workers)   # next epoch's
+        for cc in comms:
+            cc.shutdown()
+            assert not any(w.is_alive() for w in cc._workers)
+            assert not cc._rings
+
+    def test_a_rank_that_skips_an_op_errors_and_recovers(self, store,
+                                                         monkeypatch):
+        """Rank 1 skips the second of four ops, so its ordinals fall one
+        behind: its ops meet other ops (the preamble's format hash
+        fails) or none (the receive times out). Both ranks see a
+        ``CommunicatorError`` within the timeout and no future hangs;
+        after ``configure`` the counters agree again."""
+        import time
+
+        from torchft_tpu.backends.host import _fold_exact_ring_order
+
+        comms = _lane_comms(monkeypatch, 2, 2, timeout_sec=2)
+        sizes = [1000, 2000, 3000, 4000]
+        xs = [[_contribution(50 + op, r, n, False)
+               for op, n in enumerate(sizes)] for r in range(2)]
+
+        def step(rank, prefix, skip):
+            c = comms[rank]
+            c.configure(f"{store.address()}/{prefix}", rank, 2)
+            t0 = time.monotonic()
+            futs = [c.allreduce_wire([xs[rank][op]], ["float32"])
+                    for op in range(len(sizes)) if op not in skip]
+            out = []
+            for f in futs:
+                try:
+                    out.append(f.result(timeout=10)[0].copy())
+                except CommunicatorError as e:
+                    out.append(e)
+            return out, time.monotonic() - t0
+
+        skewed = _run_ranks(2, lambda r: step(r, "skew", {1} if r else ()))
+        for out, took in skewed:
+            assert any(isinstance(o, CommunicatorError) for o in out)
+            assert took < 8
+        healed = _run_ranks(2, lambda r: step(r, "heal", ()))
+        for out, _ in healed:
+            for op in range(len(sizes)):
+                want = _fold_exact_ring_order(
+                    [xs[0][op], xs[1][op]], _F32, 2)
+                assert out[op].tobytes() == want.tobytes()
+        for c in comms:
+            c.shutdown()
+
+    def test_lanes_skew_dies_at_rendezvous(self, store, monkeypatch):
+        """A build with another lane count would leave a lane with
+        nobody to dial and deal ops to other lanes: the fingerprint
+        carries ``lanes=``."""
+        comms = (_lane_comms(monkeypatch, 1, 1, timeout_sec=5)
+                 + _lane_comms(monkeypatch, 1, 2, timeout_sec=5))
+        for c in comms:
+            c.allreduce_config_fingerprint = "bucket_bytes=4194304;None"
+
+        def run(rank):
+            comms[rank].configure(f"{store.address()}/lskew", rank, 2)
+
+        with pytest.raises(RuntimeError,
+                           match=r"allreduce config skew.*lanes="):
+            _run_ranks(2, run)
+        for c in comms:
+            assert c.ring_lane_counters()[0] == 0
+            c.shutdown()
+
+    def test_hier_topology_keeps_one_lane_and_world_one_none(self, store,
+                                                             monkeypatch):
+        comms = []
+        for r in range(4):
+            comms += _lane_comms(monkeypatch, 1, 3, host_id=f"h{r // 2}",
+                                 hier=True)
+
+        def run(rank):
+            c = comms[rank]
+            c.configure(f"{store.address()}/hier", rank, 4)
+            x = np.full(1001, rank + 1.0, np.float32)
+            outs = [c.allreduce_wire([x], ["float32"]).result(timeout=30)[0]
+                    for _ in range(3)]
+            return c.ring_topology(), c.ring_lane_counters(), outs
+
+        for topo, lanes, outs in _run_ranks(4, run):
+            assert topo == "hier:2x2"
+            assert lanes == (1.0, 0.0)
+            for o in outs:
+                np.testing.assert_array_equal(o, np.full(1001, 10.0))
+        solo = comms[0]
+        solo.configure("unused/prefix", 0, 1)
+        assert solo.ring_lane_counters() == (0.0, 0.0)
+        assert DummyCommunicator().ring_lane_counters() == (0.0, 0.0)
+        for c in comms:
+            c.shutdown()
+
+    @pytest.mark.parametrize("world", [2, 3])
+    def test_accumulators_settle_after_the_first_step(self, store,
+                                                      monkeypatch, world):
+        """A step's ops go out together and their results come back
+        when the step ends: the first step allocates one accumulator an
+        op (as many as are out at once), every later step none, and
+        several ops overlap."""
+        from torchft_tpu.backends import host
+
+        comms = _lane_comms(monkeypatch, world, host._RING_LANES)
+        n = len(_LANE_OP_SIZES)
+
+        def run(rank):
+            c = comms[rank]
+            c.configure(f"{store.address()}/acc", rank, world)
+            seen = []
+            for step in range(3):
+                futs = [c.allreduce_wire(
+                    *_lane_buffers("exact", rank, 10 * step + op, size))
+                    for op, size in enumerate(_LANE_OP_SIZES)]
+                res = [f.result(timeout=30) for f in futs]
+                for r in res:
+                    c.release_wire_buffers(r)
+                del res, futs
+                seen.append((c.accum_counters(), c.ring_step_counters(),
+                             c.ring_lane_counters()))
+            return seen
+
+        from torchft_tpu import _native
+
+        native = _native.ring_core() is not None
+        for seen in _run_ranks(world, run):
+            for step, (acc, steps, lanes) in enumerate(seen, 1):
+                assert acc == (0.0, float(n * (step - 1)), float(n))
+                total = float(step * n * 2 * (world - 1))
+                assert steps == ((total, 0.0) if native else (0.0, total))
+                assert lanes[0] == host._RING_LANES
+                assert lanes[1] <= step * n
+            if host._RING_LANES > 1:
+                assert seen[-1][2][1] > 0
+        for c in comms:
+            c.shutdown()

@@ -61,6 +61,42 @@ class _SeenComm(_FoldComm):
                              else [id(b) for b in buffers])
 
 
+class _ReverseComm(_SeenComm):
+    """Resolves a step's ``n_ops`` wire ops in the reverse of the order
+    they were submitted in, once all of them are folded: the furthest a
+    communicator with several lanes can stray from submission order."""
+
+    def __init__(self, hub, rank, n_ops):
+        super().__init__(hub, rank)
+        self.n_ops = n_ops
+        self.held = []
+        self.resolved = []   # ordinals within the step, as resolved
+        self.settlers = []
+
+    def _late(self, inner):
+        outer = Future()
+        self.held.append((inner, outer))
+        if len(self.held) == self.n_ops:
+            batch, self.held = self.held, []
+            t = threading.Thread(target=self._settle, args=(batch,))
+            self.settlers.append(t)
+            t.start()
+        return outer
+
+    def _settle(self, batch):
+        results = [inner.result(timeout=60) for inner, _ in batch]
+        for i in reversed(range(len(batch))):
+            self.resolved.append(i)
+            batch[i][1].set_result(results[i])
+
+    def allreduce_wire(self, buffers, orig_dtypes, op="sum"):
+        return self._late(super().allreduce_wire(buffers, orig_dtypes, op))
+
+    def reduce_scatter_wire(self, buffers, orig_dtypes, op="sum"):
+        return self._late(
+            super().reduce_scatter_wire(buffers, orig_dtypes, op))
+
+
 def _tree(rank):
     """Device and host leaves, two dtypes, and (under the file's 1 KiB
     slices) two split leaves."""
@@ -158,6 +194,50 @@ class TestExchangeAlone:
                         "allreduce_fetch_ms_total",
                         "allreduce_wire_bytes_total"):
                 assert rig.counters[key] > 0, key
+
+    @pytest.mark.parametrize("op", ["allreduce", "reduce_scatter"])
+    def test_ops_may_complete_in_any_order(self, op, exchange_rig,
+                                           small_slices):
+        """A ring of several lanes finishes ops out of submission order.
+        Buckets settle by slot and a split leaf is assembled by row
+        offset, so a communicator that resolves every step's ops in
+        REVERSE gives the same averaged tree, split leaves included,
+        two steps running (the second folds into handed-back
+        buffers)."""
+        leaves, treedef = jax.tree_util.tree_flatten(_tree(0))
+        hub = _FoldHub()
+        probe = exchange_rig(_SeenComm(hub, 0), bucket_bytes=256)
+        sched = probe.x.schedule(treedef, leaves)
+        n = len(sched.chunks)
+        assert len(sched.slices) == 2 and n > 4
+        comms = [_ReverseComm(hub, r, n) for r in range(2)]
+        rigs = [exchange_rig(c, bucket_bytes=256) for c in comms]
+        steps = _run_world(op, rigs, steps=2)
+        want = jax.tree_util.tree_map(
+            lambda a, b: div_by_count(np.asarray(a) + np.asarray(b), 2),
+            _tree(0), _tree(1))
+        for step in range(2):
+            got = [r[step] for r in steps]
+            if op == "reduce_scatter":
+                got = [_stripes_as_tree(got)] * 2
+            for g in got:
+                for k, w in want.items():
+                    np.testing.assert_array_equal(np.asarray(g[k]), w)
+        kind = "ar" if op == "allreduce" else "rs"
+        for c, rig in zip(comms, rigs):
+            for t in c.settlers:
+                t.join(timeout=30)
+            assert c.resolved == list(reversed(range(n))) * 2
+            assert c.ops == [(kind, [ch.total for ch in cs])
+                             for cs in sched.chunks] * 2
+            assert rig.counters["allreduce_ring_ops_total"] == 2 * n
+            assert rig.counters["allreduce_count"] == 2
+            if op == "allreduce":
+                # every bucket's buffers still come back, whatever the
+                # order, before the step resolves
+                handed = [r for r in c.released if r is not None]
+                assert sorted(map(tuple, handed)) == sorted(
+                    map(tuple, c.lent))
 
     def test_ops_agree_on_spans_and_counts(self, exchange_rig,
                                            small_slices):

@@ -949,14 +949,19 @@ def _wired_comm(ring, rank, world):
     """HostCommunicator with the store rendezvous replaced by a
     pre-wired ring, so the full pipelined allreduce — pack, async D2H,
     wire ring, device unpack — runs without the native library."""
-    from torchft_tpu.backends.host import HostCommunicator
+    from unittest.mock import patch
 
-    class WiredComm(HostCommunicator):
+    from torchft_tpu.backends import host as host_mod
+
+    class WiredComm(host_mod.HostCommunicator):
         def configure(self, store_addr, rank, world_size):
             pass  # pre-wired
 
-    c = WiredComm(timeout_sec=15)
-    c._ring, c._rank, c._world = ring, rank, world
+    # one ring, or a list of them: the epoch's lanes, with a worker each
+    rings = list(ring) if isinstance(ring, list) else [ring]
+    with patch.object(host_mod, "_RING_LANES", max(len(rings), 1)):
+        c = WiredComm(timeout_sec=15)
+    c._rings, c._rank, c._world = rings, rank, world
     return c
 
 
@@ -965,13 +970,15 @@ class TestWireRingPipelined:
     transport, mocked control plane): the tier-1 spelling of the
     numerics guarantees that don't need the native store."""
 
-    def _run_steps(self, world, trees, **mkw):
-        """One Manager a rank over pre-wired rings; ``trees[step](rank)``
-        is each step's gradient tree. Returns, per rank and step, the
-        result (kept alive to the end) and ``Manager.metrics()``."""
+    def _run_steps(self, world, trees, lanes=1, **mkw):
+        """One Manager a rank over pre-wired rings (``lanes`` of them a
+        rank); ``trees[step](rank)`` is each step's gradient tree.
+        Returns, per rank and step, the result (kept alive to the end)
+        and ``Manager.metrics()``."""
         import threading as _t
 
-        rings = _make_test_rings(world)
+        lane_rings = [_make_test_rings(world) for _ in range(lanes)]
+        rings = [[lane[r] for lane in lane_rings] for r in range(world)]
         results = [[] for _ in range(world)]
         metrics = [[] for _ in range(world)]
         errors = []
@@ -1005,8 +1012,9 @@ class TestWireRingPipelined:
         for t in threads:
             t.join(timeout=90)
         alive = [t for t in threads if t.is_alive()]
-        for r in rings:
-            r.close()
+        for lane in lane_rings:
+            for r in lane:
+                r.close()
         assert not alive, "pipelined allreduce deadlocked"
         assert not errors, errors
         return results, metrics
@@ -1244,6 +1252,47 @@ class TestWireRingPipelined:
                 assert (mx["allreduce_ring_native_steps_total"],
                         mx["allreduce_ring_python_steps_total"]) == (
                     (steps, 0.0) if native else (0.0, steps))
+
+    @pytest.mark.parametrize("lanes", [1, 2, 3])
+    def test_ring_lanes_are_published(self, lanes):
+        """``allreduce_ring_lanes`` is the ring's lane count and
+        ``allreduce_ring_overlapped_ops_total`` counts the wire ops that
+        began beside another lane's (none can with one lane); the
+        averaged tree is one lane's to the bit, split leaves and all."""
+        import jax.numpy as jnp
+
+        def tree(rank):
+            return {k: jnp.asarray(np.random.default_rng(
+                [rank, n]).normal(size=n).astype(np.float32))
+                for k, n in (("a", 700), ("b", 1000), ("c", 1001),
+                             ("d", 5000), ("e", 40))}
+
+        results, metrics = self._run_steps(2, [tree] * 3, lanes=lanes,
+                                           allreduce_bucket_bytes=1024)
+        want = jax.tree_util.tree_map(
+            lambda a, b: (np.asarray(a) + np.asarray(b)) / 2,
+            tree(0), tree(1))
+        for rank in range(2):
+            for step, mx in enumerate(metrics[rank], 1):
+                assert mx["allreduce_ring_lanes"] == lanes
+                ops = mx["allreduce_ring_ops_total"]
+                assert ops == 5 * step
+                over = mx["allreduce_ring_overlapped_ops_total"]
+                assert 0 <= over < ops and (lanes > 1 or over == 0)
+                assert mx["allreduce_ring_native_steps_total"] \
+                    + mx["allreduce_ring_python_steps_total"] == 2 * ops
+                for k, w in want.items():
+                    np.testing.assert_array_equal(
+                        np.asarray(results[rank][step - 1][k]), w)
+
+    def test_world_one_reports_no_lane(self):
+        m = make_manager(MagicMock(), comm=_wired_comm([], 0, 1))
+        try:
+            mx = m.metrics()
+            assert mx["allreduce_ring_lanes"] == 0
+            assert mx["allreduce_ring_overlapped_ops_total"] == 0
+        finally:
+            m.shutdown()
 
     def test_changed_gradient_signature_drops_the_buffers(self):
         """A, A, B, B, A: each change of signature starts from nothing

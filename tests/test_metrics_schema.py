@@ -66,6 +66,10 @@ DOCUMENTED_KEYS = frozenset([
     # of the native core / the Python segment loop
     "allreduce_ring_native_steps_total",
     "allreduce_ring_python_steps_total",
+    # the ring's lanes [gauge: socket pairs of the ring in force, 1
+    # under the hierarchical transport, 0 at world 1], and the wire ops
+    # that began while another lane's op was on the wire [count]
+    "allreduce_ring_lanes", "allreduce_ring_overlapped_ops_total",
     # cross-step overlap engine
     "allreduce_hidden_ms_total", "allreduce_drain_wait_ms_total",
     "allreduce_inflight", "overlap_steps_deferred",

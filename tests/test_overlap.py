@@ -554,7 +554,7 @@ class _SlowWiredComm(HostCommunicator):
 
     def __init__(self, ring, rank, world, delay):
         super().__init__(timeout_sec=30)
-        self._ring, self._rank, self._world = ring, rank, world
+        self._rings, self._rank, self._world = [ring], rank, world
         self._delay = delay
 
     def configure(self, store_addr, rank, world_size):

@@ -409,7 +409,8 @@ class TestManagerReduceScatter:
             m.wait_quorum()
             # Kill the ring under the collective: both ranks' sockets
             # die, the comm worker raises, wrap_future swallows.
-            m._comm._ring.close()
+            for ring in m._comm._rings:
+                ring.close()
             tx = optax.adam(1e-2)
             opt = FTOptimizer(m, tx, jit=False)
             h = _Holder(jax.tree_util.tree_map(jnp.asarray, GRADS))

@@ -770,7 +770,8 @@ class TestHierByteScaling:
         def run_hier(c, r):
             c._do_allreduce_wire(None, [Int8Wire.quantize(xs[r])],
                                  [F32], "sum", "step", -1)
-            return (c._hier_leader_bytes, c._hier_intra_bytes)
+            return (c.hier_leader_bytes_total(),
+                    c.hier_intra_bytes_total())
 
         hier, he = _run_ranks(4, run_hier, _hier_comms(hosts))
         assert not he, he
@@ -779,7 +780,7 @@ class TestHierByteScaling:
             c._do_allreduce_wire(c._flat_test_ring,
                                  [Int8Wire.quantize(xs[r])],
                                  [F32], "sum", "step", -1)
-            return (c._ring_bytes, 0.0)
+            return (c.ring_bytes_total(), 0.0)
 
         flat, fe = _run_ranks(4, run_flat, _flat_comms())
         assert not fe, fe
@@ -818,7 +819,7 @@ class TestTopologyAccessors:
     def test_wrappers_forward(self):
         inner = HostCommunicator(timeout_sec=1)
         inner._hier = _HierTopo([[0, 1], [2, 3]], 0)
-        inner._hier_intra_bytes = 42.0
+        inner._count(hier_intra_bytes=42.0)
         wrapped = ErrorSwallowingCommunicator(inner)
         try:
             assert wrapped.ring_topology() == "hier:2x2"
@@ -892,7 +893,7 @@ class TestManagerHierEndToEnd:
             if topos is not None:
                 c._hier = topos[r]
             else:
-                c._ring = rings[r]
+                c._rings = [rings[r]]
             comms.append(c)
 
         results = {r: [] for r in range(world)}

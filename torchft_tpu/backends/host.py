@@ -11,9 +11,11 @@ problem that forced the reference into subprocess isolation,
 ``process_group.py:511-741``).
 
 Design notes:
-- One background op thread per communicator: collectives are issued in
-  program order on every rank (a requirement shared with every collective
-  library), run asynchronously, and resolve ``Future``s.
+- ``_RING_LANES`` socket pairs an epoch, each with an op thread of its
+  own: collectives are issued in program order on every rank (a
+  requirement shared with every collective library), wire ops are dealt
+  to the lanes by their ordinal, run asynchronously (in order within a
+  lane, not across lanes), and resolve ``Future``s.
 - Leaves are concatenated per dtype into single ring buffers, so per-step
   cost is O(bytes) with one ring round-trip per dtype, not per leaf.
 - A fresh listener per configure + per-quorum store prefixes make stale
@@ -84,6 +86,23 @@ def _recv_exact_into(sock: socket.socket, view: memoryview) -> None:
 # pieces of the same size: what stays in L2 between the kernel's copy out
 # of the socket and the fold that reads it back.
 _SEG_BYTES = 1 << 18  # 256 KB
+
+# Lanes of the flat ring: socket pairs an epoch, each with its own sender
+# thread and op worker, so that many wire ops are on the wire at once
+# (docs/design/cross_group_backend.md, "Ring lanes"). One TCP stream and
+# one copying thread at each end move 1.5 GB/s a direction over loopback;
+# the host has more than that, and only the native inbound step (no GIL)
+# lets a second stream use it. A constant, not an option: every rank must
+# deal the same op to the same lane, so it rides the configure-time
+# fingerprint (``;lanes=``). From paired chip runs of 2, 3 and 4 (PERF.md,
+# PR 34): with a process and a host share a group (a ring of four) 4 lanes
+# settle at 1.62 s a step where 2 settle at 1.92 and one at 2.65; with two
+# groups in one process on 13 cores 2, 3 and 4 read alike, inside that
+# cell's run-to-run spread.
+_RING_LANES = 4
+
+# The ops that are dealt to lanes; every other kind stays on lane 0.
+_WIRE_KINDS = ("allreduce_wire", "reduce_scatter_wire")
 
 
 class _StoreLookupError(RuntimeError):
@@ -255,48 +274,71 @@ class HostCommunicator(Communicator):
         self._host_id = host_id
         self._hier_opt = hier
         self._hier: Optional[_HierTopo] = None
-        # Send-site byte counters of the two hierarchical legs: intra =
-        # loopback star traffic (member->leader + leader->members),
-        # leader = the cross-host leader-ring slice of _ring_bytes —
-        # the bytes the hierarchy exists to shrink.
-        self._hier_intra_bytes = 0.0
-        self._hier_leader_bytes = 0.0
 
         self._rank = 0
         self._world = 1
-        self._ring: Optional[_Ring] = None
-        # Allreduce payload bytes this rank has sent over the ring
-        # (exact + wire paths). Written on the single op-worker thread
-        # only; read via ring_bytes_total() for Manager.metrics().
-        self._ring_bytes = 0.0
-        # The int8-rung slice of _ring_bytes (payload + segment
-        # headers), so the ~4x saving of the int8+EF wire is observable
-        # on its own (Manager surfaces it as
-        # allreduce_int8_ring_bytes_total).
-        self._ring_bytes_int8 = 0.0
+        # The epoch's lanes: one _Ring a lane, lane i's served by worker
+        # i. Empty at world 1 and before the first configure; one ring
+        # under the hierarchical transport (_HierTopo has one set of
+        # sockets, so every wire op stays on lane 0).
+        self._rings: List[_Ring] = []
+        # Ordinal of the next wire op of this epoch, taken in _submit and
+        # reset by configure: the op's lane is the ordinal modulo the
+        # lane count. Every rank submits the same wire ops in the same
+        # order (the schedule comes from metadata; healers and spares
+        # submit zero buffers), so every rank derives the same lane with
+        # no message. A rank whose counter disagrees (an op skipped
+        # after an error) pairs an op with another one or with none: the
+        # preamble's format hash fails or the receive times out, both as
+        # a CommunicatorError, which poisons the communicator
+        # (manager.py, _comm_poisoned); the recovery rendezvous that
+        # follows calls configure, and the counters agree again.
+        self._wire_ordinal = 0
+        # Ops between a worker's epoch check and their result, over all
+        # lanes: a wire op that starts while this is not 0 is counted
+        # in "overlapped_ops".
+        self._on_wire = 0
+        # Cumulative counters, written by every lane's worker (and by
+        # callers that hand a source in): under _lock, through _count.
+        #   ring_bytes: allreduce payload bytes this rank has sent over
+        #     the ring (exact + wire paths); ring_bytes_int8 its int8
+        #     slice (payload + segment headers), so the ~4x saving of
+        #     the int8+EF wire is observable on its own;
+        #   hier_intra_bytes / hier_leader_bytes: send-site bytes of the
+        #     two hierarchical legs (loopback star traffic; the
+        #     cross-host leader-ring slice of ring_bytes, the bytes the
+        #     hierarchy exists to shrink);
+        #   accum_reuse / accum_alloc, host_copy_bytes: _take_accum,
+        #     _ring_source;
+        #   native_steps / python_steps: inbound steps of the exact ring
+        #     (one a chunk received: world-1 folded and world-1 plain an
+        #     allreduce buffer), by who ran them: the native core in one
+        #     GIL-free call, or the Python segment loop (_native_inbound
+        #     / _python_inbound);
+        #   overlapped_ops: wire ops that began while another lane's op
+        #     was on the wire.
+        self._counts: Dict[str, float] = dict.fromkeys(
+            ("ring_bytes", "ring_bytes_int8", "hier_intra_bytes",
+             "hier_leader_bytes", "accum_reuse", "accum_alloc",
+             "host_copy_bytes", "native_steps", "python_steps",
+             "overlapped_ops"), 0.0)
         # Exact-ring accumulators that live across steps (_take_accum):
         # `free` holds what callers handed back, by (dtype, size); `lent`
         # knows, weakly, the results an op resolved to, so only those
-        # come back. Both under _lock; the counters are the op worker's.
+        # come back. Both under _lock. As many are inside ops at once as
+        # there are lanes.
         self._accum_free: Dict[Tuple[str, int], List[np.ndarray]] = {}
         self._accum_lent: "weakref.WeakValueDictionary[int, np.ndarray]" \
             = weakref.WeakValueDictionary()
-        self._accum_reuse = 0.0
-        self._accum_alloc = 0.0
-        self._host_copy_bytes = 0.0
-        # Inbound steps of the exact ring (one a chunk received: world-1
-        # folded and world-1 plain an allreduce buffer), by who ran them:
-        # the native core in one GIL-free call, or the Python segment
-        # loop (_native_inbound / _python_inbound). The op worker's.
-        self._ring_native_steps = 0.0
-        self._ring_python_steps = 0.0
         self._epoch = 0
         self._lock = threading.Lock()
-        self._ops: "queue.Queue[Optional[Tuple]]" = queue.Queue()
-        self._worker = threading.Thread(target=self._run, daemon=True,
-                                        name="host-comm")
-        self._worker.start()
         self._shutdown = False
+        # A queue and a worker a lane; the threads start with the first
+        # op (_submit), so a communicator that never leaves world 1
+        # holds none.
+        self._ops: List["queue.Queue[Optional[Tuple]]"] = [
+            queue.Queue() for _ in range(_RING_LANES)]
+        self._workers: List[threading.Thread] = []
 
     def set_retry_policy(self, policy, stats=None) -> None:
         """Adopt the owning Manager's policy + shared stats (forwarded by
@@ -341,17 +383,18 @@ class HostCommunicator(Communicator):
         ring, so leader death recovers through the same
         poison-and-re-rendezvous path as any ring reset."""
         with self._lock:
-            old, self._ring = self._ring, None
+            old, self._rings = self._rings, []
             old_hier, self._hier = self._hier, None
             self._epoch += 1
             epoch = self._epoch
+            self._wire_ordinal = 0
             self._drop_accums()
-        if old is not None:
-            old.close()
+        for ring in old:
+            ring.close()
         if old_hier is not None:
             old_hier.close()
-        # Fail anything still queued from the old epoch.
-        self._drain_queue("aborted by reconfigure")
+        # Fail anything still queued from the old epoch, on every lane.
+        self._drain_queues("aborted by reconfigure")
 
         self._rank = rank
         self._world = world_size
@@ -375,7 +418,11 @@ class HostCommunicator(Communicator):
         # DIFFERENT transports for the same op and wedge mid-collective.
         fp = getattr(self, "allreduce_config_fingerprint", None)
         if fp is not None:
-            fp = f"{fp};hier={int(self._hier_flag())}"
+            # So is the lane count: a rank with fewer lanes would leave
+            # a peer's lane with nobody to dial at this rendezvous, and
+            # deal its ops to other lanes than the peer does.
+            fp = (f"{fp};hier={int(self._hier_flag())}"
+                  f";lanes={len(self._ops)}")
             tmo = int(self._timeout * 1000)
             store.set(f"{prefix}/arcfg/{rank}", fp.encode())
 
@@ -415,38 +462,49 @@ class HostCommunicator(Communicator):
             store.set(f"{prefix}/host/{rank}",
                       self._effective_host_id().encode())
 
-        next_sock, prev_sock, listener = self._ring_rendezvous(
-            store, prefix, "", rank, world_size)
-
+        # Lane 0 first: its rendezvous is the barrier the hier build
+        # reads the host map behind. The other lanes ("/lane1", ...: the
+        # namespace keeps each ring's keys and handshake apart) only
+        # where the flat ring carries the wire ops: every rank resolves
+        # the same host map, so every rank builds the same number.
+        socks: List[Tuple[socket.socket, ...]] = []
         topo: Optional[_HierTopo] = None
-        if self._hier_flag():
-            try:
+
+        def discard() -> None:
+            for trio in socks:
+                for sock in trio:
+                    sock.close()
+            if topo is not None:
+                topo.close()
+
+        try:
+            socks.append(self._ring_rendezvous(
+                store, prefix, "", rank, world_size))
+            if self._hier_flag():
                 topo = self._build_hier(store, prefix, rank, world_size)
-            except BaseException:
-                next_sock.close()
-                prev_sock.close()
-                listener.close()
-                raise
+            if topo is None:
+                for lane in range(1, len(self._ops)):
+                    socks.append(self._ring_rendezvous(
+                        store, prefix, f"/lane{lane}", rank, world_size))
+        except BaseException:
+            discard()
+            raise
 
         with self._lock:
             if self._epoch != epoch:  # raced with another configure
-                next_sock.close()
-                prev_sock.close()
-                listener.close()
-                if topo is not None:
-                    topo.close()
+                discard()
                 return
             # Chaos wrapping AFTER the epoch handshake: rendezvous stays
             # clean (a fault there is just a failed configure), the data
             # plane — every ring collective byte — is injectable.
-            self._ring = _Ring(
-                chaos.wrap_socket(next_sock, "ring"),
-                chaos.wrap_socket(prev_sock, "ring"),
-                listener)
+            self._rings = [
+                _Ring(chaos.wrap_socket(next_sock, "ring"),
+                      chaos.wrap_socket(prev_sock, "ring"), listener)
+                for next_sock, prev_sock, listener in socks]
             self._hier = topo
         logger.info("host communicator configured: rank=%d world=%d "
-                    "topology=%s (%s)", rank, world_size,
-                    self.ring_topology(), prefix)
+                    "topology=%s lanes=%d (%s)", rank, world_size,
+                    self.ring_topology(), len(socks), prefix)
 
     def _ring_rendezvous(self, store: StoreClient, prefix: str, ns: str,
                          pos: int, ring_world: int
@@ -731,61 +789,64 @@ class HostCommunicator(Communicator):
         return _HierTopo(hosts, rank, leader_ring=leader_ring,
                          member_socks=member_socks, listener=lst)
 
-    def _ring_span(self, kind: str) -> Any:
+    def _ring_span(self, kind: str, lane: int) -> Any:
         """A ``ring`` span from the Manager-installed tracer
         (:meth:`Communicator.set_tracer`), or a no-op when none/disabled
         — raw HostCommunicators in tests carry no tracer."""
         return maybe_span(getattr(self, "tracer", None), "ring",
                           kind=kind, world=self._world,
-                          rank=self._rank)
+                          rank=self._rank, lane=lane)
 
-    def _drain_queue(self, reason: str) -> None:
-        while True:
-            try:
-                item = self._ops.get_nowait()
-            except queue.Empty:
-                return
-            if item is not None:
-                item[0].set_exception(CommunicatorError(reason))
+    def _drain_queues(self, reason: str) -> None:
+        for ops in self._ops:
+            while True:
+                try:
+                    item = ops.get_nowait()
+                except queue.Empty:
+                    break
+                if item is not None:
+                    item[0].set_exception(CommunicatorError(reason))
+
+    def _count(self, **deltas: float) -> None:
+        """Add to the cumulative counters (``_counts``), which every
+        lane's worker writes."""
+        with self._lock:
+            for key, n in deltas.items():
+                self._counts[key] += n
 
     # ------------------------------------------------------------ op plumbing
 
     def _submit(self, kind: str, *args: Any) -> Future:
         fut: Future = Future()
-        self._ops.put((fut, self._epoch, kind, args))
+        lane = 0
+        with self._lock:
+            if not self._workers and not self._shutdown:
+                self._workers = [
+                    threading.Thread(target=self._run, args=(lane,),
+                                     daemon=True, name=f"host-comm-{lane}")
+                    for lane in range(len(self._ops))]
+                for w in self._workers:
+                    w.start()
+            epoch = self._epoch
+            # The lane of a wire op is its ordinal among the epoch's
+            # wire ops modulo the lane count (see _wire_ordinal, in
+            # __init__, for what a rank that disagrees turns into).
+            # Everything else keeps lane 0 and its submission order.
+            if kind in _WIRE_KINDS and len(self._rings) > 1:
+                lane = self._wire_ordinal % len(self._rings)
+                self._wire_ordinal += 1
+        self._ops[lane].put((fut, epoch, kind, args))
         return fut
 
-    def _run(self) -> None:
+    def _run(self, lane: int) -> None:
+        ops = self._ops[lane]
         while True:
-            item = self._ops.get()
+            item = ops.get()
             if item is None:
                 return
             fut, epoch, kind, args = item
             try:
-                with self._lock:
-                    ring = self._ring
-                    if epoch != self._epoch:
-                        raise CommunicatorError("aborted by reconfigure")
-                # One `ring` span per op on the comm worker
-                # (docs/design/observability.md): send/recv of a whole
-                # wire op, queue wait excluded (the Manager's
-                # allreduce_ring_ms_total includes it — the two
-                # together attribute "slow ring" to wire vs backlog).
-                with self._ring_span(kind):
-                    if kind == "allreduce":
-                        fut.set_result(self._do_allreduce(ring, *args))
-                    elif kind == "allreduce_wire":
-                        fut.set_result(
-                            self._do_allreduce_wire(ring, *args))
-                    elif kind == "reduce_scatter_wire":
-                        fut.set_result(
-                            self._do_reduce_scatter_wire(ring, *args))
-                    elif kind == "broadcast":
-                        fut.set_result(self._do_broadcast(ring, *args))
-                    elif kind == "allgather":
-                        fut.set_result(self._do_allgather(ring, *args))
-                    else:
-                        raise CommunicatorError(f"unknown op {kind}")
+                fut.set_result(self._run_op(lane, epoch, kind, args))
             except Exception as e:  # noqa: BLE001
                 fut.set_exception(
                     e if isinstance(e, CommunicatorError)
@@ -796,6 +857,36 @@ class HostCommunicator(Communicator):
             # stay on the device until the NEXT exchange (two extra
             # gradient trees per group — 3.5 GiB at Llama-2-7B widths).
             del item, fut, args
+
+    def _run_op(self, lane: int, epoch: int, kind: str, args: Any) -> Any:
+        with self._lock:
+            if epoch != self._epoch:
+                raise CommunicatorError("aborted by reconfigure")
+            ring = self._rings[lane] if lane < len(self._rings) else None
+            if kind in _WIRE_KINDS and self._on_wire:
+                self._counts["overlapped_ops"] += 1
+            self._on_wire += 1
+        try:
+            # One `ring` span per op on its lane's worker
+            # (docs/design/observability.md): send/recv of a whole
+            # wire op, queue wait excluded (the Manager's
+            # allreduce_ring_ms_total includes it — the two
+            # together attribute "slow ring" to wire vs backlog).
+            with self._ring_span(kind, lane):
+                if kind == "allreduce":
+                    return self._do_allreduce(ring, *args)
+                if kind == "allreduce_wire":
+                    return self._do_allreduce_wire(ring, *args)
+                if kind == "reduce_scatter_wire":
+                    return self._do_reduce_scatter_wire(ring, *args)
+                if kind == "broadcast":
+                    return self._do_broadcast(ring, *args)
+                if kind == "allgather":
+                    return self._do_allgather(ring, *args)
+                raise CommunicatorError(f"unknown op {kind}")
+        finally:
+            with self._lock:
+                self._on_wire -= 1
 
     # ------------------------------------------------------------ collectives
 
@@ -902,14 +993,16 @@ class HostCommunicator(Communicator):
         the ring can therefore never write memory a caller still reads.
         Above glibc's mmap threshold a fresh buffer is never-touched
         pages, a fault a page at the ring's pace: reuse is what takes
-        the first touch of gradient-sized memory off the op worker."""
+        the first touch of gradient-sized memory off the op workers. As
+        many are out at once as there are lanes, so the kept set
+        settles, after the first step, that many buffers larger."""
         with self._lock:
             free = self._accum_free.get((like.dtype.str, like.size))
             acc = free.pop() if free else None
+            self._counts["accum_alloc" if acc is None
+                         else "accum_reuse"] += 1
         if acc is None:
-            self._accum_alloc += 1
             return np.empty(like.size, like.dtype)
-        self._accum_reuse += 1
         return acc
 
     def _drop_accums(self) -> None:
@@ -936,11 +1029,20 @@ class HostCommunicator(Communicator):
                     self._keep_accum(b)
 
     def accum_counters(self) -> Tuple[float, float, float]:
-        return (self._host_copy_bytes, self._accum_reuse,
-                self._accum_alloc)
+        with self._lock:
+            c = self._counts
+            return (c["host_copy_bytes"], c["accum_reuse"],
+                    c["accum_alloc"])
 
     def ring_step_counters(self) -> Tuple[float, float]:
-        return (self._ring_native_steps, self._ring_python_steps)
+        with self._lock:
+            return (self._counts["native_steps"],
+                    self._counts["python_steps"])
+
+    def ring_lane_counters(self) -> Tuple[float, float]:
+        with self._lock:
+            return (float(len(self._rings)),
+                    self._counts["overlapped_ops"])
 
     def _ring_source(self, buf: Any) -> np.ndarray:
         """One wire buffer as the contiguous 1-D array the ring reads
@@ -949,7 +1051,7 @@ class HostCommunicator(Communicator):
         a = np.asarray(buf)
         flat = np.ravel(a)
         if not np.may_share_memory(flat, a):
-            self._host_copy_bytes += flat.nbytes
+            self._count(host_copy_bytes=flat.nbytes)
         return flat
 
     def _ring_allreduce_buffer(self, ring: _Ring, src: np.ndarray,
@@ -972,7 +1074,7 @@ class HostCommunicator(Communicator):
             ring, src, acc)
         for step in range(n - 1):
             send_view = chunk_bytes(rank + 1 - step)
-            self._ring_bytes += len(send_view)
+            self._count(ring_bytes=len(send_view))
             fut = ring.send_async(send_view)
             recv_chunk(rank - step)
             fut.result()
@@ -1023,7 +1125,7 @@ class HostCommunicator(Communicator):
             # later one is the chunk folded into acc the step before.
             send_view = chunk_bytes(rank - step,
                                     acc_bytes if step else src_bytes)
-            self._ring_bytes += len(send_view)
+            self._count(ring_bytes=len(send_view))
             fut = ring.send_async(send_view)
             recv_chunk(rank - step - 1, fold=True)
             fut.result()
@@ -1080,7 +1182,7 @@ class HostCommunicator(Communicator):
                 _recv_exact_into(
                     sock, acc_bytes[bounds[c] * itemsize:
                                     bounds[c + 1] * itemsize])
-            self._ring_python_steps += 1
+            self._count(python_steps=1)
 
         return recv
 
@@ -1129,7 +1231,7 @@ class HostCommunicator(Communicator):
                     raise CommunicatorError(str(e)) from e
                 finally:
                     os.close(fd)
-            self._ring_native_steps += 1
+            self._count(native_steps=1)
 
         return recv
 
@@ -1154,7 +1256,7 @@ class HostCommunicator(Communicator):
         # `rank`, so receive it straight into place while streaming our
         # owned chunk to next.
         send_view = chunk_bytes(rank + 1)
-        self._ring_bytes += len(send_view)
+        self._count(ring_bytes=len(send_view))
         fut = ring.send_async(send_view)
         recv_chunk(rank)
         fut.result()
@@ -1356,7 +1458,7 @@ class HostCommunicator(Communicator):
             # bitwise-identical results — and bitwise-identical to the
             # upcast-before-ring path they replace.
             acc = wire_buf.astype(orig)
-            self._ring_bytes += nbytes
+            self._count(ring_bytes=nbytes)
             fut = ring.send_async(send_view)
             scratch = bytearray(min(_SEG_BYTES, max(nbytes, 1)))
             sv = memoryview(scratch)
@@ -1378,7 +1480,7 @@ class HostCommunicator(Communicator):
         bufs: List[Optional[np.ndarray]] = [None] * n
         bufs[rank] = wire_buf
         for step in range(n - 1):
-            self._ring_bytes += nbytes
+            self._count(ring_bytes=nbytes)
             fut = ring.send_async(send_view)
             recv = np.empty(size, wdt)
             _recv_exact_into(ring.prev_sock, _as_bytes(recv))
@@ -1406,7 +1508,7 @@ class HostCommunicator(Communicator):
         bufs[rank] = a
         send_view = _as_bytes(a)
         for step in range(n - 1):
-            self._ring_bytes += nbytes
+            self._count(ring_bytes=nbytes)
             fut = ring.send_async(send_view)
             recv = np.empty(size, wdt)
             _recv_exact_into(ring.prev_sock, _as_bytes(recv))
@@ -1528,8 +1630,7 @@ class HostCommunicator(Communicator):
         raw[rank] = w
         send_view: Any = memoryview(payload)
         for step in range(n - 1):
-            self._ring_bytes += nbytes
-            self._ring_bytes_int8 += nbytes
+            self._count(ring_bytes=nbytes, ring_bytes_int8=nbytes)
             fut = ring.send_async(send_view)
             recv = bytearray(nbytes)
             _recv_exact_into(ring.prev_sock, memoryview(recv))
@@ -1646,7 +1747,7 @@ class HostCommunicator(Communicator):
             plo, phi = int(bounds[peer]), int(bounds[peer + 1])
             send_view = _as_bytes(
                 np.ascontiguousarray(wire_buf[plo:phi]))
-            self._ring_bytes += len(send_view)
+            self._count(ring_bytes=len(send_view))
             fut = ring.send_async(send_view)
             acc = wire_buf[lo:hi].astype(orig)
             nbytes = (hi - lo) * wdt.itemsize
@@ -1672,7 +1773,7 @@ class HostCommunicator(Communicator):
         bufs: List[Optional[np.ndarray]] = [None] * n
         bufs[rank] = wire_buf
         for step in range(n - 1):
-            self._ring_bytes += nbytes
+            self._count(ring_bytes=nbytes)
             fut = ring.send_async(send_view)
             recv = np.empty(size, wdt)
             _recv_exact_into(ring.prev_sock, _as_bytes(recv))
@@ -1814,10 +1915,8 @@ class HostCommunicator(Communicator):
                     recv_chunks.extend(pl)
                 for f in futs:
                     f.result()
-                self._ring_bytes += sent
-                self._hier_leader_bytes += sent
-                if all_int8:
-                    self._ring_bytes_int8 += sent
+                self._count(ring_bytes=sent, hier_leader_bytes=sent,
+                            ring_bytes_int8=sent if all_int8 else 0)
                 send_chunks = recv_chunks  # forward along the ring
 
     def _do_wire_hier(self, topo: "_HierTopo", kind: str,
@@ -1845,7 +1944,7 @@ class HostCommunicator(Communicator):
                     _send_all(topo.up_sock, hdr)
                     for p in parts:
                         _send_all(topo.up_sock, p)
-                    self._hier_intra_bytes += rec_bytes
+                    self._count(hier_intra_bytes=rec_bytes)
                 with self._hier_span("hier_intra", kind=kind,
                                      leg="down"):
                     # The leader elides THIS member's own record from
@@ -1892,7 +1991,7 @@ class HostCommunicator(Communicator):
                         s = topo.member_socks[m]
                         _send_all(s, down[:offs[m]])
                         _send_all(s, down[offs[m + 1]:])
-                        self._hier_intra_bytes += (n - 1) * rec_bytes
+                        self._count(hier_intra_bytes=(n - 1) * rec_bytes)
         except Exception as e:
             if topo.is_leader:
                 self._hier_abort_down(topo)
@@ -2019,10 +2118,12 @@ class HostCommunicator(Communicator):
         return self._rank
 
     def ring_bytes_total(self) -> float:
-        return self._ring_bytes
+        with self._lock:
+            return self._counts["ring_bytes"]
 
     def int8_ring_bytes_total(self) -> float:
-        return self._ring_bytes_int8
+        with self._lock:
+            return self._counts["ring_bytes_int8"]
 
     def ring_topology(self) -> str:
         topo = self._hier
@@ -2032,13 +2133,15 @@ class HostCommunicator(Communicator):
                 f"{max(len(ms) for ms in topo.hosts)}")
 
     def hier_intra_bytes_total(self) -> float:
-        return self._hier_intra_bytes
+        with self._lock:
+            return self._counts["hier_intra_bytes"]
 
     def hier_leader_bytes_total(self) -> float:
         """The cross-host leader-ring slice of :meth:`ring_bytes_total`
         — the bytes the hierarchy exists to shrink (scales with hosts,
         not groups)."""
-        return self._hier_leader_bytes
+        with self._lock:
+            return self._counts["hier_leader_bytes"]
 
     def hier_leader(self) -> float:
         topo = self._hier
@@ -2048,17 +2151,20 @@ class HostCommunicator(Communicator):
         if self._shutdown:
             return
         self._shutdown = True
-        self._drain_queue("communicator shutdown")
-        self._ops.put(None)
+        self._drain_queues("communicator shutdown")
+        for ops in self._ops:
+            ops.put(None)
         with self._lock:
-            ring, self._ring = self._ring, None
+            rings, self._rings = self._rings, []
             topo, self._hier = self._hier, None
+            workers = self._workers  # none start once _shutdown is set
             self._drop_accums()
-        if ring is not None:
+        for ring in rings:
             ring.close()
         if topo is not None:
             topo.close()
-        self._worker.join(timeout=5)
+        for w in workers:
+            w.join(timeout=5)
 
 
 # Wire-op preamble magic (see _wire_preamble): distinguishes a format

@@ -1150,6 +1150,9 @@ class ChaosCommunicator(Communicator):
     def ring_step_counters(self) -> Any:
         return self._comm.ring_step_counters()
 
+    def ring_lane_counters(self) -> Any:
+        return self._comm.ring_lane_counters()
+
     def ring_bytes_total(self) -> float:
         return self._comm.ring_bytes_total()
 

@@ -416,6 +416,17 @@ class Communicator(ABC):
         ``allreduce_ring_python_steps_total``. Wrappers MUST forward."""
         return (0.0, 0.0)
 
+    def ring_lane_counters(self) -> Tuple[float, float]:
+        """``(lanes, overlapped_ops)``: the lanes of the ring in force
+        (socket pairs an epoch, each with an op worker of its own, wire
+        ops dealt to them by ordinal: a gauge; 1 under the hierarchical
+        transport, 0 at world 1 and for a backend without a ring), and,
+        cumulative, the wire ops that began while another lane's op was
+        on the wire. Surfaced by the Manager as ``allreduce_ring_lanes``
+        / ``allreduce_ring_overlapped_ops_total``. Wrappers MUST
+        forward."""
+        return (0.0, 0.0)
+
     def ring_bytes_total(self) -> float:
         """Cumulative allreduce payload bytes this rank has *sent* over
         the collective transport, surfaced by the Manager as
@@ -761,6 +772,9 @@ class ErrorSwallowingCommunicator(Communicator):
     def ring_step_counters(self) -> Tuple[float, float]:
         return self._comm.ring_step_counters()
 
+    def ring_lane_counters(self) -> Tuple[float, float]:
+        return self._comm.ring_lane_counters()
+
     def ring_bytes_total(self) -> float:
         return self._comm.ring_bytes_total()
 
@@ -915,6 +929,9 @@ class ManagedCommunicator(Communicator):
 
     def ring_step_counters(self) -> Tuple[float, float]:
         return self._comm.ring_step_counters()
+
+    def ring_lane_counters(self) -> Tuple[float, float]:
+        return self._comm.ring_lane_counters()
 
     def ring_bytes_total(self) -> float:
         return self._comm.ring_bytes_total()

@@ -3680,6 +3680,17 @@ class Manager:
             native = python = 0.0
         out["allreduce_ring_native_steps_total"] = native
         out["allreduce_ring_python_steps_total"] = python
+        # The ring's lanes (Communicator.ring_lane_counters): how many
+        # are in force, and the wire ops that began while another
+        # lane's op was on the wire. With every lane busy that is all
+        # of allreduce_ring_ops_total but a step's first.
+        lane_counters = getattr(self._comm, "ring_lane_counters", None)
+        try:
+            lanes, overlapped = map(float, lane_counters())
+        except (TypeError, ValueError):  # bare duck-typed / mocked comms
+            lanes = overlapped = 0.0
+        out["allreduce_ring_lanes"] = lanes
+        out["allreduce_ring_overlapped_ops_total"] = overlapped
         # Hierarchical-transport legs (docs/design/hier_transport.md):
         # loopback intra-host bytes (traffic that stopped crossing the
         # DCN ring) and whether this rank leads its host's star. 0 on
