@@ -28,6 +28,7 @@ import json  # noqa: E402
 import math  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
+import statistics  # noqa: E402
 import subprocess  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
@@ -49,6 +50,7 @@ import warnings  # noqa: E402
 warnings.filterwarnings("ignore", message="Some donated buffers")
 
 KNOWN_TPU_KINDS = ("TPU v5 lite", "TPU v5e")
+STALL_FACTOR = 3.0    # a stalled step: over three times the median wall
 
 
 def log(msg: str) -> None:
@@ -72,6 +74,17 @@ def step_walls(steps: List[Dict[str, Any]], groups: int
         if s["committed"] and s["world"] == groups:
             walls.append((s["t1"] - start) / 1e9)
     return walls
+
+
+def stalled_steps(walls: List[float]) -> int:
+    """How many walls are over ``STALL_FACTOR`` times the median wall. No
+    metric reads it: ``tokens_per_s`` keeps a stall as somebody's time, and
+    the count lets a reader of the ledger tell a stalled run from a slow
+    tree."""
+    if not walls:
+        return 0
+    limit = STALL_FACTOR * statistics.median(walls)
+    return sum(w > limit for w in walls)
 
 
 def end_to_end(run: Dict[str, Any]) -> Dict[str, Optional[float]]:
@@ -116,6 +129,7 @@ def judge(run: Dict[str, Any], limits: Dict[str, float],
                 if any(w is not None and s["t1"] >= w[0] and s["t0"] <= w[1]
                        for w in spans)]
     lines, ok = [], not errors
+    compared: Dict[str, Dict[str, Optional[float]]] = {}
     for e in errors:
         lines.append(f"error: {e}")
 
@@ -123,6 +137,7 @@ def judge(run: Dict[str, Any], limits: Dict[str, float],
         nonlocal ok
         good = value is not None and value <= limit
         ok = ok and good
+        compared[name] = {"value": value, "limit": limit}
         lines.append(f"check {name}: {value} (limit {limit}) "
                      f"{'ok' if good else 'NOT CORRECT'}")
 
@@ -144,7 +159,7 @@ def judge(run: Dict[str, Any], limits: Dict[str, float],
         compare("recoveries_missing",
                 float(sum(w is None for w in spans)), 0.0)
     return {"correct": bool(ok), "attempted": len(window),
-            "failed": len(aborted), "lines": lines}
+            "failed": len(aborted), "lines": lines, "compared": compared}
 
 
 # ----------------------------------------------------- one process's part
@@ -310,10 +325,12 @@ def finish(args: argparse.Namespace, cell: Cell, parts: List[Dict[str, Any]]
            "events_spec": cell.mix["events"]}
     run["steps"] = {int(g): s for g, s in run["steps"].items()}  # JSON keys
     verdict = judge(run, load_limits(cell), digests, compiled, errors)
-    for line in verdict["lines"]:
-        log(line)
     for note in lead.get("notes", []):
         log(f"note: {note}")
+    walls = step_walls(run["steps"].get(0, []), run["groups"])
+    counts = {"window_joint_steps": len(walls),
+              "stalled_steps": stalled_steps(walls)}
+    log("window joint walls (s): " + " ".join(f"{w:.3f}" for w in walls))
     device = dict(lead["device"])
     device["count"] = sum(p["device"]["count"] for p in parts)
     device["memory_peak_bytes"] = max(
@@ -329,12 +346,16 @@ def finish(args: argparse.Namespace, cell: Cell, parts: List[Dict[str, Any]]
     result = {"correct": verdict["correct"],
               "attempted": verdict["attempted"],
               "failed": verdict["failed"], "metrics": metrics,
-              "device": device}
+              "device": device, **counts}
     if args.trace and "breakdown" in lead:
         result["breakdown"] = lead["breakdown"]
+    result["compared"] = verdict["compared"]    # last in the line
     if device["platform"] != "tpu" and not args.rehearse:
         log("no result: not a TPU")
         return 1
+    for line in verdict["lines"]:               # last on standard error
+        log(line)
+        sys.stderr.write(line + "\n")
     sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0
@@ -424,7 +445,22 @@ def orchestrate(args: argparse.Namespace, cell: Cell, sync_dir: str,
 
 # --------------------------------------------------------------------- main
 
-def main(argv: Optional[List[str]] = None) -> int:
+def start_with(env: Dict[str, str]) -> None:
+    """Start this process anew, by its own command line, with ``env`` in its
+    environment, where it does not have it yet. A traffic mix's ``env`` is
+    what an operator sets for the job's process, and some of it (the C
+    library's allocator reads ``MALLOC_*`` before ``main``) only a process
+    started with it obeys. The clock of ``setup_s`` keeps running: the new
+    start is told when the first one began."""
+    if all(os.environ.get(k) == v for k, v in env.items()):
+        return
+    os.environ.update(env)
+    sys.stdout.flush()
+    os.execv(sys.executable,
+             [*sys.orig_argv, "--t-process", str(T_PROCESS_NS)])
+
+
+def main(argv: Optional[List[str]] = None, may_restart: bool = False) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
@@ -455,7 +491,10 @@ def main(argv: Optional[List[str]] = None) -> int:
         log(f"CONTROL RUN, not a measurement: mix overridden with "
             f"{args.override}")
     mix = cell.mix
-    os.environ.update({k: str(v) for k, v in mix.get("env", {}).items()})
+    env = {k: str(v) for k, v in mix.get("env", {}).items()}
+    if may_restart and args.group is None:
+        start_with(env)
+    os.environ.update(env)
     if args.trace:
         # The Tracer keeps 64 steps of spans by default; a traced window
         # reads all of its steps' spans at the end.
@@ -483,4 +522,4 @@ def main(argv: Optional[List[str]] = None) -> int:
 
 
 if __name__ == "__main__":
-    sys.exit(main())
+    sys.exit(main(may_restart=True))
