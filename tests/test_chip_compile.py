@@ -163,6 +163,52 @@ def test_latent_flash_compiles_at_two_head_sizes(one_chip, monkeypatch,
     assert "bf16[32,8192,128]" in text and "bf16[32,8192,192]" in text
 
 
+@pytest.mark.parametrize("fused_bwd", ["1", "0"], ids=["fused", "split"])
+def test_flash_compiles_at_head_256_on_2_kv_heads(one_chip, monkeypatch,
+                                                  fused_bwd):
+    """[1, 8192, 16, 256] queries on [1, 8192, 2, 256] keys and values, bf16
+    causal (the full-attention layers of ``qwen3-next-80b-a3b`` at its
+    cell's length): 512-token tiles inside scoped VMEM, the key/value heads
+    shared through the index maps and not repeated in memory."""
+    monkeypatch.setenv("TORCHFT_FLASH_FUSED_BWD", fused_bwd)
+    q = jax.ShapeDtypeStruct((1, 8192, 16, 256), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 8192, 2, 256), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True,
+                               interpret=False).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    assert text.count("tpu_custom_call") >= (2 if fused_bwd == "1" else 3)
+    assert "bf16[16,8192,256]" in text and "bf16[2,8192,256]" in text
+
+
+@pytest.mark.parametrize("tokens", [8192, 8192 + 96], ids=["8k", "ragged"])
+def test_gated_delta_rule_compiles_at_the_published_sizes(one_chip, tokens):
+    """The chunked scan forward and backward at 16 key heads, 32 value heads
+    of 128 (``ops/gated_delta.py``; XLA loops and fusions, no kernel): the
+    triangular solve of the [64, 64] blocks and the scan lower for the chip,
+    and the state the loop carries is float32 [1, 32, 128, 128]."""
+    from torchft_tpu.ops import gated_delta_rule
+
+    def shaped(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (shaped(1, tokens, 16, 128), shaped(1, tokens, 16, 128),
+            shaped(1, tokens, 32, 128),
+            shaped(1, tokens, 32, dtype=jnp.float32),
+            shaped(1, tokens, 32, dtype=jnp.float32))
+    c = jax.jit(jax.grad(lambda *a: gated_delta_rule(*a).sum(),
+                         argnums=(0, 1, 2, 3, 4))).lower(*args).compile()
+    text = c.as_text()
+    assert "while(" in text and "f32[1,32,128,128]" in text
+    assert "tpu_custom_call" not in text
+    assert _footprint(c) < 3 * 2**30
+
+
 def test_one_group_step_depth2_fits_the_chip(one_chip):
     """Phase 2: the single-group fused step holds the old and the new
     params + adam state at once (it is not donated), which at depth 2 is
@@ -319,6 +365,8 @@ CELL_STEPS = {
     "trinity-mini.steady-1g-8k": ("flash_fwd_window", "gmm"),
     "joyai-llm-flash.steady-1g-8k": ("flash_fwd_mla", "flash_bwd_mla",
                                      "gmm"),
+    "qwen3-next-80b-a3b.steady-1g-8k": ("%attn", "gmm", "f32[1,32,128,128]",
+                                        "bf16[16,8192,256]"),
 }
 
 
@@ -330,9 +378,11 @@ def test_sparse_cells_step_fits_the_chip(one_chip, name):
     sequences, adamw. ``trinity-mini``: windowed and full flash kernels;
     ``joyai-llm-flash``: the 192/128 latent kernels (forward and the fused
     backward) in four layers and the prediction module, two loss scans over
-    one head. The grouped matmuls compile as Mosaic custom calls, and the
-    step fits with the room the driver's oracle needs beside it for one
-    more seeded tree."""
+    one head; ``qwen3-next-80b-a3b``: three Gated DeltaNet layers (the
+    scans that carry ``f32[1,32,128,128]``) and one full layer's flash
+    kernel at 16 heads of 256, 16 of 512 experts held. The grouped matmuls
+    compile as Mosaic custom calls, and the step fits with the room the
+    driver's oracle needs beside it for one more seeded tree."""
     import sys
 
     bench = os.path.join(os.path.dirname(os.path.dirname(

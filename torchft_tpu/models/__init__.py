@@ -15,8 +15,10 @@ from torchft_tpu.models.transformer import (
     tp_rules,
 )
 from torchft_tpu.models.mla import LatentAttention
+from torchft_tpu.models.linear_attention import GatedDeltaNet
 
 __all__ = [
+    "GatedDeltaNet",
     "LatentAttention",
     "MLP",
     "MoEMLP",

@@ -389,6 +389,8 @@ class RoutedMoEMLP(nn.Module):
         held: ``(first, count)``: the experts whose weights this layer has
             and whose part it computes; ``None`` holds all.
         shared_dim: hidden width of the shared expert (0: none).
+        shared_gate: the shared expert's output times ``sigmoid(u . w_s)``,
+            ``w_s`` one column of its own (``shared_gate/kernel`` [D, 1]).
         score / route_norm / route_scale: the router (:func:`route`).
         pass_rows: sorted pairs taken through the experts at a time.
         interpret: run the Pallas grouped matmul interpreted (``None``: off
@@ -423,6 +425,7 @@ class RoutedMoEMLP(nn.Module):
     top_k: int = 2
     held: Optional[Tuple[int, int]] = None
     shared_dim: int = 0
+    shared_gate: bool = False
     score: str = "sigmoid"
     route_norm: bool = True
     route_scale: float = 1.0
@@ -453,7 +456,13 @@ class RoutedMoEMLP(nn.Module):
         shared = None
         if self.shared_dim:
             shared = _SharedExpert(self.shared_dim, self.dtype,
-                                   name="shared")(x).reshape(t, d)
+                                   name="shared")(x)
+            if self.shared_gate:
+                gate = nn.Dense(1, use_bias=False, dtype=self.dtype,
+                                name="shared_gate")(x)
+                shared = shared * jax.nn.sigmoid(
+                    gate.astype(jnp.float32)).astype(shared.dtype)
+            shared = shared.reshape(t, d)
 
         router = nn.Dense(e, use_bias=False, dtype=jnp.float32,
                           precision=jax.lax.Precision.HIGHEST, name="router")

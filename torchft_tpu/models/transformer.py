@@ -53,16 +53,26 @@ class TransformerConfig:
     moe_score: str = "sigmoid"           # routed dispatch only
     moe_route_norm: bool = True
     moe_route_scale: float = 1.0
+    moe_shared_gate: bool = False        # sigmoid(u . w) on the shared expert
     moe_dense_layers: int = 0            # leading layers that keep a dense MLP
     moe_interpret: Optional[bool] = None  # routed: Pallas interpreted (None:
     #                                       off a TPU)
-    # Layers of different kinds. ``layer_types[i]`` is "full_attention" or
+    # Layers of different kinds. ``layer_types[i]`` is "full_attention",
     # "sliding_attention" (key j visible to query i iff 0 <= i - j <
-    # ``sliding_window``); None = every layer full. ``rope_full_layers=False``
-    # leaves rotary off the full-attention layers.
+    # ``sliding_window``) or "linear_attention" (a Gated DeltaNet mixer,
+    # models/linear_attention.py, at the ``linear_*`` sizes below); None =
+    # every layer full. ``rope_full_layers=False`` leaves rotary off the
+    # full-attention layers; ``rotary_dim`` turns only the leading
+    # ``rotary_dim`` dims of a head (None: the whole head).
     layer_types: Optional[Tuple[str, ...]] = None
     sliding_window: Optional[int] = None
     rope_full_layers: bool = True
+    rotary_dim: Optional[int] = None
+    linear_key_heads: int = 0
+    linear_key_dim: int = 0
+    linear_value_heads: int = 0
+    linear_value_dim: int = 0
+    linear_conv_kernel: int = 4
     # The attention block's options, all off in the Llama recipe: a stated
     # head size (None → embed_dim / num_heads), RMSNorm of queries and keys
     # per head, a sigmoid gate on the attention output, a second norm on
@@ -120,6 +130,7 @@ class TransformerConfig:
 
 
 FULL, SLIDING = "full_attention", "sliding_attention"
+LINEAR = "linear_attention"
 
 
 def _norm(cfg: "TransformerConfig", name: str) -> "RMSNorm":
@@ -176,6 +187,17 @@ def rotary_interleaved(x: jnp.ndarray, positions: jnp.ndarray,
     return (x32 * cos + swapped * sin).astype(x.dtype)
 
 
+def _rotary_leading(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+                    rotary_dim: Optional[int]) -> jnp.ndarray:
+    """:func:`rotary` over the first ``rotary_dim`` dims of each head (its
+    halves paired, angles ``theta ** (-2i / rotary_dim)``); the rest pass."""
+    if not rotary_dim or rotary_dim == x.shape[-1]:
+        return rotary(x, positions, theta)
+    return jnp.concatenate(
+        [rotary(x[..., :rotary_dim], positions, theta), x[..., rotary_dim:]],
+        axis=-1)
+
+
 def plain_attention(q, k, v, causal: bool = True,
                     window: Optional[int] = None):
     """Reference softmax attention; q: [B, S, H, D], k/v may carry fewer
@@ -228,8 +250,8 @@ class Attention(nn.Module):
             q = _norm(cfg, "q_norm")(q)
             k = _norm(cfg, "k_norm")(k)
         if sliding or cfg.rope_full_layers:
-            q = rotary(q, positions, cfg.rope_theta)
-            k = rotary(k, positions, cfg.rope_theta)
+            q = _rotary_leading(q, positions, cfg.rope_theta, cfg.rotary_dim)
+            k = _rotary_leading(k, positions, cfg.rope_theta, cfg.rotary_dim)
         attn = cfg.attention_fn or plain_attention
         if (cfg.kv_heads != cfg.num_heads
                 and not getattr(attn, "supports_gqa", False)):
@@ -268,10 +290,12 @@ class MLPBlock(nn.Module):
 
 
 class DecoderLayer(nn.Module):
-    """One layer: attention of ``kind`` and a dense or (``moe``) an expert
-    MLP, pre-norm; with ``cfg.sandwich_norm`` each sub-block's output is
-    normed again before it joins the stream. Returns the stream, and with a
-    routed expert layer ``(stream, that layer's int32[3] counts)``."""
+    """One layer: a mixer of ``kind`` (attention, or the Gated DeltaNet of
+    ``"linear_attention"``) and a dense or (``moe``) an expert MLP,
+    pre-norm; with ``cfg.sandwich_norm`` each sub-block's output is normed
+    again before it joins the stream. Returns the stream, and where the
+    layer has numbers for the program counters (a routed expert layer, a
+    linear-attention mixer) ``(stream, {counter: value})``."""
 
     cfg: TransformerConfig
     kind: str = FULL
@@ -280,13 +304,23 @@ class DecoderLayer(nn.Module):
     @nn.compact
     def __call__(self, x, positions):
         cfg = self.cfg
-        if cfg.kv_lora_rank:
+        counts = {}
+        h = _norm(cfg, "attn_norm")(x)
+        if self.kind == LINEAR:
+            from torchft_tpu.models.linear_attention import (GDN_COUNTERS,
+                                                             GatedDeltaNet)
+
+            a, (chunks, log_decay) = GatedDeltaNet(cfg, name="attn")(
+                h, return_stats=True)
+            # the step's mean over its linear layers, in millionths
+            share = 1e6 / sum(t == LINEAR for t in cfg.layer_types)
+            counts.update(zip(GDN_COUNTERS, (chunks, log_decay * share)))
+        elif cfg.kv_lora_rank:
             from torchft_tpu.models.mla import LatentAttention
 
-            attention = LatentAttention(cfg, name="attn")
+            a = LatentAttention(cfg, name="attn")(h, positions)
         else:
-            attention = Attention(cfg, kind=self.kind, name="attn")
-        a = attention(_norm(cfg, "attn_norm")(x), positions)
+            a = Attention(cfg, kind=self.kind, name="attn")(h, positions)
         if cfg.sandwich_norm:
             a = _norm(cfg, "post_attn_norm")(a)
         x = x + a
@@ -297,7 +331,8 @@ class DecoderLayer(nn.Module):
             mlp = RoutedMoEMLP(
                 num_experts=cfg.moe_experts, top_k=cfg.moe_top_k,
                 mlp_dim=cfg.moe_dim or cfg.mlp_dim, held=cfg.moe_held,
-                shared_dim=cfg.moe_shared_dim, score=cfg.moe_score,
+                shared_dim=cfg.moe_shared_dim,
+                shared_gate=cfg.moe_shared_gate, score=cfg.moe_score,
                 route_norm=cfg.moe_route_norm,
                 route_scale=cfg.moe_route_scale, dtype=cfg.dtype,
                 interpret=cfg.moe_interpret, name="moe")
@@ -313,12 +348,25 @@ class DecoderLayer(nn.Module):
         else:
             mlp = MLPBlock(cfg, name="mlp")
         u = _norm(cfg, "mlp_norm")(x)
-        # A routed layer's counts leave the (rematerialised) layer as
-        # values: Transformer counts them once a step.
-        m, stats = mlp(u, return_stats=True) if routed else (mlp(u), None)
+        # The counts leave the (rematerialised) layer as values:
+        # Transformer counts them once a step.
+        if routed:
+            from torchft_tpu.models.moe import MOE_COUNTERS
+
+            m, stats = mlp(u, return_stats=True)
+            counts.update(zip(MOE_COUNTERS, stats))
+        else:
+            m = mlp(u)
         if cfg.sandwich_norm:
             m = _norm(cfg, "post_mlp_norm")(m)
-        return (x + m, stats) if routed else x + m
+        return (x + m, counts) if counts else x + m
+
+
+def _add_counts(total: Optional[dict], counts: dict) -> dict:
+    total = dict(total or {})
+    for key, value in counts.items():
+        total[key] = total[key] + value if key in total else value
+    return total
 
 
 class MTPModule(nn.Module):
@@ -327,7 +375,8 @@ class MTPModule(nn.Module):
     trunk's own table), ``z = [N_e(embedding) ; N_h(hidden)] W`` (2E -> E),
     one whole decoder layer with weights of its own, a final norm. What it
     returns goes through the trunk's head against token ``i + 2``. Returns
-    ``(z, counts)``: a routed expert layer's int32[3] counts, else None."""
+    ``(z, counts)``: a routed expert layer's ``{counter: value}``, else
+    None."""
 
     cfg: TransformerConfig
 
@@ -363,9 +412,9 @@ class Transformer(nn.Module):
         the prediction module's (position ``i`` predicts token ``i + 2``;
         the last position has no next token and reads the first one's
         embedding, which causal attention keeps from every other), and the
-        routed layers' summed counts (``None`` without routed layers),
-        which are then NOT counted here: the caller counts them with
-        whatever else it counts, in one callback
+        layers' summed counts as ``{counter: value}`` (``None`` where no
+        layer has any), which are then NOT counted here: the caller counts
+        them with whatever else it counts, in one callback
         (:func:`mtp_causal_lm_loss`)."""
         cfg = self.cfg
         if return_mtp and cfg.mtp_layers != 1:
@@ -390,11 +439,11 @@ class Transformer(nn.Module):
                           name=f"layer_{i}")(x, positions)
             if isinstance(x, tuple):
                 x, stats = x
-                moe_stats = stats if moe_stats is None else moe_stats + stats
+                moe_stats = _add_counts(moe_stats, stats)
         if moe_stats is not None and not return_mtp:
-            from torchft_tpu.models.moe import count_moe_stats
+            from torchft_tpu import tracing
 
-            count_moe_stats(moe_stats)
+            tracing.count_in_program(**moe_stats)
         x = _norm(cfg, "final_norm")(x)
         if return_mtp:
             nxt = embed(jnp.roll(tokens, -1, axis=1))
@@ -403,7 +452,7 @@ class Transformer(nn.Module):
             with jax.named_scope("mtp"):
                 z, stats = MTPModule(cfg, name="mtp")(x, nxt, positions)
             if stats is not None:
-                moe_stats = stats if moe_stats is None else moe_stats + stats
+                moe_stats = _add_counts(moe_stats, stats)
             return x, z, moe_stats
         if return_hidden:
             return x
@@ -551,7 +600,6 @@ def mtp_causal_lm_loss(model: "Transformer", params: Any,
     ``loss_main_micro_total`` / ``loss_mtp_micro_total`` (each loss x 1e6,
     summed over steps) to :func:`tracing.program_counters`."""
     from torchft_tpu import tracing
-    from torchft_tpu.models.moe import MOE_COUNTERS
 
     hidden, mtp_hidden, stats = model.apply(params, tokens, return_mtp=True)
     head = params["params"]["lm_head"]["kernel"]
@@ -559,7 +607,7 @@ def mtp_causal_lm_loss(model: "Transformer", params: Any,
     with jax.named_scope("mtp"):
         mtp = chunked_causal_lm_loss(mtp_hidden[:, :-1], head, tokens[:, 1:],
                                      chunk_size)
-    counts = {} if stats is None else dict(zip(MOE_COUNTERS, stats))
+    counts = stats or {}
     tracing.count_in_program(
         loss_main_micro_total=jax.lax.stop_gradient(main) * 1e6,
         loss_mtp_micro_total=jax.lax.stop_gradient(mtp) * 1e6, **counts)
