@@ -367,7 +367,8 @@ def test_routed_sandwich_options_are_bitwise_what_they_were():
     (``trinity-mini``'s options at a tiny size), through the flash kernel and
     remat: tree, loss and gradients as commit 6195c1c computed them on the
     CPU (``tests/golden_latent_pr33.json``; the Llama-style block's are in
-    ``tests/golden_transformer.json``, ``test_afmoe_model.py``)."""
+    ``tests/golden_transformer.json``, ``test_afmoe_model.py``, which also
+    says what PR 41 re-took: the ``lm_head`` gradient's numbers)."""
     with open(os.path.join(REPO, "tests/golden_latent_pr33.json")) as f:
         golden = json.load(f)["transformer"]["routed_sandwich"]
     cfg = tiny_config(

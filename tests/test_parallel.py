@@ -176,6 +176,208 @@ class TestPresets:
                 rtol=1e-5, atol=1e-6),
             gf, gc)
 
+    @pytest.mark.parametrize("matmul_dtype", [None, jnp.bfloat16],
+                             ids=["f32", "bf16"])
+    @pytest.mark.parametrize("chunk_size", [None, 16], ids=["rule", "16"])
+    @pytest.mark.parametrize("batch,seq", [(2, 50), (1, 33), (3, 64),
+                                           (4, 300)])
+    def test_fused_head_loss_matches_full(self, batch, seq, chunk_size,
+                                          matmul_dtype):
+        """The one-scan rule (a custom_vjp: loss, dh and dW from the same
+        pass) against ``causal_lm_loss`` on full logits: the loss and both
+        gradients under a cotangent of 2.5, over sequences that do not
+        divide the chunk ((4, 300) is two chunks of 256 by the rule)."""
+        from torchft_tpu.models import chunked_causal_lm_loss
+
+        e, v = 32, 96
+        k1, k2, k3 = jax.random.split(jax.random.key(batch * seq), 3)
+        hidden = jax.random.normal(k1, (batch, seq, e))
+        head = 0.3 * jax.random.normal(k2, (e, v))
+        tokens = jax.random.randint(k3, (batch, seq), 0, v)
+        mm = jnp.float32 if matmul_dtype is None else matmul_dtype
+
+        def full(h, w):
+            logits = jnp.einsum("bse,ev->bsv", h.astype(mm), w.astype(mm),
+                                preferred_element_type=jnp.float32)
+            return 2.5 * causal_lm_loss(logits, tokens)
+
+        def fused(h, w):
+            return 2.5 * chunked_causal_lm_loss(
+                h, w, tokens, chunk_size=chunk_size,
+                matmul_dtype=matmul_dtype)
+
+        lf, gf = jax.jit(jax.value_and_grad(full, argnums=(0, 1)))(
+            hidden, head)
+        lc, gc = jax.jit(jax.value_and_grad(fused, argnums=(0, 1)))(
+            hidden, head)
+        np.testing.assert_allclose(float(lf), float(lc), rtol=2e-6)
+        # bf16 inputs: the full path's autodiff rounds dW to bf16, the fused
+        # rule keeps it f32
+        tol = 1e-5 if matmul_dtype is None else 5e-3
+        for want, got in zip(gf, gc):
+            assert got.shape == want.shape and got.dtype == want.dtype
+            assert float(jnp.linalg.norm(got - want)) \
+                < tol * float(jnp.linalg.norm(want))
+        # the undifferentiated call is the same number
+        np.testing.assert_allclose(float(jax.jit(fused)(hidden, head)),
+                                   float(lc), rtol=1e-6)
+
+    def test_mtp_head_gradient_is_the_sum_of_the_two_losses(self):
+        """``mtp_causal_lm_loss`` runs the fused rule twice over one head
+        kernel: its gradient is the main loss's plus ``mtp_weight`` times
+        the module's, each taken apart."""
+        from torchft_tpu.models import (chunked_causal_lm_loss,
+                                        mtp_causal_lm_loss, tiny_config)
+
+        model = Transformer(tiny_config(mtp_layers=1))
+        tokens = jnp.asarray(
+            np.random.default_rng(1).integers(0, 256, (2, 40)), jnp.int32)
+        params = {"params": {
+            **model.init(jax.random.key(0), tokens)["params"],
+            **model.init(jax.random.key(0), tokens,
+                         return_mtp=True)["params"]}}
+
+        def part(which):
+            def loss(p):
+                hidden, mtp_hidden, _ = model.apply(p, tokens,
+                                                    return_mtp=True)
+                head = p["params"]["lm_head"]["kernel"]
+                if which == "main":
+                    return chunked_causal_lm_loss(hidden, head, tokens)
+                return chunked_causal_lm_loss(mtp_hidden[:, :-1], head,
+                                              tokens[:, 1:])
+            return jax.jit(jax.grad(loss))(params)["params"]["lm_head"][
+                "kernel"]
+
+        total = jax.jit(jax.grad(
+            lambda p: mtp_causal_lm_loss(model, p, tokens, 0.3)))(
+                params)["params"]["lm_head"]["kernel"]
+        main, mtp = part("main"), part("mtp")
+        assert float(jnp.max(jnp.abs(mtp))) > 0
+        np.testing.assert_allclose(total, main + 0.3 * mtp, rtol=1e-5,
+                                   atol=1e-7)
+
+    def test_fused_head_loss_sharded_matches_one_device(self):
+        """Batch over ``fsdp`` and the head's vocabulary over ``tp``: the
+        partitioner splits the fused scan as it split the differentiated
+        one, and loss and gradients are the one-device ones."""
+        from torchft_tpu.models import chunked_causal_lm_loss
+
+        mesh = make_mesh({"fsdp": 4, "tp": 2})
+        k1, k2, k3 = jax.random.split(jax.random.key(5), 3)
+        hidden = jax.random.normal(k1, (4, 70, 32))
+        head = 0.3 * jax.random.normal(k2, (32, 128))
+        tokens = jax.random.randint(k3, (4, 70), 0, 128)
+
+        def loss(h, w, t):
+            return chunked_causal_lm_loss(h, w, t, chunk_size=16)
+
+        want = jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(
+            hidden, head, tokens)
+        put = lambda x, spec: jax.device_put(x, NamedSharding(mesh, spec))
+        got = jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(
+            put(hidden, P("fsdp")), put(head, P(None, "tp")),
+            put(tokens, P("fsdp")))
+        jax.tree_util.tree_map(
+            lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-5,
+                                                    atol=1e-7), want, got)
+
+    @pytest.mark.parametrize("differentiated", [True, False],
+                             ids=["value_and_grad", "loss_only"])
+    def test_head_loss_program_is_one_scan(self, differentiated):
+        """The compiled program (optimised HLO, here on the CPU): ONE
+        ``while``; differentiated it holds three head products a chunk
+        (logits, dh, dW), alone one; and no array as large as
+        ``[b, s, vocab]`` in either."""
+        import re
+
+        from torchft_tpu.models import chunked_causal_lm_loss
+
+        b, s, e, v, chunk = 2, 100, 32, 96, 8
+        hidden = jnp.ones((b, s, e), jnp.bfloat16)
+        head = jnp.ones((e, v), jnp.float32)
+        tokens = jnp.zeros((b, s), jnp.int32)
+
+        def loss(h, w):
+            return chunked_causal_lm_loss(h, w, tokens, chunk_size=chunk)
+
+        fn = (jax.value_and_grad(loss, argnums=(0, 1)) if differentiated
+              else loss)
+        text = jax.jit(fn).lower(hidden, head).compile().as_text()
+        assert len(re.findall(r" while\(", text)) == 1
+        dots = [line for line in text.splitlines()
+                if re.search(r" (dot|convolution)\(", line)]
+        shapes = sorted(re.search(r"= \w+\[([\d,]+)\]", d).group(1)
+                        for d in dots)
+        rows = b * chunk
+        want = [f"{rows},{v}"] + ([f"{rows},{e}", f"{e},{v}"]
+                                  if differentiated else [])
+        assert shapes == sorted(want), dots
+        largest = max(
+            int(np.prod([int(n) for n in dims.split(",")]))
+            for dims in re.findall(r"(?:f32|bf16|s32|pred)\[([\d,]+)\]",
+                                   text))
+        assert largest < b * (s - 1) * v, largest
+
+    @pytest.mark.parametrize("shape", [
+        (4, 4096, 32000), (1, 8192, 16160), (1, 8192, 18992),
+        (1, 8192, 25024), (1, 4096, 32000), (1, 1024, 92544), (2, 64, 256)],
+        ids=lambda sh: "x".join(map(str, sh)))
+    def test_head_loss_chunk_follows_the_shapes(self, shape):
+        """The chunk rule alone: the benchmark cells' shapes get at least
+        the dW ridge's tokens a chunk (and no more than one multiple of 256
+        over it), InternLM2's vocabulary stays under the tile's byte
+        budget, a short sequence gets one chunk of 256."""
+        from torchft_tpu.models import transformer as T
+
+        batch, seq, vocab = shape
+        chunk = T.head_loss_chunk(batch, seq, vocab)
+        ridge = T._DW_RIDGE_TOKENS
+        assert 900 < ridge < 1024          # 4 x 197e12 / 819e9
+        assert chunk % 256 == 0 and chunk >= 256
+        if shape == (2, 64, 256):
+            assert chunk == 256
+        elif vocab == 92544:
+            assert batch * chunk * vocab * 4 <= T._LOGITS_TILE_BYTES
+            assert chunk == 512
+        else:
+            assert ridge <= batch * chunk < ridge + 256 * batch
+            assert batch * chunk * vocab * 4 <= T._LOGITS_TILE_BYTES
+
+    def test_head_loss_counters_are_counted_at_trace_time(self):
+        """``head_loss_fused_traces_total`` / ``head_loss_chunks_traced_total``
+        are added on the host when the fused rule is traced for a gradient
+        (their ratio is the chunks a loss, as the rule chose them); the
+        undifferentiated call adds nothing, and neither program holds a
+        host callback."""
+        from torchft_tpu import tracing
+        from torchft_tpu.models import chunked_causal_lm_loss
+        from torchft_tpu.models.transformer import head_loss_chunk
+
+        b, s, e, v = 4, 600, 16, 64
+        hidden = jnp.ones((b, s, e), jnp.bfloat16)
+        head = jnp.ones((e, v), jnp.float32)
+        tokens = jnp.zeros((b, s), jnp.int32)
+
+        def loss(h, w):
+            return chunked_causal_lm_loss(h, w, tokens)
+
+        def counters():
+            c = tracing.program_counters()
+            return (c.get("head_loss_fused_traces_total", 0),
+                    c.get("head_loss_chunks_traced_total", 0))
+
+        before = counters()
+        alone = str(jax.make_jaxpr(loss)(hidden, head))
+        assert counters() == before
+        grad = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(
+            hidden, head))
+        after = counters()
+        chunks = -(-(s - 1) // head_loss_chunk(b, s, v))
+        assert chunks == 3
+        assert after == (before[0] + 1, before[1] + chunks)
+        assert "callback" not in alone and "callback" not in grad
+
     def test_remat_matches_plain_gradients(self):
         """cfg.remat trades backward FLOPs for activation memory; values
         and gradients must be bitwise-stable vs the plain path."""

@@ -444,7 +444,8 @@ def test_new_options_off_leave_the_goldens_bitwise(which):
     """Tree, loss and gradients of the blocks the other four configurations
     run, with PR 40's options stated at their off values, as the commits
     before them computed them on the CPU (``tests/golden_transformer.json``,
-    ``tests/golden_latent_pr33.json``)."""
+    ``tests/golden_latent_pr33.json``; the ``lm_head`` gradient's numbers
+    re-taken at PR 41, see ``test_afmoe_model.py``)."""
     file, kw = GOLDEN[which]
     with open(os.path.join(REPO, "tests", file)) as f:
         golden = json.load(f)

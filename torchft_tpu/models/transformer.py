@@ -538,19 +538,153 @@ def causal_lm_loss(logits: jnp.ndarray, tokens: jnp.ndarray) -> jnp.ndarray:
     return jnp.mean(nll)
 
 
+# ------------------------------------------------------- the head's loss
+#
+# The chip the chunk rule is sized for (TPU v5e, Google Cloud's system
+# architecture table: 197 TFLOP/s bf16, 819 GB/s of HBM; the same two
+# numbers as ``benchmarks/harness/peaks.py`` and ``bench.py``).
+_MXU_FLOPS_PER_S = 197e12
+_HBM_BYTES_PER_S = 819e9
+# Tokens a chunk from which the head-gradient product is bound by the MXU
+# and not by the f32 ``[hidden, vocab]`` accumulator: a chunk of T tokens is
+# ``2 T E V`` operations against ``8 E V`` bytes (the accumulator read and
+# written), whatever E and V. 963 on this chip.
+_DW_RIDGE_TOKENS = int(-(-4 * _MXU_FLOPS_PER_S // _HBM_BYTES_PER_S))
+# Most bytes of one f32 logits tile ``[batch, chunk, vocab]``; the fused
+# body holds two or three such tiles at once. 1,024 tokens at a vocabulary
+# of 32,000 are 125 MiB.
+_LOGITS_TILE_BYTES = 256 << 20
+_CHUNK_MULTIPLE = 256
+
+
+def head_loss_chunk(batch: int, seq: int, vocab: int) -> int:
+    """Positions a chunk of :func:`chunked_causal_lm_loss` when the caller
+    names none, from the shapes alone, in multiples of 256: the fewest that
+    give ``batch * chunk`` at least ``_DW_RIDGE_TOKENS`` tokens (measured
+    on the chip, more buys little at batch 4 and costs at batch 1, and the
+    tile grows with it), but no more than keep the f32 logits tile
+    ``[batch, chunk, vocab]`` under ``_LOGITS_TILE_BYTES`` (a vocabulary
+    over about 69,000 gets the smaller chunk) or than the sequence needs,
+    and never fewer than 256."""
+    def up(n):
+        return -(-n // _CHUNK_MULTIPLE) * _CHUNK_MULTIPLE
+
+    ridge = up(-(-_DW_RIDGE_TOKENS // batch))
+    most = (_LOGITS_TILE_BYTES // (4 * vocab * batch)
+            // _CHUNK_MULTIPLE * _CHUNK_MULTIPLE)
+    return max(min(ridge, most, up(max(seq - 1, 1))), _CHUNK_MULTIPLE)
+
+
+def _head_chunks(hidden, tokens, chunk_size):
+    """``hidden[:, :-1]`` and its targets cut into chunks, leading axis the
+    chunk: ``[n, b, chunk, e]``, ``[n, b, chunk]`` and the f32 mask of the
+    positions that are not padding."""
+    b, s, e = hidden.shape
+    s1 = s - 1
+    n_chunks = -(-s1 // chunk_size)
+    pad = n_chunks * chunk_size - s1
+    h = jnp.pad(hidden[:, :-1], ((0, 0), (0, pad), (0, 0)))
+    t = jnp.pad(tokens[:, 1:], ((0, 0), (0, pad)))
+    mask = jnp.pad(jnp.ones((b, s1), jnp.float32), ((0, 0), (0, pad)))
+    hc = h.reshape(b, n_chunks, chunk_size, e).transpose(1, 0, 2, 3)
+    tc = t.reshape(b, n_chunks, chunk_size).transpose(1, 0, 2)
+    mc = mask.reshape(b, n_chunks, chunk_size).transpose(1, 0, 2)
+    return hc, tc, mc
+
+
+def _chunk_nll(h_c, t_c, w):
+    """f32 log-softmax of a chunk's logits and its targets' NLL."""
+    logits = jnp.einsum("bce,ev->bcv", h_c.astype(w.dtype), w,
+                        preferred_element_type=jnp.float32)
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    nll = -jnp.take_along_axis(logp, t_c[..., None], axis=-1)[..., 0]
+    return logp, nll
+
+
+def _head_operand(head_kernel, matmul_dtype):
+    return head_kernel.astype(jnp.float32 if matmul_dtype is None
+                              else matmul_dtype)
+
+
+def _head_loss_alone(hidden, head_kernel, tokens, chunk_size, matmul_dtype):
+    """The loss alone: one scan, one head product a chunk."""
+    b, s, _ = hidden.shape
+    w = _head_operand(head_kernel, matmul_dtype)
+
+    def body(total, xs):
+        h_c, t_c, m_c = xs
+        _, nll = _chunk_nll(h_c, t_c, w)
+        return total + jnp.sum(nll * m_c), None
+
+    total, _ = jax.lax.scan(body, jnp.float32(0.0),
+                            _head_chunks(hidden, tokens, chunk_size))
+    return total / (b * (s - 1))
+
+
+def _head_loss_fwd(hidden, head_kernel, tokens, chunk_size, matmul_dtype):
+    """The loss and, in the same scan, both head gradients at a cotangent
+    of one: per chunk the logits once, ``dlogits = (softmax - onehot) *
+    mask / (b * s1)`` in f32, ``dh_c = dlogits @ W^T`` stacked out and
+    ``dW += h_c^T @ dlogits`` in an f32 carry. Three head products a chunk;
+    the backward rule only scales."""
+    from torchft_tpu import tracing
+
+    b, s, e = hidden.shape
+    s1 = s - 1
+    v = head_kernel.shape[1]
+    w = _head_operand(head_kernel, matmul_dtype)
+    chunks = _head_chunks(hidden, tokens, chunk_size)
+    # counted on the host, when the rule is traced: no callback in the step
+    tracing.add_program_counters(
+        head_loss_fused_traces_total=1,
+        head_loss_chunks_traced_total=chunks[0].shape[0])
+
+    def body(carry, xs):
+        total, dw = carry
+        h_c, t_c, m_c = xs
+        logp, nll = _chunk_nll(h_c, t_c, w)
+        hit = t_c[..., None] == jnp.arange(v, dtype=t_c.dtype)
+        dlogits = (jnp.exp(logp) - hit) * (m_c / (b * s1))[..., None]
+        dh_c = jnp.einsum("bcv,ev->bce", dlogits, w,
+                          preferred_element_type=jnp.float32)
+        dw = dw + jnp.einsum("bce,bcv->ev", h_c.astype(w.dtype), dlogits,
+                             preferred_element_type=jnp.float32)
+        return (total + jnp.sum(nll * m_c), dw), dh_c.astype(hidden.dtype)
+
+    (total, dw), dh = jax.lax.scan(
+        body, (jnp.float32(0.0), jnp.zeros((e, v), jnp.float32)), chunks)
+    dh = dh.transpose(1, 0, 2, 3).reshape(b, -1, e)[:, :s1]
+    dh = jnp.pad(dh, ((0, 0), (0, 1), (0, 0)))
+    return total / (b * s1), (dh, dw.astype(head_kernel.dtype))
+
+
+def _head_loss_bwd(chunk_size, matmul_dtype, residuals, g):
+    dh, dw = residuals
+    return ((dh.astype(jnp.float32) * g).astype(dh.dtype),
+            (dw.astype(jnp.float32) * g).astype(dw.dtype), None)
+
+
+_head_loss = jax.custom_vjp(_head_loss_alone, nondiff_argnums=(3, 4))
+_head_loss.defvjp(_head_loss_fwd, _head_loss_bwd)
+
+
 def chunked_causal_lm_loss(hidden: jnp.ndarray, head_kernel: jnp.ndarray,
                            tokens: jnp.ndarray,
-                           chunk_size: int = 256,
+                           chunk_size: Optional[int] = None,
                            matmul_dtype: Any = None) -> jnp.ndarray:
     """Next-token cross-entropy WITHOUT materializing [B, S, vocab].
 
     The full-logits tensor is the largest allocation in LM training
     (B=16, S=2048, V=32k → 2 GB in f32, live through the log-softmax
     backward). This computes the head matmul + log-softmax per sequence
-    chunk under ``jax.checkpoint`` inside a scan, so both passes peak at
-    one [B, chunk, V] tile. Use with
-    ``model.apply(params, tokens, return_hidden=True)`` and the
+    chunk inside ONE scan, which peaks at a few [B, chunk, V] tiles; when
+    the loss is differentiated the same scan yields both head gradients
+    (a ``jax.custom_vjp``: no forward scan, no recomputed logits). Use
+    with ``model.apply(params, tokens, return_hidden=True)`` and the
     ``lm_head`` kernel from params.
+
+    ``chunk_size``: positions a chunk; ``None`` takes
+    :func:`head_loss_chunk` of the shapes.
 
     ``matmul_dtype``: input dtype for the head matmul (accumulation is
     always f32 and the log-softmax runs on f32 logits either way). The
@@ -558,37 +692,15 @@ def chunked_causal_lm_loss(hidden: jnp.ndarray, head_kernel: jnp.ndarray,
     matmul (~10% of a small-model step's FLOPs) at the MXU's full bf16
     rate, the same precision the body's matmuls already use.
     """
-    b, s, e = hidden.shape
-    h = hidden[:, :-1]
-    t = tokens[:, 1:]
-    s1 = s - 1
-    n_chunks = -(-s1 // chunk_size)
-    pad = n_chunks * chunk_size - s1
-    h = jnp.pad(h, ((0, 0), (0, pad), (0, 0)))
-    t = jnp.pad(t, ((0, 0), (0, pad)))
-    mask = jnp.pad(jnp.ones((b, s1), jnp.float32), ((0, 0), (0, pad)))
-    hc = h.reshape(b, n_chunks, chunk_size, e).transpose(1, 0, 2, 3)
-    tc = t.reshape(b, n_chunks, chunk_size).transpose(1, 0, 2)
-    mc = mask.reshape(b, n_chunks, chunk_size).transpose(1, 0, 2)
-
-    def body(carry, xs):
-        h_c, t_c, m_c = xs
-        mm = jnp.float32 if matmul_dtype is None else matmul_dtype
-        logits = jnp.einsum("bce,ev->bcv", h_c.astype(mm),
-                            head_kernel.astype(mm),
-                            preferred_element_type=jnp.float32)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, t_c[..., None], axis=-1)[..., 0]
-        return carry + jnp.sum(nll * m_c), None
-
-    total, _ = jax.lax.scan(jax.checkpoint(body), jnp.float32(0.0),
-                            (hc, tc, mc))
-    return total / (b * s1)
+    if chunk_size is None:
+        chunk_size = head_loss_chunk(hidden.shape[0], hidden.shape[1],
+                                     head_kernel.shape[1])
+    return _head_loss(hidden, head_kernel, tokens, chunk_size, matmul_dtype)
 
 
 def mtp_causal_lm_loss(model: "Transformer", params: Any,
                        tokens: jnp.ndarray, mtp_weight: float,
-                       chunk_size: int = 256) -> jnp.ndarray:
+                       chunk_size: Optional[int] = None) -> jnp.ndarray:
     """``L_main + mtp_weight * L_mtp`` of a model with one multi-token
     prediction module: the trunk's next-token loss and the module's loss
     against the token after next (mean over the ``S - 2`` positions that

@@ -95,7 +95,11 @@ def default_trace_steps() -> int:
 # on the device reaches the host through ``jax.debug.callback`` and is added
 # here. Process-wide: every Manager of the process reports the same totals
 # (``Manager.metrics()`` merges them). A program that holds a host callback
-# is not written to jax's persistent compile cache.
+# is not written to jax's persistent compile cache. What is known when a
+# program is traced needs no callback: the head's loss adds
+# ``head_loss_fused_traces_total`` (one each time its fused gradient rule is
+# traced) and ``head_loss_chunks_traced_total`` (the chunks of each such
+# loss; their ratio is the chunks a loss) on the host, then and there.
 
 _program_counters: Dict[str, float] = {}
 _program_counters_lock = threading.Lock()
