@@ -289,11 +289,13 @@ def test_a_restarted_trainer_adopts_healed_state_before_it_computes():
     import optax
 
     from torchft_tpu.parallel.step import FTTrainer
+    from torchft_tpu.tracing import Tracer
 
     def loss_fn(params, batch):
         return jnp.sum(params["w"] * batch)
 
     manager = MagicMock()
+    manager.tracer.return_value = Tracer(enabled=False)
     manager.is_healing.return_value = True
     manager.single_group_step.return_value = False
     manager.should_commit.return_value = True

@@ -98,6 +98,9 @@ DOCUMENTED_KEYS = frozenset([
     "allreduce_int8_ring_bytes_total",
     # observability tier (docs/design/observability.md)
     "trace_spans_total", "trace_spans_dropped", "flight_dumps_total",
+    # the runs of the jitted programs' host callback [count,
+    # process-wide] (tracing.count_in_program)
+    "program_callbacks_total",
     # spot-instance churn (docs/design/churn.md)
     "preempt_notices_total", "preempt_drain_deferrals_total",
     "preempt_deadline_expired_total", "graceful_exits_total",
@@ -174,6 +177,20 @@ DOCUMENTED_INFO_KEYS = frozenset([
 # Span context tags every exported trace event must carry (the fleet
 # merger aligns on quorum_id/epoch/step; dashboards group by the rest).
 REQUIRED_TRACE_TAGS = frozenset(tracing.CONTEXT_TAGS)
+
+# Who recorded a span and under what: on every span dict and, as args, on
+# every exported event (the benchmark's readers and self time go by them).
+REQUIRED_SPAN_FIELDS = frozenset(["thread", "thread_id", "id", "parent"])
+
+# The documented stages (docs/design/observability.md's taxonomy): one
+# Perfetto track each, in protocol order. Append, never drop.
+DOCUMENTED_STAGES = (
+    "step_begin", "dispatch", "wait_quorum",
+    "quorum", "heal", "heal_stripe", "fetch_dispatch", "fetch_wait",
+    "ring", "hier_intra", "hier_leader", "put", "exchange_wait",
+    "overlap_drain", "drain", "pre_vote", "vote", "post_vote",
+    "publish_status", "state_digest", "update", "ckpt_save", "publish",
+)
 
 
 class TestMetricsSchema:
@@ -421,7 +438,8 @@ class TestTraceEventSchema:
         assert "X" in phases and "B" in phases and "E" in phases
         spans = [ev for ev in events if ev["ph"] in ("X", "B")]
         for ev in spans:
-            missing = REQUIRED_TRACE_TAGS - set(ev["args"])
+            missing = (REQUIRED_TRACE_TAGS | REQUIRED_SPAN_FIELDS) \
+                - set(ev["args"])
             assert not missing, (ev["name"], sorted(missing))
             assert ev["args"]["step"] == 11
             assert ev["args"]["quorum_id"] == 3
@@ -437,6 +455,23 @@ class TestTraceEventSchema:
                 if ev["ph"] == "M" and ev["name"] == "process_name"]
         assert proc and proc[0]["args"]["name"] == "g0"
         open_span.__exit__(None, None, None)
+
+    def test_span_fields_and_stages(self):
+        tr = tracing.Tracer(steps=4, enabled=True)
+        with tr.span("publish_status"):
+            with tr.span("state_digest", device=True):
+                pass
+        for rec in tr.spans():
+            assert REQUIRED_SPAN_FIELDS <= set(rec), rec
+            assert {"stage", "t0_ns", "dur_ns"} <= set(rec)
+        assert tracing.STAGES == DOCUMENTED_STAGES
+        # A known stage's track number is its place in the taxonomy.
+        tid_of = {ev["name"]: ev["tid"]
+                  for ev in tr.chrome_trace()["traceEvents"]
+                  if ev["ph"] == "X"}
+        assert tid_of == {
+            "publish_status": DOCUMENTED_STAGES.index("publish_status") + 1,
+            "state_digest": DOCUMENTED_STAGES.index("state_digest") + 1}
 
     def test_open_spans_marked(self):
         tr = tracing.Tracer(steps=4, enabled=True)

@@ -499,6 +499,7 @@ class TestFTTrainerModelState:
         import optax
 
         from torchft_tpu.parallel.step import FTTrainer
+        from torchft_tpu.tracing import Tracer
 
         class BNModel(nn.Module):
             @nn.compact
@@ -519,6 +520,7 @@ class TestFTTrainerModelState:
             return jnp.mean(out ** 2), new_state
 
         manager = MagicMock()
+        manager.tracer.return_value = Tracer(enabled=False)
         manager.should_commit.return_value = True
         manager.is_healing.return_value = False
 
@@ -554,11 +556,13 @@ class TestFTTrainerModelState:
         import optax
 
         from torchft_tpu.parallel.step import FTTrainer
+        from torchft_tpu.tracing import Tracer
 
         def loss_fn(params, model_state, batch):
             return jnp.sum(params["w"] * batch), {"s": model_state["s"] + 1}
 
         manager = MagicMock()
+        manager.tracer.return_value = Tracer(enabled=False)
         manager.should_commit.return_value = False
         manager.is_healing.return_value = False
         f = Future()

@@ -322,16 +322,18 @@ class Manager:
         tracing: per-step span tracing
             (:mod:`torchft_tpu.tracing`, docs/design/observability.md).
             Default on (env ``TORCHFT_TRACING=0`` disables): every hot
-            stage — quorum, per-bucket fetch dispatch/wait, ring ops,
-            unpack/put, drain/vote, heal stripes per donor, durable
+            stage — the step thread's dispatch and waits, quorum,
+            per-bucket fetch dispatch/wait, ring ops, unpack/put, every
+            commit-boundary hook, heal stripes per donor, durable
             saves, publishes — records a monotonic span tagged with
             ``replica_id/quorum_id/epoch/step/policy_name`` into a
             bounded ring of the last ``trace_steps`` steps, exported
             at ``GET /trace.json`` (Chrome trace-event format) and
             dumped by the flight recorder (``TORCHFT_FLIGHT_DIR``) on
             vote abort / latched comm error / heal failover / policy
-            escalation / crash exit. Measured overhead < 2% of host
-            steps/s (bench ``multigroup_8mb_trace_ab``).
+            escalation / crash exit. Measured on the chip: under the
+            1 ms that two runs of a 337 ms one-group step differ by
+            (seven pairs against ``TORCHFT_TRACING=0``; PERF.md, PR 42).
         trace_steps: span-ring depth in steps (env
             ``TORCHFT_TRACE_STEPS``, default 64).
         fleet_telemetry: quorum-piggybacked fleet health telemetry
@@ -1966,8 +1968,7 @@ class Manager:
             return _instant(tree)
 
         try:
-            assert self._quorum_future is not None, "call step() first"
-            self._quorum_future.result()
+            self._join_quorum()
 
             # Single-group fast path: sum-over-one is identity; skip the
             # device->host round trip entirely (grads stay on device: the
@@ -2130,8 +2131,7 @@ class Manager:
         if self._errored is not None:
             return _instant(tree)
         try:
-            assert self._quorum_future is not None, "call step() first"
-            self._quorum_future.result()
+            self._join_quorum()
             if self.single_group_step():
                 return _instant(tree)
             leaves, treedef = jax.tree_util.tree_flatten(tree)
@@ -2198,6 +2198,14 @@ class Manager:
                      shard_state_resets=resets)
         with self._metrics_lock:
             self._metrics["shard_state_bytes"] = float(shard_state_bytes)
+
+    def _join_quorum(self) -> None:
+        """The exchange's own join of this step's quorum round, on the
+        caller's thread under a ``wait_quorum`` span (raises what the
+        round raised)."""
+        assert self._quorum_future is not None, "call step() first"
+        with self._tracer.span("wait_quorum"):
+            self._quorum_future.result()
 
     def wait_quorum(self) -> None:
         """Join this step's quorum round; a quorum failure latches via
@@ -3318,9 +3326,13 @@ class Manager:
         # only drains the allgather it tracked.)
         self.prepare_commit()
 
-        if self._controller is not None:
-            self._policy_pre_vote()
-        self._rebalance_pre_vote()
+        # Every hook of the boundary runs on the caller's thread under a
+        # span of its own (docs/design/observability.md), one after the
+        # other: what the boundary costs the host is their self time.
+        with self._tracer.span("pre_vote"):
+            if self._controller is not None:
+                self._policy_pre_vote()
+            self._rebalance_pre_vote()
 
         enough = self._participating_world_size >= self._min_replica_size
         local_ok = self._errored is None and enough
@@ -3334,30 +3346,33 @@ class Manager:
                 timeout_ms=timeout_ms or self._timeout_ms,
             )
             vote_span.set(decision=bool(decision))
-        self._record(
-            commit_count=1,
-            commit_ms_total=(time.perf_counter() - commit_t0) * 1e3,
-            committed_steps=1 if decision else 0,
-            aborted_steps=0 if decision else 1,
-        )
-        logger.info(
-            "%s step=%d should_commit=%s (local=%s enough=%s errored=%s)",
-            self._replica_id, self._step, decision, local_ok, enough,
-            self._errored,
-        )
-
-        if not decision:
-            self._log_event(
-                event="abort", step=self._step, local_ok=local_ok,
-                error=repr(self._errored) if self._errored else None,
+        with self._tracer.span("post_vote"):
+            self._record(
+                commit_count=1,
+                commit_ms_total=(time.perf_counter() - commit_t0) * 1e3,
+                committed_steps=1 if decision else 0,
+                aborted_steps=0 if decision else 1,
             )
-            self._flight_dump(
-                "vote_abort", local_ok=local_ok,
-                error=repr(self._errored) if self._errored else None)
-        if self._controller is not None:
-            self._policy_post_vote(decision)
-        self._rebalance_post_vote()
-        self._publish_status()
+            logger.info(
+                "%s step=%d should_commit=%s (local=%s enough=%s "
+                "errored=%s)",
+                self._replica_id, self._step, decision, local_ok, enough,
+                self._errored,
+            )
+
+            if not decision:
+                self._log_event(
+                    event="abort", step=self._step, local_ok=local_ok,
+                    error=repr(self._errored) if self._errored else None,
+                )
+                self._flight_dump(
+                    "vote_abort", local_ok=local_ok,
+                    error=repr(self._errored) if self._errored else None)
+            if self._controller is not None:
+                self._policy_post_vote(decision)
+            self._rebalance_post_vote()
+        with self._tracer.span("publish_status"):
+            self._publish_status()
 
         # Shut the heal window before the caller mutates state (reference
         # manager.py:453, checkpointing.py:123-144).
@@ -3578,32 +3593,38 @@ class Manager:
         the kernel is parity-frozen against."""
         if not self._attestation:
             return ""
-        try:
-            t0 = time.monotonic()
-            leaves = [
-                leaf for leaf in jax.tree_util.tree_leaves(
-                    self._user_state_dict())
-                if serialization._is_array_leaf(leaf)
-                and getattr(leaf, "nbytes", 0)
-            ]
-            if not leaves:
+        with self._tracer.span("state_digest") as span:
+            try:
+                t0 = time.monotonic()
+                leaves = [
+                    leaf for leaf in jax.tree_util.tree_leaves(
+                        self._user_state_dict())
+                    if serialization._is_array_leaf(leaf)
+                    and getattr(leaf, "nbytes", 0)
+                ]
+                if not leaves:
+                    return ""
+                if all(isinstance(x, jax.Array) for x in leaves):
+                    # The read waits for the digest's program, which is
+                    # queued behind whatever the caller has in flight
+                    # (the step's own program): the span's wall and
+                    # sdc_digest_ms_total include that wait.
+                    span.set(device=True)
+                    words = np.asarray(_attest_device_words(leaves),
+                                       dtype=np.uint32)
+                    digest = serialization.attest_combine(
+                        [int(w) for w in words])
+                else:
+                    digest = serialization.attest_fingerprint(leaves)
+                self._record(
+                    sdc_digests_total=1,
+                    sdc_digest_ms_total=(time.monotonic() - t0) * 1e3)
+                self._last_state_digest = digest
+                return digest
+            except Exception:  # noqa: BLE001 — attestation never fails a step
+                self._record(sdc_digest_failures=1)
+                logger.warning("state digest failed", exc_info=True)
                 return ""
-            if all(isinstance(x, jax.Array) for x in leaves):
-                words = np.asarray(_attest_device_words(leaves),
-                                   dtype=np.uint32)
-                digest = serialization.attest_combine(
-                    [int(w) for w in words])
-            else:
-                digest = serialization.attest_fingerprint(leaves)
-            self._record(
-                sdc_digests_total=1,
-                sdc_digest_ms_total=(time.monotonic() - t0) * 1e3)
-            self._last_state_digest = digest
-            return digest
-        except Exception:  # noqa: BLE001 — attestation never fails a step
-            self._record(sdc_digest_failures=1)
-            logger.warning("state digest failed", exc_info=True)
-            return ""
 
     def metrics(self) -> Dict[str, float]:
         """Snapshot of counters + cumulative timings (ms): quorum rounds,
@@ -3636,8 +3657,9 @@ class Manager:
             if self._manager_server is not None else 0.0)
         out.update(self._retry_stats.snapshot())
         # Totals the jitted programs counted themselves (tracing.
-        # count_in_program: routed pairs, pairs on held experts, ...);
-        # process-wide, absent until a program counted one.
+        # count_in_program: routed pairs, pairs on held experts, ...)
+        # and program_callbacks_total, the runs of their host callback;
+        # process-wide, the former absent until a program counted one.
         out.update(tracing_mod.program_counters())
         # Bytes that actually crossed the TCP ring, counted by the
         # backend at its send sites (halved vs allreduce_wire_bytes_total

@@ -71,6 +71,9 @@ class FTOptimizer:
         self.manager = manager
         self.tx = tx
         self._jit = jit
+        # The update's own dispatch is an ``update`` span on the step
+        # thread, beside the boundary's hooks (Manager.should_commit).
+        self._tracer = manager.tracer()
 
         def update(params: Any, opt_state: Any, grads: Any):
             updates, new_state = tx.update(grads, opt_state, params)
@@ -147,8 +150,9 @@ class FTOptimizer:
                                        self.manager.full_shards(grads))
         committed = self.manager.should_commit()
         if committed:
-            holder.params, holder.opt_state = self._update(
-                holder.params, holder.opt_state, grads)
+            with self._tracer.span("update"):
+                holder.params, holder.opt_state = self._update(
+                    holder.params, holder.opt_state, grads)
         return committed
 
     def _apply_sharded(self, holder: Any, sg: ShardedGrads) -> bool:

@@ -1,16 +1,22 @@
 """Per-step distributed tracing + flight recorder (the observability
 tier, docs/design/observability.md).
 
-Three layers, all pure Python + stdlib (native-free, like the serving
-tier):
+Three layers, native-free like the serving tier (jax is imported only
+for the profiler annotation below, and its absence is tolerated):
 
 * :class:`Tracer` — a low-overhead span tracer. Every hot-path stage of
-  the step protocol (quorum, per-bucket fetch dispatch/wait, ring ops,
-  unpack/put, drain/vote, heal stripes per donor, durable saves,
-  publishes) records a span: a ``time.monotonic_ns()`` start + duration
-  tagged with the step-protocol coordinates
-  (``replica_id/quorum_id/epoch/step/policy_name``) that make spans
-  from different groups alignable. Spans live in a bounded per-step
+  the step protocol (the step thread's own partition: step_begin,
+  dispatch, the waits, every commit-boundary hook, the update; quorum,
+  per-bucket fetch dispatch/wait, ring ops, unpack/put, heal stripes per
+  donor, durable saves, publishes) records a span: a
+  ``time.monotonic_ns()`` start + duration tagged with the step-protocol
+  coordinates (``replica_id/quorum_id/epoch/step/policy_name``) that
+  make spans from different groups alignable, the recording ``thread``,
+  an ``id`` and the ``parent`` open on that thread when it began (self
+  time = duration less what the children cover). A span used as a
+  context manager also enters a ``jax.profiler.TraceAnnotation`` of its
+  stage, so a profiler capture of a live job holds the stages beside
+  the device's operations. Spans live in a bounded per-step
   ring (last ``TORCHFT_TRACE_STEPS`` steps, default 64), so memory is
   O(steps x spans/step) forever. The run-total counters in
   ``Manager.metrics()`` answer "how much"; the spans answer "when, and
@@ -35,14 +41,19 @@ tier):
   ``(quorum_id, epoch, step)`` into one fleet timeline
   (``scripts/tracefleet.py``).
 
-Tracing defaults ON (the bench's ``multigroup_8mb_trace_ab`` row holds
-the overhead under 2% of host steps/s); ``TORCHFT_TRACING=0`` disables
-it process-wide, turning every ``span()`` into a shared no-op.
+Tracing defaults ON. What it costs on the chip (PERF.md, PR 42: the
+benchmark's ``mistral-7b.steady-1g`` cell on a TPU v5e, ten spans a
+step, seven same-seed pairs against ``TORCHFT_TRACING=0``): a step of
+337.07 ms with it against 337.02 ms without, the pairs' differences
+-0.92 to +0.80 ms with no direction, so under what two runs of one
+seed differ by. ``TORCHFT_TRACING=0`` disables it process-wide,
+turning every ``span()`` into a shared no-op.
 """
 
 from __future__ import annotations
 
 import atexit
+import itertools
 import json
 import logging
 import os
@@ -66,9 +77,11 @@ CONTEXT_TAGS = ("replica_id", "quorum_id", "epoch", "step", "policy_name")
 # Stable track (tid) order for the known pipeline stages — one Perfetto
 # track per stage, in protocol order. Unknown stages append after.
 STAGES = (
+    "step_begin", "dispatch", "wait_quorum",
     "quorum", "heal", "heal_stripe", "fetch_dispatch", "fetch_wait",
-    "ring", "hier_intra", "hier_leader", "put", "overlap_drain",
-    "drain", "vote", "ckpt_save", "publish",
+    "ring", "hier_intra", "hier_leader", "put", "exchange_wait",
+    "overlap_drain", "drain", "pre_vote", "vote", "post_vote",
+    "publish_status", "state_digest", "update", "ckpt_save", "publish",
 )
 
 
@@ -100,8 +113,11 @@ def default_trace_steps() -> int:
 # ``head_loss_fused_traces_total`` (one each time its fused gradient rule is
 # traced) and ``head_loss_chunks_traced_total`` (the chunks of each such
 # loss; their ratio is the chunks a loss) on the host, then and there.
+# The callback counts itself: ``program_callbacks_total`` is its runs (a
+# program that holds one never takes jit's C++ dispatch path, and a
+# callback inside a rematerialised region runs twice a step).
 
-_program_counters: Dict[str, float] = {}
+_program_counters: Dict[str, float] = {"program_callbacks_total": 0.0}
 _program_counters_lock = threading.Lock()
 
 
@@ -121,7 +137,8 @@ def count_in_program(**values: Any) -> None:
 
     keys = sorted(values)
     jax.debug.callback(
-        lambda *vals: add_program_counters(**dict(zip(keys, vals))),
+        lambda *vals: add_program_counters(program_callbacks_total=1,
+                                           **dict(zip(keys, vals))),
         *[values[k] for k in keys])
 
 
@@ -152,21 +169,43 @@ _NOOP_SPAN = _NoopSpan()
 
 
 class _Span:
-    """One in-flight span: started on ``__enter__``/construction,
-    recorded into the tracer's ring on ``__exit__``. ``ctx`` is the
-    tracer's copy-on-write context dict at start time (shared, never
-    mutated), so capturing it is one reference, not a copy."""
+    """One in-flight span: started on construction, recorded into the
+    tracer's ring on ``__exit__``. ``ctx`` is the tracer's copy-on-write
+    context dict at start time (shared, never mutated), so capturing it
+    is one reference, not a copy. ``thread`` is the recording thread's
+    name and ``thread_id`` its ``threading.get_ident()`` (names repeat:
+    pool threads share them), ``id`` the span's number in its tracer.
+    Used as a context manager it also learns its ``parent`` (the id of
+    the span open on the same thread when it was entered; a span closed
+    by a bare ``__exit__`` has none) and sits in a profiler capture
+    under its stage's name. After the exit ``t0_ns``, ``dur_ns`` and
+    ``end_ns`` are the caller's to read (:meth:`Tracer.timed`)."""
 
-    __slots__ = ("tracer", "stage", "tags", "ctx", "t0_ns", "dur_ns")
+    __slots__ = ("tracer", "stage", "tags", "ctx", "t0_ns", "dur_ns",
+                 "thread", "thread_id", "id", "parent", "_stack",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", stage: str,
-                 tags: Optional[Dict[str, Any]]) -> None:
+                 tags: Optional[Dict[str, Any]],
+                 t0_ns: Optional[int] = None) -> None:
         self.tracer = tracer
         self.stage = stage
         self.tags = tags
         self.ctx = tracer._ctx
-        self.t0_ns = time.monotonic_ns()
+        self.thread = threading.current_thread().name
+        self.thread_id = threading.get_ident()
+        self.id = next(tracer._ids)
+        self.parent: Optional[int] = None
+        # The entering thread's stack of open spans, while this one is
+        # on it.
+        self._stack: Optional[List["_Span"]] = None
+        self._annotation: Any = None
+        self.t0_ns = time.monotonic_ns() if t0_ns is None else t0_ns
         self.dur_ns = -1  # open until __exit__
+
+    @property
+    def end_ns(self) -> int:
+        return self.t0_ns + self.dur_ns
 
     def set(self, **tags: Any) -> "_Span":
         """Attach/overwrite tags mid-span (e.g. the vote's decision,
@@ -178,10 +217,35 @@ class _Span:
         return self
 
     def __enter__(self) -> "_Span":
+        tracer = self.tracer
+        stack = tracer._open_here()
+        if stack:
+            self.parent = stack[-1].id
+        stack.append(self)
+        self._stack = stack
+        if tracer._annotate is not None:
+            self._annotation = tracer._annotate(self.stage)
+            self._annotation.__enter__()
         return self
 
     def __exit__(self, exc_type: Any, exc: Any, tb: Any) -> None:
         self.dur_ns = time.monotonic_ns() - self.t0_ns
+        stack, self._stack = self._stack, None
+        if stack is not None:
+            if self._annotation is not None:
+                self._annotation.__exit__(exc_type, exc, tb)
+                self._annotation = None
+            # Off the stack it was pushed on, wherever it sits there: a
+            # span left out of order (a generator holding its ``with``,
+            # an exit on another thread) must not stay behind as every
+            # later span's parent.
+            if stack and stack[-1] is self:
+                stack.pop()
+            else:
+                try:
+                    stack.remove(self)
+                except ValueError:
+                    pass
         if exc is not None:
             self.set(error=repr(exc))
         self.tracer._finish(self)
@@ -191,11 +255,48 @@ class _Span:
             "stage": self.stage,
             "t0_ns": self.t0_ns,
             "dur_ns": self.dur_ns,
+            "thread": self.thread,
+            "thread_id": self.thread_id,
+            "id": self.id,
+            "parent": self.parent,
         }
         d.update(self.ctx)
         if self.tags:
             d.update(self.tags)
         return d
+
+
+class _Stopwatch:
+    """What :meth:`Tracer.timed` hands out on a disabled tracer: the two
+    stamps for the caller, and nothing recorded."""
+
+    __slots__ = ("t0_ns", "dur_ns")
+
+    def __init__(self, t0_ns: Optional[int]) -> None:
+        self.t0_ns = time.monotonic_ns() if t0_ns is None else t0_ns
+        self.dur_ns = -1
+
+    @property
+    def end_ns(self) -> int:
+        return self.t0_ns + self.dur_ns
+
+    def set(self, **tags: Any) -> "_Stopwatch":
+        return self
+
+    def __enter__(self) -> "_Stopwatch":
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        self.dur_ns = time.monotonic_ns() - self.t0_ns
+
+
+def _trace_annotation() -> Any:
+    """``jax.profiler.TraceAnnotation``, or None where jax is absent."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
 
 
 class Tracer:
@@ -204,8 +305,10 @@ class Tracer:
     Thread-safe: spans are recorded from the caller thread, the quorum
     thread, the comm worker, the put executor, and striped-heal fetch
     threads; the ring append is one short lock hold. Span START costs a
-    ``monotonic_ns`` + one object allocation; a disabled tracer's
-    ``span()`` returns a shared no-op.
+    ``monotonic_ns`` + one object allocation, entering it one push on
+    its thread's stack of open spans and one (inert unless a profiler
+    capture is running) ``TraceAnnotation``; a disabled tracer's
+    ``span()`` returns a shared no-op and does none of this.
 
     Args:
         steps: ring depth in steps (default ``TORCHFT_TRACE_STEPS`` /
@@ -243,6 +346,11 @@ class Tracer:
         # with a synthesized E at dump time, so a dump taken mid-step
         # still shows what was in flight.
         self._open: Dict[int, _Span] = {}
+        self._ids = itertools.count(1)
+        # Per thread: the spans entered and not yet left, outermost
+        # first (a span's parent is the innermost at its entry).
+        self._stacks = threading.local()
+        self._annotate = _trace_annotation() if self.enabled else None
         self.spans_total = 0
         self.spans_dropped = 0
 
@@ -269,10 +377,34 @@ class Tracer:
         """
         if not self.enabled:
             return _NOOP_SPAN
-        s = _Span(self, stage, tags or None)
+        return self._start(stage, tags, None)
+
+    def timed(self, stage: str, after: Any = None, **tags: Any) -> Any:
+        """As :meth:`span`, for a caller that reads the stamps itself
+        (``t0_ns``, ``dur_ns``, ``end_ns`` after the exit): the span is
+        the caller's stopwatch too, so one set of clock reads serves the
+        trace and the caller's own bookkeeping. A disabled tracer hands
+        out a bare stopwatch: the two stamps, nothing recorded.
+        ``after``: the span this one follows on its thread; it begins at
+        that one's end, one clock read for the boundary between them."""
+        t0_ns = None if after is None else after.end_ns
+        if not self.enabled:
+            return _Stopwatch(t0_ns)
+        return self._start(stage, tags, t0_ns)
+
+    def _start(self, stage: str, tags: Dict[str, Any],
+               t0_ns: Optional[int]) -> _Span:
+        s = _Span(self, stage, tags or None, t0_ns)
         with self._lock:
             self._open[id(s)] = s
         return s
+
+    def _open_here(self) -> List[_Span]:
+        try:
+            return self._stacks.spans
+        except AttributeError:
+            self._stacks.spans = []
+            return self._stacks.spans
 
     def _finish(self, s: _Span) -> None:
         rec = s.as_dict()
