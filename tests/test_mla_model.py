@@ -313,18 +313,22 @@ def test_the_shares_of_256_experts_add_up_to_the_uncut_layer(builder,
 
 @pytest.mark.parametrize("remat", [True, False], ids=["remat", "plain"])
 def test_counters_go_up_once_a_step(builder, remat):
-    """One callback a step carries the routed layers' counts (the module's
-    layer among them) and the two losses, under remat too."""
+    """One output a step carries the routed layers' counts (the module's
+    layer among them) and the two losses out of a collecting program, under
+    remat too."""
     cfg = small(builder, (1,))
     params = R.init_params(builder, cfg, 13)
     toks = R.make_tokens(cfg, 13, 0, 0, 2, SEQ)
-    step = jax.jit(jax.value_and_grad(builder.make_loss_fn(
-        cfg, SEQ, interpret=True, dtype=jnp.float32, remat=remat)))
+    step = jax.jit(jax.value_and_grad(tracing.collect_counts(
+        builder.make_loss_fn(cfg, SEQ, interpret=True, dtype=jnp.float32,
+                             remat=remat)), has_aux=True))
     jax.block_until_ready(step(params, {"tokens": toks}))     # compiled
-    jax.effects_barrier()
     before = tracing.program_counters()
-    loss, _ = jax.block_until_ready(step(params, {"tokens": toks}))
-    jax.effects_barrier()
+    (loss, counts), _ = step(params, {"tokens": toks})
+    # whole numbers (the three moe_*) and the two losses, side by side
+    assert [len(names) for names in counts.keys] == [3, 2]
+    tracing.defer_program_counts(counts)
+    tracing.settle_program_counts(wait=True)
     after = tracing.program_counters()
     delta = {k: after[k] - before.get(k, 0.0) for k in after}
     main, mtp = both_losses(builder, cfg, params, toks)

@@ -153,20 +153,24 @@ def test_counters_go_up_once_a_step(remat):
     model = Transformer(cfg)
     toks = jax.random.randint(jax.random.key(1), (2, 32), 0, cfg.vocab_size)
     params = model.init(jax.random.key(0), toks)
-    jax.effects_barrier()
     before = tracing.program_counters()
-    step = jax.jit(jax.value_and_grad(
-        lambda p: jnp.mean(model.apply(p, toks) ** 2)))
-    step(params)
-    jax.effects_barrier()
+    step = jax.jit(jax.value_and_grad(tracing.collect_counts(
+        lambda p: jnp.mean(model.apply(p, toks) ** 2)), has_aux=True))
+
+    def run():
+        (_, counts), _ = step(params)
+        assert counts.keys == (tuple(sorted(MOE_COUNTERS)),)
+        tracing.defer_program_counts(counts)
+        tracing.settle_program_counts(wait=True)
+
+    run()
     after = tracing.program_counters()
     delta = {k: after[k] - before.get(k, 0.0) for k in MOE_COUNTERS}
     assert delta["moe_pairs_routed_total"] == 3 * 64 * 2
     assert 0 < delta["moe_pairs_local_total"] < 3 * 64 * 2
     assert 0 < delta["moe_expert_load_max_total"] \
         <= delta["moe_pairs_local_total"]
-    step(params)
-    jax.effects_barrier()
+    run()
     assert tracing.program_counters()["moe_pairs_routed_total"] \
         == after["moe_pairs_routed_total"] + 3 * 64 * 2
 

@@ -263,7 +263,7 @@ class TestOverlapEquivalence:
         P, O = self._params0(), optax.sgd(0.1).init(self._params0())
         staged = None
         for k in range(self.K):
-            _, _, g = fwd(P, None, self._batches(0, k))  # stale point
+            _, _, g, _ = fwd(P, None, self._batches(0, k))  # stale point
             if staged is not None:
                 P, O = upd(_copy(P), _copy(O), staged)
             staged = g

@@ -327,18 +327,21 @@ def test_the_shared_experts_gate_is_off_unless_asked():
 
 @pytest.mark.parametrize("remat", [True, False], ids=["remat", "plain"])
 def test_counters_go_up_once_a_step(builder, remat):
-    """One callback a step carries the linear layers' two numbers and the
-    routed layers' counts, under remat too."""
+    """One output a step carries the linear layers' two numbers and the
+    routed layers' counts out of a collecting program, under remat too."""
     cfg = small(builder)
     params = R.init_params(builder, cfg, 13)
     toks = R.make_tokens(cfg, 13, 0, 0, 2, SEQ)
-    step = jax.jit(jax.value_and_grad(builder.make_loss_fn(
-        cfg, SEQ, interpret=True, dtype=jnp.float32, remat=remat)))
+    step = jax.jit(jax.value_and_grad(tracing.collect_counts(
+        builder.make_loss_fn(cfg, SEQ, interpret=True, dtype=jnp.float32,
+                             remat=remat)), has_aux=True))
     jax.block_until_ready(step(params, {"tokens": toks}))     # compiled
-    jax.effects_barrier()
     before = tracing.program_counters()
-    jax.block_until_ready(step(params, {"tokens": toks}))
-    jax.effects_barrier()
+    (_, counts), _ = step(params, {"tokens": toks})
+    # whole numbers (the three moe_*) and the linear layers' float32 pair
+    assert [len(names) for names in counts.keys] == [3, 2]
+    tracing.defer_program_counts(counts)
+    tracing.settle_program_counts(wait=True)
     after = tracing.program_counters()
     delta = {k: after[k] - before.get(k, 0.0) for k in after}
     # three linear layers x 2 sequences x 128 / 64 chunks
@@ -388,7 +391,6 @@ def test_a_hybrid_model_trains_through_fttrainer_and_a_manager(builder):
         before = jax.tree_util.tree_map(np.asarray, trainer.params)
         loss, committed = trainer.train_step({"tokens": toks})
         jax.block_until_ready(trainer.params)
-        jax.effects_barrier()
         assert committed and np.isfinite(float(loss))
         assert abs(float(loss) - np.log(256)) < 1.0
         moved = jax.tree_util.tree_map(

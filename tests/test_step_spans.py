@@ -4,8 +4,8 @@
 spans follow one another on the step thread, cover the step, and
 ``last_step_timings`` is made of their stamps; (b) a span's ``thread``,
 ``thread_id``, ``id`` and ``parent``; (c) spans in a ``jax.profiler``
-capture; (d) the counters' callback counts itself; (e) the benchmark's two
-readers of these spans on a hand-built record.
+capture; (d) the counters leave a program without a callback; (e) the
+benchmark's two readers of these spans on a hand-built record.
 """
 
 import glob
@@ -390,32 +390,34 @@ class TestProfilerAnnotation:
 
 
 class TestProgramCallback:
-    """(d): the counters' callback counts its own runs."""
+    """(d): no program holds a host callback any more; the key that counted
+    its runs stays in the schema, at rest."""
 
-    def test_one_count_a_call(self):
+    def test_a_collecting_program_counts_without_a_callback(self):
         m = make_manager()
         try:
-            @jax.jit
-            def program(x):
+            def rows(x):
                 tracing.count_in_program(test_rows_total=x.shape[0])
                 return x * 2
 
+            program = jax.jit(tracing.collect_counts(rows))
+            assert "callback" not in program.lower(jnp.ones(4)).as_text()
             before = tracing.program_counters()
             for _ in range(3):
-                program(jnp.ones(4)).block_until_ready()
-            jax.effects_barrier()
+                _, counts = program(jnp.ones(4))
+                tracing.defer_program_counts(counts)
+            tracing.settle_program_counts(wait=True)
             after = tracing.program_counters()
             counters = m.metrics()
             spans = m.tracer().spans()
         finally:
             m.shutdown()
         assert after["program_callbacks_total"] \
-            - before["program_callbacks_total"] == 3
+            == before["program_callbacks_total"]
         assert after["test_rows_total"] \
             - before.get("test_rows_total", 0.0) == 12
-        assert counters["program_callbacks_total"] \
-            == after["program_callbacks_total"]
-        # The callback's thread records nothing: the count is all.
+        assert counters["test_rows_total"] == after["test_rows_total"]
+        # Adding the counts records nothing: the count is all.
         assert spans == []
 
     def test_the_count_is_there_before_any_program_ran(self):

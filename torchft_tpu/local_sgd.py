@@ -25,6 +25,7 @@ from typing import Any, Callable, Optional, Tuple
 import jax
 import optax
 
+from torchft_tpu import tracing
 from torchft_tpu.manager import Manager
 
 logger = logging.getLogger(__name__)
@@ -74,9 +75,10 @@ class DiLoCoTrainer:
         self._pending_sync_every: Optional[int] = None
 
         def inner_step(p, st, batch):
-            loss, grads = jax.value_and_grad(loss_fn)(p, batch)
+            (loss, counts), grads = jax.value_and_grad(
+                tracing.collect_counts(loss_fn), has_aux=True)(p, batch)
             updates, st = inner_tx.update(grads, st, p)
-            return optax.apply_updates(p, updates), st, loss
+            return optax.apply_updates(p, updates), st, loss, counts
 
         def outer_update(anchor, ostate, avg_delta):
             updates, ostate = self._outer_tx.update(avg_delta, ostate,
@@ -86,7 +88,10 @@ class DiLoCoTrainer:
         def delta(anchor, p):
             return jax.tree_util.tree_map(lambda a, b: a - b, anchor, p)
 
-        self._inner_step = jax.jit(inner_step) if jit else inner_step
+        # The loss's counts (tracing.count_in_program) are the program's
+        # last output; the host queues them and adds what has finished.
+        self._inner_step = tracing.deferring_counts(
+            jax.jit(inner_step) if jit else inner_step)
         self._outer_update = jax.jit(outer_update) if jit else outer_update
         self._delta = jax.jit(delta) if jit else delta
 

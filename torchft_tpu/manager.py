@@ -3657,9 +3657,12 @@ class Manager:
             if self._manager_server is not None else 0.0)
         out.update(self._retry_stats.snapshot())
         # Totals the jitted programs counted themselves (tracing.
-        # count_in_program: routed pairs, pairs on held experts, ...)
-        # and program_callbacks_total, the runs of their host callback;
-        # process-wide, the former absent until a program counted one.
+        # count_in_program: routed pairs, pairs on held experts, ...),
+        # returned from each program and added once it has finished
+        # (no wait here: a program still running is in the next
+        # snapshot); process-wide, absent until a program counted one.
+        # program_callbacks_total is always there, at 0.0: no program
+        # holds a host callback any more.
         out.update(tracing_mod.program_counters())
         # Bytes that actually crossed the TCP ring, counted by the
         # backend at its send sites (halved vs allreduce_wire_bytes_total

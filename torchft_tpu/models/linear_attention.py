@@ -24,8 +24,10 @@ one more forward scan. A region around everything between the two
 projections was tried and took more (3.1 GiB).
 
 The layer's two numbers for the program counters leave it as values
-(``return_stats=True``), since a callback inside a rematerialised layer
-runs twice: chunks walked, and the mean log decay ``g`` (whether the state
+(``return_stats=True``), since a count taken inside a rematerialised
+layer is a value of that region and cannot leave the program from there
+(``tracing.count_in_program``): chunks walked, and the mean log decay
+``g`` (whether the state
 still carries: near 0 it keeps everything, below about -0.1 a token it has
 forgotten a chunk's start by the chunk's end).
 """

@@ -410,14 +410,19 @@ class RoutedMoEMLP(nn.Module):
     case takes every pass.
 
     Three process-wide program counters (``tracing.count_in_program``;
-    ``Manager.metrics()`` reports them) go up once a call:
+    ``Manager.metrics()`` reports them) go up once a call made under a
+    collector (``tracing.collect_counts``, which every trainer of this
+    package wraps its loss in; under a hand-written
+    ``jax.jit(jax.value_and_grad(...))`` nothing is counted):
     ``moe_pairs_routed_total`` (token-expert pairs routed),
     ``moe_pairs_local_total`` (those whose expert is held) and
-    ``moe_expert_load_max_total`` (the largest load of a held expert). A
-    callback under ``jax.checkpoint`` runs again in the recomputed forward,
-    so a caller that rematerialises the layer asks for the numbers
-    (``return_stats=True``: ``(out, int32[3])`` in that order) and counts
-    them outside, as ``Transformer`` does, once a step for all its layers.
+    ``moe_expert_load_max_total`` (the largest load of a held expert). The
+    collector returns them from the program, so they have to be values of
+    the function it wraps: a caller that rematerialises the layer
+    (``jax.checkpoint``) or runs it in a ``lax.scan`` body asks for the
+    numbers (``return_stats=True``: ``(out, int32[3])`` in that order),
+    returns them out of that region and counts them outside, as
+    ``Transformer`` does, once a step for all its layers.
     """
 
     num_experts: int
@@ -561,7 +566,8 @@ MOE_COUNTERS = ("moe_pairs_routed_total", "moe_pairs_local_total",
 
 def count_moe_stats(stats: jnp.ndarray) -> None:
     """Add a routed layer's ``stats`` (or the sum of several layers') to the
-    program counters; called where the program is not rematerialised."""
+    program counters; called at the collecting function's own level, outside
+    any rematerialised region or scan body."""
     tracing.count_in_program(**dict(zip(MOE_COUNTERS, stats)))
 
 

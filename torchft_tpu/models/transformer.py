@@ -414,8 +414,7 @@ class Transformer(nn.Module):
         embedding, which causal attention keeps from every other), and the
         layers' summed counts as ``{counter: value}`` (``None`` where no
         layer has any), which are then NOT counted here: the caller counts
-        them with whatever else it counts, in one callback
-        (:func:`mtp_causal_lm_loss`)."""
+        them with whatever else it counts (:func:`mtp_causal_lm_loss`)."""
         cfg = self.cfg
         if return_mtp and cfg.mtp_layers != 1:
             raise ValueError("return_mtp needs a model with mtp_layers=1, "
@@ -634,7 +633,7 @@ def _head_loss_fwd(hidden, head_kernel, tokens, chunk_size, matmul_dtype):
     v = head_kernel.shape[1]
     w = _head_operand(head_kernel, matmul_dtype)
     chunks = _head_chunks(hidden, tokens, chunk_size)
-    # counted on the host, when the rule is traced: no callback in the step
+    # counted on the host, when the rule is traced: nothing in the step
     tracing.add_program_counters(
         head_loss_fused_traces_total=1,
         head_loss_chunks_traced_total=chunks[0].shape[0])
@@ -707,10 +706,13 @@ def mtp_causal_lm_loss(model: "Transformer", params: Any,
     have one), both through :func:`chunked_causal_lm_loss` and the one
     ``lm_head`` kernel, whose gradient is the sum of the two.
 
-    One host callback a step carries the routed layers' ``moe_*`` counts
-    (the module's layer included) and the two losses as
+    Under a collector (``tracing.collect_counts``, which every trainer of
+    this package wraps its loss in) the routed layers' ``moe_*`` counts (the
+    module's layer included) and the two losses as
     ``loss_main_micro_total`` / ``loss_mtp_micro_total`` (each loss x 1e6,
-    summed over steps) to :func:`tracing.program_counters`."""
+    summed over steps) leave the step's program as one output and reach
+    :func:`tracing.program_counters`; differentiated by hand
+    (``jax.value_and_grad`` of this function alone) it counts nothing."""
     from torchft_tpu import tracing
 
     hidden, mtp_hidden, stats = model.apply(params, tokens, return_mtp=True)

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from torchft_tpu import tracing
 from torchft_tpu.manager import Manager
 from torchft_tpu.optim import DelayedOptimizer, FTOptimizer
 
@@ -67,6 +68,10 @@ class FTTrainer:
         loss_fn: ``loss_fn(params, batch) -> scalar loss``. Traced once;
             all reference-style per-step branching (healing, membership)
             lives *outside* jit, so the compiled step is branch-free.
+            It is traced under ``tracing.collect_counts``: what it hands
+            to ``tracing.count_in_program`` is returned from the step's
+            program as one or two small vectors and added to
+            ``Manager.metrics()`` once that program has finished.
         tx: optax gradient transformation.
         params: initial parameter pytree (will be ``device_put`` onto
             ``param_shardings`` when given).
@@ -123,15 +128,22 @@ class FTTrainer:
         self._batch_sharding = batch_sharding
         self._strict_commit = strict_commit
 
+        # What the loss counts while it is traced
+        # (tracing.count_in_program) leaves the program as its last
+        # output, one or two small vectors; a loss that counts nothing
+        # (a dense model) returns None there and the program has
+        # today's outputs.
         if self._has_state:
             def fwd_bwd(p: Any, st: Any, batch: Any):
-                (loss, new_st), grads = jax.value_and_grad(
-                    loss_fn, has_aux=True)(p, st, batch)
-                return loss, new_st, grads
+                (loss, (new_st, counts)), grads = jax.value_and_grad(
+                    tracing.collect_counts(loss_fn, has_aux=True),
+                    has_aux=True)(p, st, batch)
+                return loss, new_st, grads, counts
         else:
             def fwd_bwd(p: Any, st: Any, batch: Any):
-                loss, grads = jax.value_and_grad(loss_fn)(p, batch)
-                return loss, None, grads
+                (loss, counts), grads = jax.value_and_grad(
+                    tracing.collect_counts(loss_fn), has_aux=True)(p, batch)
+                return loss, None, grads, counts
 
         self._fwd_bwd = jax.jit(fwd_bwd) if jit_fwd else fwd_bwd
 
@@ -145,9 +157,10 @@ class FTTrainer:
         # params+opt_state copy of HBM while the step runs, same transient
         # peak as the donated raw loop.
         def fused(p: Any, st: Any, o: Any, batch: Any):
-            loss, new_st, grads = fwd_bwd(p, st, batch)
+            loss, new_st, grads, counts = fwd_bwd(p, st, batch)
             updates, new_o = tx.update(grads, o, p)
-            return loss, new_st, optax.apply_updates(p, updates), new_o
+            return (loss, new_st, optax.apply_updates(p, updates), new_o,
+                    counts)
 
         self._fused = jax.jit(fused) if jit_fwd else fused
 
@@ -290,7 +303,7 @@ class FTTrainer:
             # Fused speculative step dispatched immediately (overlaps the
             # quorum); adopted below only if the quorum confirms the
             # single-group shape AND the vote passes.
-            (loss, new_state, new_p, new_o), last = self._dispatch(
+            (loss, new_state, new_p, new_o, counts), last = self._dispatch(
                 self._fused, "fused", last, self.params, self.model_state,
                 self.opt_state, batch, speculative=True)
             dispatch_ns += last.dur_ns
@@ -298,6 +311,7 @@ class FTTrainer:
                 self.manager.wait_quorum()
             wait_ns += last.dur_ns
             if self.manager.single_group_step():
+                tracing.defer_program_counts(counts)
                 loss = self._strict_sync(loss)
                 committed = self.manager.should_commit()
                 if committed and not self.manager.is_healing():
@@ -318,13 +332,15 @@ class FTTrainer:
             # The discarded update is a whole params + optimizer state
             # tree: let it go before the split path makes its gradients
             # (loop glue: the next dispatch takes its own first stamp).
-            del new_state, new_p, new_o
+            # Its counts go with it: only the rerun is counted.
+            del new_state, new_p, new_o, counts
             last = None
 
-        (loss, new_state, grads), dispatched = self._dispatch(
+        (loss, new_state, grads, counts), dispatched = self._dispatch(
             self._fwd_bwd, "fwd_bwd", last, self.params, self.model_state,
             batch)
         dispatch_ns += dispatched.dur_ns
+        tracing.defer_program_counts(counts)
         # The call joins the quorum (its own wait_quorum span) and walks
         # the exchange's stage loop on this thread, under its own
         # fetch_dispatch / fetch_wait spans; only the wait for the
@@ -365,6 +381,11 @@ class FTTrainer:
             out = fn(*args)
             if size is not None and size() > before:
                 span.set(traced=True)
+        # The counts of the programs that finished before this one was
+        # enqueued are read now, while the device is busy with it: at the
+        # boundary the read would stand between the step's end and the
+        # next dispatch.
+        tracing.settle_program_counts()
         return out, span
 
     def _set_timings(self, t0_ns: int, dispatch_ns: int, wait_ns: int,
@@ -456,11 +477,14 @@ class FTTrainer:
                 spec = None
         issued = begun
         if spec is None or healed or self.params is not params_ref:
-            (loss, new_state, grads), issued = self._dispatch(
+            # (A speculative result dropped here is dropped with its
+            # counts.)
+            (loss, new_state, grads, counts), issued = self._dispatch(
                 self._fwd_bwd, "fwd_bwd", begun, self.params,
                 self.model_state, b)
         else:
-            loss, new_state, grads = spec
+            loss, new_state, grads, counts = spec
+        tracing.defer_program_counts(counts)
 
         loss = self._strict_sync(loss)
         fut = (self.manager.reduce_scatter(grads) if self._shard
@@ -524,11 +548,16 @@ class FTTrainer:
         before ``Manager.save_durable`` (which refuses mid-flight
         snapshots) and before a clean shutdown so the final step isn't
         dropped. Returns the vote, or ``None`` when nothing was pending
-        (always ``None`` in sync mode)."""
+        (always ``None`` in sync mode). In either mode it also waits for
+        the counts of programs still running
+        (``tracing.settle_program_counts``)."""
+        committed = None
         if self._overlap and self._opt.pending():
-            self._last_committed = self._opt.settle()
-            return self._last_committed
-        return None
+            committed = self._last_committed = self._opt.settle()
+        # Counts whose read was deferred (no digest at the boundary) are
+        # the caller's to wait for here.
+        tracing.settle_program_counts(wait=True)
+        return committed
 
     def _strict_sync(self, loss: Any) -> Any:
         """Under ``strict_commit``, surface an async device failure *before*

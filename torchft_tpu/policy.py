@@ -393,6 +393,7 @@ class AdaptiveTrainer:
         import jax
         import optax
 
+        from torchft_tpu import tracing
         from torchft_tpu.local_sgd import diloco_outer_optimizer
         from torchft_tpu.optim import DelayedOptimizer, FTOptimizer
 
@@ -405,7 +406,9 @@ class AdaptiveTrainer:
         self.committed_batches = 0
 
         def fwd_bwd(p, batch):
-            return jax.value_and_grad(loss_fn)(p, batch)
+            (loss, counts), grads = jax.value_and_grad(
+                tracing.collect_counts(loss_fn), has_aux=True)(p, batch)
+            return loss, grads, counts
 
         def delta(anchor, p):
             return jax.tree_util.tree_map(lambda a, b: a - b, anchor, p)
@@ -415,7 +418,10 @@ class AdaptiveTrainer:
                                                     anchor)
             return optax.apply_updates(anchor, updates), ostate
 
-        self._fwd_bwd = jax.jit(fwd_bwd) if jit else fwd_bwd
+        # The loss's counts (tracing.count_in_program) are the program's
+        # last output; the host queues them and adds what has finished.
+        self._fwd_bwd = tracing.deferring_counts(
+            jax.jit(fwd_bwd) if jit else fwd_bwd)
         self._delta = jax.jit(delta) if jit else delta
         self._outer_update = (jax.jit(outer_update) if jit
                               else outer_update)

@@ -62,7 +62,7 @@ import sys
 import threading
 import time
 from collections import OrderedDict
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 logger: logging.Logger = logging.getLogger(__name__)
 
@@ -104,21 +104,90 @@ def default_trace_steps() -> int:
 # ------------------------------------------------------ program counters
 #
 # Totals counted by the jitted programs themselves (how many token-expert
-# pairs a step routed, how many landed on held experts): a value computed
-# on the device reaches the host through ``jax.debug.callback`` and is added
-# here. Process-wide: every Manager of the process reports the same totals
-# (``Manager.metrics()`` merges them). A program that holds a host callback
-# is not written to jax's persistent compile cache. What is known when a
-# program is traced needs no callback: the head's loss adds
+# pairs a step routed, how many landed on held experts). Process-wide:
+# every Manager of the process reports the same totals
+# (``Manager.metrics()`` merges them). A count leaves its program as an
+# ordinary output: while a program is traced under :func:`collect_counts`,
+# :func:`count_in_program` appends its values to the collector open on the
+# tracing thread, and the collecting function returns them beside its own
+# result as one :class:`ProgramCounts` (a stacked vector for each kind of
+# number; the keys are the host's from the trace on). The host queues that
+# output at dispatch (:func:`defer_program_counts`) and adds it to the
+# totals once the program has finished (:func:`settle_program_counts`), so
+# no program this package builds holds a host callback: each takes jit's
+# C++ dispatch path and jax's persistent compile cache. What is known when a program is traced
+# needs no output at all: the head's loss adds
 # ``head_loss_fused_traces_total`` (one each time its fused gradient rule is
 # traced) and ``head_loss_chunks_traced_total`` (the chunks of each such
 # loss; their ratio is the chunks a loss) on the host, then and there.
-# The callback counts itself: ``program_callbacks_total`` is its runs (a
-# program that holds one never takes jit's C++ dispatch path, and a
-# callback inside a rematerialised region runs twice a step).
+# ``program_callbacks_total`` counted the runs of the callback that carried
+# the counts until PR 43; nothing adds to it any more, and it stays in the
+# schema at 0.0 until the benchmark's metric that reads it is retired.
 
 _program_counters: Dict[str, float] = {"program_callbacks_total": 0.0}
 _program_counters_lock = threading.Lock()
+# Per thread: the collectors open while it traces, innermost last.
+_collectors = threading.local()
+# Outputs of dispatched programs, not yet added to the totals.
+_pending_counts: List["ProgramCounts"] = []
+_counts_registered = False
+
+
+class ProgramCounts:
+    """What a collecting program returns for its counts: at most two small
+    vectors, the whole numbers stacked as ``int32`` and the others as
+    ``f32``; ``keys[i]`` names ``values[i]``'s elements in (sorted) order.
+    A pytree whose leaves are the vectors and whose keys ride in the
+    tree's structure: two output leaves and two transfers a step at most,
+    whatever the number of counters, and the host knows the names from
+    the trace on. The totals are summed on the host in f64.
+
+    Why not one vector: joined, values from the routers (early in the
+    forward) and from the loss (its end) make one operation depend on
+    both, and the chip's compiler then scheduled a step differently
+    enough that it no longer matched the same step written without counts
+    bit for bit (``joyai-llm-flash``, PERF.md PR 43); side by side they
+    leave the rest of the program as it was. Whole numbers also stay
+    exact beyond 2**24."""
+
+    __slots__ = ("keys", "values")
+
+    def __init__(self, keys: Tuple[Tuple[str, ...], ...],
+                 values: Tuple[Any, ...]) -> None:
+        self.keys = keys
+        self.values = values
+
+    def __repr__(self) -> str:
+        return f"ProgramCounts({self.keys!r}, {self.values!r})"
+
+    def ready(self) -> bool:
+        """Whether the program that made them has finished."""
+        return all(getattr(v, "is_ready", lambda: True)()
+                   for v in self.values)
+
+    def totals(self) -> Dict[str, float]:
+        """Host side: ``{key: value}``; waits for the program."""
+        import numpy as np
+
+        return {key: float(x) for names, vector in zip(self.keys, self.values)
+                for key, x in zip(names, np.asarray(vector))}
+
+
+def _register_counts() -> None:
+    """Make :class:`ProgramCounts` a pytree, at the first use that needs
+    jax (this module imports without it)."""
+    global _counts_registered
+    if _counts_registered:
+        return
+    from jax import tree_util
+
+    with _program_counters_lock:
+        if not _counts_registered:
+            tree_util.register_pytree_node(
+                ProgramCounts,
+                lambda c: (c.values, c.keys),
+                lambda keys, values: ProgramCounts(keys, tuple(values)))
+            _counts_registered = True
 
 
 def add_program_counters(**values: Any) -> None:
@@ -130,20 +199,138 @@ def add_program_counters(**values: Any) -> None:
 
 
 def count_in_program(**values: Any) -> None:
-    """Inside a jitted program: add ``values`` (traced scalars or Python
-    numbers) to the totals each time the program runs. Call it outside
-    ``jax.checkpoint``: a rematerialised forward runs the callback again."""
-    import jax
+    """Inside a function traced under :func:`collect_counts`: hand
+    ``values`` (traced scalars or Python numbers) to the open collector,
+    which returns them from the program; the host adds them to the totals
+    each time the program has run. The same key counted twice in one trace
+    is summed. Outside a collector (a ``jax.jit(jax.value_and_grad(...))``
+    written by hand) nothing is counted and nothing is raised.
 
-    keys = sorted(values)
-    jax.debug.callback(
-        lambda *vals: add_program_counters(program_callbacks_total=1,
-                                           **dict(zip(keys, vals))),
-        *[values[k] for k in keys])
+    The values must be the collecting function's own: a count taken inside
+    ``jax.checkpoint``, a ``lax.scan`` body or an inner ``jax.jit`` is a
+    tracer of that region and has to be returned out of it first (as
+    ``RoutedMoEMLP(return_stats=True)`` does for a rematerialised layer),
+    or jax reports a leaked tracer when the collector stacks it."""
+    stack = getattr(_collectors, "open", None)
+    if not stack:
+        return
+    taken = stack[-1]
+    for key, value in values.items():
+        taken[key] = taken[key] + value if key in taken else value
+
+
+def collect_counts(fn: Callable[..., Any],
+                   has_aux: bool = False) -> Callable[..., Any]:
+    """``fn'`` that runs ``fn`` under a collector and returns
+    ``(fn(...), counts)``: ``counts`` is what :func:`count_in_program` was
+    handed during the call as one :class:`ProgramCounts`, or ``None`` where
+    nothing was counted (the program then has no output more than ``fn``'s).
+    With ``has_aux`` (``fn`` returns ``(out, aux)``) it returns
+    ``(out, (aux, counts))``. Either form is what
+    ``jax.value_and_grad(..., has_aux=True)`` takes: wrap the function that
+    is differentiated, so the collector opens inside the differentiation
+    and the values are tracers of its level. The caller returns ``counts``
+    from its jitted program and hands that output to
+    :func:`defer_program_counts`."""
+    _register_counts()
+
+    def collecting(*args: Any, **kwargs: Any) -> Any:
+        import jax
+        import jax.numpy as jnp
+
+        stack = getattr(_collectors, "open", None)
+        if stack is None:
+            stack = _collectors.open = []
+        taken: Dict[str, Any] = {}
+        stack.append(taken)
+        try:
+            out = fn(*args, **kwargs)
+        finally:
+            stack.pop()
+        counts = None
+        if taken:
+            whole: Dict[str, Any] = {}
+            other: Dict[str, Any] = {}
+            for key in sorted(taken):
+                value = jnp.asarray(taken[key])
+                if (jnp.issubdtype(value.dtype, jnp.integer)
+                        or value.dtype == jnp.bool_):
+                    whole[key] = value.astype(jnp.int32)
+                else:
+                    other[key] = value.astype(jnp.float32)
+            kinds = [kind for kind in (whole, other) if kind]
+            counts = ProgramCounts(
+                tuple(tuple(kind) for kind in kinds),
+                tuple(jax.lax.stop_gradient(jnp.stack(list(kind.values())))
+                      for kind in kinds))
+        if has_aux:
+            out, aux = out
+            return out, (aux, counts)
+        return out, counts
+
+    return collecting
+
+
+def defer_program_counts(counts: Optional[ProgramCounts]) -> None:
+    """Host side, after dispatching a collecting program: queue its
+    ``counts`` output (``None``, a program that counted nothing, is
+    ignored). Nothing waits here: :func:`settle_program_counts` adds them
+    once the program has finished. A result that is thrown away (a
+    discarded speculative step) is simply not deferred."""
+    if counts is None:
+        return
+    with _program_counters_lock:
+        _pending_counts.append(counts)
+
+
+def settle_program_counts(wait: bool = False) -> None:
+    """Host side: add the queued counts of every program that has finished
+    to the totals, and leave the others queued; with ``wait`` block for
+    those too. Without ``wait`` it costs the caller no wait for the
+    device; the trainers call it right after they enqueue the next
+    program, so the read of what finished before runs under that
+    program. The counts of a program that failed are
+    dropped (the step's own error path reports the failure)."""
+    if not _pending_counts:
+        return
+    with _program_counters_lock:
+        queued = list(_pending_counts)
+        del _pending_counts[:]
+    later = []
+    for counts in queued:
+        if not wait and not counts.ready():
+            later.append(counts)
+            continue
+        try:
+            totals = counts.totals()
+        except Exception:  # noqa: BLE001 - observability never fails a step
+            logger.warning("a program's counts were dropped", exc_info=True)
+            continue
+        add_program_counters(**totals)
+    if later:
+        with _program_counters_lock:
+            _pending_counts[0:0] = later
+
+
+def deferring_counts(program: Callable[..., Any]) -> Callable[..., Any]:
+    """Host side, for a loop that dispatches one collecting program a
+    step: ``program`` (jitted or not) returns ``(..., counts)``; the
+    wrapper queues the counts, adds what has finished meanwhile, and
+    returns the rest."""
+
+    def run(*args: Any) -> Any:
+        *out, counts = program(*args)
+        defer_program_counts(counts)
+        settle_program_counts()
+        return tuple(out)
+
+    return run
 
 
 def program_counters() -> Dict[str, float]:
-    """A snapshot of the totals."""
+    """A snapshot of the totals, with every finished program's counts in
+    (the queued outputs of programs still running are not waited for)."""
+    settle_program_counts()
     with _program_counters_lock:
         return dict(_program_counters)
 
