@@ -34,9 +34,7 @@ HBM_BYTES = 15.75 * 2**30   # what a v5e chip gives a program (PERF.md, PR 25)
 def compile_cell(cell, batch, one_chip):
     import jax
     import jax.numpy as jnp
-    import optax
-
-    from harness import spec
+    from harness import reference, spec
 
     driver = spec.module("drivers", cell.mix["driver"])
     cfg, seq = driver.run_config(cell, rehearse=False)
@@ -54,21 +52,16 @@ def compile_cell(cell, batch, one_chip):
         lambda x: spec_of(x.shape, x.dtype), jax.eval_shape(tx.init, params))
     tokens = {"tokens": spec_of((batch, seq), jnp.int32)}
 
-    def fused(p, o, b):
-        loss, g = jax.value_and_grad(loss_fn)(p, b)
-        updates, o = tx.update(g, o, p)
-        return loss, optax.apply_updates(p, updates), o
-
-    def fwd_bwd(p, b):
-        return jax.value_and_grad(loss_fn)(p, b)
-
-    out = {}
+    # The trainer's own two programs (``reference.step_programs``): the
+    # oracle runs them, so what fits here is what both hold.
+    fwd_bwd, fused = reference.step_programs(loss_fn, tx)
     if int(cell.mix["groups"]) == 1:
-        programs = {"fused_step": (fused, (params, opt, tokens))}
+        programs = {"fused_step": (fused, (params, None, opt, tokens))}
     else:
-        programs = {"fwd_bwd": (fwd_bwd, (params, tokens))}
+        programs = {"fwd_bwd": (fwd_bwd, (params, None, tokens))}
+    out = {}
     for name, (fn, args) in programs.items():
-        compiled = jax.jit(fn).lower(*args).compile()
+        compiled = fn.lower(*args).compile()
         m = compiled.memory_analysis()
         total = (m.argument_size_in_bytes + m.output_size_in_bytes
                  + m.temp_size_in_bytes - m.alias_size_in_bytes)

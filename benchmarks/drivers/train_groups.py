@@ -234,13 +234,17 @@ class Host:
     def make_oracle(self) -> None:
         """(c): what the job's first two steps must give. The first is the
         protocol's init sync, in which only the primary's gradients count;
-        in the second every group's do."""
-        params = reference.init_params(self.model, self.cfg, self.seed)
+        in the second every group's do. The seeded tree gets no name here:
+        it is the oracle's to let go (``reference.oracle_steps``)."""
         everyone = list(range(self.n_groups))
         batches = [[self.tokens(g, k)["tokens"] for g in everyone]
                    for k in range(ORACLE_STEPS)]
         self.oracle = reference.oracle_steps(
-            self.loss_fn, self.tx, params, batches, [[0], everyone])
+            self.loss_fn, self.tx,
+            reference.init_params(self.model, self.cfg, self.seed),
+            batches, [[0], everyone])
+        log(f"  the oracle's sample: "
+            f"{sum(x.nbytes for x in self.oracle['sample']) / 2**30:.3f} GiB")
 
     # -- one step
 
@@ -285,6 +289,7 @@ class Host:
             sync.barrier("ready", gi, n)
             if lead:
                 mark("trainers built")
+                self.memory("the trainers' making")
             warm = mix["warmup"]
             self._phase(gi, st, "warmup", events=[],
                         min_joint=int(warm["joint_steps"]), seconds=0.0,
@@ -297,6 +302,7 @@ class Host:
             sync.barrier("warm", gi, n)
             if lead:
                 mark("warm-up done")
+                self.memory("warm-up")
             first_here = gi == min(self.groups_here)
             if first_here:
                 self.rec["compiles_begin"] = self.counter.snapshot()
@@ -381,6 +387,16 @@ class Host:
     def keep_counters(self, name: str, counters: Mapping[str, Any]) -> None:
         with self.lock:
             self.rec["counters"][name] = dict(counters)
+
+    def memory(self, moment: str) -> int:
+        """The device's peak so far, one line a ``moment``: the phase that
+        ``peak_hbm_gib`` reads is the last one that raised it."""
+        stats = self.device.memory_stats() or {}
+        peak, now = (stats.get(k) or 0
+                     for k in ("peak_bytes_in_use", "bytes_in_use"))
+        log(f"  memory after {moment}: peak {peak / 2**30:.3f} GiB, "
+            f"{now / 2**30:.3f} GiB in use")
+        return peak
 
     def event(self, name: str, t_ns: int, first: bool = False) -> None:
         with self.lock:
@@ -488,8 +504,10 @@ class Host:
         if lead:
             self.check_against_reference()
             mark("gradients compared with the reference")
+            self.memory("the reference check")
             self.make_oracle()
             mark("oracle made")
+            self.memory("the oracle")
             gc.collect()
         threads = [threading.Thread(target=self.run_group, args=(gi,),
                                     name=f"group-{gi}")
@@ -501,8 +519,7 @@ class Host:
         if any(t.is_alive() for t in threads):
             self.rec["errors"].append("a replica group hung")
             self.sync.set("failed", "hung")
-        stats = self.device.memory_stats() or {}
-        self.rec["memory_peak_bytes"] = stats.get("peak_bytes_in_use")
+        self.rec["memory_peak_bytes"] = self.memory("the window")
         if lead and self.trace and not self.rec["errors"]:
             self.rec["raw_walls"] = self.raw_loop()
         return self.rec

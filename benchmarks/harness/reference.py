@@ -10,7 +10,8 @@ it) is the builder's: ``models/<builder>.py``, named by the configuration.
 from __future__ import annotations
 
 import functools
-from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence
+from typing import (Any, Callable, Dict, List, Mapping, Optional, Sequence,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -164,6 +165,38 @@ def _thin(tree: Any) -> List[Any]:
     return out
 
 
+def step_programs(loss_fn: Callable, tx: Any) -> Tuple[Callable, Callable]:
+    """``(fwd_bwd, fused)``, jitted: the two programs of an ``FTTrainer``
+    without model state, letter for letter
+    (``torchft_tpu/parallel/step.py``, ``FTTrainer.__init__``: the
+    ``fwd_bwd`` of its ``else`` branch and ``fused``), so that the chip's
+    compiler gives the oracle and the trainer the same code: a different
+    program of the same mathematics differs at bfloat16 level there (XLA may
+    keep excess precision wherever a fusion ends), which adam then amplifies
+    and a router turns into another selection. That includes the loss under
+    ``tracing.collect_counts`` and its counts as each program's last output:
+    differentiated by hand the step has one output fewer, and the compiler
+    is then free to schedule it otherwise (PERF.md, PR 43).
+    ``benchmarks/tests/test_oracle_program.py`` holds the two to the
+    trainer's own by their lowered text: a change to either side alone
+    fails there, and these lines are then carried over from ``step.py``."""
+    import optax
+
+    from torchft_tpu import tracing
+
+    def fwd_bwd(p, st, batch):
+        (loss, counts), grads = jax.value_and_grad(
+            tracing.collect_counts(loss_fn), has_aux=True)(p, batch)
+        return loss, None, grads, counts
+
+    def fused(p, st, o, batch):
+        loss, new_st, grads, counts = fwd_bwd(p, st, batch)
+        updates, new_o = tx.update(grads, o, p)
+        return (loss, new_st, optax.apply_updates(p, updates), new_o,
+                counts)
+
+    return jax.jit(fwd_bwd), jax.jit(fused)
+
 
 def oracle_steps(loss_fn: Callable, tx: Any, params0: Any,
                  batches: Sequence[Sequence[Any]],
@@ -181,29 +214,25 @@ def oracle_steps(loss_fn: Callable, tx: Any, params0: Any,
     step and, leaf by leaf, how far that step moved it: the scale an error
     is read against.
 
+    ``params0`` becomes the oracle's: pass the seeded tree without keeping
+    a name for it, and its buffers go when the first step has run. With one
+    group the oracle then holds what the trainer's non-donated step holds,
+    state in and state out, and one thinned sample beside them in the last
+    step; it must not hold more, or a cell is sized by its check.
+
     The controls: ``wire`` rounds each group's gradients to that type before
     the sum, as a narrower wire would; ``store`` rounds the updated
-    parameters to that type, as parameters kept in it would be."""
+    parameters to that type, as parameters kept in it would be.
+
+    What the loss counts (``tracing.count_in_program``) leaves the programs
+    as in the trainer and is dropped here: the run's counters stay the
+    trainer's."""
     import optax
 
     n_groups = len(batches[0])
     wire_fn = round_to(wire) if wire is not None else None
     store_fn = round_to(store) if store is not None else None
-
-    # The two programs are FTTrainer's own, letter for letter, so that the
-    # chip's compiler gives them the same code: a different program of the
-    # same mathematics differs at bfloat16 level there (XLA may keep excess
-    # precision wherever a fusion ends), which adam then amplifies.
-    def fwd_bwd(p, st, batch):
-        loss, grads = jax.value_and_grad(loss_fn)(p, batch)
-        return loss, None, grads
-
-    def fused(p, st, o, batch):
-        loss, new_st, grads = fwd_bwd(p, st, batch)
-        updates, new_o = tx.update(grads, o, p)
-        return loss, new_st, optax.apply_updates(p, updates), new_o
-
-    fwd_bwd_jit, fused_jit = jax.jit(fwd_bwd), jax.jit(fused)
+    fwd_bwd_jit, fused_jit = step_programs(loss_fn, tx)
 
     @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
     def update(total, params, opt, n):
@@ -211,32 +240,48 @@ def oracle_steps(loss_fn: Callable, tx: Any, params0: Any,
         updates, new_opt = tx.update(avg, opt, params)
         return optax.apply_updates(params, updates), new_opt
 
+    add = jax.jit(lambda a, b: jax.tree_util.tree_map(jnp.add, a, b),
+                  donate_argnums=(0,))
     thin = jax.jit(_thin)
     params, opt = params0, jax.jit(tx.init)(params0)
-    for step_batches, who in zip(batches, contributors):
-        before = thin({"params": params, "opt_state": opt})
+    del params0
+    before = None
+    for k, (step_batches, who) in enumerate(zip(batches, contributors)):
+        if k == len(contributors) - 1:
+            before = thin({"params": params, "opt_state": opt})
         if n_groups == 1 and wire_fn is None:
-            _, _, new, new_opt = fused_jit(
+            _, _, new, new_opt, _ = fused_jit(
                 params, None, opt, {"tokens": step_batches[0]})
         else:
             total = None
             for g in who:
-                _, _, one = fwd_bwd_jit(params, None,
-                                        {"tokens": step_batches[g]})
+                _, _, one, _ = fwd_bwd_jit(params, None,
+                                           {"tokens": step_batches[g]})
                 if wire_fn is not None:
                     one = jax.jit(lambda t: jax.tree_util.tree_map(
                         wire_fn, t))(one)
-                total = one if total is None else jax.tree_util.tree_map(
-                    jnp.add, total, one)
+                # The sum in place, and waited for: a gradient let go
+                # while the sum that reads it is queued would still be
+                # there when the next group's is allocated (with four
+                # groups the oracle held eight trees so, 15.09 GiB of
+                # the chip's 15.75, where a trainer holds six; PR 44).
+                total = one if total is None else add(total, one)
                 del one
+                jax.block_until_ready(total)
             new, new_opt = update(total, params, opt, float(n_groups))
         if store_fn is not None:
             new = jax.jit(lambda t: jax.tree_util.tree_map(store_fn, t),
                           donate_argnums=(0,))(new)
-        params, opt = new, new_opt      # the old state is let go here
+        # Wait for the step, then let the old state go: a buffer dropped
+        # while its program runs is freed some time after, and what is
+        # enqueued next (the sample below) is allocated beside it: a second
+        # sample at the peak (read on the chip, PR 44).
+        jax.block_until_ready((new, new_opt))
+        params, opt = new, new_opt
         del new, new_opt
-        sample = thin({"params": params, "opt_state": opt})
-        moved = [float(_rms(a, b)) for a, b in zip(sample, before)]
+    sample = thin({"params": params, "opt_state": opt})
+    del params, opt
+    moved = [float(_rms(a, b)) for a, b in zip(sample, before)]
     return {"sample": sample, "moved": moved}
 
 

@@ -1,7 +1,9 @@
 """A key of ``Manager.metrics()`` as its change over the window, optionally
 divided by another key's change. ``on``: ``leader`` (group 0's manager,
 window begin to window end) or ``replacement`` (the last manager born inside
-the window, whose totals are the changes)."""
+the window, whose totals are the changes). Where the window's last snapshot
+lacks a key (a parent commit from before the counter) there is nothing to
+read."""
 
 
 def _counters(run, on):
@@ -22,6 +24,8 @@ def read(run, args):
     if pair is None or pair[0] is None:
         return None
     begin, end = pair
+    if args["key"] not in end or args.get("per", args["key"]) not in end:
+        return None
 
     def delta(key):
         return float(end[key]) - float(begin.get(key, 0.0))

@@ -103,7 +103,9 @@ def test_every_line_of_the_benchmark_file_is_within_its_limits(bench):
     assert cell["chips"] == 1
     listed = {m["name"] for m in bench["per_layer"]
               if CELL in m.get("workloads", [])}
-    assert listed == {
+    # At least these: a tracing PR adds metrics of the step thread to every
+    # cell's list, and this test is not the one to know them.
+    assert listed >= {
         "entry_other_ms", "quorum_ms", "commit_ms", "raw_step_ms", "mfu_pct",
         "device_idle_pct", "peak_hbm_gib", "attest_device_ms",
         "moe_device_ms", "moe_experts_roofline", "attn_window_roofline",
@@ -204,6 +206,7 @@ def test_the_cell_is_found_and_runs_in_rehearsal():
     assert result["correct"] is True, out.stdout[-3000:]
     assert result["device"]["platform"] == "cpu"
     got = result["metrics"]
-    # 2 x 64 tokens, every one of 4 experts selected, 2 held, 4 layers
-    assert got["moe_pairs_local"]["value"] == 4 * 128 * 2
+    # 1 x 64 tokens a step (the mix's batch of 1 at REHEARSE_SEQ), every one
+    # of 4 experts selected, 2 held, 4 expert layers
+    assert got["moe_pairs_local"]["value"] == 4 * 64 * 2
     assert "moe_device_ms" not in got and "mfu_pct" not in got

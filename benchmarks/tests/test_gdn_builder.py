@@ -135,7 +135,9 @@ def test_every_line_of_the_benchmark_file_is_within_its_limits(bench):
               if CELL in m.get("workloads", [])}
     new = ["gdn_scan_device_ms", "gdn_chunks", "moe_device_ms_512",
            "attn_gqa256_roofline"]
-    assert listed == {
+    # At least these (later PRs add metrics to the cell's list, and after
+    # these four in the file).
+    assert listed >= {
         "entry_other_ms", "quorum_ms", "commit_ms", "raw_step_ms", "mfu_pct",
         "device_idle_pct", "peak_hbm_gib", "attest_device_ms",
         "moe_pairs_local", *new}
@@ -145,9 +147,9 @@ def test_every_line_of_the_benchmark_file_is_within_its_limits(bench):
     assert CELL not in next(m for m in bench["per_layer"]
                             if m["name"] == "moe_experts_roofline")[
                                 "workloads"]
-    assert [m["name"] for m in bench["per_layer"]][-4:] == new
-    for m in bench["per_layer"][-4:]:
-        assert m["workloads"] == [CELL] and m["moves"] == "tokens_per_s"
+    for m in bench["per_layer"]:
+        if m["name"] in new:
+            assert m["workloads"] == [CELL] and m["moves"] == "tokens_per_s"
     for m in bench["per_layer"]:
         if CELL in m.get("workloads", []):
             assert m["workloads"][-1] == CELL        # appended, last

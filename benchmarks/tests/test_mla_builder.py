@@ -126,13 +126,13 @@ def test_every_line_of_the_benchmark_file_is_within_its_limits(bench):
     assert cell["chips"] == 1 and cell["traffic"] == "steady-1g-8k"
     listed = {m["name"] for m in bench["per_layer"]
               if CELL in m.get("workloads", [])}
-    assert listed == {
+    # At least these (later PRs add metrics to the cell's list, and after
+    # these three in the file).
+    assert listed >= {
         "entry_other_ms", "quorum_ms", "commit_ms", "raw_step_ms", "mfu_pct",
         "device_idle_pct", "peak_hbm_gib", "attest_device_ms",
         "moe_experts_roofline", "moe_pairs_local", "attn_mla_roofline",
         "moe_device_ms_768", "head_loss_device_ms"}
-    assert [m["name"] for m in bench["per_layer"]][-3:] == [
-        "attn_mla_roofline", "moe_device_ms_768", "head_loss_device_ms"]
     assert os.path.getsize(os.path.join(REPO, "BENCHMARK.json")) < 64 * 1024
 
 

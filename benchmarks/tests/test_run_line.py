@@ -9,7 +9,7 @@ import os
 import pytest
 
 import run as bench
-from harness import spec
+from harness import readers, spec
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 REPO = os.path.dirname(BENCH)
@@ -68,3 +68,17 @@ def test_every_traffic_mix_loads(cell):
         assert "why_malloc_env" in mix                # a setting has a reason
     if int(mix["groups"]) == 1:
         assert mix["env"] == {}                       # and so no restart
+
+
+@pytest.mark.parametrize("reader, want", [
+    ({"key": "a_total", "per": "committed_steps"}, 3.0),
+    ({"key": "b_total", "per": "committed_steps"}, None),   # no such counter
+    ({"key": "a_total", "per": "b_total"}, None),
+    ({"key": "b_total"}, None),
+])
+def test_a_counter_the_program_lacks_reads_as_nothing(reader, want):
+    """A counter metric added with its counter is read on the parent commit
+    too, whose snapshots lack the key: nothing to read, no ``KeyError``."""
+    run = {"counters": {"begin.0": {"a_total": 2.0, "committed_steps": 4.0},
+                        "end.0.0": {"a_total": 14.0, "committed_steps": 8.0}}}
+    assert readers.read(run, {"kind": "counter_delta", **reader}) == want
