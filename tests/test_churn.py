@@ -454,10 +454,15 @@ class TestPreemptionDrain:
         try:
             m.request_preemption(120.0)
             remaining = m.request_preemption(60.0)
-            assert remaining <= 60.0
+            # (now + 60.0) - now is 60.0 to a rounding of the clock's sum:
+            # where now + 60 crosses a power of two the sum loses a bit and
+            # the difference reads just over 60.0 a quarter of the time (the
+            # monotonic clock 4,036-4,096 s or 8,132-8,192 s after boot:
+            # the driver's run of 7c58aa3 failed `<= 60.0` there).
+            assert remaining <= 60.0 + 1e-6
             # A later, LONGER notice must not extend the armed deadline.
             remaining = m.request_preemption(300.0)
-            assert remaining <= 60.0
+            assert remaining <= 60.0 + 1e-6
             assert m.metrics()["preempt_notices_total"] == 3
         finally:
             m.shutdown()

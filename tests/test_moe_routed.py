@@ -1,8 +1,11 @@
 """The routed expert layer over a share of the experts
 (``models/moe.py: RoutedMoEMLP``) against a plain loop over experts: held all,
 a share, an empty share; the shares add up to the whole layer; nothing is
-dropped under the worst imbalance; the dense-dispatch layer and the routed
-one share one router; counters go up once a step under remat."""
+dropped under the worst imbalance, each for both forms of an expert
+(``swiglu``: three matrices; ``relu2``: two and a squared ReLU, the pass
+loops' hand-written backward against plain autodiff of the loop); the
+dense-dispatch layer and the routed one share one router; counters go up
+once a step under remat."""
 
 import jax
 import jax.numpy as jnp
@@ -11,11 +14,12 @@ import pytest
 
 from torchft_tpu import tracing
 from torchft_tpu.models import Transformer, tiny_config
-from torchft_tpu.models.moe import (MOE_COUNTERS, MoEMLP, RoutedMoEMLP,
-                                    padded_rows, route)
+from torchft_tpu.models.moe import (FORMS, MOE_COUNTERS, MoEMLP,
+                                    RoutedMoEMLP, _tile, padded_rows, route)
 
 E, K, D, H = 16, 4, 64, 32
 SCALE = 2.826
+BOTH_FORMS = pytest.mark.parametrize("form", list(FORMS))
 
 
 def layer(held, **kw):
@@ -23,6 +27,21 @@ def layer(held, **kw):
     return RoutedMoEMLP(num_experts=E, mlp_dim=H, top_k=K, held=held,
                         route_scale=SCALE, dtype=jnp.float32,
                         interpret=True, **kw)
+
+
+def expert(u, p, e=None):
+    """One expert as its equations read, by the matrices it has: ``gate``,
+    ``up``, ``down`` (SwiGLU) or ``up``, ``down`` (squared ReLU); ``e``
+    picks it out of the routed stacks, ``None`` is the shared one."""
+    if e is None:
+        up, down = p["up"]["kernel"], p["down"]["kernel"]
+        gate = p["gate"]["kernel"] if "gate" in p else None
+    else:
+        up, down = p["wi_up"][e], p["wo"][e]
+        gate = p["wi_gate"][e] if "wi_gate" in p else None
+    if gate is None:
+        return jnp.square(jax.nn.relu(u @ up)) @ down
+    return (jax.nn.silu(u @ gate) * (u @ up)) @ down
 
 
 def plain(p, x, first, count, shared=True):
@@ -34,27 +53,23 @@ def plain(p, x, first, count, shared=True):
     w = SCALE * top / (top.sum(-1, keepdims=True) + 1e-20)
     out = jnp.zeros_like(u)
     if shared:
-        sh = p["shared"]
-        out = (jax.nn.silu(u @ sh["gate"]["kernel"])
-               * (u @ sh["up"]["kernel"])) @ sh["down"]["kernel"]
+        out = expert(u, p["shared"])
     for e in range(count):
         w_e = jnp.sum(jnp.where(idx == first + e, w, 0.0), -1)
-        y = (jax.nn.silu(u @ p["wi_gate"][e]) * (u @ p["wi_up"][e])) \
-            @ p["wo"][e]
-        out = out + w_e[:, None] * y
+        out = out + w_e[:, None] * expert(u, p, e)
     return out.reshape(x.shape)
 
 
-def full_params(seed=0, tokens=256):
+def full_params(seed=0, tokens=256, form="swiglu"):
     x = jax.random.normal(jax.random.key(seed + 100), (2, tokens // 2, D))
-    return layer(None).init(jax.random.key(seed), x)["params"], x
+    return layer(None, form=form).init(jax.random.key(seed), x)["params"], x
 
 
 def share_of(p, first, count):
     q = {"router": p["router"], "shared": p["shared"]}
     if count:
         q.update({k: p[k][first:first + count]
-                  for k in ("wi_gate", "wi_up", "wo")})
+                  for k in ("wi_gate", "wi_up", "wo") if k in p})
     return q
 
 
@@ -64,13 +79,19 @@ def highest():
         yield
 
 
+@BOTH_FORMS
 @pytest.mark.parametrize("held", [(0, E), (4, 3), (15, 1), (5, 0)],
                          ids=["all", "share", "last", "empty"])
-def test_routed_layer_against_the_plain_loop(held):
-    p, x = full_params()
+def test_routed_layer_against_the_plain_loop(held, form):
+    """Forward, and the backward of the pass loops (a ``custom_vjp`` that
+    recomputes each pass) against plain autodiff of the loop over
+    experts."""
+    p, x = full_params(form=form)
+    assert sorted(k for k in p if k.startswith("w")) == sorted(FORMS[form])
+    assert ("gate" in p["shared"]) == (form == "swiglu")
     first, count = held
     mine = share_of(p, first, count)
-    m = layer(held)
+    m = layer(held, form=form)
     out, stats = m.apply({"params": mine}, x, return_stats=True)
     want = plain(mine, x, first, count)
     np.testing.assert_allclose(out, want, atol=2e-5)
@@ -88,32 +109,34 @@ def test_routed_layer_against_the_plain_loop(held):
         np.testing.assert_allclose(a, b, atol=3e-4, rtol=1e-4)
 
 
+@BOTH_FORMS
 @pytest.mark.parametrize("shares", [16, 4], ids=["16x1", "4x4"])
-def test_the_shares_add_up_to_the_whole_layer(shares):
+def test_the_shares_add_up_to_the_whole_layer(shares, form):
     """What all the shares give, with the shared expert counted once, is
     the uncut layer."""
-    p, x = full_params(seed=3)
+    p, x = full_params(seed=3, form=form)
     per = E // shares
     shared_only = plain(share_of(p, 0, 0), x, 0, 0)
     total = shared_only
     for i in range(shares):
-        part = layer((i * per, per)).apply(
+        part = layer((i * per, per), form=form).apply(
             {"params": share_of(p, i * per, per)}, x)
         total = total + (part - shared_only)
     np.testing.assert_allclose(total, plain(p, x, 0, E), atol=5e-5)
 
 
+@BOTH_FORMS
 @pytest.mark.parametrize("pass_rows", [512, 32768], ids=["passes", "one"])
 def test_no_pair_is_dropped_when_every_token_picks_the_same_experts(
-        pass_rows):
+        pass_rows, form):
     """Every token's K picks are the K held experts: all T*K pairs land
     here, every pass runs, and the result is still the plain loop's."""
-    p, x = full_params(seed=5, tokens=512)
+    p, x = full_params(seed=5, tokens=512, form=form)
     x = jnp.abs(x) + 0.1
     col = jnp.where(jnp.arange(E) < K, 1.0, -1.0)
     p = {**p, "router": {"kernel": jnp.broadcast_to(col, (D, E)) * 0.05}}
     mine = share_of(p, 0, K)
-    out, stats = layer((0, K), pass_rows=pass_rows).apply(
+    out, stats = layer((0, K), pass_rows=pass_rows, form=form).apply(
         {"params": mine}, x, return_stats=True)
     t = x.shape[0] * x.shape[1]
     assert [int(v) for v in stats] == [t * K, t * K, t]
@@ -140,6 +163,19 @@ def test_dense_and_routed_dispatch_share_one_router():
                                          (1000, 1024), (131072, 131072)])
 def test_slots_are_whole_row_tiles(pairs, slots):
     assert padded_rows(pairs) == slots
+
+
+@pytest.mark.parametrize("dim,cap,tile", [
+    # the widths the three SwiGLU cells run: what they always were
+    (2048, 1024, 1024), (2048, 512, 512), (1024, 1024, 1024),
+    (1024, 512, 512), (768, 1024, 768), (768, 512, 256), (512, 512, 512),
+    # 2688 = 21 x 128 and 1856 = 14.5 x 128 have no power-of-two divisor
+    # over 128: the multiple of 128 that pads least, the largest such
+    (2688, 1024, 896), (2688, 512, 384), (1856, 1024, 640),
+    (1856, 512, 384)])
+def test_product_tiles_by_width(dim, cap, tile):
+    assert _tile(dim, cap) == tile
+    assert tile <= cap and (tile % 128 == 0 or tile == dim)
 
 
 @pytest.mark.parametrize("remat", [True, False], ids=["remat", "plain"])

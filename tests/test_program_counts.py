@@ -323,7 +323,71 @@ def _routed(remat):
     return loss_fn, params, {"tokens": toks}
 
 
+def _mamba(remat):
+    """Two mamba blocks and a relu^2 expert block between them, 2 x 256
+    tokens: two chunks a sequence a block."""
+    from torchft_tpu.models.transformer import TransformerConfig
+
+    cfg = TransformerConfig(
+        vocab_size=64, num_layers=3, embed_dim=32, num_heads=2,
+        max_seq_len=256, dtype=jnp.float32, remat=remat,
+        layer_types=("mamba", "moe", "mamba"), ssm_heads=2, ssm_head_dim=8,
+        ssm_groups=1, ssm_state=8, moe_experts=4, moe_top_k=TOP_K,
+        moe_dispatch="routed", moe_held=(0, 2), moe_dim=16,
+        moe_form="relu2", moe_interpret=True)
+    model = Transformer(cfg)
+    toks = jax.random.randint(jax.random.key(1), (2, 256), 0, cfg.vocab_size)
+    params = {"params": model.init(jax.random.key(0), toks)["params"]}
+
+    def loss_fn(p, batch):
+        return jnp.mean(model.apply(p, batch["tokens"]) ** 2)
+
+    return loss_fn, params, {"tokens": toks}
+
+
 class TestTrainers:
+    @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+    def test_a_mamba_model_counts_chunks_and_decay_as_output_values(
+            self, remat):
+        """``ssd_chunks_total`` and ``ssd_log_decay_micro_total`` leave the
+        fused step as values of its counts output, beside the routed
+        block's three whole numbers: once a step, and no host callback."""
+        loss_fn, params, batch = _mamba(remat)
+        before = tracing.program_counters()
+        trainer = FTTrainer(
+            loss_fn=loss_fn, tx=optax.sgd(0.01), params=params,
+            manager_factory=lambda load, save: make_manager(
+                _client([1]), load_state_dict=load, state_dict=save,
+                min_replica_size=1))
+        try:
+            for _ in range(2):
+                _, committed = trainer.train_step(batch)
+                assert committed
+            jax.block_until_ready(trainer.params)
+            metrics = trainer.manager.metrics()
+            args = (trainer.params, None, trainer.opt_state, batch)
+            lowered = trainer._fused.lower(*args).as_text()
+            counts = jax.eval_shape(trainer._fused, *args)[-1]
+        finally:
+            trainer.shutdown()
+        # 2 steps x 2 mamba blocks x 2 sequences x 256 / 128 chunks
+        assert metrics["ssd_chunks_total"] \
+            - before.get("ssd_chunks_total", 0.0) == 2 * 2 * 2 * 2
+        # a fresh layer's step is drawn from 0.001-0.1 and its rate from
+        # -16..-0.001: the mean log decay a token is negative, in millionths
+        decay = metrics["ssd_log_decay_micro_total"] \
+            - before.get("ssd_log_decay_micro_total", 0.0)
+        assert -2 * 2e6 < decay < 0
+        assert metrics["program_callbacks_total"] \
+            == before["program_callbacks_total"]
+        assert "callback" not in lowered
+        assert counts.keys == (
+            tuple(sorted(("moe_pairs_routed_total", "moe_pairs_local_total",
+                          "moe_expert_load_max_total"))),
+            ("ssd_chunks_total", "ssd_log_decay_micro_total"))
+        assert [(v.shape, v.dtype) for v in counts.values] \
+            == [((3,), jnp.int32), ((2,), jnp.float32)]
+
     @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
     def test_a_routed_model_counts_once_a_step_and_holds_no_callback(
             self, remat):

@@ -117,20 +117,29 @@ class TestStepPartition:
     @pytest.mark.parametrize("worlds,overlap,programs", [
         ([1], 0, ["fused"]),
         ([2], 0, ["fwd_bwd"]),
-        ([1, 1, 2], 0, ["fused", "fwd_bwd"]),   # step 3 mispredicts
+        # steps 3, 5 and 7 mispredict (fused dispatched, two groups found)
+        ([1, 1, 2, 1, 2, 1, 2], 0, ["fused", "fwd_bwd"]),
         ([2], 1, ["fwd_bwd"]),
     ], ids=["fused", "split", "mispredicted", "overlap"])
     def test_spans_partition_the_step(self, worlds, overlap, programs):
         trainer = _trainer(worlds, overlap)
         try:
-            steps = [_one_step(trainer, k) for k in range(4)]
+            steps = [_one_step(trainer, k)
+                     for k in range(max(4, len(worlds)))]
         finally:
             trainer.shutdown()
-        # Step 3 is the one whose quorum differs from the prediction; in
-        # the other cases any settled step will do: the one with the least
-        # glue, so that a descheduled thread fails nothing.
+        # Any settled step of the kind will do: the one with the least
+        # glue, so that a descheduled thread fails nothing. Of a
+        # misprediction there are three (until PR 45 step 3 alone was
+        # judged, and under six loaded workers one preemption of a few ms
+        # between two spans failed the cover; it failed in the driver's run
+        # of 7c58aa3): every step whose quorum differs from the prediction,
+        # the third among them.
         if len(worlds) > 1:
-            chosen = [steps[2]]
+            chosen = [st for st in steps
+                      if [s.get("program") for s in st[0]
+                          if s["stage"] == "dispatch"] == programs]
+            assert steps[2] in chosen and len(chosen) == 3
         else:
             chosen = steps[1:]
         for top, _ in steps:

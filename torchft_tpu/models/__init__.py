@@ -16,11 +16,13 @@ from torchft_tpu.models.transformer import (
 )
 from torchft_tpu.models.mla import LatentAttention
 from torchft_tpu.models.linear_attention import GatedDeltaNet
+from torchft_tpu.models.mamba2 import Mamba2Mixer
 
 __all__ = [
     "GatedDeltaNet",
     "LatentAttention",
     "MLP",
+    "Mamba2Mixer",
     "MoEMLP",
     "RoutedMoEMLP",
     "ep_rules",

@@ -24,7 +24,11 @@ holds:
   held experts' last pair are skipped, so time follows the pairs that are
   there (the grouped matmul's grid is sized by the loads at run time; the
   gathers move a whole pass) and memory is one pass's. A shared
-  expert, where there is one, is an ordinary SwiGLU on every token. The
+  expert, where there is one, is an ordinary MLP of the experts' form on
+  every token. An expert's form (``form``) is ``"swiglu"``
+  (``down(silu(gate(u)) * up(u))``, three matrices) or ``"relu2"``
+  (``down(relu(up(u))^2)``, two, no gate): one static field that the layer,
+  the pass loops, their hand-written backward and the shared expert read. The
   parts that the shares of one layer give add up to the whole layer with
   the shared expert counted once (``tests/test_moe_routed.py``). This is the
   layer expert parallelism needs on each device; the exchange that would
@@ -143,16 +147,21 @@ def _megablox() -> Any:
 
 def _tile(dim: int, cap: int) -> int:
     """A tile for a dimension the kernels may tile unevenly: the whole of a
-    short one, else the largest power-of-two fraction of ``cap`` (>= 128)
-    that divides it, else 128 (the kernels mask the remainder)."""
+    short one, else the largest power-of-two fraction of ``cap`` (>= 256)
+    that divides it; else, for a width that has no such divisor (2688 = 21 x
+    128, 1856 = 14.5 x 128), the multiple of 128 up to ``cap`` that pads the
+    dimension least, the largest of those (896 and 640 under a cap of 1024:
+    the kernels mask the remainder). A tile of 128 there made a grid of
+    thousands of steps of microseconds each (PERF.md, PR 45)."""
     if dim <= cap:
         return dim
     t = cap
-    while t >= 128:
+    while t > 128:
         if dim % t == 0:
             return t
         t //= 2
-    return 128
+    return min(range(128, cap + 1, 128),
+               key=lambda t: (-(-dim // t) * t - dim, -t))
 
 
 ROW_TILE = 512   # rows of sorted pairs a grid step of the products takes
@@ -304,14 +313,19 @@ def _combine_bwd(res, g):
 combine_rows.defvjp(_combine_fwd, _combine_bwd)
 
 
-def _one_pass(uc, w, wi_gate, wi_up, wo, idx, start, rows_a_pass: int,
-              interpret: bool) -> jnp.ndarray:
+FORMS = {"swiglu": ("wi_gate", "wi_up", "wo"), "relu2": ("wi_up", "wo")}
+#         an expert's matrices by its form, in the order they are held
+
+
+def _one_pass(uc, w, weights, idx, start, rows_a_pass: int,
+              interpret: bool, form: str) -> jnp.ndarray:
     """The slots ``start .. start + rows_a_pass`` through the held experts:
-    float32 [T, D], their part of every token's output. ``idx`` is the
-    routing's integer side: ``(src [M], pos [T, K], w_slot [M], ends
-    [count + 1], jmax)``."""
+    float32 [T, D], their part of every token's output. ``weights`` are the
+    experts' stacks in ``FORMS[form]``'s order; ``idx`` is the routing's
+    integer side: ``(src [M], pos [T, K], w_slot [M], ends [count + 1],
+    jmax)``."""
     src, pos, w_slot, ends, jmax = idx
-    count = wi_gate.shape[0]
+    count = weights[0].shape[0]
     with jax.named_scope("moe_dispatch"):
         src_p = jax.lax.dynamic_slice(src, (start,), (rows_a_pass,))
         w_p = jax.lax.dynamic_slice(w_slot, (start,), (rows_a_pass,))
@@ -322,64 +336,71 @@ def _one_pass(uc, w, wi_gate, wi_up, wo, idx, start, rows_a_pass: int,
         sizes_p = sizes_p.at[count].set(rows_a_pass - upto[count - 1])
         rows = dispatch_rows(uc, src_p, pos, start, jmax)
     with jax.named_scope("moe_experts"):
-        gate = grouped_matmul(rows, wi_gate, sizes_p, interpret)
-        up = grouped_matmul(rows, wi_up, sizes_p, interpret)
-        y = grouped_matmul(nn.silu(gate) * up, wo, sizes_p, interpret)
+        if form == "swiglu":
+            wi_gate, wi_up, wo = weights
+            gate = grouped_matmul(rows, wi_gate, sizes_p, interpret)
+            up = grouped_matmul(rows, wi_up, sizes_p, interpret)
+            y = grouped_matmul(nn.silu(gate) * up, wo, sizes_p, interpret)
+        else:
+            wi_up, wo = weights
+            up = grouped_matmul(rows, wi_up, sizes_p, interpret)
+            y = grouped_matmul(jnp.square(nn.relu(up)), wo, sizes_p,
+                               interpret)
     with jax.named_scope("moe_combine"):
         return combine_rows(y, w, pos, src_p, w_p, start, jmax)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
-def experts_over_passes(uc, w, wi_gate, wi_up, wo, idx, n_local,
-                        rows_a_pass: int, interpret: bool) -> jnp.ndarray:
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def experts_over_passes(uc, w, weights, idx, n_local, rows_a_pass: int,
+                        interpret: bool, form: str) -> jnp.ndarray:
     """Every pass that holds a pair of a held expert, one after the other:
     float32 [T, D]. The passes are a ``while_loop`` whose length is the
     routing's (``n_local`` pairs on held experts fill the first slots), which
     reverse-mode differentiation cannot unroll: the backward is written here,
     a second loop over the same passes that recomputes each and adds its
     gradients up, so memory is one pass's in both directions."""
-    return _passes_fwd(uc, w, wi_gate, wi_up, wo, idx, n_local, rows_a_pass,
-                       interpret)[0]
+    return _passes_fwd(uc, w, weights, idx, n_local, rows_a_pass, interpret,
+                       form)[0]
 
 
-def _passes_fwd(uc, w, wi_gate, wi_up, wo, idx, n_local, rows_a_pass,
-                interpret):
+def _passes_fwd(uc, w, weights, idx, n_local, rows_a_pass, interpret, form):
     def body(c):
         start, out = c
         return start + rows_a_pass, out + _one_pass(
-            uc, w, wi_gate, wi_up, wo, idx, start, rows_a_pass, interpret)
+            uc, w, weights, idx, start, rows_a_pass, interpret, form)
 
     _, out = jax.lax.while_loop(
         lambda c: c[0] < n_local, body,
         (jnp.int32(0), jnp.zeros(uc.shape, jnp.float32)))
-    return out, (uc, w, wi_gate, wi_up, wo, idx, n_local)
+    return out, (uc, w, weights, idx, n_local)
 
 
-def _passes_bwd(rows_a_pass, interpret, res, g):
-    uc, w, wi_gate, wi_up, wo, idx, n_local = res
-    diff = (uc, w, wi_gate, wi_up, wo)
+def _passes_bwd(rows_a_pass, interpret, form, res, g):
+    uc, w, weights, idx, n_local = res
+    diff = (uc, w, tuple(weights))
 
     def body(c):
         start, acc = c
         _, vjp = jax.vjp(
-            lambda *a: _one_pass(*a, idx, start, rows_a_pass, interpret),
-            *diff)
-        return start + rows_a_pass, tuple(
-            a + d.astype(a.dtype) for a, d in zip(acc, vjp(g)))
+            lambda *a: _one_pass(*a, idx, start, rows_a_pass, interpret,
+                                 form), *diff)
+        return start + rows_a_pass, jax.tree_util.tree_map(
+            lambda a, d: a + d.astype(a.dtype), acc, vjp(g))
 
-    zeros = tuple(jnp.zeros(x.shape, jnp.float32) for x in diff)
+    zeros = jax.tree_util.tree_map(
+        lambda x: jnp.zeros(x.shape, jnp.float32), diff)
     _, grads = jax.lax.while_loop(lambda c: c[0] < n_local, body,
                                   (jnp.int32(0), zeros))
-    return tuple(d.astype(x.dtype) for d, x in zip(grads, diff)) \
-        + (None, None)
+    return jax.tree_util.tree_map(lambda d, x: d.astype(x.dtype), grads,
+                                  diff) + (None, None)
 
 
 experts_over_passes.defvjp(_passes_fwd, _passes_bwd)
 
 
 class RoutedMoEMLP(nn.Module):
-    """Routed SwiGLU experts over a share of them (see the module
-    docstring). Input [B, S, D] -> [B, S, D].
+    """Routed experts over a share of them (see the module docstring).
+    Input [B, S, D] -> [B, S, D].
 
     Attributes:
         num_experts: the router's width: every expert of the layer, held
@@ -392,6 +413,8 @@ class RoutedMoEMLP(nn.Module):
         shared_gate: the shared expert's output times ``sigmoid(u . w_s)``,
             ``w_s`` one column of its own (``shared_gate/kernel`` [D, 1]).
         score / route_norm / route_scale: the router (:func:`route`).
+        form: ``"swiglu"`` (stacks ``wi_gate``, ``wi_up``, ``wo``) or
+            ``"relu2"`` (``wi_up``, ``wo``), the shared expert's too.
         pass_rows: sorted pairs taken through the experts at a time.
         interpret: run the Pallas grouped matmul interpreted (``None``: off
             a TPU).
@@ -434,6 +457,7 @@ class RoutedMoEMLP(nn.Module):
     score: str = "sigmoid"
     route_norm: bool = True
     route_scale: float = 1.0
+    form: str = "swiglu"
     dtype: Any = jnp.bfloat16
     pass_rows: int = 8192
     interpret: Optional[bool] = None
@@ -453,6 +477,9 @@ class RoutedMoEMLP(nn.Module):
         if not (0 <= first and count >= 0 and first + count <= e):
             raise ValueError(f"held {self.held} is not a range of the "
                              f"{e} experts")
+        if self.form not in FORMS:
+            raise ValueError(f"unknown expert form {self.form!r}; "
+                             f"one of {sorted(FORMS)}")
         interpret = (jax.default_backend() != "tpu"
                      if self.interpret is None else bool(self.interpret))
         t = b * s
@@ -460,7 +487,7 @@ class RoutedMoEMLP(nn.Module):
 
         shared = None
         if self.shared_dim:
-            shared = _SharedExpert(self.shared_dim, self.dtype,
+            shared = _SharedExpert(self.shared_dim, self.dtype, self.form,
                                    name="shared")(x)
             if self.shared_gate:
                 gate = nn.Dense(1, use_bias=False, dtype=self.dtype,
@@ -473,9 +500,10 @@ class RoutedMoEMLP(nn.Module):
                           precision=jax.lax.Precision.HIGHEST, name="router")
         init = nn.initializers.lecun_normal()
         if count:
-            weights = (self.param("wi_gate", init, (count, d, h)),
-                       self.param("wi_up", init, (count, d, h)),
-                       self.param("wo", init, (count, h, d)))
+            weights = tuple(
+                self.param(name, init,
+                           (count, h, d) if name == "wo" else (count, d, h))
+                for name in FORMS[self.form])
 
         with jax.named_scope("moe_route"):
             logits = router(u.astype(jnp.float32))            # [T, E]
@@ -523,8 +551,9 @@ class RoutedMoEMLP(nn.Module):
             return out.reshape(b, s, d), stats
 
         out = experts_over_passes(
-            u.astype(self.dtype), w, *weights,
-            (src, pos, w_slot, ends, jmax), n_local, rows_a_pass, interpret)
+            u.astype(self.dtype), w, weights,
+            (src, pos, w_slot, ends, jmax), n_local, rows_a_pass, interpret,
+            self.form)
 
         with jax.named_scope("moe_combine"):
             out = out.astype(x.dtype)
@@ -545,19 +574,26 @@ def _slots(pairs: int, pass_rows: int) -> Tuple[int, int]:
 
 
 class _SharedExpert(nn.Module):
-    """The expert every token passes through: a SwiGLU of its own width."""
+    """The expert every token passes through: an MLP of the experts'
+    ``form`` and a width of its own."""
 
     mlp_dim: int
     dtype: Any = jnp.bfloat16
+    form: str = "swiglu"
 
     @nn.compact
     def __call__(self, x):
-        gate = nn.Dense(self.mlp_dim, use_bias=False, dtype=self.dtype,
-                        name="gate")(x)
-        up = nn.Dense(self.mlp_dim, use_bias=False, dtype=self.dtype,
-                      name="up")(x)
-        return nn.Dense(x.shape[-1], use_bias=False, dtype=self.dtype,
-                        name="down")(nn.silu(gate) * up)
+        def dense(width, name):
+            return nn.Dense(width, use_bias=False, dtype=self.dtype,
+                            name=name)
+
+        if self.form == "swiglu":
+            gate = dense(self.mlp_dim, "gate")(x)
+            up = dense(self.mlp_dim, "up")(x)
+            act = nn.silu(gate) * up
+        else:
+            act = jnp.square(nn.relu(dense(self.mlp_dim, "up")(x)))
+        return dense(x.shape[-1], "down")(act)
 
 
 MOE_COUNTERS = ("moe_pairs_routed_total", "moe_pairs_local_total",

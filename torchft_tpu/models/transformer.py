@@ -54,15 +54,21 @@ class TransformerConfig:
     moe_route_norm: bool = True
     moe_route_scale: float = 1.0
     moe_shared_gate: bool = False        # sigmoid(u . w) on the shared expert
+    moe_form: str = "swiglu"             # an expert's form, routed only:
+    #                                      "swiglu" | "relu2" (models/moe.py)
     moe_dense_layers: int = 0            # leading layers that keep a dense MLP
     moe_interpret: Optional[bool] = None  # routed: Pallas interpreted (None:
     #                                       off a TPU)
     # Layers of different kinds. ``layer_types[i]`` is "full_attention",
     # "sliding_attention" (key j visible to query i iff 0 <= i - j <
     # ``sliding_window``) or "linear_attention" (a Gated DeltaNet mixer,
-    # models/linear_attention.py, at the ``linear_*`` sizes below); None =
-    # every layer full. ``rope_full_layers=False`` leaves rotary off the
-    # full-attention layers; ``rotary_dim`` turns only the leading
+    # models/linear_attention.py, at the ``linear_*`` sizes below): a mixer
+    # and an MLP, two norms; or one of the blocks that are ONE norm and ONE
+    # sub-block with the residual around it: "mamba" (a Mamba-2 mixer,
+    # models/mamba2.py, at the ``ssm_*`` sizes and ``linear_conv_kernel``),
+    # "moe" (the expert MLP alone) and "attention" (full attention alone).
+    # None = every layer full. ``rope_full_layers=False`` leaves rotary off
+    # the full-attention layers; ``rotary_dim`` turns only the leading
     # ``rotary_dim`` dims of a head (None: the whole head).
     layer_types: Optional[Tuple[str, ...]] = None
     sliding_window: Optional[int] = None
@@ -73,6 +79,10 @@ class TransformerConfig:
     linear_value_heads: int = 0
     linear_value_dim: int = 0
     linear_conv_kernel: int = 4
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 0
+    ssm_state: int = 0
     # The attention block's options, all off in the Llama recipe: a stated
     # head size (None → embed_dim / num_heads), RMSNorm of queries and keys
     # per head, a sigmoid gate on the attention output, a second norm on
@@ -119,6 +129,8 @@ class TransformerConfig:
         return self.layer_types[i] if self.layer_types else FULL
 
     def is_moe_layer(self, i: int) -> bool:
+        if self.layer_type(i) in SINGLE:
+            return self.layer_type(i) == EXPERTS
         return self.moe_experts > 0 and i >= self.moe_dense_layers
 
     @property
@@ -131,6 +143,9 @@ class TransformerConfig:
 
 FULL, SLIDING = "full_attention", "sliding_attention"
 LINEAR = "linear_attention"
+# blocks of one norm and one sub-block
+MAMBA, EXPERTS, ATTENTION = "mamba", "moe", "attention"
+SINGLE = (MAMBA, EXPERTS, ATTENTION)
 
 
 def _norm(cfg: "TransformerConfig", name: str) -> "RMSNorm":
@@ -289,13 +304,74 @@ class MLPBlock(nn.Module):
                         name="down")(nn.silu(gate) * up)
 
 
+def _mixer(cfg: TransformerConfig, kind: str, h, positions, counts: dict):
+    """The mixer of ``kind`` over the normed stream ``h``, named ``attn``;
+    what it has for the program counters goes into ``counts``."""
+    if kind in (LINEAR, MAMBA):
+        if kind == LINEAR:
+            from torchft_tpu.models.linear_attention import (
+                GDN_COUNTERS as names, GatedDeltaNet as scanned)
+        else:
+            from torchft_tpu.models.mamba2 import (
+                SSD_COUNTERS as names, Mamba2Mixer as scanned)
+        a, (chunks, log_decay) = scanned(cfg, name="attn")(
+            h, return_stats=True)
+        # the step's mean over its layers of this kind, in millionths
+        share = 1e6 / sum(t == kind for t in cfg.layer_types)
+        counts.update(zip(names, (chunks, log_decay * share)))
+        return a
+    if cfg.kv_lora_rank:
+        from torchft_tpu.models.mla import LatentAttention
+
+        return LatentAttention(cfg, name="attn")(h, positions)
+    return Attention(cfg, kind=FULL if kind == ATTENTION else kind,
+                     name="attn")(h, positions)
+
+
+def _mlp(cfg: TransformerConfig, moe: bool, u, counts: dict):
+    """The dense MLP (``mlp``) or, with ``moe``, the expert layer (``moe``)
+    over the normed stream ``u``; a routed layer's counts go into
+    ``counts``."""
+    if moe and cfg.moe_dispatch == "routed":
+        from torchft_tpu.models.moe import MOE_COUNTERS, RoutedMoEMLP
+
+        # The counts leave the (rematerialised) layer as values:
+        # Transformer counts them once a step.
+        m, stats = RoutedMoEMLP(
+            num_experts=cfg.moe_experts, top_k=cfg.moe_top_k,
+            mlp_dim=cfg.moe_dim or cfg.mlp_dim, held=cfg.moe_held,
+            shared_dim=cfg.moe_shared_dim,
+            shared_gate=cfg.moe_shared_gate, score=cfg.moe_score,
+            route_norm=cfg.moe_route_norm,
+            route_scale=cfg.moe_route_scale, form=cfg.moe_form,
+            dtype=cfg.dtype, interpret=cfg.moe_interpret, name="moe")(
+                u, return_stats=True)
+        counts.update(zip(MOE_COUNTERS, stats))
+        return m
+    if cfg.moe_form != "swiglu":
+        raise ValueError(f"moe_form {cfg.moe_form!r} is the routed expert "
+                         "layer's (moe_dispatch='routed' on a layer with "
+                         "experts); every other MLP here is a SwiGLU")
+    if moe and cfg.moe_dispatch == "dense":
+        from torchft_tpu.models.moe import MoEMLP
+
+        return MoEMLP(num_experts=cfg.moe_experts,
+                      mlp_dim=cfg.moe_dim or cfg.mlp_dim,
+                      top_k=cfg.moe_top_k, dtype=cfg.dtype, name="moe")(u)
+    if moe:
+        raise ValueError(f"unknown moe_dispatch {cfg.moe_dispatch!r}")
+    return MLPBlock(cfg, name="mlp")(u)
+
+
 class DecoderLayer(nn.Module):
-    """One layer: a mixer of ``kind`` (attention, or the Gated DeltaNet of
-    ``"linear_attention"``) and a dense or (``moe``) an expert MLP,
+    """One layer. Of most kinds: a mixer (attention, or the Gated DeltaNet
+    of ``"linear_attention"``) and a dense or (``moe``) an expert MLP,
     pre-norm; with ``cfg.sandwich_norm`` each sub-block's output is normed
-    again before it joins the stream. Returns the stream, and where the
+    again before it joins the stream. Of a kind in ``SINGLE``: one norm
+    (``norm``) and one sub-block, ``x + F(norm(x))``, ``F`` a Mamba-2 mixer,
+    the expert MLP or full attention. Returns the stream, and where the
     layer has numbers for the program counters (a routed expert layer, a
-    linear-attention mixer) ``(stream, {counter: value})``."""
+    linear-attention or mamba mixer) ``(stream, {counter: value})``."""
 
     cfg: TransformerConfig
     kind: str = FULL
@@ -305,58 +381,21 @@ class DecoderLayer(nn.Module):
     def __call__(self, x, positions):
         cfg = self.cfg
         counts = {}
-        h = _norm(cfg, "attn_norm")(x)
-        if self.kind == LINEAR:
-            from torchft_tpu.models.linear_attention import (GDN_COUNTERS,
-                                                             GatedDeltaNet)
-
-            a, (chunks, log_decay) = GatedDeltaNet(cfg, name="attn")(
-                h, return_stats=True)
-            # the step's mean over its linear layers, in millionths
-            share = 1e6 / sum(t == LINEAR for t in cfg.layer_types)
-            counts.update(zip(GDN_COUNTERS, (chunks, log_decay * share)))
-        elif cfg.kv_lora_rank:
-            from torchft_tpu.models.mla import LatentAttention
-
-            a = LatentAttention(cfg, name="attn")(h, positions)
-        else:
-            a = Attention(cfg, kind=self.kind, name="attn")(h, positions)
+        if self.kind in SINGLE:
+            u = _norm(cfg, "norm")(x)
+            if self.kind == EXPERTS:
+                if not cfg.moe_experts:
+                    raise ValueError('a "moe" layer needs moe_experts')
+                x = x + _mlp(cfg, True, u, counts)
+            else:
+                x = x + _mixer(cfg, self.kind, u, positions, counts)
+            return (x, counts) if counts else x
+        a = _mixer(cfg, self.kind, _norm(cfg, "attn_norm")(x), positions,
+                   counts)
         if cfg.sandwich_norm:
             a = _norm(cfg, "post_attn_norm")(a)
         x = x + a
-        routed = self.moe and cfg.moe_dispatch == "routed"
-        if routed:
-            from torchft_tpu.models.moe import RoutedMoEMLP
-
-            mlp = RoutedMoEMLP(
-                num_experts=cfg.moe_experts, top_k=cfg.moe_top_k,
-                mlp_dim=cfg.moe_dim or cfg.mlp_dim, held=cfg.moe_held,
-                shared_dim=cfg.moe_shared_dim,
-                shared_gate=cfg.moe_shared_gate, score=cfg.moe_score,
-                route_norm=cfg.moe_route_norm,
-                route_scale=cfg.moe_route_scale, dtype=cfg.dtype,
-                interpret=cfg.moe_interpret, name="moe")
-        elif self.moe and cfg.moe_dispatch == "dense":
-            from torchft_tpu.models.moe import MoEMLP
-
-            mlp = MoEMLP(num_experts=cfg.moe_experts,
-                         mlp_dim=cfg.moe_dim or cfg.mlp_dim,
-                         top_k=cfg.moe_top_k,
-                         dtype=cfg.dtype, name="moe")
-        elif self.moe:
-            raise ValueError(f"unknown moe_dispatch {cfg.moe_dispatch!r}")
-        else:
-            mlp = MLPBlock(cfg, name="mlp")
-        u = _norm(cfg, "mlp_norm")(x)
-        # The counts leave the (rematerialised) layer as values:
-        # Transformer counts them once a step.
-        if routed:
-            from torchft_tpu.models.moe import MOE_COUNTERS
-
-            m, stats = mlp(u, return_stats=True)
-            counts.update(zip(MOE_COUNTERS, stats))
-        else:
-            m = mlp(u)
+        m = _mlp(cfg, self.moe, _norm(cfg, "mlp_norm")(x), counts)
         if cfg.sandwich_norm:
             m = _norm(cfg, "post_mlp_norm")(m)
         return (x + m, counts) if counts else x + m
@@ -516,6 +555,10 @@ def tp_rules() -> list:
         (r"attn/[qkv]/kernel", P(None, "tp", None)),
         (r"attn/gate/kernel", P(None, "tp")),
         (r"attn/o/kernel", P("tp", None)),
+        # a mamba mixer: the fused input projection's columns and the
+        # output projection's rows (XLA moves what the scan needs)
+        (r"attn/in_proj/kernel", P(None, "tp")),
+        (r"attn/out_proj/kernel", P("tp", None)),
         (r"mlp/(gate|up)/kernel", P(None, "tp")),
         (r"mlp/down/kernel", P("tp", None)),
         (r"embed/embedding", P(None, "tp")),

@@ -8,7 +8,8 @@ from torchft_tpu.ops.gated_delta import (
     gated_delta_recurrent,
     gated_delta_rule,
 )
+from torchft_tpu.ops.ssd import ssd_recurrent, ssd_scan
 
 __all__ = ["causal_conv1d", "flash_attention", "flash_attention_block",
            "gated_delta_recurrent", "gated_delta_rule",
-           "sharded_flash_attention"]
+           "sharded_flash_attention", "ssd_recurrent", "ssd_scan"]

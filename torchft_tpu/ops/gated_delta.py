@@ -35,7 +35,7 @@ tokens that neither decay nor write (``g = 0``, ``beta = 0``).
 
 from __future__ import annotations
 
-from typing import Any
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -44,17 +44,21 @@ CHUNK = 64   # tokens a chunk: the [C, C] inverse stays small, the products
 #              between chunks stay MXU-sized
 
 
-def causal_conv1d(x: jnp.ndarray, weight: jnp.ndarray) -> jnp.ndarray:
+def causal_conv1d(x: jnp.ndarray, weight: jnp.ndarray,
+                  bias: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Causal depthwise convolution along the sequence. ``x`` [B, T, Ch],
-    ``weight`` [K, Ch]: ``y_t = sum_j weight[j] * x_{t-K+1+j}`` with zeros
-    left of the sequence and no bias. Float32 inside, ``x``'s type out. K
-    shifted copies in one fused pass: at K = 4 a convolution primitive has
-    nothing to add."""
+    ``weight`` [K, Ch], ``bias`` [Ch] or none (the delta rule's has none,
+    the state-space mixer's has one): ``y_t = sum_j weight[j] * x_{t-K+1+j}
+    + bias`` with zeros left of the sequence. Float32 inside, ``x``'s type
+    out. K shifted copies in one fused pass: at K = 4 a convolution
+    primitive has nothing to add."""
     k = weight.shape[0]
     t = x.shape[1]
     padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
     w = weight.astype(jnp.float32)
     y = sum(w[j] * padded[:, j:j + t] for j in range(k))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     return y.astype(x.dtype)
 
 
