@@ -492,6 +492,65 @@ class TestHealUnderChaos:
             srv.shutdown()
 
 
+class TestHealOverlapUnderChaos:
+    """The heal's stages run beside each other (docs/design/healing.md),
+    shown on a stream that chaos slows and a placement that is slow, not
+    with a timing threshold: every read of the body waits 30 ms and
+    every placement 40 ms, so one after the other they would take their
+    sum, and the stage clocks add up to more than the transfer's wall."""
+
+    def test_stage_clocks_sum_to_more_than_the_wall(self, monkeypatch):
+        from torchft_tpu import checkpointing
+        from torchft_tpu.checkpointing import CheckpointServer
+
+        rng = np.random.RandomState(1)
+        state = {f"w{i:02d}": rng.rand(1 << 18).astype(np.float32)
+                 for i in range(12)}
+
+        class Slow(ChaosSchedule):
+            def config_for(self, endpoint):
+                return EndpointChaos()
+
+            def decide(self, endpoint, op):
+                return Decision(endpoint=endpoint, op=op, n=0,
+                                delay_ms=30.0 if op == "read" else 0.0,
+                                fault=None, phase="pre", frac=0.5,
+                                blackhole_ms=0.0)
+
+        real_put = checkpointing.device_put_like
+
+        def slow_put(arr, tleaf, **kw):
+            time.sleep(0.04)
+            return real_put(arr, tleaf, **kw)
+
+        monkeypatch.setattr(checkpointing, "device_put_like", slow_put)
+        srv = CheckpointServer(lambda: state, bind_host="127.0.0.1")
+        srv.allow_checkpoint(1)
+        chaos.install(Slow(seed=0, endpoints={}))
+        try:
+            import jax.numpy as jnp
+
+            target = {k: jnp.zeros(v.shape, v.dtype)
+                      for k, v in state.items()}
+            stats = {}
+            t0 = time.perf_counter()
+            out = CheckpointServer.load_from_address(
+                srv.address(), target, stats=stats)
+            wall_ms = (time.perf_counter() - t0) * 1e3
+            for k, v in state.items():
+                np.testing.assert_array_equal(np.asarray(out[k]), v)
+            assert stats["recv_ms"] >= 12 * 30
+            assert stats["place_ms"] >= 12 * 40
+            assert stats["verify_ms"] > 0 and stats["manifest_ms"] > 0
+            stream_ms = wall_ms - stats["manifest_ms"]
+            busy = (stats["recv_ms"] + stats["verify_ms"]
+                    + stats["place_ms"])
+            assert busy > stream_ms, (busy, stream_ms, stats)
+        finally:
+            chaos.uninstall()
+            srv.shutdown()
+
+
 class TestDonorKill:
     """The donor-kill fault family: a killed endpoint hangs up its
     in-flight stream and refuses every later dial — the way a dead donor

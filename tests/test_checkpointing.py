@@ -707,9 +707,9 @@ class TestResumableHeal:
         placed = []
         real_put = checkpointing.device_put_like
 
-        def recording_put(arr, tleaf):
+        def recording_put(arr, tleaf, **kw):
             placed.append(arr.copy())
-            return real_put(arr, tleaf)
+            return real_put(arr, tleaf, **kw)
 
         monkeypatch.setattr(checkpointing, "device_put_like",
                             recording_put)
@@ -882,5 +882,300 @@ class TestResumableHeal:
                             max_attempts=8, base_delay_ms=1.0,
                             jitter=0.0),
                         stall_timeout_sec=10)
+        finally:
+            server.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# The heal transfer as one overlapped pass (docs/design/healing.md): the
+# fetch engine cuts wide leaves into runs of rows and fetches one batch
+# ahead; the healer reads, verifies and places beside each other.
+
+MIB = 1 << 20
+
+
+def _wide_state(wide_mib: int = 56, small: int = 24) -> dict:
+    """One leaf several fetch batches wide (and not a whole number of
+    them) among many small ones, on the device."""
+    rng = np.random.RandomState(46)
+    rows = wide_mib * MIB // (1031 * 4)
+    state = {"a_small": [jnp.asarray(rng.rand(257, 33).astype(np.float32))
+                         for _ in range(small // 2)],
+             "m_wide": jnp.asarray(rng.rand(rows, 1031).astype(np.float32)),
+             "z_small": [jnp.asarray(rng.rand(1000).astype(np.float32)
+                                     ).astype(jnp.bfloat16)
+                         for _ in range(small // 2)],
+             "step": 3}
+    return state
+
+
+def _reference_stream(tree) -> bytes:
+    """The serialized stream spelled plainly: preamble, then every array
+    leaf's bytes whole."""
+    preamble, _, leaves = plan_pytree(tree)
+    return preamble + b"".join(np.asarray(leaf).tobytes() for leaf in leaves)
+
+
+class TestFetchEngine:
+    TREE = {"w": jnp.arange(7 * 300 * 5, dtype=jnp.float32
+                            ).reshape(7, 300, 5),
+            "e": jnp.zeros((0, 4), jnp.float32),
+            "v": np.arange(5000, dtype=np.int64),
+            "b": jnp.ones((33,), jnp.bfloat16),
+            "s": np.float32(2.5), "tag": "t"}
+
+    @pytest.mark.parametrize("batch_bytes", [64, 1000, 6000, 1 << 20])
+    def test_sliced_stream_is_byte_for_byte_the_plain_one(
+            self, batch_bytes):
+        got = b"".join(iter_pytree_chunks(self.TREE, chunk_bytes=777,
+                                          batch_bytes=batch_bytes))
+        assert got == _reference_stream(self.TREE)
+        assert got == save_pytree(self.TREE)
+
+    @pytest.mark.parametrize("lo,hi", [
+        (0, None), (5, 9), (0, 40), (300, 301), (1234, 30001),
+        (40000, None), (10 ** 9, None), (77, 77)])
+    def test_ranges_of_a_sliced_stream(self, lo, hi):
+        ref = _reference_stream(self.TREE)
+        got = b"".join(iter_pytree_chunks(
+            self.TREE, chunk_bytes=512, batch_bytes=1000, start=lo,
+            end=hi))
+        assert got == ref[lo:hi]
+
+    def test_digests_of_sliced_leaves_are_the_whole_leaf_crc32(self):
+        import zlib
+
+        plan = plan_pytree(self.TREE)
+        want = [zlib.crc32(np.asarray(leaf).tobytes())
+                for leaf in plan.array_leaves]
+        assert plan.digests(batch_bytes=1000) == want
+        assert plan_pytree(self.TREE).digests() == want
+
+    def test_one_batch_ahead_and_no_more(self, monkeypatch):
+        """Host bytes in flight on the donor: the batch the consumer
+        holds and the one fetched ahead, 2 x batch_bytes, counted in
+        fetches (not RSS); no fetch is wider than a batch."""
+        from torchft_tpu import serialization
+
+        fetched = []
+        real = serialization._fetch_unit
+
+        def counting(leaves, unit, clock):
+            out = real(leaves, unit, clock)
+            fetched.append(sum(len(mv) for _, _, mv in out))
+            return out
+
+        monkeypatch.setattr(serialization, "_fetch_unit", counting)
+        leaves = plan_pytree(self.TREE).array_leaves
+        units = serialization._fetch_units(leaves, 1000)
+        assert len(units) > 20
+        first_piece = {(u[0][0], u[0][1]): k for k, u in enumerate(units)}
+        seen = 0
+        for i, off, mv in serialization._iter_leaf_views(leaves, 1000):
+            k = first_piece.get((i, off))
+            if k is not None:
+                # unit k in hand: nothing beyond unit k + 1 was fetched
+                assert len(fetched) <= k + 2
+                seen += 1
+        assert seen == len(units) == len(fetched)
+        assert max(fetched) <= 1000
+
+    def test_a_tree_of_one_batch_starts_no_thread(self):
+        import threading as _threading
+
+        before = {t.ident for t in _threading.enumerate()}
+        save_pytree({"w": np.ones(100, np.float32), "k": 1})
+        names = [t.name for t in _threading.enumerate()
+                 if t.ident not in before]
+        assert not [n for n in names if n.startswith("tft-fetch")]
+
+
+class TestStagingPool:
+    def test_a_returned_buffer_is_lent_again_to_its_size(self):
+        pool = checkpointing._StagingPool(100)
+        a = pool.take(40)
+        pool.give(a, True)
+        assert pool.take(40) is a
+        b = pool.take(40)
+        assert b is not a and pool.peak == 80
+
+    def test_take_waits_for_the_bound_and_sheds_idle_sizes(self):
+        pool = checkpointing._StagingPool(100)
+        a, b = pool.take(40), pool.take(40)
+        got = []
+        t = threading.Thread(target=lambda: got.append(pool.take(60)))
+        t.start()
+        time.sleep(0.1)
+        assert not got                      # 80 lent: 60 more is over
+        pool.give(a, True)                  # idle 40 is shed for the 60
+        t.join(timeout=10)
+        assert len(got) == 1 and len(got[0]) == 60
+        assert pool.peak <= 100
+        pool.give(b, False)                 # kept by its leaf: uncounted
+        assert len(pool.take(40)) == 40 and pool.peak <= 100
+
+    def test_a_warmed_buffer_is_the_one_taken(self):
+        pool = checkpointing._StagingPool(1 << 20)
+        pool.warm(300_000)
+        buf = pool.take(300_000)            # waits for the warm-up
+        assert len(buf) == 300_000 and pool.peak == 300_000
+        assert not buf[::4096].any()
+
+    def test_a_session_bounds_its_pool_by_its_widest_leaf(self):
+        from torchft_tpu.serialization import DEFAULT_BATCH_BYTES
+
+        small = checkpointing._HealSession({"w": np.ones(10)}, None)
+        assert small.staging.bound == 2 * DEFAULT_BATCH_BYTES
+        wide = checkpointing._HealSession(
+            {"w": np.ones(10), "e": jnp.zeros((5000, 2048)), "k": 1}, None)
+        assert wide.widest == 5000 * 2048 * 4
+        assert wide.staging.bound == 2 * wide.widest
+
+
+@pytest.fixture
+def staging_pools(monkeypatch):
+    """Every staging pool a heal of the test makes, for its counts."""
+    pools = []
+    real_pool = checkpointing._StagingPool
+
+    def recording_pool(bound):
+        pools.append(real_pool(bound))
+        return pools[-1]
+
+    monkeypatch.setattr(checkpointing, "_StagingPool", recording_pool)
+    return pools
+
+
+class TestOverlappedHeal:
+    @pytest.mark.parametrize("device_put", [True, False])
+    def test_wide_leaf_and_many_small_heal_bitwise(self, device_put,
+                                                   staging_pools):
+        """A leaf several batches wide among many small ones arrives
+        bitwise, the stage clocks of both sides are filled, and the
+        staging buffers stayed under their stated bound."""
+        from torchft_tpu.serialization import DEFAULT_BATCH_BYTES
+
+        state = _wide_state()
+        wide = state["m_wide"].nbytes
+        assert wide > 2 * DEFAULT_BATCH_BYTES
+        server = CheckpointServer(lambda: state)
+        try:
+            server.allow_checkpoint(1)
+            stats = {}
+            seen = []
+            restored = CheckpointServer.load_from_address(
+                server.address(), state, device_put=device_put,
+                stats=stats, progress_cb=lambda b, t: seen.append(b))
+            import jax
+            for got, want in zip(jax.tree_util.tree_leaves(restored),
+                                 jax.tree_util.tree_leaves(state)):
+                assert np.asarray(got).tobytes() == \
+                    np.asarray(want).tobytes()
+            assert restored["step"] == 3
+            for stage in ("manifest", "recv", "verify", "place"):
+                assert stats[f"{stage}_ms"] > 0, stage
+            donor = server.metrics()
+            assert donor["heal_serve_fetch_ms_total"] > 0
+            assert donor["heal_serve_send_ms_total"] > 0
+            assert seen == sorted(seen) and seen[-1] == \
+                stats["payload_bytes"] - _fetch_manifest(
+                    server.address())["preamble_len"]
+            (pool,) = staging_pools
+            assert pool.bound == 2 * wide
+            assert 0 < pool.peak <= pool.bound
+        finally:
+            server.shutdown()
+
+    def test_corrupt_chunk_mid_wide_leaf_places_nothing_of_it(
+            self, monkeypatch):
+        """A flipped byte in a middle chunk of a leaf many chunks long:
+        the running digest fails with the leaf's last byte, the leaf
+        stays missing with nothing of it placed, the other leaves of the
+        round are kept, and the refetch commits it."""
+        state = _wide_state(wide_mib=28, small=8)
+        placed = []
+        real_put = checkpointing.device_put_like
+
+        def recording_put(arr, tleaf, **kw):
+            placed.append((arr.shape, arr.tobytes()))
+            return real_put(arr, tleaf, **kw)
+
+        monkeypatch.setattr(checkpointing, "device_put_like",
+                            recording_put)
+        server = CheckpointServer(lambda: state)
+        proxy = None
+        try:
+            server.allow_checkpoint(1)
+            mf = _fetch_manifest(server.address())
+            entry = next(e for e in mf["leaves"] if e["key"] == "m_wide")
+            proxy = _FlakyProxy(
+                server.address(), mode="flip",
+                flip_at=entry["offset"] + entry["nbytes"] // 2)
+            stats = {}
+            restored = CheckpointServer.load_from_address(
+                proxy.address(1), state, stats=stats,
+                retry_policy=_FAST_RETRY, stall_timeout_sec=10)
+            tree_equal(restored, state)
+            assert stats["digest_mismatches"] == 1
+            assert stats["attempts"] == 2
+            # the second round moved the wide leaf alone
+            assert stats["bytes_resumed"] == entry["nbytes"]
+            wide = [b for shape, b in placed
+                    if shape == state["m_wide"].shape]
+            assert wide == [np.asarray(state["m_wide"]).tobytes()]
+            assert len(placed) == len(
+                [e for e in mf["leaves"] if e["kind"] == "array"])
+        finally:
+            if proxy is not None:
+                proxy.close()
+            server.shutdown()
+
+    def test_cut_mid_wide_leaf_resumes_at_that_leaf(self):
+        """A connection cut inside a wide leaf keeps every leaf verified
+        before it, and the next attempt re-enters at the wide leaf's
+        first byte: `bytes_resumed` is exactly the stream from there."""
+        state = _wide_state(wide_mib=28, small=8)
+        server = CheckpointServer(lambda: state)
+        proxy = None
+        try:
+            server.allow_checkpoint(1)
+            mf = _fetch_manifest(server.address())
+            body = mf["total_len"] - mf["preamble_len"]
+            entry = next(e for e in mf["leaves"] if e["key"] == "m_wide")
+            proxy = _FlakyProxy(
+                server.address(), mode="cut",
+                fault_after=entry["offset"] + entry["nbytes"] * 2 // 3)
+            stats = {}
+            restored = CheckpointServer.load_from_address(
+                proxy.address(1), state, stats=stats,
+                retry_policy=_FAST_RETRY, stall_timeout_sec=10)
+            tree_equal(restored, state)
+            assert stats["attempts"] == 2
+            assert stats["bytes_resumed"] == body - entry["offset"]
+            assert stats["bytes"] == body + entry["nbytes"] * 2 // 3
+        finally:
+            if proxy is not None:
+                proxy.close()
+            server.shutdown()
+
+    def test_a_failing_placement_surfaces_and_lends_nothing_out(
+            self, monkeypatch, staging_pools):
+        """An error on the placement thread reaches the caller as itself,
+        and every staging buffer has come back."""
+        def failing_put(arr, tleaf, **kw):
+            raise MemoryError("device out of memory")
+
+        monkeypatch.setattr(checkpointing, "device_put_like", failing_put)
+        state = _heal_state(6, 2048)
+        server = CheckpointServer(lambda: state)
+        try:
+            server.allow_checkpoint(1)
+            with pytest.raises(MemoryError, match="out of memory"):
+                CheckpointServer.load_from_address(
+                    server.address(), state, stall_timeout_sec=10)
+            (pool,) = staging_pools
+            idle = sum(size * len(bufs)
+                       for size, bufs in pool._idle.items())
+            assert pool._held == idle
         finally:
             server.shutdown()

@@ -40,6 +40,12 @@ DOCUMENTED_KEYS = frozenset([
     "heal_leaf_digest_mismatches", "heal_attempts_total",
     "heal_last_bytes_committed", "heal_last_payload_bytes",
     "heal_striped_donors", "heal_redials_avoided",
+    # the heal transfer's stages, busy ms (docs/design/healing.md): the
+    # healer's wait for the manifest, socket reads, crc32, placement;
+    # the donor's D2H and socket writes
+    "heal_manifest_ms_total", "heal_recv_ms_total",
+    "heal_verify_ms_total", "heal_place_ms_total",
+    "heal_serve_fetch_ms_total", "heal_serve_send_ms_total",
     # allreduce pipeline
     "allreduce_count", "allreduce_ms_total",
     "allreduce_fetch_ms_total", "allreduce_fetch_dispatch_ms_total",
@@ -190,6 +196,7 @@ DOCUMENTED_STAGES = (
     "ring", "hier_intra", "hier_leader", "put", "exchange_wait",
     "overlap_drain", "drain", "pre_vote", "vote", "post_vote",
     "publish_status", "state_digest", "update", "ckpt_save", "publish",
+    "heal_manifest", "heal_recv", "heal_verify", "heal_place",
 )
 
 

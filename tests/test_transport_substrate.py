@@ -409,3 +409,133 @@ class TestChaosSeam:
         finally:
             chaos.uninstall()
             srv.shutdown()
+
+
+class TestHealWireCompatibility:
+    """The overlapped heal (docs/design/healing.md) changes neither the
+    manifest, nor the stream's bytes, nor the HTTP surface: a healer of
+    the serial build heals from this donor, and this healer from a donor
+    of the serial build. The serial ends are spelled out here as the
+    protocol has them: `/manifest`, then a Range GET from the first
+    missing byte, each leaf read whole, digested, compared."""
+
+    @staticmethod
+    def _state():
+        import jax.numpy as jnp
+
+        rng = np.random.RandomState(5)
+        # one leaf wider than a fetch batch (cut into runs of rows by
+        # the donor), small ones around it, a scalar
+        return {"a": jnp.asarray(rng.rand(300, 7).astype(np.float32)),
+                "m": jnp.asarray(rng.rand(7000, 1031).astype(np.float32)),
+                "z": [np.arange(1000, dtype=np.int32),
+                      jnp.ones((64,), jnp.bfloat16)],
+                "step": 9}
+
+    def test_a_serial_healer_heals_from_this_donor(self):
+        import zlib
+
+        from torchft_tpu.checkpointing import CheckpointServer
+        from torchft_tpu.serialization import (DEFAULT_BATCH_BYTES,
+                                               plan_pytree)
+
+        state = self._state()
+        assert state["m"].nbytes > DEFAULT_BATCH_BYTES
+        leaves = plan_pytree(state).array_leaves
+        srv = CheckpointServer(lambda: state, bind_host="127.0.0.1")
+        try:
+            srv.allow_checkpoint(4)
+            with urllib.request.urlopen(srv.address() + "/manifest",
+                                        timeout=30) as resp:
+                mf = json.loads(resp.read())
+            assert sorted(mf) == ["digest", "format", "leaves",
+                                  "preamble_len", "step", "total_len"]
+            arrays = [e for e in mf["leaves"] if e["kind"] == "array"]
+            assert [sorted(e) for e in arrays] == [
+                ["crc32", "dtype", "key", "kind", "nbytes", "offset",
+                 "shape"]] * len(leaves)
+            req = urllib.request.Request(
+                srv.address(),
+                headers={"Range": f"bytes={mf['preamble_len']}-"
+                                  f"{mf['total_len'] - 1}"})
+            with urllib.request.urlopen(req, timeout=30) as resp:
+                assert resp.status == 206
+                assert resp.headers["Content-Range"] == (
+                    f"bytes {mf['preamble_len']}-{mf['total_len'] - 1}"
+                    f"/{mf['total_len']}")
+                for entry, leaf in zip(arrays, leaves):
+                    buf = bytearray(entry["nbytes"])
+                    view, got = memoryview(buf), 0
+                    while got < len(buf):
+                        n = resp.readinto(view[got:])
+                        assert n, "truncated"
+                        got += n
+                    assert zlib.crc32(buf) == entry["crc32"]
+                    assert bytes(buf) == np.asarray(leaf).tobytes()
+                assert resp.read() == b""
+        finally:
+            srv.shutdown()
+
+    def test_this_healer_heals_from_a_serial_donor(self):
+        import re
+        import zlib
+        from http.server import BaseHTTPRequestHandler, HTTPServer
+
+        import jax
+
+        from torchft_tpu.checkpointing import CheckpointServer
+        from torchft_tpu.serialization import plan_pytree
+
+        state = self._state()
+        plan = plan_pytree(state)
+        blobs = [np.asarray(leaf).tobytes() for leaf in plan.array_leaves]
+        payload = plan.preamble + b"".join(blobs)
+        crcs = iter(zlib.crc32(b) for b in blobs)
+        manifest = json.dumps({
+            "format": "tft-manifest-1", "step": 4, "digest": "crc32",
+            "preamble_len": len(plan.preamble),
+            "total_len": len(payload),
+            "leaves": [dict(e, crc32=next(crcs)) if e["kind"] == "array"
+                       else e for e in plan.header["leaves"]],
+        }).encode()
+
+        class SerialDonor(BaseHTTPRequestHandler):
+            protocol_version = "HTTP/1.1"
+
+            def log_message(self, fmt, *args):
+                pass
+
+            def do_GET(self):
+                if self.path == "/checkpoint/4/manifest":
+                    body, status, extra = manifest, 200, {}
+                else:
+                    assert self.path == "/checkpoint/4"
+                    m = re.match(r"bytes=(\d+)-(\d+)$",
+                                 self.headers["Range"])
+                    lo, hi = int(m.group(1)), int(m.group(2)) + 1
+                    body, status = payload[lo:hi], 206
+                    extra = {"Content-Range":
+                             f"bytes {lo}-{hi - 1}/{len(payload)}"}
+                self.send_response(status)
+                self.send_header("Content-Length", str(len(body)))
+                for k, v in extra.items():
+                    self.send_header(k, v)
+                self.end_headers()
+                self.wfile.write(body)
+
+        server = HTTPServer(("127.0.0.1", 0), SerialDonor)
+        threading.Thread(target=server.serve_forever, daemon=True).start()
+        try:
+            stats = {}
+            got = CheckpointServer.load_from_address(
+                f"http://127.0.0.1:{server.server_port}/checkpoint/4",
+                state, stats=stats, stall_timeout_sec=30)
+            for a, b in zip(jax.tree_util.tree_leaves(got),
+                            jax.tree_util.tree_leaves(state)):
+                assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+            assert got["step"] == 9
+            assert stats["attempts"] == 1
+            assert stats["bytes"] == len(payload) - len(plan.preamble)
+        finally:
+            server.shutdown()
+            server.server_close()

@@ -67,6 +67,7 @@ from torchft_tpu.serialization import (
     balanced_ranges,
     device_put_like,
     iter_pytree_chunks,  # noqa: F401  (re-exported; legacy test seam)
+    leaf_digests,
     load_pytree_from,
     manifest_from,
     plan_pytree,
@@ -280,11 +281,9 @@ def _write_v2_stream(f, plan: Any, head_bytes: bytes,
     w(len(head_bytes).to_bytes(4, "little"))
     w(head_bytes)
     w(plan.preamble)
-    digests = []
-    for _, mv in _iter_leaf_views(plan.array_leaves,
-                                  DEFAULT_BATCH_BYTES):
-        digests.append(zlib.crc32(mv))
-        w(mv)
+    digests = list(leaf_digests(
+        _iter_leaf_views(plan.array_leaves, DEFAULT_BATCH_BYTES),
+        plan.array_leaves, sink=w))
     mf = manifest_from(plan, digests)
     mf["head_crc32"] = zlib.crc32(head_bytes)
     mf["preamble_crc32"] = zlib.crc32(plan.preamble)
@@ -513,8 +512,8 @@ def _write_torn(path: str, head_bytes: bytes, plan: Any,
             return
         if w(plan.preamble) <= 0:
             return
-        for _, mv in _iter_leaf_views(plan.array_leaves,
-                                      DEFAULT_BATCH_BYTES):
+        for _, _, mv in _iter_leaf_views(plan.array_leaves,
+                                         DEFAULT_BATCH_BYTES):
             if w(mv) <= 0:
                 return
 

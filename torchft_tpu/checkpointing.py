@@ -39,8 +39,11 @@ TPU-native differences from the reference:
 
 from __future__ import annotations
 
+import functools
 import json
 import logging
+import mmap
+import queue
 import re
 import threading
 import time
@@ -58,6 +61,11 @@ from torchft_tpu.retry import RetryError, RetryPolicy, RetryStats
 from torchft_tpu.tracing import maybe_span
 from torchft_tpu.utils import advertise_host
 from torchft_tpu.serialization import (
+    DEFAULT_BATCH_BYTES,
+    DEFAULT_CHUNK_BYTES,
+    StageClock,
+    _is_array_leaf,
+    _leaf_nbytes,
     _match_entries,
     _read_exact_into,
     _resolve_dtype,
@@ -77,6 +85,9 @@ MANIFEST_FORMAT = "tft-manifest-1"
 # persistent (donor-side corruption, not corruption in transit) and the
 # heal fails loudly instead of looping.
 MAX_LEAF_REFETCHES = 3
+# The healer's stages, as ``load_from_address``'s ``stats`` (``<stage>_ms``)
+# and ``Manager.metrics()`` (``heal_<stage>_ms_total``) name them.
+HEAL_STAGES = ("manifest", "recv", "verify", "place")
 
 
 class HealCorruptError(ValueError):
@@ -109,7 +120,8 @@ _serve_ranged_body = transport.serve_ranged_body
 _serve_ranged_bytes = transport.serve_ranged_bytes
 
 
-def build_manifest(plan: Any, step: int) -> dict:
+def build_manifest(plan: Any, step: int,
+                   clock: Optional[StageClock] = None) -> dict:
     """JSON transfer manifest for one serialized snapshot: the header's
     leaf entries (array entries annotated with ``offset``/``nbytes``
     body coordinates and a ``crc32`` content digest) plus the stream
@@ -118,11 +130,12 @@ def build_manifest(plan: Any, step: int) -> dict:
     snapshot, cached, shared by every healer. The digest/geometry core
     is :func:`torchft_tpu.serialization.manifest_from`, shared with the
     durable on-disk checkpoint trailer
-    (:mod:`torchft_tpu.checkpoint_io`)."""
+    (:mod:`torchft_tpu.checkpoint_io`). ``clock`` takes the digest
+    pass's D2H time (``fetch``)."""
     return {
         "format": MANIFEST_FORMAT,
         "step": int(step),
-        **manifest_from(plan),
+        **manifest_from(plan, plan.digests(clock=clock)),
     }
 
 
@@ -163,6 +176,253 @@ def _heal_transient(exc: BaseException) -> bool:
 _looks_donor_dead = transport.looks_peer_dead
 
 
+class _StagingPool:
+    """Host buffers of one heal transfer: where a leaf waits, whole, from
+    its first byte off the socket until it has verified and been placed
+    (a leaf's crc32 is known only with its last byte, and nothing of an
+    unverified leaf may reach the device). It is the transfer's bound on
+    host memory: the buffers lent out and the ones kept idle never hold
+    more than ``bound`` bytes together, and :meth:`take` waits beyond it
+    for one to come back.
+
+    A buffer whose leaf has been copied to a device comes back and is
+    lent again to the next leaf of its size (a model's layers repeat
+    their shapes; its embedding and its head are one shape): pages that
+    were written once, where a new mapping of such a size is
+    never-touched pages that fault in under ``recv_into`` at a third of
+    the socket's speed (PERF.md, PR 46). A leaf that stays on the host
+    (``device_put=False``; the CPU backend, which may alias what it is
+    given) keeps its buffer, and the buffer leaves the pool's count: it
+    is the result then, not in flight."""
+
+    def __init__(self, bound: int) -> None:
+        self.bound = int(bound)
+        self.peak = 0                       # most bytes ever held at once
+        self._held = 0                      # lent + idle + being warmed
+        self._idle: Dict[int, List[np.ndarray]] = {}
+        self._warming: Dict[int, int] = {}
+        self._cond = threading.Condition()
+
+    def _hold(self, nbytes: int) -> None:
+        self._held += nbytes
+        self.peak = max(self.peak, self._held)
+
+    def warm(self, nbytes: int) -> None:
+        """Have one buffer of ``nbytes`` written once before its first
+        use, on a thread of its own: the healer does this for its
+        largest leaf while it waits for the donor's manifest, so the
+        page faults run beside the donor's digest pass and not inside
+        the stream."""
+        with self._cond:
+            self._hold(nbytes)
+            self._warming[nbytes] = self._warming.get(nbytes, 0) + 1
+
+        def touch() -> None:
+            buf = None
+            try:
+                buf = np.empty(nbytes, np.uint8)
+                buf[::mmap.PAGESIZE] = 0
+            finally:
+                with self._cond:
+                    self._warming[nbytes] -= 1
+                    if buf is None:
+                        self._held -= nbytes
+                    else:
+                        self._idle.setdefault(nbytes, []).append(buf)
+                    self._cond.notify_all()
+
+        threading.Thread(target=touch, name="heal-warm",
+                         daemon=True).start()
+
+    def take(self, nbytes: int) -> np.ndarray:
+        """A uint8 buffer of exactly ``nbytes``: an idle one of that
+        size, else a new one once the bound allows it (idle buffers of
+        other sizes are let go first)."""
+        with self._cond:
+            while True:
+                idle = self._idle.get(nbytes)
+                if idle:
+                    return idle.pop()
+                if not self._warming.get(nbytes):
+                    for size in sorted(self._idle, reverse=True):
+                        bufs = self._idle[size]
+                        while bufs and self._held + nbytes > self.bound:
+                            bufs.pop()
+                            self._held -= size
+                    if self._held + nbytes <= self.bound:
+                        self._hold(nbytes)
+                        break
+                self._cond.wait()
+        return np.empty(nbytes, np.uint8)
+
+    def give(self, buf: np.ndarray, reusable: bool) -> None:
+        """Hand a taken buffer back: to be lent again, or (not
+        ``reusable``: someone else holds its memory now) out of the
+        count."""
+        with self._cond:
+            if reusable:
+                self._idle.setdefault(len(buf), []).append(buf)
+            else:
+                self._held -= len(buf)
+            self._cond.notify_all()
+
+
+def _left_host(placed: Any) -> bool:
+    """Wait until a placed leaf's copy has finished; True when it went to
+    a device with memory of its own, so that the host buffer it was read
+    from may be written again. The CPU backend may keep the buffer it was
+    handed as the array's own memory, and a leaf that was not placed at
+    all is the buffer."""
+    if not isinstance(placed, jax.Array):
+        return False
+    placed.block_until_ready()
+    return all(d.platform != "cpu" for d in placed.devices())
+
+
+class _LeafPipeline:
+    """The verify and place stages of one Range fetch, a thread each,
+    behind the reader (the calling thread, which reads the socket into
+    the leaf's staging buffer a chunk at a time):
+
+    * verify: ``zlib.crc32`` over each chunk as it lands, folded into
+      the leaf's running digest, so the verdict is there with the leaf's
+      last byte. A mismatch leaves the leaf missing (counted, bounded by
+      ``MAX_LEAF_REFETCHES``) and its buffer goes back unplaced;
+    * place: a verified leaf's ``session.commit`` (``device_put``), the
+      wait for its copy, ``progress_cb``; then the buffer goes back.
+
+    The three run beside each other: chunk k+1 is on the wire while
+    chunk k is digested and the leaf before is copied to the device.
+    What cannot hide is the last leaf's last chunk's digest and that
+    leaf's placement. Order is the stream's, so progress is monotone. A
+    stage that fails latches :attr:`error`; the reader sees it at its
+    next chunk, and every leaf already verified is still placed or its
+    buffer returned: nothing committed is lost, nothing is left lent."""
+
+    def __init__(self, session: "_HealSession", donor: str,
+                 progress_cb: Optional[Callable[[int, int], None]]
+                 ) -> None:
+        self._session = session
+        self._donor = donor
+        self._progress_cb = progress_cb
+        self.error: Optional[BaseException] = None
+        self._verify_q: "queue.SimpleQueue" = queue.SimpleQueue()
+        self._place_q: "queue.SimpleQueue" = queue.SimpleQueue()
+        self._threads = [
+            threading.Thread(target=self._run, name=f"heal-{stage}",
+                             args=(stage, work), daemon=True)
+            for stage, work in (("verify", self._verify),
+                                ("place", self._place))]
+        for t in self._threads:
+            t.start()
+
+    def landed(self, i: int, buf: np.ndarray, off: int, n: int) -> None:
+        """Bytes ``[off, off + n)`` of leaf ``i`` are in ``buf``; with
+        the first call the buffer is the pipeline's to give back."""
+        self._verify_q.put((i, buf, off, n))
+
+    def close(self) -> None:
+        """No more chunks: let the stages finish what they hold."""
+        self._verify_q.put(None)
+        for t in self._threads:
+            t.join()
+
+    def _run(self, stage: str,
+             work: Callable[[], Tuple[float, int]]) -> None:
+        with self._session.span(f"heal_{stage}", donor=self._donor) as sp:
+            busy_s, leaves = work()
+            sp.set(busy_ms=round(busy_s * 1e3, 3), leaves=leaves)
+        self._session.clock.add(stage, busy_s)
+
+    def _fail(self, exc: BaseException) -> None:
+        if self.error is None:
+            self.error = exc
+
+    def _verify(self) -> Tuple[float, int]:
+        session, staging = self._session, self._session.staging
+        open_buf: Optional[np.ndarray] = None   # a leaf partly landed
+        crc = 0
+        busy_s, leaves = 0.0, 0
+        try:
+            while True:
+                msg = self._verify_q.get()
+                if msg is None:
+                    break
+                i, buf, off, n = msg
+                last = off + n == len(buf)
+                open_buf = None if last else buf
+                if self.error is not None:
+                    if last:
+                        staging.give(buf, True)
+                    continue
+                try:
+                    t0 = time.perf_counter()
+                    crc = zlib.crc32(memoryview(buf)[off:off + n],
+                                     crc if off else 0)
+                    busy_s += time.perf_counter() - t0
+                    if not last:
+                        continue
+                    leaves += 1
+                    entry = session.pairs[i][0]
+                    if "crc32" not in entry or crc == int(entry["crc32"]):
+                        self._place_q.put((i, buf, crc))
+                        continue
+                    staging.give(buf, True)
+                    with session.lock:
+                        session.digest_mismatches += 1
+                        tries = session.refetches[i] = \
+                            session.refetches.get(i, 0) + 1
+                    logger.warning(
+                        "heal: leaf %r digest mismatch "
+                        "(got %08x, manifest %08x; refetch %d/%d)",
+                        entry["key"], crc, int(entry["crc32"]), tries,
+                        MAX_LEAF_REFETCHES)
+                    if tries >= MAX_LEAF_REFETCHES:
+                        self._fail(HealCorruptError(
+                            f"leaf {entry['key']!r} failed digest "
+                            f"verification {tries} times; the donor's "
+                            "copy is corrupt"))
+                    # else it stays missing; the next round re-spans it
+                except BaseException as e:  # noqa: BLE001 — to the reader
+                    self._fail(e)
+                    if last:
+                        staging.give(buf, True)
+        finally:
+            if open_buf is not None:    # the stream ended inside a leaf
+                staging.give(open_buf, True)
+            self._place_q.put(None)
+        return busy_s, leaves
+
+    def _place(self) -> Tuple[float, int]:
+        session, staging = self._session, self._session.staging
+        busy_s, leaves = 0.0, 0
+        while True:
+            msg = self._place_q.get()
+            if msg is None:
+                return busy_s, leaves
+            i, buf, crc = msg
+            if self.error is not None:
+                staging.give(buf, True)
+                continue
+            reusable = False
+            try:
+                t0 = time.perf_counter()
+                entry = session.pairs[i][0]
+                placed = session.commit(
+                    i, buf.view(_resolve_dtype(entry["dtype"])).reshape(
+                        entry["shape"]), crc, donor=self._donor)
+                reusable = _left_host(placed)
+                busy_s += time.perf_counter() - t0
+                leaves += 1
+                if self._progress_cb is not None:
+                    self._progress_cb(session.committed_bytes,
+                                      session.total_len)
+            except BaseException as e:  # noqa: BLE001 — to the reader
+                self._fail(e)
+            finally:
+                staging.give(buf, reusable)
+
+
 class _HealSession:
     """Cross-attempt, cross-donor state of one resumable heal transfer:
     which leaves are committed (digest-verified and placed), their
@@ -174,6 +434,18 @@ class _HealSession:
                  device_put_fn: Optional[Callable]) -> None:
         self.target = target
         self.device_put_fn = device_put_fn
+        # Host memory of the transfer, beyond what it returns: the leaf
+        # on the wire and the leaves being verified and placed, at most
+        # twice the target's widest leaf (or two fetch batches) in all.
+        self.widest = max(
+            [_leaf_nbytes(leaf)
+             for leaf in jax.tree_util.tree_leaves(target)
+             if _is_array_leaf(leaf)] + [0])
+        self.staging = _StagingPool(
+            2 * max(self.widest, DEFAULT_BATCH_BYTES))
+        # Busy time of the healer's stages (manifest, recv, verify,
+        # place), over every attempt and donor.
+        self.clock = StageClock()
         self.treedef: Any = None
         self.pairs: Optional[list] = None   # [(entry, target_leaf)]
         self.arr_order: List[int] = []      # array pair indices, body order
@@ -266,7 +538,7 @@ class _HealSession:
                 self.commit(i, arr, zlib.crc32(b""))
 
     def commit(self, i: int, arr: np.ndarray, crc: int,
-               donor: Optional[str] = None) -> None:
+               donor: Optional[str] = None) -> Any:
         tleaf = self.pairs[i][1]
         placed = (self.device_put_fn(arr, tleaf)
                   if self.device_put_fn is not None else arr)
@@ -276,6 +548,7 @@ class _HealSession:
             self.committed_bytes += int(self.pairs[i][0]["nbytes"])
             if donor is not None:
                 self.donors_used.add(donor)
+        return placed
 
     def note_bytes(self, n: int) -> None:
         with self.lock:
@@ -422,6 +695,9 @@ class CheckpointServer:
         # docs/design/state_attestation.md): sticky 503 on every
         # state-serving GET while the owning Manager is quarantined.
         self._quarantined = False
+        # Busy time of this donor's heal stages: ``fetch`` (D2H of the
+        # digest pass and of every stream), ``send`` (socket writes).
+        self._clock = StageClock()
 
         # Host on the transport substrate's shared server core (async
         # event loop by default, TORCHFT_ASYNC_SERVER=0 for the legacy
@@ -559,7 +835,7 @@ class CheckpointServer:
                 # snapshot is immutable); computed once per snapshot,
                 # shared by every healer and attempt.
                 body = json.dumps(
-                    build_manifest(plan, req_step)).encode()
+                    build_manifest(plan, req_step, self._clock)).encode()
                 handler.send_response(200)
                 handler.send_header("Content-Type", "application/json")
                 handler.send_header("Content-Length", str(len(body)))
@@ -572,7 +848,7 @@ class CheckpointServer:
             # "truncated"), so log the real cause here.
             try:
                 _serve_ranged_body(handler, state, plan,
-                                   self._send_timeout_sec)
+                                   self._send_timeout_sec, self._clock)
             except Exception:
                 logger.exception(
                     "checkpoint stream failed mid-transfer "
@@ -636,6 +912,12 @@ class CheckpointServer:
                      else _snapshot_tree(self._state_fn()))
             self._snap = (self._step, state, plan_pytree(state))
         return self._snap[1], self._snap[2]
+
+    def metrics(self) -> Dict[str, float]:
+        """This donor's heal stages, busy ms since it started (merged
+        into ``Manager.metrics()``)."""
+        return {"heal_serve_fetch_ms_total": self._clock.ms("fetch"),
+                "heal_serve_send_ms_total": self._clock.ms("send")}
 
     def address(self) -> str:
         """Dialable HTTP URL for the current step's checkpoint. When bound
@@ -941,10 +1223,16 @@ class CheckpointServer:
                           = None,
                           tracer: Optional[Any] = None) -> T:
         """Fetch a peer's live checkpoint and restore it into ``target``'s
-        structure (and shardings, when ``device_put``). Streams: each leaf
-        is read off the socket into a preallocated buffer, digest-verified
-        against the donor's manifest, and only then device_put — corrupt
-        or truncated bytes never reach the device.
+        structure (and shardings, when ``device_put``). Streams, as one
+        overlapped pass (:class:`_LeafPipeline`): a leaf is read off the
+        socket into a staging buffer while its chunks are digested behind
+        the reader and the leaf before it is copied to the device; it is
+        ``device_put`` only once its crc32 matched the donor's manifest —
+        corrupt or truncated bytes never reach the device. Host memory
+        beyond the result: the staging buffers, at most 2 x max(widest
+        leaf, 24 MiB) bytes (:class:`_StagingPool`; 1.41 GiB for a state
+        whose widest leaf is 723 MiB, where the serial pass held a leaf
+        and its copy, the same).
 
         The transfer is RESUMABLE: the donor's ``/manifest`` endpoint
         describes the stream (per-leaf offsets + crc32 digests), and each
@@ -996,7 +1284,11 @@ class CheckpointServer:
         (bytes fetched by resumed attempts after the first),
         ``donor_failovers``, ``digest_mismatches``, and ``attempts`` —
         filled on failure too, so a FAILED heal's wire cost and attempt
-        history still reach the caller's metrics/event log.
+        history still reach the caller's metrics/event log — and the
+        stages' busy times ``manifest_ms`` (waiting for the donor's
+        digest pass), ``recv_ms`` (in the socket), ``verify_ms``
+        (crc32), ``place_ms`` (``device_put`` and its copy): their sum
+        over the transfer's wall says how far the stages overlapped.
         ``progress_cb(bytes_committed, payload_bytes)`` fires after every
         verified leaf. Chaos injection uses per-donor endpoints
         ``heal:<host:port>`` (channel ``heal``)."""
@@ -1008,9 +1300,14 @@ class CheckpointServer:
                  else timeout_sec)
         deadline = (t0 + pol.overall_deadline_ms / 1e3
                     if pol.overall_deadline_ms > 0 else None)
-        dput = device_put_like if device_put else None
+        # The staging buffer is the transfer's own until the placed copy
+        # is ready, so placement need not copy it first.
+        dput = (functools.partial(device_put_like, copy=False)
+                if device_put else None)
         session = _HealSession(target, dput)
         session.tracer = tracer
+        if session.widest > DEFAULT_BATCH_BYTES:
+            session.staging.warm(session.widest)
         # Striped donor set: seed-shuffled so concurrent healers spread
         # their first streams; the quorum's primary rides along
         # (deduped) as one donor among equals.
@@ -1047,6 +1344,8 @@ class CheckpointServer:
                     session.stripe_deaths)
                 stats["redials_avoided"] = float(
                     session.pool.redials_avoided)
+                for stage in HEAL_STAGES:
+                    stats[f"{stage}_ms"] = session.clock.ms(stage)
             session.pool.close()
         dt = time.perf_counter() - t0
         logger.info(
@@ -1084,8 +1383,15 @@ class CheckpointServer:
             marker = len(session.committed)
             try:
                 if legacy is not True and need_manifest:
-                    mf = cls._fetch_manifest(addr, stall, auth_token,
-                                             endpoint, pool=session.pool)
+                    t0 = time.perf_counter()
+                    try:
+                        with session.span("heal_manifest", donor=addr):
+                            mf = cls._fetch_manifest(
+                                addr, stall, auth_token, endpoint,
+                                pool=session.pool)
+                    finally:
+                        session.clock.add("manifest",
+                                          time.perf_counter() - t0)
                     if mf is None:
                         legacy = True
                         logger.info(
@@ -1283,6 +1589,8 @@ class CheckpointServer:
                          headers={"Range": f"bytes={a}-{b - 1}"},
                          pool=session.pool)
         counter = [0]
+        recv_s = 0.0
+        pipe = _LeafPipeline(session, addr, progress_cb)
         try:
             reader = _CountingReader(
                 chaos.wrap_reader(resp, endpoint), counter)
@@ -1297,35 +1605,33 @@ class CheckpointServer:
                     if not chunk:
                         raise ValueError("truncated checkpoint stream")
                     remaining -= len(chunk)
-            for i in idxs:
-                entry, tleaf = session.pairs[i]
-                arr = np.empty(entry["shape"],
-                               _resolve_dtype(entry["dtype"]))
-                mv = arr.reshape(-1).view(np.uint8).data
-                _read_exact_into(reader, mv)
-                crc = zlib.crc32(mv)
-                if "crc32" in entry and crc != int(entry["crc32"]):
-                    with session.lock:
-                        session.digest_mismatches += 1
-                        n = session.refetches[i] = \
-                            session.refetches.get(i, 0) + 1
-                    logger.warning(
-                        "heal: leaf %r digest mismatch "
-                        "(got %08x, manifest %08x; refetch %d/%d)",
-                        entry["key"], crc, int(entry["crc32"]), n,
-                        MAX_LEAF_REFETCHES)
-                    if n >= MAX_LEAF_REFETCHES:
-                        raise HealCorruptError(
-                            f"leaf {entry['key']!r} failed digest "
-                            f"verification {n} times; the donor's copy "
-                            "is corrupt")
-                    continue  # stays missing; next round re-spans it
-                session.commit(i, arr, crc, donor=addr)
-                if progress_cb is not None:
-                    progress_cb(session.committed_bytes, session.total_len)
+            with session.span("heal_recv", donor=addr) as sp:
+                for i in idxs:
+                    buf = session.staging.take(
+                        int(session.pairs[i][0]["nbytes"]))
+                    mv = memoryview(buf)
+                    off = 0
+                    try:
+                        while off < len(buf):
+                            if pipe.error is not None:
+                                raise pipe.error
+                            n = min(DEFAULT_CHUNK_BYTES, len(buf) - off)
+                            t0 = time.perf_counter()
+                            _read_exact_into(reader, mv[off:off + n])
+                            recv_s += time.perf_counter() - t0
+                            pipe.landed(i, buf, off, n)
+                            off += n
+                    finally:
+                        if not off:     # never handed to the pipeline
+                            session.staging.give(buf, True)
+                sp.set(busy_ms=round(recv_s * 1e3, 3), leaves=len(idxs))
         finally:
+            pipe.close()
+            session.clock.add("recv", recv_s)
             resp.close()
             session.note_bytes(counter[0])
+        if pipe.error is not None:
+            raise pipe.error
         chaos.end(tok)
 
     @classmethod

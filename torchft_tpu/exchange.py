@@ -26,7 +26,7 @@ import jax.numpy as jnp
 
 from torchft_tpu.communicator import (INT8_SEG_ELEMS, Communicator, Int8Wire,
                                       shard_bounds)
-from torchft_tpu.utils import div_by_count
+from torchft_tpu.utils import div_by_count, row_view as _row_view
 
 logger: logging.Logger = logging.getLogger(__name__)
 
@@ -340,21 +340,6 @@ def _wire_pair(dtype: Any, wire: Optional[np.dtype]) -> tuple:
             and orig.itemsize > wire.itemsize):
         return orig, np.dtype(wire)
     return orig, orig
-
-
-def _row_view(shape: tuple, itemsize: int, cap_bytes: int) -> tuple:
-    """How a leaf too wide for one slice is cut: ``(lead, rows per
-    slice)``. The leaf is viewed as ``(-1,) + shape[lead:]`` with as many
-    trailing axes kept whole as fit ``cap_bytes``, and a slice is a run
-    of rows of that view — merging leading axes and cutting the first
-    one moves no data on the device, where a ravel of the whole leaf is
-    a leaf-sized copy."""
-    cap = max(cap_bytes // itemsize, 1)
-    lead, row = len(shape), 1
-    while lead > 0 and row * shape[lead - 1] <= cap:
-        lead -= 1
-        row *= shape[lead]
-    return lead, cap // row
 
 
 def _make_buckets(shapes: list, itemsizes: list, bucket_bytes: int,

@@ -59,7 +59,7 @@ from torchft_tpu import serialization
 from torchft_tpu import tracing as tracing_mod
 from torchft_tpu import transport
 from torchft_tpu._native import ManagerClient, ManagerServer, Store, StoreClient
-from torchft_tpu.checkpointing import CheckpointServer
+from torchft_tpu.checkpointing import HEAL_STAGES, CheckpointServer
 from torchft_tpu.communicator import Communicator, CommunicatorError
 from torchft_tpu.exchange import GradExchange, ShardedGrads, StepFacts
 from torchft_tpu.retry import RetryPolicy, RetryStats
@@ -634,6 +634,17 @@ class Manager:
             "reconfigure_count": 0, "reconfigure_ms_total": 0.0,
             "heal_count": 0,
             "heal_ms_total": 0.0, "heal_bytes_total": 0.0,
+            # The heal transfer's stages, busy ms (docs/design/
+            # healing.md): this group as the healer (waiting for the
+            # donor's manifest; in the socket; crc32; device_put and its
+            # copy) and as a donor (D2H of the digest pass and of the
+            # streams; socket writes). The healer's four over
+            # heal_ms_total say how far the stages ran beside each other
+            # (1.0: one after the other).
+            "heal_manifest_ms_total": 0.0, "heal_recv_ms_total": 0.0,
+            "heal_verify_ms_total": 0.0, "heal_place_ms_total": 0.0,
+            "heal_serve_fetch_ms_total": 0.0,
+            "heal_serve_send_ms_total": 0.0,
             # Resilient-heal observability: bytes re-sent by resumed
             # attempts (strictly less than the payload when resume
             # works), donor failovers, leaves caught by digest
@@ -1497,6 +1508,7 @@ class Manager:
                     heal_attempts_total=heal_stats.get("attempts", 0.0),
                     heal_redials_avoided=heal_stats.get(
                         "redials_avoided", 0.0),
+                    **_heal_stage_ms(heal_stats),
                 )
                 with self._metrics_lock:  # gauge, not a counter
                     self._metrics["heal_striped_donors"] = heal_stats.get(
@@ -1744,7 +1756,8 @@ class Manager:
             )
         heal_ms = (time.perf_counter() - heal_t0) * 1e3
         self._record(heal_ms_total=heal_ms,
-                     heal_bytes_total=heal_stats.get("bytes", 0.0))
+                     heal_bytes_total=heal_stats.get("bytes", 0.0),
+                     **_heal_stage_ms(heal_stats))
         self._log_event(event="sdc_reheal", step=self._step,
                         donors=len(donors), ms=round(heal_ms, 1),
                         bytes=heal_stats.get("bytes", 0.0))
@@ -3766,6 +3779,11 @@ class Manager:
             out.update(self._ram_store.metrics())
         if self._ram_replicator is not None:
             out.update(self._ram_replicator.metrics())
+        # This group as a heal donor: its checkpoint server's stage
+        # clock (a caller's own transport may have none).
+        serve_metrics = getattr(self._ckpt_server, "metrics", None)
+        if callable(serve_metrics):
+            out.update(serve_metrics())
         # Transport-substrate counters (process-wide, like the jit-cache
         # stats above): per-QoS-class byte volume, scheduler waits, and
         # the async core's connection/request/sendfile totals — the
@@ -4719,6 +4737,13 @@ def _attest_device_words(leaves: list) -> Any:
 
         fn = _ATTEST_FNS["attest"] = jax.jit(attest)
     return fn(leaves)
+
+
+def _heal_stage_ms(heal_stats: Dict[str, float]) -> Dict[str, float]:
+    """A heal's stage busy times (``load_from_address``'s ``stats``) under
+    their ``metrics()`` names."""
+    return {f"heal_{stage}_ms_total": heal_stats.get(f"{stage}_ms", 0.0)
+            for stage in HEAL_STAGES}
 
 
 def _stripe_seed(replica_id: str) -> int:

@@ -14,10 +14,12 @@ healing move: weights arrive over DCN on the host and are laid out directly
 onto the receiving slice's mesh.
 
 Both directions stream: the header is computed from array *metadata* (no
-data fetched), then :func:`iter_pytree_chunks` materializes one leaf at a
-time and yields zero-copy memoryview slices, and :func:`load_pytree_from`
-fills preallocated buffers leaf-by-leaf with per-leaf ``device_put``. Peak
-extra host RAM on either side is O(largest leaf + chunk), not O(checkpoint)
+data fetched), then :func:`iter_pytree_chunks` materializes a batch at a
+time (a wide leaf in runs of rows, the next batch fetched while this one
+is consumed) and yields zero-copy memoryview slices, and
+:func:`load_pytree_from` fills preallocated buffers leaf-by-leaf with
+per-leaf ``device_put``. Peak extra host RAM is two batches on the sending
+side and O(largest leaf + chunk) on the receiving one, not O(checkpoint)
 — healing a config-3-sized model (80GB+ params+opt) cannot double host RAM
 the way a monolithic ``bytes`` round-trip would (the reference streams via
 ``torch.save`` directly to the socket for the same reason,
@@ -29,14 +31,20 @@ cannot execute code on the healer.
 
 from __future__ import annotations
 
+import functools
+import itertools
 import json
 import threading
+import time
 import zlib
-from typing import (Any, BinaryIO, Callable, Dict, Iterator, List, Optional,
-                    Tuple)
+from concurrent.futures import ThreadPoolExecutor
+from typing import (Any, BinaryIO, Callable, Dict, Iterable, Iterator, List,
+                    Optional, Tuple)
 
 import jax
 import numpy as np
+
+from torchft_tpu.utils import row_view
 
 _MAGIC = b"TFTPTREE"
 DEFAULT_CHUNK_BYTES = 8 * 1024 * 1024
@@ -120,22 +128,42 @@ class PytreePlan:
     def __len__(self) -> int:
         return 3
 
-    def digests(self, batch_bytes: int = 0) -> List[int]:
+    def digests(self, batch_bytes: int = 0,
+                clock: Optional["StageClock"] = None) -> List[int]:
         """Per-array-leaf crc32 of the raw serialized bytes, in body
-        order. Computed once (a batched ``device_get`` pass at O(batch)
-        host RAM, like streaming) and cached; safe under concurrent
-        manifest requests. crc32 is not cryptographic — it detects
-        truncation/corruption in transit, and doubles as the runtime
-        check of the cross-donor same-step bitwise-identity invariant
-        (donors for one step must produce identical digests)."""
+        order. Computed once and cached; safe under concurrent manifest
+        requests. The pass is the fetch engine's (:func:`_iter_leaf_views`):
+        the next batch crosses D2H while this one is digested, a leaf
+        wider than a batch is digested slice by slice into one running
+        crc, and host RAM holds two batches. crc32 is not cryptographic —
+        it detects truncation/corruption in transit, and doubles as the
+        runtime check of the cross-donor same-step bitwise-identity
+        invariant (donors for one step must produce identical digests).
+        ``clock`` takes the pass's D2H busy time (``fetch``)."""
         with self._digest_lock:
             if self._digests is None:
                 bb = batch_bytes or DEFAULT_BATCH_BYTES
-                self._digests = [
-                    zlib.crc32(mv)
-                    for _, mv in _iter_leaf_views(self.array_leaves, bb)
-                ]
+                self._digests = list(leaf_digests(
+                    _iter_leaf_views(self.array_leaves, bb, clock=clock),
+                    self.array_leaves))
             return list(self._digests)
+
+
+def leaf_digests(views: Iterable[Tuple[int, int, memoryview]],
+                 array_leaves: list,
+                 sink: Optional[Callable[[memoryview], Any]] = None
+                 ) -> Iterator[int]:
+    """Fold :func:`_iter_leaf_views` pieces into one crc32 a leaf, in
+    body order: a leaf's digest is yielded with its last piece. ``sink``
+    sees every piece first (a writer that digests what it writes)."""
+    crc = 0
+    for i, off, mv in views:
+        if sink is not None:
+            sink(mv)
+        crc = zlib.crc32(mv, crc)
+        if off + len(mv) == _leaf_nbytes(array_leaves[i]):
+            yield crc
+            crc = 0
 
 
 def manifest_from(plan: PytreePlan,
@@ -299,7 +327,33 @@ def plan_pytree(tree: Any) -> PytreePlan:
     return PytreePlan(preamble, len(preamble) + offset, array_leaves, header)
 
 
-DEFAULT_BATCH_BYTES = 64 * 1024 * 1024
+# What one ``device_get`` of the fetch engine lands on the host, and the
+# widest run of rows a leaf is cut into. Under glibc's largest mmap
+# threshold (32 MiB): such a buffer comes from the heap and is recycled
+# warm, where from 32 MiB up each is a new mapping of never-touched pages
+# that other threads' faults then wait behind (``exchange._SLICE_BYTES``
+# is the same size for the same reason; PERF.md, Findings PRs 30 and 36).
+DEFAULT_BATCH_BYTES = 24 * 1024 * 1024
+
+
+class StageClock:
+    """Busy seconds by stage, added to from the several threads of a
+    pipelined transfer. The heal keeps one a side: the donor's
+    ``fetch`` / ``send``, the healer's ``manifest`` / ``recv`` /
+    ``verify`` / ``place``. Their sum over the transfer's wall says how
+    far the stages ran beside each other (1.0: one after the other)."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._seconds: Dict[str, float] = {}
+
+    def add(self, stage: str, seconds: float) -> None:
+        with self._lock:
+            self._seconds[stage] = self._seconds.get(stage, 0.0) + seconds
+
+    def ms(self, stage: str) -> float:
+        with self._lock:
+            return self._seconds.get(stage, 0.0) * 1e3
 
 
 def balanced_ranges(sizes: list, n: int) -> list:
@@ -331,31 +385,128 @@ def _leaf_nbytes(leaf: Any) -> int:
                ) * np.dtype(leaf.dtype).itemsize
 
 
-def _iter_leaf_views(array_leaves: list, batch_bytes: int,
-                     ) -> Iterator[Tuple[int, memoryview]]:
-    """Host-materialize ``array_leaves`` in batched ``jax.device_get``
-    groups of up to ``batch_bytes`` and yield ``(leaf_index,
-    uint8_memoryview)`` per leaf, in order — the shared fetch engine
-    under streaming serialization and digest computation. Peak extra
-    host RAM is O(batch), not O(checkpoint)."""
-    group: list = []
-    group_bytes = 0
+@functools.lru_cache(maxsize=64)
+def _cut_rows(lead: int, count: int) -> Any:
+    """Jitted cut of ``count`` rows of :func:`row_view`'s view from a
+    TRACED first row: a leaf shape costs one program for its full slices
+    and one for its tail, however many slices it has."""
+    def cut(x, first):
+        view = x.reshape((-1,) + x.shape[lead:])
+        return jax.lax.dynamic_slice_in_dim(view, first, count, axis=0)
+    return jax.jit(cut)
 
-    def flush():
-        fetched = jax.device_get([leaf for _, leaf in group])
-        for (i, _), arr in zip(group, fetched):
-            arr = np.ascontiguousarray(arr)
-            yield i, arr.reshape(-1).view(np.uint8).data
 
+def _fetch_units(array_leaves: list, batch_bytes: int,
+                 span: Optional[Tuple[int, int]] = None) -> List[list]:
+    """The fetch engine's plan, from metadata alone: units of pieces
+    ``(leaf index, byte offset in the leaf, nbytes, rows)``, one
+    ``device_get`` a unit, in body order. Leaves of at most
+    ``batch_bytes`` group whole (``rows`` None) up to that many bytes a
+    unit; a wider leaf becomes consecutive units of one piece each,
+    ``rows = (lead, first row, row count)`` of :func:`row_view`. ``span``
+    keeps only the pieces that overlap body bytes ``[lo, hi)``."""
+    def wanted(at: int, n: int) -> bool:
+        return span is None or max(span[0], at) < min(span[1], at + n)
+
+    units: List[list] = []
+    cur: list = []
+    cur_bytes = 0
+    start = 0
     for i, leaf in enumerate(array_leaves):
         nbytes = _leaf_nbytes(leaf)
-        if group and group_bytes + nbytes > batch_bytes:
-            yield from flush()
-            group, group_bytes = [], 0
-        group.append((i, leaf))
-        group_bytes += nbytes
-    if group:
-        yield from flush()
+        base, start = start, start + nbytes
+        if nbytes > batch_bytes:
+            if cur:
+                units.append(cur)
+                cur, cur_bytes = [], 0
+            shape = tuple(leaf.shape)
+            lead, per = row_view(shape, np.dtype(leaf.dtype).itemsize,
+                                 batch_bytes)
+            row_bytes = nbytes // int(np.prod(shape[:lead], dtype=np.int64))
+            for first in range(0, nbytes // row_bytes, per):
+                count = min(per, nbytes // row_bytes - first)
+                if wanted(base + first * row_bytes, count * row_bytes):
+                    units.append([(i, first * row_bytes, count * row_bytes,
+                                   (lead, first, count))])
+            continue
+        if not wanted(base, nbytes):
+            continue
+        if cur and cur_bytes + nbytes > batch_bytes:
+            units.append(cur)
+            cur, cur_bytes = [], 0
+        cur.append((i, 0, nbytes, None))
+        cur_bytes += nbytes
+    if cur:
+        units.append(cur)
+    return units
+
+
+def _fetch_unit(array_leaves: list, unit: list,
+                clock: Optional[StageClock]) -> list:
+    """One batched ``device_get``: the unit's pieces as ``(leaf index,
+    byte offset in the leaf, uint8 memoryview)``."""
+    t0 = time.perf_counter()
+    parts = []
+    for i, _, _, rows in unit:
+        leaf = array_leaves[i]
+        if rows is not None:
+            lead, first, count = rows
+            if isinstance(leaf, jax.Array):
+                leaf = _cut_rows(lead, count)(leaf, np.int32(first))
+            else:
+                leaf = np.asarray(leaf)
+                leaf = leaf.reshape((-1,) + leaf.shape[lead:])[
+                    first:first + count]
+        parts.append(leaf)
+    out = [(i, off, np.ascontiguousarray(arr).reshape(-1).view(np.uint8).data)
+           for (i, off, _, _), arr in zip(unit, jax.device_get(parts))]
+    if clock is not None:
+        clock.add("fetch", time.perf_counter() - t0)
+    return out
+
+
+def _one_ahead(thunks: Iterable[Callable[[], Any]]) -> Iterator[Any]:
+    """``thunk()`` for each thunk, in order, the next one computed on a
+    helper thread while the consumer holds the current: two results
+    alive at most. One thunk alone runs inline, with no thread."""
+    thunks = iter(thunks)
+    first = next(thunks, None)
+    if first is None:
+        return
+    pending = next(thunks, None)
+    if pending is None:
+        yield first()
+        return
+    with ThreadPoolExecutor(1, thread_name_prefix="tft-fetch") as pool:
+        fut = pool.submit(first)
+        while fut is not None:
+            cur = fut.result()
+            fut = pool.submit(pending) if pending is not None else None
+            pending = next(thunks, None)
+            yield cur
+            del cur
+
+
+def _iter_leaf_views(array_leaves: list, batch_bytes: int,
+                     span: Optional[Tuple[int, int]] = None,
+                     clock: Optional[StageClock] = None,
+                     ) -> Iterator[Tuple[int, int, memoryview]]:
+    """Host-materialize ``array_leaves`` a :func:`_fetch_units` unit at a
+    time and yield ``(leaf index, byte offset in the leaf, uint8
+    memoryview)`` per piece, in body order — the shared fetch engine
+    under streaming serialization, digest computation and the durable
+    checkpoint's writer. A leaf of at most ``batch_bytes`` is one piece;
+    a wider one comes in runs of whole rows, so no ``device_get`` (and no
+    write after it) is longer than a batch. The next unit crosses D2H
+    on a helper thread while the consumer holds the current one
+    (:func:`_one_ahead`): host RAM holds two batches, 2 x
+    ``batch_bytes``, whatever the tree; a tree that fits one batch is
+    fetched inline. ``clock`` takes each ``device_get``'s time as
+    ``fetch``."""
+    for pieces in _one_ahead(
+            functools.partial(_fetch_unit, array_leaves, unit, clock)
+            for unit in _fetch_units(array_leaves, batch_bytes, span)):
+        yield from pieces
 
 
 def iter_pytree_chunks(tree: Any,
@@ -364,18 +515,21 @@ def iter_pytree_chunks(tree: Any,
                        batch_bytes: int = DEFAULT_BATCH_BYTES,
                        start: int = 0,
                        end: Optional[int] = None,
+                       clock: Optional[StageClock] = None,
                        ) -> Iterator[memoryview]:
     """Stream-serialize: yields the preamble, then the array leaves' raw
-    bytes in ``chunk_bytes`` slices. Leaves are host-materialized in
-    batched ``jax.device_get`` groups of up to ``batch_bytes`` (a pytree
-    with thousands of small optimizer-state leaves pays a handful of
-    dispatch round-trips, not thousands), so peak extra host RAM is
-    O(batch), not O(checkpoint). Slices are zero-copy memoryviews.
+    bytes in ``chunk_bytes`` slices. Leaves are host-materialized by the
+    fetch engine (:func:`_iter_leaf_views`): small ones in batched
+    ``jax.device_get`` groups of up to ``batch_bytes`` (a pytree with
+    thousands of small optimizer-state leaves pays a handful of dispatch
+    round-trips, not thousands), wide ones in runs of rows, the next
+    batch fetched while this one is consumed, so peak extra host RAM is
+    two batches, not O(checkpoint). Slices are zero-copy memoryviews.
     ``plan`` reuses a precomputed :func:`plan_pytree` result (the HTTP
     server plans once for Content-Length and must stream that same plan).
 
     ``start``/``end`` select a byte range of the serialized stream
-    (``end=None`` = to the end): leaves wholly outside the range are
+    (``end=None`` = to the end): what lies wholly outside the range is
     skipped WITHOUT fetching any device data, which is what makes a
     resumed heal transfer O(remaining bytes) on the donor side too, not
     just on the wire."""
@@ -383,38 +537,27 @@ def iter_pytree_chunks(tree: Any,
         plan if plan is not None else plan_pytree(tree))
     hi = total_len if end is None else min(int(end), total_len)
     lo = max(int(start), 0)
-    if lo == 0 and hi >= total_len:
-        # Full-stream fast path, bitwise-identical to the historical
-        # behavior (including the single empty chunk a 0-size leaf
-        # yields).
+    full = lo == 0 and hi >= total_len
+    if full:
+        # Bitwise the historical full stream, chunk for chunk where no
+        # leaf is cut: the preamble whole, and the single empty chunk a
+        # 0-size leaf yields.
         yield memoryview(preamble)
-        for _, mv in _iter_leaf_views(array_leaves, batch_bytes):
-            for i in range(0, len(mv) or 1, chunk_bytes):
-                yield mv[i:i + chunk_bytes]
+    elif lo >= hi:
         return
-    if lo >= hi:
-        return
-    if lo < len(preamble):
+    elif lo < len(preamble):
         mv = memoryview(preamble)[lo:min(hi, len(preamble))]
-        for i in range(0, len(mv), chunk_bytes):
-            yield mv[i:i + chunk_bytes]
-    # Select only the leaves overlapping [lo, hi); record the slice of
-    # each so a range entering mid-leaf still serves exact bytes.
-    off = len(preamble)
-    wanted: list = []
-    slices: dict = {}
-    for idx, leaf in enumerate(array_leaves):
-        nbytes = _leaf_nbytes(leaf)
-        a, b = max(lo, off), min(hi, off + nbytes)
-        if a < b:
-            slices[len(wanted)] = (a - off, b - off)
-            wanted.append(leaf)
-        off += nbytes
-    for j, mv in _iter_leaf_views(wanted, batch_bytes):
-        s, e = slices[j]
-        mv = mv[s:e]
-        for i in range(0, len(mv), chunk_bytes):
-            yield mv[i:i + chunk_bytes]
+        for k in range(0, len(mv), chunk_bytes):
+            yield mv[k:k + chunk_bytes]
+    body = (max(lo - len(preamble), 0), hi - len(preamble))
+    bases = [0, *itertools.accumulate(map(_leaf_nbytes, array_leaves))]
+    for i, off, mv in _iter_leaf_views(array_leaves, batch_bytes,
+                                       None if full else body, clock):
+        if not full:
+            at = bases[i] + off
+            mv = mv[max(body[0] - at, 0):body[1] - at]
+        for k in range(0, len(mv) or int(full), chunk_bytes):
+            yield mv[k:k + chunk_bytes]
 
 
 def save_pytree(tree: Any) -> bytes:
@@ -599,9 +742,14 @@ def load_pytree(
     return jax.tree_util.tree_unflatten(treedef, out_leaves)
 
 
-def device_put_like(arr: np.ndarray, target_leaf: Any) -> Any:
-    """Place ``arr`` with the same sharding/device as ``target_leaf``."""
+def device_put_like(arr: np.ndarray, target_leaf: Any,
+                    copy: bool = True) -> Any:
+    """Place ``arr`` with the same sharding/device as ``target_leaf``.
+    ``copy=False`` hands ``arr``'s own memory to the transfer when the
+    dtypes agree (no host copy first): for a caller that writes to it
+    again only after the placed array is ready, and never on a backend
+    that may keep the memory as the array's own."""
     if isinstance(target_leaf, jax.Array):
-        return jax.device_put(arr.astype(target_leaf.dtype),
+        return jax.device_put(arr.astype(target_leaf.dtype, copy=copy),
                               target_leaf.sharding)
     return arr

@@ -82,6 +82,7 @@ STAGES = (
     "ring", "hier_intra", "hier_leader", "put", "exchange_wait",
     "overlap_drain", "drain", "pre_vote", "vote", "post_vote",
     "publish_status", "state_digest", "update", "ckpt_save", "publish",
+    "heal_manifest", "heal_recv", "heal_verify", "heal_place",
 )
 
 

@@ -395,7 +395,8 @@ def _send_range_head(handler: Any, status: int, start: int, end: int,
 
 
 def serve_ranged_body(handler: Any, state: Any, plan: Any,
-                      send_timeout_sec: float) -> int:
+                      send_timeout_sec: float,
+                      clock: Optional[Any] = None) -> int:
     """Stream one serialized snapshot's bytes on ``handler`` with HTTP
     Range semantics (200 full / 206 partial + Content-Range / 416) —
     the ONE body-serving implementation shared by the checkpoint heal
@@ -403,7 +404,11 @@ def serve_ranged_body(handler: Any, state: Any, plan: Any,
     between them. Total length is known from the plan before any
     device data is fetched (Content-Length up front), chunks are
     zero-copy memoryviews, and socket-write backpressure paces the
-    fetches. Returns bytes written (0 for a 416)."""
+    fetches: the next batch crosses D2H while this one is written
+    (``serialization._iter_leaf_views``), two batches of host memory at
+    most beside what the connection has queued. ``clock`` (a
+    ``serialization.StageClock``) takes the D2H time as ``fetch`` and
+    the writes as ``send``. Returns bytes written (0 for a 416)."""
     from torchft_tpu.serialization import iter_pytree_chunks
 
     total = int(plan[1])
@@ -413,10 +418,17 @@ def serve_ranged_body(handler: Any, state: Any, plan: Any,
     status, start, end = span
     _send_range_head(handler, status, start, end, total, send_timeout_sec)
     sent = 0
-    for chunk in iter_pytree_chunks(state, plan=plan, start=start,
-                                    end=end):
-        handler.wfile.write(chunk)
-        sent += len(chunk)
+    send_s = 0.0
+    try:
+        for chunk in iter_pytree_chunks(state, plan=plan, start=start,
+                                        end=end, clock=clock):
+            t0 = time.monotonic()
+            handler.wfile.write(chunk)
+            send_s += time.monotonic() - t0
+            sent += len(chunk)
+    finally:
+        if clock is not None:
+            clock.add("send", send_s)
     return sent
 
 

@@ -6,6 +6,22 @@ import os
 import socket
 
 
+def row_view(shape: tuple, itemsize: int, cap_bytes: int) -> tuple:
+    """How a leaf too wide for one slice is cut: ``(lead, rows per
+    slice)``. The leaf is viewed as ``(-1,) + shape[lead:]`` with as many
+    trailing axes kept whole as fit ``cap_bytes``, and a slice is a run
+    of rows of that view — merging leading axes and cutting the first
+    one moves no data on the device, where a ravel of the whole leaf is
+    a leaf-sized copy. Shared by the gradient exchange's slices and the
+    fetch engine's."""
+    cap = max(cap_bytes // itemsize, 1)
+    lead, row = len(shape), 1
+    while lead > 0 and row * shape[lead - 1] <= cap:
+        lead -= 1
+        row *= shape[lead]
+    return lead, cap // row
+
+
 def div_by_count(a, n):
     """Divide a reduced leaf by the participant count, dtype-aware.
 
