@@ -102,6 +102,55 @@ class TestFlashAttention:
                                        atol=2e-4, rtol=2e-4)
 
 
+class TestHeadOf64:
+    """LFM2-8B-A1B's attention layer: a head of 64 (2048 / 32), 32 query
+    heads on 8 key/value heads: every other configuration runs heads of 128
+    to 256. The interpreted kernels (forward, and the split backward's two:
+    interpreted, ``_flash_bwd`` always takes them) against the module's own
+    ``_reference`` at a q grid four blocks deep; the chip runs the fused
+    backward at this head size (``scripts/flash_head64_check.py``)."""
+
+    B, S, H, HKV, D, BLOCK = 1, 256, 32, 8, 64, 64
+
+    def _inputs(self):
+        ks = jax.random.split(jax.random.key(7), 4)
+        return (jax.random.normal(ks[0], (self.B, self.S, self.H, self.D)),
+                jax.random.normal(ks[1], (self.B, self.S, self.HKV, self.D)),
+                jax.random.normal(ks[2], (self.B, self.S, self.HKV, self.D)),
+                jax.random.normal(ks[3], (self.B, self.S, self.H, self.D)))
+
+    def _both(self):
+        from torchft_tpu.ops.flash_attention import _reference
+
+        q, k, v, g = self._inputs()
+        out, vjp = jax.vjp(lambda q, k, v: flash_attention(
+            q, k, v, True, self.BLOCK, self.BLOCK, interpret=True), q, k, v)
+        rep = self.H // self.HKV     # _reference takes equal head counts
+        want, vjp_ref = jax.vjp(
+            lambda q, k, v: _reference(q, jnp.repeat(k, rep, axis=2),
+                                       jnp.repeat(v, rep, axis=2), True),
+            q, k, v)
+        return (out, *vjp(g)), (want, *vjp_ref(g))
+
+    @pytest.mark.parametrize("which", ["forward", "dq", "dk", "dv"])
+    def test_against_the_reference(self, which):
+        got, want = self._both()
+        i = ["forward", "dq", "dk", "dv"].index(which)
+        assert got[i].shape == want[i].shape
+        assert got[i].shape[2] == (self.H if i < 2 else self.HKV)
+        np.testing.assert_allclose(np.asarray(got[i]), np.asarray(want[i]),
+                                   atol=2e-5 if i == 0 else 1e-4)
+
+    def test_the_tiles_of_a_head_of_64_are_a_head_of_128s(self):
+        """No tile rule of its own: at 8,192 tokens a head of 64 takes the
+        1,024-token tiles a head of 128 takes (a head over 128 takes 512),
+        so the q grid is eight deep and the backward is the fused one."""
+        from torchft_tpu.ops.flash_attention import _auto_block
+
+        assert _auto_block(8192, cap=1024) == 1024
+        assert 8192 // _auto_block(8192, cap=1024) >= 4
+
+
 class TestFlashAttentionGQA:
     """GQA/MQA kv heads are shared via kernel index maps — values and
     gradients must match the materialized-repeat path exactly."""

@@ -138,6 +138,31 @@ def test_flash_fwd_bwd_compiles_as_a_kernel(one_chip, monkeypatch,
 
 
 @pytest.mark.parametrize("fused_bwd", ["1", "0"], ids=["fused", "split"])
+def test_flash_compiles_at_a_head_of_64_on_eight_kv_heads(one_chip,
+                                                         monkeypatch,
+                                                         fused_bwd):
+    """[1, 8192, 32, 64] queries on [1, 8192, 8, 64] keys and values, bf16
+    causal (``lfm2-8b-a1b``'s attention layer at its cell's length; every
+    other configuration runs heads of 128 to 256): forward, fused backward
+    and split backward lower as Mosaic kernels with the tiles of a head of
+    128, half a lane tile wide, and stay inside scoped VMEM."""
+    monkeypatch.setenv("TORCHFT_FLASH_FUSED_BWD", fused_bwd)
+    q = jax.ShapeDtypeStruct((1, 8192, 32, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 8192, 8, 64), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True,
+                               interpret=False).astype(jnp.float32).sum()
+
+    c = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv).compile()
+    text = c.as_text()
+    assert text.count("tpu_custom_call") >= (2 if fused_bwd == "1" else 3)
+    assert "bf16[32,8192,64]" in text and "bf16[8,8192,64]" in text
+
+
+@pytest.mark.parametrize("fused_bwd", ["1", "0"], ids=["fused", "split"])
 def test_latent_flash_compiles_at_two_head_sizes(one_chip, monkeypatch,
                                                  fused_bwd):
     """[1, 8192, 32, 192] queries and keys against [1, 8192, 32, 128]
@@ -394,12 +419,17 @@ CELL_STEPS = {
     "nemotron-3-nano-30b-a3b.steady-1g-8k": ("%attn", "gmm",
                                              "8,8,64,64,128]",
                                              "bf16[32,8192,128]"),
+    "lfm2-8b-a1b.steady-1g-8k": ("%attn", "gmm", "bf16[32,8192,64]",
+                                 "bf16[8,8192,64]", "bf16[1,8192,6144]",
+                                 "bf16[8,2048,1792]"),
 }
 # What has to fit beside the step. The first three cells were sized when the
 # driver's oracle kept one more seeded tree there (until PR 44), and keep
 # that room; the fourth was sized after, for the oracle's one thinned sample
-# (0.3 GiB of a tree of 1.97, read on the chip in PR 45).
-SAMPLE_ROOM = {"nemotron-3-nano-30b-a3b.steady-1g-8k": int(0.3 * GiB)}
+# (0.3 GiB of a tree of 1.97, read on the chip in PR 45), and so was the
+# fifth (PR 47).
+SAMPLE_ROOM = {"nemotron-3-nano-30b-a3b.steady-1g-8k": int(0.3 * GiB),
+               "lfm2-8b-a1b.steady-1g-8k": int(0.3 * GiB)}
 
 
 @pytest.mark.parametrize("name", list(CELL_STEPS), ids=list(CELL_STEPS))
@@ -416,8 +446,12 @@ def test_sparse_cells_step_fits_the_chip(one_chip, name):
     ``nemotron-3-nano-30b-a3b``: three Mamba-2 blocks (the scan's per-chunk
     states ``[8,8,64,64,128]``, no loop), three relu^2 expert blocks whose
     grouped products tile 2688 and 1856 by 896 and 640, one attention block
-    at 32 heads of 128. The grouped matmuls compile as Mosaic custom calls,
-    and the step fits with the room the driver's oracle needs beside it."""
+    at 32 heads of 128; ``lfm2-8b-a1b``: four gated short convolutions (the
+    three streams ``[1,8192,6144]``), the flash kernels at 32 heads of 64
+    on 8 key/value heads, four expert layers holding 8 of 32 at a width of
+    1792, a head that is the table (49 leaves a tree, no ``lm_head``). The
+    grouped matmuls compile as Mosaic custom calls, and the step fits with
+    the room the driver's oracle needs beside it."""
     import sys
 
     bench = os.path.join(os.path.dirname(os.path.dirname(

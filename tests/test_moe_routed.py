@@ -172,7 +172,10 @@ def test_slots_are_whole_row_tiles(pairs, slots):
     # 2688 = 21 x 128 and 1856 = 14.5 x 128 have no power-of-two divisor
     # over 128: the multiple of 128 that pads least, the largest such
     (2688, 1024, 896), (2688, 512, 384), (1856, 1024, 640),
-    (1856, 512, 384)])
+    (1856, 512, 384),
+    # 1792 = 14 x 128 = 7 x 256: a quarter of the cap divides it, in seven
+    # steps; 896 does in two (PR 47). Under the backward's cap half divides
+    (1792, 1024, 896), (1792, 512, 256)])
 def test_product_tiles_by_width(dim, cap, tile):
     assert _tile(dim, cap) == tile
     assert tile <= cap and (tile % 128 == 0 or tile == dim)

@@ -345,7 +345,72 @@ def _mamba(remat):
     return loss_fn, params, {"tokens": toks}
 
 
+def _conv(remat):
+    """Two gated short convolutions around an attention layer, a dense MLP
+    then routed experts, a tied head: 2 x 32 tokens."""
+    from torchft_tpu.models.transformer import TransformerConfig
+
+    cfg = TransformerConfig(
+        vocab_size=64, num_layers=3, embed_dim=32, num_heads=2,
+        max_seq_len=SEQ, dtype=jnp.float32, remat=remat,
+        layer_types=("conv", "full_attention", "conv"),
+        linear_conv_kernel=3, tie_embeddings=True, moe_experts=4,
+        moe_top_k=TOP_K, moe_dispatch="routed", moe_held=(0, 2), moe_dim=16,
+        moe_dense_layers=1, moe_interpret=True)
+    model = Transformer(cfg)
+    toks = jax.random.randint(jax.random.key(1), (2, SEQ), 0, cfg.vocab_size)
+    params = {"params": model.init(jax.random.key(0), toks)["params"]}
+
+    def loss_fn(p, batch):
+        return jnp.mean(model.apply(p, batch["tokens"]) ** 2)
+
+    return loss_fn, params, {"tokens": toks}
+
+
 class TestTrainers:
+    @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+    def test_a_conv_model_counts_tokens_and_rms_as_output_values(
+            self, remat):
+        """``shortconv_tokens_total`` and ``shortconv_out_rms_micro_total``
+        leave the fused step as values of its counts output, beside the
+        routed layers' three whole numbers: once a step, no host
+        callback."""
+        loss_fn, params, batch = _conv(remat)
+        before = tracing.program_counters()
+        trainer = FTTrainer(
+            loss_fn=loss_fn, tx=optax.sgd(0.01), params=params,
+            manager_factory=lambda load, save: make_manager(
+                _client([1]), load_state_dict=load, state_dict=save,
+                min_replica_size=1))
+        try:
+            for _ in range(2):
+                _, committed = trainer.train_step(batch)
+                assert committed
+            jax.block_until_ready(trainer.params)
+            metrics = trainer.manager.metrics()
+            args = (trainer.params, None, trainer.opt_state, batch)
+            lowered = trainer._fused.lower(*args).as_text()
+            counts = jax.eval_shape(trainer._fused, *args)[-1]
+        finally:
+            trainer.shutdown()
+        # 2 steps x 2 conv layers x 2 sequences x 32 tokens
+        assert metrics["shortconv_tokens_total"] \
+            - before.get("shortconv_tokens_total", 0.0) == 2 * 2 * 2 * SEQ
+        # a fresh layer's output carries something: its rms, the step's
+        # mean over the two mixers, in millionths
+        rms = metrics["shortconv_out_rms_micro_total"] \
+            - before.get("shortconv_out_rms_micro_total", 0.0)
+        assert 0 < rms < 2 * 10e6
+        assert metrics["program_callbacks_total"] \
+            == before["program_callbacks_total"]
+        assert "callback" not in lowered
+        assert counts.keys == (
+            tuple(sorted(("moe_pairs_routed_total", "moe_pairs_local_total",
+                          "moe_expert_load_max_total"))),
+            ("shortconv_out_rms_micro_total", "shortconv_tokens_total"))
+        assert [(v.shape, v.dtype) for v in counts.values] \
+            == [((3,), jnp.int32), ((2,), jnp.float32)]
+
     @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
     def test_a_mamba_model_counts_chunks_and_decay_as_output_values(
             self, remat):

@@ -141,8 +141,9 @@ def test_a_linear_layer_needs_its_sizes():
     with pytest.raises(ValueError, match="linear_key_heads"):
         Transformer(cfg).init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))
     with pytest.raises(ValueError, match="unknown layer type"):
-        Transformer(tiny_config(layer_types=("conv", "full_attention"))).init(
-            jax.random.key(0), jnp.zeros((1, 8), jnp.int32))
+        Transformer(tiny_config(
+            layer_types=("retention", "full_attention"))).init(
+                jax.random.key(0), jnp.zeros((1, 8), jnp.int32))
 
 
 # ------------------------------------------------------ the mixer alone

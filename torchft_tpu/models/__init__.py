@@ -6,6 +6,7 @@ from torchft_tpu.models.transformer import (
     TransformerConfig,
     causal_lm_loss,
     chunked_causal_lm_loss,
+    head_kernel,
     llama2_7b_config,
     llama2_13b_config,
     llama2_70b_config,
@@ -17,6 +18,7 @@ from torchft_tpu.models.transformer import (
 from torchft_tpu.models.mla import LatentAttention
 from torchft_tpu.models.linear_attention import GatedDeltaNet
 from torchft_tpu.models.mamba2 import Mamba2Mixer
+from torchft_tpu.models.short_conv import ShortConv
 
 __all__ = [
     "GatedDeltaNet",
@@ -25,6 +27,7 @@ __all__ = [
     "Mamba2Mixer",
     "MoEMLP",
     "RoutedMoEMLP",
+    "ShortConv",
     "ep_rules",
     "moe_lm_loss",
     "mtp_causal_lm_loss",
@@ -36,6 +39,7 @@ __all__ = [
     "TransformerConfig",
     "causal_lm_loss",
     "chunked_causal_lm_loss",
+    "head_kernel",
     "llama2_7b_config",
     "llama2_13b_config",
     "llama2_70b_config",

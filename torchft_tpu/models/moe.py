@@ -147,19 +147,19 @@ def _megablox() -> Any:
 
 def _tile(dim: int, cap: int) -> int:
     """A tile for a dimension the kernels may tile unevenly: the whole of a
-    short one, else the largest power-of-two fraction of ``cap`` (>= 256)
-    that divides it; else, for a width that has no such divisor (2688 = 21 x
-    128, 1856 = 14.5 x 128), the multiple of 128 up to ``cap`` that pads the
-    dimension least, the largest of those (896 and 640 under a cap of 1024:
-    the kernels mask the remainder). A tile of 128 there made a grid of
-    thousands of steps of microseconds each (PERF.md, PR 45)."""
+    short one, else ``cap`` or half of it where that divides the dimension;
+    else, for a width neither divides (2688 = 21 x 128, 1856 = 14.5 x 128,
+    1792 = 14 x 128), the multiple of 128 up to ``cap`` that pads the
+    dimension least, the largest of those (896, 640 and 896 under a cap of
+    1024: the kernels mask the remainder). A tile of 128 there made a grid
+    of thousands of steps of microseconds each (PERF.md, PR 45), and a
+    quarter of the cap, which divides 1792, seven steps where 896 makes two
+    (PERF.md, PR 47)."""
     if dim <= cap:
         return dim
-    t = cap
-    while t > 128:
+    for t in (cap, cap // 2):
         if dim % t == 0:
             return t
-        t //= 2
     return min(range(128, cap + 1, 128),
                key=lambda t: (-(-dim // t) * t - dim, -t))
 

@@ -61,12 +61,14 @@ class TransformerConfig:
     #                                       off a TPU)
     # Layers of different kinds. ``layer_types[i]`` is "full_attention",
     # "sliding_attention" (key j visible to query i iff 0 <= i - j <
-    # ``sliding_window``) or "linear_attention" (a Gated DeltaNet mixer,
-    # models/linear_attention.py, at the ``linear_*`` sizes below): a mixer
-    # and an MLP, two norms; or one of the blocks that are ONE norm and ONE
-    # sub-block with the residual around it: "mamba" (a Mamba-2 mixer,
-    # models/mamba2.py, at the ``ssm_*`` sizes and ``linear_conv_kernel``),
-    # "moe" (the expert MLP alone) and "attention" (full attention alone).
+    # ``sliding_window``), "linear_attention" (a Gated DeltaNet mixer,
+    # models/linear_attention.py, at the ``linear_*`` sizes below) or "conv"
+    # (a gated short convolution, models/short_conv.py, of
+    # ``linear_conv_kernel`` taps): a mixer and an MLP, two norms; or one of
+    # the blocks that are ONE norm and ONE sub-block with the residual around
+    # it: "mamba" (a Mamba-2 mixer, models/mamba2.py, at the ``ssm_*`` sizes
+    # and ``linear_conv_kernel``), "moe" (the expert MLP alone) and
+    # "attention" (full attention alone).
     # None = every layer full. ``rope_full_layers=False`` leaves rotary off
     # the full-attention layers; ``rotary_dim`` turns only the leading
     # ``rotary_dim`` dims of a head (None: the whole head).
@@ -93,6 +95,10 @@ class TransformerConfig:
     sandwich_norm: bool = False
     embed_scale: bool = False
     rms_norm_eps: float = 1e-5
+    # The head reads the embedding table: no ``lm_head`` in the tree, and the
+    # one ``[vocab, embed]`` leaf takes the gather's gradient and the head's
+    # (:func:`head_kernel`).
+    tie_embeddings: bool = False
     # Latent attention (models/mla.py), on when ``kv_lora_rank`` is set:
     # queries through a ``q_lora_rank`` bottleneck, keys and values through
     # one of ``kv_lora_rank`` (an RMSNorm inside each), a query/key head of
@@ -143,6 +149,7 @@ class TransformerConfig:
 
 FULL, SLIDING = "full_attention", "sliding_attention"
 LINEAR = "linear_attention"
+CONV = "conv"
 # blocks of one norm and one sub-block
 MAMBA, EXPERTS, ATTENTION = "mamba", "moe", "attention"
 SINGLE = (MAMBA, EXPERTS, ATTENTION)
@@ -307,18 +314,22 @@ class MLPBlock(nn.Module):
 def _mixer(cfg: TransformerConfig, kind: str, h, positions, counts: dict):
     """The mixer of ``kind`` over the normed stream ``h``, named ``attn``;
     what it has for the program counters goes into ``counts``."""
-    if kind in (LINEAR, MAMBA):
+    if kind in (LINEAR, MAMBA, CONV):
         if kind == LINEAR:
             from torchft_tpu.models.linear_attention import (
-                GDN_COUNTERS as names, GatedDeltaNet as scanned)
-        else:
+                GDN_COUNTERS as names, GatedDeltaNet as counted)
+        elif kind == MAMBA:
             from torchft_tpu.models.mamba2 import (
-                SSD_COUNTERS as names, Mamba2Mixer as scanned)
-        a, (chunks, log_decay) = scanned(cfg, name="attn")(
-            h, return_stats=True)
+                SSD_COUNTERS as names, Mamba2Mixer as counted)
+        else:
+            from torchft_tpu.models.short_conv import (
+                SCONV_COUNTERS as names, ShortConv as counted)
+        # a count (chunks walked, tokens) and a reading (the mean log decay,
+        # the output's rms)
+        a, (count, reading) = counted(cfg, name="attn")(h, return_stats=True)
         # the step's mean over its layers of this kind, in millionths
         share = 1e6 / sum(t == kind for t in cfg.layer_types)
-        counts.update(zip(names, (chunks, log_decay * share)))
+        counts.update(zip(names, (count, reading * share)))
         return a
     if cfg.kv_lora_rank:
         from torchft_tpu.models.mla import LatentAttention
@@ -364,14 +375,15 @@ def _mlp(cfg: TransformerConfig, moe: bool, u, counts: dict):
 
 
 class DecoderLayer(nn.Module):
-    """One layer. Of most kinds: a mixer (attention, or the Gated DeltaNet
-    of ``"linear_attention"``) and a dense or (``moe``) an expert MLP,
-    pre-norm; with ``cfg.sandwich_norm`` each sub-block's output is normed
-    again before it joins the stream. Of a kind in ``SINGLE``: one norm
+    """One layer. Of most kinds: a mixer (attention, the Gated DeltaNet of
+    ``"linear_attention"`` or the gated short convolution of ``"conv"``)
+    and a dense or (``moe``) an expert MLP, pre-norm; with
+    ``cfg.sandwich_norm`` each sub-block's output is normed again before it
+    joins the stream. Of a kind in ``SINGLE``: one norm
     (``norm``) and one sub-block, ``x + F(norm(x))``, ``F`` a Mamba-2 mixer,
     the expert MLP or full attention. Returns the stream, and where the
     layer has numbers for the program counters (a routed expert layer, a
-    linear-attention or mamba mixer) ``(stream, {counter: value})``."""
+    linear-attention, mamba or conv mixer) ``(stream, {counter: value})``."""
 
     cfg: TransformerConfig
     kind: str = FULL
@@ -494,7 +506,10 @@ class Transformer(nn.Module):
             return x, z, moe_stats
         if return_hidden:
             return x
-        # tied-untied head in f32 for stable loss
+        # the head in f32 for a stable loss: the embedding table read again
+        # (``tie_embeddings``), else a kernel of its own
+        if cfg.tie_embeddings:
+            return x.astype(jnp.float32) @ embed.embedding.T
         return nn.Dense(cfg.vocab_size, use_bias=False, dtype=jnp.float32,
                         name="lm_head")(x)
 
@@ -643,6 +658,19 @@ def _chunk_nll(h_c, t_c, w):
     return logp, nll
 
 
+def head_kernel(params: Any) -> jnp.ndarray:
+    """The head's ``[embed, vocab]`` kernel of a ``Transformer``'s
+    ``params`` (the ``init`` output or its ``"params"``), for the callers of
+    ``return_hidden=True``: the ``lm_head`` kernel, or where the model is
+    tied (no ``lm_head`` in the tree) the embedding table transposed, so
+    that a gradient taken through it lands in the table's own leaf beside
+    the gather's."""
+    p = params["params"] if "params" in params else params
+    if "lm_head" in p:
+        return p["lm_head"]["kernel"]
+    return p["embed"]["embedding"].T
+
+
 def _head_operand(head_kernel, matmul_dtype):
     return head_kernel.astype(jnp.float32 if matmul_dtype is None
                               else matmul_dtype)
@@ -722,8 +750,9 @@ def chunked_causal_lm_loss(hidden: jnp.ndarray, head_kernel: jnp.ndarray,
     chunk inside ONE scan, which peaks at a few [B, chunk, V] tiles; when
     the loss is differentiated the same scan yields both head gradients
     (a ``jax.custom_vjp``: no forward scan, no recomputed logits). Use
-    with ``model.apply(params, tokens, return_hidden=True)`` and the
-    ``lm_head`` kernel from params.
+    with ``model.apply(params, tokens, return_hidden=True)`` and
+    :func:`head_kernel` of the params (the ``lm_head`` kernel, or a tied
+    model's table transposed).
 
     ``chunk_size``: positions a chunk; ``None`` takes
     :func:`head_loss_chunk` of the shapes.
@@ -747,7 +776,7 @@ def mtp_causal_lm_loss(model: "Transformer", params: Any,
     prediction module: the trunk's next-token loss and the module's loss
     against the token after next (mean over the ``S - 2`` positions that
     have one), both through :func:`chunked_causal_lm_loss` and the one
-    ``lm_head`` kernel, whose gradient is the sum of the two.
+    head kernel (:func:`head_kernel`), whose gradient is the sum of the two.
 
     Under a collector (``tracing.collect_counts``, which every trainer of
     this package wraps its loss in) the routed layers' ``moe_*`` counts (the
@@ -759,7 +788,7 @@ def mtp_causal_lm_loss(model: "Transformer", params: Any,
     from torchft_tpu import tracing
 
     hidden, mtp_hidden, stats = model.apply(params, tokens, return_mtp=True)
-    head = params["params"]["lm_head"]["kernel"]
+    head = head_kernel(params)
     main = chunked_causal_lm_loss(hidden, head, tokens, chunk_size)
     with jax.named_scope("mtp"):
         mtp = chunked_causal_lm_loss(mtp_hidden[:, :-1], head, tokens[:, 1:],

@@ -47,11 +47,12 @@ CHUNK = 64   # tokens a chunk: the [C, C] inverse stays small, the products
 def causal_conv1d(x: jnp.ndarray, weight: jnp.ndarray,
                   bias: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Causal depthwise convolution along the sequence. ``x`` [B, T, Ch],
-    ``weight`` [K, Ch], ``bias`` [Ch] or none (the delta rule's has none,
-    the state-space mixer's has one): ``y_t = sum_j weight[j] * x_{t-K+1+j}
-    + bias`` with zeros left of the sequence. Float32 inside, ``x``'s type
-    out. K shifted copies in one fused pass: at K = 4 a convolution
-    primitive has nothing to add."""
+    ``weight`` [K, Ch], ``bias`` [Ch] or none (the delta rule's and the
+    gated short convolution's have none, the state-space mixer's has one):
+    ``y_t = sum_j weight[j] * x_{t-K+1+j} + bias`` with zeros left of the
+    sequence. Float32 inside, ``x``'s type out. K shifted copies in one
+    fused pass: at the K of 3 or 4 its callers have, a convolution primitive
+    has nothing to add."""
     k = weight.shape[0]
     t = x.shape[1]
     padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
