@@ -313,7 +313,7 @@ def phase_kernel(run: Run) -> None:
     if rel["worst"] > TOLERANCE:
         raise AssertionError(
             f"fused backward differs from split by {rel['worst']:.3e}: "
-            f"the dq read-modify-write is not safe on this libtpu; set "
+            f"the fused kernel is not right on this libtpu; set "
             f"TORCHFT_FLASH_FUSED_BWD=0")
 
 

@@ -1,13 +1,18 @@
 #!/usr/bin/env python3
-"""On the chip: the flash kernels at a head of 64, 32 query heads on 8
-key/value heads, 8,192 tokens (LFM2-8B-A1B's attention layer; every other
-configuration runs heads of 128 to 256): the forward, the fused backward and
-the split backward against plain float32 softmax attention computed one
-head at a time, and each one's wall per call.
+"""On the chip: the flash kernels alone at the shapes the benchmark's cells
+run, the forward, the fused backward and the split backward against plain
+float32 softmax attention computed one head at a time, and each one's wall
+per call. The default is the head of 64 this script was written for
+(LFM2-8B-A1B's attention layer: 32 query heads on 8 key/value heads, 8,192
+tokens); ``--shape`` names another of ``SHAPES`` and ``--shape all`` runs
+them in turn.
 
-    chiprun --chips 1 -- python3 scripts/flash_head64_check.py [--seq 8192]
+    chiprun --chips 1 -- python3 scripts/flash_head64_check.py [--shape all]
 
-Prints one JSON line; exits 1 where a relative error passes ``--tol``."""
+``--root DIR`` imports ``torchft_tpu`` from another checkout (a ``git
+archive`` copy of the parent commit under ``.chip_archive/``), so one call
+times two trees on one chip. Prints one JSON line a shape (and appends it
+to ``--out``); exits 1 where a relative error passes ``--tol``."""
 
 import argparse
 import json
@@ -15,36 +20,42 @@ import os
 import sys
 import time
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+#          batch, tokens, heads, kv heads, d_qk, d_v, window
+SHAPES = {
+    "gqa64": (1, 8192, 32, 8, 64, 64, None),        # lfm2-8b-a1b
+    "full128": (1, 8192, 32, 4, 128, 128, None),    # trinity-mini, full
+    "window128": (1, 8192, 32, 4, 128, 128, 2048),  # trinity-mini, window
+    "mla192": (1, 8192, 32, 32, 192, 128, None),    # joyai-llm-flash
+    "gqa256": (1, 8192, 16, 2, 256, 256, None),     # qwen3-next-80b-a3b
+    "mistral": (4, 4096, 32, 8, 128, 128, None),    # mistral-7b.steady-1g
+}
+ITERS = 10
 
 
-def main() -> int:
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--seq", type=int, default=8192)
-    ap.add_argument("--heads", type=int, default=32)
-    ap.add_argument("--kv-heads", type=int, default=8)
-    ap.add_argument("--head-dim", type=int, default=64)
-    ap.add_argument("--tol", type=float, default=2e-2)
-    args = ap.parse_args()
-
+def check(shape, tol):
     import jax
     import jax.numpy as jnp
 
     from torchft_tpu.ops.flash_attention import flash_attention
 
-    s, h, hkv, d = args.seq, args.heads, args.kv_heads, args.head_dim
+    b, s, h, hkv, d, d_v, window = shape
     ks = jax.random.split(jax.random.key(0), 4)
-    q = jax.random.normal(ks[0], (1, s, h, d), jnp.bfloat16)
-    k = jax.random.normal(ks[1], (1, s, hkv, d), jnp.bfloat16)
-    v = jax.random.normal(ks[2], (1, s, hkv, d), jnp.bfloat16)
-    g = jax.random.normal(ks[3], (1, s, h, d), jnp.bfloat16)
+    q = jax.random.normal(ks[0], (b, s, h, d), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (b, s, hkv, d), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (b, s, hkv, d_v), jnp.bfloat16)
+    g = jax.random.normal(ks[3], (b, s, h, d_v), jnp.bfloat16)
 
     def plain(q, k, v):
         rep = h // hkv
         qh = q.astype(jnp.float32).transpose(2, 0, 1, 3)
         kh = jnp.repeat(k.astype(jnp.float32).transpose(2, 0, 1, 3), rep, 0)
         vh = jnp.repeat(v.astype(jnp.float32).transpose(2, 0, 1, 3), rep, 0)
-        mask = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
+        i, j = jnp.arange(s)[:, None], jnp.arange(s)[None, :]
+        mask = i >= j
+        if window is not None:
+            mask &= i - j < window
 
         @jax.checkpoint
         def one(a):
@@ -56,46 +67,72 @@ def main() -> int:
 
         return jax.lax.map(one, (qh, kh, vh)).transpose(1, 2, 0, 3)
 
-    def run(fn, fused):
-        os.environ["TORCHFT_FLASH_FUSED_BWD"] = "1" if fused else "0"
-        f = jax.jit(lambda q, k, v: jax.vjp(fn, q, k, v)[1](
-            g.astype(fn(q, k, v).dtype)))
+    def timed(f):
         out = jax.block_until_ready(f(q, k, v))
         t0 = time.monotonic()
-        for _ in range(5):
+        for _ in range(ITERS):
             out = f(q, k, v)
         jax.block_until_ready(out)
-        return out, (time.monotonic() - t0) / 5
+        return out, 1e3 * (time.monotonic() - t0) / ITERS
 
-    flash = lambda q, k, v: flash_attention(q, k, v, True)  # noqa: E731
-    fwd = jax.jit(flash)
-    out = jax.block_until_ready(fwd(q, k, v))
-    t0 = time.monotonic()
-    for _ in range(5):
-        o = fwd(q, k, v)
-    jax.block_until_ready(o)
-    fwd_ms = 1e3 * (time.monotonic() - t0) / 5
+    def grads(fn, fused):
+        os.environ["TORCHFT_FLASH_FUSED_BWD"] = "1" if fused else "0"
+        return timed(jax.jit(lambda q, k, v: jax.vjp(fn, q, k, v)[1](
+            g.astype(fn(q, k, v).dtype))))
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, True, window=window)
+
+    out, fwd_ms = timed(jax.jit(flash))
     want_out = jax.jit(plain)(q, k, v)
-    want, _ = run(plain, True)
+    want, _ = grads(plain, True)
 
     def rel(a, b):
         a, b = a.astype(jnp.float32), b.astype(jnp.float32)
         return float(jnp.sqrt(jnp.mean(jnp.square(a - b))
                               / jnp.mean(jnp.square(b))))
 
-    res = {"shape": [1, s, h, hkv, d],
+    res = {"shape": [b, s, h, hkv, d, d_v, window],
            "device": jax.devices()[0].device_kind,
            "fwd_rel": rel(out, want_out), "fwd_ms": fwd_ms}
     for name, fused in (("fused", True), ("split", False)):
-        got, secs = run(flash, fused)
+        got, ms = grads(flash, fused)
         res[name] = {n: rel(a, b) for n, a, b in zip(
             ("dq", "dk", "dv"), got, want)}
-        res[name]["fwd_bwd_ms"] = 1e3 * secs
+        res[name]["fwd_bwd_ms"] = ms
+        res[name]["bwd_ms"] = ms - fwd_ms
     worst = max([res["fwd_rel"]] + [res[n][x] for n in ("fused", "split")
                                     for x in ("dq", "dk", "dv")])
-    res["worst"], res["ok"] = worst, worst <= args.tol
-    print(json.dumps(res), flush=True)
-    return 0 if res["ok"] else 1
+    res["worst"], res["ok"] = worst, worst <= tol
+    return res
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--shape", default="gqa64",
+                    choices=sorted(SHAPES) + ["all"])
+    ap.add_argument("--seq", type=int, default=None,
+                    help="tokens, where not the shape's own (a rehearsal)")
+    ap.add_argument("--root", default=HERE)
+    ap.add_argument("--out", default=None)
+    ap.add_argument("--tol", type=float, default=2e-2)
+    args = ap.parse_args()
+    sys.path.insert(0, os.path.abspath(args.root))
+    ok = True
+    for name in (sorted(SHAPES) if args.shape == "all" else [args.shape]):
+        shape = SHAPES[name]
+        if args.seq:
+            shape = shape[:1] + (args.seq,) + shape[2:]
+        res = {"name": name, "root": args.root, **check(shape, args.tol)}
+        line = json.dumps(res)
+        print(line, flush=True)
+        if args.out:
+            os.makedirs(os.path.dirname(os.path.abspath(args.out)),
+                        exist_ok=True)
+            with open(args.out, "a") as f:
+                f.write(line + "\n")
+        ok = ok and res["ok"]
+    return 0 if ok else 1
 
 
 if __name__ == "__main__":

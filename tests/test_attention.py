@@ -107,8 +107,9 @@ class TestHeadOf64:
     heads on 8 key/value heads: every other configuration runs heads of 128
     to 256. The interpreted kernels (forward, and the split backward's two:
     interpreted, ``_flash_bwd`` always takes them) against the module's own
-    ``_reference`` at a q grid four blocks deep; the chip runs the fused
-    backward at this head size (``scripts/flash_head64_check.py``)."""
+    ``_reference`` at a q grid four blocks deep; the fused backward at
+    this head size is interpreted in ``tests/test_flash_band.py`` and run
+    on the chip by ``scripts/flash_head64_check.py``."""
 
     B, S, H, HKV, D, BLOCK = 1, 256, 32, 8, 64, 64
 
@@ -364,9 +365,9 @@ class TestTransformerWithRing:
 @pytest.mark.nightly
 @pytest.mark.slow
 class TestFusedBwdHardware:
-    """Recurring real-device validation of the fused-bwd dq RMW (the
-    nqb>=4 gate is empirical; interpret mode can't catch a Mosaic
-    pipelining race — see flash_attention.py's safety contract).
+    """Recurring real-device check of the fused backward against the
+    split one (dq held in VMEM across a grid row's sweep: what Mosaic
+    makes of it only a chip shows; ``ops/fused_bwd_check.py``).
 
     Nightly, and slow as well so that it never sits in the per-commit
     tier-1 budget: the child process runs with JAX_PLATFORMS unset and

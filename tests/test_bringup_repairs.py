@@ -74,8 +74,10 @@ def test_interpret_is_decided_in_one_place(explicit, want):
 
 
 def test_interpreted_backward_is_right_on_a_deep_q_grid():
-    """nqb >= 4 is where a compiled kernel takes the fused backward; the
-    interpreter cannot model its aliased dq buffer and must not try."""
+    """nqb >= 4 is where a compiled kernel takes the fused backward; an
+    interpreted call keeps to the split kernels, so what the CPU computes
+    is what it always was (``tests/test_flash_band.py`` interprets the
+    fused kernel, steered)."""
     from torchft_tpu.ops.fused_bwd_check import fused_vs_split
 
     q, k, v = _qkv((1, 64, 2, 16))

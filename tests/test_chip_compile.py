@@ -211,6 +211,57 @@ def test_flash_compiles_at_head_256_on_2_kv_heads(one_chip, monkeypatch,
     assert "bf16[16,8192,256]" in text and "bf16[2,8192,256]" in text
 
 
+# One chip's VMEM (v5e: 128 MiB), which a kernel's ``vmem_limit_bytes``
+# has to stay under.
+VMEM_BYTES = 128 << 20
+#   batch, tokens, q heads, kv heads, d_qk, d_v, window: the cells' shapes
+CELL_SHAPES = {
+    "trinity_full": (1, 8192, 32, 4, 128, 128, None),
+    "trinity_window": (1, 8192, 32, 4, 128, 128, 2048),
+    "joyai_latent": (1, 8192, 32, 32, 192, 128, None),
+    "qwen3_next_head_256": (1, 8192, 16, 2, 256, 256, None),
+    "lfm2_head_64": (1, 8192, 32, 8, 64, 64, None),
+    "mistral_4x4096": (4, 4096, 32, 8, 128, 128, None),
+}
+
+
+@pytest.mark.parametrize("cell", list(CELL_SHAPES), ids=list(CELL_SHAPES))
+def test_fused_backward_keeps_dq_in_vmem_at_the_cells_shapes(one_chip,
+                                                             monkeypatch,
+                                                             cell):
+    """The forward (K and V clamped to the visible band) and the fused
+    backward (one (batch, head)'s dq held in VMEM for its whole sweep) at
+    every shape a cell runs: two Mosaic kernels, the backward asking for
+    the default scoped VMEM plus the resident dq and no more, far under the
+    chip's 128 MiB."""
+    import importlib
+    import re
+
+    fa = importlib.import_module("torchft_tpu.ops.flash_attention")
+    monkeypatch.delenv("TORCHFT_FLASH_FUSED_BWD", raising=False)
+    b, s, h, h_kv, d, d_v, window = CELL_SHAPES[cell]
+    q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((b, s, h_kv, d), jnp.bfloat16,
+                             sharding=one_chip)
+    v = jax.ShapeDtypeStruct((b, s, h_kv, d_v), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False,
+                               window=window).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, k, v).compile().as_text()
+    assert text.count("tpu_custom_call") == 2   # forward, fused backward
+    asked = [int(n) for n in re.findall(
+        r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', text)]
+    want = fa._fused_vmem_limit(s, d, 2)
+    assert want == fa._SCOPED_VMEM_BYTES + fa._dq_resident_bytes(s, d, 2)
+    assert max(asked) == want < VMEM_BYTES // 2
+    # the forward, and XLA's own fusions, keep to the default
+    assert set(asked) <= {fa._SCOPED_VMEM_BYTES, want}
+
+
 @pytest.mark.parametrize("tokens", [8192, 8192 + 96], ids=["8k", "ragged"])
 def test_gated_delta_rule_compiles_at_the_published_sizes(one_chip, tokens):
     """The chunked scan forward and backward at 16 key heads, 32 value heads

@@ -2,7 +2,7 @@
 and keys ``d_qk`` wide, values ``d_v``, as latent attention has them):
 forward and gradients against masked softmax at 192/128 and 96/64, with
 shared key/value heads and with more keys than queries, through the split
-backward and through the fused one; one head size computes what the parent
+backward and through the fused one (four and eight key blocks); one head size computes what the parent
 commit computed, bit for bit; the kernels' names; the plain attention, the
 sharded wrapper and the padding path take the two sizes."""
 
@@ -91,12 +91,11 @@ FUSED = {"192_128": (256, 256, 2, 2, 192, 128),
 def test_fused_backward_at_two_head_sizes(case, monkeypatch):
     """The fused backward kernel, which off a chip only runs when steered:
     every ``pallas_call`` of the backward is made interpreted from here
-    while ``_flash_bwd`` is told it compiles. The interpreter gives the
-    aliased dq its input as zeros at every key block, so dq is whole only
-    with ONE key block: four query blocks (the fused path's gate) against
-    one key block. dk and dv accumulate in scratch and are whole anyway."""
+    while ``_flash_bwd`` is told it compiles. Four and eight key blocks
+    against four query blocks: dq stays in the kernel's VMEM accumulator
+    from one key block's sweep to the next (no aliased buffer for the
+    interpreter to get wrong), dk and dv accumulate in scratch."""
     q, k, v, g = inputs(*FUSED[case])
-    s_k = k.shape[1]
     real = fa.pl.pallas_call
     names = []
 
@@ -104,10 +103,10 @@ def test_fused_backward_at_two_head_sizes(case, monkeypatch):
         names.append(kw.get("name"))
         return real(*a, **{**kw, "interpret": True})
 
-    out, lse = fa._flash_fwd(q, k, v, True, BLOCK, s_k, True)
+    out, lse = fa._flash_fwd(q, k, v, True, BLOCK, BLOCK, True)
     monkeypatch.setattr(fa.pl, "pallas_call", interpreted)
     monkeypatch.delenv("TORCHFT_FLASH_FUSED_BWD", raising=False)
-    dq, dk, dv = fa._flash_bwd(q, k, v, out, lse, g, True, BLOCK, s_k,
+    dq, dk, dv = fa._flash_bwd(q, k, v, out, lse, g, True, BLOCK, BLOCK,
                                interpret=False)
     assert names == ["flash_bwd_mla"]
     _, vjp_ref = jax.vjp(masked_softmax, q, k, v)

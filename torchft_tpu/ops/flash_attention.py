@@ -7,9 +7,9 @@ accumulators with bf16 inputs. This is new scope relative to the reference
 because long-context is first-class in the TPU build and the plain
 attention in :mod:`torchft_tpu.models.transformer` is HBM-bound at long S.
 
-Two structural choices shape the kernels. Their timings predate the
-installed jax/libtpu and have not been re-measured on an attached chip;
-the reasons are what the code relies on:
+Three structural choices shape the kernels (timings: a TPU v5e, jax
+0.9.0, libtpu 0.0.34, the kernels alone at the benchmark cells' shapes by
+``scripts/flash_head64_check.py --shape all``; PERF.md, Findings, PR 48):
 
 1. **Interior blocks skip the mask entirely.** The kernel is VPU-bound
    (per block the softmax's element passes outweigh the two matmuls' MXU
@@ -19,20 +19,34 @@ the reasons are what the code relies on:
    diagonal-adjacent blocks, plain for interior), so only ~nqb of the
    ~nqb^2/2 computed blocks pay for masking. (Hoisting the mask behind a
    per-tile lax.cond *inside* one body serializes, and lost.)
-2. **Fused backward** (_bwd_fused_kernel): dq does not run as a separate
+2. **A masked block moves no bytes.** The grid is the whole
+   (q-block, k-block) rectangle and a block right of the diagonal or left
+   of the window computes nothing (:func:`_block_visibility`, on the grid
+   index); the index map of every input such a step does not read stops
+   at the row's visible band (:func:`_visible_band`, from the same
+   inequalities), so a run of skipped steps names the neighbouring visible
+   block again and Pallas, which copies a tile only when its block index
+   changes, fetches nothing: 28 of 64 steps causal at 8,192 tokens, 43 of
+   64 under a window of 2,048, 120 of 256 at 512-token tiles. Kernels
+   under a traced ``shift`` (ring attention) keep the plain maps: their
+   visibility is data.
+3. **Fused backward** (_bwd_fused_kernel): dq does not run as a separate
    kernel recomputing (logits, p, dp, ds) — one kernel does 5 matmuls +
-   1 exp per block instead of the split path's 7 + 2, with dq
-   accumulated across the outer k-grid via an aliased read-modify-write
-   HBM buffer. Checked against the split path on hardware by
+   1 exp per block instead of the split path's 7 + 2, with one
+   (batch, head)'s whole dq accumulated in an f32 VMEM scratch across the
+   outer k-grid and written out once, in q's dtype, at the grid row's
+   last step (no HBM buffer read back, so nothing for the pipeline to
+   race). Where that accumulator would not fit its VMEM budget the split
+   kernels run. Checked against the split path on hardware by
    ``fused_bwd_check`` (run by ``chip_smoke.py``);
    TORCHFT_FLASH_FUSED_BWD=0 falls back.
 
-Tiles default to the largest power of two <= 1024 dividing the sequence.
-Head_dim matters more than tiles: d=128 fills the MXU contraction, d=64
-halves it. An exp2-domain rewrite (log2(e) folded into the logit scale)
-was tried and reverted: Mosaic already lowers jnp.exp to the hardware
-exp2 with the multiply fused. Throughput and roofline share: not
-measured on an attached chip (ROADMAP S7).
+Tiles default to the largest power of two <= 1024 dividing the sequence
+(512 at a head over 128). Head_dim matters more than tiles: d=128 fills
+the MXU contraction, d=64 halves it, d=192 pads to 256. An exp2-domain
+rewrite (log2(e) folded into the logit scale) was tried and reverted:
+Mosaic already lowers jnp.exp to the hardware exp2 with the multiply
+fused.
 
 Kernel structure: grid (batch*heads, q_blocks, k_blocks). The innermost
 (k) grid dimension is sequential on a TPU core, so the running
@@ -120,6 +134,73 @@ def _visible(q_pos, k_pos, window):
     if window is None:
         return q_pos >= k_pos
     return jnp.logical_and(q_pos >= k_pos, q_pos - k_pos < window)
+
+
+def _visible_band(idx, bq: int, bk: int, n: int, offset: int,
+                  window: Optional[int], keys: bool):
+    """First and last visible block along the inner grid axis under a
+    STATIC causal/window mask: :func:`_block_visibility`'s ``diag_ok``
+    solved for the inner index. ``keys=True``: ``idx`` is a query block and
+    the range is over the ``n`` key blocks (forward, split dq);
+    ``keys=False``: ``idx`` is a key block and the range is over the ``n``
+    query blocks (dk/dv, fused backward). Visible blocks are exactly
+    ``first..last``, contiguous; ``last < first`` says the row sees nothing
+    (key blocks left of every query's window, only with ``s_k > s_q``),
+    and ``first`` is a valid block index even then. Python ints or traced
+    scalars (an index map's)."""
+    if keys:
+        b = bk
+        lo = 0 if window is None else (idx * bq + offset - window + 1) // b
+        hi = (idx * bq + bq - 1 + offset) // b
+    else:
+        b = bq
+        lo = (idx * bk - offset) // b
+        hi = (n - 1 if window is None
+              else (window + idx * bk + bk - 2 - offset) // b)
+    if isinstance(idx, int):
+        return max(lo, 0), min(hi, n - 1)
+    return jnp.maximum(lo, 0), jnp.minimum(hi, n - 1)
+
+
+def _band_clamp(causal: bool, dynamic_shift: bool, bq: int, bk: int,
+                n: int, offset: int, window: Optional[int], keys: bool):
+    """``clamp(outer, inner)`` for the index map of an input that a wholly
+    masked step does not read: the inner block index held inside the outer
+    block's visible band, so a run of skipped steps names the neighbouring
+    visible block again and Pallas, which fetches only when a block index
+    changes, moves nothing. The kernels' bodies keep deciding by
+    :func:`_block_visibility` on the GRID index. Without a static mask
+    (non-causal; a traced shift, whose visibility is data) the index is
+    the grid's own."""
+    if not causal or dynamic_shift:
+        return lambda outer, inner: inner
+
+    def clamp(outer, inner):
+        lo, hi = _visible_band(outer, bq, bk, n, offset, window, keys)
+        return jnp.clip(inner, lo, jnp.maximum(hi, lo))
+    return clamp
+
+
+def _count_grid_steps(bh: int, n_outer: int, n_inner: int, causal: bool,
+                      dynamic_shift: bool, bq: int, bk: int, offset: int,
+                      window: Optional[int], keys: bool, **also: int) -> None:
+    """Trace-time counters of one ``pallas_call``, added on the host as
+    the head loss's are (``tracing.add_program_counters``): its grid steps,
+    batch x heads included, and those of them wholly masked by a static
+    mask, which move no bytes. Under a traced shift none is counted as
+    skipped: which are is data. ``also``: counters of the same call
+    (which backward it is)."""
+    from torchft_tpu import tracing
+
+    skipped = 0
+    if causal and not dynamic_shift:
+        for idx in range(n_outer):
+            lo, hi = _visible_band(idx, bq, bk, n_inner, offset, window,
+                                   keys)
+            skipped += n_inner - max(hi - lo + 1, 0)
+    tracing.add_program_counters(
+        flash_grid_steps_traced_total=bh * n_outer * n_inner,
+        flash_skipped_steps_traced_total=bh * skipped, **also)
 
 
 def _dual_instantiate(compute, causal, shift_ref, diag_ok, full_vis):
@@ -307,11 +388,18 @@ def _flash_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         return (bh // h) * h_kv + (bh % h) // rep
 
     grid = (b * h, s // block_q, nkb)
+    # K and V stop at the query block's visible band: a wholly masked
+    # step re-names a neighbouring visible tile and fetches nothing.
+    kj = _band_clamp(causal, dynamic_shift, block_q, block_k, nkb, sk - s,
+                     window, keys=True)
+    _count_grid_steps(*grid, causal, dynamic_shift, block_q, block_k,
+                      sk - s, window, keys=True)
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
-        pl.BlockSpec((1, block_k, d), lambda bh, i, j: (kv_row(bh), j, 0)),
+        pl.BlockSpec((1, block_k, d),
+                     lambda bh, i, j: (kv_row(bh), kj(i, j), 0)),
         pl.BlockSpec((1, block_k, d_v),
-                     lambda bh, i, j: (kv_row(bh), j, 0)),
+                     lambda bh, i, j: (kv_row(bh), kj(i, j), 0)),
     ]
     inputs = [qh, kh, vh]
     if dynamic_shift:
@@ -478,7 +566,7 @@ def _bwd_dkdv_kernel(*refs, causal: bool, scale: float, nqb: int,
 
 
 def _bwd_fused_kernel(*refs, causal: bool, scale: float, nqb: int,
-                      offset: int, dynamic_shift: bool,
+                      nkb: int, offset: int, dynamic_shift: bool,
                       window: Optional[int] = None):
     """One backward kernel for dq, dk AND dv.
 
@@ -488,20 +576,22 @@ def _bwd_fused_kernel(*refs, causal: bool, scale: float, nqb: int,
     does 5 matmuls + 1 exp instead of the split path's 7 matmuls + 2 exps.
 
     Grid (bh, ki, qi): dk/dv accumulate in VMEM scratch across the inner
-    qi sweep (as before); dq accumulates ACROSS the outer ki dimension
-    through an HBM read-modify-write — the dq buffer is passed as both
-    input and output (input_output_aliases) and every step writes
-    ``dq_out = dq_in + contribution``. The write of (ki, qi)'s dq block
-    and the prefetch of (ki+1, qi)'s are nqb steps apart, so the pipeline
-    never races a block against itself; _flash_bwd gates the fused path
-    on nqb >= 4 and falls back to the split kernels below it.
+    qi sweep; dq accumulates ACROSS the outer ki dimension in ``dq_acc``,
+    an f32 ``[s, d]`` scratch that holds the whole sequence of one
+    (batch, head) on the chip for that grid row's (ki, qi) sweep. A
+    computed step adds into its ``bq`` rows (key blocks ascending: the
+    split dq kernel's order), a skipped step touches nothing, and the
+    row's last step writes dq out once, in q's dtype: the output block is
+    the row's whole ``[s, d]``, whose index changes only with ``bh``, so
+    it leaves VMEM once a row. No HBM buffer is read back, so there is
+    nothing for the pipeline to race and no grid depth it needs.
     """
     if dynamic_shift:
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_in, shift_ref, \
-            dk_ref, dv_ref, dq_ref, dk_acc, dv_acc = refs
+        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, shift_ref, \
+            dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, dq_acc = refs
     else:
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_in, \
-            dk_ref, dv_ref, dq_ref, dk_acc, dv_acc = refs
+        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, \
+            dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, dq_acc = refs
         shift_ref = None
     ki = pl.program_id(1)
     qi = pl.program_id(2)
@@ -512,6 +602,10 @@ def _bwd_fused_kernel(*refs, causal: bool, scale: float, nqb: int,
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    @pl.when(jnp.logical_and(ki == 0, qi == 0))
+    def _init_row():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
 
     diag_ok, full_vis = _block_visibility(
         qi, ki, bq, bk, offset, causal, shift_ref, window)
@@ -525,23 +619,47 @@ def _bwd_fused_kernel(*refs, causal: bool, scale: float, nqb: int,
                              preferred_element_type=jnp.float32)
         dk_acc[:] += jnp.dot(ds.astype(q.dtype).T, q,
                              preferred_element_type=jnp.float32) * scale
-        dq_ref[0] = dq_in[0] + jnp.dot(
+        rows = pl.ds(pl.multiple_of(qi * bq, bq), bq)
+        dq_acc[rows, :] += jnp.dot(
             ds.astype(k.dtype), k,
             preferred_element_type=jnp.float32) * scale
 
     _dual_instantiate(_compute, causal, shift_ref, diag_ok, full_vis)
 
-    if causal or dynamic_shift:
-        # Skipped block: the dq out-window still gets copied back to HBM,
-        # so it must carry the running value through unchanged.
-        @pl.when(jnp.logical_not(diag_ok))
-        def _passthrough():
-            dq_ref[0] = dq_in[0]
-
     @pl.when(qi == nqb - 1)
     def _finalize():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+    @pl.when(jnp.logical_and(ki == nkb - 1, qi == nqb - 1))
+    def _finalize_row():
+        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+
+
+# The fused backward holds one (batch, head)'s dq in VMEM: an f32 [s, d]
+# accumulator and the double-buffered [s, d] output block in q's dtype,
+# each row padded to whole 128-lane tiles (4 + 2 x 2 MiB at 8192 x 128 in
+# bf16, the same at a head of 64, 8 + 2 x 4 at 192 and at 256) of the
+# chip's 128 MiB. Over this many accumulator bytes the split kernels run.
+_DQ_RESIDENT_BYTES = 16 << 20
+# What a kernel may use beside that: Mosaic's own default scoped limit,
+# which the kernels' tiles and score buffers are sized to at bf16 operands
+# (see _flash_fwd).
+_SCOPED_VMEM_BYTES = 16 << 20
+
+
+def _dq_resident_bytes(s: int, d: int, itemsize: int = 0) -> int:
+    """VMEM bytes of one row's f32 dq accumulator and, with an
+    ``itemsize``, of its two output buffers as well."""
+    return s * (-(-d // _LANES) * _LANES) * (4 + 2 * itemsize)
+
+
+def _fused_vmem_limit(s: int, d: int, itemsize: int) -> int:
+    """``vmem_limit_bytes`` of the fused backward: the scoped default for
+    its tiles (as wide as the operands: f32 takes twice bf16's) and the
+    resident dq."""
+    return (_SCOPED_VMEM_BYTES * max(itemsize // 2, 1)
+            + _dq_resident_bytes(s, d, itemsize))
 
 
 def _flash_bwd(q, k, v, out, lse, g, causal: bool, block_q: Optional[int],
@@ -582,27 +700,30 @@ def _flash_bwd(q, k, v, out, lse, g, causal: bool, block_q: Optional[int],
 
     dynamic_shift = shift is not None
     _check_window(window, causal, dynamic_shift)
+    band = (causal, dynamic_shift, block_q, block_k)
+    # (bh, q-block, k-block) grid order, the split dq kernel's: K and V
+    # stop at the query block's visible band (see _flash_fwd).
+    kj = _band_clamp(*band, nkb, offset, window, keys=True)
     q_spec = pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0))
     do_spec = pl.BlockSpec((1, block_q, d_v), lambda bh, i, j: (bh, i, 0))
     k_spec = pl.BlockSpec((1, block_k, d),
-                          lambda bh, i, j: (kv_row(bh), j, 0))
+                          lambda bh, i, j: (kv_row(bh), kj(i, j), 0))
     v_spec = pl.BlockSpec((1, block_k, d_v),
-                          lambda bh, i, j: (kv_row(bh), j, 0))
+                          lambda bh, i, j: (kv_row(bh), kj(i, j), 0))
     row_spec = pl.BlockSpec((1, block_q, _LANES),
                             lambda bh, i, j: (bh, i, 0))
 
     in_specs = [q_spec, k_spec, v_spec, do_spec, row_spec, row_spec]
     inputs = [qh, kh, vh, doh, lse_l, delta_l]
-    if dynamic_shift:
-        shift_arr = jnp.broadcast_to(
-            jnp.asarray(shift, jnp.int32).reshape(1, 1), (1, _LANES))
-        in_specs.append(pl.BlockSpec((1, _LANES), lambda bh, i, j: (0, 0)))
-        inputs.append(shift_arr)
 
     # Specs in (bh, k-block, q-block) grid order + output reshapers,
-    # shared by the fused kernel and the split dk/dv kernel.
-    q_spec2 = pl.BlockSpec((1, block_q, d), lambda bh, j, i: (bh, i, 0))
-    do_spec2 = pl.BlockSpec((1, block_q, d_v), lambda bh, j, i: (bh, i, 0))
+    # shared by the fused kernel and the split dk/dv kernel: q, do and the
+    # two row statistics stop at the key block's visible band.
+    qi_ = _band_clamp(*band, nqb, offset, window, keys=False)
+    q_spec2 = pl.BlockSpec((1, block_q, d),
+                           lambda bh, j, i: (bh, qi_(j, i), 0))
+    do_spec2 = pl.BlockSpec((1, block_q, d_v),
+                            lambda bh, j, i: (bh, qi_(j, i), 0))
     k_in_spec2 = pl.BlockSpec((1, block_k, d),
                               lambda bh, j, i: (kv_row(bh), j, 0))
     v_in_spec2 = pl.BlockSpec((1, block_k, d_v),
@@ -611,7 +732,16 @@ def _flash_bwd(q, k, v, out, lse, g, causal: bool, block_q: Optional[int],
     v_out_spec2 = pl.BlockSpec((1, block_k, d_v),
                                lambda bh, j, i: (bh, j, 0))
     row_spec2 = pl.BlockSpec((1, block_q, _LANES),
-                             lambda bh, j, i: (bh, i, 0))
+                             lambda bh, j, i: (bh, qi_(j, i), 0))
+    in_specs2 = [q_spec2, k_in_spec2, v_in_spec2, do_spec2, row_spec2,
+                 row_spec2]
+    if dynamic_shift:
+        # Traced mask selector, one scalar riding a [1, LANES] i32 tile
+        # (an index map ignores its argument names: one spec, both grids).
+        shift_spec = pl.BlockSpec((1, _LANES), lambda bh, i, j: (0, 0))
+        in_specs, in_specs2 = in_specs + [shift_spec], in_specs2 + [shift_spec]
+        inputs.append(jnp.broadcast_to(
+            jnp.asarray(shift, jnp.int32).reshape(1, 1), (1, _LANES)))
 
     def from_bh(x, seq):
         return x.reshape(b, h, seq, x.shape[-1]).transpose(0, 2, 1, 3)
@@ -628,61 +758,50 @@ def _flash_bwd(q, k, v, out, lse, g, causal: bool, block_q: Optional[int],
             return from_bh(dq, s), from_bh(dk, sk), from_bh(dv, sk)
         return from_bh(dq, s), kv_from_bh(dk, sk), kv_from_bh(dv, sk)
 
-    # Fused backward (dq+dk+dv in one kernel, one recompute per block)
-    # whenever the q-grid is deep enough for the dq read-modify-write to
-    # be pipeline-safe (see _bwd_fused_kernel); the split kernels below
-    # remain the short-sequence fallback. TORCHFT_FLASH_FUSED_BWD=0 is
-    # the operational kill-switch back to the split kernels.
-    #
-    # SAFETY CONTRACT for the nqb >= 4 gate: the dq accumulation relies on
-    # input_output_aliases HBM read-modify-write whose correctness depends
-    # on Mosaic's write-back-vs-prefetch distance along the innermost (q)
-    # grid axis. nqb >= 4 is an EMPIRICAL margin (measured safe on v5e at
-    # block_q=512), not a documented Pallas guarantee, and interpret-mode
-    # tests cannot catch a real-device race. Revisit whenever (a) jaxlib /
-    # libtpu is upgraded, (b) block_q or the grid order changes, or (c) a
-    # new tile shape is enabled — by running the hardware split-vs-fused
-    # comparison (tests/test_attention.py::TestFusedBwdHardware, marked
-    # `nightly`; skips without a TPU) which re-validates dq on every
-    # nightly TPU run rather than as a one-off.
-    #
-    # Interpret mode always takes the split kernels: the interpreter gives
-    # the aliased dq input and output separate buffers, so the fused
-    # kernel's read-modify-write would read zeros and return only the
-    # last k-block's dq (seen on jax 0.9.0 at every shape with nqb >= 4).
+    def count(n_outer, n_inner, keys, **also):
+        _count_grid_steps(b * h, n_outer, n_inner, *band, offset, window,
+                          keys, **also)
+
+    # Which backward runs follows from the shapes. The fused kernel (dq,
+    # dk and dv from one recompute a block) where the q grid is four or
+    # more blocks deep (below that the split kernels are what the cells'
+    # one-block programs were measured on) and one (batch, head)'s f32 dq
+    # fits the VMEM set aside for it; the split kernels otherwise, and
+    # under TORCHFT_FLASH_FUSED_BWD=0. Interpreted calls take the split
+    # kernels too, so what the CPU computes stays what it was: the fused
+    # kernel has nothing an interpreter cannot model, and
+    # tests/test_flash_band.py runs it interpreted.
     import os
     fused_ok = os.environ.get("TORCHFT_FLASH_FUSED_BWD", "1") != "0"
-    if nqb >= 4 and fused_ok and not interpret:
-        in_specs2 = [q_spec2, k_in_spec2, v_in_spec2, do_spec2, row_spec2,
-                     row_spec2, q_spec2]
-        inputs2 = [qh, kh, vh, doh, lse_l, delta_l,
-                   jnp.zeros((b * h, s, d), jnp.float32)]
-        if dynamic_shift:
-            in_specs2.append(
-                pl.BlockSpec((1, _LANES), lambda bh, j, i: (0, 0)))
-            inputs2.append(shift_arr)
+    if (nqb >= 4 and fused_ok and not interpret
+            and _dq_resident_bytes(s, d) <= _DQ_RESIDENT_BYTES):
+        count(nkb, nqb, keys=False, flash_dq_resident_traces_total=1)
         dk, dv, dq = pl.pallas_call(
             functools.partial(_bwd_fused_kernel, causal=causal,
-                              scale=scale, nqb=nqb, offset=offset,
+                              scale=scale, nqb=nqb, nkb=nkb, offset=offset,
                               dynamic_shift=dynamic_shift, window=window),
             out_shape=[
                 jax.ShapeDtypeStruct((b * h, sk, d), k.dtype),
                 jax.ShapeDtypeStruct((b * h, sk, d_v), v.dtype),
-                jax.ShapeDtypeStruct((b * h, s, d), jnp.float32),
+                jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
             ],
             grid=(b * h, nkb, nqb),
             in_specs=in_specs2,
-            out_specs=[k_out_spec2, v_out_spec2, q_spec2],
+            out_specs=[k_out_spec2, v_out_spec2,
+                       pl.BlockSpec((1, s, d), lambda bh, j, i: (bh, 0, 0))],
             scratch_shapes=[
                 pltpu.VMEM((block_k, d), jnp.float32),
                 pltpu.VMEM((block_k, d_v), jnp.float32),
+                pltpu.VMEM((s, d), jnp.float32),   # the row's whole dq
             ],
-            input_output_aliases={6: 2},  # dq buffer: read-modify-write
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=_fused_vmem_limit(s, d, q.dtype.itemsize)),
             interpret=interpret,
             name=_kernel_name("flash_bwd", window, latent),
-        )(*inputs2)
-        return pack(dq.astype(q.dtype), dk, dv)
+        )(*inputs)
+        return pack(dq, dk, dv)
 
+    count(nqb, nkb, keys=True, flash_dq_split_traces_total=1)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, causal=causal, scale=scale,
                           nkb=nkb, offset=offset,
@@ -700,12 +819,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal: bool, block_q: Optional[int],
     # Outputs are per QUERY head (each grid row writes its own block, no
     # cross-row accumulation hazards); GQA reduces over the rep query
     # heads sharing a kv head afterwards, outside the kernel.
-    in_specs2 = [q_spec2, k_in_spec2, v_in_spec2, do_spec2, row_spec2,
-                 row_spec2]
-    inputs2 = [qh, kh, vh, doh, lse_l, delta_l]
-    if dynamic_shift:
-        in_specs2.append(pl.BlockSpec((1, _LANES), lambda bh, j, i: (0, 0)))
-        inputs2.append(shift_arr)
+    count(nkb, nqb, keys=False)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkdv_kernel, causal=causal, scale=scale,
                           nqb=nqb, offset=offset,
@@ -723,7 +837,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal: bool, block_q: Optional[int],
         ],
         interpret=interpret,
         name=_kernel_name("flash_bwd_dkdv", window, latent),
-    )(*inputs2)
+    )(*inputs)
 
     return pack(dq, dk, dv)
 

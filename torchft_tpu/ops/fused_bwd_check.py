@@ -1,14 +1,15 @@
-"""Hardware re-validation of the fused flash-attention backward.
+"""Hardware check of the fused flash-attention backward.
 
-The fused backward's dq accumulation is an HBM read-modify-write through
-``input_output_aliases`` whose safety rests on Mosaic's write-back vs
-prefetch distance — an empirical property (the ``nqb >= 4`` gate in
-``flash_attention.py``), not a documented guarantee, and one that
-interpret-mode tests can never exercise. This module is the recurring
-real-device check the gate's comment promises: it runs the SAME backward
-twice on hardware — fused (``TORCHFT_FLASH_FUSED_BWD=1``) and split
-(``=0``) — and compares dq/dk/dv. A pipelining race corrupts dq by whole
-tiles, so any mismatch beyond last-ulp accumulation noise fails loudly.
+The fused backward computes dq, dk and dv from one recompute a block and
+holds one (batch, head)'s dq in a VMEM accumulator for the whole sweep of
+its grid row (``flash_attention.py`` ``_bwd_fused_kernel``); the split
+backward is two kernels that each recompute. Off a chip the fused kernel
+runs only interpreted and steered (``tests/test_flash_band.py``): what
+Mosaic makes of it, the dynamic row slice into the accumulator and the
+VMEM the call asks for, only a chip shows. This module runs the SAME
+backward twice on hardware, fused (``TORCHFT_FLASH_FUSED_BWD=1``) and
+split (``=0``), and compares dq/dk/dv: both accumulate in f32 over the
+same key-block order, so anything beyond last-ulp noise is a fault.
 
 Exit codes of ``python -m torchft_tpu.ops.fused_bwd_check``: 0 = match,
 75 = no TPU backend (the caller decides whether that is a skip or a
@@ -30,9 +31,9 @@ from typing import Dict, Optional, Tuple
 SKIP = 75
 
 
-# Both paths accumulate dq in f32 over the same k-block order; a pipelining
-# race corrupts whole tiles (rel ~ O(1)). 1e-3 leaves room for bf16
-# recompute noise while catching any real corruption.
+# Both paths accumulate dq in f32 over the same k-block order; a lost or
+# doubled tile is rel ~ O(1). 1e-3 leaves room for bf16 recompute noise
+# while catching any real corruption.
 TOLERANCE = 1e-3
 
 
@@ -42,10 +43,8 @@ def _grads(q, k, v, use_fused: bool, block: int, interpret: Optional[bool]):
     from torchft_tpu.ops.flash_attention import flash_attention
 
     def loss(q, k, v):
-        # The block is pinned explicitly: block_q=512 is the tile shape the
-        # gate's safety contract documents as measured-safe (auto-pick
-        # would choose block_q=1024 → nqb=4, validating a different shape
-        # than the one the contract names).
+        # The block is pinned explicitly, so the q grid is eight deep at
+        # the default shape (auto-pick would choose block_q=1024: four).
         return flash_attention(q, k, v, causal=True, block_q=block,
                                block_k=block,
                                interpret=interpret).astype("float32").sum()
@@ -100,8 +99,9 @@ def main() -> int:
     for name in ("dq", "dk", "dv"):
         print(f"fused_bwd_check: {name} rel={rel[name]:.3e}")
     if rel["worst"] > TOLERANCE:
-        print("fused_bwd_check: MISMATCH — possible dq RMW race; set "
-              "TORCHFT_FLASH_FUSED_BWD=0 and investigate", file=sys.stderr)
+        print("fused_bwd_check: MISMATCH: the fused kernel's dq, dk or dv "
+              "is not the split kernels'; set TORCHFT_FLASH_FUSED_BWD=0 "
+              "and investigate", file=sys.stderr)
         return 1
     print("fused_bwd_check: OK (fused == split on hardware)")
     return 0

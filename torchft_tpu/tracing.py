@@ -120,7 +120,11 @@ def default_trace_steps() -> int:
 # needs no output at all: the head's loss adds
 # ``head_loss_fused_traces_total`` (one each time its fused gradient rule is
 # traced) and ``head_loss_chunks_traced_total`` (the chunks of each such
-# loss; their ratio is the chunks a loss) on the host, then and there.
+# loss; their ratio is the chunks a loss) on the host, then and there, and
+# ``ops/flash_attention.py`` adds each traced kernel's grid steps and those
+# a static mask skips (``flash_grid_steps_traced_total``,
+# ``flash_skipped_steps_traced_total``) and which backward a call took
+# (``flash_dq_resident_traces_total`` / ``flash_dq_split_traces_total``).
 # ``program_callbacks_total`` counted the runs of the callback that carried
 # the counts until PR 43; nothing adds to it any more, and it stays in the
 # schema at 0.0 until the benchmark's metric that reads it is retired.
