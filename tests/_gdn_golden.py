@@ -1,10 +1,12 @@
-"""What ``tests/golden_gdn_pr44.json`` holds and how it is made: a small
+"""What ``tests/golden_gdn_pr49.json`` holds and how it is made: a small
 ``GatedDeltaNet`` (float32 and bfloat16) on seeded weights and inputs, its
 output and every gradient as two wrapping 32-bit sums of the bit patterns.
-The file was written by running this module against the commit before PR 45
-(``python tests/_gdn_golden.py <file>`` with that checkout first on
-``sys.path``); ``tests/test_ssd.py`` computes the same with the tree's own
-layer."""
+The file was written by running this module on PR 49's tree
+(``python tests/_gdn_golden.py <file>``), whose rule runs its recurrence
+and its chunk inverse in Pallas kernels, interpreted on the CPU;
+``tests/test_ssd.py`` computes the same with the tree's own layer. (Until
+PR 49 the file was ``golden_gdn_pr44.json``, from the commit before PR 45:
+the ``lax.scan`` and XLA's substitution, other roundings in the last bits.)"""
 
 import json
 import sys

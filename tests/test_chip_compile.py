@@ -262,13 +262,28 @@ def test_fused_backward_keeps_dq_in_vmem_at_the_cells_shapes(one_chip,
     assert set(asked) <= {fa._SCOPED_VMEM_BYTES, want}
 
 
+def _compiled_delta_rule(monkeypatch):
+    """``gated_delta_rule`` decides by the backend whether its kernels run
+    interpreted, and the backend here is the CPU: steered in the test."""
+    import importlib
+
+    gd = importlib.import_module("torchft_tpu.ops.gated_delta")
+    monkeypatch.setattr(gd, "_resolve_interpret", lambda _: False)
+    return gd
+
+
 @pytest.mark.parametrize("tokens", [8192, 8192 + 96], ids=["8k", "ragged"])
-def test_gated_delta_rule_compiles_at_the_published_sizes(one_chip, tokens):
-    """The chunked scan forward and backward at 16 key heads, 32 value heads
-    of 128 (``ops/gated_delta.py``; XLA loops and fusions, no kernel): the
-    triangular solve of the [64, 64] blocks and the scan lower for the chip,
-    and the state the loop carries is float32 [1, 32, 128, 128]."""
-    from torchft_tpu.ops import gated_delta_rule
+def test_gated_delta_rule_compiles_at_the_published_sizes(one_chip, tokens,
+                                                          monkeypatch):
+    """The rule forward and backward at 16 key heads, 32 value heads of 128
+    (``ops/gated_delta.py``): three Mosaic kernels, the chunk inverse
+    (``gdn_inv``: substitution, so no triangular solve) and the chunk
+    recurrence (``gdn_fwd`` keeping the states, ``gdn_bwd``: no loop
+    carries the state ``f32[1,32,128,128]``), and the recurrence's kernels
+    ask for the VMEM they are written to and are given it."""
+    import re
+
+    gd = _compiled_delta_rule(monkeypatch)
 
     def shaped(*shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -277,11 +292,27 @@ def test_gated_delta_rule_compiles_at_the_published_sizes(one_chip, tokens):
             shaped(1, tokens, 32, 128),
             shaped(1, tokens, 32, dtype=jnp.float32),
             shaped(1, tokens, 32, dtype=jnp.float32))
-    c = jax.jit(jax.grad(lambda *a: gated_delta_rule(*a).sum(),
+    c = jax.jit(jax.grad(lambda *a: gd.gated_delta_rule(*a).sum(),
                          argnums=(0, 1, 2, 3, 4))).lower(*args).compile()
     text = c.as_text()
-    assert "while(" in text and "f32[1,32,128,128]" in text
-    assert "tpu_custom_call" not in text
+    kernels = {name: line for line in text.splitlines()
+               if "tpu_custom_call" in line
+               for name in re.findall(r"gdn_(?:inv|fwd|bwd)", line)[:1]}
+    assert sorted(kernels) == ["gdn_bwd", "gdn_fwd", "gdn_inv"]
+    assert text.count("tpu_custom_call") == 3
+    assert "while(" not in text and "f32[1,32,128,128]" not in text
+    assert "riangular" not in text
+    chunks = -(-tokens // 64)
+    assert f"f32[32,{chunks},128,128]" in kernels["gdn_fwd"]   # the states
+    lanes = -(-32 * chunks // 128) * 128        # whole vectors of matrices
+    assert f"f32[64,64,{lanes}]" in kernels["gdn_inv"]
+    assert gd._heads_a_step(32, 128, 128, 2) == 16
+    for name in ("gdn_fwd", "gdn_bwd"):
+        asked = re.findall(
+            r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"',
+            kernels[name])
+        assert asked == [str(gd._VMEM_LIMIT_BYTES)]
+    assert gd._TILE_BYTES < gd._VMEM_LIMIT_BYTES < VMEM_BYTES // 2
     assert _footprint(c) < 3 * 2**30
 
 
@@ -465,7 +496,9 @@ CELL_STEPS = {
     "trinity-mini.steady-1g-8k": ("flash_fwd_window", "gmm"),
     "joyai-llm-flash.steady-1g-8k": ("flash_fwd_mla", "flash_bwd_mla",
                                      "gmm"),
-    "qwen3-next-80b-a3b.steady-1g-8k": ("%attn", "gmm", "f32[1,32,128,128]",
+    "qwen3-next-80b-a3b.steady-1g-8k": ("%attn", "gmm", "%gdn_fwd",
+                                        "%gdn_bwd", "%gdn_inv",
+                                        "f32[32,128,128,128]",
                                         "bf16[16,8192,256]"),
     "nemotron-3-nano-30b-a3b.steady-1g-8k": ("%attn", "gmm",
                                              "8,8,64,64,128]",
@@ -484,7 +517,7 @@ SAMPLE_ROOM = {"nemotron-3-nano-30b-a3b.steady-1g-8k": int(0.3 * GiB),
 
 
 @pytest.mark.parametrize("name", list(CELL_STEPS), ids=list(CELL_STEPS))
-def test_sparse_cells_step_fits_the_chip(one_chip, name):
+def test_sparse_cells_step_fits_the_chip(one_chip, name, monkeypatch):
     """A sparse configuration's 8k cell as ``benchmarks/`` builds it: the
     fused one-group step (not donated) at the published widths, the cut's
     layers and held experts and the cell's own batch of 8192-token
@@ -511,6 +544,7 @@ def test_sparse_cells_step_fits_the_chip(one_chip, name):
         sys.path.insert(0, bench)
     from harness import spec
 
+    _compiled_delta_rule(monkeypatch)
     cell = spec.Cell(name)
     cfg, seq, batch = (cell.config, int(cell.mix["seq"]),
                        int(cell.mix["batch_per_group"]))
@@ -529,5 +563,6 @@ def test_sparse_cells_step_fits_the_chip(one_chip, name):
     text = c.as_text()
     for kernel in CELL_STEPS[name]:
         assert kernel in text
+    assert "f32[1,32,128,128]" not in text and "riangular" not in text
     tree = 4 * builder.param_count(cfg)
     assert 6 * tree < _footprint(c) < HBM_BYTES - SAMPLE_ROOM.get(name, tree)

@@ -1,17 +1,22 @@
-"""The gated delta rule (``ops/gated_delta.py``): the chunked scan against
-the token-by-token recurrence, outputs and every gradient, at lengths of 1,
-1.5 and 4 chunks with the decay near 1 and near 0; the bfloat16 recipe to a
-written tolerance; key heads shared by value heads; the causal short
-convolution against an explicit shifted sum."""
+"""The gated delta rule (``ops/gated_delta.py``): the chunked form (its
+recurrence two Pallas kernels, interpreted here) against the token-by-token
+recurrence, outputs and every gradient, at lengths of 1, 1.5 and 4 chunks
+with the decay near 1 and near 0; the bfloat16 recipe to a written
+tolerance; key heads shared by value heads; the kernels against the
+``lax.scan`` they replaced and its derivative; the chunk inverse's kernel
+against ``triangular_solve``; the causal short convolution against an explicit
+shifted sum."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from torchft_tpu import tracing
 from torchft_tpu.ops import (causal_conv1d, gated_delta_recurrent,
                              gated_delta_rule)
-from torchft_tpu.ops.gated_delta import CHUNK
+from torchft_tpu.ops.gated_delta import (CHUNK, _mm, chunk_recurrence,
+                                         unit_lower_inverse)
 
 NAMES = ("q", "k", "v", "g", "beta")
 LENGTHS = {"1_chunk": CHUNK, "1.5_chunks": CHUNK + CHUNK // 2,
@@ -149,6 +154,134 @@ def test_no_overflow_where_the_decay_is_strong():
     assert bool(jnp.all(jnp.isfinite(grads)))
     np.testing.assert_allclose(out, gated_delta_recurrent(q, k, v, g, beta),
                                atol=5e-3)
+
+
+# ------------------------------------------------- the kernels alone
+
+def scan_recurrence(w, u, q_in, qk, k_out, keep, dtype=jnp.float32):
+    """The plain form of the rule's sequential part, as ``gated_delta_rule``
+    ran it before the kernels: one ``lax.scan`` over the chunks, arguments
+    [BH, n, CHUNK, ...] and ``keep`` [BH, n]."""
+    def chunk(state, xs):
+        w_c, u_c, q_c, qk_c, k_c, keep_c = xs
+        v_new = u_c - _mm("hcd,hde->hce", w_c, state, dtype)
+        out = _mm("hcd,hde->hce", q_c, state, dtype) \
+            + _mm("hij,hje->hie", qk_c, v_new, dtype)
+        state = keep_c[..., None, None] * state \
+            + _mm("hcd,hce->hde", k_c, v_new, dtype)
+        return state, out
+
+    _, out = jax.lax.scan(
+        chunk, jnp.zeros((w.shape[0], w.shape[-1], u.shape[-1])),
+        tuple(jnp.moveaxis(x, 1, 0) for x in (w, u, q_in, qk, k_out, keep)))
+    return jnp.moveaxis(out, 0, 1)
+
+
+@pytest.mark.parametrize("chunks", [3, 5], ids=["3_chunks", "5_chunks"])
+@pytest.mark.parametrize("hb", [1, 4], ids=["hb1", "hb4"])
+def test_the_kernels_are_the_scan_and_its_derivative(hb, chunks):
+    """``gdn_fwd`` against the scan body and ``gdn_bwd`` against
+    ``jax.vjp`` of it, all six cotangents, in float32 on inputs of the
+    sizes the chunk products hand over."""
+    bh, d_k, d_v = 4, 16, 8
+    ks = jax.random.split(jax.random.key(11), 7)
+    w, q_in, k_out = (0.3 * jax.random.normal(k, (bh, chunks, CHUNK, d_k))
+                      for k in ks[:3])
+    u, do = (jax.random.normal(k, (bh, chunks, CHUNK, d_v))
+             for k in ks[3:5])
+    qk = 0.3 * jax.random.normal(ks[5], (bh, chunks, CHUNK, CHUNK))
+    keep = jax.random.uniform(ks[6], (bh, chunks), minval=0.2, maxval=1.0)
+
+    def kernels(w, u, q_in, qk, k_out, keep):
+        wide = jnp.broadcast_to(keep[..., None, None],
+                                (bh, chunks, 1, d_v))
+        return chunk_recurrence(w, u, q_in, qk, k_out, wide, hb, True)
+
+    args = (w, u, q_in, qk, k_out, keep)
+    got, pull = jax.vjp(kernels, *args)
+    want, want_pull = jax.vjp(scan_recurrence, *args)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    assert float(jnp.max(jnp.abs(want))) > 1
+    for name, a, b in zip(("w", "u", "q_in", "qk", "k_out", "keep"),
+                          pull(do), want_pull(do)):
+        scale = float(jnp.max(jnp.abs(b)))
+        assert scale > 0.1, name
+        np.testing.assert_allclose(a / scale, b / scale, atol=1e-5,
+                                   err_msg=name)
+
+
+def _keys_that_repeat(key):
+    """beta k_i . k_j with one key all along the chunk, beta near 1: every
+    entry near 1, the inverse near bidiagonal."""
+    beta = jax.random.uniform(key, (8, CHUNK, 1), minval=0.9, maxval=1.0)
+    return jnp.broadcast_to(beta, (8, CHUNK, CHUNK))
+
+
+MATRICES = {
+    "signed": lambda k: jax.random.uniform(k, (8, CHUNK, CHUNK), minval=-1,
+                                           maxval=1),
+    "positive": lambda k: jax.random.uniform(k, (8, CHUNK, CHUNK)),
+    "keys_that_repeat": _keys_that_repeat,
+}
+
+
+@pytest.mark.parametrize("kind", list(MATRICES), ids=list(MATRICES))
+def test_the_chunk_inverse_equals_substitution(kind):
+    """``(I + A)^-1`` on seeded strictly lower ``A`` with entries up to 1,
+    by the kernel that substitutes across the lanes, and its pullback by
+    products: within 1e-5 of ``triangular_solve``'s relative to the largest
+    entry. (The product ``(I - A)(I + A^2)(I + A^4)...`` reads 24 and 145
+    on the last two kinds in float32: its terms grow like binomial
+    coefficients before they cancel.)"""
+    a = jnp.tril(MATRICES[kind](jax.random.key(21)), -1)
+    assert 0.9 < float(jnp.max(jnp.abs(a))) <= 1
+    eye = jnp.eye(CHUNK)
+
+    def solve(a):
+        return jax.lax.linalg.triangular_solve(
+            eye + a, jnp.broadcast_to(eye, a.shape), left_side=True,
+            lower=True, unit_diagonal=True)
+
+    got, pull = jax.vjp(unit_lower_inverse, a)
+    want, want_pull = jax.vjp(solve, a)
+    scale = jnp.max(jnp.abs(want), axis=(-1, -2), keepdims=True)
+    assert float(jnp.max(jnp.abs(got - want) / scale)) < 1e-5
+    np.testing.assert_array_equal(jnp.triu(got, 1), 0)
+    ct = jax.random.normal(jax.random.key(22), a.shape)
+    d, d_want = jnp.tril(pull(ct)[0], -1), jnp.tril(want_pull(ct)[0], -1)
+    scale = jnp.max(jnp.abs(d_want), axis=(-1, -2), keepdims=True)
+    assert float(jnp.max(jnp.abs(d - d_want) / scale)) < 1e-5
+
+
+@pytest.mark.parametrize("what", ["forward", "gradient"])
+def test_a_traced_kernel_counts_itself_and_its_grid_steps(what):
+    """The cell's call, traced and never run: 32 heads in steps of 16 over
+    128 chunks are 256 grid steps a sweep; a gradient traces the forward
+    that keeps the states and the backward."""
+    def shaped(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    args = (shaped(1, 8192, 16, 128), shaped(1, 8192, 16, 128),
+            shaped(1, 8192, 32, 128), shaped(1, 8192, 32, dtype=jnp.float32),
+            shaped(1, 8192, 32, dtype=jnp.float32))
+
+    def loss(*a):
+        return gated_delta_rule(*a).sum()
+
+    def counters():
+        c = tracing.program_counters()
+        return [c.get("gdn_kernel_traces_total", 0),
+                c.get("gdn_kernel_grid_steps_traced_total", 0)]
+
+    before = counters()
+    sweeps = 1
+    if what == "forward":
+        jax.eval_shape(loss, *args)
+    else:
+        jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2, 3, 4)), *args)
+        sweeps = 2
+    assert [a - b for a, b in zip(counters(), before)] \
+        == [sweeps, sweeps * 256]
 
 
 # ------------------------------------------------- the short convolution

@@ -196,10 +196,11 @@ def test_convolution_with_a_bias():
 def test_gated_deltanet_is_bitwise_what_it_was_before_the_bias(dtype):
     """``causal_conv1d`` serves both scans; called without a bias it is the
     same function of the same inputs: ``GatedDeltaNet``'s output and every
-    gradient at a small size, bit for bit what the commit before PR 45
-    computed on the CPU (``tests/golden_gdn_pr44.json``, made by
-    ``tests/_gdn_golden.py`` there)."""
+    gradient at a small size, bit for bit what PR 49's tree computed on the
+    CPU (``tests/golden_gdn_pr49.json``, made by ``tests/_gdn_golden.py``
+    there: the rule's kernels interpreted; the file before it,
+    ``golden_gdn_pr44.json``, held the ``lax.scan`` the kernels replaced)."""
     with open(os.path.join(os.path.dirname(__file__),
-                           "golden_gdn_pr44.json")) as f:
+                           "golden_gdn_pr49.json")) as f:
         golden = json.load(f)[dtype]
     assert _gdn_golden.digests(dtype) == golden

@@ -13,15 +13,19 @@ heads of ``d_v`` (a key head serves ``H / H_k`` value heads):
     o <- RMSNorm_head(o) * w_n * SiLU(z);  out = [o] W_out
 
 The state that the rule carries along the sequence is the only thing in the
-model that does; ``ops/gated_delta.py`` says how it is computed in chunks.
-The rule is rematerialised here (``jax.checkpoint``): the scan's residuals
-are the per-chunk states, 256 MiB a layer at 8,192 tokens and the published
-sizes, and kept from forward to backward in every linear layer they are
-what the step has no room for beside its trees (the chip's compiler puts
-the benchmark cell's step at 6.9 GiB of temporaries with them and 2.8
-without, PERF.md PR 40); recomputed, one layer's are alive at a time, for
-one more forward scan. A region around everything between the two
-projections was tried and took more (3.1 GiB).
+model that does; ``ops/gated_delta.py`` says how it is computed in chunks
+(batched products, one kernel for the chunk inverses, two for the chunks'
+recurrence). The rule is rematerialised here (``jax.checkpoint``): what its
+backward keeps is the state every chunk found, 256 MiB a layer at 8,192
+tokens and the published sizes, and the chunk products' outputs, 0.35 GiB
+more. Without the checkpoint the benchmark cell's step has one forward a
+layer less to compute and 0.85 GiB more temporaries (4.58 -> 5.43 GiB by
+the chip's compiler), and beside the six trees of a step that is not
+donated it ran 3 % slower on the chip, not faster (31,316 -> 30,399
+tokens/s at one seed; the plain donated loop read the other way, 250 ->
+244 ms a step; PERF.md PR 49): it stays until the step holds fewer trees.
+A region around everything between the two projections was tried in PR 40
+and took more.
 
 The layer's two numbers for the program counters leave it as values
 (``return_stats=True``), since a count taken inside a rematerialised

@@ -124,7 +124,10 @@ def default_trace_steps() -> int:
 # ``ops/flash_attention.py`` adds each traced kernel's grid steps and those
 # a static mask skips (``flash_grid_steps_traced_total``,
 # ``flash_skipped_steps_traced_total``) and which backward a call took
-# (``flash_dq_resident_traces_total`` / ``flash_dq_split_traces_total``).
+# (``flash_dq_resident_traces_total`` / ``flash_dq_split_traces_total``),
+# and ``ops/gated_delta.py`` each traced sweep of its chunk recurrence and
+# the sweep's grid steps (``gdn_kernel_traces_total``,
+# ``gdn_kernel_grid_steps_traced_total``).
 # ``program_callbacks_total`` counted the runs of the callback that carried
 # the counts until PR 43; nothing adds to it any more, and it stays in the
 # schema at 0.0 until the benchmark's metric that reads it is retired.
