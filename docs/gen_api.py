@@ -28,6 +28,9 @@ sys.path.insert(0, str(REPO))
 # plus the TPU-native additions.
 MODULES = [
     ("torchft_tpu.manager", "Per-step fault-tolerance state machine"),
+    ("torchft_tpu.boundary", "The commit boundary: refusal rule, "
+                             "feature slots"),
+    ("torchft_tpu.preemption", "Graceful preemption drain"),
     ("torchft_tpu.exchange", "Cross-group gradient exchange (schedule, "
                              "pack, stage, ring, put)"),
     ("torchft_tpu.communicator", "Resizable cross-group communicators"),

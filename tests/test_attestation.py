@@ -434,7 +434,7 @@ class TestDonorAdmission:
         store.set("torchft/healset/2", b"4:http://bad:1/checkpoint/4")
         store.set("torchft/healset/3", b"4:http://live:1/checkpoint/4")
         m = make_manager(store=store)
-        m._last_round_facts = (FAKE_STORE_ADDR, 0, 4)
+        m._last_round_facts = (FAKE_STORE_ADDR, 0, 4, 4)
         self._quarantine_bases(m, "http://bad:1")
         try:
             assert m._ram_peer_bases() == ["http://live:1"]
@@ -474,7 +474,7 @@ class TestQuarantineLadder:
         store = FakeStore()
         store.set("torchft/healset/0", b"1:http://me:1/checkpoint/1")
         m = make_manager(store=store)
-        m._last_round_facts = (FAKE_STORE_ADDR, 0, 3)
+        m._last_round_facts = (FAKE_STORE_ADDR, 0, 3, 3)
         m._flight = MagicMock()
         try:
             m._consume_fleet_hint(self._verdict(
@@ -482,7 +482,7 @@ class TestQuarantineLadder:
                 addrs="http://me:1/checkpoint/1"))
             assert m._sdc_quarantined
             assert not m.is_participating()  # zero-weight fold
-            assert m._wire_weight() == 0
+            assert m._share.wire_weight() == 0
             # Advertisement withdrawn with the PR 14 tombstone.
             assert store.kv["torchft/healset/0"] == b"-1:"
             mx = m.metrics()
@@ -495,26 +495,6 @@ class TestQuarantineLadder:
             assert "sdc0" in m._sdc_quarantined_peers
             assert "http://me:1" in m._sdc_quarantined_bases
         finally:
-            m.shutdown()
-
-    def test_refusal_classes(self, tmp_path):
-        m = make_manager()
-        try:
-            m._should_step = True  # a settled committed boundary...
-            with m._metrics_lock:
-                m._sdc_quarantined = True  # ...under a verdict
-            writer = MagicMock()
-            assert m.save_durable(writer, str(tmp_path)) is None
-            assert not writer.save_async.called
-            pub = MagicMock()
-            assert m.publish(pub) is None
-            assert not pub.publish.called
-            m._ram_replicator = MagicMock()
-            assert m.replicate_ram() is None
-            assert not m._ram_replicator.replicate_async.called
-            assert m.metrics()["sdc_refusals_total"] == 3.0
-        finally:
-            m._ram_replicator = None
             m.shutdown()
 
     def test_checkpoint_serve_gate_503(self):
@@ -667,17 +647,17 @@ class TestChaosSdcBand:
         try:
             with m._metrics_lock:
                 m._healing = True
-            m._maybe_chaos_sdc()
+            m._chaos_sdc.at_step_edge(True)
             assert m.metrics()["sdc_chaos_flips_total"] == 0.0
             with m._metrics_lock:
                 m._healing = False
                 m._sdc_quarantined = True
-            m._maybe_chaos_sdc()
+            m._chaos_sdc.at_step_edge(True)
             assert m.metrics()["sdc_chaos_flips_total"] == 0.0
             assert "sdc" not in sched._counts  # guarded before the draw
             with m._metrics_lock:
                 m._sdc_quarantined = False
-            m._maybe_chaos_sdc()  # a participant DOES flip
+            m._chaos_sdc.at_step_edge(True)  # a participant DOES flip
             assert m.metrics()["sdc_chaos_flips_total"] == 1.0
         finally:
             chaos.uninstall()
@@ -689,14 +669,14 @@ class TestChaosSdcBand:
         m._user_load_state_dict = lambda s: (cell.clear(), cell.update(s))
         try:
             clean = cell["w"].copy()
-            m._apply_sdc_flip(0.37)
+            m._chaos_sdc.flip(0.37)
             diff = cell["w"].view(np.uint8) ^ clean.view(np.uint8)
             changed = np.nonzero(diff)[0]
             assert changed.size == 1  # exactly one byte...
             assert bin(int(diff[changed[0]])).count("1") == 1  # ...one bit
             # Pure function of frac: the same draw reproduces the flip.
             cell["w"] = clean.copy()
-            m._apply_sdc_flip(0.37)
+            m._chaos_sdc.flip(0.37)
             assert np.array_equal(cell["w"].view(np.uint8) ^
                                   clean.view(np.uint8), diff)
         finally:
@@ -708,7 +688,7 @@ class TestChaosSdcBand:
         m._user_load_state_dict = lambda s: (cell.clear(), cell.update(s))
         try:
             clean = m._compute_state_digest()
-            m._apply_sdc_flip(0.5)
+            m._chaos_sdc.flip(0.5)
             assert m._compute_state_digest() != clean
         finally:
             m.shutdown()

@@ -386,11 +386,11 @@ class TestPreemptionDrain:
                                  user_state={"rich": {"w": np.ones(2)}})
             assert fut is not None
             fut.result(timeout=30)
-            assert m._durable_target is None  # no mismatched drain save
+            assert m._drain.target is None  # no mismatched drain save
             # A plain save (manager-registered tree) IS remembered.
             fut = m.save_durable(writer, str(tmp_path))
             fut.result(timeout=30)
-            assert m._durable_target is not None
+            assert m._drain.target is not None
         finally:
             m.shutdown()
 

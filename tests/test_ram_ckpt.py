@@ -430,7 +430,7 @@ class TestManagerRamTier:
         try:
             for _ in range(3):
                 assert boundary(m)
-            m._ram_replicator.wait()
+            m._ram.replicator.wait()
             assert pstore.steps()  # the commit images crossed the wire
             mx = m.metrics()
             assert mx["ram_ckpt_peers"] == 1.0
@@ -450,30 +450,6 @@ class TestManagerRamTier:
         finally:
             m.shutdown()
 
-    def test_refusal_classes(self):
-        m, client, _ = ram_manager(peers=1)
-        try:
-            assert boundary(m)
-            # Latched error: the state may be mid-apply — refuse.
-            m._errored = RuntimeError("boom")
-            assert m.replicate_ram() is None
-            m._errored = None
-            # Healing: staged/unapplied state — refuse.
-            with m._metrics_lock:
-                m._healing = True
-            assert m.replicate_ram() is None
-            with m._metrics_lock:
-                m._healing = False
-            # Aborted vote: nothing committed — refuse.
-            m._should_step = False
-            assert m.replicate_ram() is None
-            m._should_step = True
-            assert m.metrics()["ram_replicate_skipped"] == 3.0
-            events = [e["event"] for e in m.history()]
-            assert events.count("ram_replicate_skip") == 3
-        finally:
-            m.shutdown()
-
     def test_replication_set_collapse_dumps_once(self, peer):
         srv, _ = peer
         m, _, _ = ram_manager(peers=1)
@@ -481,12 +457,12 @@ class TestManagerRamTier:
         try:
             assert boundary(m)
             assert boundary(m)  # first boundary with a discovered peer
-            m._ram_replicator.wait()
+            m._ram.replicator.wait()
             assert m.metrics()["ram_ckpt_peers"] == 1.0
             srv.shutdown()  # the whole replication set dies
             for _ in range(4):
                 assert boundary(m)
-                m._ram_replicator.wait()
+                m._ram.replicator.wait()
             mx = m.metrics()
             assert mx["ram_ckpt_peers"] == 0.0
             assert mx["ram_replica_collapses_total"] == 1.0  # one-shot

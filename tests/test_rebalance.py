@@ -35,7 +35,7 @@ from torchft_tpu import chaos, fleet
 from torchft_tpu.backends.host import HostCommunicator, _Ring
 from torchft_tpu.communicator import DummyCommunicator
 from torchft_tpu.data import ElasticSampler, _reports_samples
-from torchft_tpu.manager import _REBALANCE_KEY
+from torchft_tpu.degraded import _REBALANCE_KEY
 
 pytestmark = pytest.mark.rebalance
 
@@ -512,9 +512,9 @@ class TestManagerAdoption:
             rank, _committed, frac = m.participant_slot()
             assert rank == 0
             assert frac == pytest.approx(0.375)
-            assert m._wire_weight() == round(0.375 * 10_000)
+            assert m._share.wire_weight() == round(0.375 * 10_000)
             m.set_step_samples(24)  # the sampler's exact draw wins
-            assert m._wire_weight() == 24
+            assert m._share.wire_weight() == 24
         finally:
             m.shutdown()
 
@@ -764,16 +764,16 @@ class TestChaosSlowBand:
             boundary(m)  # establishes participation + the prev stamp
             time.sleep(0.05)
             t0 = time.monotonic()
-            m._maybe_chaos_slow()
+            m._chaos_slow.at_step_edge(True)
             slept = time.monotonic() - t0
-            first = m._chaos_slow_injected
+            first = m._chaos_slow.injected
             assert first >= 0.05  # ~2x the ~0.05 s natural wall
             assert slept >= first * 0.9
             # Immediately again: the wall is almost all injected sleep,
             # so the natural remainder — and the new injection — is
             # tiny (convergence, not compounding).
-            m._maybe_chaos_slow()
-            assert m._chaos_slow_injected < first * 0.5
+            m._chaos_slow.at_step_edge(True)
+            assert m._chaos_slow.injected < first * 0.5
         finally:
             m.shutdown()
             chaos.reset()
@@ -796,8 +796,8 @@ class TestChaosSlowBand:
             m._participating_rank = None  # benched spare
             draws_before = sched._counts.get("slow", 0)
             time.sleep(0.02)
-            m._maybe_chaos_slow()
-            assert m._chaos_slow_injected == 0.0
+            m._chaos_slow.at_step_edge(True)
+            assert m._chaos_slow.injected == 0.0
             assert sched._counts.get("slow", 0) == draws_before
         finally:
             m.shutdown()

@@ -421,20 +421,11 @@ class TestProgramCallback:
             spans = m.tracer().spans()
         finally:
             m.shutdown()
-        assert after["program_callbacks_total"] \
-            == before["program_callbacks_total"]
         assert after["test_rows_total"] \
             - before.get("test_rows_total", 0.0) == 12
         assert counters["test_rows_total"] == after["test_rows_total"]
         # Adding the counts records nothing: the count is all.
         assert spans == []
-
-    def test_the_count_is_there_before_any_program_ran(self):
-        m = make_manager()
-        try:
-            assert "program_callbacks_total" in m.metrics()
-        finally:
-            m.shutdown()
 
 
 # ------------------------------------------------- (e) the two readers

@@ -426,7 +426,7 @@ class TestManagerDeviceQuant:
         m = _devq_manager(DummyCommunicator(), 0, True)
         try:
             m._exchange._dev_residuals[("fp", 0, 0)] = np.zeros(4, np.float32)
-            m._install_policy(
+            m._switch.install(
                 next(p for p in policy_mod.LADDER
                      if p.name == "sync-bf16"), "test", "policy_switch")
             assert not m._exchange._dev_residuals

@@ -58,6 +58,7 @@ a no-op costing one global read on the hot path.
 
 from __future__ import annotations
 
+import logging
 import os
 import random
 import socket
@@ -68,7 +69,10 @@ from typing import Any, Dict, List, Optional
 
 from concurrent.futures import Future
 
+from torchft_tpu.boundary import Boundary, BoundaryFeature
 from torchft_tpu.communicator import Communicator, CommunicatorError
+
+logger = logging.getLogger(__name__)
 
 __all__ = [
     "EndpointChaos",
@@ -722,12 +726,9 @@ def sdc_fault(endpoint: str,
     draw so the corruption sequence is a pure function of
     ``(seed, channel, n)`` like every other channel; the rate scales
     with the live intensity, so :class:`~torchft_tpu.policy.PhasedChaos`
-    drives SDC storms unmodified. The caller must never poll while
-    healing or benched: corrupting a transient mid-restore state would
-    both wreck the freshly verified fetch and model a fault the
-    attestation vote deliberately abstains on — the injection contract
-    is post-commit, participants only (Manager._maybe_chaos_sdc guards
-    it; frozen by tests/test_attestation.py)."""
+    drives SDC storms unmodified. The injection contract is
+    post-commit, never mid-restore (:class:`SdcBand` guards it; frozen
+    by tests/test_attestation.py)."""
     sched = schedule if schedule is not None else active()
     if sched is None:
         return None
@@ -748,16 +749,13 @@ def slow_fault(endpoint: str,
     Returns the stretch multiplier for THIS boundary: ``slow_factor``
     when a ``slow`` decision fires, else ``1.0`` (no stretch — also
     when no schedule/config is active, with NO decision drawn: stream
-    purity, like the sdc band). The caller stretches the step by
-    sleeping ``(factor - 1) x`` its natural boundary wall — an honest
-    straggler whose slowness the health plane measures end-to-end,
-    not a clock hack. A persistent straggler is ``slow_rate=1`` on
+    purity, like the sdc band) — an honest straggler whose slowness
+    the health plane measures end-to-end, not a clock hack
+    (:class:`SlowBand`). A persistent straggler is ``slow_rate=1`` on
     the endpoint; the rate scales with the live intensity, so a
     PhasedChaos walk mints the straggler in its storm phase and
-    clears it in the next stable phase with no latch to forget. The
-    injection contract mirrors the sdc band: participants only, once
-    per boundary (Manager._maybe_chaos_slow guards it; frozen by
-    tests/test_rebalance.py)."""
+    clears it in the next stable phase with no latch to forget
+    (frozen by tests/test_rebalance.py)."""
     sched = schedule if schedule is not None else active()
     if sched is None:
         return 1.0
@@ -768,6 +766,116 @@ def slow_fault(endpoint: str,
     if d is None or d.fault != "slow":
         return 1.0
     return max(1.0, float(cfg.slow_factor))
+
+
+class SdcBand(BoundaryFeature):
+    """The ``sdc`` band as a commit-boundary feature
+    (docs/design/state_attestation.md): poll the channel once per
+    boundary and, on an ``sdc_flip`` decision, flip ONE bit of one
+    committed param leaf. It rides the step edge — the corrupted params
+    train this step and lose the attestation vote at the NEXT boundary,
+    which is exactly the <=1-boundary detection-latency bound the soak
+    asserts. Never while healing or quarantined: corrupting a transient
+    mid-restore state would both wreck the freshly verified fetch and
+    model a fault the attestation vote deliberately abstains on. Built
+    from the boundary and the caller's ``state_dict`` /
+    ``load_state_dict`` callables."""
+
+    METRICS = {"sdc_chaos_flips_total": 0.0}  # bit-flips applied
+
+    def __init__(self, boundary: Boundary, state_dict: Any,
+                 load_state_dict: Any) -> None:
+        self._b = boundary
+        self._state_dict = state_dict
+        self._load_state_dict = load_state_dict
+
+    def at_step_edge(self, committed: bool) -> None:
+        v = self._b.view()
+        if v.healing or v.quarantined:
+            return
+        try:
+            d = sdc_fault(f"sdc:{v.replica_id}")
+            if d is not None:
+                self.flip(d.frac)
+        except Exception:  # noqa: BLE001 — chaos never fails a step
+            logger.debug("sdc chaos injection failed", exc_info=True)
+
+    def flip(self, frac: float) -> None:
+        """Deterministically corrupt one bit of the committed params:
+        the (leaf, byte, bit) choice is a pure function of the
+        decision's ``frac`` draw, so a seeded schedule reproduces the
+        exact same corruption run over run (the soak's determinism
+        contract). The flipped leaf is re-placed like the original
+        (device arrays stay device, host stays host) and loaded back
+        through the registered ``load_state_dict`` — the corruption is
+        indistinguishable from a real in-memory flip by the time the
+        digest sees it."""
+        import jax
+        import numpy as np
+
+        from torchft_tpu import serialization
+
+        leaves, treedef = jax.tree_util.tree_flatten(self._state_dict())
+        idxs = [i for i, leaf in enumerate(leaves)
+                if serialization._is_array_leaf(leaf)
+                and getattr(leaf, "nbytes", 0)]
+        if not idxs:
+            return
+        li = idxs[int(frac * len(idxs)) % len(idxs)]
+        leaf = leaves[li]
+        a = np.array(leaf)  # contiguous host copy, any dtype
+        b = a.view(np.uint8).reshape(-1)
+        byte = int(frac * b.size) % b.size
+        bit = int(frac * 8) % 8
+        b[byte] ^= np.uint8(1 << bit)
+        leaves[li] = (serialization.device_put_like(a, leaf)
+                      if isinstance(leaf, jax.Array) else a)
+        self._load_state_dict(
+            jax.tree_util.tree_unflatten(treedef, leaves))
+        v = self._b.view()
+        self._b.record(sdc_chaos_flips_total=1)
+        self._b.log_event(event="sdc_chaos_flip", step=v.step,
+                          leaf=li, byte=byte, bit=bit)
+        logger.warning(
+            "%s: chaos sdc_flip at step %d — leaf %d byte %d bit %d",
+            v.replica_id, v.step, li, byte, bit)
+
+
+class SlowBand(BoundaryFeature):
+    """The ``slow`` band as a commit-boundary feature
+    (docs/design/fleet_rebalance.md): poll the channel once per
+    boundary, at the step edge, and on a ``slow`` decision sleep
+    ``(factor - 1) x`` the NATURAL wall of the boundary just finished —
+    natural meaning the measured wall minus the sleep THIS band
+    injected there, so the stretch converges to a steady ``factor x``
+    wall instead of compounding its own injections (at factor >= 2 the
+    naive spelling diverges). Participants only, like the sdc band: a
+    healer/spare contributes no wall the Rebalancer reads."""
+
+    def __init__(self, boundary: Boundary) -> None:
+        self._b = boundary
+        # Last boundary's timestamp and the sleep injected there.
+        self._prev: Optional[float] = None
+        self.injected = 0.0
+
+    def at_step_edge(self, committed: bool) -> None:
+        now = time.monotonic()
+        prev, injected = self._prev, self.injected
+        self._prev, self.injected = now, 0.0
+        if not self._b.participating():
+            return
+        try:
+            factor = slow_fault(f"slow:{self._b.replica_id()}")
+        except Exception:  # noqa: BLE001 — chaos never fails a step
+            logger.debug("slow chaos injection failed", exc_info=True)
+            return
+        if factor <= 1.0 or prev is None:
+            return
+        sleep_s = (factor - 1.0) * max(0.0, (now - prev) - injected)
+        if sleep_s <= 0.0:
+            return
+        self.injected = sleep_s
+        time.sleep(sleep_s)
 
 
 # ------------------------------------------------------------ RAM faults

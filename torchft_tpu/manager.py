@@ -45,7 +45,6 @@ import time
 import uuid
 from collections import OrderedDict, deque
 from concurrent.futures import Future, ThreadPoolExecutor
-from datetime import timedelta  # noqa: F401  (kept for API familiarity)
 from enum import Enum
 from typing import Any, Callable, Dict, Optional, Tuple, TypeVar, cast
 
@@ -53,7 +52,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from torchft_tpu import fleet as fleet_mod
+from torchft_tpu import boundary as boundary_mod
+from torchft_tpu import chaos, degraded, ram_ckpt
 from torchft_tpu import policy as policy_mod
 from torchft_tpu import serialization
 from torchft_tpu import tracing as tracing_mod
@@ -62,36 +62,15 @@ from torchft_tpu._native import ManagerClient, ManagerServer, Store, StoreClient
 from torchft_tpu.checkpointing import HEAL_STAGES, CheckpointServer
 from torchft_tpu.communicator import Communicator, CommunicatorError
 from torchft_tpu.exchange import GradExchange, ShardedGrads, StepFacts
+from torchft_tpu.preemption import PreemptedExit  # noqa: F401 (re-exported)
+from torchft_tpu.preemption import PreemptionDrain
 from torchft_tpu.retry import RetryPolicy, RetryStats
 from torchft_tpu.utils import advertise_host, div_by_count
 
 logger: logging.Logger = logging.getLogger(__name__)
 
 MANAGER_ADDR_KEY: str = "manager/addr"
-# Fixed quorum-store key the adaptive-policy decision rides on (fixed,
-# like the healset keys: the store has no delete/TTL, so a per-step key
-# would leak one entry per boundary for the life of the job).
-_POLICY_KEY: str = "torchft/policy"
-# Fixed quorum-store key the fleet-rebalance decision rides on (same
-# fixed-key rationale as _POLICY_KEY: no delete/TTL in the store, so a
-# per-step key would leak one entry per boundary).
-_REBALANCE_KEY: str = "torchft/rebalance"
-# Fold-weight encoding of a capacity fraction when the caller never
-# reports exact per-step sample counts (degraded-mode groups,
-# docs/design/degraded_mode.md): weight = round(fraction * SCALE).
-# Only RATIOS between groups matter, so any shared scale works; 10_000
-# keeps three decimal places of fraction resolution in integer weights.
-_CAPACITY_WEIGHT_SCALE = 10_000
 T = TypeVar("T")
-
-
-class PreemptedExit(RuntimeError):
-    """Raised by :meth:`Manager.step` once a graceful preemption drain
-    has completed (docs/design/churn.md): the manager has taken its
-    final durable save, withdrawn its heal/publish advertisements, said
-    farewell to the quorum, and shut down — the training loop must exit
-    (with status 0: this is the *noticed-reclaim success path*, not a
-    failure)."""
 
 
 class _LatencyReservoir:
@@ -131,6 +110,185 @@ class _LatencyReservoir:
             "p95": s[min(len(s) - 1, int(len(s) * 0.95))],
             "max": self._max,
         }
+
+
+# Lightweight observability: counters + cumulative timings (ms).
+# The reference exposes only current_step/batches_committed
+# (manager.py:484-506); these cover the SRE questions its dashboard
+# can't answer (how long do quorums take, how often do we heal).
+_METRICS: Dict[str, float] = {
+    "quorum_count": 0, "quorum_ms_total": 0.0, "quorum_ms_last": 0.0,
+    # Control-plane scaling observability
+    # (docs/design/control_plane.md): rounds served from the
+    # lighthouse's membership-unchanged cache vs. full rendezvous
+    # rounds, and the lighthouse's monotonic decision epoch as of
+    # the last round. quorum_ms_p50/p95/max (from a bounded
+    # reservoir) and lighthouse_redials join them in metrics().
+    "quorum_fast_path_hits": 0,
+    "quorum_slow_path_rounds": 0,
+    "quorum_epoch_last": 0,
+    "reconfigure_count": 0, "reconfigure_ms_total": 0.0,
+    "heal_count": 0,
+    "heal_ms_total": 0.0, "heal_bytes_total": 0.0,
+    # The heal transfer's stages, busy ms (docs/design/
+    # healing.md): this group as the healer (waiting for the
+    # donor's manifest; in the socket; crc32; device_put and its
+    # copy) and as a donor (D2H of the digest pass and of the
+    # streams; socket writes). The healer's four over
+    # heal_ms_total say how far the stages ran beside each other
+    # (1.0: one after the other).
+    "heal_manifest_ms_total": 0.0, "heal_recv_ms_total": 0.0,
+    "heal_verify_ms_total": 0.0, "heal_place_ms_total": 0.0,
+    "heal_serve_fetch_ms_total": 0.0,
+    "heal_serve_send_ms_total": 0.0,
+    # Resilient-heal observability: bytes re-sent by resumed
+    # attempts (strictly less than the payload when resume
+    # works), donor failovers, leaves caught by digest
+    # verification, fetch rounds, and a live progress gauge
+    # (committed/payload bytes of the CURRENT transfer, updated
+    # per verified leaf — visible mid-heal in /metrics.json).
+    "heal_bytes_resumed_total": 0.0,
+    "heal_donor_failovers": 0.0,
+    "heal_leaf_digest_mismatches": 0.0,
+    "heal_attempts_total": 0.0,
+    "heal_last_bytes_committed": 0.0,
+    "heal_last_payload_bytes": 0.0,
+    # Striped-heal observability: donors the last heal actually
+    # fetched from (1 = single-donor path).
+    "heal_striped_donors": 0.0,
+    "allreduce_count": 0, "allreduce_ms_total": 0.0,
+    # Stage breakdown of the pipelined host allreduce (cumulative
+    # BUSY ms per stage; stages overlap across buckets, so sums
+    # can exceed allreduce_ms_total — they attribute, not
+    # partition). fetch = dispatch + wait: dispatch is the cost
+    # of kicking off packs + async D2H copies, wait is the time
+    # blocked on DMA completion. wire_bytes counts what actually
+    # crossed D2H; the ring leg's bytes
+    # (allreduce_ring_wire_bytes_total) come from the backend's
+    # own send counter and are merged in metrics().
+    "allreduce_fetch_ms_total": 0.0,
+    "allreduce_fetch_dispatch_ms_total": 0.0,
+    "allreduce_fetch_wait_ms_total": 0.0,
+    "allreduce_ring_ms_total": 0.0,
+    "allreduce_put_ms_total": 0.0, "allreduce_wire_bytes_total": 0.0,
+    # Actual device->host traffic of the fetch stage (what
+    # device_get / copy_to_host_async really moved — wire bytes
+    # under device-side quantization, NOT grad bytes). Tracks
+    # allreduce_wire_bytes_total today but is frozen under its
+    # own name so the devquant A/B and bench fetch accounting
+    # never conflate "bytes fetched" with "payload represented".
+    "allreduce_d2h_wire_bytes_total": 0.0,
+    # Bytes of gradient copied host-to-host between the fetch
+    # and the ring's first send (bytes): assembling a mixed
+    # host/device chunk or the hostcast leg here, a
+    # non-contiguous wire buffer in the backend (merged in
+    # metrics() with the accumulator counts). 0 where every
+    # leaf is on the device: the ring reads the fetched buffer.
+    "allreduce_host_copy_bytes_total": 0.0,
+    # Wire ops handed to the ring (one a bucket), and how many
+    # of them were one slice of a leaf wider than _SLICE_BYTES:
+    # per committed step they say how often the cut engages.
+    "allreduce_ring_ops_total": 0.0,
+    "allreduce_split_slices_total": 0.0,
+    # Cross-step overlap engine (docs/design/overlap.md):
+    # hidden = comm wall that ran concurrently with the caller's
+    # compute between dispatch and drain (the ms the engine
+    # exists to hide); drain_wait = what the caller still
+    # blocked on at the settle boundary; inflight = live
+    # allreduce futures right now (gauge); deferred/dropped
+    # count staged steps and stale-grad drops (vote aborts,
+    # latched comm errors, heals).
+    "allreduce_hidden_ms_total": 0.0,
+    "allreduce_drain_wait_ms_total": 0.0,
+    "allreduce_inflight": 0,
+    "overlap_steps_deferred": 0,
+    "overlap_grads_dropped": 0,
+    # ZeRO-style sharded update (docs/design/sharded_update.md):
+    # reduce-scatter rounds, the optimizer's stripe-update wall
+    # (pack + tx.update + allgather + reassembly, recorded by
+    # FTOptimizer via record_update), the live stripe
+    # optimizer-state footprint (gauge — ~1/world of the full
+    # state), and stripe-state resets forced by geometry changes
+    # (membership change ⇒ every rank resets together, keeping
+    # params lockstep).
+    "reduce_scatter_count": 0,
+    "update_count": 0, "update_ms_total": 0.0,
+    "shard_state_bytes": 0.0,
+    "shard_state_resets": 0,
+    "commit_count": 0, "commit_ms_total": 0.0,
+    "committed_steps": 0, "aborted_steps": 0,
+    # Durable-checkpoint observability (cold-start resilience,
+    # docs/design/durable_checkpoints.md): corrupt snapshots
+    # quarantined / newer candidates skipped by recovery scans,
+    # cold starts performed, and commit-coupled saves refused
+    # because the state was mid-heal/errored/uncommitted. The
+    # writer-side counters (ckpt_save_count/-fatal/-stalls, last
+    # error) merge in from the attached AsyncCheckpointer in
+    # metrics().
+    "ckpt_corrupt_quarantined": 0.0,
+    "ckpt_recover_fallbacks": 0.0,
+    "ckpt_recover_legacy": 0.0,
+    "ckpt_cold_starts": 0.0,
+    "ckpt_save_skipped": 0.0,
+    # Ranged-fetch connection reuse (heal + serving transport):
+    # requests served over an already-open per-donor connection
+    # instead of a fresh TCP dial.
+    "heal_redials_avoided": 0.0,
+    # Live-publication tier (docs/design/serving.md): commit-
+    # coupled publishes, refusals (mid-heal/errored/aborted/
+    # deferred state — the publish analogue of ckpt_save_skipped),
+    # cumulative publish wall, and the newest generation id
+    # (gauge). The attached WeightPublisher's own counters
+    # (publish_generations, delta bytes/ratio, serve volume)
+    # merge in via metrics().
+    "publish_count": 0.0,
+    "publish_skipped": 0.0,
+    "publish_ms_total": 0.0,
+    "publish_last_generation": 0.0,
+    # The int8 rung's live error-feedback residual footprint
+    # (gauge; docs/design/adaptive_policy.md).
+    "wire_quant_residual_bytes": 0.0,
+    # Spot-instance churn (docs/design/churn.md): cold pre-join
+    # heals (join backpressure: the replacement healed BEFORE its
+    # first quorum join), and joiners this manager observed being
+    # admitted as one coalesced membership delta (world grew by
+    # >1 in a single reconfigure). reconfigures_per_min (ring
+    # rebuilds in the trailing 60 s) is computed at metrics()
+    # read time.
+    "prejoin_heals_total": 0.0,
+    "joins_coalesced_total": 0.0,
+    # Fleet health plane (docs/design/fleet_health.md): the
+    # lighthouse's per-requester hint, refreshed every quorum
+    # round — fleet p95 step wall, this group's robust-z
+    # straggler score, groups contributing digests, whether
+    # this group is currently out of any SLO (gauge), and the
+    # cumulative SLO breaches echoed to this group. All zero
+    # with no digests / no native control plane.
+    "fleet_p95_ms": 0.0,
+    "straggler_score": 0.0,
+    "fleet_groups": 0.0,
+    "slo_breach": 0.0,
+    "slo_breaches_total": 0.0,
+    # RAM checkpoint tier (docs/design/memory_tier.md): heals
+    # served from a peer's RAM rung instead of disk.
+    "ram_ckpt_heals_total": 0.0,
+    # State attestation (docs/design/state_attestation.md):
+    # fingerprints computed, digests that raised and were
+    # swallowed, and the fingerprints' cumulative wall; whether
+    # THIS group is currently under a divergence verdict
+    # (gauge) and how often it entered/left quarantine; the
+    # recovery heals the verdict forced; boundary actions the
+    # quarantine refused (save/publish/RAM-replicate) on top
+    # of their per-path skip counters.
+    "sdc_digests_total": 0.0,
+    "sdc_digest_failures": 0.0,
+    "sdc_digest_ms_total": 0.0,
+    "sdc_quarantined": 0.0,
+    "sdc_quarantines_total": 0.0,
+    "sdc_quarantine_clears_total": 0.0,
+    "sdc_reheals_total": 0.0,
+    "sdc_refusals_total": 0.0,
+}
 
 
 class WorldSizeMode(Enum):
@@ -270,22 +428,16 @@ class Manager:
             canonical-order f32 fold is shared). The flag is the opt-in
             contract read by the trainer wiring; the collective calls
             themselves work on any Manager.
-        degraded_mode: opt-in degraded-mode groups (env
-            ``TORCHFT_DEGRADED``, docs/design/degraded_mode.md): a
-            group that loses part of its devices survives at reduced
-            capacity instead of dying wholesale — it re-``pjit``s onto
-            the surviving submesh, shrinks its per-group batch, and
-            rejoins the quorum advertising a capacity fraction
-            (:meth:`request_degrade` / :meth:`request_restore`, landing
-            only at commit boundaries, refused mid-heal/mid-deferred
-            like :meth:`save_durable`). When True, every host-ring wire
-            op carries this group's fold weight — the samples actually
-            contributed this step — and the ring runs the **weighted
-            canonical-order fold** (``sum_r(w_r·g_r) / sum_r(w_r)``,
-            bitwise identical across ranks); the per-op preamble turns
-            any weight-mode or geometry skew into a clean abort. Must
-            be enabled on EVERY group or none (enforced at rendezvous
-            via the config fingerprint and per-op via the preamble).
+        degraded_mode / rebalance: opt-in weighted fold (env
+            ``TORCHFT_DEGRADED`` / ``TORCHFT_REBALANCE``): a group that
+            loses part of its devices survives at reduced capacity
+            (:meth:`request_degrade` / :meth:`request_restore`,
+            docs/design/degraded_mode.md), and the lighthouse shrinks a
+            persistent straggler's batch share
+            (docs/design/fleet_rebalance.md); see
+            :class:`~torchft_tpu.degraded.BatchShare`. Must be enabled
+            on EVERY group or none (enforced at rendezvous via the
+            config fingerprint and per-op via the preamble).
         heal_striped: stripe a heal transfer across ALL live donors
             concurrently (docs/design/sharded_update.md; env
             ``TORCHFT_HEAL_STRIPED``, default on). Participants publish
@@ -406,27 +558,27 @@ class Manager:
             raise ValueError(
                 "overlap_steps must be 0 (sync commit) or 1 (one-step "
                 f"deferred commit), got {overlap_steps!r}")
-        self._overlap_steps = int(overlap_steps)
-        # --- adaptive FT policy (docs/design/adaptive_policy.md) ---------
+        # The Manager's own counters; each feature's are merged below.
+        self._metrics: Dict[str, float] = dict(_METRICS)
+        self._metrics_lock = threading.Lock()
+        # --- the commit boundary (docs/design/commit_boundary.md) --------
+        self._boundary = boundary_mod.Boundary(
+            tracer=self._tracer, lock=self._metrics_lock,
+            record=self._record, gauge=self._gauge,
+            log_event=self._log_event, flight_dump=self._flight_dump,
+            view=self._boundary_view, replica_id=self.replica_id,
+            participating=self.is_participating,
+            decider=lambda: (self._participating_rank == 0
+                             and self.is_participating()),
+            coordination=self._coordination,
+            store_client=self._store_client, timeout_ms=timeout_ms)
         # The FT knobs (overlap_steps / wire rung / DiLoCo / durable-
-        # checkpoint cadence) live in ONE hot-swappable FTPolicy. An
-        # explicit `policy=` wins over the legacy knob args; with only a
-        # controller, its ladder's rung 0 is the starting policy; with
-        # neither, a fixed policy is synthesized from the legacy knobs so
-        # every Manager reports a coherent policy_name (and stays
-        # switchable via set_policy). `_policy_aware` gates the parts
-        # with cross-version surface (state-dict policy fields, the
-        # "dynamic" rendezvous fingerprint): only managers explicitly
-        # opted into hot-swapping carry them.
-        self._controller = policy_controller
-        self._policy_aware = (policy is not None
-                              or policy_controller is not None)
-        if policy is None:
-            policy = (policy_controller.policy()
-                      if policy_controller is not None
-                      else policy_mod.from_knobs(self._overlap_steps,
-                                                 allreduce_wire_dtype))
-        self._policy = policy
+        # checkpoint cadence) live in ONE hot-swappable FTPolicy.
+        self._switch = policy_mod.PolicySwitch(
+            self._boundary, policy, policy_controller,
+            (overlap_steps, allreduce_wire_dtype),
+            lambda p: self._exchange.set_wire(p.wire, p.wire_dtype()),
+            self.metrics)
         # The cross-group exchange (torchft_tpu/exchange.py) owns the
         # gradient bytes. Its fourth stage (scale + device_put back)
         # runs on this single worker, so puts stay ordered and never
@@ -442,81 +594,19 @@ class Manager:
                 not in ("0", "false")
         self._exchange = GradExchange(
             comm, self._tracer, self._record, self._put_executor,
-            self._set_residual_gauge,
+            lambda n: self._gauge(wire_quant_residual_bytes=n),
             bucket_bytes=allreduce_bucket_bytes,
-            wire_dtype=allreduce_wire_dtype, wire_rung=policy.wire,
+            wire_dtype=allreduce_wire_dtype,
+            wire_rung=self._switch.policy.wire,
             device_quant=device_quantize)
-        if self._policy_aware:
-            self._install_policy_knobs(policy)
-        if self._controller is not None:
-            rung = self._controller.rung_of(policy)
-            if rung is not None:
-                self._controller.sync_rung(rung)
-        # Decider-side staged proposal + latest published decision
-        # (step, rung, reason, signals), and the per-boundary counter
-        # snapshot the comm/compute signal derives from.
-        self._policy_pending: Optional[tuple] = None
-        self._policy_published: Optional[tuple] = None
-        self._policy_last_reason = "init"
-        self._policy_prev_counters: Optional[Dict[str, float]] = None
-        # Last quorum round's coordination facts (store address,
-        # replica/max world) — stamped by _async_quorum_inner, consumed
-        # by the commit-boundary hook.
-        self._policy_round: Optional[tuple] = None
+        if self._switch.aware:
+            self._exchange.set_wire(self._switch.policy.wire,
+                                    self._switch.policy.wire_dtype())
         self._shard_update = bool(shard_update)
-        # --- degraded-mode groups (docs/design/degraded_mode.md) ---------
-        # Weighted folding is a CLUSTER-WIDE wire-format property (every
-        # group weighted or none — mode mixing is a per-op preamble
-        # abort), so it is a launch flag like shard_update, not a live
-        # knob; the per-group capacity fraction IS live
-        # (request_degrade/request_restore, landing only at commit
-        # boundaries). _step_samples, when reported (set_step_samples /
-        # an ElasticSampler draw), is the exact fold weight; otherwise
-        # the weight derives from the capacity fraction at a fixed
-        # scale, so groups sharing a batch config stay proportional.
-        if degraded_mode is None:
-            degraded_mode = os.environ.get(
-                "TORCHFT_DEGRADED", "0").strip() in ("1", "true")
-        self._degraded = bool(degraded_mode)
-        if self._degraded and getattr(comm, "wants_device_arrays", False):
-            raise ValueError(
-                "degraded_mode requires a host-path communicator: the "
-                "weighted fold lives in the host ring's wire ops, which "
-                "on-device backends never issue")
-        self._capacity_fraction = 1.0
-        self._step_samples: Optional[int] = None
-        # --- straggler-aware rebalance (docs/design/fleet_rebalance.md) --
-        # Like degraded_mode, arming rebalance switches the fold into
-        # weighted mode — a cluster-wide WIRE-FORMAT property (every
-        # group weighted or none; mixing is a per-op preamble abort) —
-        # so it is a launch flag, not a live knob. The per-group batch
-        # fraction itself IS live: the lighthouse Rebalancer computes
-        # it from persistent straggler scores, the decider publishes it
-        # on the quorum store, and every group adopts only at commit
-        # boundaries (save_durable's refusal classes defer a boundary).
-        # _rebalance_frac_prev is the fraction that was IN FORCE for
-        # the step the next digest measures: the digest is pushed after
-        # adoption lands, so stamping the live value would mis-
-        # normalize the just-measured wall by one boundary.
-        if rebalance is None:
-            rebalance = os.environ.get(
-                "TORCHFT_REBALANCE", "0").strip().lower() in ("1", "true")
-        self._rebalance = bool(rebalance)
-        if self._rebalance and getattr(comm, "wants_device_arrays", False):
-            raise ValueError(
-                "rebalance requires a host-path communicator: the "
-                "weighted fold lives in the host ring's wire ops, which "
-                "on-device backends never issue")
-        self._rebalance_fraction = 1.0
-        self._rebalance_frac_prev = 1.0
-        self._rebalance_table = ""
-        self._rebalance_published: Optional[tuple] = None
-        # Chaos slow: band bookkeeping — last boundary timestamp and
-        # the sleep injected there, so the stretch applies to the
-        # NATURAL wall only (sleeping (f-1)x a wall that already
-        # includes the prior injection diverges for f >= 2).
-        self._chaos_slow_prev: Optional[float] = None
-        self._chaos_slow_injected = 0.0
+        # Degraded-mode capacity x rebalance fraction (the fold's weight).
+        self._share = degraded.BatchShare(
+            self._boundary, degraded_mode, rebalance,
+            getattr(comm, "wants_device_arrays", False))
         if heal_striped is None:
             heal_striped = os.environ.get(
                 "TORCHFT_HEAL_STRIPED", "1").strip() not in ("0", "false")
@@ -616,236 +706,6 @@ class Manager:
         self._pending_state_dict: Optional[Dict[str, Any]] = None
         self._pending_work: list[Future] = []
         self._quorum_future: Optional[Future] = None
-        # Lightweight observability: counters + cumulative timings (ms).
-        # The reference exposes only current_step/batches_committed
-        # (manager.py:484-506); these cover the SRE questions its dashboard
-        # can't answer (how long do quorums take, how often do we heal).
-        self._metrics: Dict[str, float] = {
-            "quorum_count": 0, "quorum_ms_total": 0.0, "quorum_ms_last": 0.0,
-            # Control-plane scaling observability
-            # (docs/design/control_plane.md): rounds served from the
-            # lighthouse's membership-unchanged cache vs. full rendezvous
-            # rounds, and the lighthouse's monotonic decision epoch as of
-            # the last round. quorum_ms_p50/p95/max (from a bounded
-            # reservoir) and lighthouse_redials join them in metrics().
-            "quorum_fast_path_hits": 0,
-            "quorum_slow_path_rounds": 0,
-            "quorum_epoch_last": 0,
-            "reconfigure_count": 0, "reconfigure_ms_total": 0.0,
-            "heal_count": 0,
-            "heal_ms_total": 0.0, "heal_bytes_total": 0.0,
-            # The heal transfer's stages, busy ms (docs/design/
-            # healing.md): this group as the healer (waiting for the
-            # donor's manifest; in the socket; crc32; device_put and its
-            # copy) and as a donor (D2H of the digest pass and of the
-            # streams; socket writes). The healer's four over
-            # heal_ms_total say how far the stages ran beside each other
-            # (1.0: one after the other).
-            "heal_manifest_ms_total": 0.0, "heal_recv_ms_total": 0.0,
-            "heal_verify_ms_total": 0.0, "heal_place_ms_total": 0.0,
-            "heal_serve_fetch_ms_total": 0.0,
-            "heal_serve_send_ms_total": 0.0,
-            # Resilient-heal observability: bytes re-sent by resumed
-            # attempts (strictly less than the payload when resume
-            # works), donor failovers, leaves caught by digest
-            # verification, fetch rounds, and a live progress gauge
-            # (committed/payload bytes of the CURRENT transfer, updated
-            # per verified leaf — visible mid-heal in /metrics.json).
-            "heal_bytes_resumed_total": 0.0,
-            "heal_donor_failovers": 0.0,
-            "heal_leaf_digest_mismatches": 0.0,
-            "heal_attempts_total": 0.0,
-            "heal_last_bytes_committed": 0.0,
-            "heal_last_payload_bytes": 0.0,
-            # Striped-heal observability: donors the last heal actually
-            # fetched from (1 = single-donor path).
-            "heal_striped_donors": 0.0,
-            "allreduce_count": 0, "allreduce_ms_total": 0.0,
-            # Stage breakdown of the pipelined host allreduce (cumulative
-            # BUSY ms per stage; stages overlap across buckets, so sums
-            # can exceed allreduce_ms_total — they attribute, not
-            # partition). fetch = dispatch + wait: dispatch is the cost
-            # of kicking off packs + async D2H copies, wait is the time
-            # blocked on DMA completion. wire_bytes counts what actually
-            # crossed D2H; the ring leg's bytes
-            # (allreduce_ring_wire_bytes_total) come from the backend's
-            # own send counter and are merged in metrics().
-            "allreduce_fetch_ms_total": 0.0,
-            "allreduce_fetch_dispatch_ms_total": 0.0,
-            "allreduce_fetch_wait_ms_total": 0.0,
-            "allreduce_ring_ms_total": 0.0,
-            "allreduce_put_ms_total": 0.0, "allreduce_wire_bytes_total": 0.0,
-            # Actual device->host traffic of the fetch stage (what
-            # device_get / copy_to_host_async really moved — wire bytes
-            # under device-side quantization, NOT grad bytes). Tracks
-            # allreduce_wire_bytes_total today but is frozen under its
-            # own name so the devquant A/B and bench fetch accounting
-            # never conflate "bytes fetched" with "payload represented".
-            "allreduce_d2h_wire_bytes_total": 0.0,
-            # Bytes of gradient copied host-to-host between the fetch
-            # and the ring's first send (bytes): assembling a mixed
-            # host/device chunk or the hostcast leg here, a
-            # non-contiguous wire buffer in the backend (merged in
-            # metrics() with the accumulator counts). 0 where every
-            # leaf is on the device: the ring reads the fetched buffer.
-            "allreduce_host_copy_bytes_total": 0.0,
-            # Wire ops handed to the ring (one a bucket), and how many
-            # of them were one slice of a leaf wider than _SLICE_BYTES:
-            # per committed step they say how often the cut engages.
-            "allreduce_ring_ops_total": 0.0,
-            "allreduce_split_slices_total": 0.0,
-            # Cross-step overlap engine (docs/design/overlap.md):
-            # hidden = comm wall that ran concurrently with the caller's
-            # compute between dispatch and drain (the ms the engine
-            # exists to hide); drain_wait = what the caller still
-            # blocked on at the settle boundary; inflight = live
-            # allreduce futures right now (gauge); deferred/dropped
-            # count staged steps and stale-grad drops (vote aborts,
-            # latched comm errors, heals).
-            "allreduce_hidden_ms_total": 0.0,
-            "allreduce_drain_wait_ms_total": 0.0,
-            "allreduce_inflight": 0,
-            "overlap_steps_deferred": 0,
-            "overlap_grads_dropped": 0,
-            # ZeRO-style sharded update (docs/design/sharded_update.md):
-            # reduce-scatter rounds, the optimizer's stripe-update wall
-            # (pack + tx.update + allgather + reassembly, recorded by
-            # FTOptimizer via record_update), the live stripe
-            # optimizer-state footprint (gauge — ~1/world of the full
-            # state), and stripe-state resets forced by geometry changes
-            # (membership change ⇒ every rank resets together, keeping
-            # params lockstep).
-            "reduce_scatter_count": 0,
-            "update_count": 0, "update_ms_total": 0.0,
-            "shard_state_bytes": 0.0,
-            "shard_state_resets": 0,
-            "commit_count": 0, "commit_ms_total": 0.0,
-            "committed_steps": 0, "aborted_steps": 0,
-            # Durable-checkpoint observability (cold-start resilience,
-            # docs/design/durable_checkpoints.md): corrupt snapshots
-            # quarantined / newer candidates skipped by recovery scans,
-            # cold starts performed, and commit-coupled saves refused
-            # because the state was mid-heal/errored/uncommitted. The
-            # writer-side counters (ckpt_save_count/-fatal/-stalls, last
-            # error) merge in from the attached AsyncCheckpointer in
-            # metrics().
-            "ckpt_corrupt_quarantined": 0.0,
-            "ckpt_recover_fallbacks": 0.0,
-            "ckpt_recover_legacy": 0.0,
-            "ckpt_cold_starts": 0.0,
-            "ckpt_save_skipped": 0.0,
-            # Ranged-fetch connection reuse (heal + serving transport):
-            # requests served over an already-open per-donor connection
-            # instead of a fresh TCP dial.
-            "heal_redials_avoided": 0.0,
-            # Live-publication tier (docs/design/serving.md): commit-
-            # coupled publishes, refusals (mid-heal/errored/aborted/
-            # deferred state — the publish analogue of ckpt_save_skipped),
-            # cumulative publish wall, and the newest generation id
-            # (gauge). The attached WeightPublisher's own counters
-            # (publish_generations, delta bytes/ratio, serve volume)
-            # merge in via metrics().
-            "publish_count": 0.0,
-            "publish_skipped": 0.0,
-            "publish_ms_total": 0.0,
-            "publish_last_generation": 0.0,
-            # Adaptive-policy observability
-            # (docs/design/adaptive_policy.md): the ladder rung in force
-            # (gauge; -1 = not on the attached controller's ladder /
-            # no controller), applied switches, refusals (mid-heal /
-            # errored / deferred — the switch analogue of
-            # ckpt_save_skipped), switches deferred because a heal was
-            # in flight somewhere in the quorum, the controller's
-            # windowed failure-rate estimate (gauge), and the int8
-            # rung's live error-feedback residual footprint (gauge).
-            # policy_name / policy_last_reason are strings and live in
-            # metrics_info() with ckpt_last_error (the numeric/string
-            # split, docs/design/observability.md).
-            # Degraded-mode groups (docs/design/degraded_mode.md): the
-            # capacity fraction in force (gauge, 1.0 = full capacity),
-            # and the count of degrade / restore transitions that
-            # actually landed (refusals ride the event log).
-            "degraded_capacity_fraction": 1.0,
-            "degrade_events_total": 0.0,
-            "restore_events_total": 0.0,
-            # Straggler-aware rebalance (docs/design/fleet_rebalance.md):
-            # the lighthouse-assigned batch fraction in force (gauge,
-            # 1.0 = uniform share), adoptions that landed, and adoptions
-            # deferred a boundary by save_durable's refusal classes.
-            "rebalance_fraction": 1.0,
-            "rebalance_adoptions_total": 0.0,
-            "rebalance_deferred_total": 0.0,
-            "policy_current": -1.0,
-            "policy_switches_total": 0.0,
-            "policy_switch_refusals": 0.0,
-            "policy_switch_deferrals": 0.0,
-            "failure_rate": 0.0,
-            "wire_quant_residual_bytes": 0.0,
-            # Spot-instance churn (docs/design/churn.md): preemption
-            # notices received (SIGTERM / request_preemption), drains
-            # deferred past a boundary (mid-heal / mid-deferred /
-            # errored / aborted — the save_durable refusal classes),
-            # graceful exits completed (farewell sent, ads withdrawn),
-            # reclaim deadlines that expired before the drain landed
-            # (degraded to hard-kill behavior + a flight dump), cold
-            # pre-join heals (join backpressure: the replacement healed
-            # BEFORE its first quorum join), and joiners this manager
-            # observed being admitted as one coalesced membership delta
-            # (world grew by >1 in a single reconfigure).
-            # reconfigures_per_min (ring rebuilds in the trailing
-            # 60 s) is computed at metrics() read time.
-            "preempt_notices_total": 0.0,
-            "preempt_drain_deferrals_total": 0.0,
-            "preempt_deadline_expired_total": 0.0,
-            "graceful_exits_total": 0.0,
-            "prejoin_heals_total": 0.0,
-            "joins_coalesced_total": 0.0,
-            # Fleet health plane (docs/design/fleet_health.md): the
-            # lighthouse's per-requester hint, refreshed every quorum
-            # round — fleet p95 step wall, this group's robust-z
-            # straggler score, groups contributing digests, whether
-            # this group is currently out of any SLO (gauge), and the
-            # cumulative SLO breaches echoed to this group. All zero
-            # with no digests / no native control plane.
-            "fleet_p95_ms": 0.0,
-            "straggler_score": 0.0,
-            "fleet_groups": 0.0,
-            "slo_breach": 0.0,
-            "slo_breaches_total": 0.0,
-            # RAM checkpoint tier (docs/design/memory_tier.md): heals
-            # served from a peer's RAM rung instead of disk, and
-            # commit-boundary replications refused because the state
-            # was mid-heal/errored/uncommitted/deferred (the
-            # ckpt_save_skipped analogue). The store/replicator's own
-            # counters (ram_ckpt_peers, ram_ckpt_bytes_replicated_total,
-            # demote_stage_ms_total, …) merge in via metrics() while
-            # the tier is enabled.
-            "ram_ckpt_heals_total": 0.0,
-            "ram_replicate_skipped": 0.0,
-            "ram_replicate_errors_total": 0.0,
-            "ram_replica_collapses_total": 0.0,
-            # State attestation (docs/design/state_attestation.md):
-            # fingerprints computed, digests that raised and were
-            # swallowed, and the fingerprints' cumulative wall; whether
-            # THIS group is currently under a divergence verdict
-            # (gauge) and how often it entered/left quarantine; the
-            # recovery heals the verdict forced; boundary actions the
-            # quarantine refused (save/publish/RAM-replicate) on top
-            # of their per-path skip counters; and chaos sdc: band
-            # bit-flips actually applied.
-            "sdc_digests_total": 0.0,
-            "sdc_digest_failures": 0.0,
-            "sdc_digest_ms_total": 0.0,
-            "sdc_quarantined": 0.0,
-            "sdc_quarantines_total": 0.0,
-            "sdc_quarantine_clears_total": 0.0,
-            "sdc_reheals_total": 0.0,
-            "sdc_refusals_total": 0.0,
-            "sdc_chaos_flips_total": 0.0,
-        }
-        self._metrics_lock = threading.Lock()
-        if self._controller is not None:
-            self._metrics["policy_current"] = float(self._controller.rung)
         # Quorum latency distribution (p50/p95/max in metrics()): bounded
         # reservoir, mutated under the metrics lock on the quorum thread.
         self._quorum_latency = _LatencyReservoir()
@@ -914,28 +774,12 @@ class Manager:
         # every poisoned group independently computes the same prefix and
         # they re-mesh without any extra coordination channel.
         self._comm_poisoned = False
-        # --- graceful preemption drain (docs/design/churn.md) ------------
-        # A reclaim notice (SIGTERM / request_preemption) arms a drain
-        # that lands at the next CLEAN commit boundary: farewell first
-        # (membership intent must beat the survivors' next quorum
-        # round), then the final durable save, then advertisement
-        # withdrawal, then shutdown. _preempt is None or
-        # {"deadline": monotonic, "reason": str}; _drained flips once
-        # the drain completed (step() then raises PreemptedExit);
-        # _preempt_expired latches the degraded-to-hard-kill outcome.
-        # _durable_target is the (writer, directory, prefix,
-        # user_state_fn) the final save goes to (set_durable_target /
-        # auto-remembered from save_durable).
-        self._preempt: Optional[Dict[str, Any]] = None
-        self._drained = False
-        self._preempt_expired = False
-        self._durable_target: Optional[tuple] = None
-        self._durable_explicit = False
         self._shutdown_done = False
-        # Facts of the last validated quorum round consumed by the
-        # drain's advertisement withdrawal and the RAM tier's peer
-        # discovery: (store_address, replica_rank, max_world_size).
-        # None before the first round.
+        # Facts of the last validated quorum round, for what runs after
+        # the quorum thread has moved on (the boundary's coordinated
+        # decisions, advertisement withdrawal, the RAM tier's peer
+        # discovery): (store_address, replica_rank, max_world_size,
+        # replica_world_size). None before the first round.
         self._last_round_facts: Optional[tuple] = None
         # Churn-rate observability: monotonic stamps of recent ring
         # reconfigures (reconfigures_per_min gauge), and the previous
@@ -974,32 +818,47 @@ class Manager:
             auth_token=self._auth_token,
         )
 
-        # --- RAM checkpoint tier (docs/design/memory_tier.md) ------------
-        # Armed in _init_observability (the replica id must exist for
-        # the chaos scope + log attribution): at every commit boundary
-        # the committed snapshot is encoded once and cross-replicated to
-        # K peer hosts' RAM over the striped transport run in reverse,
-        # then demoted RAM -> local disk -> durable store off the
-        # training loop. 0 peers (the default) leaves the tier off and
-        # every path bit-exact with pre-tier builds.
-        self._ram_store: Optional[Any] = None
-        self._ram_replicator: Optional[Any] = None
-        self._ram_peers_k = 0
-        self._ram_demote_dir = (ram_demote_dir
-                                or os.environ.get("TORCHFT_RAM_DEMOTE_DIR")
-                                or None)
-        self._ram_prefix = "ckpt_"
-        # High-water mark of peers that accepted a replication — a drop
-        # to 0 afterwards is a replication-set collapse (flight dump).
-        self._ram_peers_seen = 0.0
-        self._ram_collapse_dumped = False
-        if ram_ckpt_peers is None:
-            try:
-                ram_ckpt_peers = int(
-                    os.environ.get("TORCHFT_RAM_CKPT_PEERS", "0"))
-            except ValueError:
-                ram_ckpt_peers = 0
-        self._ram_peers_pending = max(int(ram_ckpt_peers), 0)
+        # --- the boundary's features (docs/design/commit_boundary.md) ----
+        self._drain = PreemptionDrain(
+            self._boundary, self._send_farewell,
+            lambda *a, **kw: self.save_durable(*a, **kw),
+            self._withdraw_advertisements, self.shutdown)
+        self._ram = ram_ckpt.RamTier(
+            self._boundary, self._ckpt_server, self._ram_peer_bases,
+            lambda: (self._user_state_dict(), self.state_dict(),
+                     self._snapshot_meta()),
+            peers=ram_ckpt_peers, demote_dir=ram_demote_dir,
+            auth_token=self._auth_token,
+            retry_policy=self._retry_policy,
+            retry_stats=self._retry_stats)
+        self._chaos_sdc = chaos.SdcBand(
+            self._boundary, lambda: self._user_state_dict(),
+            lambda state: self._user_load_state_dict(state))
+        self._chaos_slow = chaos.SlowBand(self._boundary)
+        # THE ORDER IS THE PROTOCOL (docs/design/commit_boundary.md).
+        # step() walks every feature's at_step_edge (the caller has
+        # applied the committed update, so what is saved or replicated
+        # there carries step N's metadata over step N's params), then
+        # makes its own refusals; should_commit() walks every pre_vote
+        # and, after the vote's own record, every post_vote:
+        # - the drain first: a landed drain ends the run, and one that
+        #   a deferred step blocks is counted before step() refuses to
+        #   advance over that step;
+        # - RAM replication, at the edge of the drain's final save and
+        #   for its reason;
+        # - the sdc band at the same edge: the corrupted params train
+        #   this step and lose the attestation vote at the NEXT
+        #   boundary (the <=1-boundary detection bound the soak
+        #   asserts); then the slow band, whose sleep stretches the
+        #   wall of the step about to start;
+        # - around the vote, the policy switch, then the batch share:
+        #   each decider publishes before the vote and every group
+        #   adopts after it; neither reads what the other writes.
+        self._features: Tuple[boundary_mod.BoundaryFeature, ...] = (
+            self._drain, self._ram, self._chaos_sdc, self._chaos_slow,
+            self._switch, self._share)
+        for feature in self._features:
+            self._metrics.update(feature.METRICS)
 
         if _manager_client is not None:
             # Test hook: fully wired externally (mirrors patching
@@ -1066,7 +925,7 @@ class Manager:
         transports in tests."""
         self._tracer.set_context(replica_id=self._replica_id,
                                  step=self._step,
-                                 policy_name=self._policy.name)
+                                 policy_name=self._switch.policy.name)
         self._flight = tracing_mod.FlightRecorder(
             self._tracer, replica_id=self._replica_id,
             metrics_fn=self.metrics, info_fn=self.metrics_info,
@@ -1076,9 +935,7 @@ class Manager:
             attach(tracer=self._tracer, metrics_fn=self.metrics,
                    info_fn=self.metrics_info,
                    labels={"replica_id": self._replica_id})
-        if self._ram_peers_pending > 0:
-            self.enable_ram_tier(peers=self._ram_peers_pending,
-                                 demote_dir=self._ram_demote_dir)
+        self._ram.enable_pending()
 
     def _flight_dump(self, reason: str, **extra: Any) -> None:
         """Trigger a flight-recorder dump (no-op without
@@ -1102,27 +959,12 @@ class Manager:
         nor count as aborted, silently losing a step the protocol
         thinks succeeded.
         """
-        if self._drained:
-            raise PreemptedExit(
-                f"{self._replica_id}: graceful preemption drain completed "
-                f"at step {self._step}; the training loop must exit "
-                "(this is the noticed-reclaim success path)")
-        # Preemption drain (docs/design/churn.md): a pending reclaim
-        # notice lands HERE — the post-apply half of the last commit
-        # boundary. Inside should_commit the caller has not yet applied
-        # the committed update, so a save there would persist step N's
-        # metadata over step N-1's params (a committed step silently
-        # lost on a fleet-wide drain); by the next step() the update is
-        # applied and the final save follows the exact convention of
-        # the cadence saves. Blocked boundaries (mid-heal, mid-deferred,
-        # errored, aborted vote) defer to the next one.
-        if self._preempt is not None:
-            self._maybe_drain(self._should_step)
-            if self._drained:
-                raise PreemptedExit(
-                    f"{self._replica_id}: graceful preemption drain "
-                    f"completed at step {self._step}; the training loop "
-                    "must exit (this is the noticed-reclaim success path)")
+        # The step edge — the post-apply half of the last commit
+        # boundary — in the order of ``_features`` (a landed preemption
+        # drain raises :class:`PreemptedExit` here), then this step's
+        # own refusals.
+        for feature in self._features:
+            feature.at_step_edge(self._should_step)
         if self._deferred is not None:
             raise RuntimeError(
                 f"{self._replica_id}: step {self._step} has a deferred "
@@ -1143,29 +985,6 @@ class Manager:
             # into a busy spin of doomed RPCs.
             time.sleep(min(0.05 * streak, 1.0))
 
-        # RAM checkpoint tier (docs/design/memory_tier.md): replicate
-        # the committed snapshot to K peer hosts' RAM HERE — the same
-        # post-apply edge the preemption drain lands on, and for the
-        # same reason: the caller has applied the committed update, so
-        # the image carries step N's metadata over step N's params.
-        # Refusal classes (mid-heal / errored / aborted / deferred)
-        # skip the boundary; the cost on the loop is one on-device
-        # snapshot — encode and the demotion ladder run behind it.
-        self._maybe_replicate_ram()
-
-        # Chaos sdc: band (docs/design/state_attestation.md): the
-        # deterministic post-commit bit-flip rides the SAME boundary
-        # edge — the corrupted params train this step and lose the
-        # attestation vote at the NEXT boundary, which is exactly the
-        # ≤1-boundary detection-latency bound the soak asserts.
-        self._maybe_chaos_sdc()
-
-        # Chaos slow: band (docs/design/fleet_rebalance.md): stretch
-        # this group's step wall at the same edge, so soaks can mint a
-        # persistent straggler the lighthouse Rebalancer must shrink —
-        # without wall-clock hacks.
-        self._maybe_chaos_slow()
-
         if self._should_step:
             # Under the metrics lock so (participant_rank,
             # batches_committed) snapshots (participant_slot()) can never
@@ -1185,7 +1004,7 @@ class Manager:
         # (quorum_id/epoch refresh on the quorum thread once the round
         # resolves).
         self._tracer.set_context(step=self._step,
-                                 policy_name=self._policy.name)
+                                 policy_name=self.policy().name)
 
         self._quorum_future = self._executor.submit(self._async_quorum)
         if not self._use_async_quorum:
@@ -1272,19 +1091,13 @@ class Manager:
         # guilty stage).
         self._consume_fleet_hint(q)
 
-        # Coordination facts for the adaptive-policy commit hook: the
-        # quorum store the decision key rides on, and whether anyone in
-        # the quorum is healing this round (max_world < replica_world ⇒
-        # a member is behind max_step ⇒ the decider defers switches —
-        # the "refused mid-heal, retried next boundary" rule).
-        self._policy_round = (getattr(q, "store_address", "") or "",
-                              q.replica_world_size, q.max_world_size)
-        # Facts the graceful drain's advertisement withdrawal and the
-        # RAM tier's peer discovery need after the quorum thread has
-        # moved on (store + our healset key rank + the rank space to
-        # scan, docs/design/churn.md + memory_tier.md).
+        # The quorum store the boundary's decision keys and our healset
+        # key ride on, our healset rank, the rank space to scan, and
+        # the replica world (max_world < replica_world ⇒ a member is
+        # behind max_step, healing ⇒ the policy decider defers).
         self._last_round_facts = (getattr(q, "store_address", "") or "",
-                                  q.replica_rank, q.max_world_size)
+                                  q.replica_rank, q.max_world_size,
+                                  q.replica_world_size)
 
         with self._metrics_lock:  # pair with participant_slot() snapshots
             if self._use_async_quorum:
@@ -1385,11 +1198,11 @@ class Manager:
                 # _SLICE_BYTES cross the ring as slices, one op each —
                 # a pre-v6 rank would submit fewer, wider ops and the
                 # ring would wedge on mismatched op counts.
-                wire_fp = ("dynamic" if self._policy_aware
+                wire_fp = ("dynamic" if self._switch.aware
                            else str(self._exchange.wire_dtype))
                 setter(f"bucket_bytes={self._exchange.bucket_bytes};"
                        f"wire_dtype={wire_fp};"
-                       f"degraded={int(self._degraded)};"
+                       f"degraded={int(self._share.degraded)};"
                        f"payload=wire-v6")
             reconf_t0 = time.perf_counter()
             self._comm.configure(
@@ -1444,7 +1257,8 @@ class Manager:
             # the native client (tests) or a flaky set must never fail a
             # training step.
             self._publish_healset(q)
-            self._publish_capacity(q)
+            self._share.publish_capacity(
+                lambda: self._store_client(q.store_address), q.replica_rank)
         else:
             # We are lagging (or a fresh step-1 non-primary): fetch the
             # primary's live weights (reference manager.py:380-396).
@@ -1463,28 +1277,14 @@ class Manager:
             try:
                 ckpt_addr = self._resolve_checkpoint_addr(
                     q.recover_manager_address)
-                target = self._manager_state_dict()
-                with self._metrics_lock:  # fresh gauges for this transfer
-                    self._metrics["heal_last_bytes_committed"] = 0.0
-                    self._metrics["heal_last_payload_bytes"] = 0.0
+                # fresh gauges for this transfer
+                self._gauge(heal_last_bytes_committed=0.0,
+                            heal_last_payload_bytes=0.0)
                 donor_addrs = (self._healset_donors(q, ckpt_addr)
                                if self._heal_striped else None)
-                state = cast(
-                    Dict[str, Any],
-                    CheckpointServer.load_from_address(
-                        ckpt_addr, target, stats=heal_stats,
-                        auth_token=self._auth_token,
-                        retry_policy=self._retry_policy,
-                        retry_stats=self._retry_stats,
-                        stall_timeout_sec=self._heal_stall_timeout_sec,
-                        donors=lambda i: self._resolve_next_donor(i, q),
-                        max_donor_failovers=(
-                            self._heal_max_donor_failovers),
-                        donor_addrs=donor_addrs,
-                        stripe_seed=_stripe_seed(self._replica_id),
-                        progress_cb=self._heal_progress,
-                        tracer=self._tracer),
-                )
+                state = self._fetch_state(
+                    donor_addrs or [ckpt_addr], heal_stats,
+                    failover=lambda i: self._resolve_next_donor(i, q))
             finally:
                 # Failed heals count too: without this, an aborted fetch's
                 # seconds leak into whatever the caller's "unattributed"
@@ -1510,9 +1310,8 @@ class Manager:
                         "redials_avoided", 0.0),
                     **_heal_stage_ms(heal_stats),
                 )
-                with self._metrics_lock:  # gauge, not a counter
-                    self._metrics["heal_striped_donors"] = heal_stats.get(
-                        "donors_used", 1.0)
+                self._gauge(heal_striped_donors=heal_stats.get(
+                    "donors_used", 1.0))
                 self._log_event(
                     event="heal", step=self._step,
                     source=q.recover_manager_address,
@@ -1583,10 +1382,10 @@ class Manager:
             # (pre-rebalance lighthouses, duck-typed test clients) is
             # inert, so an old control plane never reads as a
             # restore-everyone-to-1.0 order. Adoption happens only at
-            # the commit boundary (_rebalance_post_vote).
+            # the commit boundary (BatchShare.post_vote).
             rt = getattr(q, "rebalance_table", None)
             if isinstance(rt, str):
-                self._rebalance_table = rt
+                self._share.table = rt
         self._consume_sdc_verdict(q)
         if not fresh:
             return
@@ -1699,22 +1498,10 @@ class Manager:
         self._record(sdc_reheals_total=1)
         donors: list = []
         try:
-            store = self._healset_client(q)
+            store = self._store_client(q.store_address)
             if store is not None:
-                for r in range(q.max_world_size):
-                    if r == q.replica_rank:
-                        continue  # our own (tombstoned) advertisement
-                    try:
-                        v = store.get(f"torchft/healset/{r}",
-                                      timeout_ms=200).decode()
-                    except Exception:  # noqa: BLE001 — absent rank key
-                        continue
-                    step_s, _, a = v.partition(":")
-                    if not self._donor_admissible(a, step_s=step_s,
-                                                  max_step=q.max_step):
-                        continue  # stale/tombstoned/quarantined
-                    if a not in donors:
-                        donors.append(a)
+                donors = self._healset_addrs(
+                    store, q.replica_rank, q.max_world_size, q.max_step)
         except Exception:  # noqa: BLE001 — scrape is best-effort
             logger.debug("sdc reheal donor scrape failed", exc_info=True)
         if not donors and getattr(q, "recover_manager_address", ""):
@@ -1738,22 +1525,7 @@ class Manager:
                     len(donors), self._step)
         with self._tracer.span("sdc_reheal", donors=len(donors),
                                max_step=q.max_step):
-            target = self._manager_state_dict()
-            state = cast(
-                Dict[str, Any],
-                CheckpointServer.load_from_address(
-                    donors[0], target, stats=heal_stats,
-                    auth_token=self._auth_token,
-                    retry_policy=self._retry_policy,
-                    retry_stats=self._retry_stats,
-                    stall_timeout_sec=self._heal_stall_timeout_sec,
-                    donors=lambda i: None,
-                    max_donor_failovers=0,
-                    donor_addrs=donors if len(donors) > 1 else None,
-                    stripe_seed=_stripe_seed(self._replica_id),
-                    progress_cb=self._heal_progress,
-                    tracer=self._tracer),
-            )
+            state = self._fetch_state(donors, heal_stats)
         heal_ms = (time.perf_counter() - heal_t0) * 1e3
         self._record(heal_ms_total=heal_ms,
                      heal_bytes_total=heal_stats.get("bytes", 0.0),
@@ -1766,6 +1538,28 @@ class Manager:
         # the main thread at the commit boundary.
         self.load_state_dict(state["torchft"])
         self._pending_state_dict = state
+
+    def _fetch_state(self, addrs: list, stats: Dict[str, float],
+                     failover: Optional[Callable[[int], Optional[str]]]
+                     = None, progress: bool = True) -> Dict[str, Any]:
+        """The ONE spelling of the resumable, digest-verified fetch of
+        ``{user, torchft}`` from peers' checkpoint servers (in-quorum
+        heal, quarantine re-heal, pre-join heal, RAM-rung cold start):
+        striped across ``addrs`` when there are several; ``failover``
+        re-resolves a donor when the current one dies mid-heal."""
+        return cast(Dict[str, Any], CheckpointServer.load_from_address(
+            addrs[0], self._manager_state_dict(), stats=stats,
+            auth_token=self._auth_token,
+            retry_policy=self._retry_policy,
+            retry_stats=self._retry_stats,
+            stall_timeout_sec=self._heal_stall_timeout_sec,
+            donors=failover or (lambda i: None),
+            max_donor_failovers=(self._heal_max_donor_failovers
+                                 if failover else 0),
+            donor_addrs=addrs if len(addrs) > 1 else None,
+            stripe_seed=_stripe_seed(self._replica_id),
+            progress_cb=self._heal_progress if progress else None,
+            tracer=self._tracer))
 
     def _resolve_checkpoint_addr(self, manager_addr: str) -> str:
         """Resolve a peer manager's checkpoint-server URL for this
@@ -1821,9 +1615,8 @@ class Manager:
         """Per-verified-leaf progress gauge of the current heal transfer
         (rides metrics()/metrics.json, so an operator can watch a heal
         advance instead of staring at a silent multi-minute fetch)."""
-        with self._metrics_lock:
-            self._metrics["heal_last_bytes_committed"] = float(committed)
-            self._metrics["heal_last_payload_bytes"] = float(payload)
+        self._gauge(heal_last_bytes_committed=float(committed),
+                    heal_last_payload_bytes=float(payload))
 
     def _resolve_next_donor(self, failover_idx: int,
                             q: Any) -> Optional[str]:
@@ -1898,9 +1691,6 @@ class Manager:
         self._healset_store = (addr, client)
         return client
 
-    def _healset_client(self, q: Any) -> Optional[Any]:
-        return self._store_client(q.store_address)
-
     def _publish_healset(self, q: Any) -> None:
         """Advertise this participant's checkpoint address under the
         FIXED per-rank key ``torchft/healset/{replica_rank}`` on the
@@ -1913,7 +1703,7 @@ class Manager:
         if not self._heal_striped or q.replica_world_size <= 1:
             return
         try:
-            store = self._healset_client(q)
+            store = self._store_client(q.store_address)
             if store is None:
                 return
             store.set(
@@ -1922,35 +1712,43 @@ class Manager:
         except Exception:  # noqa: BLE001 — advertisement is best-effort
             logger.debug("healset publication failed", exc_info=True)
 
+    def _healset_addrs(self, store: Any, skip_rank: int, max_world: int,
+                       max_step: Optional[int] = None) -> list:
+        """The admissible (:meth:`_donor_admissible`) checkpoint
+        addresses the other ranks advertise under
+        ``torchft/healset/{rank}``. Live ranks re-publish every step, so
+        their keys exist and the gets return immediately; only
+        never-joined ranks burn the short absent-key timeout."""
+        addrs: list = []
+        for r in range(int(max_world)):
+            if r == skip_rank:
+                continue  # our own (absent or tombstoned) advertisement
+            try:
+                v = store.get(f"torchft/healset/{r}",
+                              timeout_ms=200).decode()
+            except Exception:  # noqa: BLE001 — absent rank key
+                continue
+            step_s, _, a = v.partition(":")
+            if a not in addrs and self._donor_admissible(
+                    a, step_s=step_s, max_step=max_step):
+                addrs.append(a)
+        return addrs
+
     def _healset_donors(self, q: Any,
                         primary_addr: str) -> Optional[list]:
         """Resolve the live donor set for a striped heal: the quorum's
         designated primary plus every peer whose advertisement carries
-        this heal's ``max_step``. Live ranks re-publish every step, so
-        their keys exist and the gets return immediately; only
-        never-joined ranks (and none of this is on the happy path — the
-        probe runs once per heal) burn the short absent-key timeout.
-        Returns None (single-donor fallback) when fewer than two
-        distinct donors emerge."""
+        this heal's ``max_step`` (none of this is on the happy path —
+        the probe runs once per heal). Returns None (single-donor
+        fallback) when fewer than two distinct donors emerge."""
         addrs = [primary_addr]
         try:
-            store = self._healset_client(q)
+            store = self._store_client(q.store_address)
             if store is None:
                 return None
-            for r in range(q.max_world_size):
-                if r == q.replica_rank:
-                    continue  # the healer itself never published
-                try:
-                    v = store.get(f"torchft/healset/{r}",
-                                  timeout_ms=200).decode()
-                except Exception:  # noqa: BLE001 — absent rank key
-                    continue
-                step_s, _, a = v.partition(":")
-                if not self._donor_admissible(a, step_s=step_s,
-                                              max_step=q.max_step):
-                    continue  # stale/tombstoned/quarantined
-                if a not in addrs:
-                    addrs.append(a)
+            addrs += [a for a in self._healset_addrs(
+                store, q.replica_rank, q.max_world_size, q.max_step)
+                if a != primary_addr]
         except Exception:  # noqa: BLE001 — resolution is best-effort
             logger.debug("healset donor listing failed", exc_info=True)
             return None
@@ -2051,15 +1849,12 @@ class Manager:
         by the total weight (backends/host.py), so the put's n is 1."""
         facts = StepFacts(
             participating=self.is_participating(),
-            n=1 if self._degraded else max(self.num_participants(), 1),
-            int8=self._policy.wire == policy_mod.WIRE_INT8)
+            n=(1 if self._share.degraded
+               else max(self.num_participants(), 1)),
+            int8=self._switch.policy.wire == policy_mod.WIRE_INT8)
         self._set_wire_tag()
         fut, default_fn = op(facts, tree, leaves, treedef)
         return self.wrap_future(fut, default_fn=default_fn)
-
-    def _set_residual_gauge(self, nbytes: float) -> None:
-        with self._metrics_lock:  # gauge, not a counter
-            self._metrics["wire_quant_residual_bytes"] = nbytes
 
     def _set_wire_tag(self) -> None:
         """Stamp the payload-kind tag AND the degraded-mode fold weight
@@ -2072,42 +1867,21 @@ class Manager:
         getattr tolerates bare duck-typed comms."""
         setter = getattr(self._comm, "set_wire_tag", None)
         if setter is not None:
-            setter("diloco" if self._policy.diloco else "step")
+            setter("diloco" if self._switch.policy.diloco else "step")
         wsetter = getattr(self._comm, "set_wire_weight", None)
         if wsetter is not None:
-            weighted = self._degraded or self._rebalance
-            wsetter(self._wire_weight() if weighted else -1)
-
-    def _wire_weight(self) -> int:
-        """This step's fold weight (degraded mode / rebalance): 0 while
-        healing or benched (the zero contribution must carry zero
-        weight), else the samples the caller reported via
-        :meth:`set_step_samples` (an
-        :class:`~torchft_tpu.data.ElasticSampler` draw reports
-        automatically), else a fixed-scale encoding of the EFFECTIVE
-        fraction (capacity x rebalance — the same product
-        :meth:`participant_slot` snapshots, so the sampler's draw and
-        the fallback weight always agree) — so groups that share a
-        batch config stay PROPORTIONAL whether or not they report
-        exact counts, as long as every group uses the same
-        convention."""
-        if not self.is_participating():
-            return 0
-        with self._metrics_lock:
-            samples = self._step_samples
-            frac = self._capacity_fraction * self._rebalance_fraction
-        if samples is not None:
-            return max(int(samples), 0)
-        return max(1, int(round(frac * _CAPACITY_WEIGHT_SCALE)))
+            wsetter(self._share.wire_weight() if self._share.weighted
+                    else -1)
 
     def set_step_samples(self, samples: Optional[int]) -> None:
         """Report the samples this group actually contributes this step
-        (the weighted fold's weight). ``None`` reverts to the
-        fraction-derived weight. No-op unless degraded mode or
-        rebalance armed the weighted fold."""
+        (the weighted fold's weight,
+        :meth:`~torchft_tpu.degraded.BatchShare.wire_weight`). ``None``
+        reverts to the fraction-derived weight. No-op unless degraded
+        mode or rebalance armed the weighted fold."""
         with self._metrics_lock:
-            self._step_samples = (None if samples is None
-                                  else int(samples))
+            self._share.step_samples = (None if samples is None
+                                        else int(samples))
 
     # alias matching the reference's gradient-specific spelling
     allreduce_grad = allreduce
@@ -2209,8 +1983,7 @@ class Manager:
         resets."""
         self._record(update_count=1, update_ms_total=ms,
                      shard_state_resets=resets)
-        with self._metrics_lock:
-            self._metrics["shard_state_bytes"] = float(shard_state_bytes)
+        self._gauge(shard_state_bytes=float(shard_state_bytes))
 
     def _join_quorum(self) -> None:
         """The exchange's own join of this step's quorum round, on the
@@ -2348,264 +2121,39 @@ class Manager:
                         else None)
 
     # -------------------------------------- graceful preemption drain
-    # Spot-instance churn survival (docs/design/churn.md): a cloud
-    # reclaim notice (SIGTERM with TORCHFT_RECLAIM_SEC of warning, or an
-    # explicit request_preemption) arms a drain that lands at the next
-    # CLEAN commit boundary — concretely at the step() call that
-    # follows it, once the caller has APPLIED the committed update
-    # (saving inside should_commit would persist step N's metadata
-    # over step N-1's params) — with the save_durable refusal
-    # discipline: a boundary that is mid-heal, mid-deferred, errored,
-    # or aborted defers the drain to the next one. The drain itself:
-    # (1) farewell
-    # FIRST — the leaving intent must reach the lighthouse before the
-    # survivors' next quorum round is served, or their already-
-    # dispatched step would run a collective against a peer that is
-    # about to vanish (the vote abort this protocol exists to avoid);
-    # everything after the farewell is local, so ordering it first
-    # costs nothing. (2) the final durable save to the registered
-    # target (sharded when the writer shards). (3) advertisement
-    # withdrawal: the healset key is tombstoned (step -1 never matches
-    # a heal's max_step) and the publication tier detaches, so no
-    # healer or subscriber is steered at a corpse. (4) shutdown; the
-    # next step() raises PreemptedExit and the loop exits 0. Deadline
-    # expiry at any point degrades to today's hard-kill behavior with
-    # a flight-recorder dump attributing where the drain was stuck.
 
     def set_durable_target(self, writer: Any, directory: str,
                            prefix: str = "ckpt_",
                            user_state_fn: Optional[Callable[[], Any]]
                            = None) -> None:
-        """Register where the graceful drain's FINAL durable save goes
+        """:meth:`torchft_tpu.preemption.PreemptionDrain.set_target`
         (and attach ``writer``'s counters to :meth:`metrics`, like
-        :meth:`save_durable` does). Callers already saving through
-        :meth:`save_durable` get this for free — it remembers its last
-        target — but a trainer that wants drain coverage from step 0
-        should register explicitly.
-
-        ``user_state_fn``: optional snapshot source for the final save,
-        for callers whose durable tree is richer than the
-        manager-registered state (the ``user_state`` analogue of
-        :meth:`save_durable` — e.g. a trainer checkpointing its loader
-        position alongside). The drain's file must load against the
-        same target structure as the cadence saves, or cold-start
-        resume breaks on a tree mismatch. An explicit registration is
-        never overwritten by later :meth:`save_durable` calls."""
+        :meth:`save_durable` does)."""
         self._ckpt_writer = writer
-        self._durable_target = (writer, directory, prefix, user_state_fn)
-        self._durable_explicit = True
+        self._drain.set_target(writer, directory, prefix, user_state_fn)
 
     def request_preemption(self, deadline_s: Optional[float] = None,
                            reason: str = "reclaim",
                            _signal_safe: bool = False) -> float:
-        """Arm the graceful preemption drain: this group will exit
-        cleanly at the next clean commit boundary (see the section
-        comment above). Idempotent under repeated notices: every
-        notice counts, the EARLIEST deadline wins.
-
-        ``_signal_safe`` (the installed SIGTERM handler passes True):
-        skip everything that acquires a lock — ``_metrics_lock``
-        (counters/events) and the logging module's handler locks. A
-        signal handler runs ON the main thread between bytecodes, so
-        taking a non-reentrant lock that the interrupted frame already
-        holds (step()'s advance block, any ``_record``) would deadlock
-        the training loop: no drain, no farewell, strictly worse than
-        no handler. The skipped accounting is staged in the
-        ``_preempt`` dict (plain main-thread field writes) and flushed
-        by :meth:`_maybe_drain` at the next boundary.
-
-        ``deadline_s`` is the reclaim warning the cloud gave (env
-        ``TORCHFT_RECLAIM_SEC``, default 120 — the common spot/
-        preemptible notice); past it the drain degrades to hard-kill
-        behavior with a flight dump. Returns the deadline in force (s
-        from now)."""
-        if deadline_s is None:
-            deadline_s = float(os.environ.get("TORCHFT_RECLAIM_SEC", 120.0))
-        deadline_s = max(float(deadline_s), 0.0)
-        now = time.monotonic()
-        # Work on a LOCAL snapshot: notices can arrive from a signal
-        # handler or a watcher/orchestrator thread while the training
-        # thread's _execute_drain nulls self._preempt — re-reading the
-        # attribute after the None check would TypeError. (Two racing
-        # FIRST notices can still drop one from the count — benign: the
-        # deadline is near-identical and the drain arms either way.)
-        p = self._preempt
-        if p is None:
-            p = {"deadline": now + deadline_s, "reason": str(reason),
-                 "pending_notices": 1}
-            self._preempt = p
-        elif self._preempt_expired:
-            # A FRESH notice after an expired one (spot reprieve, then
-            # re-reclaim): re-arm with the new deadline — min() against
-            # the long-expired stamp would keep the drain inert forever
-            # while logging a negative deadline.
-            p["deadline"] = now + deadline_s
-            p["reason"] = str(reason)
-            p["pending_notices"] += 1
-            self._preempt_expired = False
-        else:
-            p["deadline"] = min(p["deadline"], now + deadline_s)
-            p["pending_notices"] += 1
-        remaining = p["deadline"] - now
-        if not _signal_safe:
-            self._flush_preempt_notices()
-            logger.warning(
-                "%s: preemption notice (%s) — draining at the next clean "
-                "commit boundary, deadline %.1fs", self._replica_id,
-                reason, remaining)
-        return remaining
-
-    def _flush_preempt_notices(self) -> None:
-        """Move signal-staged notice accounting into the locked
-        counters/events — always on the training thread, never inside
-        a signal handler."""
-        p = self._preempt
-        if p is None:
-            return
-        pending = p.get("pending_notices", 0)
-        if pending:
-            p["pending_notices"] = 0
-            self._record(preempt_notices_total=pending)
-            self._log_event(
-                event="preempt_notice", step=self._step,
-                deadline_s=round(p["deadline"] - time.monotonic(), 3),
-                reason=p["reason"], notices=pending)
+        """:meth:`torchft_tpu.preemption.PreemptionDrain.request`."""
+        return self._drain.request(deadline_s, reason, _signal_safe)
 
     def install_preemption_handler(
             self, deadline_s: Optional[float] = None,
             signum: int = signal.SIGTERM) -> Any:
-        """Install a ``SIGTERM`` handler that turns the cloud's reclaim
-        signal into :meth:`request_preemption` (deadline from
-        ``deadline_s`` / ``TORCHFT_RECLAIM_SEC``), chaining any
-        previously-installed handler. Returns the previous handler.
-        Must run on the main thread (a Python signal constraint)."""
-        prev = signal.getsignal(signum)
-
-        def handler(sig: int, frame: Any) -> None:
-            # _signal_safe: no locks here — see request_preemption.
-            self.request_preemption(deadline_s, reason=f"signal {sig}",
-                                    _signal_safe=True)
-            if callable(prev) and prev not in (signal.SIG_IGN,
-                                               signal.SIG_DFL):
-                prev(sig, frame)
-
-        signal.signal(signum, handler)
-        return prev
+        """:meth:`torchft_tpu.preemption.PreemptionDrain.
+        install_handler`."""
+        return self._drain.install_handler(deadline_s, signum)
 
     def preemption_pending(self) -> bool:
         """True while a reclaim notice is armed and the drain has not
         yet landed (or expired)."""
-        return self._preempt is not None and not self._drained \
-            and not self._preempt_expired
+        return self._drain.pending()
 
     def drained(self) -> bool:
         """True once the graceful drain completed; :meth:`step` raises
         :class:`PreemptedExit` from then on."""
-        return self._drained
-
-    def _maybe_drain(self, decision: bool) -> None:
-        """Boundary half of the drain: land it, defer it, or expire
-        it. Runs on the caller thread at the top of :meth:`step` — the
-        post-apply edge of the previous commit boundary, where nothing
-        is in flight and the caller has already applied the committed
-        update (so the final save snapshots exactly what a cadence
-        save at this step would)."""
-        p = self._preempt
-        if p is None or self._drained or self._preempt_expired:
-            return
-        self._flush_preempt_notices()  # signal-staged accounting
-        with self._metrics_lock:
-            healing = self._healing
-        blocked = []
-        if healing:
-            blocked.append("healing")
-        if self._deferred is not None:
-            blocked.append("deferred in flight")
-        if self._errored is not None:
-            blocked.append("errored")
-        if not decision:
-            blocked.append("vote aborted")
-        now = time.monotonic()
-        if now > p["deadline"]:
-            self._expire_preemption(",".join(blocked) or "notice deadline "
-                                    "passed before a boundary")
-            return
-        if blocked:
-            # save_durable's refusal classes: this boundary's state is
-            # not a settled committed step's — a final save now would
-            # persist (and a farewell would strand) exactly the
-            # inconsistent state the drain exists to escape. Retry at
-            # the next boundary; the deadline bounds how long.
-            self._record(preempt_drain_deferrals_total=1)
-            self._log_event(event="preempt_deferred", step=self._step,
-                            why=",".join(blocked))
-            logger.warning(
-                "%s: preemption drain deferred at step %d (%s); retrying "
-                "at the next boundary", self._replica_id, self._step,
-                ",".join(blocked))
-            return
-        self._execute_drain(p)
-
-    def _expire_preemption(self, why: str) -> None:
-        """The reclaim deadline passed before the drain landed: degrade
-        to the pre-protocol hard-kill behavior — the imminent SIGKILL
-        will look like a crash to survivors (staleness eviction, not
-        farewell) — leaving a flight-recorder dump attributing where
-        the drain was stuck."""
-        self._preempt_expired = True
-        self._record(preempt_deadline_expired_total=1)
-        self._log_event(event="preempt_deadline_expired",
-                        step=self._step, why=why)
-        self._flight_dump("preempt_deadline_expired", why=why)
-        logger.error(
-            "%s: preemption deadline expired before the drain landed "
-            "(%s); degrading to hard-kill behavior", self._replica_id,
-            why)
-
-    def _execute_drain(self, p: Dict[str, Any]) -> None:
-        self._log_event(event="preempt_drain", step=self._step,
-                        reason=p["reason"])
-        # (1) Farewell: membership intent out FIRST (section comment).
-        self._send_farewell()
-        # (2) Final durable save, bounded by the remaining deadline.
-        if self._durable_target is not None:
-            writer, directory, prefix, user_fn = self._durable_target
-            remaining = p["deadline"] - time.monotonic()
-            try:
-                fut = self.save_durable(
-                    writer, directory, prefix=prefix,
-                    user_state=(user_fn() if user_fn is not None
-                                else None))
-                if fut is None:
-                    # save_durable REFUSED: state turned unclean between
-                    # _maybe_drain's check and here (an async callback
-                    # latched an error, the quorum thread flagged a
-                    # heal). Completing the drain would log "final save
-                    # taken" while the newest checkpoint is a cadence
-                    # stale — degrade like a failed save instead.
-                    self._expire_preemption(
-                        "final durable save refused (state no longer a "
-                        "settled committed step's)")
-                    return
-                fut.result(timeout=max(remaining, 0.001))
-            except Exception as e:  # noqa: BLE001
-                self._expire_preemption(f"final durable save failed: {e!r}")
-                return
-        # (3) Withdraw heal/publish advertisements.
-        self._withdraw_advertisements()
-        # (4) Done: mark, count, shut down. step() raises PreemptedExit.
-        self._drained = True
-        self._preempt = None
-        self._record(graceful_exits_total=1)
-        self._log_event(event="graceful_exit", step=self._step,
-                        reason=p["reason"])
-        logger.warning(
-            "%s: graceful preemption drain complete at step %d "
-            "(farewell sent, final save %s, advertisements withdrawn)",
-            self._replica_id, self._step,
-            "taken" if self._durable_target is not None else "skipped "
-            "(no durable target registered)")
-        self.shutdown()
+        return self._drain.drained()
 
     def _send_farewell(self) -> None:
         """Send the quorum farewell (leaving beat): survivors' next
@@ -2614,36 +2162,30 @@ class Manager:
         staleness. Best-effort — a lost farewell degrades to the
         staleness eviction a crash would get."""
         sent = False
-        try:
-            fw = (getattr(self._manager_server, "farewell", None)
-                  if self._manager_server is not None else None)
-            if fw is not None:
+        # The manager server's; else, for externally-wired control
+        # planes (tests, alternative bridges), a client exposing
+        # farewell() carries the leaving intent the same way.
+        for via, src in (("manager server", self._manager_server),
+                         ("client", self._client)):
+            fw = getattr(src, "farewell", None)
+            if fw is None or sent:
+                continue
+            try:
                 fw()
                 sent = True
-        except Exception:  # noqa: BLE001
-            logger.warning("%s: farewell via manager server failed",
-                           self._replica_id, exc_info=True)
-        if not sent:
-            # Duck-typed fallback for externally-wired control planes
-            # (tests, alternative bridges): a client exposing farewell()
-            # carries the leaving intent the same way.
-            fw = getattr(self._client, "farewell", None)
-            if fw is not None:
-                try:
-                    fw()
-                    sent = True
-                except Exception:  # noqa: BLE001
-                    logger.warning("%s: farewell via client failed",
-                                   self._replica_id, exc_info=True)
+            except Exception:  # noqa: BLE001
+                logger.warning("%s: farewell via %s failed",
+                               self._replica_id, via, exc_info=True)
         self._log_event(event="farewell", step=self._step, sent=sent)
 
     def _withdraw_advertisements(self) -> None:
         """Withdraw this group's heal + publication advertisements so no
         replacement or subscriber is steered at a corpse: tombstone the
         healset key (step ``-1`` never matches a heal's ``max_step``,
-        so :meth:`_healset_donors` filters it without a format change),
-        detach the publication store (subscribers' next head poll gets
-        404 and rotates parents), and shut the heal serve window."""
+        so :meth:`_donor_admissible` filters it without a format
+        change), detach the publication store (subscribers' next head
+        poll gets 404 and rotates parents) and the RAM rung, and shut
+        the heal serve window."""
         facts = self._last_round_facts
         if facts is not None and self._heal_striped:
             try:
@@ -2656,13 +2198,8 @@ class Manager:
             detach = getattr(self._ckpt_server, "detach_publication", None)
             if detach is not None:
                 detach()
-        if self._ram_store is not None:
-            # A draining group must stop serving/accepting the RAM
-            # rung too: peers' next probe 404s and rotates donors
-            # instead of striping a heal across a corpse.
-            detach = getattr(self._ckpt_server, "detach_ram_store", None)
-            if detach is not None:
-                detach()
+        if self._ram.store is not None:
+            self._ram.detach()
         self._ckpt_server.disallow_checkpoint()
 
     # ------------------------------------------- join admission control
@@ -2744,7 +2281,7 @@ class Manager:
             # only when this manager runs the tier itself; a probe miss
             # or a RAM-leg failure falls back to the checkpoint tier.
             ram_addrs: list = []
-            if self._ram_store is not None:
+            if self._ram.store is not None:
                 from torchft_tpu import ram_ckpt
 
                 for a in addrs:
@@ -2754,32 +2291,15 @@ class Manager:
                     if fleet_step in ram_ckpt.peer_steps(
                             base, auth_token=self._auth_token):
                         ram_addrs.append(f"{base}/ramckpt/{fleet_step}")
-            target = self._manager_state_dict()
             stats: Dict[str, float] = {}
-
-            def _fetch(donor_addrs: list) -> Dict[str, Any]:
-                return cast(
-                    Dict[str, Any],
-                    CheckpointServer.load_from_address(
-                        donor_addrs[0], target, stats=stats,
-                        auth_token=self._auth_token,
-                        retry_policy=self._retry_policy,
-                        retry_stats=self._retry_stats,
-                        stall_timeout_sec=self._heal_stall_timeout_sec,
-                        donors=lambda i: None,
-                        max_donor_failovers=0,
-                        donor_addrs=(donor_addrs
-                                     if len(donor_addrs) > 1 else None),
-                        stripe_seed=_stripe_seed(self._replica_id),
-                        tracer=self._tracer),
-                )
-
             used_ram = bool(ram_addrs)
             with self._tracer.span("prejoin_heal", donors=len(addrs),
                                    fleet_step=fleet_step,
                                    tier="ram" if used_ram else "disk"):
                 try:
-                    state = _fetch(ram_addrs if used_ram else addrs)
+                    state = self._fetch_state(
+                        ram_addrs if used_ram else addrs, stats,
+                        progress=False)
                 except Exception:  # noqa: BLE001 — rung fallback
                     if not used_ram:
                         raise
@@ -2788,7 +2308,8 @@ class Manager:
                         "back to the checkpoint tier",
                         self._replica_id, exc_info=True)
                     used_ram = False
-                    state = _fetch(addrs)
+                    state = self._fetch_state(addrs, stats,
+                                              progress=False)
             self.load_state_dict(state["torchft"])
             self._user_load_state_dict(state["user"])
             self._record(prejoin_heals_total=1,
@@ -2813,277 +2334,41 @@ class Manager:
                            exc_info=True)
             return False
 
-    # ------------------------------------------- degraded-mode groups
-    # Partial-chip-loss survival (docs/design/degraded_mode.md): instead
-    # of dying wholesale when a chip drops, a group lands a capacity
-    # transition at the commit boundary — the trainer re-pjits onto the
-    # surviving submesh and shrinks its batch (DegradedModeDriver), the
-    # manager advertises the fraction on the quorum store and weights
-    # this group's fold contribution by samples actually contributed.
-    # Transitions are refused mid-heal/mid-deferred/errored, the
-    # save_durable refusal discipline — minus its not-committed rule,
-    # DELIBERATELY: an aborted step applied nothing (there is no state
-    # to mix), and the dominant degrade trigger IS a chip loss that
-    # keeps aborting the vote — refusing on aborted boundaries would
-    # deadlock exactly the recovery this path exists for.
+    # ------------------------------------- batch share (degraded, rebalance)
 
     def degraded_mode(self) -> bool:
         """True when this Manager was built with ``degraded_mode=True``
         (weighted folding enabled cluster-wide)."""
-        return self._degraded
+        return self._share.degraded
 
     def capacity_fraction(self) -> float:
         """The capacity fraction in force (1.0 = full capacity)."""
         with self._metrics_lock:
-            return self._capacity_fraction
-
-    def _capacity_blocked(self) -> list:
-        with self._metrics_lock:
-            healing = self._healing
-        blocked = []
-        if healing:
-            blocked.append("healing")
-        if self._deferred is not None:
-            blocked.append("deferred in flight")
-        if self._errored is not None:
-            blocked.append("errored")
-        return blocked
-
-    def _land_capacity(self, fraction: float, samples: Optional[int],
-                       event: str, counter: str, reason: str) -> bool:
-        blocked = self._capacity_blocked()
-        if blocked:
-            self._log_event(event=f"{event}_refused", step=self._step,
-                            fraction=fraction, why=",".join(blocked))
-            logger.warning(
-                "%s: %s to capacity %.3f refused (%s); retry at the "
-                "next boundary", self._replica_id, event, fraction,
-                ",".join(blocked))
-            return False
-        with self._metrics_lock:
-            prev = self._capacity_fraction
-            self._capacity_fraction = float(fraction)
-            self._step_samples = (None if samples is None
-                                  else int(samples))
-            self._metrics["degraded_capacity_fraction"] = float(fraction)
-            self._metrics[counter] += 1
-        self._log_event(event=event, step=self._step, reason=reason,
-                        **{"from": prev, "to": fraction})
-        # Every capacity transition leaves a Perfetto-loadable dump:
-        # the span ring around a degrade is exactly what the "why did
-        # this group shrink" postmortem wants.
-        self._flight_dump(event, **{"from": prev, "to": fraction,
-                                    "why": reason})
-        logger.info("%s capacity %.3f -> %.3f at step %d (%s)",
-                    self._replica_id, prev, fraction, self._step, reason)
-        return True
+            return self._share.capacity
 
     def request_degrade(self, fraction: float,
                         samples: Optional[int] = None,
                         reason: str = "device_loss") -> bool:
-        """Land a capacity degrade at the current commit boundary: this
-        group keeps training on its surviving submesh, contributing
-        ``fraction`` of its nominal batch, its gradient weighted by
-        samples actually contributed. Refused — returning False and
-        stamping a ``degrade_refused`` event — mid-heal, mid-deferred,
-        or errored, exactly like :meth:`save_durable`; callers retry at
-        the next boundary (:class:`~torchft_tpu.degraded.
-        DegradedModeDriver` does). ``samples`` optionally pins the
-        exact per-step sample count the fold weight uses. Under a
-        DiLoCo policy call this only at outer-round boundaries (where
-        the driver's tick naturally lands): the round's pseudo-gradient
-        is weighted by the per-step rate, which represents the round
-        only while capacity is constant across it."""
-        if not self._degraded:
-            raise RuntimeError(
-                f"{self._replica_id}: request_degrade needs "
-                "Manager(degraded_mode=True) — the weighted fold must "
-                "be armed cluster-wide at launch")
-        if not 0.0 < fraction <= 1.0:
-            raise ValueError(
-                f"capacity fraction must be in (0, 1], got {fraction!r}"
-                " — a group at fraction 0 is dead, which is the "
-                "whole-group eviction path's job")
-        return self._land_capacity(fraction, samples, "degrade",
-                                   "degrade_events_total", reason)
+        """:meth:`torchft_tpu.degraded.BatchShare.request_degrade`."""
+        return self._share.request_degrade(fraction, samples, reason)
 
     def request_restore(self, reason: str = "devices_returned") -> bool:
-        """Land the restore back to full capacity (devices returned /
-        replaced): the inverse of :meth:`request_degrade`, with the
-        same boundary discipline and refusal rules."""
-        if not self._degraded:
-            raise RuntimeError(
-                f"{self._replica_id}: request_restore needs "
-                "Manager(degraded_mode=True)")
-        return self._land_capacity(1.0, None, "restore",
-                                   "restore_events_total", reason)
-
-    def _publish_capacity(self, q: Any) -> None:
-        """Advertise this group's capacity fraction under the fixed
-        per-rank key ``torchft/capacity/{replica_rank}`` on the quorum
-        store, value ``"{step}:{fraction}"`` — the fleet-visibility
-        half of "rejoins the quorum advertising a capacity fraction"
-        (the fold itself learns weights from the wire preamble, which
-        is authoritative). Best-effort, like the healset keys, and the
-        key is fixed per rank for the same no-TTL-store reason."""
-        if not self._degraded:
-            return
-        try:
-            store = self._healset_client(q)
-            if store is None:
-                return
-            with self._metrics_lock:
-                frac = self._capacity_fraction
-            store.set(f"torchft/capacity/{q.replica_rank}",
-                      f"{self._step}:{frac}".encode())
-        except Exception:  # noqa: BLE001 — advertisement is best-effort
-            logger.debug("capacity publication failed", exc_info=True)
-
-    # --------------------------------------------- fleet rebalance
-    # Straggler-aware nonuniform data parallelism
-    # (docs/design/fleet_rebalance.md): the lighthouse Rebalancer (the
-    # fleet.py mirror of _core/lighthouse.cc) turns persistent
-    # straggler scores into per-group batch fractions (floor 0.5,
-    # trimmed slice reallocated to headroom groups, hysteresis +
-    # cooldown so transient stalls never flap the fleet) and echoes
-    # the table in every FleetHint. The fractions land through the
-    # SAME decider-publishes/all-adopt protocol as policy switches:
-    # participating rank 0 publishes {step}:{table} on the quorum
-    # store every boundary, every group adopts its own entry on read —
-    # only at commit boundaries, with save_durable's refusal classes
-    # deferring the adoption one boundary. The adopted fraction
-    # composes multiplicatively with degraded-mode capacity inside
-    # participant_slot(); the ElasticSampler draw reports the exact
-    # sample count as the fold weight, so the wire-v4 weighted
-    # canonical fold keeps the update bitwise with zero new wire
-    # format.
+        """:meth:`torchft_tpu.degraded.BatchShare.request_restore`."""
+        return self._share.request_restore(reason)
 
     def rebalance_enabled(self) -> bool:
         """True when this Manager was built with ``rebalance=True``
         (weighted folding armed cluster-wide, lighthouse fractions
         adopted at commit boundaries)."""
-        return self._rebalance
+        return self._share.rebalance
 
     def rebalance_fraction(self) -> float:
         """The rebalance batch fraction in force (1.0 = uniform
         share)."""
         with self._metrics_lock:
-            return self._rebalance_fraction
-
-    def _land_rebalance(self, fraction: float, reason: str) -> bool:
-        """Adopt a lighthouse-assigned batch fraction at this commit
-        boundary, or defer: the :meth:`_land_capacity` discipline with
-        the rebalance counters (a refused adoption counts
-        ``rebalance_deferred_total`` and retries at the next boundary —
-        the table re-reads every round, so nothing is lost)."""
-        blocked = self._capacity_blocked()
-        if blocked:
-            with self._metrics_lock:
-                self._metrics["rebalance_deferred_total"] += 1
-            self._log_event(event="rebalance_deferred", step=self._step,
-                            fraction=fraction, why=",".join(blocked))
-            logger.warning(
-                "%s: rebalance to fraction %.4f deferred (%s); retry "
-                "at the next boundary", self._replica_id, fraction,
-                ",".join(blocked))
-            return False
-        with self._metrics_lock:
-            prev = self._rebalance_fraction
-            self._rebalance_fraction = float(fraction)
-            self._metrics["rebalance_fraction"] = float(fraction)
-            self._metrics["rebalance_adoptions_total"] += 1
-        self._log_event(event="rebalance_adopt", step=self._step,
-                        reason=reason,
-                        **{"from": prev, "to": fraction})
-        self._flight_dump("rebalance_adopt",
-                          **{"from": prev, "to": fraction, "why": reason})
-        logger.info("%s rebalance fraction %.4f -> %.4f at step %d (%s)",
-                    self._replica_id, prev, fraction, self._step, reason)
-        return True
-
-    def _rebalance_pre_vote(self) -> None:
-        """Decider half of the rebalance boundary hook: participating
-        rank 0 publishes ``{step}:{table}`` (the latest FleetHint
-        fraction table) under the fixed key every boundary —
-        unconditionally, like the policy decider, so a follower's read
-        never blocks on a boundary with no change."""
-        if not self._rebalance:
-            return
-        addr, _rw, _mw, coordinated = self._policy_coordination()
-        if not coordinated:
-            return
-        if self._participating_rank != 0 or not self.is_participating():
-            return
-        with self._metrics_lock:
-            table = self._rebalance_table
-        value = f"{self._step}:{table}"
-        try:
-            store = self._store_client(addr)
-            if store is not None:
-                store.set(_REBALANCE_KEY, value.encode())
-                self._rebalance_published = (self._step, table)
-        except Exception:  # noqa: BLE001 — retried next boundary
-            logger.debug("rebalance publication failed", exc_info=True)
-
-    def _rebalance_post_vote(self) -> None:
-        """All-groups half: read the published table (coordinated) or
-        fall back to this group's own hint copy (single-group /
-        storeless runs), pick out our entry — absent means 1.0, the
-        restore-to-uniform spelling and the farewell path's implicit
-        clear (a departed group's entry is dropped from the table the
-        same round the lighthouse forgets its digests) — clamp to the
-        ladder bounds, and land it via :meth:`_land_rebalance`. A
-        failed read adopts nothing: stale-but-consistent beats a
-        torn default."""
-        if not self._rebalance:
-            return
-        addr, _rw, _mw, coordinated = self._policy_coordination()
-        table: Optional[str] = None
-        if coordinated:
-            try:
-                store = self._store_client(addr)
-                if store is not None:
-                    raw = store.get(
-                        _REBALANCE_KEY,
-                        timeout_ms=min(self._timeout_ms, 2000)).decode()
-                    _seq, _, table = raw.partition(":")
-            except Exception:  # noqa: BLE001 — next boundary re-reads
-                logger.debug("rebalance decision read failed",
-                             exc_info=True)
-                return
-        else:
-            with self._metrics_lock:
-                table = self._rebalance_table
-        if table is None:
-            return
-        fractions = fleet_mod.parse_rebalance_table(table)
-        target = float(fractions.get(self._replica_id, 1.0))
-        target = min(fleet_mod.REBALANCE_CEIL,
-                     max(fleet_mod.REBALANCE_FLOOR, target))
-        with self._metrics_lock:
-            cur = self._rebalance_fraction
-        if abs(target - cur) < 1e-9:
-            return
-        self._land_rebalance(target, reason="lighthouse table")
+            return self._share.rebalance_fraction
 
     # ------------------------------------------------- adaptive policy
-    # Hot-swappable FT knobs (docs/design/adaptive_policy.md): the
-    # policy in force bundles overlap_steps / wire rung / DiLoCo /
-    # durable-checkpoint cadence, and switches land ONLY at the commit
-    # boundary — after prepare_commit drained every in-flight
-    # collective and applied any staged heal, before the next step's
-    # quorum — where every existing invariant already synchronizes.
-    # Cross-group lockstep: the quorum's participating rank 0 decides
-    # (from its controller's windowed failure-rate + comm/compute
-    # signals) and publishes {step}:{rung}:{reason} on the quorum store
-    # each boundary; every group adopts on read. The ring collective
-    # between consecutive boundaries orders each publication before
-    # every group's NEXT read, so adoption skew is bounded to one
-    # boundary; healers adopt the donor's policy with the manager
-    # metadata (state_dict), and any residual wire-format skew is
-    # DETECTED by the wire-op preamble (backends/host.py) — aborting
-    # the step instead of folding garbage — then repaired at the next
-    # boundary's read.
 
     def policy(self) -> "policy_mod.FTPolicy":
         """The FT policy in force. Always set — synthesized from the
@@ -3092,234 +2377,28 @@ class Manager:
         (``policy().diloco`` / ``overlap_steps``) and durable-save
         cadence (``policy().ckpt_every``), and bench rows stay
         attributable to the policy that produced them."""
-        return self._policy
+        return self._switch.policy
 
     def policy_controller(self) -> Optional["policy_mod.PolicyController"]:
-        return self._controller
-
-    def _install_policy_knobs(self, p: "policy_mod.FTPolicy") -> None:
-        self._overlap_steps = int(p.overlap_steps)
-        self._exchange.set_wire(p.wire, p.wire_dtype())
-
-    def _install_policy(self, p: "policy_mod.FTPolicy", reason: str,
-                        event: str,
-                        signals: Optional[Any] = None) -> None:
-        """Unconditional install (callers hold the safety checks):
-        knobs (the exchange flushes its residuals on a wire-rung
-        change), controller rung sync, counters, and the ``policy_switch``/``policy_adopt``
-        event with from/to/reason/signals."""
-        old = self._policy
-        old_rung = (self._controller.rung_of(old)
-                    if self._controller is not None else None)
-        self._policy = p
-        self._install_policy_knobs(p)
-        self._tracer.set_context(policy_name=p.name)
-        rung = -1.0
-        if self._controller is not None:
-            r = self._controller.rung_of(p)
-            if r is not None:
-                self._controller.sync_rung(r)
-                rung = float(r)
-        self._policy_last_reason = str(reason)
-        with self._metrics_lock:
-            self._metrics["policy_switches_total"] += 1
-            self._metrics["policy_current"] = rung
-        sig = {}
-        if signals is not None:
-            sig = {"signals": signals.as_dict()
-                   if hasattr(signals, "as_dict") else signals}
-        self._log_event(event=event, step=self._step, reason=reason,
-                        **{"from": old.name, "to": p.name}, **sig)
-        if old_rung is not None and rung > old_rung:
-            # An escalation means the failure regime just got worse —
-            # exactly the moment a postmortem wants the span ring and
-            # event window that DROVE the controller's decision.
-            self._flight_dump("policy_escalation",
-                              **{"from": old.name, "to": p.name,
-                                 "why": reason})
-        logger.info("%s policy %s -> %s at step %d (%s)",
-                    self._replica_id, old.name, p.name, self._step,
-                    reason)
+        return self._switch.controller
 
     def set_policy(self, p: "policy_mod.FTPolicy", reason: str = "manual",
                    signals: Optional[Any] = None,
                    _force: bool = False) -> bool:
-        """Switch the FT policy at the current commit boundary.
+        """:meth:`torchft_tpu.policy.PolicySwitch.set`."""
+        return self._switch.set(p, reason, signals, _force)
 
-        Refused — returning False, counting ``policy_switch_refusals``
-        and stamping a ``policy_switch_refused`` event — while a heal is
-        in flight (exactly like ``save_durable``: the restored state and
-        the knob change must not interleave), while a deferred allreduce
-        is staged (wire/overlap transitions drain deferred state first —
-        flush via ``DelayedOptimizer.flush()``), or (unless the
-        coordinated-adoption path forces it) while an error is latched.
-        Callers retry at the next boundary; the controller hook does so
-        automatically."""
-        if p.knobs() == self._policy.knobs():
-            return True
-        with self._metrics_lock:
-            healing = self._healing
-        blocked = []
-        if healing:
-            blocked.append("healing")
-        if self._deferred is not None:
-            blocked.append("deferred in flight")
-        if not _force and self._errored is not None:
-            blocked.append("errored")
-        if blocked:
-            with self._metrics_lock:
-                self._metrics["policy_switch_refusals"] += 1
-            self._log_event(event="policy_switch_refused",
-                            step=self._step, to=p.name, reason=reason,
-                            why=",".join(blocked))
-            logger.warning("%s: policy switch to %s refused (%s); retry "
-                           "at the next boundary", self._replica_id,
-                           p.name, ",".join(blocked))
-            return False
-        self._install_policy(p, reason, "policy_switch", signals)
-        return True
-
-    def _policy_coordination(self) -> tuple:
+    def _coordination(self) -> tuple:
         """(store_addr, replica_world, max_world, coordinated) of the
         current round; coordinated means a real quorum store exists and
         the ring world is >1 (otherwise decisions apply locally)."""
-        rd = self._policy_round
-        if rd is None:
+        if self._last_round_facts is None:
             return "", 0, 0, False
-        addr, replica_world, max_world = rd
+        addr, _rank, max_world, replica_world = self._last_round_facts
         if not isinstance(addr, str):  # mocked control planes
             addr = ""
         coordinated = bool(addr) and self._comm.size() > 1
         return addr, replica_world, max_world, coordinated
-
-    def _policy_pre_vote(self) -> None:
-        """Decider half of the commit-boundary hook: promote the staged
-        proposal to the published decision (unless a heal is in flight
-        anywhere in the quorum — deferred, retried next boundary, the
-        same refusal ``save_durable`` applies) and refresh the decision
-        key on the quorum store. The key always carries the CURRENT
-        agreed rung, so follower reads never block on an absent key and
-        a group that missed a boundary (failed read, late join) catches
-        up at its next one.
-
-        Adoption is immediate-on-read rather than gated on a future
-        step: commit-step clocks freeze under exactly the churn that
-        makes escalation urgent. The cost is a possible one-boundary
-        adoption skew when the publish races a same-boundary read —
-        which only matters for wire-rung switches, where the wire-op
-        preamble (backends/host.py) detects it and converts the one
-        skewed collective into a clean abort; every group is aligned by
-        the following boundary (its read is ordered after this publish
-        by the intervening ring collective)."""
-        addr, replica_world, max_world, coordinated = \
-            self._policy_coordination()
-        if self._participating_rank != 0 or not self.is_participating():
-            return
-        if self._policy_pending is not None:
-            if max_world < replica_world:
-                # A quorum member is healing: a switch would race its
-                # restore — refused, retried next boundary.
-                with self._metrics_lock:
-                    self._metrics["policy_switch_deferrals"] += 1
-                self._log_event(event="policy_switch_deferred",
-                                step=self._step,
-                                to=self._policy_pending[0],
-                                why="heal in flight")
-            else:
-                rung, reason, sig = self._policy_pending
-                self._policy_pending = None
-                self._policy_published = (self._step, rung, reason, sig)
-        if not coordinated:
-            return
-        pub = self._policy_published
-        if pub is None:
-            cur = self._controller.rung if self._controller else 0
-            value = f"{self._step}:{cur}:init"
-        else:
-            value = (f"{pub[0]}:{pub[1]}:"
-                     f"{str(pub[2]).replace(':', ';')}")
-        try:
-            store = self._store_client(addr)
-            if store is not None:
-                store.set(_POLICY_KEY, value.encode())
-        except Exception:  # noqa: BLE001 — retried next boundary
-            logger.debug("policy publication failed", exc_info=True)
-
-    def _policy_post_vote(self, decision: bool) -> None:
-        """All-groups half of the commit-boundary hook: adopt the
-        published rung when it differs from the one in force, then feed
-        this boundary's outcome to the controller (failure window,
-        comm/compute ratio) and stage any new proposal for the decider's
-        next pre-vote."""
-        addr, _rw, _mw, coordinated = self._policy_coordination()
-        ladder = (self._controller.ladder if self._controller
-                  else policy_mod.LADDER)
-        if coordinated:
-            raw = None
-            try:
-                store = self._store_client(addr)
-                if store is not None:
-                    raw = store.get(
-                        _POLICY_KEY,
-                        timeout_ms=min(self._timeout_ms, 2000)).decode()
-            except Exception:  # noqa: BLE001 — next boundary re-reads;
-                # a missed switch is DETECTED by the wire-op preamble
-                # (abort, not garbage) and repaired then.
-                logger.debug("policy decision read failed",
-                             exc_info=True)
-            if raw:
-                _seq, _, rest = raw.partition(":")
-                rung_s, _, reason = rest.partition(":")
-                try:
-                    rung = int(rung_s)
-                except ValueError:
-                    rung = -1
-                if 0 <= rung < len(ladder):
-                    target = ladder[rung]
-                    if target.knobs() != self._policy.knobs():
-                        self.set_policy(
-                            target, reason=f"coordinated: {reason}",
-                            _force=True)
-        else:
-            pub = self._policy_published
-            if pub is not None and 0 <= pub[1] < len(ladder):
-                target = ladder[pub[1]]
-                if target.knobs() == self._policy.knobs() or \
-                        self.set_policy(target, reason=pub[2],
-                                        signals=pub[3], _force=True):
-                    self._policy_published = None
-
-        if self._controller is None:
-            return
-        now = time.monotonic()
-        with self._metrics_lock:
-            rc = self._metrics["reconfigure_count"]
-            ar = self._metrics["allreduce_ms_total"]
-            churn_per_min = self._churn_per_min_locked(now)
-            fleet_p95 = self._metrics["fleet_p95_ms"]
-            straggler = self._metrics["straggler_score"]
-        prev = self._policy_prev_counters
-        reconfigured = prev is not None and rc > prev["rc"]
-        comm_frac = 0.0
-        if prev is not None:
-            wall_ms = (now - prev["t"]) * 1e3
-            if wall_ms > 0:
-                comm_frac = min(1.0, max(0.0, ar - prev["ar"]) / wall_ms)
-        self._policy_prev_counters = {"rc": rc, "ar": ar, "t": now}
-        proposal = self._controller.note_boundary(
-            decision, reconfigured=reconfigured, comm_frac=comm_frac,
-            churn_rate=churn_per_min,
-            fleet_p95_ms=fleet_p95, straggler_score=straggler)
-        with self._metrics_lock:  # gauge
-            self._metrics["failure_rate"] = \
-                self._controller.last_signals.failure_rate
-        decider = (self._participating_rank == 0
-                   and self.is_participating())
-        if decider and proposal is not None \
-                and self._policy_pending is None:
-            self._policy_pending = proposal
-
-    # ---------------------------------------------------------------- commit
 
     def should_commit(self, timeout_ms: Optional[int] = None) -> bool:
         """Distributed commit gate (reference ``manager.py:410-458``).
@@ -3327,10 +2406,9 @@ class Manager:
         Drains in-flight collectives, applies staged heal state on the main
         thread, then votes: the step commits iff *every* rank of *every*
         participating group succeeded and the quorum was large enough.
-        With a policy controller attached, the commit boundary doubles as
-        the policy-switch boundary (see the adaptive-policy section
-        above): the decider publishes before its vote, every group adopts
-        after it — the only point in the step where nothing is in flight.
+        The boundary's features (``_features``) get their say before
+        the vote and after it — the only point in the step where
+        nothing is in flight.
         """
         # The quorum must have resolved before we can vote (or heal): join
         # it here even if the caller never issued a collective this step.
@@ -3339,13 +2417,12 @@ class Manager:
         # only drains the allgather it tracked.)
         self.prepare_commit()
 
-        # Every hook of the boundary runs on the caller's thread under a
+        # Every slot of the boundary runs on the caller's thread under a
         # span of its own (docs/design/observability.md), one after the
         # other: what the boundary costs the host is their self time.
         with self._tracer.span("pre_vote"):
-            if self._controller is not None:
-                self._policy_pre_vote()
-            self._rebalance_pre_vote()
+            for feature in self._features:
+                feature.pre_vote()
 
         enough = self._participating_world_size >= self._min_replica_size
         local_ok = self._errored is None and enough
@@ -3381,9 +2458,8 @@ class Manager:
                 self._flight_dump(
                     "vote_abort", local_ok=local_ok,
                     error=repr(self._errored) if self._errored else None)
-            if self._controller is not None:
-                self._policy_post_vote(decision)
-            self._rebalance_post_vote()
+            for feature in self._features:
+                feature.post_vote(decision)
         with self._tracer.span("publish_status"):
             self._publish_status()
 
@@ -3428,13 +2504,20 @@ class Manager:
             for key, delta in deltas.items():
                 self._metrics[key] += delta
 
-    def _churn_per_min_locked(self, now_mono: float) -> float:
-        """Ring reconfigures in the trailing 60 s (requires
-        ``_metrics_lock`` held) — the one spelling behind both the
-        ``reconfigures_per_min`` gauge and the policy controller's
-        ``churn_rate`` signal, so the two can never drift."""
-        return float(sum(1 for t in self._reconfig_times
-                         if now_mono - t <= 60.0))
+    def _gauge(self, **values: float) -> None:
+        with self._metrics_lock:
+            self._metrics.update(values)
+
+    def _boundary_view(self) -> boundary_mod.View:
+        """The boundary's one read of the protocol state
+        (:class:`~torchft_tpu.boundary.View`)."""
+        with self._metrics_lock:
+            healing = self._healing
+            quarantined = self._sdc_quarantined
+        return boundary_mod.View(
+            self._replica_id, self._step, healing, quarantined,
+            self._deferred is not None, self._errored is not None,
+            self._should_step)
 
     def _log_event(self, **event: Any) -> None:
         event["t"] = time.time()
@@ -3515,16 +2598,10 @@ class Manager:
             "publish_count": mx.get("publish_count", 0.0),
         }
         self._digest_prev = snap
-        # The rebalance fraction stamped below is the one that was IN
-        # FORCE for the step this digest MEASURES — the digest is
-        # pushed after this boundary's adoption landed, so the live
-        # value would mis-normalize the just-measured wall by one
-        # boundary. Rolled on EVERY boundary (including the skipped
-        # first one, whose adoption would otherwise stamp one boundary
-        # late) so prev always holds the previous boundary's adoption.
-        with self._metrics_lock:
-            reb_prev = self._rebalance_frac_prev
-            self._rebalance_frac_prev = self._rebalance_fraction
+        # The rebalance fraction IN FORCE for the step this digest
+        # MEASURES; rolled on EVERY boundary (including the skipped
+        # first one).
+        reb_prev = self._share.roll_digest_fraction()
         if prev is None:
             return  # the first boundary has no wall to report yet
 
@@ -3550,7 +2627,7 @@ class Manager:
             publish_bytes_inflight=mx.get(
                 "publish_payload_bytes_last", 0.0),
             policy_rung=int(mx.get("policy_current", -1.0)),
-            capacity_fraction=self._capacity_fraction,
+            capacity_fraction=self._share.capacity,
             churn_per_min=mx.get("reconfigures_per_min", 0.0),
             healing=bool(self._healing
                          or not self.is_participating()),
@@ -3652,12 +2729,13 @@ class Manager:
         with self._metrics_lock:
             out = dict(self._metrics)
             pct = self._quorum_latency.percentiles()
-            # Churn-rate gauge (docs/design/churn.md): the
-            # reconfigures-per-minute bound the join-coalescing window
-            # exists to hold under a storm, and the churn signal the
-            # policy controller reads.
-            out["reconfigures_per_min"] = \
-                self._churn_per_min_locked(time.monotonic())
+            # Churn-rate gauge (docs/design/churn.md): ring
+            # reconfigures in the trailing 60 s — the bound the
+            # join-coalescing window exists to hold under a storm, and
+            # the churn signal the policy controller reads.
+            now = time.monotonic()
+            out["reconfigures_per_min"] = float(sum(
+                1 for t in self._reconfig_times if now - t <= 60.0))
         out["quorum_ms_p50"] = pct["p50"]
         out["quorum_ms_p95"] = pct["p95"]
         out["quorum_ms_max"] = pct["max"]
@@ -3674,8 +2752,6 @@ class Manager:
         # returned from each program and added once it has finished
         # (no wait here: a program still running is in the next
         # snapshot); process-wide, absent until a program counted one.
-        # program_callbacks_total is always there, at 0.0: no program
-        # holds a host callback any more.
         out.update(tracing_mod.program_counters())
         # Bytes that actually crossed the TCP ring, counted by the
         # backend at its send sites (halved vs allreduce_wire_bytes_total
@@ -3735,15 +2811,12 @@ class Manager:
         # flat topologies / backends without a hierarchy; getattr
         # tolerates bare duck-typed comms in tests, and the float()
         # guard tolerates MagicMock getters.
-        for mkey, attr in (("hier_intra_bytes_total",
-                            "hier_intra_bytes_total"),
-                           ("hier_leader", "hier_leader")):
-            getter = getattr(self._comm, attr, None)
+        for key in ("hier_intra_bytes_total", "hier_leader"):
+            getter = getattr(self._comm, key, None)
             try:
-                out[mkey] = (float(getter())
-                             if getter is not None else 0.0)
+                out[key] = float(getter()) if getter is not None else 0.0
             except (TypeError, ValueError):
-                out[mkey] = 0.0
+                out[key] = 0.0
         # Observability-tier health: span ring volume/drops and flight-
         # recorder dump count (docs/design/observability.md).
         out.update(self._tracer.metrics())
@@ -3769,16 +2842,7 @@ class Manager:
         # what training is doing.
         if self._publisher is not None:
             out.update(self._publisher.metrics())
-        # RAM-tier counters (docs/design/memory_tier.md): the store's
-        # accept/reject/eviction/loss accounting and the replicator's
-        # replication/demotion pipeline (ram_ckpt_peers,
-        # ram_ckpt_bytes_replicated_total, demote_stage_ms_total, …) —
-        # present only while the tier is enabled, like the attached
-        # writer/publisher merges above.
-        if self._ram_store is not None:
-            out.update(self._ram_store.metrics())
-        if self._ram_replicator is not None:
-            out.update(self._ram_replicator.metrics())
+        out.update(self._ram.metrics())
         # This group as a heal donor: its checkpoint server's stage
         # clock (a caller's own transport may have none).
         serve_metrics = getattr(self._ckpt_server, "metrics", None)
@@ -3821,8 +2885,8 @@ class Manager:
         with self._metrics_lock:
             fleet_stage = self._fleet_stage
         return {
-            "policy_name": self._policy.name,
-            "policy_last_reason": self._policy_last_reason,
+            "policy_name": self._switch.policy.name,
+            "policy_last_reason": self._switch.last_reason,
             "ckpt_last_error": last_err,
             "flight_last_path": (self._flight.last_path
                                  if self._flight is not None else ""),
@@ -3833,15 +2897,6 @@ class Manager:
         }
 
     # ------------------------------------------------- RAM checkpoint tier
-    # docs/design/memory_tier.md: peer RAM is the first rung of the
-    # recovery ladder. At every commit boundary the committed snapshot is
-    # encoded ONCE into an in-memory v2 image (single-write-pass digests)
-    # and pushed to K peer hosts' RamCheckpointStores over the striped
-    # transport run in reverse; demotion RAM -> local disk -> durable
-    # store runs behind it on the AsyncCheckpointer discipline. A cold
-    # replacement heals from a peer's RAM at NIC speed (prejoin_heal /
-    # cold_start prefer the RAM rung); disk is the correlated-failure
-    # rung only.
 
     def enable_ram_tier(self, peers: int = 2,
                         demote_dir: Optional[str] = None,
@@ -3849,77 +2904,30 @@ class Manager:
                         prefix: str = "ckpt_",
                         keep: int = 2,
                         store: Optional[Any] = None) -> None:
-        """Arm the RAM checkpoint tier: attach a
-        :class:`~torchft_tpu.ram_ckpt.RamCheckpointStore` to this
-        manager's checkpoint server (``/ramckpt/*`` starts serving and
-        accepting peer pushes) and start commit-coupled replication to
-        ``peers`` peer hosts at every boundary (:meth:`step` dispatches
-        automatically; :meth:`replicate_ram` is the manual spelling).
-        ``demote_dir``/``durable_dir`` add the local-disk / durable
-        rungs of async demotion (files land as
-        ``{dir}/{prefix}{step}`` — :func:`torchft_tpu.checkpoint_io.
-        recover` and :meth:`cold_start` pick them up with no new scan
-        logic). Idempotent re-arm replaces the replicator config but
-        keeps an existing store's images."""
-        from torchft_tpu import ram_ckpt
-
-        scope = f"ram:{self._replica_id}"
-        try:  # chaos scope = the served endpoint's identity when known
-            import urllib.parse as _up
-
-            netloc = _up.urlsplit(self._ckpt_server.address()).netloc
-            if netloc:
-                scope = f"ram:{netloc}"
-        except Exception:  # noqa: BLE001 — duck-typed transports
-            pass
-        if store is None:
-            store = (self._ram_store
-                     or ram_ckpt.RamCheckpointStore(keep=keep,
-                                                    chaos_scope=scope))
-        self._ram_store = store
-        self._ram_peers_k = max(int(peers), 0)
-        self._ram_prefix = prefix
-        if demote_dir is not None:
-            self._ram_demote_dir = demote_dir
-        self._ram_replicator = ram_ckpt.RamReplicator(
-            store,
-            peers_fn=self._ram_peer_bases,
-            k=self._ram_peers_k,
-            demote_dir=self._ram_demote_dir,
-            durable_dir=durable_dir,
-            prefix=prefix,
-            auth_token=self._auth_token,
-            retry_policy=self._retry_policy,
-            retry_stats=self._retry_stats,
-            chaos_scope=scope,
-        )
-        attach = getattr(self._ckpt_server, "attach_ram_store", None)
-        if attach is not None:
-            attach(store)
-        logger.info(
-            "%s: RAM checkpoint tier armed (k=%d demote_dir=%s "
-            "durable_dir=%s)", self._replica_id, self._ram_peers_k,
-            self._ram_demote_dir, durable_dir)
+        """:meth:`torchft_tpu.ram_ckpt.RamTier.enable`."""
+        self._ram.enable(peers, demote_dir, durable_dir, prefix, keep,
+                         store)
 
     def disable_ram_tier(self) -> None:
-        """Withdraw the RAM tier: drain the in-flight replication,
-        detach ``/ramckpt/*`` (peers' next probe 404s and rotates), and
-        stop dispatching at boundaries. The store's images are dropped
-        with it — a disabled tier must not serve stale steps."""
-        rep, self._ram_replicator = self._ram_replicator, None
-        self._ram_peers_k = 0
-        if rep is not None:
-            rep.shutdown()
-        detach = getattr(self._ckpt_server, "detach_ram_store", None)
-        if detach is not None:
-            detach()
-        if self._ram_store is not None:
-            self._ram_store.clear()
-        self._ram_store = None
+        """:meth:`torchft_tpu.ram_ckpt.RamTier.disable`."""
+        self._ram.disable()
 
     def ram_tier_enabled(self) -> bool:
         """True while commit boundaries replicate to peer RAM."""
-        return self._ram_replicator is not None
+        return self._ram.replicator is not None
+
+    def replicate_ram(self) -> Optional[Future]:
+        """:meth:`torchft_tpu.ram_ckpt.RamTier.replicate`."""
+        return self._ram.replicate()
+
+    def _snapshot_meta(self) -> Dict[str, Any]:
+        """The head of every committed snapshot (durable, RAM)."""
+        return {
+            "committed": True,
+            "quorum_id": self._quorum_id,
+            "replica_id": self._replica_id,
+            "participants": self._participating_world_size,
+        }
 
     def _ram_peer_bases(self) -> list:
         """Replication targets: every OTHER live group's checkpoint
@@ -3933,219 +2941,21 @@ class Manager:
         about to hand it a new one). Empty before the first quorum
         round or on mocked control planes."""
         facts = self._last_round_facts
-        if facts is None or len(facts) < 3:
+        if facts is None:
             return []
-        store_addr, my_rank, max_world = facts
+        store_addr, my_rank, max_world = facts[:3]
         bases: list = []
         try:
             store = self._store_client(store_addr)
             if store is None:
                 return []
-            for r in range(int(max_world)):
-                if r == my_rank:
-                    continue
-                try:
-                    v = store.get(f"torchft/healset/{r}",
-                                  timeout_ms=200).decode()
-                except Exception:  # noqa: BLE001 — absent rank key
-                    continue
-                step_s, _, a = v.partition(":")
-                if not self._donor_admissible(a, step_s=step_s):
-                    continue  # withdrawn/quarantined or malformed
+            for a in self._healset_addrs(store, my_rank, max_world):
                 base = _addr_base(a)
                 if base and base not in bases:
                     bases.append(base)
         except Exception:  # noqa: BLE001 — discovery is best-effort
             logger.debug("ram peer discovery failed", exc_info=True)
         return bases
-
-    def replicate_ram(self) -> Optional[Future]:
-        """Commit-coupled RAM replication: snapshot the committed state
-        and run the encode -> peer-push -> demote pipeline in the
-        background; returns the job's Future (peer-accept count) or
-        ``None`` when refused. Same refusal classes as
-        :meth:`save_durable` — a heal staged/unapplied, a latched
-        error, an aborted vote, or a deferred allreduce in flight mean
-        this state is NOT a settled committed step's, and an image of
-        it replicated to K hosts would multiply exactly the
-        inconsistency the tier exists to escape."""
-        if self._ram_replicator is None:
-            return None
-        with self._metrics_lock:
-            healing = self._healing
-            quarantined = self._sdc_quarantined
-        committed = self._should_step
-        deferred = self.deferred_pending()
-        if healing or self._errored is not None or not committed \
-                or deferred or quarantined:
-            logger.warning(
-                "%s: skipping RAM replication at step %d (healing=%s "
-                "errored=%s committed=%s deferred=%s quarantined=%s) — "
-                "state is not a settled committed step's",
-                self._replica_id, self._step, healing,
-                self._errored is not None, committed, deferred,
-                quarantined)
-            self._record(ram_replicate_skipped=1)
-            if quarantined:
-                self._record(sdc_refusals_total=1)
-            self._log_event(
-                event="ram_replicate_skip", step=self._step,
-                healing=healing, errored=self._errored is not None,
-                committed=committed, deferred=deferred,
-                quarantined=quarantined)
-            return None
-        meta = {
-            "committed": True,
-            "quorum_id": self._quorum_id,
-            "replica_id": self._replica_id,
-            "participants": self._participating_world_size,
-        }
-        # Spans the DISPATCH (on-device snapshot + enqueue); encode and
-        # every demotion stage run on the replicator's worker and are
-        # timed by its demote_*_ms counters.
-        with self._tracer.span("ram_replicate", step=self._step):
-            fut = self._ram_replicator.replicate_async(
-                self._user_state_dict(), self.state_dict(), meta=meta)
-        self._log_event(event="ram_replicate", step=self._step)
-        return fut
-
-    def _maybe_replicate_ram(self) -> None:
-        """:meth:`step`'s boundary hook: dispatch this boundary's
-        replication, surface the previous job's latched error into the
-        log/counters (the tier is best-effort — it must never take the
-        training loop down with it), and detect replication-set
-        collapse (peers accepting dropped to ZERO after replication had
-        been landing) with a one-shot flight dump: the operator's
-        signal that the fleet is one correlated failure away from the
-        disk rung."""
-        if self._ram_replicator is None:
-            return
-        m = self._ram_replicator.metrics()
-        peers_now = m.get("ram_ckpt_peers", 0.0)
-        if peers_now > 0:
-            self._ram_peers_seen = max(self._ram_peers_seen, peers_now)
-            self._ram_collapse_dumped = False
-        elif (self._ram_peers_seen > 0
-                and m.get("ram_ckpt_replications_total", 0.0) > 0
-                and not self._ram_collapse_dumped):
-            self._ram_collapse_dumped = True
-            self._record(ram_replica_collapses_total=1)
-            self._log_event(event="ram_replica_collapse",
-                            step=self._step,
-                            peers_seen=self._ram_peers_seen)
-            self._flight_dump("ram_replica_collapse",
-                              peers_seen=self._ram_peers_seen)
-            logger.error(
-                "%s: RAM replication set collapsed (previously %d "
-                "peer(s), now 0) — recovery is one correlated failure "
-                "from the disk rung", self._replica_id,
-                int(self._ram_peers_seen))
-        try:
-            self.replicate_ram()
-        except Exception:  # noqa: BLE001 — best-effort tier
-            self._record(ram_replicate_errors_total=1)
-            self._log_event(event="ram_replicate_error",
-                            step=self._step)
-            logger.warning(
-                "%s: RAM replication dispatch failed at step %d",
-                self._replica_id, self._step, exc_info=True)
-
-    # --------------------------------------------- sdc chaos injection
-
-    def _maybe_chaos_sdc(self) -> None:
-        """:meth:`step`'s chaos hook for the attestation plane: poll
-        the ``sdc`` chaos channel once per commit boundary and, on an
-        ``sdc_flip`` decision, flip ONE bit of one committed param
-        leaf. Participants only — a healer/spare is mid-restore and
-        the injection contract (chaos.sdc_fault) is post-commit state,
-        so corruption there would model a fault the vote deliberately
-        abstains on. No schedule / no config for this endpoint = no
-        decision draw, keeping every other channel's fault sequence
-        byte-identical with the band off (stream purity)."""
-        with self._metrics_lock:
-            healing = self._healing
-            quarantined = self._sdc_quarantined
-        if healing or quarantined:
-            return
-        try:
-            from torchft_tpu import chaos as chaos_mod
-
-            d = chaos_mod.sdc_fault(f"sdc:{self._replica_id}")
-            if d is None:
-                return
-            self._apply_sdc_flip(d.frac)
-        except Exception:  # noqa: BLE001 — chaos never fails a step
-            logger.debug("sdc chaos injection failed", exc_info=True)
-
-    def _apply_sdc_flip(self, frac: float) -> None:
-        """Deterministically corrupt one bit of the committed params:
-        the (leaf, byte, bit) choice is a pure function of the
-        decision's ``frac`` draw, so a seeded schedule reproduces the
-        exact same corruption run over run (the soak's determinism
-        contract). The flipped leaf is re-placed like the original
-        (device arrays stay device, host stays host) and loaded back
-        through the registered ``load_state_dict`` — the corruption is
-        indistinguishable from a real in-memory flip by the time the
-        digest sees it."""
-        leaves, treedef = jax.tree_util.tree_flatten(
-            self._user_state_dict())
-        idxs = [i for i, leaf in enumerate(leaves)
-                if serialization._is_array_leaf(leaf)
-                and getattr(leaf, "nbytes", 0)]
-        if not idxs:
-            return
-        li = idxs[int(frac * len(idxs)) % len(idxs)]
-        leaf = leaves[li]
-        a = np.array(leaf)  # contiguous host copy, any dtype
-        b = a.view(np.uint8).reshape(-1)
-        byte = int(frac * b.size) % b.size
-        bit = int(frac * 8) % 8
-        b[byte] ^= np.uint8(1 << bit)
-        leaves[li] = (serialization.device_put_like(a, leaf)
-                      if isinstance(leaf, jax.Array) else a)
-        self._user_load_state_dict(
-            jax.tree_util.tree_unflatten(treedef, leaves))
-        self._record(sdc_chaos_flips_total=1)
-        self._log_event(event="sdc_chaos_flip", step=self._step,
-                        leaf=li, byte=byte, bit=bit)
-        logger.warning(
-            "%s: chaos sdc_flip at step %d — leaf %d byte %d bit %d",
-            self._replica_id, self._step, li, byte, bit)
-
-    def _maybe_chaos_slow(self) -> None:
-        """:meth:`step`'s chaos hook for the ``slow`` band
-        (docs/design/fleet_rebalance.md): poll the channel once per
-        commit boundary and, on a ``slow`` decision, sleep
-        ``(factor - 1) x`` the NATURAL wall of the boundary just
-        finished — natural meaning the measured wall minus the sleep
-        THIS hook injected there, so the stretch converges to a steady
-        ``factor x`` wall instead of compounding its own injections
-        (at factor >= 2 the naive spelling diverges). Participants
-        only, like the sdc band: a healer/spare contributes no wall
-        the Rebalancer reads. No schedule / no config for this
-        endpoint = no decision draw (stream purity)."""
-        now = time.monotonic()
-        prev = self._chaos_slow_prev
-        injected = self._chaos_slow_injected
-        self._chaos_slow_prev = now
-        self._chaos_slow_injected = 0.0
-        if not self.is_participating():
-            return
-        try:
-            from torchft_tpu import chaos as chaos_mod
-
-            factor = chaos_mod.slow_fault(f"slow:{self._replica_id}")
-        except Exception:  # noqa: BLE001 — chaos never fails a step
-            logger.debug("slow chaos injection failed", exc_info=True)
-            return
-        if factor <= 1.0 or prev is None:
-            return
-        natural = max(0.0, (now - prev) - injected)
-        sleep_s = (factor - 1.0) * natural
-        if sleep_s <= 0.0:
-            return
-        self._chaos_slow_injected = sleep_s
-        time.sleep(sleep_s)
 
     # ------------------------------------------------- durable checkpoints
 
@@ -4160,11 +2970,14 @@ class Manager:
         head.
 
         Refuses — returning ``None`` and counting ``ckpt_save_skipped`` —
-        when the current state did NOT come from a committed step: a heal
-        is staged/unapplied, an error is latched, or the last commit vote
-        aborted. A snapshot taken then would durably persist exactly the
-        inconsistent state durable checkpoints exist to escape; the next
-        committed step's save covers the gap (one cadence, bounded).
+        when the state is not a settled committed step's
+        (:meth:`~torchft_tpu.boundary.Boundary.settled`). A snapshot
+        taken then would durably persist exactly the inconsistent state
+        durable checkpoints exist to escape (a deferred allreduce in
+        flight: metadata at step N+1 over step-N weights — flush it
+        first; a divergence verdict: the bytes lost the fleet vote); the
+        next committed step's save covers the gap (one cadence,
+        bounded).
 
         ``user_state`` overrides the snapshot source for callers whose
         durable tree is richer than the manager-registered state (e.g. a
@@ -4172,56 +2985,17 @@ class Manager:
         default is this manager's registered ``state_dict`` callable.
         Recovery is :meth:`cold_start` (or
         :func:`torchft_tpu.checkpoint_io.recover` directly)."""
-        with self._metrics_lock:
-            healing = self._healing
-            quarantined = self._sdc_quarantined
-        committed = self._should_step
-        deferred = self.deferred_pending()
-        if healing or self._errored is not None or not committed \
-                or deferred or quarantined:
-            # A deferred allreduce in flight means the manager metadata
-            # (step already advanced) and the params (update not yet
-            # applied) describe DIFFERENT steps: a snapshot now would
-            # cold-start at step N+1 with step-N weights. Callers flush
-            # the deferred step first (DelayedOptimizer.flush /
-            # FTTrainer.flush), then save. A divergence verdict
-            # (quarantined) means the bytes themselves lost the fleet
-            # vote — persisting them would make the corruption durable.
-            logger.warning(
-                "%s: skipping durable snapshot at step %d "
-                "(healing=%s errored=%s committed=%s deferred=%s "
-                "quarantined=%s) — state is not a settled committed "
-                "step's%s", self._replica_id,
-                self._step, healing, self._errored is not None, committed,
-                deferred, quarantined,
-                " (flush() the deferred step first)" if deferred else "")
-            self._record(ckpt_save_skipped=1)
-            if quarantined:
-                self._record(sdc_refusals_total=1)
-            self._log_event(
-                event="ckpt_skip", step=self._step, healing=healing,
-                errored=self._errored is not None, committed=committed,
-                deferred=deferred, quarantined=quarantined)
+        if not self._boundary.settled("durable snapshot",
+                                      "ckpt_save_skipped", "ckpt_skip"):
             return None
         self._ckpt_writer = writer
-        # Remember the target: the graceful preemption drain's FINAL
-        # save reuses it (docs/design/churn.md). Never clobbers an
-        # explicit set_durable_target (which may carry a richer
-        # user_state_fn the drain's file must keep matching) — and
-        # never auto-remembers a call that passed an explicit
-        # user_state: the drain would then write the manager-registered
-        # tree while every cadence save wrote the caller's richer one,
-        # and the NEWEST checkpoint would break cold-start resume on
-        # the structure mismatch. Such callers must register via
-        # set_durable_target(user_state_fn=...) for drain coverage.
-        if not self._durable_explicit and user_state is None:
-            self._durable_target = (writer, directory, prefix, None)
-        meta = {
-            "committed": True,
-            "quorum_id": self._quorum_id,
-            "replica_id": self._replica_id,
-            "participants": self._participating_world_size,
-        }
+        # The preemption drain's FINAL save reuses the target — but
+        # never from a call that passed an explicit user_state: the
+        # drain would write another tree than the cadence saves did.
+        # Such callers register set_durable_target(user_state_fn=...).
+        if user_state is None:
+            self._drain.remember_target(writer, directory, prefix)
+        meta = self._snapshot_meta()
         path = os.path.join(directory, f"{prefix}{self._step}")
         state = (user_state if user_state is not None
                  else self._user_state_dict())
@@ -4248,14 +3022,13 @@ class Manager:
 
         Same coupling discipline as :meth:`save_durable`: refuses —
         returning ``None`` and counting ``publish_skipped`` — when the
-        state did not come from a settled committed step (mid-heal,
-        latched error, aborted vote, or a deferred allreduce in
-        flight). A generation published then could hand subscribers
-        exactly the inconsistent state the torn-read guarantee exists
-        to rule out; the next committed step's publish covers the gap.
-        While this manager heals or cold-starts, publication simply
-        pauses — subscribers keep serving the newest *committed*
-        generation, aging against their ``max_lag_steps`` bound.
+        state is not a settled committed step's. A generation published
+        then could hand subscribers exactly the inconsistent state the
+        torn-read guarantee exists to rule out; the next committed
+        step's publish covers the gap. While this manager heals or
+        cold-starts, publication simply pauses — subscribers keep
+        serving the newest *committed* generation, aging against their
+        ``max_lag_steps`` bound.
 
         ``user_state`` overrides the published tree (default: the
         registered ``state_dict`` callable — the weights, not the
@@ -4270,26 +3043,8 @@ class Manager:
         merge into :meth:`metrics`, and :meth:`relay_rows` exposes the
         table itself for the fleet export
         (:meth:`torchft_tpu.fleet.FleetAggregator.note_relays`)."""
-        with self._metrics_lock:
-            healing = self._healing
-            quarantined = self._sdc_quarantined
-        committed = self._should_step
-        deferred = self.deferred_pending()
-        if healing or self._errored is not None or not committed \
-                or deferred or quarantined:
-            logger.warning(
-                "%s: skipping publish at step %d (healing=%s errored=%s "
-                "committed=%s deferred=%s quarantined=%s) — state is not "
-                "a settled committed step's", self._replica_id, self._step,
-                healing, self._errored is not None, committed, deferred,
-                quarantined)
-            self._record(publish_skipped=1)
-            if quarantined:
-                self._record(sdc_refusals_total=1)
-            self._log_event(
-                event="publish_skip", step=self._step, healing=healing,
-                errored=self._errored is not None, committed=committed,
-                deferred=deferred, quarantined=quarantined)
+        if not self._boundary.settled("publish", "publish_skipped",
+                                      "publish_skip"):
             return None
         self._publisher = publisher
         attach = getattr(self._ckpt_server, "attach_publication", None)
@@ -4303,8 +3058,7 @@ class Manager:
             pub_span.set(generation=gen)
         self._record(publish_count=1,
                      publish_ms_total=(time.perf_counter() - t0) * 1e3)
-        with self._metrics_lock:  # gauge, not a counter
-            self._metrics["publish_last_generation"] = float(gen)
+        self._gauge(publish_last_generation=float(gen))
         self._log_event(event="publish", step=self._step, generation=gen)
         return gen
 
@@ -4380,17 +3134,8 @@ class Manager:
                 try:
                     with self._tracer.span("cold_start_ram",
                                            step=best_step):
-                        state = cast(
-                            Dict[str, Any],
-                            CheckpointServer.load_from_address(
-                                addr, self._manager_state_dict(),
-                                stats=stats,
-                                auth_token=self._auth_token,
-                                retry_policy=self._retry_policy,
-                                retry_stats=self._retry_stats,
-                                stall_timeout_sec=(
-                                    self._heal_stall_timeout_sec),
-                                tracer=self._tracer))
+                        state = self._fetch_state([addr], stats,
+                                                  progress=False)
                     self._user_load_state_dict(state["user"])
                     self.load_state_dict(state["torchft"])
                     self._record(ckpt_cold_starts=1,
@@ -4440,36 +3185,20 @@ class Manager:
 
     def state_dict(self) -> Dict[str, int]:
         """Manager metadata that must ride along with user checkpoints to
-        keep step counters in sync (reference ``manager.py:460-482``).
-        Policy-aware managers (explicit ``policy=``/``policy_controller=``)
-        also carry the active policy's numeric knob encoding, so a healer
-        or cold start adopts the JOB's current policy — a restarted group
-        defaulting to rung 0 while the fleet runs int8 would otherwise
-        skew the wire format for its first participating step."""
-        out = {
+        keep step counters in sync (reference ``manager.py:460-482``),
+        with the active policy's knobs on policy-aware managers
+        (:meth:`~torchft_tpu.policy.PolicySwitch.state`)."""
+        return {
             "step": self._step,
             "batches_committed": self._batches_committed,
+            **self._switch.state(),
         }
-        if self._policy_aware:
-            out.update(self._policy.to_state())
-        return out
 
     def load_state_dict(self, state_dict: Dict[str, int]) -> None:
         with self._metrics_lock:  # pair with participant_slot() snapshots
             self._step = int(state_dict["step"])
             self._batches_committed = int(state_dict["batches_committed"])
-        # Adopt the donor's / snapshot's policy (policy-aware managers
-        # only; legacy state dicts simply lack the keys). Runs on the
-        # quorum thread BEFORE this step's collectives join the quorum
-        # future, so a healer's zero contribution is already in the
-        # fleet's wire format.
-        if self._policy_aware and "policy_wire" in state_dict:
-            ladder = (self._controller.ladder if self._controller
-                      else policy_mod.LADDER)
-            p = policy_mod.FTPolicy.from_state(state_dict, ladder=ladder)
-            if p.knobs() != self._policy.knobs():
-                self._install_policy(p, reason="adopted with restored "
-                                     "state", event="policy_adopt")
+        self._switch.adopt_state(state_dict)
 
     # ------------------------------------------------------------- accessors
 
@@ -4477,7 +3206,7 @@ class Manager:
         """Configured cross-step overlap depth: 0 = sync commit, 1 = the
         one-step deferred-commit engine (docs/design/overlap.md). Read by
         :class:`~torchft_tpu.parallel.step.FTTrainer` to pick the loop."""
-        return self._overlap_steps
+        return self._switch.policy.overlap_steps
 
     def num_participants(self) -> int:
         """Groups contributing real gradients this step (reference
@@ -4501,8 +3230,9 @@ class Manager:
 
         All three are written under the metrics lock (``step()`` bumps
         the commit counter, the quorum thread installs the new rank,
-        :meth:`request_degrade`/:meth:`request_restore` move the
-        capacity, :meth:`_land_rebalance` moves the rebalance share),
+        :meth:`request_degrade`/:meth:`request_restore` and the rebalance
+        adoption move the share,
+        :class:`~torchft_tpu.degraded.BatchShare`),
         so unlike separate accessor calls this can never
         observe a torn combination — e.g. the new rank with the
         previous step's counter, or a fresh capacity with a stale rank
@@ -4536,7 +3266,7 @@ class Manager:
             # (round(batch x this)) reported as the exact fold weight
             # keeps the weighted canonical fold bitwise for the product
             # just as for either factor alone.
-            frac = self._capacity_fraction * self._rebalance_fraction
+            frac = self._share.capacity * self._share.rebalance_fraction
             return rank, self._batches_committed, frac
 
     def is_participating(self) -> bool:
@@ -4611,10 +3341,7 @@ class Manager:
             self._deferred = None
         if self._flight is not None:
             self._flight.close()  # off the atexit crash-dump registry
-        if self._ram_replicator is not None:
-            # Drain (or abandon, if stalled) the in-flight replication
-            # before the server that peers pull from goes away.
-            self._ram_replicator.shutdown()
+        self._ram.shutdown()
         self._ckpt_server.shutdown()
         self._executor.shutdown(wait=False, cancel_futures=True)
         # No cancel_futures here: a queued finish_bucket must still run (it

@@ -19,9 +19,9 @@ stable, robustness-first under churn), and drives switches from a
 :class:`PolicyController` — a windowed failure-rate estimator with
 hysteresis and a cooldown so the controller cannot flap. The Manager
 applies switches only **between steps, at the commit boundary**, where
-every existing invariant already synchronizes (see
-``Manager.set_policy`` / the controller hook in ``should_commit``), and
-refuses them mid-heal exactly like ``save_durable``.
+every existing invariant already synchronizes (:class:`PolicySwitch`,
+a commit-boundary feature; ``Manager.set_policy`` delegates), and
+refuses them mid-heal under the boundary's one refusal rule.
 
 Cross-group lockstep (the part a naive per-group controller gets wrong):
 wire-format and mode knobs must change on every replica group at the
@@ -46,6 +46,8 @@ import time
 from collections import deque
 from dataclasses import dataclass, replace
 from typing import Any, Callable, Dict, Optional, Tuple
+
+from torchft_tpu.boundary import Boundary, BoundaryFeature
 
 logger = logging.getLogger(__name__)
 
@@ -362,6 +364,250 @@ class PolicyController:
             return (self.rung - 1,
                     f"relax: {self._quiet} quiet boundaries", sig)
         return None
+
+
+# The quorum-store key the policy decision rides on
+# (:meth:`~torchft_tpu.boundary.Boundary.publish`).
+_POLICY_KEY: str = "torchft/policy"
+
+
+class PolicySwitch(BoundaryFeature):
+    """The policy in force and its switches, as a commit-boundary
+    feature (docs/design/adaptive_policy.md). Switches land ONLY at the
+    commit boundary — after ``prepare_commit`` drained every in-flight
+    collective and applied any staged heal, before the next step's
+    quorum — and in cross-group lockstep, as the module docstring
+    describes: :meth:`pre_vote` is the decider's half, :meth:`post_vote`
+    every group's; healers adopt the donor's policy with the manager
+    metadata (:meth:`adopt_state`).
+
+    An explicit ``policy`` wins over the legacy knob args; with only a
+    controller, its ladder's rung 0 is the starting policy; with
+    neither, a fixed policy is synthesized from ``legacy_knobs``
+    (``overlap_steps``, ``wire_dtype``) so every Manager reports a
+    coherent policy_name (and stays switchable). ``aware`` gates the
+    parts with cross-version surface (state-dict policy fields, the
+    "dynamic" rendezvous fingerprint): only managers explicitly opted
+    into hot-swapping carry them.
+
+    Built from the boundary, ``install_knobs(policy)`` (the exchange's
+    wire) and ``metrics()`` (``Manager.metrics``: the counters the
+    controller's signals derive from)."""
+
+    # The ladder rung in force (gauge; -1 = not on the attached
+    # controller's ladder / no controller), applied switches, refusals
+    # at an unclean boundary, switches deferred because a heal was in
+    # flight somewhere in the quorum, and the controller's windowed
+    # failure-rate estimate (gauge). policy_name / policy_last_reason
+    # are strings and live in metrics_info().
+    METRICS = {
+        "policy_current": -1.0,
+        "policy_switches_total": 0.0,
+        "policy_switch_refusals": 0.0,
+        "policy_switch_deferrals": 0.0,
+        "failure_rate": 0.0,
+    }
+
+    def __init__(self, boundary: Boundary, policy: Optional[FTPolicy],
+                 controller: Optional[PolicyController],
+                 legacy_knobs: tuple,
+                 install_knobs: Callable[[FTPolicy], None],
+                 metrics: Callable[[], Dict[str, float]]) -> None:
+        self._b = boundary
+        self.controller = controller
+        self.aware = policy is not None or controller is not None
+        if policy is None:
+            policy = (controller.policy() if controller is not None
+                      else from_knobs(*legacy_knobs))
+        self.policy = policy
+        self._install_knobs = install_knobs
+        self._metrics = metrics
+        if controller is not None:
+            rung = controller.rung_of(policy)
+            if rung is not None:
+                controller.sync_rung(rung)
+            self.METRICS = dict(self.METRICS,
+                                policy_current=float(controller.rung))
+        # Decider-side staged proposal + latest published decision
+        # (step, rung, reason, signals), and the per-boundary counter
+        # snapshot the comm/compute signal derives from.
+        self._pending: Optional[tuple] = None
+        self._published: Optional[tuple] = None
+        self.last_reason = "init"
+        self._prev_counters: Optional[Dict[str, float]] = None
+
+    def _ladder(self) -> Tuple[FTPolicy, ...]:
+        return self.controller.ladder if self.controller else LADDER
+
+    def state(self) -> Dict[str, int]:
+        """The policy's numeric knob encoding for the manager metadata
+        (policy-aware managers only), so a healer or cold start adopts
+        the JOB's current policy — a restarted group defaulting to rung
+        0 while the fleet runs int8 would otherwise skew the wire
+        format for its first participating step."""
+        return self.policy.to_state() if self.aware else {}
+
+    def adopt_state(self, state_dict: Dict[str, Any]) -> None:
+        """Adopt the donor's / snapshot's policy (policy-aware managers
+        only; legacy state dicts simply lack the keys). Runs on the
+        quorum thread BEFORE this step's collectives join the quorum
+        future, so a healer's zero contribution is already in the
+        fleet's wire format."""
+        if self.aware and "policy_wire" in state_dict:
+            p = FTPolicy.from_state(state_dict, ladder=self._ladder())
+            if p.knobs() != self.policy.knobs():
+                self.install(p, reason="adopted with restored state",
+                             event="policy_adopt")
+
+    def install(self, p: FTPolicy, reason: str, event: str,
+                signals: Optional[Any] = None) -> None:
+        """Unconditional install (callers hold the safety checks):
+        knobs (the exchange flushes its residuals on a wire-rung
+        change), controller rung sync, counters, and the
+        ``policy_switch``/``policy_adopt`` event with
+        from/to/reason/signals."""
+        old = self.policy
+        old_rung = (self.controller.rung_of(old)
+                    if self.controller is not None else None)
+        self.policy = p
+        self._install_knobs(p)
+        self._b.tracer.set_context(policy_name=p.name)
+        rung = -1.0
+        if self.controller is not None:
+            r = self.controller.rung_of(p)
+            if r is not None:
+                self.controller.sync_rung(r)
+                rung = float(r)
+        self.last_reason = str(reason)
+        self._b.record(policy_switches_total=1)
+        self._b.gauge(policy_current=rung)
+        sig = {}
+        if signals is not None:
+            sig = {"signals": signals.as_dict()
+                   if hasattr(signals, "as_dict") else signals}
+        v = self._b.view()
+        self._b.log_event(event=event, step=v.step, reason=reason,
+                          **{"from": old.name, "to": p.name}, **sig)
+        if old_rung is not None and rung > old_rung:
+            # An escalation means the failure regime just got worse —
+            # exactly the moment a postmortem wants the span ring and
+            # event window that DROVE the controller's decision.
+            self._b.flight_dump("policy_escalation",
+                                **{"from": old.name, "to": p.name,
+                                   "why": reason})
+        logger.info("%s policy %s -> %s at step %d (%s)",
+                    v.replica_id, old.name, p.name, v.step, reason)
+
+    def set(self, p: FTPolicy, reason: str = "manual",
+            signals: Optional[Any] = None, _force: bool = False) -> bool:
+        """Switch the FT policy at the current commit boundary.
+
+        Refused — returning False, counting ``policy_switch_refusals``
+        and stamping a ``policy_switch_refused`` event — at a boundary
+        that is not clean (:meth:`~torchft_tpu.boundary.Boundary.
+        blocked`; wire/overlap transitions drain deferred state first —
+        flush via ``DelayedOptimizer.flush()``; the coordinated
+        adoption forces past a latched error). Callers retry at the
+        next boundary; the controller hook does so automatically."""
+        if p.knobs() == self.policy.knobs():
+            return True
+        blocked = self._b.blocked(ignore_errored=_force)
+        if blocked:
+            v = self._b.view()
+            self._b.record(policy_switch_refusals=1)
+            self._b.log_event(event="policy_switch_refused",
+                              step=v.step, to=p.name, reason=reason,
+                              why=",".join(blocked))
+            logger.warning("%s: policy switch to %s refused (%s); retry "
+                           "at the next boundary", v.replica_id,
+                           p.name, ",".join(blocked))
+            return False
+        self.install(p, reason, "policy_switch", signals)
+        return True
+
+    def pre_vote(self) -> None:
+        """Decider half: promote the staged proposal to the published
+        decision (unless a heal is in flight anywhere in the quorum —
+        deferred, retried next boundary) and refresh the decision key.
+        The key always carries the CURRENT agreed rung, so a group that
+        missed a boundary (failed read, late join) catches up at its
+        next one. Adoption is immediate-on-read rather than gated on a
+        future step: commit-step clocks freeze under exactly the churn
+        that makes escalation urgent."""
+        if self.controller is None or not self._b.decider():
+            return
+        _addr, replica_world, max_world, _c = self._b.coordination()
+        step = self._b.view().step
+        if self._pending is not None:
+            if max_world < replica_world:
+                # A quorum member is healing: a switch would race its
+                # restore — refused, retried next boundary.
+                self._b.record(policy_switch_deferrals=1)
+                self._b.log_event(event="policy_switch_deferred",
+                                  step=step, to=self._pending[0],
+                                  why="heal in flight")
+            else:
+                rung, reason, sig = self._pending
+                self._pending = None
+                self._published = (step, rung, reason, sig)
+        pub = self._published
+        if pub is None:
+            value = f"{step}:{self.controller.rung}:init"
+        else:
+            value = (f"{pub[0]}:{pub[1]}:"
+                     f"{str(pub[2]).replace(':', ';')}")
+        self._b.publish(_POLICY_KEY, value)
+
+    def post_vote(self, decision: bool) -> None:
+        """All-groups half: adopt the published rung when it differs
+        from the one in force (a missed switch is DETECTED by the
+        wire-op preamble — abort, not garbage — and repaired at the
+        next read), then feed this boundary's outcome to the controller
+        (failure window, comm/compute ratio) and stage any new proposal
+        for the decider's next pre-vote."""
+        if self.controller is None:
+            return
+        ladder = self._ladder()
+        if self._b.coordination()[3]:
+            raw = self._b.read(_POLICY_KEY)
+            if raw:
+                _seq, _, rest = raw.partition(":")
+                rung_s, _, reason = rest.partition(":")
+                try:
+                    rung = int(rung_s)
+                except ValueError:
+                    rung = -1
+                if 0 <= rung < len(ladder):
+                    self.set(ladder[rung],
+                             reason=f"coordinated: {reason}", _force=True)
+        else:
+            pub = self._published
+            if pub is not None and 0 <= pub[1] < len(ladder):
+                if self.set(ladder[pub[1]], reason=pub[2],
+                            signals=pub[3], _force=True):
+                    self._published = None
+
+        now = time.monotonic()
+        mx = self._metrics()
+        rc, ar = mx["reconfigure_count"], mx["allreduce_ms_total"]
+        prev = self._prev_counters
+        reconfigured = prev is not None and rc > prev["rc"]
+        comm_frac = 0.0
+        if prev is not None:
+            wall_ms = (now - prev["t"]) * 1e3
+            if wall_ms > 0:
+                comm_frac = min(1.0, max(0.0, ar - prev["ar"]) / wall_ms)
+        self._prev_counters = {"rc": rc, "ar": ar, "t": now}
+        proposal = self.controller.note_boundary(
+            decision, reconfigured=reconfigured, comm_frac=comm_frac,
+            churn_rate=mx["reconfigures_per_min"],
+            fleet_p95_ms=mx["fleet_p95_ms"],
+            straggler_score=mx["straggler_score"])
+        self._b.gauge(
+            failure_rate=self.controller.last_signals.failure_rate)
+        if self._b.decider() and proposal is not None \
+                and self._pending is None:
+            self._pending = proposal
 
 
 class AdaptiveTrainer:

@@ -60,6 +60,7 @@ from concurrent.futures import TimeoutError as FutureTimeout
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from torchft_tpu import chaos, transport
+from torchft_tpu.boundary import Boundary, BoundaryFeature
 from torchft_tpu.checkpoint_io import (
     CheckpointCorruptError,
     CheckpointStallError,
@@ -771,3 +772,203 @@ class RamReplicator:
         else:
             op()
         return path
+
+
+class RamTier(BoundaryFeature):
+    """The tier as a commit-boundary feature of one Manager
+    (docs/design/memory_tier.md): at every commit boundary the
+    committed snapshot goes through one :class:`RamReplicator` job.
+    Replication lands at the step edge — the same post-apply edge the
+    preemption drain lands on, and for the same reason: the caller has
+    applied the committed update, so the image carries step N's
+    metadata over step N's params; the cost on the loop is one
+    on-device snapshot. 0 peers (the default; env
+    ``TORCHFT_RAM_CKPT_PEERS``) leaves the tier off.
+
+    Built from the boundary, the checkpoint server that serves
+    ``/ramckpt/*``, ``peers_fn`` (the Manager's healset-derived
+    discovery), and ``snapshot`` -> ``(user_state, manager_state,
+    meta)`` of the committed step."""
+
+    # Boundary replications refused because the state was not a settled
+    # committed step's (the ckpt_save_skipped analogue), dispatches that
+    # raised, and replication-set collapses. The store's and the
+    # replicator's own counters merge in via metrics() while the tier
+    # is enabled.
+    METRICS = {
+        "ram_replicate_skipped": 0.0,
+        "ram_replicate_errors_total": 0.0,
+        "ram_replica_collapses_total": 0.0,
+    }
+
+    def __init__(self, boundary: Boundary, ckpt_server: Any,
+                 peers_fn: Callable[[], List[str]],
+                 snapshot: Callable[[], tuple],
+                 peers: Optional[int] = None,
+                 demote_dir: Optional[str] = None,
+                 **replicator_kwargs: Any) -> None:
+        self._b = boundary
+        self._ckpt_server = ckpt_server
+        self._peers_fn = peers_fn
+        self._snapshot = snapshot
+        self._replicator_kwargs = replicator_kwargs
+        self.store: Optional[RamCheckpointStore] = None
+        self.replicator: Optional[RamReplicator] = None
+        self._demote_dir = (demote_dir
+                            or os.environ.get("TORCHFT_RAM_DEMOTE_DIR")
+                            or None)
+        # High-water mark of peers that accepted a replication — a drop
+        # to 0 afterwards is a replication-set collapse (flight dump).
+        self._peers_seen = 0.0
+        self._collapse_dumped = False
+        if peers is None:
+            try:
+                peers = int(os.environ.get("TORCHFT_RAM_CKPT_PEERS", "0"))
+            except ValueError:
+                peers = 0
+        self._peers_pending = max(int(peers), 0)
+
+    def enable_pending(self) -> None:
+        """Arm what the constructor asked for, once a replica id exists."""
+        if self._peers_pending > 0:
+            self.enable(peers=self._peers_pending)
+
+    def enable(self, peers: int = 2, demote_dir: Optional[str] = None,
+               durable_dir: Optional[str] = None, prefix: str = "ckpt_",
+               keep: int = 2,
+               store: Optional[RamCheckpointStore] = None) -> None:
+        """Arm the tier: attach a :class:`RamCheckpointStore` to the
+        checkpoint server (``/ramckpt/*`` starts serving and accepting
+        peer pushes) and start commit-coupled replication to ``peers``
+        peer hosts at every boundary (:meth:`replicate` is the manual
+        spelling). ``demote_dir``/``durable_dir`` add the local-disk /
+        durable rungs of async demotion (files land as
+        ``{dir}/{prefix}{step}``, where ``checkpoint_io.recover`` and
+        ``Manager.cold_start`` find them). Idempotent re-arm replaces
+        the replicator config but keeps an existing store's images."""
+        replica_id = self._b.view().replica_id
+        scope = f"ram:{replica_id}"
+        try:  # chaos scope = the served endpoint's identity when known
+            netloc = urllib.parse.urlsplit(
+                self._ckpt_server.address()).netloc
+            if netloc:
+                scope = f"ram:{netloc}"
+        except Exception:  # noqa: BLE001 — duck-typed transports
+            pass
+        if store is None:
+            store = (self.store
+                     or RamCheckpointStore(keep=keep, chaos_scope=scope))
+        self.store = store
+        if demote_dir is not None:
+            self._demote_dir = demote_dir
+        self.replicator = RamReplicator(
+            store, peers_fn=self._peers_fn, k=max(int(peers), 0),
+            demote_dir=self._demote_dir, durable_dir=durable_dir,
+            prefix=prefix, chaos_scope=scope, **self._replicator_kwargs)
+        attach = getattr(self._ckpt_server, "attach_ram_store", None)
+        if attach is not None:
+            attach(store)
+        logger.info(
+            "%s: RAM checkpoint tier armed (k=%d demote_dir=%s "
+            "durable_dir=%s)", replica_id, max(int(peers), 0),
+            self._demote_dir, durable_dir)
+
+    def disable(self) -> None:
+        """Withdraw the tier: drain the in-flight replication, detach
+        ``/ramckpt/*`` (peers' next probe 404s and rotates), and stop
+        dispatching at boundaries. The store's images are dropped with
+        it — a disabled tier must not serve stale steps."""
+        rep, self.replicator = self.replicator, None
+        if rep is not None:
+            rep.shutdown()
+        self.detach()
+        if self.store is not None:
+            self.store.clear()
+        self.store = None
+
+    def detach(self) -> None:
+        """Stop serving/accepting the RAM rung (a draining or
+        quarantined group): peers' next probe 404s and rotates."""
+        detach = getattr(self._ckpt_server, "detach_ram_store", None)
+        if detach is not None:
+            detach()
+
+    def shutdown(self) -> None:
+        """Drain (or abandon, if stalled) the in-flight replication."""
+        if self.replicator is not None:
+            self.replicator.shutdown()
+
+    def metrics(self) -> Dict[str, float]:
+        """The store's and the replicator's own counters
+        (``ram_ckpt_peers``, ``demote_stage_ms_total``, …) — present
+        only while the tier is enabled."""
+        out: Dict[str, float] = {}
+        if self.store is not None:
+            out.update(self.store.metrics())
+        if self.replicator is not None:
+            out.update(self.replicator.metrics())
+        return out
+
+    def replicate(self) -> Optional[Future]:
+        """Commit-coupled RAM replication: snapshot the committed state
+        and run the encode -> peer-push -> demote pipeline in the
+        background; returns the job's Future (peer-accept count) or
+        ``None`` when refused — an image of a state that is not a
+        settled committed step's
+        (:meth:`~torchft_tpu.boundary.Boundary.settled`) would multiply
+        exactly the inconsistency the tier exists to escape."""
+        if self.replicator is None:
+            return None
+        if not self._b.settled("RAM replication", "ram_replicate_skipped",
+                               "ram_replicate_skip"):
+            return None
+        step = self._b.view().step
+        # Spans the DISPATCH (on-device snapshot + enqueue); encode and
+        # every demotion stage run on the replicator's worker and are
+        # timed by its demote_*_ms counters.
+        with self._b.tracer.span("ram_replicate", step=step):
+            user_state, manager_state, meta = self._snapshot()
+            fut = self.replicator.replicate_async(
+                user_state, manager_state, meta=meta)
+        self._b.log_event(event="ram_replicate", step=step)
+        return fut
+
+    def at_step_edge(self, committed: bool) -> None:
+        """Dispatch this boundary's replication, surface a failed
+        dispatch into the log/counters (the tier is best-effort — it
+        must never take the training loop down with it), and detect
+        replication-set collapse (peers accepting dropped to ZERO after
+        replication had been landing) with a one-shot flight dump: the
+        operator's signal that the fleet is one correlated failure away
+        from the disk rung."""
+        if self.replicator is None:
+            return
+        m = self.replicator.metrics()
+        peers_now = m.get("ram_ckpt_peers", 0.0)
+        if peers_now > 0:
+            self._peers_seen = max(self._peers_seen, peers_now)
+            self._collapse_dumped = False
+        elif (self._peers_seen > 0
+                and m.get("ram_ckpt_replications_total", 0.0) > 0
+                and not self._collapse_dumped):
+            v = self._b.view()
+            self._collapse_dumped = True
+            self._b.record(ram_replica_collapses_total=1)
+            self._b.log_event(event="ram_replica_collapse", step=v.step,
+                              peers_seen=self._peers_seen)
+            self._b.flight_dump("ram_replica_collapse",
+                                peers_seen=self._peers_seen)
+            logger.error(
+                "%s: RAM replication set collapsed (previously %d "
+                "peer(s), now 0) — recovery is one correlated failure "
+                "from the disk rung", v.replica_id,
+                int(self._peers_seen))
+        try:
+            self.replicate()
+        except Exception:  # noqa: BLE001 — best-effort tier
+            v = self._b.view()
+            self._b.record(ram_replicate_errors_total=1)
+            self._b.log_event(event="ram_replicate_error", step=v.step)
+            logger.warning(
+                "%s: RAM replication dispatch failed at step %d",
+                v.replica_id, v.step, exc_info=True)

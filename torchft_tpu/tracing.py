@@ -128,11 +128,8 @@ def default_trace_steps() -> int:
 # and ``ops/gated_delta.py`` each traced sweep of its chunk recurrence and
 # the sweep's grid steps (``gdn_kernel_traces_total``,
 # ``gdn_kernel_grid_steps_traced_total``).
-# ``program_callbacks_total`` counted the runs of the callback that carried
-# the counts until PR 43; nothing adds to it any more, and it stays in the
-# schema at 0.0 until the benchmark's metric that reads it is retired.
 
-_program_counters: Dict[str, float] = {"program_callbacks_total": 0.0}
+_program_counters: Dict[str, float] = {}
 _program_counters_lock = threading.Lock()
 # Per thread: the collectors open while it traces, innermost last.
 _collectors = threading.local()
