@@ -200,6 +200,36 @@ class TestFlashAttentionGQA:
                                        rtol=1e-5, atol=1e-5)
 
 
+    @pytest.mark.parametrize("window", [None, 24], ids=["full", "window"])
+    @pytest.mark.parametrize("h,h_kv", [(7, 1), (14, 2)])
+    def test_seven_query_heads_a_key_value_head(self, h, h_kv, window):
+        """A group of 7 (no power of two, as every cell's before PR 51):
+        the kernels share a key/value head by ``(bh % h) // rep`` and sum
+        the group's dk/dv, with and without a window shorter than the
+        sequence, in blocks the window does not end on: forward and all
+        three gradients against plain attention."""
+        q, _, _ = qkv(s=64, h=h, seed=1)
+        _, k, v = qkv(s=64, h=h_kv, seed=2)
+
+        def flash(q, k, v):
+            return flash_attention(q, k, v, True, 16, 16, window=window)
+
+        def plain(q, k, v):
+            return plain_attention(q, k, v, True, window=window)
+
+        np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                                   np.asarray(plain(q, k, v)),
+                                   rtol=2e-5, atol=2e-5)
+        g1 = jax.grad(lambda *a: jnp.sum(jnp.sin(flash(*a))),
+                      argnums=(0, 1, 2))(q, k, v)
+        g2 = jax.grad(lambda *a: jnp.sum(jnp.sin(plain(*a))),
+                      argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(g1, g2):
+            assert float(jnp.max(jnp.abs(b))) > 1e-3
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-4, atol=2e-5)
+
+
 class TestFlashAttentionBlock:
     """The ring-attention building block: one flash pass against a K/V
     block with a TRACED mask shift, returning (out, lse) for

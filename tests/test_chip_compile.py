@@ -506,14 +506,20 @@ CELL_STEPS = {
     "lfm2-8b-a1b.steady-1g-8k": ("%attn", "gmm", "bf16[32,8192,64]",
                                  "bf16[8,8192,64]", "bf16[1,8192,6144]",
                                  "bf16[8,2048,1792]"),
+    "smallthinker-21b-a3b.steady-1g-8k": ("flash_fwd_window", "%attn", "gmm",
+                                          "bf16[28,8192,128]",
+                                          "bf16[4,8192,128]",
+                                          "bf16[16,2560,768]"),
 }
 # What has to fit beside the step. The first three cells were sized when the
 # driver's oracle kept one more seeded tree there (until PR 44), and keep
 # that room; the fourth was sized after, for the oracle's one thinned sample
 # (0.3 GiB of a tree of 1.97, read on the chip in PR 45), and so was the
-# fifth (PR 47).
+# fifth (PR 47) and the sixth (PR 51: 16 of 64 experts held, the case
+# ISSUE 51's fallback rule reads; a tree of 2.08 GiB).
 SAMPLE_ROOM = {"nemotron-3-nano-30b-a3b.steady-1g-8k": int(0.3 * GiB),
-               "lfm2-8b-a1b.steady-1g-8k": int(0.3 * GiB)}
+               "lfm2-8b-a1b.steady-1g-8k": int(0.3 * GiB),
+               "smallthinker-21b-a3b.steady-1g-8k": int(0.4 * GiB)}
 
 
 @pytest.mark.parametrize("name", list(CELL_STEPS), ids=list(CELL_STEPS))
@@ -533,8 +539,11 @@ def test_sparse_cells_step_fits_the_chip(one_chip, name, monkeypatch):
     at 32 heads of 128; ``lfm2-8b-a1b``: four gated short convolutions (the
     three streams ``[1,8192,6144]``), the flash kernels at 32 heads of 64
     on 8 key/value heads, four expert layers holding 8 of 32 at a width of
-    1792, a head that is the table (49 leaves a tree, no ``lm_head``). The
-    grouped matmuls compile as Mosaic custom calls, and the step fits with
+    1792, a head that is the table (49 leaves a tree, no ``lm_head``);
+    ``smallthinker-21b-a3b``: the windowed and full flash kernels at 28 query
+    heads of 128 on 4 key/value heads (a group of 7), four ReGLU expert
+    layers holding 16 of 64 at a width of 768, routed on the attention's
+    input. The grouped matmuls compile as Mosaic custom calls, and the step fits with
     the room the driver's oracle needs beside it."""
     import sys
 

@@ -367,7 +367,72 @@ def _conv(remat):
     return loss_fn, params, {"tokens": toks}
 
 
+def _routed_ahead(remat):
+    """A full and a windowed layer at seven query heads on one key/value
+    head, both with ReGLU experts routed on the mixer's input: 2 x 32
+    tokens."""
+    from torchft_tpu.models.transformer import TransformerConfig
+
+    cfg = TransformerConfig(
+        vocab_size=64, num_layers=2, embed_dim=32, num_heads=7,
+        num_kv_heads=1, attn_head_dim=8, max_seq_len=SEQ, dtype=jnp.float32,
+        remat=remat, layer_types=("full_attention", "sliding_attention"),
+        sliding_window=16, rope_full_layers=False, moe_experts=4,
+        moe_top_k=TOP_K, moe_dispatch="routed", moe_held=(0, 2), moe_dim=16,
+        moe_score="softmax", moe_form="reglu", moe_route_input="mixer",
+        moe_interpret=True)
+    model = Transformer(cfg)
+    toks = jax.random.randint(jax.random.key(1), (2, SEQ), 0, cfg.vocab_size)
+    params = {"params": model.init(jax.random.key(0), toks)["params"]}
+
+    def loss_fn(p, batch):
+        return jnp.mean(model.apply(p, batch["tokens"]) ** 2)
+
+    return loss_fn, params, {"tokens": toks}
+
+
 class TestTrainers:
+    @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+    def test_a_model_routed_ahead_counts_layers_and_active_units(
+            self, remat):
+        """``moe_route_ahead_layers_total`` (a whole number, beside the
+        routed layers' three) and ``moe_reglu_active_micro_total`` (the pass
+        loop's carry, a float) leave the fused step, rematerialised or not,
+        as values of its counts output: once a step, no host callback."""
+        loss_fn, params, batch = _routed_ahead(remat)
+        before = tracing.program_counters()
+        trainer = FTTrainer(
+            loss_fn=loss_fn, tx=optax.sgd(0.01), params=params,
+            manager_factory=lambda load, save: make_manager(
+                _client([1]), load_state_dict=load, state_dict=save,
+                min_replica_size=1))
+        try:
+            for _ in range(2):
+                _, committed = trainer.train_step(batch)
+                assert committed
+            jax.block_until_ready(trainer.params)
+            metrics = trainer.manager.metrics()
+            args = (trainer.params, None, trainer.opt_state, batch)
+            lowered = trainer._fused.lower(*args).as_text()
+            counts = jax.eval_shape(trainer._fused, *args)[-1]
+        finally:
+            trainer.shutdown()
+        # 2 steps x 2 expert layers routed on the mixer's input
+        assert metrics["moe_route_ahead_layers_total"] \
+            - before.get("moe_route_ahead_layers_total", 0.0) == 2 * 2
+        # a share in (0, 1e6) a step: near a half at fresh weights
+        active = metrics["moe_reglu_active_micro_total"] \
+            - before.get("moe_reglu_active_micro_total", 0.0)
+        assert 2 * 300_000 < active < 2 * 700_000
+        assert "callback" not in lowered
+        assert counts.keys == (
+            tuple(sorted(("moe_pairs_routed_total", "moe_pairs_local_total",
+                          "moe_expert_load_max_total",
+                          "moe_route_ahead_layers_total"))),
+            ("moe_reglu_active_micro_total",))
+        assert [(v.shape, v.dtype) for v in counts.values] \
+            == [((4,), jnp.int32), ((1,), jnp.float32)]
+
     @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
     def test_a_conv_model_counts_tokens_and_rms_as_output_values(
             self, remat):

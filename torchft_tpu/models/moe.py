@@ -26,9 +26,12 @@ holds:
   gathers move a whole pass) and memory is one pass's. A shared
   expert, where there is one, is an ordinary MLP of the experts' form on
   every token. An expert's form (``form``) is ``"swiglu"``
-  (``down(silu(gate(u)) * up(u))``, three matrices) or ``"relu2"``
-  (``down(relu(up(u))^2)``, two, no gate): one static field that the layer,
-  the pass loops, their hand-written backward and the shared expert read. The
+  (``down(silu(gate(u)) * up(u))``, three matrices), ``"reglu"``
+  (``down(relu(gate(u)) * up(u))``, the same three and another gate) or
+  ``"relu2"`` (``down(relu(up(u))^2)``, two, no gate): one static field that
+  the layer, the pass loops, their hand-written backward and the shared
+  expert read. What the router reads need not be what the experts compute
+  on (``route_on``: a router placed before the layer's mixer). The
   parts that the shares of one layer give add up to the whole layer with
   the shared expert counted once (``tests/test_moe_routed.py``). This is the
   layer expert parallelism needs on each device; the exchange that would
@@ -313,17 +316,22 @@ def _combine_bwd(res, g):
 combine_rows.defvjp(_combine_fwd, _combine_bwd)
 
 
-FORMS = {"swiglu": ("wi_gate", "wi_up", "wo"), "relu2": ("wi_up", "wo")}
+FORMS = {"swiglu": ("wi_gate", "wi_up", "wo"), "relu2": ("wi_up", "wo"),
+         "reglu": ("wi_gate", "wi_up", "wo")}
 #         an expert's matrices by its form, in the order they are held
+# the gate's function in the forms that have one
+GATES = {"swiglu": nn.silu, "reglu": nn.relu}
 
 
 def _one_pass(uc, w, weights, idx, start, rows_a_pass: int,
-              interpret: bool, form: str) -> jnp.ndarray:
+              interpret: bool, form: str) -> Tuple[jnp.ndarray, Any]:
     """The slots ``start .. start + rows_a_pass`` through the held experts:
-    float32 [T, D], their part of every token's output. ``weights`` are the
-    experts' stacks in ``FORMS[form]``'s order; ``idx`` is the routing's
-    integer side: ``(src [M], pos [T, K], w_slot [M], ends [count + 1],
-    jmax)``."""
+    float32 [T, D], their part of every token's output, and with it what
+    the pass has for the layer's counts: under ``"reglu"`` the hidden units
+    with ``gate > 0`` over the pass's rows on held experts (a float32
+    count), else ``None``. ``weights`` are the experts' stacks in
+    ``FORMS[form]``'s order; ``idx`` is the routing's integer side:
+    ``(src [M], pos [T, K], w_slot [M], ends [count + 1], jmax)``."""
     src, pos, w_slot, ends, jmax = idx
     count = weights[0].shape[0]
     with jax.named_scope("moe_dispatch"):
@@ -335,26 +343,36 @@ def _one_pass(uc, w, weights, idx, start, rows_a_pass: int,
         sizes_p = jnp.diff(upto, prepend=0).astype(jnp.int32)
         sizes_p = sizes_p.at[count].set(rows_a_pass - upto[count - 1])
         rows = dispatch_rows(uc, src_p, pos, start, jmax)
+    active = None
     with jax.named_scope("moe_experts"):
-        if form == "swiglu":
+        if form in GATES:
             wi_gate, wi_up, wo = weights
             gate = grouped_matmul(rows, wi_gate, sizes_p, interpret)
             up = grouped_matmul(rows, wi_up, sizes_p, interpret)
-            y = grouped_matmul(nn.silu(gate) * up, wo, sizes_p, interpret)
+            y = grouped_matmul(GATES[form](gate) * up, wo, sizes_p,
+                               interpret)
+            if form == "reglu":
+                held_row = jnp.arange(rows_a_pass) < upto[count - 1]
+                active = jnp.sum(
+                    jnp.logical_and(gate > 0, held_row[:, None]),
+                    dtype=jnp.float32)
         else:
             wi_up, wo = weights
             up = grouped_matmul(rows, wi_up, sizes_p, interpret)
             y = grouped_matmul(jnp.square(nn.relu(up)), wo, sizes_p,
                                interpret)
     with jax.named_scope("moe_combine"):
-        return combine_rows(y, w, pos, src_p, w_p, start, jmax)
+        return combine_rows(y, w, pos, src_p, w_p, start, jmax), active
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
 def experts_over_passes(uc, w, weights, idx, n_local, rows_a_pass: int,
-                        interpret: bool, form: str) -> jnp.ndarray:
+                        interpret: bool, form: str
+                        ) -> Tuple[jnp.ndarray, Any]:
     """Every pass that holds a pair of a held expert, one after the other:
-    float32 [T, D]. The passes are a ``while_loop`` whose length is the
+    float32 [T, D], and the passes' counts (:func:`_one_pass`) added up in
+    the loop's carry (``None`` where the form has none; no gradient flows
+    into them). The passes are a ``while_loop`` whose length is the
     routing's (``n_local`` pairs on held experts fill the first slots), which
     reverse-mode differentiation cannot unroll: the backward is written here,
     a second loop over the same passes that recomputes each and adds its
@@ -365,14 +383,17 @@ def experts_over_passes(uc, w, weights, idx, n_local, rows_a_pass: int,
 
 def _passes_fwd(uc, w, weights, idx, n_local, rows_a_pass, interpret, form):
     def body(c):
-        start, out = c
-        return start + rows_a_pass, out + _one_pass(
-            uc, w, weights, idx, start, rows_a_pass, interpret, form)
+        start, out, active = c
+        nxt = start + rows_a_pass    # first, where the loop always had it
+        part, hot = _one_pass(uc, w, weights, idx, start, rows_a_pass,
+                              interpret, form)
+        return nxt, out + part, None if hot is None else active + hot
 
-    _, out = jax.lax.while_loop(
+    _, out, active = jax.lax.while_loop(
         lambda c: c[0] < n_local, body,
-        (jnp.int32(0), jnp.zeros(uc.shape, jnp.float32)))
-    return out, (uc, w, weights, idx, n_local)
+        (jnp.int32(0), jnp.zeros(uc.shape, jnp.float32),
+         jnp.float32(0.0) if form == "reglu" else None))
+    return (out, active), (uc, w, weights, idx, n_local)
 
 
 def _passes_bwd(rows_a_pass, interpret, form, res, g):
@@ -381,11 +402,11 @@ def _passes_bwd(rows_a_pass, interpret, form, res, g):
 
     def body(c):
         start, acc = c
-        _, vjp = jax.vjp(
+        _, vjp, _ = jax.vjp(
             lambda *a: _one_pass(*a, idx, start, rows_a_pass, interpret,
-                                 form), *diff)
+                                 form), *diff, has_aux=True)
         return start + rows_a_pass, jax.tree_util.tree_map(
-            lambda a, d: a + d.astype(a.dtype), acc, vjp(g))
+            lambda a, d: a + d.astype(a.dtype), acc, vjp(g[0]))
 
     zeros = jax.tree_util.tree_map(
         lambda x: jnp.zeros(x.shape, jnp.float32), diff)
@@ -413,15 +434,20 @@ class RoutedMoEMLP(nn.Module):
         shared_gate: the shared expert's output times ``sigmoid(u . w_s)``,
             ``w_s`` one column of its own (``shared_gate/kernel`` [D, 1]).
         score / route_norm / route_scale: the router (:func:`route`).
-        form: ``"swiglu"`` (stacks ``wi_gate``, ``wi_up``, ``wo``) or
-            ``"relu2"`` (``wi_up``, ``wo``), the shared expert's too.
+        form: ``"swiglu"`` or ``"reglu"`` (stacks ``wi_gate``, ``wi_up``,
+            ``wo``) or ``"relu2"`` (``wi_up``, ``wo``), the shared
+            expert's too.
         pass_rows: sorted pairs taken through the experts at a time.
         interpret: run the Pallas grouped matmul interpreted (``None``: off
             a TPU).
 
     The router's inputs, logits and scores are float32 and its product is
     taken at the highest precision: the selection is a comparison of
-    scores, and a lower precision flips the close ones.
+    scores, and a lower precision flips the close ones. The router reads
+    ``route_on`` [B, S, D] where the caller gives one (a router placed
+    before the layer's mixer: the selection, its weights and the router's
+    gradient are the mixer's input's, and nothing of the routing waits for
+    the mixer), else ``x``; experts and shared expert compute on ``x``.
 
     **Passes.** All ``T * top_k`` pairs are sorted by group (a held
     expert's place, then everything else) into as many slots, which is room
@@ -445,7 +471,13 @@ class RoutedMoEMLP(nn.Module):
     (``jax.checkpoint``) or runs it in a ``lax.scan`` body asks for the
     numbers (``return_stats=True``: ``(out, int32[3])`` in that order),
     returns them out of that region and counts them outside, as
-    ``Transformer`` does, once a step for all its layers.
+    ``Transformer`` does, once a step for all its layers. A ``"reglu"``
+    layer has a fourth number, ``moe_reglu_active_micro_total``: over the
+    rows that hold a pair of a held expert, the share of the hidden units
+    with ``gate > 0``, in millionths (about half at seeded weights; 0 says
+    the experts carry nothing, 1,000,000 that the gate is a plain product),
+    added up in the pass loop's carry; its stats are the pair
+    ``(int32[3], float32 share)``.
     """
 
     num_experts: int
@@ -463,15 +495,21 @@ class RoutedMoEMLP(nn.Module):
     interpret: Optional[bool] = None
 
     @nn.compact
-    def __call__(self, x: jnp.ndarray, return_stats: bool = False) -> Any:
-        out, stats = self._routed(x)
+    def __call__(self, x: jnp.ndarray,
+                 route_on: Optional[jnp.ndarray] = None,
+                 return_stats: bool = False) -> Any:
+        out, stats = self._routed(x, route_on)
         if return_stats:
             return out, stats
-        count_moe_stats(stats)
+        tracing.count_in_program(**moe_counts(stats))
         return out
 
-    def _routed(self, x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    def _routed(self, x: jnp.ndarray, route_on: Optional[jnp.ndarray]
+                ) -> Tuple[jnp.ndarray, Any]:
         b, s, d = x.shape
+        if route_on is not None and route_on.shape != x.shape:
+            raise ValueError(f"route_on {route_on.shape} is not the "
+                             f"input's shape {x.shape}")
         e, h, k = self.num_experts, self.mlp_dim, self.top_k
         first, count = self.held if self.held is not None else (0, e)
         if not (0 <= first and count >= 0 and first + count <= e):
@@ -506,7 +544,8 @@ class RoutedMoEMLP(nn.Module):
                 for name in FORMS[self.form])
 
         with jax.named_scope("moe_route"):
-            logits = router(u.astype(jnp.float32))            # [T, E]
+            read = u if route_on is None else route_on.reshape(t, d)
+            logits = router(read.astype(jnp.float32))         # [T, E]
             top_w, top_idx, _ = route(logits, k, self.score,
                                       self.route_norm, self.route_scale)
             # read only by a caller that asks for "intermediates"
@@ -546,14 +585,21 @@ class RoutedMoEMLP(nn.Module):
                 jnp.minimum(pair, t * k)))
             ends = jnp.cumsum(sizes)
 
+        def with_share(active):
+            # a "reglu" layer's fourth number beside the three
+            if self.form != "reglu":
+                return stats
+            return stats, active / (jnp.maximum(n_local, 1) * h)
+
         if not count:
             out = jnp.zeros((t, d), x.dtype) if shared is None else shared
-            return out.reshape(b, s, d), stats
+            return out.reshape(b, s, d), with_share(jnp.float32(0.0))
 
-        out = experts_over_passes(
+        out, active = experts_over_passes(
             u.astype(self.dtype), w, weights,
             (src, pos, w_slot, ends, jmax), n_local, rows_a_pass, interpret,
             self.form)
+        stats = with_share(active)
 
         with jax.named_scope("moe_combine"):
             out = out.astype(x.dtype)
@@ -587,10 +633,10 @@ class _SharedExpert(nn.Module):
             return nn.Dense(width, use_bias=False, dtype=self.dtype,
                             name=name)
 
-        if self.form == "swiglu":
+        if self.form in GATES:
             gate = dense(self.mlp_dim, "gate")(x)
             up = dense(self.mlp_dim, "up")(x)
-            act = nn.silu(gate) * up
+            act = GATES[self.form](gate) * up
         else:
             act = jnp.square(nn.relu(dense(self.mlp_dim, "up")(x)))
         return dense(x.shape[-1], "down")(act)
@@ -598,13 +644,21 @@ class _SharedExpert(nn.Module):
 
 MOE_COUNTERS = ("moe_pairs_routed_total", "moe_pairs_local_total",
                 "moe_expert_load_max_total")
+REGLU_COUNTER = "moe_reglu_active_micro_total"
+ROUTE_AHEAD_COUNTER = "moe_route_ahead_layers_total"
 
 
-def count_moe_stats(stats: jnp.ndarray) -> None:
-    """Add a routed layer's ``stats`` (or the sum of several layers') to the
-    program counters; called at the collecting function's own level, outside
-    any rematerialised region or scan body."""
-    tracing.count_in_program(**dict(zip(MOE_COUNTERS, stats)))
+def moe_counts(stats: Any, layers: int = 1) -> dict:
+    """A routed layer's ``stats`` as ``{counter: value}`` for
+    ``tracing.count_in_program`` (at the collecting function's own level,
+    outside any rematerialised region or scan body); a ``"reglu"`` layer's
+    share of active units (the pair's second) in millionths over
+    ``layers``, so that the layers' sum is the step's mean."""
+    if isinstance(stats, tuple):
+        stats, active = stats
+        return {**dict(zip(MOE_COUNTERS, stats)),
+                REGLU_COUNTER: active * (1e6 / layers)}
+    return dict(zip(MOE_COUNTERS, stats))
 
 
 def ep_rules() -> list:

@@ -55,7 +55,13 @@ class TransformerConfig:
     moe_route_scale: float = 1.0
     moe_shared_gate: bool = False        # sigmoid(u . w) on the shared expert
     moe_form: str = "swiglu"             # an expert's form, routed only:
-    #                                      "swiglu" | "relu2" (models/moe.py)
+    #                             "swiglu" | "reglu" | "relu2" (models/moe.py)
+    # What a routed layer's router reads, in layers of a mixer and an MLP:
+    # "mlp", the MLP's own normed input, or "mixer", the mixer's normed
+    # input (a router placed before attention: its selection, weights and
+    # gradient are that stream's, and no part of the routing waits for the
+    # mixer; the experts still compute on the MLP's input).
+    moe_route_input: str = "mlp"
     moe_dense_layers: int = 0            # leading layers that keep a dense MLP
     moe_interpret: Optional[bool] = None  # routed: Pallas interpreted (None:
     #                                       off a TPU)
@@ -138,6 +144,12 @@ class TransformerConfig:
         if self.layer_type(i) in SINGLE:
             return self.layer_type(i) == EXPERTS
         return self.moe_experts > 0 and i >= self.moe_dense_layers
+
+    @property
+    def moe_layers(self) -> int:
+        """Expert layers a step runs, a prediction module's among them."""
+        return sum(self.is_moe_layer(i) for i in range(self.num_layers)) \
+            + (self.mtp_layers if self.moe_experts else 0)
 
     @property
     def mlp_dim(self) -> int:
@@ -339,12 +351,14 @@ def _mixer(cfg: TransformerConfig, kind: str, h, positions, counts: dict):
                      name="attn")(h, positions)
 
 
-def _mlp(cfg: TransformerConfig, moe: bool, u, counts: dict):
+def _mlp(cfg: TransformerConfig, moe: bool, u, counts: dict, route_on=None):
     """The dense MLP (``mlp``) or, with ``moe``, the expert layer (``moe``)
     over the normed stream ``u``; a routed layer's counts go into
-    ``counts``."""
+    ``counts``. ``route_on``: the stream a routed layer's router reads
+    where that is not ``u`` (``cfg.moe_route_input``)."""
     if moe and cfg.moe_dispatch == "routed":
-        from torchft_tpu.models.moe import MOE_COUNTERS, RoutedMoEMLP
+        from torchft_tpu.models.moe import (ROUTE_AHEAD_COUNTER,
+                                            RoutedMoEMLP, moe_counts)
 
         # The counts leave the (rematerialised) layer as values:
         # Transformer counts them once a step.
@@ -356,9 +370,14 @@ def _mlp(cfg: TransformerConfig, moe: bool, u, counts: dict):
             route_norm=cfg.moe_route_norm,
             route_scale=cfg.moe_route_scale, form=cfg.moe_form,
             dtype=cfg.dtype, interpret=cfg.moe_interpret, name="moe")(
-                u, return_stats=True)
-        counts.update(zip(MOE_COUNTERS, stats))
+                u, route_on=route_on, return_stats=True)
+        counts.update(moe_counts(stats, layers=cfg.moe_layers))
+        if route_on is not None:
+            counts[ROUTE_AHEAD_COUNTER] = jnp.int32(1)
         return m
+    if route_on is not None:
+        raise ValueError("moe_route_input='mixer' is the routed expert "
+                         "layer's (moe_dispatch='routed')")
     if cfg.moe_form != "swiglu":
         raise ValueError(f"moe_form {cfg.moe_form!r} is the routed expert "
                          "layer's (moe_dispatch='routed' on a layer with "
@@ -379,7 +398,9 @@ class DecoderLayer(nn.Module):
     ``"linear_attention"`` or the gated short convolution of ``"conv"``)
     and a dense or (``moe``) an expert MLP, pre-norm; with
     ``cfg.sandwich_norm`` each sub-block's output is normed again before it
-    joins the stream. Of a kind in ``SINGLE``: one norm
+    joins the stream; with ``cfg.moe_route_input="mixer"`` a routed expert
+    layer's router reads the mixer's normed input, which the layer carries
+    past the mixer. Of a kind in ``SINGLE``: one norm
     (``norm``) and one sub-block, ``x + F(norm(x))``, ``F`` a Mamba-2 mixer,
     the expert MLP or full attention. Returns the stream, and where the
     layer has numbers for the program counters (a routed expert layer, a
@@ -393,21 +414,30 @@ class DecoderLayer(nn.Module):
     def __call__(self, x, positions):
         cfg = self.cfg
         counts = {}
+        if cfg.moe_route_input not in ("mlp", "mixer"):
+            raise ValueError(f"unknown moe_route_input "
+                             f"{cfg.moe_route_input!r}; 'mlp' or 'mixer'")
         if self.kind in SINGLE:
             u = _norm(cfg, "norm")(x)
             if self.kind == EXPERTS:
                 if not cfg.moe_experts:
                     raise ValueError('a "moe" layer needs moe_experts')
+                if cfg.moe_route_input == "mixer":
+                    raise ValueError('a "moe" block has no mixer for '
+                                     "moe_route_input='mixer' to read "
+                                     "before")
                 x = x + _mlp(cfg, True, u, counts)
             else:
                 x = x + _mixer(cfg, self.kind, u, positions, counts)
             return (x, counts) if counts else x
-        a = _mixer(cfg, self.kind, _norm(cfg, "attn_norm")(x), positions,
-                   counts)
+        h = _norm(cfg, "attn_norm")(x)
+        a = _mixer(cfg, self.kind, h, positions, counts)
         if cfg.sandwich_norm:
             a = _norm(cfg, "post_attn_norm")(a)
         x = x + a
-        m = _mlp(cfg, self.moe, _norm(cfg, "mlp_norm")(x), counts)
+        ahead = self.moe and cfg.moe_route_input == "mixer"
+        m = _mlp(cfg, self.moe, _norm(cfg, "mlp_norm")(x), counts,
+                 route_on=h if ahead else None)
         if cfg.sandwich_norm:
             m = _norm(cfg, "post_mlp_norm")(m)
         return (x + m, counts) if counts else x + m
