@@ -9,10 +9,17 @@ that ends inside a group among them); what the router reads apart from what
 the experts compute on (``route_on``); the published order "top k of the
 logits, then a softmax over them" against ``route``; the dense-dispatch
 layer and the routed one share one router; counters go up once a step under
-remat."""
+remat; the rolled sweep over token tiles (PR 53) against the plain loop and,
+to the bit, against the loop over a token's pairs it replaces; ``tgmm``
+adding into the stacks it is given; and a layer's program is of one size
+whatever its tokens, with no ``cond`` and no float32 add of two stacks in
+its pass loops."""
 
+import collections
+import itertools
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +29,7 @@ from _moe_forms_golden import FORMS_THEN, digests
 
 from torchft_tpu import tracing
 from torchft_tpu.models import Transformer, tiny_config
+from torchft_tpu.models import moe
 from torchft_tpu.models.moe import (FORMS, GATES, MOE_COUNTERS,
                                     REGLU_COUNTER, MoEMLP, RoutedMoEMLP,
                                     _tile, padded_rows, route)
@@ -32,14 +40,14 @@ EVERY_FORM = pytest.mark.parametrize("form", list(FORMS))
 
 
 def layer(held, **kw):
-    kw = {"shared_dim": H, "pass_rows": 512, **kw}
-    return RoutedMoEMLP(num_experts=E, mlp_dim=H, top_k=K, held=held,
+    kw = {"shared_dim": H, "pass_rows": 512, "top_k": K, **kw}
+    return RoutedMoEMLP(num_experts=E, mlp_dim=H, held=held,
                         route_scale=SCALE, dtype=jnp.float32,
                         interpret=True, **kw)
 
 
 def whole(stats):
-    """A layer's three whole-number stats (a ``reglu`` layer's come with
+    """A layer's four whole-number stats (a ``reglu`` layer's come with
     its share of active units)."""
     return stats[0] if isinstance(stats, tuple) else stats
 
@@ -61,12 +69,12 @@ def expert(u, p, e=None, form="swiglu"):
     return (act(u @ gate) * (u @ up)) @ down
 
 
-def plain(p, x, first, count, shared=True, form="swiglu"):
+def plain(p, x, first, count, shared=True, form="swiglu", k=K):
     """The layer as its equations read: every held expert computes every
     token under a mask of the pairs routed to it."""
     u = x.reshape(-1, x.shape[-1])
     s = jax.nn.sigmoid(u @ p["router"]["kernel"])
-    top, idx = jax.lax.top_k(s, K)
+    top, idx = jax.lax.top_k(s, k)
     w = SCALE * top / (top.sum(-1, keepdims=True) + 1e-20)
     out = jnp.zeros_like(u)
     if shared:
@@ -75,6 +83,18 @@ def plain(p, x, first, count, shared=True, form="swiglu"):
         w_e = jnp.sum(jnp.where(idx == first + e, w, 0.0), -1)
         out = out + w_e[:, None] * expert(u, p, e, form)
     return out.reshape(x.shape)
+
+
+def sin_grads(fn, p, x):
+    """Gradients of ``sum(sin(fn(p, x)))`` in ``p`` and ``x``."""
+    return jax.grad(lambda q, x: jnp.sum(jnp.sin(fn(q, x))),
+                    argnums=(0, 1))(p, x)
+
+
+def assert_leaves_close(got, want):
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(a, b, atol=3e-4, rtol=1e-4)
 
 
 def full_params(seed=0, tokens=256, form="swiglu"):
@@ -90,10 +110,27 @@ def share_of(p, first, count):
     return q
 
 
+_TESTS_RUN = itertools.count(1)
+
+
 @pytest.fixture(autouse=True)
 def highest():
     with jax.default_matmul_precision("highest"):
         yield
+    # An interpreted kernel's program is hundreds of memory maps on the CPU
+    # and a process may hold 65,530 (``vm.max_map_count``): this file's
+    # programs alone pass that, and the compiler then dies in ``mmap``
+    # (a segmentation fault in ``backend_compile_and_load``, the worker
+    # lost with every test it still had). Dropping them after every test
+    # triples the file's time (what the tests share is compiled again).
+    if next(_TESTS_RUN) % 16 == 0:
+        jax.clear_caches()
+
+
+@pytest.fixture(autouse=True, scope="module")
+def leave_no_programs_behind():
+    yield
+    jax.clear_caches()
 
 
 @EVERY_FORM
@@ -117,15 +154,11 @@ def test_routed_layer_against_the_plain_loop(held, form):
     assert int(stats[0]) == x.shape[0] * x.shape[1] * K
     assert int(stats[1]) <= int(stats[0]) and (count or int(stats[1]) == 0)
 
-    def f(fn):
-        return jax.grad(lambda q, x: jnp.sum(jnp.sin(fn(q, x))),
-                        argnums=(0, 1))(mine, x)
-
-    got = f(lambda q, x: m.apply({"params": q}, x, return_stats=True)[0])
-    ref = f(lambda q, x: plain(q, x, first, count, form=form))
-    for a, b in zip(jax.tree_util.tree_leaves(got),
-                    jax.tree_util.tree_leaves(ref)):
-        np.testing.assert_allclose(a, b, atol=3e-4, rtol=1e-4)
+    assert_leaves_close(
+        sin_grads(lambda q, x: m.apply({"params": q}, x,
+                                       return_stats=True)[0], mine, x),
+        sin_grads(lambda q, x: plain(q, x, first, count, form=form),
+                  mine, x))
 
 
 @EVERY_FORM
@@ -159,7 +192,8 @@ def test_no_pair_is_dropped_when_every_token_picks_the_same_experts(
     out, stats = layer((0, K), pass_rows=pass_rows, form=form).apply(
         {"params": mine}, x, return_stats=True)
     t = x.shape[0] * x.shape[1]
-    assert [int(v) for v in whole(stats)] == [t * K, t * K, t]
+    assert [int(v) for v in whole(stats)] == [
+        t * K, t * K, t, -(-t * K // min(pass_rows, t * K))]
     np.testing.assert_allclose(out, plain(mine, x, 0, K, form=form),
                                atol=5e-5)
 
@@ -182,6 +216,239 @@ def test_the_forms_of_pr50_are_bitwise_what_they_were(form, route_on, dtype):
     if route_on:
         assert got.pop("grad[1]") and golden.pop("grad[1]")
     assert got == golden
+
+
+def _picks_within(p, x, lo, hi):
+    """Inputs and a router under which every token's K picks lie among the
+    experts ``lo .. hi``: the inputs' first feature is 1 and the router's
+    first row lifts those experts over the others; the rest is the seeded
+    routing."""
+    x = x.at[..., 0].set(1.0)
+    lift = jnp.where(jnp.logical_and(jnp.arange(E) >= lo, jnp.arange(E) < hi),
+                     6.0, -6.0)
+    kernel = p["router"]["kernel"].at[0].set(lift)
+    return {**p, "router": {"kernel": kernel}}, x
+
+
+@pytest.mark.parametrize("pass_rows,tile", [(512, 96), (32768, 1024)],
+                         ids=["passes-tiles", "one"])
+@pytest.mark.parametrize("fanout,held", [
+    (0, (9, 4)), (1, (7, 3)), (K - 1, (5, 4)), (K, (3, 5)), (3, (5, 3))],
+    ids=["0", "1", "J-1", "J", "held<K"])
+@EVERY_FORM
+def test_the_rolled_sweep_in_the_layer_against_the_plain_loop(
+        form, fanout, held, pass_rows, tile, monkeypatch):
+    """A token's pairs are summed over the first ``J = min(K, held)``
+    columns whatever the most any token has on held experts (``jmax``):
+    none (no pass runs), 1, ``J - 1`` (a column of zeros), ``J``, and
+    ``J`` = 3 held under 4 a token. Every token picks among the experts
+    2..7 and the layer's share holds ``fanout`` of those, so the fan-out
+    is that number: forward and every gradient against the plain loop,
+    over several passes (each holds part of a token's pairs) and tiles of
+    tokens that do not divide them, and at one pass and one tile; and the
+    tiled layer gives the bits of the layer in one tile."""
+    monkeypatch.setattr(moe, "TOKEN_TILE", tile)
+    p, x = full_params(seed=20 + fanout, form=form)
+    p, x = _picks_within(p, x, 2, 8)
+    first, count = held
+    mine = share_of(p, first, count)
+    m = layer(held, form=form, pass_rows=pass_rows)
+
+    def mine_of(q, x):
+        return m.apply({"params": q}, x, return_stats=True)[0]
+
+    out, stats = m.apply({"params": mine}, x, return_stats=True)
+    np.testing.assert_allclose(
+        out, plain(mine, x, first, count, form=form), atol=2e-5)
+    routed, local, _, passes = (int(v) for v in whole(stats))
+    assert passes == -(-local // min(pass_rows, routed))
+    assert (local == 0) == (fanout == 0)
+    assert (passes > 1) == (pass_rows == 512 and fanout > 2)
+    grads = sin_grads(mine_of, mine, x)
+    assert_leaves_close(
+        grads, sin_grads(lambda q, x: plain(q, x, first, count, form=form),
+                         mine, x))
+    if tile >= x.shape[0] * x.shape[1]:
+        return
+    monkeypatch.setattr(moe, "TOKEN_TILE", 4096)
+    for a, b in zip(jax.tree_util.tree_leaves((out, grads)),
+                    jax.tree_util.tree_leaves(
+                        (mine_of(mine, x), sin_grads(mine_of, mine, x)))):
+        np.testing.assert_array_equal(a, b)
+
+
+def _loop_it_replaces(carry, y, pos, start, jmax, scale, round_to):
+    """The sums as the pass loops made them before PR 53: a loop over a
+    token's pairs of one gather and one add over the whole ``[T, D]`` from
+    zeros, the pass's part rounded where the backward rounded it, and the
+    part then added to the carry."""
+    def add(j, part):
+        col = jax.lax.dynamic_index_in_dim(pos, j, 1, keepdims=False)
+        rows, inside = moe._slot_rows(y, col, start)
+        rows = rows.astype(jnp.float32)
+        if scale is None:
+            return part + jnp.where(inside[:, None], rows, 0.0)
+        by = jax.lax.dynamic_index_in_dim(scale, j, 1, keepdims=False)
+        return part + jnp.where(inside, by, 0.0)[:, None] * rows
+
+    part = jax.lax.fori_loop(0, jmax, add, jnp.zeros(carry.shape,
+                                                     jnp.float32))
+    if round_to is not None:
+        part = part.astype(round_to).astype(jnp.float32)
+    return carry + part
+
+
+@pytest.mark.parametrize("tokens,tile", [(256, 96), (256, 64), (100, 1024),
+                                         (97, 96)],
+                         ids=["ragged", "whole", "one", "one-over"])
+@pytest.mark.parametrize("jmax", [0, 1, 5, 6], ids=["0", "1", "J-1", "J"])
+@pytest.mark.parametrize("side", ["out", "dx"])
+def test_the_rolled_sweep_is_the_loop_it_replaces_bit_for_bit(
+        side, jmax, tokens, tile, monkeypatch):
+    """:func:`_add_pairs` against the loop over a token's pairs it
+    replaces, to the bit: the forward's side (weights, float32) and the
+    backward's (no weights, rounded to bfloat16 a pass), for ``jmax`` of 0,
+    1, ``J - 1`` and ``J`` = 6 (the columns past ``jmax`` point outside the
+    pass, as a pair of an expert that is not held does), a pass that holds
+    a third of the slots (so part of a token's pairs), and token tiles
+    that divide the tokens, do not, and exceed them."""
+    monkeypatch.setattr(moe, "TOKEN_TILE", tile)
+    fanout, width = 6, 40
+    rows = 8 * -(-tokens * fanout // 24)        # a third of the slots
+    keys = jax.random.split(jax.random.key(7 * jmax + tokens), 5)
+    slots = jax.random.permutation(keys[0], 3 * rows)[: tokens * jmax]
+    pos = jnp.concatenate(
+        [slots.reshape(tokens, jmax).astype(jnp.int32),
+         jnp.full((tokens, fanout - jmax), 3 * rows + 5, jnp.int32)], axis=1)
+    start = jnp.int32(rows)
+    y = jax.random.normal(keys[1], (rows, width), jnp.bfloat16)
+    carry = jax.random.normal(keys[2], (tokens, width), jnp.float32)
+    scale = jax.random.uniform(keys[3], (tokens, fanout)) \
+        if side == "out" else None
+    round_to = jnp.bfloat16 if side == "dx" else None
+    inside = jnp.logical_and(pos >= rows, pos < 2 * rows)
+    if jmax:
+        assert 0 < int(inside.sum()) < tokens * jmax
+    got = jax.jit(lambda *a: moe._add_pairs(
+        *a, scale=scale, round_to=round_to))(carry, y, pos, start)
+    # the loop's length an argument, as the routing's is: not a constant
+    # the compiler could unroll the loop by
+    want = jax.jit(lambda *a: _loop_it_replaces(*a, scale, round_to))(
+        carry, y, pos, start, jnp.int32(jmax))
+    np.testing.assert_array_equal(got, want)
+    if not jmax:
+        np.testing.assert_array_equal(got, carry)
+
+
+@pytest.mark.parametrize("sizes", [(40, 0, 24, 0, 64), (0, 0, 0, 0, 128),
+                                   (128, 0, 0, 0, 0), (30, 30, 30, 30, 8)],
+                         ids=["two-empty", "all-outside", "one", "all"])
+def test_tgmm_adds_into_the_stacks_it_is_given(sizes):
+    """:func:`_tgmm_into` against ``acc + tgmm(...)``: a group that has rows
+    gets the product added to its block, one that has none keeps its block
+    as it was, and the rows after the held groups (the last size) add to
+    none."""
+    rows, groups = sum(sizes), len(sizes) - 1
+    keys = jax.random.split(jax.random.key(sum(sizes[:2])), 3)
+    lhs = jax.random.normal(keys[0], (rows, D), jnp.float32)
+    g = jax.random.normal(keys[1], (rows, H), jnp.float32)
+    acc = jax.random.normal(keys[2], (groups, D, H), jnp.float32)
+    group_sizes = jnp.asarray(sizes, jnp.int32)
+    got = moe._tgmm_into(acc, lhs, g, group_sizes, True)
+    fresh = moe._megablox().tgmm(
+        lhs.swapaxes(0, 1), g, group_sizes, jnp.float32,
+        (moe._row_tile(rows), D, H), num_actual_groups=groups,
+        interpret=True)
+    ends = np.cumsum(sizes)
+    for e in range(groups):
+        if sizes[e]:
+            np.testing.assert_array_equal(got[e], acc[e] + fresh[e])
+            lo = ends[e] - sizes[e]
+            np.testing.assert_allclose(
+                got[e] - acc[e], lhs[lo:ends[e]].T @ g[lo:ends[e]],
+                atol=1e-4)
+        else:
+            np.testing.assert_array_equal(got[e], acc[e])
+
+
+def _eqns(jaxpr, inside_pass=False):
+    """Every equation of ``jaxpr`` and of the jaxprs its equations hold,
+    each with whether it lies in a pass loop's body (a ``while`` that
+    carries the held stacks); a kernel's own jaxpr is not entered."""
+    for eqn in jaxpr.eqns:
+        yield eqn, inside_pass
+        if eqn.primitive.name == "pallas_call":
+            continue
+        is_pass = eqn.primitive.name == "while" and any(
+            v.aval.shape == (8, D, H) for v in eqn.invars)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub, inside_pass or is_pass)
+
+
+def _layer_and_gradient(form, tokens):
+    """A layer that holds 8 of the experts under 8 a token, and its
+    forward + gradient as a function of ``(params, x)``."""
+    held = (4, 8)
+    p, x = full_params(form=form, tokens=tokens)
+    m = layer(held, form=form, top_k=8)
+    return jax.grad(
+        lambda q, x: jnp.sum(jnp.sin(m.apply({"params": q}, x))),
+        argnums=(0, 1)), share_of(p, *held), x
+
+
+@EVERY_FORM
+def test_the_program_of_a_layer_does_not_grow_with_its_tokens(
+        form, monkeypatch):
+    """What PR 52 was refused for, held here: the layer's forward +
+    gradient is a program of one size whatever ``T / TOKEN_TILE`` is. The
+    lowered text holds the same number of ``gather`` operations (and of
+    every other operation) at two tiles of tokens and at eight; the two
+    pass loops (the ``while`` equations that carry the held stacks) hold
+    no ``cond`` outside a kernel (no ladder of fan-outs, no ``lax.switch``;
+    an interpreted kernel has its own); their only
+    loops over rows are the sweeps over token tiles, one a loop, whose
+    bodies hold ``J`` gathers of rows (the other loops left are
+    megablox's binary searches over the groups' sizes, vectors of a few
+    numbers); and no float32 ``add`` of two ``[held, D, H]`` or
+    ``[held, H, D]`` stacks is left in them (the stacks' gradients are
+    added inside ``tgmm``, which takes them as an aliased input)."""
+    monkeypatch.setattr(moe, "TOKEN_TILE", 64)
+
+    def ops(tokens):
+        f, mine, x = _layer_and_gradient(form, tokens)
+        text = jax.jit(f).lower(mine, x).as_text()
+        return collections.Counter(re.findall(r"stablehlo\.[a-z_]+", text))
+
+    two, eight = ops(2 * 64), ops(8 * 64)
+    assert two["stablehlo.gather"] == eight["stablehlo.gather"] > 0
+    assert two == eight
+
+    f, mine, x = _layer_and_gradient(form, 8 * 64)
+    eqns = list(_eqns(jax.make_jaxpr(f)(mine, x).jaxpr))
+    loops = [e for e, _ in eqns if e.primitive.name == "while" and any(
+        v.aval.shape == (8, D, H) for v in e.invars)]
+    assert len(loops) == 2                      # forward and backward
+    in_pass = [e for e, inside in eqns if inside]
+    names = {e.primitive.name for e in in_pass}
+    assert "pallas_call" in names
+    assert not names & {"cond", "switch"}
+    sweeps = [e for e in in_pass if e.primitive.name in ("while", "scan")
+              and any(v.aval.ndim > 1 for v in e.invars)]
+    assert len(sweeps) == 2                     # the output's, the input's
+    for e in sweeps:
+        body, = [j for j in jax.core.jaxprs_in_params(e.params)
+                 if len(j.eqns) > 4]
+        wide = [q for q in body.eqns if q.primitive.name == "gather"
+                and q.outvars[0].aval.shape == (64, D)]
+        assert len(wide) == 8                   # J = min(8 a token, 8 held)
+    stacks = {(8, D, H), (8, H, D)}
+    assert not [e for e in in_pass if e.primitive.name in ("add", "add_any")
+                and e.outvars[0].aval.shape in stacks]
+    # the kernels that add the stacks' gradients take them as an input
+    into = [e for e in in_pass if e.primitive.name == "pallas_call"
+            and e.outvars[0].aval.shape in stacks]
+    assert len(into) == len(FORMS[form])
+    assert all(e.params["input_output_aliases"] for e in into)
 
 
 def _routed_on(p, x, r, first, count, form):
@@ -347,19 +614,22 @@ def test_counters_go_up_once_a_step(remat):
     assert 0 < delta["moe_pairs_local_total"] < 3 * 64 * 2
     assert 0 < delta["moe_expert_load_max_total"] \
         <= delta["moe_pairs_local_total"]
+    # a pass a layer (64 tokens' pairs fit one), three layers
+    assert delta["moe_passes_total"] == 3
     run()
     assert tracing.program_counters()["moe_pairs_routed_total"] \
         == after["moe_pairs_routed_total"] + 3 * 64 * 2
 
 
-def test_manager_metrics_report_the_program_counters():
+@pytest.mark.parametrize("counter", MOE_COUNTERS[1:])
+def test_manager_metrics_report_the_program_counters(counter):
     from mockplane import make_manager
 
-    tracing.add_program_counters(moe_pairs_local_total=5)
+    tracing.add_program_counters(**{counter: 5})
     m = make_manager()
     try:
         got = m.metrics()
-        assert got["moe_pairs_local_total"] >= 5.0
+        assert got[counter] >= 5.0
         assert all(isinstance(got[k], float) for k in got
                    if k.startswith("moe_"))
     finally:

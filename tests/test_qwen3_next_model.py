@@ -339,8 +339,8 @@ def test_counters_go_up_once_a_step(builder, remat):
     jax.block_until_ready(step(params, {"tokens": toks}))     # compiled
     before = tracing.program_counters()
     (_, counts), _ = step(params, {"tokens": toks})
-    # whole numbers (the three moe_*) and the linear layers' float32 pair
-    assert [len(names) for names in counts.keys] == [3, 2]
+    # whole numbers (the four moe_*) and the linear layers' float32 pair
+    assert [len(names) for names in counts.keys] == [4, 2]
     tracing.defer_program_counts(counts)
     tracing.settle_program_counts(wait=True)
     after = tracing.program_counters()

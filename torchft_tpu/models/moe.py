@@ -23,7 +23,11 @@ holds:
   the slots go through the experts a pass at a time and passes beyond the
   held experts' last pair are skipped, so time follows the pairs that are
   there (the grouped matmul's grid is sized by the loads at run time; the
-  gathers move a whole pass) and memory is one pass's. A shared
+  gathers move a whole pass) and memory is one pass's. A pass adds where
+  it produces: the loop's float32 carries go into the pass and come out
+  added to, a token's pairs and the passes before it in one sweep over
+  ``[T, D]`` (a loop over tiles of tokens), the stacks' gradients inside
+  the ``tgmm`` kernel (:func:`experts_over_passes`). A shared
   expert, where there is one, is an ordinary MLP of the experts' form on
   every token. An expert's form (``form``) is ``"swiglu"``
   (``down(silu(gate(u)) * up(u))``, three matrices), ``"reglu"``
@@ -44,7 +48,7 @@ from __future__ import annotations
 
 import functools
 import importlib
-from typing import Any, Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -181,24 +185,17 @@ def _row_tile(rows: int) -> int:
     return ROW_TILE if rows >= ROW_TILE else rows
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def grouped_matmul(lhs: jnp.ndarray, rhs: jnp.ndarray,
-                   group_sizes: jnp.ndarray, interpret: bool) -> jnp.ndarray:
+def _gmm(lhs: jnp.ndarray, rhs: jnp.ndarray, group_sizes: jnp.ndarray,
+         interpret: bool, transpose_rhs: bool = False) -> jnp.ndarray:
     """``lhs`` [M, K] holds rows sorted by group; ``rhs`` [G, K, N] one
-    matrix a group; ``group_sizes`` [G + 1] int32, whose last entry counts
-    the rows after the G groups (pairs of experts not held, padding): those
-    rows are not computed and come out zero. Returns [M, N] in ``lhs``'s
-    type. The Pallas kernels are jax's ``megablox`` (``gmm`` / ``tgmm``);
-    their grid is sized by the groups' rows at run time, so the products
-    cost what the rows that are there cost, to a row tile a group.
-    ``rhs`` is given in float32 and multiplied in ``lhs``'s type; its
-    gradient comes back in float32 from the kernel's float32 accumulator."""
-    return _gmm(lhs, rhs.astype(lhs.dtype), group_sizes, False, interpret)
-
-
-def _gmm(lhs, rhs, group_sizes, transpose_rhs, interpret):
-    m = lhs.shape[0]
-    k = lhs.shape[1]
+    matrix a group (``transpose_rhs``: [G, N, K]), in ``lhs``'s type;
+    ``group_sizes`` [G + 1] int32, whose last entry counts the rows after
+    the G groups (pairs of experts not held, padding): those rows are not
+    computed and come out zero. Returns [M, N] in ``lhs``'s type. The Pallas
+    kernel is jax's ``megablox`` ``gmm``; its grid is sized by the groups'
+    rows at run time, so the product costs what the rows that are there
+    cost, to a row tile a group."""
+    m, k = lhs.shape
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
     return _megablox().gmm(
         lhs, rhs, group_sizes, lhs.dtype,
@@ -206,25 +203,22 @@ def _gmm(lhs, rhs, group_sizes, transpose_rhs, interpret):
         transpose_rhs=transpose_rhs, interpret=interpret)
 
 
-def _grouped_fwd(lhs, rhs, group_sizes, interpret):
-    rhs_c = rhs.astype(lhs.dtype)
-    return (_gmm(lhs, rhs_c, group_sizes, False, interpret),
-            (lhs, rhs_c, group_sizes))
-
-
-def _grouped_bwd(interpret, res, g):
-    lhs, rhs_c, group_sizes = res
-    d_lhs = _gmm(g, rhs_c, group_sizes, True, interpret)
+def _tgmm_into(acc: jnp.ndarray, lhs: jnp.ndarray, g: jnp.ndarray,
+               group_sizes: jnp.ndarray, interpret: bool) -> jnp.ndarray:
+    """``acc`` [G, K, N] float32 plus the groups' ``lhs^T @ g`` (``lhs``
+    [M, K], ``g`` [M, N], rows sorted by group as in :func:`_gmm`): the
+    gradient of a product's matrices added to what the earlier passes gave.
+    megablox's ``tgmm`` takes ``acc`` as its ``existing_out``, aliased in
+    place: a group's block is read once and stored once, the kernel's
+    float32 accumulator added to it, and a group without rows keeps its
+    block."""
     m, k = lhs.shape
     n = g.shape[1]
-    d_rhs = _megablox().tgmm(
+    return _megablox().tgmm(
         lhs.swapaxes(0, 1), g, group_sizes, jnp.float32,
         (_row_tile(m), _tile(k, 512), _tile(n, 1024)),
-        num_actual_groups=rhs_c.shape[0], interpret=interpret)
-    return d_lhs, d_rhs, None
-
-
-grouped_matmul.defvjp(_grouped_fwd, _grouped_bwd)
+        num_actual_groups=acc.shape[0], existing_out=acc,
+        interpret=interpret)
 
 
 def _rows(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
@@ -240,100 +234,124 @@ def _slot_rows(y: jnp.ndarray, slot: jnp.ndarray, start: jnp.ndarray):
     return _rows(y, jnp.clip(idx, 0, y.shape[0] - 1)), inside
 
 
-def _column(a: jnp.ndarray, j: jnp.ndarray) -> jnp.ndarray:
-    return jax.lax.dynamic_index_in_dim(a, j, axis=1, keepdims=False)
+# The data movements of a pass over the slots ``start .. start + R`` of the
+# sorted pairs. ``src`` [R] (a slot's token) and ``pos`` [T, K] (the slot of
+# token t's j-th pair) are one permutation read both ways, so both
+# directions of both movements are gathers and no backward needs a scatter.
+# A token's pairs on held experts come first among its K, so the sums over a
+# token's pairs read the first ``min(K, held)`` columns and no more (six
+# where K is 6 of 64 experts and sixteen are held).
+
+TOKEN_TILE = 1024   # tokens whose pairs' rows one step of a sweep gathers
 
 
-# The two data movements of a pass over the slots ``start .. start + R`` of
-# the sorted pairs. ``src`` [R] (a slot's token) and ``pos`` [T, K] (the
-# slot of token t's j-th pair) are one permutation read both ways, so both
-# directions of both are gathers and no backward needs a scatter. A token's
-# pairs on held experts come first among its K, and ``jmax`` is the largest
-# number of them any token has: the gathers over tokens stop there (a
-# handful where K is 8 and a sixteenth of the experts is held).
-
-@jax.custom_vjp
-def dispatch_rows(x: jnp.ndarray, src: jnp.ndarray, pos: jnp.ndarray,
-                  start: jnp.ndarray, jmax: jnp.ndarray) -> jnp.ndarray:
-    """``x`` [T, D] -> [R, D], row ``i`` the row of token ``src[i]``."""
-    return _rows(x, src)
-
-
-def _dispatch_fwd(x, src, pos, start, jmax):
-    return _rows(x, src), (pos, start, jmax)
-
-
-def _dispatch_bwd(res, g):
-    pos, start, jmax = res
-
-    def add(j, dx):
-        rows, inside = _slot_rows(g, _column(pos, j), start)
-        return dx + jnp.where(inside[:, None], rows.astype(jnp.float32), 0.0)
-
-    dx = jax.lax.fori_loop(
-        0, jmax, add, jnp.zeros((pos.shape[0], g.shape[1]), jnp.float32))
-    return dx.astype(g.dtype), None, None, None, None
+def _add_pairs(carry: jnp.ndarray, y: jnp.ndarray, pos: jnp.ndarray,
+               start: jnp.ndarray, scale: Optional[jnp.ndarray] = None,
+               round_to: Any = None) -> jnp.ndarray:
+    """``carry`` [T, D] float32 plus, for every token, the rows of ``y``
+    [R, D] (the slots ``start .. start + R``) at its pairs' slots ``pos``
+    [T, J] that the pass holds, each times its ``scale`` [T, J] where there
+    is one: ``carry[t] + sum_j scale[t, j] * y[pos[t, j] - start]``, the
+    addends summed in the order ``j = 0, 1, ...`` in float32 (and rounded
+    to ``round_to`` and back where that is given) and the sum then added to
+    the carry. One sweep over the carry, rolled: a ``fori_loop`` over tiles
+    of ``TOKEN_TILE`` tokens whose body gathers the tile's ``J`` columns (a
+    gather a column: the rows in flight are ``J`` tiles, which the compiler
+    keeps in its fast memory; PERF.md, PR 52 and PR 53) and adds them to
+    the tile of the carry in place. The program holds ``J`` gathers a
+    sweep however many tiles ``T`` makes. Where ``TOKEN_TILE`` does not
+    divide ``T`` the last tile starts at ``T - TOKEN_TILE`` and the rows
+    the tile before it has done keep their sums. A column that holds no
+    pair of a held expert adds zeros: a loop over the columns up to the
+    most any token has was timed and lost (PERF.md, PR 53)."""
+    return _sweep(carry, y, pos, start, scale, round_to,
+                  min(TOKEN_TILE, pos.shape[0]))
 
 
-dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
+@functools.partial(jax.jit, static_argnums=(5, 6))
+def _sweep(carry, y, pos, start, scale, round_to, tile: int):
+    """:func:`_add_pairs` at a tile of ``tile`` tokens. A function of its
+    own under ``jax.jit`` so that every layer's sweep of one direction is
+    traced once and not a layer at a time: inlined by the compiler it is
+    the program the loop written in place would be, but Python traces its
+    ``J`` gathers once a model instead of once a site, which every run's
+    set-up pays whatever its compile cache holds (PERF.md, PR 53)."""
+    t, fanout = pos.shape
+
+    def body(i, carry):
+        t0 = jnp.minimum(i * tile, t - tile)
+        slots = jax.lax.dynamic_slice(pos, (t0, 0), (tile, fanout))
+        if scale is not None:
+            by = jax.lax.dynamic_slice(scale, (t0, 0), (tile, fanout))
+        total = None
+        for j in range(fanout):
+            rows, inside = _slot_rows(y, slots[:, j], start)
+            rows = rows.astype(jnp.float32)
+            if scale is None:
+                term = jnp.where(inside[:, None], rows, 0.0)
+            else:
+                term = jnp.where(inside, by[:, j], 0.0)[:, None] * rows
+            # the product on the left: a backend that contracts
+            # ``x * y + s`` then rounds as a loop over j would
+            total = term if total is None else term + total
+        if round_to is not None:
+            total = total.astype(round_to).astype(jnp.float32)
+        old = jax.lax.dynamic_slice(carry, (t0, 0), (tile, carry.shape[1]))
+        new = old + total
+        if t % tile:
+            done = t0 + jnp.arange(tile) < i * tile
+            new = jnp.where(done[:, None], old, new)
+        return jax.lax.dynamic_update_slice(carry, new, (t0, 0))
+
+    return jax.lax.fori_loop(0, -(-t // tile), body, carry)
 
 
-@jax.custom_vjp
-def combine_rows(y: jnp.ndarray, w: jnp.ndarray, pos: jnp.ndarray,
-                 src: jnp.ndarray, w_slot: jnp.ndarray,
-                 start: jnp.ndarray, jmax: jnp.ndarray) -> jnp.ndarray:
-    """The pass's part of every token's output, float32 [T, D]:
-    ``sum_j w[t, j] * y[pos[t, j] - start]`` over the pairs whose slot the
-    pass holds. ``y`` [R, D] by slot, ``w`` [T, K] float32 (zero for a pair
-    whose expert is not held), ``w_slot`` [R] the same weights by slot."""
-    return _combine(y, w, pos, start, jmax)
-
-
-def _combine(y, w, pos, start, jmax):
-    def add(j, out):
-        rows, inside = _slot_rows(y, _column(pos, j), start)
-        return out + jnp.where(inside, _column(w, j), 0.0)[:, None] \
-            * rows.astype(jnp.float32)
-
-    return jax.lax.fori_loop(
-        0, jmax, add, jnp.zeros((pos.shape[0], y.shape[1]), jnp.float32))
-
-
-def _combine_fwd(y, w, pos, src, w_slot, start, jmax):
-    return _combine(y, w, pos, start, jmax), (y, pos, src, w_slot, start)
-
-
-def _combine_bwd(res, g):
-    y, pos, src, w_slot, start = res
-    g_rows = _rows(g, src)                                   # [R, D]
-    dy = (w_slot[:, None] * g_rows).astype(y.dtype)
-    # a weight's gradient where its pair's slot is, then back by token
-    dw_slot = jnp.sum(g_rows * y.astype(jnp.float32), axis=-1)
-    dw, inside = _slot_rows(dw_slot, pos, start)
-    return dy, jnp.where(inside, dw, 0.0), None, None, None, None, None
-
-
-combine_rows.defvjp(_combine_fwd, _combine_bwd)
+def _held_first(a: jnp.ndarray, stacks: Tuple[jnp.ndarray, ...]
+                ) -> jnp.ndarray:
+    """The columns of ``a`` [T, K] that can hold a pair of a held expert: a
+    token's pairs on held experts are the first of its K and at most as
+    many as the experts ``stacks`` hold."""
+    return a[:, :min(a.shape[1], stacks[0].shape[0])]
 
 
 FORMS = {"swiglu": ("wi_gate", "wi_up", "wo"), "relu2": ("wi_up", "wo"),
          "reglu": ("wi_gate", "wi_up", "wo")}
-#         an expert's matrices by its form, in the order they are held
+#         an expert's matrices by its form, in the order they are held: the
+#         matrices into the hidden width, then ``wo`` out of it
 # the gate's function in the forms that have one
 GATES = {"swiglu": nn.silu, "reglu": nn.relu}
 
 
-def _one_pass(uc, w, weights, idx, start, rows_a_pass: int,
-              interpret: bool, form: str) -> Tuple[jnp.ndarray, Any]:
-    """The slots ``start .. start + rows_a_pass`` through the held experts:
-    float32 [T, D], their part of every token's output, and with it what
-    the pass has for the layer's counts: under ``"reglu"`` the hidden units
-    with ``gate > 0`` over the pass's rows on held experts (a float32
-    count), else ``None``. ``weights`` are the experts' stacks in
-    ``FORMS[form]``'s order; ``idx`` is the routing's integer side:
-    ``(src [M], pos [T, K], w_slot [M], ends [count + 1], jmax)``."""
-    src, pos, w_slot, ends, jmax = idx
-    count = weights[0].shape[0]
+def _hidden(form: str, pre: Tuple[jnp.ndarray, ...]) -> jnp.ndarray:
+    """What ``wo`` multiplies, from the rows' products with the matrices
+    before it (``FORMS[form]``'s order)."""
+    if form in GATES:
+        gate, up = pre
+        return GATES[form](gate) * up
+    up, = pre
+    return jnp.square(nn.relu(up))
+
+
+class _Pass(NamedTuple):
+    """A pass's values up to the rows that leave the experts."""
+    src: jnp.ndarray        # [R] a slot's token
+    w: jnp.ndarray          # [R] a slot's weight
+    sizes: jnp.ndarray      # [count + 1] each group's rows inside the pass
+    held_rows: jnp.ndarray  # how many of the pass's rows are a held expert's
+    rows: jnp.ndarray       # [R, D] the tokens' rows by slot
+    pre: Tuple[jnp.ndarray, ...]   # their products into the hidden width
+    act: jnp.ndarray        # [R, H] what ``wo`` multiplies
+    y: jnp.ndarray          # [R, D] the experts' output by slot
+
+
+def _pass_products(uc, stacks, idx, start, rows_a_pass: int,
+                   interpret: bool, form: str) -> _Pass:
+    """The slots ``start .. start + rows_a_pass`` through the held experts,
+    up to the rows that leave them. ``stacks`` are the experts' matrices in
+    ``FORMS[form]``'s order and the rows' type; ``idx`` is the routing's
+    integer side: ``(src [M], pos [T, K], w_slot [M], ends [count + 1])``."""
+    src, _, w_slot, ends = idx
+    count = stacks[0].shape[0]
     with jax.named_scope("moe_dispatch"):
         src_p = jax.lax.dynamic_slice(src, (start,), (rows_a_pass,))
         w_p = jax.lax.dynamic_slice(w_slot, (start,), (rows_a_pass,))
@@ -342,27 +360,12 @@ def _one_pass(uc, w, weights, idx, start, rows_a_pass: int,
         upto = jnp.clip(ends - start, 0, rows_a_pass)
         sizes_p = jnp.diff(upto, prepend=0).astype(jnp.int32)
         sizes_p = sizes_p.at[count].set(rows_a_pass - upto[count - 1])
-        rows = dispatch_rows(uc, src_p, pos, start, jmax)
-    active = None
+        rows = _rows(uc, src_p)
     with jax.named_scope("moe_experts"):
-        if form in GATES:
-            wi_gate, wi_up, wo = weights
-            gate = grouped_matmul(rows, wi_gate, sizes_p, interpret)
-            up = grouped_matmul(rows, wi_up, sizes_p, interpret)
-            y = grouped_matmul(GATES[form](gate) * up, wo, sizes_p,
-                               interpret)
-            if form == "reglu":
-                held_row = jnp.arange(rows_a_pass) < upto[count - 1]
-                active = jnp.sum(
-                    jnp.logical_and(gate > 0, held_row[:, None]),
-                    dtype=jnp.float32)
-        else:
-            wi_up, wo = weights
-            up = grouped_matmul(rows, wi_up, sizes_p, interpret)
-            y = grouped_matmul(jnp.square(nn.relu(up)), wo, sizes_p,
-                               interpret)
-    with jax.named_scope("moe_combine"):
-        return combine_rows(y, w, pos, src_p, w_p, start, jmax), active
+        pre = tuple(_gmm(rows, wi, sizes_p, interpret) for wi in stacks[:-1])
+        act = _hidden(form, pre)
+        y = _gmm(act, stacks[-1], sizes_p, interpret)
+    return _Pass(src_p, w_p, sizes_p, upto[count - 1], rows, pre, act, y)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
@@ -370,24 +373,48 @@ def experts_over_passes(uc, w, weights, idx, n_local, rows_a_pass: int,
                         interpret: bool, form: str
                         ) -> Tuple[jnp.ndarray, Any]:
     """Every pass that holds a pair of a held expert, one after the other:
-    float32 [T, D], and the passes' counts (:func:`_one_pass`) added up in
-    the loop's carry (``None`` where the form has none; no gradient flows
-    into them). The passes are a ``while_loop`` whose length is the
-    routing's (``n_local`` pairs on held experts fill the first slots), which
-    reverse-mode differentiation cannot unroll: the backward is written here,
-    a second loop over the same passes that recomputes each and adds its
-    gradients up, so memory is one pass's in both directions."""
+    float32 [T, D], the held experts' part of every token's output, and the
+    passes' counts added up in the loop's carry: under ``"reglu"`` the
+    hidden units with ``gate > 0`` over the rows on held experts (a float32
+    count), else ``None``; no gradient flows into them. ``uc`` [T, D] the
+    tokens in the experts' type, ``w`` [T, K] float32 (zero for a pair whose
+    expert is not held), ``weights`` the experts' float32 stacks in
+    ``FORMS[form]``'s order, multiplied in ``uc``'s type; ``idx`` as
+    :func:`_pass_products` takes it.
+
+    The passes are a ``while_loop`` whose length is the routing's
+    (``n_local`` pairs on held experts fill the first slots), which
+    reverse-mode differentiation cannot unroll: the backward is written
+    here, a second loop over the same passes that recomputes each pass's
+    products and adds its gradients up, so memory is one pass's in both
+    directions. **A pass adds where it produces.** The loop's carry goes
+    into the pass and comes out added to, every sum in float32 and made by
+    the operation that has the addends: a token's pairs and the passes
+    before in one sweep over ``[T, D]`` (:func:`_add_pairs`: the output
+    forward, the tokens' gradient backward, that one rounded to the rows'
+    type a pass as the rows' gradient is), the stacks' gradients inside
+    ``tgmm`` (:func:`_tgmm_into`). No pass starts a sum from zeros of its
+    own and none is added to the carry afterwards."""
     return _passes_fwd(uc, w, weights, idx, n_local, rows_a_pass, interpret,
                        form)[0]
 
 
 def _passes_fwd(uc, w, weights, idx, n_local, rows_a_pass, interpret, form):
+    stacks = tuple(x.astype(uc.dtype) for x in weights)
+    pos_j, w_j = _held_first(idx[1], stacks), _held_first(w, stacks)
+
     def body(c):
         start, out, active = c
-        nxt = start + rows_a_pass    # first, where the loop always had it
-        part, hot = _one_pass(uc, w, weights, idx, start, rows_a_pass,
-                              interpret, form)
-        return nxt, out + part, None if hot is None else active + hot
+        p = _pass_products(uc, stacks, idx, start, rows_a_pass, interpret,
+                           form)
+        with jax.named_scope("moe_combine"):
+            out = _add_pairs(out, p.y, pos_j, start, scale=w_j)
+        if form == "reglu":
+            on_held = jnp.arange(rows_a_pass) < p.held_rows
+            active = active + jnp.sum(
+                jnp.logical_and(p.pre[0] > 0, on_held[:, None]),
+                dtype=jnp.float32)
+        return start + rows_a_pass, out, active
 
     _, out, active = jax.lax.while_loop(
         lambda c: c[0] < n_local, body,
@@ -398,22 +425,47 @@ def _passes_fwd(uc, w, weights, idx, n_local, rows_a_pass, interpret, form):
 
 def _passes_bwd(rows_a_pass, interpret, form, res, g):
     uc, w, weights, idx, n_local = res
-    diff = (uc, w, tuple(weights))
+    pos = idx[1]
+    stacks = tuple(x.astype(uc.dtype) for x in weights)
+    pos_j = _held_first(pos, stacks)
+    g = g[0]                                                 # [T, D] f32
 
     def body(c):
-        start, acc = c
-        _, vjp, _ = jax.vjp(
-            lambda *a: _one_pass(*a, idx, start, rows_a_pass, interpret,
-                                 form), *diff, has_aux=True)
-        return start + rows_a_pass, jax.tree_util.tree_map(
-            lambda a, d: a + d.astype(a.dtype), acc, vjp(g[0]))
+        start, dx, dw, dstacks = c
+        p = _pass_products(uc, stacks, idx, start, rows_a_pass, interpret,
+                           form)
+        with jax.named_scope("moe_combine"):
+            g_rows = _rows(g, p.src)                         # [R, D]
+            dy = (p.w[:, None] * g_rows).astype(p.y.dtype)
+            # a weight's gradient where its pair's slot is, then back by
+            # token
+            dw_slot = jnp.sum(g_rows * p.y.astype(jnp.float32), axis=-1)
+            dw_p, inside = _slot_rows(dw_slot, pos, start)
+            dw = dw + jnp.where(inside, dw_p, 0.0)
+        with jax.named_scope("moe_experts"):
+            d_act = _gmm(dy, stacks[-1], p.sizes, interpret,
+                         transpose_rhs=True)
+            d_wo = _tgmm_into(dstacks[-1], p.act, dy, p.sizes, interpret)
+            d_pre, = jax.vjp(functools.partial(_hidden, form),
+                             p.pre)[1](d_act)
+            d_rows, d_in = None, []
+            for wi, d, acc in zip(stacks[:-1], d_pre, dstacks[:-1]):
+                part = _gmm(d, wi, p.sizes, interpret, transpose_rhs=True)
+                d_rows = part if d_rows is None else d_rows + part
+                d_in.append(_tgmm_into(acc, p.rows, d, p.sizes, interpret))
+        with jax.named_scope("moe_dispatch"):
+            dx = _add_pairs(dx, d_rows, pos_j, start,
+                            round_to=d_rows.dtype)
+        return start + rows_a_pass, dx, dw, (*d_in, d_wo)
 
-    zeros = jax.tree_util.tree_map(
-        lambda x: jnp.zeros(x.shape, jnp.float32), diff)
-    _, grads = jax.lax.while_loop(lambda c: c[0] < n_local, body,
-                                  (jnp.int32(0), zeros))
-    return jax.tree_util.tree_map(lambda d, x: d.astype(x.dtype), grads,
-                                  diff) + (None, None)
+    _, dx, dw, dstacks = jax.lax.while_loop(
+        lambda c: c[0] < n_local, body,
+        (jnp.int32(0), jnp.zeros(uc.shape, jnp.float32),
+         jnp.zeros(w.shape, jnp.float32),
+         tuple(jnp.zeros(x.shape, jnp.float32) for x in weights)))
+    return (dx.astype(uc.dtype), dw.astype(w.dtype),
+            tuple(d.astype(x.dtype) for d, x in zip(dstacks, weights)),
+            None, None)
 
 
 experts_over_passes.defvjp(_passes_fwd, _passes_bwd)
@@ -456,28 +508,39 @@ class RoutedMoEMLP(nn.Module):
     combine ``pass_rows`` at a time, in a loop that ends with the last pair
     of a held expert (:func:`experts_over_passes`): time follows the pairs
     that are there in units of a pass, memory is one pass's, and the worst
-    case takes every pass.
+    case takes every pass. A pass's sums are made where their addends are
+    (since PR 53): the output's carry takes a token's pairs, weighted, in
+    one sweep (:func:`_add_pairs`: the columns ``j < J`` of the token's
+    pairs, ``J`` the most a token can have on held experts (``top_k``, or
+    the held experts where those are fewer); a loop over tiles of
+    ``TOKEN_TILE`` tokens, so the program's size does not follow the
+    tokens), the backward's carry of the tokens' gradient the same way, and
+    the three (or two) float32 stacks of the experts' gradients go through
+    ``tgmm`` as its ``existing_out``.
 
-    Three process-wide program counters (``tracing.count_in_program``;
+    Four process-wide program counters (``tracing.count_in_program``;
     ``Manager.metrics()`` reports them) go up once a call made under a
     collector (``tracing.collect_counts``, which every trainer of this
     package wraps its loss in; under a hand-written
     ``jax.jit(jax.value_and_grad(...))`` nothing is counted):
     ``moe_pairs_routed_total`` (token-expert pairs routed),
-    ``moe_pairs_local_total`` (those whose expert is held) and
-    ``moe_expert_load_max_total`` (the largest load of a held expert). The
+    ``moe_pairs_local_total`` (those whose expert is held),
+    ``moe_expert_load_max_total`` (the largest load of a held expert) and
+    ``moe_passes_total`` (passes the loops ran: the pairs on held experts
+    over the slots a pass takes, rounded up; a pass sweeps ``[T, D]`` once
+    a direction). The
     collector returns them from the program, so they have to be values of
     the function it wraps: a caller that rematerialises the layer
     (``jax.checkpoint``) or runs it in a ``lax.scan`` body asks for the
-    numbers (``return_stats=True``: ``(out, int32[3])`` in that order),
+    numbers (``return_stats=True``: ``(out, int32[4])`` in that order),
     returns them out of that region and counts them outside, as
     ``Transformer`` does, once a step for all its layers. A ``"reglu"``
-    layer has a fourth number, ``moe_reglu_active_micro_total``: over the
+    layer has one number more, ``moe_reglu_active_micro_total``: over the
     rows that hold a pair of a held expert, the share of the hidden units
     with ``gate > 0``, in millionths (about half at seeded weights; 0 says
     the experts carry nothing, 1,000,000 that the gate is a plain product),
     added up in the pass loop's carry; its stats are the pair
-    ``(int32[3], float32 share)``.
+    ``(int32[4], float32 share)``.
     """
 
     num_experts: int
@@ -552,15 +615,14 @@ class RoutedMoEMLP(nn.Module):
             self.sow("intermediates", "experts", top_idx)
 
         with jax.named_scope("moe_dispatch"):
-            # A token's pairs on held experts first among its K (the
-            # gathers over tokens stop at the most any token has).
+            # A token's pairs on held experts first among its K (the sums
+            # over a token's pairs read the first ``min(K, count)``).
             local = jnp.logical_and(top_idx >= first, top_idx < first + count)
             by_local = jnp.argsort(jnp.logical_not(local), axis=-1,
                                    stable=True)
             top_idx = jnp.take_along_axis(top_idx, by_local, axis=-1)
             top_w = jnp.take_along_axis(top_w, by_local, axis=-1)
             local = jnp.take_along_axis(local, by_local, axis=-1)
-            jmax = jnp.max(jnp.sum(local, axis=-1, dtype=jnp.int32))
             # A pair's group: its expert's place among the held ones, or
             # ``count`` for an expert that is not held. One stable sort puts
             # the held experts' pairs first, expert by expert.
@@ -577,7 +639,8 @@ class RoutedMoEMLP(nn.Module):
                             axis=0, dtype=jnp.int32)
             n_local = jnp.sum(sizes[:count])
             stats = jnp.stack([jnp.int32(t * k), n_local,
-                               jnp.max(sizes[:count], initial=0)])
+                               jnp.max(sizes[:count], initial=0),
+                               -(-n_local // rows_a_pass)])
             w = jnp.where(local, top_w, 0.0)
             # a slot's weight; a padding slot reads the zero at the end
             w_slot = jax.lax.stop_gradient(_rows(
@@ -586,7 +649,7 @@ class RoutedMoEMLP(nn.Module):
             ends = jnp.cumsum(sizes)
 
         def with_share(active):
-            # a "reglu" layer's fourth number beside the three
+            # a "reglu" layer's share of active units beside the four
             if self.form != "reglu":
                 return stats
             return stats, active / (jnp.maximum(n_local, 1) * h)
@@ -597,7 +660,7 @@ class RoutedMoEMLP(nn.Module):
 
         out, active = experts_over_passes(
             u.astype(self.dtype), w, weights,
-            (src, pos, w_slot, ends, jmax), n_local, rows_a_pass, interpret,
+            (src, pos, w_slot, ends), n_local, rows_a_pass, interpret,
             self.form)
         stats = with_share(active)
 
@@ -643,7 +706,7 @@ class _SharedExpert(nn.Module):
 
 
 MOE_COUNTERS = ("moe_pairs_routed_total", "moe_pairs_local_total",
-                "moe_expert_load_max_total")
+                "moe_expert_load_max_total", "moe_passes_total")
 REGLU_COUNTER = "moe_reglu_active_micro_total"
 ROUTE_AHEAD_COUNTER = "moe_route_ahead_layers_total"
 
