@@ -60,6 +60,8 @@ MODULES = [
     ("torchft_tpu.ops.flash_attention", "Pallas flash attention kernels"),
     ("torchft_tpu.ops.gated_delta", "Gated delta rule: chunked linear-"
                                     "attention scan, causal short conv"),
+    ("torchft_tpu.ops.ssd", "Mamba-2 state-space scan: two Pallas kernels "
+                            "under one custom_vjp, token-by-token oracle"),
     ("torchft_tpu.models", "Example model zoo"),
     ("torchft_tpu.parameter_server", "Lighthouse-free parameter server"),
     ("torchft_tpu.lighthouse", "Standalone lighthouse CLI"),

@@ -316,13 +316,28 @@ def test_gated_delta_rule_compiles_at_the_published_sizes(one_chip, tokens,
     assert _footprint(c) < 3 * 2**30
 
 
+def _compiled_state_space_scan(monkeypatch):
+    """``ssd_scan`` decides as the delta rule does: steered the same way."""
+    import importlib
+
+    ssd = importlib.import_module("torchft_tpu.ops.ssd")
+    monkeypatch.setattr(ssd, "_resolve_interpret", lambda _: False)
+    return ssd
+
+
 @pytest.mark.parametrize("tokens", [8192, 8192 + 96], ids=["8k", "ragged"])
-def test_state_space_scan_compiles_at_the_published_sizes(one_chip, tokens):
-    """The chunked Mamba-2 scan forward and backward at 64 heads of 64 in 8
-    groups, state 128 (``ops/ssd.py``): batched products only, so no loop
-    and no kernel, and the per-chunk states are float32
-    [8, 8, chunks, 64, 128] (the chip's compiler drops the batch's 1)."""
-    from torchft_tpu.ops import ssd_scan
+def test_state_space_scan_compiles_at_the_published_sizes(one_chip, tokens,
+                                                          monkeypatch):
+    """The Mamba-2 scan forward and backward at 64 heads of 64 in 8 groups,
+    state 128 (``ops/ssd.py``): two Mosaic kernels, ``ssd_fwd`` handing
+    ``ssd_bwd`` the state each chunk found, float32 [1, chunks, 64 x 64, 128],
+    a group's 8 heads a grid step; no loop, and nowhere in the program the
+    batched products' ``[8, 8, chunks, 128, 128]`` triangles or their
+    per-chunk states ``[8, 8, chunks, 64, 128]``; the kernels ask for the
+    VMEM they are written to and are given it."""
+    import re
+
+    ssd = _compiled_state_space_scan(monkeypatch)
 
     def shaped(*shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -331,13 +346,29 @@ def test_state_space_scan_compiles_at_the_published_sizes(one_chip, tokens):
     args = (shaped(1, tokens, 64, 64), shaped(1, tokens, 64, dtype=f32),
             shaped(64, dtype=f32), shaped(1, tokens, 8, 128),
             shaped(1, tokens, 8, 128), shaped(64, dtype=f32))
-    c = jax.jit(jax.grad(lambda *a: ssd_scan(*a).sum(),
+    c = jax.jit(jax.grad(lambda *a: ssd.ssd_scan(*a).sum(),
                          argnums=tuple(range(6)))).lower(*args).compile()
     text = c.as_text()
+    kernels = {name: line for line in text.splitlines()
+               if "tpu_custom_call" in line
+               for name in re.findall(r"ssd_(?:fwd|bwd)", line)[:1]}
+    assert sorted(kernels) == ["ssd_bwd", "ssd_fwd"]
+    assert text.count("tpu_custom_call") == 2
     chunks = -(-tokens // 128)
-    assert f"8,8,{chunks},64,128]" in text
-    assert "while(" not in text and "tpu_custom_call" not in text
-    assert _footprint(c) < 3 * 2**30
+    states = f"f32[1,{chunks},4096,128]"
+    assert f"{states}{{3,2,1,0" in kernels["ssd_fwd"].split(
+        "custom-call(")[0]                                    # written
+    assert states in kernels["ssd_bwd"].split("custom-call(")[1]   # read
+    assert "while(" not in text
+    assert f"8,8,{chunks},128,128]" not in text
+    assert f"8,8,{chunks},64,128]" not in text
+    assert ssd._heads_a_step(8, 64, 128, 2) == 8
+    for line in kernels.values():
+        asked = re.findall(
+            r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', line)
+        assert asked == [str(ssd._VMEM_LIMIT_BYTES)]
+    assert ssd._TILE_BYTES < ssd._VMEM_LIMIT_BYTES < VMEM_BYTES // 2
+    assert _footprint(c) < 2**30
 
 
 def test_one_group_step_depth2_fits_the_chip(one_chip):
@@ -500,8 +531,9 @@ CELL_STEPS = {
                                         "%gdn_bwd", "%gdn_inv",
                                         "f32[32,128,128,128]",
                                         "bf16[16,8192,256]"),
-    "nemotron-3-nano-30b-a3b.steady-1g-8k": ("%attn", "gmm",
-                                             "8,8,64,64,128]",
+    "nemotron-3-nano-30b-a3b.steady-1g-8k": ("%attn", "gmm", "%ssd_fwd",
+                                             "%ssd_bwd",
+                                             "f32[1,64,4096,128]",
                                              "bf16[32,8192,128]"),
     "lfm2-8b-a1b.steady-1g-8k": ("%attn", "gmm", "bf16[32,8192,64]",
                                  "bf16[8,8192,64]", "bf16[1,8192,6144]",
@@ -533,9 +565,10 @@ def test_sparse_cells_step_fits_the_chip(one_chip, name, monkeypatch):
     one head; ``qwen3-next-80b-a3b``: three Gated DeltaNet layers (the
     scans that carry ``f32[1,32,128,128]``) and one full layer's flash
     kernel at 16 heads of 256, 16 of 512 experts held;
-    ``nemotron-3-nano-30b-a3b``: three Mamba-2 blocks (the scan's per-chunk
-    states ``[8,8,64,64,128]``, no loop), three relu^2 expert blocks whose
-    grouped products tile 2688 and 1856 by 896 and 640, one attention block
+    ``nemotron-3-nano-30b-a3b``: three Mamba-2 blocks (the scan's kernels
+    ``ssd_fwd`` and ``ssd_bwd`` and between them the states the chunks found,
+    ``f32[1,64,4096,128]``; no ``[8,8,64,128,128]`` triangle), three relu^2
+    expert blocks whose grouped products tile 2688 and 1856 by 896 and 640, one attention block
     at 32 heads of 128; ``lfm2-8b-a1b``: four gated short convolutions (the
     three streams ``[1,8192,6144]``), the flash kernels at 32 heads of 64
     on 8 key/value heads, four expert layers holding 8 of 32 at a width of
@@ -554,6 +587,7 @@ def test_sparse_cells_step_fits_the_chip(one_chip, name, monkeypatch):
     from harness import spec
 
     _compiled_delta_rule(monkeypatch)
+    _compiled_state_space_scan(monkeypatch)
     cell = spec.Cell(name)
     cfg, seq, batch = (cell.config, int(cell.mix["seq"]),
                        int(cell.mix["batch_per_group"]))
@@ -573,5 +607,6 @@ def test_sparse_cells_step_fits_the_chip(one_chip, name, monkeypatch):
     for kernel in CELL_STEPS[name]:
         assert kernel in text
     assert "f32[1,32,128,128]" not in text and "riangular" not in text
+    assert "8,8,64,128,128]" not in text
     tree = 4 * builder.param_count(cfg)
     assert 6 * tree < _footprint(c) < HBM_BYTES - SAMPLE_ROOM.get(name, tree)

@@ -1,10 +1,11 @@
-"""The chunked state-space scan (``ops/ssd.py: ssd_scan``) against the
-token-by-token recurrence (``ssd_recurrent``): values and every gradient in
-float32 and with bfloat16 products, a ragged length, a state that must be
-carried over chunks, decays of -20 a token, groups shared by heads; and the
-convolution that serves both scans (``causal_conv1d``): with a bias and,
-without one, bit for bit what ``GatedDeltaNet`` computed before it had the
-argument."""
+"""The chunked state-space scan (``ops/ssd.py: ssd_scan``, its two Pallas
+kernels interpreted here) against the token-by-token recurrence
+(``ssd_recurrent``): values and every gradient in float32 and with bfloat16
+products, a ragged length, a state that must be carried over chunks, decays
+of -20 a token, groups shared by heads, grid steps narrower than a group,
+and what the lowered program holds beside the kernels; and the convolution
+that serves both scans (``causal_conv1d``): with a bias and, without one,
+bit for bit what ``GatedDeltaNet`` computed before it had the argument."""
 
 import functools
 import json
@@ -30,14 +31,14 @@ def small_chunk(monkeypatch):
     monkeypatch.setattr(ssd_module, "CHUNK", 16)
 
 
-def inputs(t, seed=0, dt_shift=-2.0, rate=0.3):
+def inputs(t, seed=0, dt_shift=-2.0, rate=0.3, bsz=B, h=H, g=G):
     k = jax.random.split(jax.random.key(seed), 6)
-    return (jax.random.normal(k[0], (B, t, H, P)),
-            jax.nn.softplus(jax.random.normal(k[1], (B, t, H)) + dt_shift),
-            -jnp.exp(jax.random.normal(k[2], (H,)) * rate),
-            jax.random.normal(k[3], (B, t, G, N)),
-            jax.random.normal(k[4], (B, t, G, N)),
-            jax.random.normal(k[5], (H,)))
+    return (jax.random.normal(k[0], (bsz, t, h, P)),
+            jax.nn.softplus(jax.random.normal(k[1], (bsz, t, h)) + dt_shift),
+            -jnp.exp(jax.random.normal(k[2], (h,)) * rate),
+            jax.random.normal(k[3], (bsz, t, g, N)),
+            jax.random.normal(k[4], (bsz, t, g, N)),
+            jax.random.normal(k[5], (h,)))
 
 
 def grads(fn, args):
@@ -81,11 +82,127 @@ def test_chunked_scan_against_the_recurrence_float32(t):
 
 @pytest.mark.parametrize("which", range(6), ids=NAMES)
 def test_every_gradient_against_the_recurrence_float32(which):
-    """The scan's derivative (of the batched products) against the
-    derivative of the token loop, input by input; float32, so the same
-    1e-4 of the gradient's own scale."""
+    """The scan's written backward (``ssd_bwd``) against the derivative of
+    the token loop, input by input; float32, so the same 1e-4 of the
+    gradient's own scale."""
     got, want = both_gradients()
     assert rel(got[which], want[which]) < 1e-4
+
+
+# name: batch, tokens, heads, groups, heads a grid step (None: what the
+# shapes give, the whole group at these sizes). Chunks are 16 tokens.
+LAYOUTS = {
+    "batch-of-2": (2, 32, 4, 2, None),
+    "batch-of-1": (1, 32, 4, 2, None),
+    "ragged-backward": (2, 23, 4, 2, None),
+    "two-groups-narrow-steps": (2, 40, 4, 2, 1),
+    "one-group-of-4": (1, 40, 4, 1, None),
+    "one-group-steps-of-2": (1, 40, 4, 1, 2),
+    "one-group-steps-of-1": (1, 23, 4, 1, 1),
+    "a-head-a-group": (1, 32, 4, 4, None),
+}
+
+
+def layout_inputs(name):
+    bsz, t, h, g, _ = LAYOUTS[name]
+    return inputs(t, seed=sum(map(ord, name)), bsz=bsz, h=h, g=g)
+
+
+@pytest.mark.parametrize("name", list(LAYOUTS))
+def test_value_and_gradients_in_every_layout(name, monkeypatch):
+    """The grid is (batch, heads / hb, chunks): a batch of 2 and of 1, a
+    length that is no whole number of chunks THROUGH the backward, one group
+    and a group a head, and steps narrower than a group, where ``dB`` and
+    ``dC`` leave the kernel as float32 partial sums a step and are added
+    outside. Value and all six gradients against the recurrence, float32."""
+    hb = LAYOUTS[name][4]
+    if hb is not None:
+        monkeypatch.setattr(ssd_module, "_heads_a_step", lambda *_: hb)
+    args = layout_inputs(name)
+    with jax.default_matmul_precision("highest"):
+        scan = lambda *a: ssd_scan(*a, jnp.float32)
+        assert rel(jax.jit(scan)(*args), jax.jit(ssd_recurrent)(*args)) < 1e-4
+        for n, got, want in zip(NAMES, grads(scan, args),
+                                grads(ssd_recurrent, args)):
+            assert got.shape == want.shape and got.dtype == want.dtype, n
+            assert rel(got, want) < 1e-4, n
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_narrow_steps_against_the_whole_group(dtype, monkeypatch):
+    """``hb`` is how the work is cut, not what is computed: steps of one
+    head against the whole group of four, value and gradients. A head's own
+    results see nothing of the others (equal to float32 rounding: the CPU
+    fuses the two shapes differently); ``dB`` and ``dC`` are sums over the
+    group's heads, which narrow steps leave as a partial sum a step, each
+    product's cotangent rounded to ``dtype`` before it, where the whole
+    group rounds their sum once."""
+    args = layout_inputs("one-group-of-4")
+    scan = lambda *a: ssd_scan(*a, dtype)
+    assert ssd_module._heads_a_step(4, P, N, 2) == 4
+    whole = (jax.jit(scan)(*args), *grads(scan, args))
+    monkeypatch.setattr(ssd_module, "_heads_a_step", lambda *_: 1)
+    narrow = (jax.jit(scan)(*args), *grads(scan, args))
+    for n, a, b in zip(("y",) + NAMES, narrow, whole):
+        shared = n in ("b", "c") and dtype == jnp.bfloat16
+        assert rel(a, b) < (1e-2 if shared else 1e-5), n
+
+
+def test_heads_a_step_is_a_divisor_that_fits(monkeypatch):
+    """The whole group at the published sizes; under a tighter budget the
+    largest divisor of the group whose tiles fit, and never less than one
+    head."""
+    monkeypatch.setattr(ssd_module, "CHUNK", 128)
+    assert ssd_module._heads_a_step(8, 64, 128, 2) == 8
+    monkeypatch.setattr(ssd_module, "_TILE_BYTES", 2 << 20)
+    assert ssd_module._heads_a_step(8, 64, 128, 2) == 4
+    assert ssd_module._heads_a_step(10, 64, 128, 2) == 5
+    monkeypatch.setattr(ssd_module, "_TILE_BYTES", 1 << 10)
+    assert ssd_module._heads_a_step(8, 64, 128, 2) == 1
+
+
+def test_the_lowered_gradient_holds_two_kernels_and_no_triangle(monkeypatch):
+    """The jitted gradient lowered for a TPU (nothing runs; chunks of 128,
+    the mixer's layouts: ``x`` a ``[B, T, H P]`` array, ``B`` and ``C``
+    ``[B, T, G N]``): two Mosaic calls, ``ssd_fwd`` and ``ssd_bwd``; outside
+    them no ``[.., 128, 128]`` triangle, no per-chunk state but the one the
+    forward hands the backward, and no transpose of anything larger than
+    the ``[B, T, H]`` rows of ``Delta`` and ``gamma``: ``x``, ``y``, ``B``
+    and ``C`` go in and out as they lie."""
+    import re
+
+    monkeypatch.setattr(ssd_module, "CHUNK", 128)
+    monkeypatch.setattr(ssd_module, "_resolve_interpret", lambda _: False)
+    bsz, t, h, p, g, n = 2, 256, 4, 64, 2, 128
+    f32, bf16 = jnp.float32, jnp.bfloat16
+
+    def loss(x, dt, a, b, c, d):
+        return ssd_scan(x.reshape(bsz, t, h, p), dt, a,
+                        b.reshape(bsz, t, g, n), c.reshape(bsz, t, g, n),
+                        d).sum()
+
+    shapes = [jax.ShapeDtypeStruct(s, dt) for s, dt in (
+        ((bsz, t, h * p), bf16), ((bsz, t, h), f32), ((h,), f32),
+        ((bsz, t, g * n), bf16), ((bsz, t, g * n), bf16), ((h,), f32))]
+    text = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).trace(
+        *shapes).lower(lowering_platforms=("tpu",)).as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 2
+    assert "ssd_fwd" in calls[0] and "ssd_bwd" in calls[1]
+    assert "x128x128x" not in text                    # no triangle
+    states = f"tensor<{bsz}x{t // 128}x{h * p}x{n}xf32>"
+    assert all(states in line for line in calls)      # written, then read
+    # and nothing else computes on it: the other lines that name it hand it
+    # from the forward sweep's function to the backward's
+    for line in text.splitlines():
+        if states in line and "tpu_custom_call" not in line:
+            assert "stablehlo." not in line, line
+    transposed = re.findall(r"stablehlo\.transpose.*: \(tensor<([\dx]+)xf32>\)",
+                            text)
+    assert transposed and len(transposed) == text.count("stablehlo.transpose")
+    for shape in transposed:
+        assert np.prod([int(s) for s in shape.split("x")]) <= bsz * t * h
 
 
 def test_bfloat16_products_stay_near_the_recurrence():

@@ -16,14 +16,16 @@ over each group's ``H P / G`` channels. ``Delta``, the scan's decays and
 states, the gate and the norm are float32.
 
 The scan's residuals are kept, not recomputed under ``jax.checkpoint`` as
-the delta rule's are: it is a handful of batched products (no loop), and
-what the step has no room for the chip's compiler rematerialises by itself
-either way. It puts the benchmark cell's step (three mixers at 1 x 8,192
-tokens, published sizes, six trees of state beside it) at 2.344 GiB of
-temporaries as the cell runs, at 2.358 with per-layer remat asked for and
-at 2.348 with that and the scan under ``jax.checkpoint``
-(``benchmarks/aot_check.py``; PERF.md, PR 45): a second forward scan
-would buy 10 MiB.
+the delta rule's are: since PR 54 they are the scan's inputs and the state
+each chunk found (float32 ``[B, chunks, H P, N]``: 0.13 GiB a block at the
+benchmark cell's 1 x 8,192 tokens and published sizes, where the batched
+products the kernels replaced kept or recomputed 0.7 of triangles and
+per-chunk states), and what the step has no room for the chip's compiler
+rematerialises by itself either way: under the cell's six trees of state it
+reads 2.13 GiB of temporaries (2.29 before the kernels;
+``tests/test_chip_compile.py``'s compile of the step, PERF.md, PR 54), and a
+second forward scan under ``jax.checkpoint`` would buy back one state a
+block.
 
 The layer's two numbers for the program counters leave it as values
 (``return_stats=True``), as ``GatedDeltaNet``'s do and for the same reason:
