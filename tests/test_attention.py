@@ -152,6 +152,52 @@ class TestHeadOf64:
         assert 8192 // _auto_block(8192, cap=1024) >= 4
 
 
+class TestSixteenHeadsOf128:
+    """Ouro-2.6B's attention (PR 56): plain multi-head attention, 16 query
+    heads on 16 key/value heads of 128: a group of ONE at a head of 128,
+    which no other cell's plain kernels run (``(bh % h) // rep`` with
+    ``rep`` 1, no dk/dv sum over a group). Forward, the split backward
+    (interpreted, ``_flash_bwd`` takes it) and the fused backward (told it
+    compiles, every ``pallas_call`` interpreted, as
+    ``tests/test_flash_band.py`` does) against ``plain_attention`` at a q
+    grid four blocks deep."""
+
+    B, S, H, D, BLOCK = 1, 256, 16, 128, 64
+
+    @pytest.mark.parametrize("which", ["forward", "split", "fused"])
+    def test_against_plain_attention(self, which, monkeypatch):
+        import importlib
+
+        fa = importlib.import_module("torchft_tpu.ops.flash_attention")
+        q, k, v = qkv(b=self.B, s=self.S, h=self.H, d=self.D, seed=3)
+        g = jax.random.normal(jax.random.key(4), q.shape)
+        want, vjp = jax.vjp(lambda *a: plain_attention(*a, True), q, k, v)
+        if which == "forward":
+            got = flash_attention(q, k, v, True, self.BLOCK, self.BLOCK,
+                                  interpret=True)
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       atol=2e-5)
+            return
+        real = fa.pl.pallas_call
+        calls = []
+
+        def call(*a, **kw):
+            calls.append(kw.get("name"))
+            return real(*a, **{**kw, "interpret": True})
+
+        monkeypatch.setattr(fa.pl, "pallas_call", call)
+        monkeypatch.delenv("TORCHFT_FLASH_FUSED_BWD", raising=False)
+        out, lse = fa._flash_fwd(q, k, v, True, self.BLOCK, self.BLOCK, True)
+        del calls[:]
+        got = fa._flash_bwd(q, k, v, out, lse, g, True, self.BLOCK,
+                            self.BLOCK, interpret=which == "split")
+        assert len(calls) == (2 if which == "split" else 1)
+        for a, b in zip(got, vjp(g)):
+            assert a.shape == b.shape == q.shape
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=1e-4)
+
+
 class TestFlashAttentionGQA:
     """GQA/MQA kv heads are shared via kernel index maps — values and
     gradients must match the materialized-repeat path exactly."""

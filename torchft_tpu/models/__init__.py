@@ -6,10 +6,12 @@ from torchft_tpu.models.transformer import (
     TransformerConfig,
     causal_lm_loss,
     chunked_causal_lm_loss,
+    chunked_weighted_nll,
     head_kernel,
     llama2_7b_config,
     llama2_13b_config,
     llama2_70b_config,
+    looped_causal_lm_loss,
     moe_lm_loss,
     mtp_causal_lm_loss,
     tiny_config,
@@ -39,10 +41,12 @@ __all__ = [
     "TransformerConfig",
     "causal_lm_loss",
     "chunked_causal_lm_loss",
+    "chunked_weighted_nll",
     "head_kernel",
     "llama2_7b_config",
     "llama2_13b_config",
     "llama2_70b_config",
+    "looped_causal_lm_loss",
     "tiny_config",
     "tp_rules",
 ]

@@ -124,9 +124,24 @@ class TransformerConfig:
     # and head (``Transformer.__call__(..., return_mtp=True)``,
     # :func:`mtp_causal_lm_loss`).
     mtp_layers: int = 0
-    # Per-layer rematerialization (jax.checkpoint): trade ~30% backward
-    # FLOPs for O(num_layers) fewer live activations — the standard move
-    # for long-context / big-batch training on HBM-bound chips.
+    # A looped stack: the ``num_layers`` layers and the final norm run
+    # ``loop_steps`` times over ONE set of leaves, each pass reading the
+    # pass before (the first the embedding), as one scan over the passes
+    # whose body is one pass (``return_exits=True`` hands every pass's
+    # output out, :func:`looped_causal_lm_loss`). ``exit_gate``: a learned
+    # gate ``h . w + b`` on each exit (one float32 leaf, ``exit_gate/kernel``
+    # [E + 1, 1], the bias its last row), whose exit distribution weights
+    # the exits' losses. At 1 and False the model is the plain stack, the
+    # same names and the same program.
+    loop_steps: int = 1
+    exit_gate: bool = False
+    # Per-layer rematerialization (``nn.remat`` of every layer). In a stack
+    # that runs once it frees NOTHING on the chip (PERF.md section 7): with
+    # ``prevent_cse=False`` outside a scan XLA merges the rematerialised
+    # forward with the first one and keeps its activations, so it is no
+    # switch to reach for when a model that runs once is short of memory.
+    # Inside the pass scan of ``loop_steps > 1`` it is real: a pass keeps
+    # each layer's input and the backward pass recomputes the layer.
     remat: bool = False
 
     @property
@@ -481,7 +496,7 @@ class Transformer(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, return_hidden: bool = False,
-                 return_mtp: bool = False):
+                 return_mtp: bool = False, return_exits: bool = False):
         """``return_hidden=True`` skips the LM head and returns the
         final-norm hidden states [B, S, E] — pair with
         :func:`chunked_causal_lm_loss` so the [B, S, vocab] logits tensor
@@ -495,11 +510,22 @@ class Transformer(nn.Module):
         embedding, which causal attention keeps from every other), and the
         layers' summed counts as ``{counter: value}`` (``None`` where no
         layer has any), which are then NOT counted here: the caller counts
-        them with whatever else it counts (:func:`mtp_causal_lm_loss`)."""
+        them with whatever else it counts (:func:`mtp_causal_lm_loss`).
+
+        ``return_exits=True`` (a looped stack, ``loop_steps`` passes)
+        returns ``(exits, gate_logits)``: every pass's final-norm states
+        stacked ``[T, B, S, E]`` and, with ``exit_gate``, the gate's float32
+        logits on the exits of the passes before the last ``[T - 1, B, S]``
+        (else ``None``). With ``loop_steps > 1`` ``return_hidden`` and the
+        logits are the last pass's."""
         cfg = self.cfg
         if return_mtp and cfg.mtp_layers != 1:
             raise ValueError("return_mtp needs a model with mtp_layers=1, "
                              f"got {cfg.mtp_layers}")
+        looped = cfg.loop_steps > 1 or cfg.exit_gate or return_exits
+        if looped and (return_mtp or cfg.loop_steps < 1):
+            raise ValueError("a looped stack has loop_steps >= 1 and no "
+                             "prediction module")
         embed = nn.Embed(cfg.vocab_size, cfg.embed_dim,
                          dtype=cfg.dtype, name="embed")
         x = embed(tokens)
@@ -508,6 +534,9 @@ class Transformer(nn.Module):
         if cfg.layer_types and len(cfg.layer_types) != cfg.num_layers:
             raise ValueError(f"{len(cfg.layer_types)} layer_types for "
                              f"{cfg.num_layers} layers")
+        if looped:
+            return self._looped(embed, x, tokens.shape, return_hidden,
+                                return_exits)
         positions = jnp.broadcast_to(
             jnp.arange(tokens.shape[1]), tokens.shape)
         layer_cls = (nn.remat(DecoderLayer, prevent_cse=False)
@@ -538,10 +567,68 @@ class Transformer(nn.Module):
             return x
         # the head in f32 for a stable loss: the embedding table read again
         # (``tie_embeddings``), else a kernel of its own
-        if cfg.tie_embeddings:
+        return self._head(embed, x)
+
+    def _head(self, embed, x):
+        if self.cfg.tie_embeddings:
             return x.astype(jnp.float32) @ embed.embedding.T
-        return nn.Dense(cfg.vocab_size, use_bias=False, dtype=jnp.float32,
-                        name="lm_head")(x)
+        return nn.Dense(self.cfg.vocab_size, use_bias=False,
+                        dtype=jnp.float32, name="lm_head")(x)
+
+    def _looped(self, embed, x, shape, return_hidden, return_exits):
+        """The stack as ONE scan over ``loop_steps`` passes whose body is
+        one pass: the layers, then the final norm, over one set of leaves
+        (broadcast to every pass: no pass axis in the tree, the paths
+        ``layer_i/...`` and ``final_norm/...`` of the plain stack). A
+        pass's output is both its exit and the next pass's input. With
+        ``cfg.remat`` each layer is rematerialised inside the body, where
+        it is real: a pass keeps its layers' inputs and nothing else."""
+        cfg = self.cfg
+        layer_cls = (nn.remat(DecoderLayer, prevent_cse=False)
+                     if cfg.remat else DecoderLayer)
+
+        def one_pass(mdl, x):
+            positions = jnp.broadcast_to(jnp.arange(shape[1]), shape)
+            with jax.named_scope("loop_pass"):
+                for i in range(cfg.num_layers):
+                    x = layer_cls(cfg, kind=cfg.layer_type(i),
+                                  moe=cfg.is_moe_layer(i), parent=mdl,
+                                  name=f"layer_{i}")(x, positions)
+                    if isinstance(x, tuple):
+                        raise ValueError(
+                            "a looped stack's layers hand no counts out of "
+                            f"the pass scan; layer {i} "
+                            f"({cfg.layer_type(i)!r}) has some")
+                x = RMSNorm(eps=cfg.rms_norm_eps, parent=mdl,
+                            name="final_norm")(x)
+            return x, x
+
+        x, exits = nn.scan(one_pass, variable_broadcast="params",
+                           split_rngs={"params": False},
+                           length=cfg.loop_steps)(self, x)
+        logits = None
+        if cfg.exit_gate:   # whatever is asked for: the leaf exists
+            with jax.named_scope("loop_gate"):
+                logits = ExitGate(name="exit_gate")(exits[:-1])
+        if return_exits:
+            return exits, logits
+        return x if return_hidden else self._head(embed, x)
+
+
+class ExitGate(nn.Module):
+    """A looped stack's exit gate: ``h . w + b`` a position in float32 (a
+    multiply and a sum, not a product the chip would round). ONE leaf,
+    ``kernel`` ``[E + 1, 1]``: ``w`` and, its last row, ``b`` (the input
+    read as ``[h ; 1]``). A bias leaf of its own is one number whose
+    gradient, a mean over every position of terms of both signs, passes
+    through zero from seed to seed, where no comparison relative to the
+    leaf's own scale can hold it (PERF.md, PR 56)."""
+
+    @nn.compact
+    def __call__(self, h):
+        w = self.param("kernel", nn.initializers.normal(0.02),
+                       (h.shape[-1] + 1, 1))[:, 0]
+        return jnp.sum(h.astype(jnp.float32) * w[:-1], axis=-1) + w[-1]
 
 
 # --------------------------------------------------------------- presets
@@ -797,6 +884,186 @@ def chunked_causal_lm_loss(hidden: jnp.ndarray, head_kernel: jnp.ndarray,
         chunk_size = head_loss_chunk(hidden.shape[0], hidden.shape[1],
                                      head_kernel.shape[1])
     return _head_loss(hidden, head_kernel, tokens, chunk_size, matmul_dtype)
+
+
+# ------------------------------------------- the head's loss, weighted
+#
+# ``_head_loss`` with the weight of a position an argument and the
+# per-position loss a result: what a loss over several exits needs, whose
+# weights (an exit distribution) are themselves differentiated.
+
+def _weight_chunks(weights, n_chunks, chunk_size):
+    """``weights`` [b, s1] cut as :func:`_head_chunks` cuts the positions,
+    zeros where they pad: ``[n, b, chunk]``."""
+    b, s1 = weights.shape
+    padded = jnp.pad(weights, ((0, 0), (0, n_chunks * chunk_size - s1)))
+    return padded.reshape(b, n_chunks, chunk_size).transpose(1, 0, 2)
+
+
+def _rows(stacked, s1):
+    """``[n, b, chunk, ...]`` scan outputs back to ``[b, s1, ...]``."""
+    b = stacked.shape[1]
+    joined = jnp.moveaxis(stacked, 0, 1)
+    return joined.reshape((b, -1) + stacked.shape[3:])[:, :s1]
+
+
+def _weighted_nll_alone(hidden, head_kernel, tokens, weights, chunk_size,
+                        matmul_dtype):
+    """The weighted sum and the per-position loss: one scan, one head
+    product a chunk."""
+    w = _head_operand(head_kernel, matmul_dtype)
+    hc, tc, _ = _head_chunks(hidden, tokens, chunk_size)
+    wc = _weight_chunks(weights, hc.shape[0], chunk_size)
+
+    def body(total, xs):
+        h_c, t_c, w_c = xs
+        _, nll = _chunk_nll(h_c, t_c, w)
+        return total + jnp.sum(nll * w_c), nll
+
+    total, nll = jax.lax.scan(body, jnp.float32(0.0), (hc, tc, wc))
+    return total, _rows(nll, weights.shape[1])
+
+
+def _weighted_nll_fwd(hidden, head_kernel, tokens, weights, chunk_size,
+                      matmul_dtype):
+    """As :func:`_head_loss_fwd` with ``dlogits = (softmax - onehot) *
+    weights``: the sum, the per-position loss and, in the same scan, both
+    head gradients at a cotangent of one; three head products a chunk."""
+    from torchft_tpu import tracing
+
+    b, s, e = hidden.shape
+    s1 = s - 1
+    v = head_kernel.shape[1]
+    w = _head_operand(head_kernel, matmul_dtype)
+    hc, tc, _ = _head_chunks(hidden, tokens, chunk_size)
+    wc = _weight_chunks(weights, hc.shape[0], chunk_size)
+    # counted on the host, when the rule is traced: nothing in the step
+    tracing.add_program_counters(
+        head_loss_weighted_traces_total=1,
+        head_loss_weighted_chunks_traced_total=hc.shape[0])
+
+    def body(carry, xs):
+        total, dw = carry
+        h_c, t_c, w_c = xs
+        logp, nll = _chunk_nll(h_c, t_c, w)
+        hit = t_c[..., None] == jnp.arange(v, dtype=t_c.dtype)
+        dlogits = (jnp.exp(logp) - hit) * w_c[..., None]
+        dh_c = jnp.einsum("bcv,ev->bce", dlogits, w,
+                          preferred_element_type=jnp.float32)
+        dw = dw + jnp.einsum("bce,bcv->ev", h_c.astype(w.dtype), dlogits,
+                             preferred_element_type=jnp.float32)
+        return ((total + jnp.sum(nll * w_c), dw),
+                (dh_c.astype(hidden.dtype), nll))
+
+    (total, dw), (dh, nll) = jax.lax.scan(
+        body, (jnp.float32(0.0), jnp.zeros((e, v), jnp.float32)),
+        (hc, tc, wc))
+    dh = jnp.pad(_rows(dh, s1), ((0, 0), (0, 1), (0, 0)))
+    nll = _rows(nll, s1)
+    return (total, nll), (dh, dw.astype(head_kernel.dtype), nll)
+
+
+def _weighted_nll_bwd(chunk_size, matmul_dtype, residuals, cotangents):
+    # the per-position loss is handed out undifferentiated: its cotangent
+    # is not read
+    g, _ = cotangents
+    dh, dw, nll = residuals
+    return ((dh.astype(jnp.float32) * g).astype(dh.dtype),
+            (dw.astype(jnp.float32) * g).astype(dw.dtype), None, nll * g)
+
+
+_weighted_nll = jax.custom_vjp(_weighted_nll_alone, nondiff_argnums=(4, 5))
+_weighted_nll.defvjp(_weighted_nll_fwd, _weighted_nll_bwd)
+
+
+def chunked_weighted_nll(hidden: jnp.ndarray, head_kernel: jnp.ndarray,
+                         tokens: jnp.ndarray, weights: jnp.ndarray,
+                         chunk_size: Optional[int] = None,
+                         matmul_dtype: Any = None
+                         ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``(sum(weights * nll), nll)``: the next-token cross-entropy of every
+    position, ``nll`` float32 ``[B, S - 1]``, and its sum under ``weights``
+    (float32, the same shape), in ONE scan over chunks as
+    :func:`chunked_causal_lm_loss` (the same chunk rule, the same three
+    head products a chunk when differentiated, ``dW`` in one float32
+    carry). The sum is differentiated in ``hidden``, ``head_kernel`` AND
+    ``weights`` (whose gradient is ``nll``); ``nll`` itself is handed out
+    with its gradient stopped, for what only reads it. At ``weights =
+    1 / (B * (S - 1))`` the sum is :func:`chunked_causal_lm_loss`. Several
+    hidden streams over one head ride as batch (``hidden`` ``[T * B, S,
+    E]``, ``tokens`` tiled): one scan, one ``dW`` carry."""
+    if chunk_size is None:
+        chunk_size = head_loss_chunk(hidden.shape[0], hidden.shape[1],
+                                     head_kernel.shape[1])
+    total, nll = _weighted_nll(hidden, head_kernel, tokens, weights,
+                               chunk_size, matmul_dtype)
+    return total, jax.lax.stop_gradient(nll)
+
+
+def exit_distribution(gate_logits: jnp.ndarray) -> Tuple[jnp.ndarray,
+                                                         jnp.ndarray]:
+    """``(q, log q)``, float32 ``[T, ...]``, of the gate's logits on the
+    first ``T - 1`` exits ``[T - 1, ...]``: ``lambda_t = sigmoid(logit_t)``,
+    ``lambda_T = 1``, ``q_t = lambda_t * prod_{j<t} (1 - lambda_j)``, the
+    running product kept as a sum of logs (no ``log(0)``); ``sum_t q_t =
+    1``."""
+    z = gate_logits.astype(jnp.float32)
+    log_keep = jax.nn.log_sigmoid(-z)            # log(1 - lambda_t)
+    kept_before = jnp.cumsum(log_keep, axis=0) - log_keep
+    log_q = jnp.concatenate(
+        [jax.nn.log_sigmoid(z) + kept_before,
+         jnp.sum(log_keep, axis=0, keepdims=True)], axis=0)
+    return jnp.exp(log_q), log_q
+
+
+def looped_causal_lm_loss(model: "Transformer", params: Any,
+                          tokens: jnp.ndarray, beta: float,
+                          gate_bias_shift: float = 0.0,
+                          chunk_size: Optional[int] = None) -> jnp.ndarray:
+    """The loss of a looped stack with an exit gate (``loop_steps`` = ``T``
+    passes, ``exit_gate``): the mean over positions of ``sum_t q_t l_t -
+    beta * H(q)``, ``l_t`` the next-token cross-entropy on pass ``t``'s
+    exit through the one head, ``q`` the gate's exit distribution there
+    (:func:`exit_distribution`) and ``H`` its entropy. The ``T`` exits ride
+    :func:`chunked_weighted_nll` as batch: one loss scan and one ``dW``
+    carry a step; the gate learns through the weights. ``gate_bias_shift``
+    is added to every gate logit (a constant beside the learned bias).
+
+    Under a collector (``tracing.collect_counts``) it counts
+    ``loop_passes_total`` (+``T``), ``loop_expected_exit_milli_total`` (the
+    step's mean exit ``sum_t t q_t`` x 1000: 1000 says every token leaves
+    at the first pass, ``T`` x 1000 that the gate never opens),
+    ``loop_exit_entropy_micro_total`` (its mean entropy x 1e6) and
+    ``loss_exit_first_micro_total`` / ``loss_exit_last_micro_total`` (the
+    unweighted mean loss on the first and on the last exit x 1e6: their gap
+    is what the passes buy)."""
+    from torchft_tpu import tracing
+
+    exits, gate_logits = model.apply(params, tokens, return_exits=True)
+    if gate_logits is None:
+        raise ValueError("looped_causal_lm_loss weights the exits by the "
+                         "model's gate: exit_gate=True")
+    t, b, s, e = exits.shape
+    with jax.named_scope("loop_gate"):
+        # the last position has no next token
+        q, log_q = exit_distribution(gate_logits[..., :-1] + gate_bias_shift)
+        entropy = -jnp.sum(q * log_q, axis=0)
+    with jax.named_scope("loop_exits_loss"):
+        weighted, nll = chunked_weighted_nll(
+            exits.reshape(t * b, s, e), head_kernel(params),
+            jnp.tile(tokens, (t, 1)),
+            (q / (b * (s - 1))).reshape(t * b, s - 1), chunk_size)
+    nll = nll.reshape(t, b, s - 1)
+    step = jnp.arange(1, t + 1, dtype=jnp.float32)[:, None, None]
+    # the collector stops the gradient of what it is handed
+    tracing.count_in_program(
+        loop_passes_total=t,
+        loop_expected_exit_milli_total=jnp.mean(
+            jnp.sum(step * q, axis=0)) * 1e3,
+        loop_exit_entropy_micro_total=jnp.mean(entropy) * 1e6,
+        loss_exit_first_micro_total=jnp.mean(nll[0]) * 1e6,
+        loss_exit_last_micro_total=jnp.mean(nll[-1]) * 1e6)
+    return weighted - beta * jnp.mean(entropy)
 
 
 def mtp_causal_lm_loss(model: "Transformer", params: Any,
