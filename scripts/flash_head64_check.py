@@ -12,11 +12,21 @@ them in turn.
 ``--root DIR`` imports ``torchft_tpu`` from another checkout (a ``git
 archive`` copy of the parent commit under ``.chip_archive/``), so one call
 times two trees on one chip. Prints one JSON line a shape (and appends it
-to ``--out``); exits 1 where a relative error passes ``--tol``."""
+to ``--out``); exits 1 where a relative error passes ``--tol``. Given more
+than once (``--root .chip_archive/parent --root . --root . --root
+.chip_archive/parent``), each root runs in a process of its own, one after
+the other (this one stays off JAX, so each child has the chip), and the
+lines end in a table, a row a shape and root: forward, fused backward and
+split backward in ms.
+
+    chiprun --chips 1 -- python3 scripts/flash_head64_check.py \
+        --shape mla192 --root .chip_archive/parent --root .
+"""
 
 import argparse
 import json
 import os
+import subprocess
 import sys
 import time
 
@@ -107,16 +117,47 @@ def check(shape, tol):
     return res
 
 
+def each_root(roots, argv) -> int:
+    """One child a root, in turn; their lines as a table at the end."""
+    rows, rc = [], 0
+    for root in roots:
+        child = subprocess.run(
+            [sys.executable, os.path.abspath(__file__), "--root", root]
+            + argv, stdout=subprocess.PIPE, text=True)
+        sys.stdout.write(child.stdout)
+        sys.stdout.flush()
+        rc = rc or child.returncode
+        rows += [json.loads(line) for line in child.stdout.splitlines()
+                 if line.startswith("{")]
+    print("| shape | root | forward ms | fused backward ms "
+          "| split backward ms | worst rel |\n"
+          "| --- | --- | --- | --- | --- | --- |")
+    for r in sorted(rows, key=lambda r: r["name"]):
+        print(f"| `{r['name']}` | `{r['root']}` | {r['fwd_ms']:.2f} "
+              f"| {r['fused']['bwd_ms']:.2f} | {r['split']['bwd_ms']:.2f} "
+              f"| {r['worst']:.4f} |")
+    return rc
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--shape", default="gqa64",
                     choices=sorted(SHAPES) + ["all"])
     ap.add_argument("--seq", type=int, default=None,
                     help="tokens, where not the shape's own (a rehearsal)")
-    ap.add_argument("--root", default=HERE)
+    ap.add_argument("--root", action="append", default=None,
+                    help="a checkout to import torchft_tpu from; several: "
+                    "each in a process of its own, then a table")
     ap.add_argument("--out", default=None)
     ap.add_argument("--tol", type=float, default=2e-2)
     args = ap.parse_args()
+    roots = args.root or [HERE]
+    if len(roots) > 1:
+        argv = ["--shape", args.shape, "--tol", str(args.tol)]
+        argv += ["--seq", str(args.seq)] if args.seq else []
+        argv += ["--out", args.out] if args.out else []
+        return each_root(roots, argv)
+    args.root = roots[0]
     sys.path.insert(0, os.path.abspath(args.root))
     ok = True
     for name in (sorted(SHAPES) if args.shape == "all" else [args.shape]):

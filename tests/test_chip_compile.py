@@ -167,8 +167,9 @@ def test_latent_flash_compiles_at_two_head_sizes(one_chip, monkeypatch,
                                                  fused_bwd):
     """[1, 8192, 32, 192] queries and keys against [1, 8192, 32, 128]
     values, bf16 causal (the latent attention of ``joyai-llm-flash`` at its
-    cell's length): every kernel lowers with the narrower value blocks and
-    inside scoped VMEM, and carries its own ``_mla`` name."""
+    cell's length): every kernel lowers with the narrower value blocks, at
+    1024-token tiles inside the two lane tiles' scoped VMEM it asks for
+    (PR 57), and carries its own ``_mla`` name."""
     monkeypatch.setenv("TORCHFT_FLASH_FUSED_BWD", fused_bwd)
     qk = jax.ShapeDtypeStruct((1, 8192, 32, 192), jnp.bfloat16,
                               sharding=one_chip)
@@ -193,8 +194,10 @@ def test_flash_compiles_at_head_256_on_2_kv_heads(one_chip, monkeypatch,
                                                   fused_bwd):
     """[1, 8192, 16, 256] queries on [1, 8192, 2, 256] keys and values, bf16
     causal (the full-attention layers of ``qwen3-next-80b-a3b`` at its
-    cell's length): 512-token tiles inside scoped VMEM, the key/value heads
-    shared through the index maps and not repeated in memory."""
+    cell's length): 1024-token tiles inside the two lane tiles' scoped
+    VMEM it asks for (512-token tiles inside the default before PR 57), the
+    key/value heads shared through the index maps and not repeated in
+    memory."""
     monkeypatch.setenv("TORCHFT_FLASH_FUSED_BWD", fused_bwd)
     q = jax.ShapeDtypeStruct((1, 8192, 16, 256), jnp.bfloat16,
                              sharding=one_chip)
@@ -232,8 +235,8 @@ def test_fused_backward_keeps_dq_in_vmem_at_the_cells_shapes(one_chip,
     """The forward (K and V clamped to the visible band) and the fused
     backward (one (batch, head)'s dq held in VMEM for its whole sweep) at
     every shape a cell runs: two Mosaic kernels, the backward asking for
-    the default scoped VMEM plus the resident dq and no more, far under the
-    chip's 128 MiB."""
+    the scoped VMEM of its head's lane tiles (the default a tile) plus the
+    resident dq and no more, far under the chip's 128 MiB."""
     import importlib
     import re
 
@@ -256,10 +259,11 @@ def test_fused_backward_keeps_dq_in_vmem_at_the_cells_shapes(one_chip,
     asked = [int(n) for n in re.findall(
         r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', text)]
     want = fa._fused_vmem_limit(s, d, 2)
-    assert want == fa._SCOPED_VMEM_BYTES + fa._dq_resident_bytes(s, d, 2)
+    tiles = fa._SCOPED_VMEM_BYTES * fa._lane_tiles(d)
+    assert want == tiles + fa._dq_resident_bytes(s, d, 2)
     assert max(asked) == want < VMEM_BYTES // 2
-    # the forward, and XLA's own fusions, keep to the default
-    assert set(asked) <= {fa._SCOPED_VMEM_BYTES, want}
+    # XLA's own fusions keep to the default, the forward to its tiles'
+    assert set(asked) <= {fa._SCOPED_VMEM_BYTES, tiles, want}
 
 
 def _compiled_delta_rule(monkeypatch):

@@ -213,7 +213,7 @@ KEYS = ("flash_grid_steps_traced_total", "flash_skipped_steps_traced_total",
 #            q heads, kv heads, d_qk, d_v, window: skipped, grid a (b, h)
 TRACED = {"causal": (32, 4, 128, 128, None, 28, 64),
           "window": (32, 4, 128, 128, 2048, 43, 64),
-          "latent": (32, 32, 192, 128, None, 120, 256)}
+          "latent": (32, 32, 192, 128, None, 28, 64)}
 
 
 def _counters():
@@ -227,8 +227,10 @@ def test_counters_are_counted_when_a_kernel_is_traced(case, fused,
                                                      monkeypatch):
     """The 8k calls of the cells, traced and never run: forward and
     backward count their grid steps, batch x heads included, and those a
-    static mask skips (28/64 causal, 43/64 under a window of 2,048, 120/256
-    at the latent kernels' 512-token tiles), and which backward was taken."""
+    static mask skips (28/64 causal, 43/64 under a window of 2,048; the
+    latent kernels' as the causal ones' since PR 57 gave a head over 128
+    the 1024-token tiles too: 120/256 before), and which backward was
+    taken."""
     h, h_kv, d, d_v, window, skipped, grid = TRACED[case]
     monkeypatch.setenv("TORCHFT_FLASH_FUSED_BWD", fused)
     q = jax.ShapeDtypeStruct((1, 8192, h, d), jnp.bfloat16)

@@ -4,7 +4,12 @@ forward and gradients against masked softmax at 192/128 and 96/64, with
 shared key/value heads and with more keys than queries, through the split
 backward and through the fused one (four and eight key blocks); one head size computes what the parent
 commit computed, bit for bit; the kernels' names; the plain attention, the
-sharded wrapper and the padding path take the two sizes."""
+sharded wrapper and the padding path take the two sizes.
+
+Since PR 57 a query/key head over one 128-lane tile (192, 256) keeps the
+1024-token tiles of every other head and asks for a lane tile's scoped
+VMEM more instead: the tiles and the limits each traced kernel asks for,
+and heads of one lane tile traced to the parent's jaxpr."""
 
 import importlib
 import json
@@ -17,6 +22,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh
 
+from _flash_jaxpr_golden import ONE_LANE_TILE, jaxpr_hash
 from torchft_tpu.models.transformer import plain_attention
 from torchft_tpu.ops import flash_attention, sharded_flash_attention
 
@@ -113,6 +119,66 @@ def test_fused_backward_at_two_head_sizes(case, monkeypatch):
     for a, b in zip((dq, dk, dv), vjp_ref(g)):
         assert a.shape == b.shape
         np.testing.assert_allclose(a, b, atol=1e-5)
+
+
+MIB = 1 << 20
+#        d_qk d_v  heads kv: scoped VMEM asked, MiB (None: Mosaic's default)
+TILES = {"192_128": (192, 128, 32, 32, 32),
+         "256": (256, 256, 16, 2, 32),
+         "160_128": (160, 128, 4, 4, 32),
+         "320": (320, 320, 2, 2, 48),
+         "128": (128, 128, 32, 4, None),
+         "96_64": (96, 64, 8, 8, None)}
+
+
+@pytest.mark.parametrize("fused", ["1", "0"], ids=["fused", "split"])
+@pytest.mark.parametrize("case", list(TILES), ids=list(TILES))
+def test_every_head_size_keeps_1024_token_tiles(case, fused, monkeypatch):
+    """The 8k calls traced as the chip compiles them, never run: every
+    kernel takes 1024-token tiles whatever the head (a head over 128 took
+    512 before PR 57, four times the grid steps), and a query/key head of
+    ``n`` lane tiles asks for ``n`` times Mosaic's default scoped VMEM,
+    the fused backward for its resident dq beside that; one lane tile asks
+    for nothing, as before."""
+    d_qk, d_v, h, h_kv, scoped = TILES[case]
+    monkeypatch.setenv("TORCHFT_FLASH_FUSED_BWD", fused)
+    real, calls = fa.pl.pallas_call, []
+
+    def recording(*a, **kw):
+        calls.append((kw["grid"],
+                      [spec.block_shape for spec in kw["in_specs"][:3]],
+                      getattr(kw.get("compiler_params"), "vmem_limit_bytes",
+                              None)))
+        return real(*a, **kw)
+
+    monkeypatch.setattr(fa.pl, "pallas_call", recording)
+    q = jax.ShapeDtypeStruct((1, 8192, h, d_qk), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, 8192, h_kv, d_qk), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((1, 8192, h_kv, d_v), jnp.bfloat16)
+    jax.eval_shape(jax.grad(
+        lambda q, k, v: flash_attention(
+            q, k, v, True, interpret=False).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)), q, k, v)
+    assert len(calls) == (2 if fused == "1" else 3)  # forward first
+    for i, (grid, blocks, limit) in enumerate(calls):
+        assert grid == (h, 8, 8)
+        assert blocks == [(1, 1024, d_qk), (1, 1024, d_qk), (1, 1024, d_v)]
+        if fused == "1" and i == 1:
+            assert limit == ((scoped or 16) * MIB
+                             + fa._dq_resident_bytes(8192, d_qk, 2))
+        else:
+            assert limit == (scoped * MIB if scoped else None)
+    if scoped:      # float32 operands: tiles twice as wide
+        assert fa._tile_vmem(d_qk, 4).vmem_limit_bytes == 2 * scoped * MIB
+
+
+@pytest.mark.parametrize("case", list(ONE_LANE_TILE), ids=list(ONE_LANE_TILE))
+def test_heads_of_one_lane_tile_trace_to_the_parents_jaxpr(case):
+    """Forward and gradients at a head of at most 128 trace to the text
+    PR 57's parent traced (``tests/_flash_jaxpr_golden.py``): what a wider
+    head asks for does not reach them."""
+    with open(os.path.join(HERE, "golden_flash_jaxpr_pr57.json")) as f:
+        assert jaxpr_hash(case) == json.load(f)[case]
 
 
 def _digest(tree):

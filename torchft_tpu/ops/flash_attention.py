@@ -41,9 +41,15 @@ Three structural choices shape the kernels (timings: a TPU v5e, jax
    ``fused_bwd_check`` (run by ``chip_smoke.py``);
    TORCHFT_FLASH_FUSED_BWD=0 falls back.
 
-Tiles default to the largest power of two <= 1024 dividing the sequence
-(512 at a head over 128). Head_dim matters more than tiles: d=128 fills
-the MXU contraction, d=64 halves it, d=192 pads to 256. An exp2-domain
+Tiles default to the largest power of two <= 1024 dividing the sequence,
+at every head size: a head over 128 lanes asks for more scoped VMEM
+(:func:`_tile_vmem`), not for smaller tiles. At 512-token tiles the
+192/128 forward took 11.66 ms for 32 heads x 8,192 tokens where it takes
+7.85 at 1024 (half the key steps a query block, so half the rescales of
+its accumulator, and a quarter of the grid steps; PERF.md, Findings,
+PR 57). Head_dim matters too: d=128 fills the MXU contraction, d=64 halves
+it, d=192 pads to 256 (and runs no faster when the program pads it to 256
+lanes itself, in HBM or in VMEM: same place). An exp2-domain
 rewrite (log2(e) folded into the logit scale) was tried and reverted:
 Mosaic already lowers jnp.exp to the hardware exp2 with the multiply
 fused.
@@ -341,6 +347,31 @@ def _auto_block(seq: int, cap: int = 1024) -> int:
     return seq if seq <= cap else 128
 
 
+# Mosaic's own default scoped VMEM limit: what a kernel's tiles and score
+# buffers fit at bf16 operands, 1024-token tiles and a head of one
+# 128-lane tile.
+_SCOPED_VMEM_BYTES = 16 << 20
+
+
+def _lane_tiles(d: int) -> int:
+    return -(-d // _LANES)
+
+
+def _tile_vmem(d: int, itemsize: int) -> Optional[pltpu.CompilerParams]:
+    """``compiler_params`` of the forward and of the split backward kernels:
+    the [bq, bk] f32 score / probability buffers do not grow with the head,
+    the [b*, d] operand tiles and accumulators do (a 1024-token tile at
+    d=192 asked for 17.45 MB of the default's 16), so a query/key head of
+    ``n`` 128-lane tiles asks for ``n`` times the default and keeps the
+    1024-token tiles. ``None`` at one lane tile: Mosaic's default, and
+    such calls trace as they always did."""
+    if _lane_tiles(d) == 1:
+        return None
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=_SCOPED_VMEM_BYTES * _lane_tiles(d)
+        * max(itemsize // 2, 1))
+
+
 def _to_bh(x: jnp.ndarray) -> jnp.ndarray:
     """[B, S, H, D] -> [B*H, S, D], whatever the head size."""
     b, s, h, d = x.shape
@@ -361,12 +392,8 @@ def _flash_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         f"{v.shape[2]}")
     rep = h // h_kv
     scale = d ** -0.5
-    # Wider heads need smaller tiles: the [bq, bk] f32 score/prob buffers
-    # plus the [b*, d] operand tiles must fit scoped VMEM (16 MB); at
-    # d > 128 a 1024-tile overflows it (observed: d=192 at 17.45M).
-    cap = 1024 if d <= 128 else 512
-    block_q = block_q or _auto_block(s, cap=cap)
-    block_k = block_k or _auto_block(k.shape[1], cap=cap)
+    block_q = block_q or _auto_block(s)
+    block_k = block_k or _auto_block(k.shape[1])
     dynamic_shift = shift is not None
     _check_window(window, causal, dynamic_shift)
 
@@ -431,6 +458,7 @@ def _flash_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.VMEM((block_q, 1), jnp.float32),   # running sum
             pltpu.VMEM((block_q, d_v), jnp.float32),  # output accumulator
         ],
+        compiler_params=_tile_vmem(d, q.dtype.itemsize),
         interpret=interpret,
         name=_kernel_name("flash_fwd", window, d_v != d),
     )(*inputs)
@@ -642,10 +670,6 @@ def _bwd_fused_kernel(*refs, causal: bool, scale: float, nqb: int,
 # bf16, the same at a head of 64, 8 + 2 x 4 at 192 and at 256) of the
 # chip's 128 MiB. Over this many accumulator bytes the split kernels run.
 _DQ_RESIDENT_BYTES = 16 << 20
-# What a kernel may use beside that: Mosaic's own default scoped limit,
-# which the kernels' tiles and score buffers are sized to at bf16 operands
-# (see _flash_fwd).
-_SCOPED_VMEM_BYTES = 16 << 20
 
 
 def _dq_resident_bytes(s: int, d: int, itemsize: int = 0) -> int:
@@ -656,9 +680,9 @@ def _dq_resident_bytes(s: int, d: int, itemsize: int = 0) -> int:
 
 def _fused_vmem_limit(s: int, d: int, itemsize: int) -> int:
     """``vmem_limit_bytes`` of the fused backward: the scoped default for
-    its tiles (as wide as the operands: f32 takes twice bf16's) and the
-    resident dq."""
-    return (_SCOPED_VMEM_BYTES * max(itemsize // 2, 1)
+    its tiles (as wide as the operands: f32 takes twice bf16's; a lane
+    tile of the head, see :func:`_tile_vmem`) and the resident dq."""
+    return (_SCOPED_VMEM_BYTES * max(itemsize // 2, 1) * _lane_tiles(d)
             + _dq_resident_bytes(s, d, itemsize))
 
 
@@ -678,9 +702,8 @@ def _flash_bwd(q, k, v, out, lse, g, causal: bool, block_q: Optional[int],
     qh, kh, vh = _to_bh(q), _to_bh(k), _to_bh(v)
     doh, oh = _to_bh(g), _to_bh(out)
     sk = kh.shape[1]
-    cap = 1024 if d <= 128 else 512  # see _flash_fwd's VMEM note
-    block_q = min(block_q or _auto_block(s, cap=cap), s)
-    block_k = min(block_k or _auto_block(sk, cap=cap), sk)
+    block_q = min(block_q or _auto_block(s), s)
+    block_k = min(block_k or _auto_block(sk), sk)
     nqb = s // block_q
     nkb = sk // block_k
     offset = sk - s
@@ -811,6 +834,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal: bool, block_q: Optional[int],
         in_specs=in_specs,
         out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        compiler_params=_tile_vmem(d, q.dtype.itemsize),
         interpret=interpret,
         name=_kernel_name("flash_bwd_dq", window, latent),
     )(*inputs)
@@ -835,6 +859,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal: bool, block_q: Optional[int],
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d_v), jnp.float32),
         ],
+        compiler_params=_tile_vmem(d, q.dtype.itemsize),
         interpret=interpret,
         name=_kernel_name("flash_bwd_dkdv", window, latent),
     )(*inputs)
