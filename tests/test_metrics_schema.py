@@ -34,6 +34,14 @@ DOCUMENTED_KEYS = frozenset([
     "quorum_epoch_last", "quorum_ms_p50", "quorum_ms_p95",
     "quorum_ms_max", "lighthouse_redials",
     "reconfigure_count", "reconfigure_ms_total",
+    # a recovery, second by second (PR 58): rounds that changed the
+    # quorum, the healed tree's adoption, a Manager's birth to its first
+    # commit, the dispatches that traced their program, and what the
+    # process spent building programs
+    "quorum_changed_count", "quorum_changed_ms_total",
+    "heal_adopt_ms_total", "join_first_commit_ms",
+    "dispatch_traced_ms_total",
+    "program_build_ms_total", "program_cache_read_ms_total",
     # healing
     "heal_count", "heal_ms_total", "heal_bytes_total",
     "heal_bytes_resumed_total", "heal_donor_failovers",
@@ -186,11 +194,13 @@ REQUIRED_TRACE_TAGS = frozenset(tracing.CONTEXT_TAGS)
 REQUIRED_SPAN_FIELDS = frozenset(["thread", "thread_id", "id", "parent"])
 
 # The documented stages (docs/design/observability.md's taxonomy): one
-# Perfetto track each, in protocol order. Append, never drop.
+# Perfetto track each, in protocol order. Add, never drop.
 DOCUMENTED_STAGES = (
     "step_begin", "dispatch", "wait_quorum",
-    "quorum", "heal", "heal_stripe", "fetch_dispatch", "fetch_wait",
-    "ring", "hier_intra", "hier_leader", "put", "exchange_wait",
+    "quorum", "reconfigure", "heal", "heal_stripe", "heal_adopt",
+    "fetch_dispatch", "fetch_wait",
+    "ring", "ring_preamble", "hier_intra", "hier_leader", "put",
+    "exchange_wait",
     "overlap_drain", "drain", "pre_vote", "vote", "post_vote",
     "publish_status", "state_digest", "update", "ckpt_save", "publish",
     "heal_manifest", "heal_recv", "heal_verify", "heal_place",

@@ -167,6 +167,123 @@ class TestManagerSpans:
             m.shutdown()
 
 
+class TestRecoveryPhases:
+    """The quorum thread's two phases of a membership change, on a mocked
+    control plane (tests/test_recovery_timeline.py has the real one)."""
+
+    def test_a_changed_round_is_tagged_and_counted_apart(self):
+        m = make_manager()
+        try:
+            for _ in range(3):
+                m.step()
+                assert m.should_commit()
+            rounds = [s for s in m.tracer().spans()
+                      if s["stage"] == "quorum"]
+            # The first round hands out a quorum id the Manager did not
+            # hold; the mocked plane then repeats it.
+            assert [s["changed"] for s in rounds] == [True, False, False]
+            assert all(s["world"] == 2 and s["heal"] is False
+                       for s in rounds)
+            mx = m.metrics()
+            assert mx["quorum_changed_count"] == 1
+            assert mx["quorum_changed_ms_total"] == pytest.approx(
+                rounds[0]["dur_ns"] / 1e6, rel=1e-12)
+            assert mx["quorum_ms_total"] == pytest.approx(
+                sum(s["dur_ns"] for s in rounds) / 1e6, rel=1e-12)
+        finally:
+            m.shutdown()
+
+    def test_reconfigure_is_a_span_after_its_round_on_its_thread(self):
+        m = make_manager()
+        try:
+            m.step()
+            assert m.should_commit()
+            spans = m.tracer().spans()
+            (reconf,) = [s for s in spans if s["stage"] == "reconfigure"]
+            (round_,) = [s for s in spans if s["stage"] == "quorum"]
+            assert reconf["thread_id"] == round_["thread_id"]
+            assert reconf["t0_ns"] >= round_["t0_ns"] + round_["dur_ns"]
+            assert (reconf["world"], reconf["rank"], reconf["recovery"],
+                    reconf["quorum_id"]) == (2, 0, False, 1)
+            assert m.metrics()["reconfigure_ms_total"] == pytest.approx(
+                reconf["dur_ns"] / 1e6, rel=1e-12)
+        finally:
+            m.shutdown()
+
+    def test_first_commit_is_noted_once(self):
+        client = mockplane.mock_client()
+        client.should_commit.side_effect = [False, True, True]
+        m = make_manager(client)
+        try:
+            seen = []
+            for _ in range(3):
+                m.step()
+                m.should_commit()
+                seen.append(m.metrics()["join_first_commit_ms"])
+            assert seen[0] == 0.0 and seen[1] > 0.0 and seen[2] == seen[1]
+            firsts = [e for e in m.history()
+                      if e["event"] == "first_commit"]
+            assert len(firsts) == 1 and firsts[0]["step"] == 1
+            assert firsts[0]["ms"] == round(seen[1], 1)
+        finally:
+            m.shutdown()
+
+
+class TestProgramBuilds:
+    """``program_build_ms_total`` / ``program_cache_read_ms_total``: what
+    the process spent building programs, from jax's own duration events."""
+
+    def test_a_first_call_builds_and_a_second_does_not(self):
+        import jax
+        import jax.numpy as jnp
+
+        m = make_manager()
+        try:
+            x = jnp.arange(7.0)
+            before = m.metrics()["program_build_ms_total"]
+            fn = jax.jit(lambda v: v * 3.0 + 1.0)
+            fn(x).block_until_ready()
+            first = m.metrics()["program_build_ms_total"]
+            assert first > before
+            fn(x).block_until_ready()
+            assert m.metrics()["program_build_ms_total"] == first
+        finally:
+            m.shutdown()
+
+    def test_one_listener_however_many_managers(self):
+        from jax._src import monitoring
+
+        managers = [make_manager() for _ in range(3)]
+        try:
+            mine = [fn for fn in monitoring.get_event_duration_listeners()
+                    if fn is tracing._on_build_event]
+            assert len(mine) == 1
+        finally:
+            for m in managers:
+                m.shutdown()
+
+    def test_a_cache_read_counts_apart_and_other_events_nowhere(self):
+        import jax.monitoring
+
+        m = make_manager()
+        try:
+            before = m.metrics()
+            jax.monitoring.record_event_duration_secs(
+                "/jax/compilation_cache/cache_retrieval_time_sec", 0.25)
+            # A trace nests in its parent's duration: not summed (the
+            # dispatch span's stamps time the step's).
+            for other in ("/jax/core/compile/jaxpr_trace_duration",
+                          "/jax/some/other_duration"):
+                jax.monitoring.record_event_duration_secs(other, 9.0)
+            after = m.metrics()
+            assert after["program_cache_read_ms_total"] == pytest.approx(
+                before["program_cache_read_ms_total"] + 250.0)
+            assert after["program_build_ms_total"] == \
+                before["program_build_ms_total"]
+        finally:
+            m.shutdown()
+
+
 # ------------------------------------------------------- flight recorder
 
 

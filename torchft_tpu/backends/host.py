@@ -192,10 +192,11 @@ class _Ring:
     """
 
     def __init__(self, next_sock: socket.socket, prev_sock: socket.socket,
-                 listener: socket.socket):
+                 listener: socket.socket, lane: int = 0):
         self.next_sock = next_sock
         self.prev_sock = prev_sock
         self.listener = listener
+        self.lane = lane    # which of the epoch's rings this is
         self._send_q: "queue.Queue[Optional[Tuple[Any, Future]]]" = \
             queue.Queue()
         self._sender = threading.Thread(target=self._send_loop, daemon=True,
@@ -499,8 +500,9 @@ class HostCommunicator(Communicator):
             # plane — every ring collective byte — is injectable.
             self._rings = [
                 _Ring(chaos.wrap_socket(next_sock, "ring"),
-                      chaos.wrap_socket(prev_sock, "ring"), listener)
-                for next_sock, prev_sock, listener in socks]
+                      chaos.wrap_socket(prev_sock, "ring"), listener, lane)
+                for lane, (next_sock, prev_sock, listener)
+                in enumerate(socks)]
             self._hier = topo
         logger.info("host communicator configured: rank=%d world=%d "
                     "topology=%s lanes=%d (%s)", rank, world_size,
@@ -1334,27 +1336,32 @@ class HostCommunicator(Communicator):
         weights = [0] * n
         weights[rank] = weight
         payload: Any = struct.pack("<qqq", _WIRE_MAGIC, key, weight)
-        for step in range(n - 1):
-            fut = ring.send_async(payload)
-            got = bytes(_recv_exact(ring.prev_sock, 24))
-            fut.result()
-            magic, gkey, gw = struct.unpack("<qqq", got)
-            if magic != _WIRE_MAGIC or gkey != key:
-                raise skew(gkey)
-            if (gw < 0) != (weight < 0):
-                raise CommunicatorError(
-                    "wire weight skew: this op mixes weighted and "
-                    f"unweighted ranks (mine {weight}, a peer's {gw}) "
-                    "— degraded mode (weighted folding) must be "
-                    "enabled on EVERY group or none; aborting the "
-                    "collective before folding garbage")
-            if weight < 0:
-                # Unweighted op: one pairwise hop proved format + mode
-                # agreement (transitively, around the cycle) — the
-                # classic preamble cost, no weight collection needed.
-                return None
-            weights[(rank - step - 1) % n] = gw
-            payload = got  # forward the received record along the ring
+        # The op's first receive: a rank whose peer has not reached this
+        # op waits here, so the span (a child of the op's ``ring`` span)
+        # is the peers' lateness and not the wire.
+        with maybe_span(getattr(self, "tracer", None), "ring_preamble",
+                        lane=ring.lane):
+            for step in range(n - 1):
+                fut = ring.send_async(payload)
+                got = bytes(_recv_exact(ring.prev_sock, 24))
+                fut.result()
+                magic, gkey, gw = struct.unpack("<qqq", got)
+                if magic != _WIRE_MAGIC or gkey != key:
+                    raise skew(gkey)
+                if (gw < 0) != (weight < 0):
+                    raise CommunicatorError(
+                        "wire weight skew: this op mixes weighted and "
+                        f"unweighted ranks (mine {weight}, a peer's {gw}) "
+                        "— degraded mode (weighted folding) must be "
+                        "enabled on EVERY group or none; aborting the "
+                        "collective before folding garbage")
+                if weight < 0:
+                    # Unweighted op: one pairwise hop proved format + mode
+                    # agreement (transitively, around the cycle) — the
+                    # classic preamble cost, no weight collection needed.
+                    return None
+                weights[(rank - step - 1) % n] = gw
+                payload = got  # forward the received record along the ring
         return weights if weight >= 0 else None
 
     def _do_allreduce_wire(self, ring: Optional[_Ring],

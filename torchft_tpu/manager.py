@@ -127,7 +127,14 @@ _METRICS: Dict[str, float] = {
     "quorum_fast_path_hits": 0,
     "quorum_slow_path_rounds": 0,
     "quorum_epoch_last": 0,
+    # Rounds that returned a quorum id other than the one held, and
+    # their wall: on a survivor the wait for the lighthouse to cut the
+    # shrunken quorum, on a replacement the wait to be let in.
+    "quorum_changed_count": 0, "quorum_changed_ms_total": 0.0,
     "reconfigure_count": 0, "reconfigure_ms_total": 0.0,
+    # Gauge, set once in a Manager's life: from the start of __init__
+    # to the end of its first should_commit that returned true.
+    "join_first_commit_ms": 0.0,
     "heal_count": 0,
     "heal_ms_total": 0.0, "heal_bytes_total": 0.0,
     # The heal transfer's stages, busy ms (docs/design/
@@ -141,6 +148,12 @@ _METRICS: Dict[str, float] = {
     "heal_verify_ms_total": 0.0, "heal_place_ms_total": 0.0,
     "heal_serve_fetch_ms_total": 0.0,
     "heal_serve_send_ms_total": 0.0,
+    # The user's load_state_dict of the healed tree (step thread).
+    "heal_adopt_ms_total": 0.0,
+    # Wall of the trainer's dispatch spans whose call traced (and
+    # lowered, compiled or read from the cache) its program: a new
+    # Manager's first steps, a shape seen for the first time.
+    "dispatch_traced_ms_total": 0.0,
     # Resilient-heal observability: bytes re-sent by resumed
     # attempts (strictly less than the payload when resume
     # works), donor failovers, leaves caught by digest
@@ -546,7 +559,10 @@ class Manager:
         ram_demote_dir: Optional[str] = None,
         _manager_client: Optional[ManagerClient] = None,
     ) -> None:
+        # Where join_first_commit_ms counts from; None once it is set.
+        self._born_ns: Optional[int] = time.monotonic_ns()
         self._comm = comm
+        tracing_mod.watch_program_builds()
         # Per-step span tracer (docs/design/observability.md): created
         # first so every later init step can already be spanned; the
         # flight recorder and the export endpoints attach once the
@@ -1037,23 +1053,27 @@ class Manager:
             raise
 
     def _async_quorum_inner(self) -> None:
-        t0 = time.perf_counter()
-        with self._tracer.span("quorum") as sp:
+        with self._tracer.timed("quorum") as sp:
             q = self._client.quorum(
                 rank=self._rank,
                 step=self._step,
                 checkpoint_server_addr=self._ckpt_server.address(),
                 timeout_ms=self._quorum_timeout_ms,
             )
-            sp.set(fast=bool(getattr(q, "fast_path", False) is True),
-                   quorum_id=q.quorum_id)
-        quorum_ms = (time.perf_counter() - t0) * 1e3
-        # getattr: duck-typed/mocked clients in tests predate the
-        # fast_path/epoch fields.
-        fast = bool(getattr(q, "fast_path", False) is True)
+            # getattr: duck-typed/mocked clients in tests predate the
+            # fast_path/epoch fields.
+            fast = bool(getattr(q, "fast_path", False) is True)
+            # A round that changed the quorum waited for the lighthouse
+            # to cut it; _quorum_id is this thread's own.
+            changed = q.quorum_id != self._quorum_id
+            sp.set(fast=fast, quorum_id=q.quorum_id, changed=changed,
+                   world=q.replica_world_size, heal=bool(q.heal))
+        quorum_ms = sp.dur_ns / 1e6
         self._record(quorum_count=1, quorum_ms_total=quorum_ms,
                      quorum_fast_path_hits=1 if fast else 0,
-                     quorum_slow_path_rounds=0 if fast else 1)
+                     quorum_slow_path_rounds=0 if fast else 1,
+                     quorum_changed_count=1 if changed else 0,
+                     quorum_changed_ms_total=quorum_ms if changed else 0.0)
         with self._metrics_lock:
             self._metrics["quorum_ms_last"] = quorum_ms
             self._quorum_latency.add(quorum_ms)
@@ -1204,10 +1224,15 @@ class Manager:
                        f"wire_dtype={wire_fp};"
                        f"degraded={int(self._share.degraded)};"
                        f"payload=wire-v6")
-            reconf_t0 = time.perf_counter()
-            self._comm.configure(
-                store_prefixed, q.replica_rank, q.replica_world_size
-            )
+            # The communicator's rendezvous with every member of the
+            # new quorum; its stamps are reconfigure_ms_total's.
+            with self._tracer.timed(
+                    "reconfigure", world=q.replica_world_size,
+                    rank=q.replica_rank, recovery=recovery,
+                    quorum_id=q.quorum_id) as reconf:
+                self._comm.configure(
+                    store_prefixed, q.replica_rank, q.replica_world_size
+                )
             # Manager-side join-coalescing observability
             # (docs/design/churn.md): a membership reconfigure that grew
             # the world by K>1 admitted K joiners as ONE delta (the
@@ -1231,8 +1256,8 @@ class Manager:
             # (peers not there yet) must leave the poison set so the next
             # round tries again.
             self._comm_poisoned = False
-            self._record(reconfigure_count=1, reconfigure_ms_total=(
-                time.perf_counter() - reconf_t0) * 1e3)
+            self._record(reconfigure_count=1,
+                         reconfigure_ms_total=reconf.dur_ns / 1e6)
             self._log_event(
                 event="reconfigure", step=self._step,
                 quorum_id=q.quorum_id, rank=q.replica_rank,
@@ -1608,8 +1633,10 @@ class Manager:
     def _apply_pending_state_dict(self) -> None:
         assert self._pending_state_dict is not None, "no staged state"
         logger.info("%s applying healed user state", self._replica_id)
-        self._user_load_state_dict(self._pending_state_dict["user"])
+        with self._tracer.timed("heal_adopt") as adopt:
+            self._user_load_state_dict(self._pending_state_dict["user"])
         self._pending_state_dict = None
+        self._record(heal_adopt_ms_total=adopt.dur_ns / 1e6)
 
     def _heal_progress(self, committed: int, payload: int) -> None:
         """Per-verified-leaf progress gauge of the current heal transfer
@@ -1984,6 +2011,12 @@ class Manager:
         self._record(update_count=1, update_ms_total=ms,
                      shard_state_resets=resets)
         self._gauge(shard_state_bytes=float(shard_state_bytes))
+
+    def record_traced_dispatch(self, ms: float) -> None:
+        """Trainer-side (:class:`~torchft_tpu.parallel.FTTrainer`): the
+        wall of a ``dispatch`` span tagged ``traced=True``, from the
+        span's own stamps."""
+        self._record(dispatch_traced_ms_total=ms)
 
     def _join_quorum(self) -> None:
         """The exchange's own join of this step's quorum round, on the
@@ -2467,7 +2500,25 @@ class Manager:
         # manager.py:453, checkpointing.py:123-144).
         self._ckpt_server.disallow_checkpoint()
         self._should_step = decision
+        if decision and self._born_ns is not None:
+            self._note_first_commit(self._born_ns)
+            self._born_ns = None
         return decision
+
+    def _note_first_commit(self, born_ns: int) -> None:
+        """Once in a Manager's life, at the end of its first committed
+        step: how long joining took (``join_first_commit_ms``), and one
+        history line with the phases' totals at that moment, so a rejoin
+        reads as one event."""
+        ms = (time.monotonic_ns() - born_ns) / 1e6
+        self._gauge(join_first_commit_ms=ms)
+        with self._metrics_lock:
+            phases = {
+                f"{phase}_ms": round(self._metrics[f"{phase}_ms_total"], 1)
+                for phase in ("quorum_changed", "reconfigure", "heal",
+                              "heal_adopt")}
+        self._log_event(event="first_commit", step=self._step,
+                        ms=round(ms, 1), **phases)
 
     # ---------------------------------------------------------------- errors
 
@@ -2753,6 +2804,8 @@ class Manager:
         # (no wait here: a program still running is in the next
         # snapshot); process-wide, absent until a program counted one.
         out.update(tracing_mod.program_counters())
+        # What the process spent building programs (the host's clocks).
+        out.update(tracing_mod.program_build_ms())
         # Bytes that actually crossed the TCP ring, counted by the
         # backend at its send sites (halved vs allreduce_wire_bytes_total
         # under bf16 wire at world 2 — the per-leg observability the

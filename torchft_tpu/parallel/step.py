@@ -379,8 +379,11 @@ class FTTrainer:
         with self._tracer.timed("dispatch", after=after, program=program,
                                 speculative=speculative) as span:
             out = fn(*args)
-            if size is not None and size() > before:
+            traced = size is not None and size() > before
+            if traced:
                 span.set(traced=True)
+        if traced:
+            self.manager.record_traced_dispatch(span.dur_ns / 1e6)
         # The counts of the programs that finished before this one was
         # enqueued are read now, while the device is busy with it: at the
         # boundary the read would stand between the step's end and the

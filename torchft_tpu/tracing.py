@@ -46,7 +46,11 @@ benchmark's ``mistral-7b.steady-1g`` cell on a TPU v5e, ten spans a
 step, seven same-seed pairs against ``TORCHFT_TRACING=0``): a step of
 337.07 ms with it against 337.02 ms without, the pairs' differences
 -0.92 to +0.80 ms with no direction, so under what two runs of one
-seed differ by. ``TORCHFT_TRACING=0`` disables it process-wide,
+seed differ by. With two groups, about 500 spans a step a group
+(PERF.md, PR 58: ``mistral-7b.steady-2g``, four same-seed pairs):
+tokens/s -1.5, -0.6, -1.1, +1.1 % against ``TORCHFT_TRACING=0``: a
+median of -0.9 % with three pairs of four one way, which four pairs do
+not resolve from zero. ``TORCHFT_TRACING=0`` disables it process-wide,
 turning every ``span()`` into a shared no-op.
 """
 
@@ -78,8 +82,10 @@ CONTEXT_TAGS = ("replica_id", "quorum_id", "epoch", "step", "policy_name")
 # track per stage, in protocol order. Unknown stages append after.
 STAGES = (
     "step_begin", "dispatch", "wait_quorum",
-    "quorum", "heal", "heal_stripe", "fetch_dispatch", "fetch_wait",
-    "ring", "hier_intra", "hier_leader", "put", "exchange_wait",
+    "quorum", "reconfigure", "heal", "heal_stripe", "heal_adopt",
+    "fetch_dispatch", "fetch_wait",
+    "ring", "ring_preamble", "hier_intra", "hier_leader", "put",
+    "exchange_wait",
     "overlap_drain", "drain", "pre_vote", "vote", "post_vote",
     "publish_status", "state_digest", "update", "ckpt_save", "publish",
     "heal_manifest", "heal_recv", "heal_verify", "heal_place",
@@ -330,6 +336,59 @@ def deferring_counts(program: Callable[..., Any]) -> Callable[..., Any]:
         return tuple(out)
 
     return run
+
+
+# What building programs costs, process-wide: jax reports every lowering
+# and backend compile (the last includes a read of the persistent compile
+# cache, which it also reports alone) as a duration event, on whichever
+# thread built the program. A steady step builds nothing and fires none.
+# Neither event nests in itself or in the other, so the total is
+# thread-seconds of building. jax's trace durations do nest (a jitted
+# function traced inside another is inside its duration too) and are left
+# out: the ``dispatch`` span's ``traced=True`` says which step traced, and
+# its own stamps what that cost (``dispatch_traced_ms_total``). These are
+# the host's clocks, not what a program counted, so they keep a dict of
+# their own beside ``_program_counters``.
+_BUILD_EVENTS = {
+    "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        "program_build_ms_total",
+    "/jax/core/compile/backend_compile_duration": "program_build_ms_total",
+    "/jax/compilation_cache/cache_retrieval_time_sec":
+        "program_cache_read_ms_total",
+}
+_build_ms: Dict[str, float] = {}    # empty until the listener is there
+_build_ms_lock = threading.Lock()
+
+
+def _on_build_event(event: str, duration_secs: float, **_: Any) -> None:
+    key = _BUILD_EVENTS.get(event)
+    if key is not None:
+        with _build_ms_lock:
+            _build_ms[key] += duration_secs * 1e3
+
+
+def watch_program_builds() -> None:
+    """Register the one listener a process needs for
+    ``program_build_ms_total`` / ``program_cache_read_ms_total`` (the first
+    ``Manager`` calls it; later calls do nothing, and so does a process
+    without jax)."""
+    try:
+        from jax import monitoring
+    except ImportError:
+        return
+    with _build_ms_lock:
+        if _build_ms:
+            return
+        _build_ms.update(dict.fromkeys(_BUILD_EVENTS.values(), 0.0))
+    monitoring.register_event_duration_secs_listener(_on_build_event)
+
+
+def program_build_ms() -> Dict[str, float]:
+    """The process's ``program_build_ms_total`` and
+    ``program_cache_read_ms_total`` (``Manager.metrics()`` merges them in);
+    empty before :func:`watch_program_builds`."""
+    with _build_ms_lock:
+        return dict(_build_ms)
 
 
 def program_counters() -> Dict[str, float]:
