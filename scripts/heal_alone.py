@@ -14,13 +14,49 @@ another checkout (a parent unpacked under `.chip_archive/`)::
 Run it under the mix's allocator settings (`benchmarks/traffic/
 kill-heal-2g.json` `env`) to read what the benchmark's process sees. Not a
 benchmark cell: `recover_s` is the cell's.
+
+`--beside thread|process|burner` (PR 59) runs the
+transfers beside something that never blocks: a thread spinning in Python
+(what a trace is to the interpreter lock), the same spinner in another
+process (cores and memory, no lock), or a thread busy in `zlib.crc32`
+outside the lock. On the chip's host: 2.1 s alone and beside the other
+two; beside the thread 32 s with the interpreter's own socket calls and
+7.1 s with a chunk in one foreign call a side (PERF.md, Findings PR 59).
 """
 
 import argparse
 import json
 import os
+import subprocess
 import sys
+import threading
 import time
+import zlib
+
+_SPIN = "x = 0\nwhile True:\n    for i in range(1000): x += i * i\n"
+
+
+def _beside(kind: str):
+    """Start what the transfers run beside; returns what stops it."""
+    stop = threading.Event()
+    if kind == "process":
+        child = subprocess.Popen([sys.executable, "-c", _SPIN])
+        return child.kill
+
+    def spin():
+        x = 0
+        while not stop.is_set():
+            for i in range(1000):
+                x += i * i
+
+    def burn():
+        buf = bytes(64 << 20)
+        while not stop.is_set():
+            zlib.crc32(buf)
+
+    threading.Thread(target=spin if kind == "thread" else burn,
+                     daemon=True).start()
+    return stop.set
 
 
 def main() -> int:
@@ -33,6 +69,10 @@ def main() -> int:
     ap.add_argument("--chunk-mib", type=float, default=None,
                     help="an experiment: the donor's socket writes in "
                          "chunks of this size, not the program's")
+    ap.add_argument("--beside", choices=("thread", "process", "burner"),
+                    help="run the transfers beside a Python spinner thread, "
+                         "the same in another process, or a thread busy "
+                         "outside the interpreter lock")
     args = ap.parse_args()
     sys.path.insert(0, os.path.abspath(args.root))
 
@@ -79,6 +119,7 @@ def main() -> int:
                                  "kind": dev.device_kind},
                       "root": args.root}), flush=True)
     server = CheckpointServer(lambda: state)
+    stop_beside = _beside(args.beside) if args.beside else (lambda: None)
     try:
         for k in range(args.repeats):
             server.allow_checkpoint(k + 1)
@@ -107,6 +148,7 @@ def main() -> int:
             del out, target
             server.disallow_checkpoint()
     finally:
+        stop_beside()
         server.shutdown()
     return 0
 

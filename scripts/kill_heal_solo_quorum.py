@@ -93,10 +93,10 @@ def init(self, *a, **k):
     say(f"trainer_init_end thread={who} n={_made[who]}")
 
 
-def quorum(self):
+def quorum(self, *a):
     t0 = time.monotonic()
     try:
-        return _quorum(self)
+        return _quorum(self, *a)
     finally:
         if time.monotonic() - t0 > 0.2:
             say(f"quorum_rpc replica={self._replica_id.split(':')[0]} "

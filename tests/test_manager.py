@@ -122,6 +122,45 @@ class TestManagerHealing:
         finally:
             m.shutdown()
 
+    def test_round_never_joined_answers_into_its_own_future(self):
+        """A step thread may call step() again with the last round still
+        in flight (a train_step that raised between step() and its
+        join): that round's answer goes into the future step() made for
+        IT, and round_heals() says what the newest round answered."""
+        import threading
+        client = MagicMock()
+        held = threading.Event()
+        later = quorum_result(
+            quorum_id=1, max_step=21, max_rank=1, max_world_size=2,
+            replica_rank=1, replica_world_size=2)
+
+        def quorum(**_kw):
+            if client.quorum.call_count == 1:
+                assert held.wait(30)
+                return self._heal_quorum(max_step=20)
+            return later
+
+        client.quorum.side_effect = quorum
+        client.should_commit.return_value = True
+        m = make_manager(client, use_async_quorum=True,
+                         load_state_dict=MagicMock(), min_replica_size=1)
+        state = {"user": {"w": np.full(2, 7.0)},
+                 "torchft": {"step": 20, "batches_committed": 40}}
+        cp, pc = self._patch_heal(state)
+        try:
+            with cp, pc:
+                m.step()
+                first = m._round_answer
+                m.step()                    # the first round never joined
+                assert m._round_answer is not first
+                held.set()
+                assert first.result(30) is True
+                assert m.round_heals() is False
+                m._quorum_future.result(30)   # no InvalidStateError
+        finally:
+            held.set()
+            m.shutdown()
+
     def test_sync_heal_participates_immediately(self):
         client = MagicMock()
         client.quorum.return_value = quorum_result(

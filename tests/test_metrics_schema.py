@@ -42,6 +42,9 @@ DOCUMENTED_KEYS = frozenset([
     "heal_adopt_ms_total", "join_first_commit_ms",
     "dispatch_traced_ms_total",
     "program_build_ms_total", "program_cache_read_ms_total",
+    # a healer's step program built beside its heal (PR 59): how often,
+    # and the wall of those ``dispatch`` spans (tagged ``ahead=True``)
+    "dispatch_ahead_count", "dispatch_ahead_ms_total",
     # healing
     "heal_count", "heal_ms_total", "heal_bytes_total",
     "heal_bytes_resumed_total", "heal_donor_failovers",
@@ -486,6 +489,42 @@ class TestTraceEventSchema:
         assert tid_of == {
             "publish_status": DOCUMENTED_STAGES.index("publish_status") + 1,
             "state_digest": DOCUMENTED_STAGES.index("state_digest") + 1}
+
+    def test_dispatch_tags(self):
+        """A ``dispatch`` span says which program, whether the call was
+        speculative and whether it traced; one that built a healer's
+        program beside its heal says ``ahead`` and none of the other two
+        (no call was made). The runbook's recovery recipe and the
+        counters ``dispatch_traced_ms_total`` / ``dispatch_ahead_ms_total``
+        go by these names."""
+        import jax.numpy as jnp
+        import optax
+
+        from torchft_tpu.parallel import FTTrainer
+
+        trainer = FTTrainer(
+            loss_fn=lambda p, b: jnp.sum(p["w"] * b), tx=optax.sgd(0.1),
+            params={"w": jnp.zeros(2)},
+            manager_factory=lambda load, save: make_manager(
+                load_state_dict=load, state_dict=save))
+        try:
+            batch = jnp.ones(2)
+            trainer.train_step(batch)
+            trainer._build_ahead(None, batch)
+            mx = trainer.manager.metrics()
+            events = [ev for ev in
+                      trainer.manager.tracer().chrome_trace()["traceEvents"]
+                      if ev["ph"] == "X" and ev["name"] == "dispatch"]
+        finally:
+            trainer.shutdown()
+        known = REQUIRED_TRACE_TAGS | REQUIRED_SPAN_FIELDS
+        call, ahead = ({k: v for k, v in ev["args"].items()
+                        if k not in known} for ev in events)
+        assert call == {"program": "fwd_bwd", "speculative": False,
+                        "traced": True}
+        assert ahead == {"program": "fwd_bwd", "ahead": True}
+        assert mx["dispatch_ahead_count"] == 1
+        assert mx["dispatch_ahead_ms_total"] > 0
 
     def test_open_spans_marked(self):
         tr = tracing.Tracer(steps=4, enabled=True)

@@ -10,6 +10,7 @@ import time
 
 import pytest
 
+from torchft_tpu import _native
 from torchft_tpu._native import (
     Lighthouse,
     ManagerClient,
@@ -308,3 +309,49 @@ def test_step_retry_gets_fresh_rounds():
         m.shutdown()
     finally:
         lh.shutdown()
+
+
+class TestSockCalls:
+    """ring.cc's two entry points for a body chunk of the HTTP tiers:
+    one foreign call a chunk a side."""
+
+    @pytest.fixture
+    def core(self):
+        core = _native.sock_core()
+        if core is None:
+            pytest.skip("no native core")
+        return core
+
+    @pytest.mark.parametrize("blocking", [True, False])
+    def test_roundtrip_short_at_close_and_errors(self, core, blocking):
+        import socket
+        import threading
+        a, b = socket.socketpair()
+        if not blocking:
+            a.settimeout(5.0)
+            b.settimeout(5.0)
+        data = bytes(range(256)) * 40_000       # far over a socket buffer
+        buf = bytearray(len(data))
+        t = threading.Thread(target=_native.sock_send_all,
+                             args=(core, a.fileno(), memoryview(data), 5.0))
+        t.start()
+        assert _native.sock_recv_into(core, b.fileno(), memoryview(buf),
+                                      5.0) == len(data)
+        t.join()
+        assert bytes(buf) == data
+        # nothing to read: the wait is bounded, whatever the socket's mode
+        with pytest.raises(TimeoutError):
+            _native.sock_recv_into(core, b.fileno(), memoryview(buf), 0.05)
+        # the peer's close is a short count, not an error
+        a.sendall(b"tail")
+        a.close()
+        assert _native.sock_recv_into(core, b.fileno(), memoryview(buf),
+                                      5.0) == 4
+        assert bytes(buf[:4]) == b"tail"
+        assert _native.sock_recv_into(core, b.fileno(), memoryview(buf),
+                                      5.0) == 0
+        with pytest.raises(ConnectionError):
+            for _ in range(4):
+                _native.sock_send_all(core, b.fileno(), memoryview(data),
+                                      5.0)
+        b.close()

@@ -223,6 +223,117 @@ class TestServerCore:
             srv.shutdown()
             srv.server_close()
 
+    @pytest.mark.parametrize("native", [True, False])
+    def test_large_body_chunks_cross_in_one_call_a_side(self, native,
+                                                        monkeypatch):
+        """A body chunk of NATIVE_BODY_BYTES or more is written by the
+        handler's thread and read by the client in one foreign call each
+        (no socket call of the interpreter's a piece the kernel moves);
+        the bytes, the class's byte count, the small reads between the
+        large ones and the connection's reuse are what they are without
+        the native core."""
+        from torchft_tpu import _native
+        if not native:
+            monkeypatch.setattr(_native, "sock_core", lambda: None)
+        elif _native.sock_core() is None:
+            pytest.skip("no native core")
+        chunk = 3 * transport.NATIVE_BODY_BYTES
+        rng = np.random.default_rng(59)
+        payload = rng.integers(0, 256, 4 * chunk + 12_345,
+                               dtype=np.uint8).tobytes()
+        view = memoryview(payload)
+
+        def route(h):
+            h.send_response(200)
+            h.send_header("Content-Length", str(len(payload)))
+            h.end_headers()
+            for a in range(0, len(payload), chunk):
+                h.wfile.write(view[a:a + chunk])
+
+        calls = {"recv": 0}
+        real = socket.SocketIO.readinto
+
+        def counted(self, b):
+            calls["recv"] += 1
+            return real(self, b)
+
+        srv, base = _serve(route)
+        pool = ConnectionPool()
+        before = transport.metrics()["transport_qos_heal_bytes_total"]
+        try:
+            for _ in range(2):      # the second on the pooled connection
+                got = bytearray(len(payload))
+                mv = memoryview(got)
+                with pool.request(f"{base}/x", 5.0, None) as r:
+                    monkeypatch.setattr(socket.SocketIO, "readinto",
+                                        counted)
+                    calls["recv"] = 0
+                    at = 0
+                    # large, small, large ... : a small read fills the
+                    # response's buffered reader again, which the next
+                    # large one has to empty first.
+                    for n in (chunk, 7, chunk, 100, 2 * chunk):
+                        k = 0
+                        while k < n:
+                            step = r.readinto(mv[at + k:at + n])
+                            assert step
+                            k += step
+                        at += n
+                    tail = r.read()
+                    monkeypatch.setattr(socket.SocketIO, "readinto", real)
+                    got[at:] = tail
+                    assert r.readinto(bytearray(8)) == 0
+                assert bytes(got) == payload
+                if native:
+                    # at most the one buffer-sized read ahead of a large
+                    # read, and the tail's
+                    assert calls["recv"] < 40, calls
+            assert pool.redials == 1 and pool.redials_avoided == 1
+            after = transport.metrics()[
+                "transport_qos_heal_bytes_total"]
+            assert after - before >= 2 * len(payload)
+        finally:
+            pool.close()
+            srv.shutdown()
+            srv.server_close()
+
+    def test_large_body_to_a_client_that_went_away_fails_the_handler(self):
+        """A direct write to a peer that hung up raises in the handler,
+        as a queued one's failure did at its next write."""
+        from torchft_tpu import _native
+        if _native.sock_core() is None:
+            pytest.skip("no native core")
+        chunk = memoryview(bytes(4 * transport.NATIVE_BODY_BYTES))
+        seen = []
+
+        def route(h):
+            h.connection.settimeout(2.0)
+            h.send_response(200)
+            h.send_header("Content-Length", str(64 * len(chunk)))
+            h.end_headers()
+            try:
+                for _ in range(64):
+                    h.wfile.write(chunk)
+            except (ConnectionError, socket.timeout) as e:
+                seen.append(e)
+                raise
+
+        srv, base = _serve(route)
+        try:
+            host, port = srv.server_address[:2]
+            c = socket.create_connection((host, port), 5.0)
+            c.sendall(b"GET /x HTTP/1.1\r\nHost: x\r\n\r\n")
+            assert c.recv(1 << 16)
+            c.close()
+            deadline = time.monotonic() + 10
+            while not seen and time.monotonic() < deadline:
+                time.sleep(0.01)
+            assert seen and isinstance(seen[0], (ConnectionError,
+                                                 socket.timeout))
+        finally:
+            srv.shutdown()
+            srv.server_close()
+
     def test_bearer_gate(self):
         def route(h):
             if not transport.check_bearer_auth(h, "s3cret"):

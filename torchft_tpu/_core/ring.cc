@@ -11,8 +11,9 @@
 // to vectorise at the Release flags (-O3), a scalar fold is slower than
 // numpy's.
 //
-// Python sockets with a timeout are non-blocking underneath: recv() and,
-// on EAGAIN, poll() with the ring's timeout (< 0 = wait for ever). Closing
+// Python sockets with a timeout are non-blocking underneath, and every
+// call here asks for that itself (MSG_DONTWAIT): recv() and, on EAGAIN,
+// poll() with the ring's timeout (< 0 = wait for ever). Closing
 // a ring shuts its sockets down first (_Ring.close), which is what wakes a
 // blocked poll/recv here on abort or reconfigure.
 //
@@ -39,16 +40,21 @@ int fail(char** err, const char* msg) {
   return -1;
 }
 
-// Receive exactly n bytes into dst.
-int recv_exact(int fd, char* dst, size_t n, int64_t timeout_ms, char** err) {
+// Receive exactly n bytes into dst. With `got`, the peer's close is not an
+// error: *got says how many bytes arrived before it (n otherwise).
+int recv_exact(int fd, char* dst, size_t n, int64_t timeout_ms, char** err,
+               size_t* got_out = nullptr) {
   size_t got = 0;
   while (got < n) {
-    ssize_t r = recv(fd, dst + got, n - got, 0);
+    ssize_t r = recv(fd, dst + got, n - got, MSG_DONTWAIT);
     if (r > 0) {
       got += (size_t)r;
       continue;
     }
-    if (r == 0) return fail(err, "peer closed connection");
+    if (r == 0) {
+      if (got_out) break;
+      return fail(err, "peer closed connection");
+    }
     if (errno == EINTR) continue;
     if (errno != EAGAIN && errno != EWOULDBLOCK)
       return fail(err, strerror(errno));
@@ -57,6 +63,28 @@ int recv_exact(int fd, char* dst, size_t n, int64_t timeout_ms, char** err) {
     if (pr == 0) return fail(err, "timed out");
     if (pr < 0 && errno != EINTR) return fail(err, strerror(errno));
     // Readable, hung up or in error: the next recv() says which.
+  }
+  if (got_out) *got_out = got;
+  return 0;
+}
+
+// Send exactly n bytes from src: send() and, on EAGAIN, poll() as above.
+int send_all(int fd, const char* src, size_t n, int64_t timeout_ms,
+             char** err) {
+  size_t put = 0;
+  while (put < n) {
+    ssize_t r = send(fd, src + put, n - put, MSG_NOSIGNAL | MSG_DONTWAIT);
+    if (r >= 0) {
+      put += (size_t)r;
+      continue;
+    }
+    if (errno == EINTR) continue;
+    if (errno != EAGAIN && errno != EWOULDBLOCK)
+      return fail(err, strerror(errno));
+    struct pollfd p = {fd, POLLOUT, 0};
+    int pr = poll(&p, 1, timeout_ms < 0 ? -1 : (int)timeout_ms);
+    if (pr == 0) return fail(err, "timed out");
+    if (pr < 0 && errno != EINTR) return fail(err, strerror(errno));
   }
   return 0;
 }
@@ -118,6 +146,19 @@ int tft_ring_recv_fold(int fd, const void* mine, void* out, size_t nbytes,
 int tft_ring_recv_exact(int fd, void* out, size_t nbytes, int64_t timeout_ms,
                         char** err) {
   return recv_exact(fd, (char*)out, nbytes, timeout_ms, err);
+}
+
+// A body chunk of the HTTP tiers (transport.py) in one foreign call a
+// side: read until nbytes have arrived or the peer closed (*got), and
+// written whole.
+int tft_sock_recv_into(int fd, void* out, size_t nbytes, int64_t timeout_ms,
+                       size_t* got, char** err) {
+  return recv_exact(fd, (char*)out, nbytes, timeout_ms, err, got);
+}
+
+int tft_sock_send_all(int fd, const void* src, size_t nbytes,
+                      int64_t timeout_ms, char** err) {
+  return send_all(fd, (const char*)src, nbytes, timeout_ms, err);
 }
 
 }  // extern "C"

@@ -21,6 +21,8 @@ import subprocess
 from dataclasses import dataclass
 from typing import Optional
 
+import numpy as np
+
 from torchft_tpu import chaos
 from torchft_tpu.retry import RetryPolicy, RetryStats, call_with_retry
 
@@ -183,6 +185,15 @@ def _load() -> ctypes.CDLL:
         lib.tft_ring_recv_exact.argtypes = [
             i32, vp, ctypes.c_size_t, i64, ctypes.POINTER(vp)]
         lib.tft_ring_recv_exact.restype = i32
+    # A body chunk of the HTTP tiers in one call a side (ring.cc).
+    if hasattr(lib, "tft_sock_send_all"):
+        lib.tft_sock_recv_into.argtypes = [
+            i32, vp, ctypes.c_size_t, i64,
+            ctypes.POINTER(ctypes.c_size_t), ctypes.POINTER(vp)]
+        lib.tft_sock_recv_into.restype = i32
+        lib.tft_sock_send_all.argtypes = [
+            i32, vp, ctypes.c_size_t, i64, ctypes.POINTER(vp)]
+        lib.tft_sock_send_all.restype = i32
     return lib
 
 
@@ -290,6 +301,60 @@ def ring_recv(core: ctypes.CDLL, fd: int, out: int, nbytes: int,
         rc = core.tft_ring_recv_fold(fd, mine, out, nbytes, dtype,
                                      timeout_ms, ctypes.byref(err))
     _check(rc, err)
+
+
+@functools.cache
+def sock_core() -> Optional[ctypes.CDLL]:
+    """The loaded core where it moves a socket's bytes itself
+    (:func:`sock_recv_into`, :func:`sock_send_all`), else None, as
+    :func:`ring_core`."""
+    try:
+        core = lib()
+    except (OSError, RuntimeError):
+        return None
+    return core if hasattr(core, "tft_sock_send_all") else None
+
+
+def _sock_check(rc: int, err: ctypes.c_void_p) -> None:
+    """A socket call's failure as the exception the interpreter's own
+    socket would have raised, which is what callers classify by."""
+    if rc != 0:
+        msg = _take_str(err.value) if err.value else "unknown native error"
+        if msg == "timed out":
+            raise TimeoutError(msg)
+        raise ConnectionError(msg)
+
+
+def _address(view: memoryview) -> int:
+    return np.frombuffer(view, np.uint8).ctypes.data
+
+
+def sock_recv_into(core: ctypes.CDLL, fd: int, view: memoryview,
+                   timeout: Optional[float]) -> int:
+    """Fill ``view`` from socket ``fd`` in ONE foreign call (the GIL is
+    released for all of it, where ``recv_into`` takes it back for every
+    piece the kernel has ready); returns the bytes read, short only
+    where the peer closed. ``timeout`` (seconds, None = for ever) bounds
+    each wait for the next byte, as a socket's timeout does."""
+    got = ctypes.c_size_t()
+    err = ctypes.c_void_p()
+    rc = core.tft_sock_recv_into(
+        fd, _address(view), len(view),
+        -1 if timeout is None else int(timeout * 1000),
+        ctypes.byref(got), ctypes.byref(err))
+    _sock_check(rc, err)
+    return got.value
+
+
+def sock_send_all(core: ctypes.CDLL, fd: int, view: memoryview,
+                  timeout: Optional[float]) -> None:
+    """Write all of ``view`` to socket ``fd`` in ONE foreign call;
+    ``timeout`` bounds each wait for room."""
+    err = ctypes.c_void_p()
+    rc = core.tft_sock_send_all(
+        fd, _address(view), len(view),
+        -1 if timeout is None else int(timeout * 1000), ctypes.byref(err))
+    _sock_check(rc, err)
 
 
 class Lighthouse:

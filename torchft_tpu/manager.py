@@ -154,6 +154,10 @@ _METRICS: Dict[str, float] = {
     # lowered, compiled or read from the cache) its program: a new
     # Manager's first steps, a shape seen for the first time.
     "dispatch_traced_ms_total": 0.0,
+    # A healer's step program built beside its heal (the trainer's
+    # dispatch spans tagged ahead=True): how often, and their wall.
+    # Off the critical path, so not in dispatch_traced_ms_total.
+    "dispatch_ahead_count": 0, "dispatch_ahead_ms_total": 0.0,
     # Resilient-heal observability: bytes re-sent by resumed
     # attempts (strictly less than the payload when resume
     # works), donor failovers, leaves caught by digest
@@ -722,6 +726,10 @@ class Manager:
         self._pending_state_dict: Optional[Dict[str, Any]] = None
         self._pending_work: list[Future] = []
         self._quorum_future: Optional[Future] = None
+        # Does this group heal in this step's round: set on the quorum
+        # thread once the response has validated, ahead of the
+        # reconfigure and the heal (round_heals()).
+        self._round_answer: Optional[Future] = None
         # Quorum latency distribution (p50/p95/max in metrics()): bounded
         # reservoir, mutated under the metrics lock on the quorum thread.
         self._quorum_latency = _LatencyReservoir()
@@ -1022,7 +1030,14 @@ class Manager:
         self._tracer.set_context(step=self._step,
                                  policy_name=self.policy().name)
 
-        self._quorum_future = self._executor.submit(self._async_quorum)
+        answer = self._round_answer = Future()
+        self._quorum_future = self._executor.submit(self._async_quorum,
+                                                    answer)
+        # A round that ends without its answer (it raised first, or was
+        # cancelled with its executor) must not leave a waiter behind:
+        # the answer is then "unknown".
+        self._quorum_future.add_done_callback(
+            lambda _f: answer.done() or answer.set_result(None))
         if not self._use_async_quorum:
             self._quorum_future.result()
             if self._healing:
@@ -1040,11 +1055,11 @@ class Manager:
     # as an alias so either spelling of the loop works.
     start_quorum = step
 
-    def _async_quorum(self) -> None:
+    def _async_quorum(self, answer: Future) -> None:
         """Quorum round-trip + membership reaction (reference
         ``manager.py:334-396``). Runs on the single quorum thread."""
         try:
-            self._async_quorum_inner()
+            self._async_quorum_inner(answer)
             with self._metrics_lock:  # read by step() on the caller thread
                 self._quorum_failure_streak = 0
         except Exception:
@@ -1052,7 +1067,7 @@ class Manager:
                 self._quorum_failure_streak += 1
             raise
 
-    def _async_quorum_inner(self) -> None:
+    def _async_quorum_inner(self, answer: Future) -> None:
         with self._tracer.timed("quorum") as sp:
             q = self._client.quorum(
                 rank=self._rank,
@@ -1141,6 +1156,13 @@ class Manager:
                     and self._participating_rank >= self._min_replica_size
                 ):
                     self._participating_rank = None
+
+        # The round's answer, for a step thread that can use the time
+        # the reconfigure and the heal below take (round_heals()): into
+        # the future step() made for THIS round, which a later step()
+        # has replaced on the attribute if the round was never joined.
+        if not answer.done():
+            answer.set_result(bool(q.heal))
 
         # Rebuild the communicator when membership changed — OR when a
         # collective error poisoned the current ring: its sockets may be
@@ -2018,6 +2040,12 @@ class Manager:
         span's own stamps."""
         self._record(dispatch_traced_ms_total=ms)
 
+    def record_dispatch_ahead(self, ms: float) -> None:
+        """Trainer-side: the wall of a ``dispatch`` span tagged
+        ``ahead=True`` (a healer's step program built beside its heal),
+        from the span's own stamps."""
+        self._record(dispatch_ahead_count=1, dispatch_ahead_ms_total=ms)
+
     def _join_quorum(self) -> None:
         """The exchange's own join of this step's quorum round, on the
         caller's thread under a ``wait_quorum`` span (raises what the
@@ -2025,6 +2053,20 @@ class Manager:
         assert self._quorum_future is not None, "call step() first"
         with self._tracer.span("wait_quorum"):
             self._quorum_future.result()
+
+    def round_heals(self) -> Optional[bool]:
+        """Wait for this step's quorum round to ANSWER (its response
+        validated; the reconfigure and the heal still ahead of it on the
+        quorum thread) and say whether this group heals in it. ``None``
+        where there is no answer to act on ahead of the round's end: the
+        round raised first (:meth:`wait_quorum` latches what it raised),
+        or the quorum is synchronous and :meth:`step` has joined the
+        round and restored the healed state already. Never outlasts the
+        round."""
+        assert self._round_answer is not None, "call step() first"
+        if not self._use_async_quorum:
+            return None
+        return self._round_answer.result()
 
     def wait_quorum(self) -> None:
         """Join this step's quorum round; a quorum failure latches via

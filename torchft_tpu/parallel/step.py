@@ -22,9 +22,12 @@ Canonical use (examples/train_ddp.py)::
 
 from __future__ import annotations
 
+import contextlib
 import logging
+import sys
+import threading
 import time
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Callable, Iterator, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -35,6 +38,38 @@ from torchft_tpu.manager import Manager
 from torchft_tpu.optim import DelayedOptimizer, FTOptimizer
 
 logger = logging.getLogger(__name__)
+
+# While a step thread builds a program beside its group's heal, the
+# interpreter's switch interval: a trace is seconds of Python that never
+# blocks, and every return of a heal thread from a socket, digest or
+# device call waits an interval for the lock. At the default 5 ms the
+# kill cell's heal took 3.9 s beside the build, at 0.5 ms 2.5 and at
+# 0.1 ms its lone 2.1, the build 3.8 in all three (PERF.md, PR 59).
+_BUILD_SWITCH_INTERVAL = 5e-4
+_builders = [0, None]   # builds in flight, the interval to put back
+_builders_lock = threading.Lock()
+
+
+@contextlib.contextmanager
+def _yielding_lock() -> Iterator[None]:
+    """Hold the switch interval at :data:`_BUILD_SWITCH_INTERVAL` (where
+    it is longer) while the body runs; the last of several bodies in
+    flight puts back what the first found."""
+    with _builders_lock:
+        if not _builders[0]:
+            found = sys.getswitchinterval()
+            _builders[1] = found if found > _BUILD_SWITCH_INTERVAL else None
+            if _builders[1] is not None:
+                sys.setswitchinterval(_BUILD_SWITCH_INTERVAL)
+        _builders[0] += 1
+    try:
+        yield
+    finally:
+        with _builders_lock:
+            _builders[0] -= 1
+            if not _builders[0] and _builders[1] is not None:
+                # The interval is kept in whole microseconds, cut off.
+                sys.setswitchinterval(_builders[1] + 5e-7)
 
 
 def _on_mesh(tree: Any, param_shardings: Any) -> Any:
@@ -207,11 +242,15 @@ class FTTrainer:
         # shape only changes on membership changes, so last step's answer is
         # right in both steady states and the quorum round-trip stays fully
         # overlapped with device execution. None = not yet known; the first
-        # step joins its quorum *before* dispatching so the right program is
-        # compiled from the start (multi-group runs never pay the fused
-        # compile, single-group runs never pay the split one). Later
-        # mispredictions cost one recompute (fused->split) or one
-        # slower-but-correct step (split->fused next step).
+        # step learns its quorum round's outcome *before* dispatching so
+        # the right program is compiled from the start (multi-group runs
+        # never pay the fused compile, single-group runs never pay the
+        # split one). A round that says this trainer heals has settled
+        # the choice by its answer alone (a healer runs the split step):
+        # that program is built while the quorum thread heals, and the
+        # round joined after (_build_ahead). Later mispredictions cost one
+        # recompute (fused->split) or one slower-but-correct step
+        # (split->fused next step).
         self._predict_single: Optional[bool] = None
         # Main-thread wall partition of the most recent train_step (see
         # train_step docstring); empty until the first step runs.
@@ -239,10 +278,21 @@ class FTTrainer:
         before the step would lag the commit counter by one step and draw
         step 1's slots twice. Plain array batches are unaffected.
 
+        A fresh trainer's first step has no last step to predict its
+        program from, so it waits for its round's outcome before it
+        dispatches. Where the round's answer says this trainer heals
+        (``Manager.round_heals``), the program is settled by that
+        answer, and this thread builds it (trace, lowering, compile)
+        while the quorum thread fetches the donor's state: then it joins
+        the round, adopts the healed state and dispatches. Otherwise it
+        joins the round first and builds what the round's outcome asks
+        for, in the dispatch itself.
+
         After each call, :attr:`last_step_timings` holds a MAIN-THREAD wall
         partition of the step (seconds): ``dispatch`` (trace + compile +
         async dispatch of the jitted step — compiles land here on a
-        first/reshaped step), ``allreduce_wait`` (blocked on the
+        first/reshaped step, a healer's build beside its heal too),
+        ``allreduce_wait`` (blocked on the
         cross-group exchange, which joins the quorum, so quorum/heal wall
         not hidden under dispatch surfaces here), ``commit`` (vote +
         update), and ``other`` (quorum kick, batch placement, loop glue).
@@ -275,8 +325,9 @@ class FTTrainer:
         # Quorum/heal wall the main thread blocks on BEFORE dispatch (the
         # first step of a fresh trainer joins its quorum here to learn the
         # step shape) counts as allreduce_wait — on a restarted trainer
-        # this early join contains the entire heal fetch, the dominant
-        # recovery component, which must not be mislabeled as loop glue.
+        # this early join contains what of the heal fetch outlasts the
+        # build, the dominant recovery component, which must not be
+        # mislabeled as loop glue.
         wait_ns = 0
         dispatch_ns = 0
         if self._predict_single is None:
@@ -285,10 +336,22 @@ class FTTrainer:
             # state lives stripe-wise in FTOptimizer, not in
             # self.opt_state, which the fused program would read.
             with tr.timed("wait_quorum", after=last) as last:
+                # `is True`: a duck-typed / mocked manager builds nothing.
+                heals = self.manager.round_heals() is True
+            wait_ns += last.dur_ns
+            if heals and not self._shard and hasattr(self._fwd_bwd, "lower"):
+                # Nothing of the trace, the lowering and the compile
+                # reads the healed values: while the quorum thread
+                # fetches them, this thread builds the step it will
+                # run on them. (Shard mode's first step is left as it
+                # was: no cell measures a shard-mode heal.)
+                last = self._build_ahead(last, batch)
+                dispatch_ns += last.dur_ns
+            with tr.timed("wait_quorum", after=last) as last:
                 self.manager.wait_quorum()
                 if self.manager.is_healing():
                     # A restarted trainer has fetched the donor's state
-                    # inside that wait. Adopt it now rather than at the
+                    # inside that round. Adopt it now rather than at the
                     # vote: until then this trainer would hold its weights
                     # at init AND the healed ones, and run forward/backward
                     # on the former. Nothing is in flight yet, so this is
@@ -390,6 +453,34 @@ class FTTrainer:
         # next dispatch.
         tracing.settle_program_counts()
         return out, span
+
+    def _build_ahead(self, after: Any, batch: Any) -> Any:
+        """Trace, lower and compile (a read, where the compile cache is
+        warm) the split step for the leaves as a heal will place them,
+        under a ``dispatch`` span tagged ``ahead=True``; returns the
+        span. The call that follows the heal then finds the trace, the
+        lowering and the executable in ``jax.jit``'s own caches, which
+        go by the arguments' shapes, dtypes and placements: a healed
+        leaf has its target's shape and dtype and is committed to its
+        target's sharding (``serialization.device_put_like``), which a
+        leaf at init need not be, so the state is described and not
+        passed. For the build's length the interpreter hands its lock
+        over at :data:`_BUILD_SWITCH_INTERVAL`, so that the heal beside
+        it keeps its pace (:func:`_yielding_lock`)."""
+        def placed(leaf: Any) -> Any:
+            if isinstance(leaf, jax.Array):
+                return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                            sharding=leaf.sharding)
+            return leaf
+
+        with self._tracer.timed("dispatch", after=after, program="fwd_bwd",
+                                ahead=True) as span, _yielding_lock():
+            self._fwd_bwd.lower(
+                *jax.tree_util.tree_map(
+                    placed, (self.params, self.model_state)),
+                batch).compile()
+        self.manager.record_dispatch_ahead(span.dur_ns / 1e6)
+        return span
 
     def _set_timings(self, t0_ns: int, dispatch_ns: int, wait_ns: int,
                      commit_t0_ns: Optional[int] = None,

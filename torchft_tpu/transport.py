@@ -34,6 +34,11 @@ The substrate has four layers:
   bodies are queued as zero-copy memoryviews and drained under
   **per-path QoS** (ring > heal > publication > demotion, weighted-fair
   so no class starves), with ``os.sendfile`` for file-backed payloads.
+  A body chunk of ``NATIVE_BODY_BYTES`` or more crosses its socket in
+  one foreign call a side where the native core is there: the handler's
+  thread writes it (grants asked for in runs, a run ahead), and
+  :class:`PooledResponse` reads it, with the interpreter lock released
+  for the whole chunk and not taken back a piece the kernel moves.
   ``TORCHFT_ASYNC_SERVER=0`` falls back to the legacy threaded host —
   same routes, same semantics — for A/B benching.
 
@@ -66,6 +71,7 @@ import urllib.request
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from torchft_tpu import _native
 from torchft_tpu.communicator import shard_bounds
 from torchft_tpu.retry import is_transient
 
@@ -76,6 +82,13 @@ _RANGE_RE = re.compile(r"bytes=(\d+)-(\d+)?$")
 #: Header a client uses to declare its QoS class to the server; the
 #: server core accounts and schedules the response bytes under it.
 QOS_HEADER = "X-TFT-QoS"
+
+# A body chunk of at least this many bytes crosses its socket in one
+# foreign call a side where the native core is there (_native.sock_core):
+# the interpreter's own socket calls take the lock back for every piece
+# the kernel moves, which beside a thread that never blocks is a switch
+# interval's wait a piece.
+NATIVE_BODY_BYTES = 1 << 20
 
 
 # --------------------------------------------------------------------- QoS
@@ -230,15 +243,20 @@ class QoSScheduler:
 
     async def _pump(self) -> None:
         while any(self._waiters[c] for c in QoS):
+            backlogged = [c for c in QoS if self._waiters[c]]
             for c in QoS:
                 q = self._waiters[c]
                 if not q:
                     self._deficit[c] = 0.0
                     continue
                 self._deficit[c] += QOS_WEIGHTS[c] * self.QUANTUM
-                while q and q[0][1] <= self._deficit[c]:
+                # One class alone is FIFO, whatever a chunk's size: a
+                # round of the loop for every quantum of its head would
+                # be waited for and share nothing with anyone.
+                while q and (q[0][1] <= self._deficit[c]
+                             or len(backlogged) == 1):
                     fut, n = q.popleft()
-                    self._deficit[c] -= n
+                    self._deficit[c] = max(0.0, self._deficit[c] - n)
                     self._counters.note(c, n)
                     if not fut.done():
                         fut.set_result(None)
@@ -524,6 +542,9 @@ class PooledResponse:
         self._conn = conn
         self._pool = pool
         self._key = key
+        # The response's buffered reader is known to hold nothing: the
+        # last read emptied it and went to the socket itself.
+        self._emptied = False
 
     def __getattr__(self, name: str) -> Any:
         return getattr(self._resp, name)
@@ -538,7 +559,30 @@ class PooledResponse:
         return self._resp.read(None if n is None or n < 0 else n)
 
     def readinto(self, b) -> int:
-        return self._resp.readinto(b)
+        resp = self._resp
+        core = _native.sock_core()
+        if (len(b) < NATIVE_BODY_BYTES or core is None or resp.chunked
+                or resp.fp is None or (resp.length or 0) < len(b)):
+            self._emptied = False
+            return resp.readinto(b)
+        # A body chunk in one foreign call: what the response's reader
+        # already holds (at most its buffer, emptied by one read of that
+        # size), then the rest from the socket itself. recv_into would
+        # take the interpreter lock back for every piece the kernel has
+        # ready, some 2,600 a GiB, each a wait of a switch interval
+        # beside a thread that does not block (a healer's trace).
+        view = memoryview(b).cast("B")
+        got = 0
+        if not self._emptied:
+            got = resp.fp.readinto1(view[:io.DEFAULT_BUFFER_SIZE])
+            self._emptied = True
+        sock = self._conn.sock
+        got += _native.sock_recv_into(core, sock.fileno(), view[got:],
+                                      sock.gettimeout())
+        resp.length -= got
+        if not got or not resp.length:
+            resp._close_conn()
+        return got
 
     def close(self) -> None:
         conn, self._conn = self._conn, None
@@ -827,8 +871,11 @@ class _WorkerPool:
 class _TransportCore:
     """The single process-wide asyncio event loop + worker pool + QoS
     scheduler every async-hosted server shares. Lazily started on a
-    daemon thread; all socket I/O happens here (GIL released inside the
-    kernel calls), handler bodies fold on the worker pool."""
+    daemon thread; socket I/O happens here (GIL released inside the
+    kernel calls) but for large body chunks, which their handler's
+    thread writes in one foreign call
+    (:meth:`_AsyncConnection._send_direct`); handler bodies fold on the
+    worker pool."""
 
     _instance: Optional["_TransportCore"] = None
     _ilock = threading.Lock()
@@ -915,7 +962,8 @@ class _HandlerShim:
     :meth:`send_file` for the sendfile body path. Header/status bytes
     are composed worker-side and enqueued as one blob; body chunks are
     enqueued as the caller's own memoryviews (no copies) and drained on
-    the event loop under the request's QoS class."""
+    the event loop under the request's QoS class, those of
+    ``NATIVE_BODY_BYTES`` or more written by the worker itself."""
 
     protocol_version = "HTTP/1.1"
 
@@ -1008,6 +1056,7 @@ class _AsyncConnection:
     thread, no buffer."""
 
     HIGH_WATER = 8 << 20
+    DIRECT_GRANT_BYTES = 32 << 20
 
     def __init__(self, core: _TransportCore, server: "_AsyncHTTPServer",
                  reader: asyncio.StreamReader,
@@ -1027,6 +1076,9 @@ class _AsyncConnection:
         self._drained = asyncio.Event()
         self._drained.set()
         self._writer_task: Optional[asyncio.Task] = None
+        self._direct_fd: Optional[int] = None
+        self._grant: Optional[Any] = None   # of the last run of chunks
+        self._ungranted = 0                 # direct bytes not asked for
 
     # -- worker-thread side --
 
@@ -1036,8 +1088,13 @@ class _AsyncConnection:
         n = len(mv)
         deadline = (time.monotonic() + self.timeout
                     if self.timeout else None)
+        core = _native.sock_core()
+        direct = n >= NATIVE_BODY_BYTES and core is not None
         with self._wcond:
-            while self._werr is None and self._buffered >= self.HIGH_WATER:
+            # A chunk this thread will write itself goes after all that
+            # is queued; any other waits for room only.
+            while self._werr is None and self._buffered >= (
+                    1 if direct else self.HIGH_WATER):
                 remaining = None if deadline is None \
                     else deadline - time.monotonic()
                 if remaining is not None and remaining <= 0:
@@ -1049,9 +1106,52 @@ class _AsyncConnection:
             if self._werr is not None:
                 raise ConnectionError(
                     f"transport: peer connection failed: {self._werr}")
-            self._q.append(("data", mv, shim.qos))
-            self._buffered += n
-        self.core.loop.call_soon_threadsafe(self._wake_up)
+            if not direct:
+                self._q.append(("data", mv, shim.qos))
+                self._buffered += n
+        if direct:
+            self._send_direct(core, memoryview(mv), shim.qos, deadline)
+        else:
+            self.core.loop.call_soon_threadsafe(self._wake_up)
+
+    def _send_direct(self, core: Any, mv: memoryview, qos: QoS,
+                     deadline: Optional[float]) -> None:
+        """A large body chunk written by the handler's own thread in one
+        foreign call, after its grant: the loop's transport would take
+        the interpreter lock back for every piece the kernel accepts.
+        Nothing of this connection's is queued or in the transport's
+        buffer by now (one handler a connection, and it is here)."""
+        transport = self.writer.transport
+        while transport.get_write_buffer_size():
+            if transport.is_closing() or (
+                    deadline is not None and time.monotonic() > deadline):
+                raise socket.timeout("transport: send buffer stalled "
+                                     "past send timeout")
+            time.sleep(0.0005)
+        # Grants are asked for a run of chunks at a time and waited for
+        # by the next run: the loop's hops for one lie beside the
+        # other's bytes, and a class the scheduler holds back is held
+        # back a run later (at most two runs written ahead of grants).
+        self._ungranted += len(mv)
+        if self._ungranted >= self.DIRECT_GRANT_BYTES:
+            self._ask_grant(qos)
+        if self._direct_fd is None:
+            sock = transport.get_extra_info("socket")
+            if sock is None or transport.is_closing():
+                raise ConnectionError("transport: peer connection closed")
+            # A descriptor of the connection's own, closed when it has
+            # served its last request: a transport closed under a call
+            # cannot hand the number to another socket.
+            self._direct_fd = os.dup(sock.fileno())
+        _native.sock_send_all(core, self._direct_fd, mv,
+                              self.timeout or None)
+
+    def _ask_grant(self, qos: QoS) -> None:
+        ahead, n, self._ungranted = self._grant, self._ungranted, 0
+        self._grant = asyncio.run_coroutine_threadsafe(
+            self.core.scheduler.grant(qos, n), self.core.loop)
+        if ahead is not None:
+            ahead.result(self.timeout)
 
     def enqueue_sendfile(self, shim: _HandlerShim, fobj: Any,
                          offset: int, count: int) -> None:
@@ -1194,6 +1294,14 @@ class _AsyncConnection:
                 # call_soon_threadsafe ordering guarantees every write
                 # the handler made is already queued loop-side here.
                 await self._drained.wait()
+                # The last direct chunks': their bytes are counted
+                # before the response counts as served.
+                if self._ungranted:
+                    n, self._ungranted = self._ungranted, 0
+                    await self.core.scheduler.grant(shim.qos, n)
+                if self._grant is not None:
+                    grant, self._grant = self._grant, None
+                    await asyncio.wrap_future(grant)
                 with self._wcond:
                     if self._werr is not None:
                         break
@@ -1206,6 +1314,9 @@ class _AsyncConnection:
                 self.writer.close()
             except Exception:  # noqa: BLE001
                 pass
+            if self._direct_fd is not None:
+                os.close(self._direct_fd)
+                self._direct_fd = None
             self.server.conns.discard(self)
 
 
