@@ -58,6 +58,8 @@ MODULES = [
     ("torchft_tpu.parallel.ring_attention", "Ring attention (sequence "
                                             "parallel)"),
     ("torchft_tpu.ops.flash_attention", "Pallas flash attention kernels"),
+    ("torchft_tpu.ops.sparse_index", "A learned sparse attention's indexer: "
+     "exact top-k selection by bisection, the indexer's KL loss"),
     ("torchft_tpu.ops.gated_delta", "Gated delta rule: chunked linear-"
                                     "attention scan, causal short conv"),
     ("torchft_tpu.ops.ssd", "Mamba-2 state-space scan: two Pallas kernels "

@@ -178,6 +178,33 @@ def test_the_shares_add_up_to_the_whole_layer(shares, form):
                                atol=5e-5)
 
 
+def test_softmax_shares_with_no_shared_expert_add_up_to_the_whole_layer():
+    """Two shares of 4 of 8 experts under softmax scores over all 8, the 2
+    largest renormalised to sum to one, no scale and NO shared expert (the
+    routing ``keye-vl-2.0-30b-a3b`` holds a share under): their sum is the
+    uncut layer written out."""
+    def make(held):
+        return RoutedMoEMLP(num_experts=8, mlp_dim=H, top_k=2, held=held,
+                            score="softmax", route_norm=True,
+                            dtype=jnp.float32, interpret=True)
+
+    x = jax.random.normal(jax.random.key(7), (2, 64, D))
+    p = make(None).init(jax.random.key(8), x)["params"]
+    assert "shared" not in p
+    u = x.reshape(-1, D)
+    top, idx = jax.lax.top_k(jax.nn.softmax(u @ p["router"]["kernel"]), 2)
+    w = top / (top.sum(-1, keepdims=True) + 1e-20)
+    want = sum(jnp.sum(jnp.where(idx == e, w, 0.0), -1)[:, None]
+               * expert(u, p, e) for e in range(8)).reshape(x.shape)
+    got = sum(make((first, 4)).apply({"params": {
+        "router": p["router"],
+        **{k: p[k][first:first + 4] for k in ("wi_gate", "wi_up", "wo")}}},
+        x) for first in (0, 4))
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    np.testing.assert_allclose(make(None).apply({"params": p}, x), want,
+                               atol=2e-5)
+
+
 @EVERY_FORM
 @pytest.mark.parametrize("pass_rows", [512, 32768], ids=["passes", "one"])
 def test_no_pair_is_dropped_when_every_token_picks_the_same_experts(

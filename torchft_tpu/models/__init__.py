@@ -14,6 +14,8 @@ from torchft_tpu.models.transformer import (
     looped_causal_lm_loss,
     moe_lm_loss,
     mtp_causal_lm_loss,
+    sparse_lm_loss,
+    sparse_lm_losses,
     tiny_config,
     tp_rules,
 )
@@ -47,6 +49,8 @@ __all__ = [
     "llama2_13b_config",
     "llama2_70b_config",
     "looped_causal_lm_loss",
+    "sparse_lm_loss",
+    "sparse_lm_losses",
     "tiny_config",
     "tp_rules",
 ]

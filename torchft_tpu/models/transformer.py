@@ -135,6 +135,20 @@ class TransformerConfig:
     # same names and the same program.
     loop_steps: int = 1
     exit_gate: bool = False
+    # A learned sparse attention, on when ``sparse_topk`` > 0: every
+    # full-attention layer grows an ``indexer`` (``indexer_heads`` query
+    # heads of ``indexer_head_dim`` on ONE key head, a ReLU and a learned
+    # weight a head, reading a STOPPED copy of the layer's normed input),
+    # each query attends to the ``min(t + 1, sparse_topk)`` earlier keys of
+    # largest index score (``ops/sparse_index.py``,
+    # ``ops.flash_attention.sparse_flash_attention``; ``attention_fn`` is
+    # not asked), and the layer sows the indexer's loss (``indexer_loss``
+    # collection; :func:`sparse_lm_loss` adds them). ``sparse_interpret``:
+    # those kernels interpreted (None: off a TPU), as ``moe_interpret``.
+    sparse_topk: int = 0
+    indexer_heads: int = 0
+    indexer_head_dim: int = 0
+    sparse_interpret: Optional[bool] = None
     # Per-layer rematerialization (``nn.remat`` of every layer). In a stack
     # that runs once it frees NOTHING on the chip (PERF.md section 7): with
     # ``prevent_cse=False`` outside a scan XLA merges the rematerialised
@@ -165,6 +179,14 @@ class TransformerConfig:
         """Expert layers a step runs, a prediction module's among them."""
         return sum(self.is_moe_layer(i) for i in range(self.num_layers)) \
             + (self.mtp_layers if self.moe_experts else 0)
+
+    @property
+    def sparse_layers(self) -> int:
+        """Layers whose attention goes through a selection."""
+        if not self.sparse_topk:
+            return 0
+        return sum(self.layer_type(i) in (FULL, ATTENTION)
+                   for i in range(self.num_layers))
 
     @property
     def mlp_dim(self) -> int:
@@ -276,12 +298,106 @@ def plain_attention(q, k, v, causal: bool = True,
 plain_attention.supports_gqa = True
 
 
+class LayerNorm(nn.Module):
+    """Mean and variance over the last axis in float32, a gain and a bias."""
+    eps: float = 1e-5
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        bias = self.param("bias", nn.initializers.zeros, (x.shape[-1],))
+        x32 = x.astype(jnp.float32)
+        centred = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+        norm = centred * jax.lax.rsqrt(
+            jnp.mean(centred * centred, axis=-1, keepdims=True) + self.eps)
+        return (norm * scale + bias).astype(x.dtype)
+
+
+SPARSE_COUNTERS = ("sparse_selected_keys_milli_total",
+                   "indexer_kl_micro_total")
+
+
+class Indexer(nn.Module):
+    """A sparse attention's indexer over the layer's normed input, whose
+    gradient it stops (the indexer learns from its own loss and moves
+    nothing upstream): ``(a, b, u)``, the index queries [B, S, J, c], the
+    ONE index key head [B, S, c] through a LayerNorm, both under rotary
+    (half-split, the whole ``c`` dims), and the heads' weights [B, S, J]
+    in float32. Leaves ``a``, ``b``, ``b_norm``, ``u``."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, h, positions):
+        cfg = self.cfg
+        g = jax.lax.stop_gradient(h)
+        heads, dim = cfg.indexer_heads, cfg.indexer_head_dim
+        a = nn.DenseGeneral((heads, dim), use_bias=False, dtype=cfg.dtype,
+                            name="a")(g)
+        b = nn.Dense(dim, use_bias=False, dtype=cfg.dtype, name="b")(g)
+        b = LayerNorm(eps=cfg.rms_norm_eps, name="b_norm")(b)
+        a = rotary(a, positions, cfg.rope_theta)
+        b = rotary(b[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
+        u = nn.Dense(heads, use_bias=False, dtype=cfg.dtype, name="u")(g)
+        return a, b, u.astype(jnp.float32)
+
+
+def _selected_attention(module, x, q, k, v, positions):
+    """Attention over the keys the layer's indexer selects, inside
+    ``module`` (an :class:`Attention` being called): sows the indexer's loss
+    there and returns ``(out, counts)``."""
+    from torchft_tpu.ops.flash_attention import sparse_flash_attention
+    from torchft_tpu.ops.sparse_index import indexer_kl, select_keys
+
+    cfg = module.cfg
+    interpret = cfg.sparse_interpret
+    with jax.named_scope("sparse_index"):
+        a, b, u = Indexer(cfg, name="indexer")(x, positions)
+    with jax.named_scope("sparse_select"):
+        selection, index_lse = select_keys(a, b, u, cfg.sparse_topk,
+                                           interpret=interpret)
+    with jax.named_scope("sparse_attn"):
+        out, lse = sparse_flash_attention(
+            q, k, v, selection, interpret=interpret, return_lse=True)
+    with jax.named_scope("indexer_loss"):
+        kl = indexer_kl(a, b, u, q, k, lse, selection, index_lse,
+                        interpret=interpret)
+    module.sow("indexer_loss", "kl", kl)
+    module.sow("intermediates", "selection", selection)
+    # the step's mean over its sparse layers
+    share = 1.0 / cfg.sparse_layers
+    keys = jnp.mean(jnp.sum(selection.astype(jnp.float32), axis=-1))
+    counts = dict(zip(SPARSE_COUNTERS, (
+        keys * (1e3 * share),
+        jax.lax.stop_gradient(kl) * (1e6 * share))))
+    return out, counts
+
+
+def _position_attention(cfg, q, k, v, sliding: bool):
+    """Attention under a mask of positions (causal, or a window),
+    through ``cfg.attention_fn``."""
+    attn = cfg.attention_fn or plain_attention
+    if (cfg.kv_heads != cfg.num_heads
+            and not getattr(attn, "supports_gqa", False)):
+        # GQA: repeat kv heads for impls that need equal head counts.
+        # The flash kernel shares them via index maps instead — no
+        # H/H_kv-times kv memory blowup.
+        rep = cfg.num_heads // cfg.kv_heads
+        k = jnp.repeat(k, rep, axis=2)
+        v = jnp.repeat(v, rep, axis=2)
+    if sliding:
+        return attn(q, k, v, True, window=int(cfg.sliding_window))
+    return attn(q, k, v, True)
+
+
 class Attention(nn.Module):
     cfg: TransformerConfig
     kind: str = FULL
 
     @nn.compact
     def __call__(self, x, positions):
+        """The attention block's output; where the layer selects its keys
+        (``cfg.sparse_topk``, a full layer) ``(output, counts)``."""
         cfg = self.cfg
         if self.kind not in (FULL, SLIDING):
             raise ValueError(f"unknown layer type {self.kind!r}")
@@ -301,27 +417,19 @@ class Attention(nn.Module):
         if sliding or cfg.rope_full_layers:
             q = _rotary_leading(q, positions, cfg.rope_theta, cfg.rotary_dim)
             k = _rotary_leading(k, positions, cfg.rope_theta, cfg.rotary_dim)
-        attn = cfg.attention_fn or plain_attention
-        if (cfg.kv_heads != cfg.num_heads
-                and not getattr(attn, "supports_gqa", False)):
-            # GQA: repeat kv heads for impls that need equal head counts.
-            # The flash kernel shares them via index maps instead — no
-            # H/H_kv-times kv memory blowup.
-            rep = cfg.num_heads // cfg.kv_heads
-            k = jnp.repeat(k, rep, axis=2)
-            v = jnp.repeat(v, rep, axis=2)
-        if sliding:
-            out = attn(q, k, v, True, window=int(cfg.sliding_window))
+        if cfg.sparse_topk and not sliding:
+            out, counts = _selected_attention(self, x, q, k, v, positions)
         else:
-            out = attn(q, k, v, True)
+            out, counts = _position_attention(cfg, q, k, v, sliding), None
         out = out.reshape(B, S, cfg.num_heads * cfg.head_dim)
         if cfg.attn_gate:
             gate = nn.Dense(cfg.num_heads * cfg.head_dim, use_bias=False,
                             dtype=cfg.dtype, name="gate")(x)
             out = out * jax.nn.sigmoid(
                 gate.astype(jnp.float32)).astype(out.dtype)
-        return nn.DenseGeneral(cfg.embed_dim, use_bias=False,
-                               dtype=cfg.dtype, name="o")(out)
+        out = nn.DenseGeneral(cfg.embed_dim, use_bias=False,
+                              dtype=cfg.dtype, name="o")(out)
+        return out if counts is None else (out, counts)
 
 
 class MLPBlock(nn.Module):
@@ -362,8 +470,12 @@ def _mixer(cfg: TransformerConfig, kind: str, h, positions, counts: dict):
         from torchft_tpu.models.mla import LatentAttention
 
         return LatentAttention(cfg, name="attn")(h, positions)
-    return Attention(cfg, kind=FULL if kind == ATTENTION else kind,
-                     name="attn")(h, positions)
+    a = Attention(cfg, kind=FULL if kind == ATTENTION else kind,
+                  name="attn")(h, positions)
+    if isinstance(a, tuple):       # a layer that selects its keys
+        a, sparse = a
+        counts.update(sparse)
+    return a
 
 
 def _mlp(cfg: TransformerConfig, moe: bool, u, counts: dict, route_on=None):
@@ -1095,6 +1207,49 @@ def mtp_causal_lm_loss(model: "Transformer", params: Any,
         loss_main_micro_total=jax.lax.stop_gradient(main) * 1e6,
         loss_mtp_micro_total=jax.lax.stop_gradient(mtp) * 1e6, **counts)
     return main + mtp_weight * mtp
+
+
+def sparse_lm_losses(model: "Transformer", params: Any, tokens: jnp.ndarray,
+                     chunk_size: Optional[int] = None
+                     ) -> Tuple[jnp.ndarray, list]:
+    """``(L_lm, [L_I of each sparse layer])`` of a model with a learned
+    sparse attention (``sparse_topk``): the next-token loss through
+    :func:`chunked_causal_lm_loss` and the indexers' losses the layers sow,
+    in layer order. The two have DISJOINT leaves: the selection is a hard
+    set and the indexer reads a stopped stream, so ``L_lm`` reaches every
+    leaf but the indexers', and each ``L_I`` its own indexer's alone."""
+    variables = {
+        "params": params["params"] if "params" in params else params}
+    hidden, sown = model.apply(variables, tokens, return_hidden=True,
+                               mutable=["indexer_loss"])
+    layers = sown.get("indexer_loss", {})
+    kls = [kl for i in range(model.cfg.num_layers)
+           for kl in layers.get(f"layer_{i}", {}).get("attn", {}).get(
+               "kl", ())]
+    if len(kls) != model.cfg.sparse_layers or not kls:
+        raise ValueError(
+            f"sparse_lm_loss: {len(kls)} indexer losses sown for "
+            f"{model.cfg.sparse_layers} sparse layers (sparse_topk="
+            f"{model.cfg.sparse_topk}; a rematerialised layer sows none)")
+    return (chunked_causal_lm_loss(hidden, head_kernel(params), tokens,
+                                   chunk_size), kls)
+
+
+def sparse_lm_loss(model: "Transformer", params: Any, tokens: jnp.ndarray,
+                   indexer_weight: float = 1.0,
+                   chunk_size: Optional[int] = None) -> jnp.ndarray:
+    """``L_lm + indexer_weight * sum_layers L_I`` (:func:`sparse_lm_losses`):
+    one step trains the model under its selection and every layer's indexer
+    toward the attention it steers, and because the two objectives share no
+    leaf the sum's gradient IS the two gradients side by side.
+
+    Under a collector the layers count ``sparse_selected_keys_milli_total``
+    (the step's mean over queries and sparse layers of the keys a query
+    attends to, x 1000) and ``indexer_kl_micro_total`` (the mean ``L_I``
+    over layers x 1e6) beside the routed layers' ``moe_*``; tracing a
+    selected attention counts ``sparse_attn_traces_total`` on the host."""
+    lm, kls = sparse_lm_losses(model, params, tokens, chunk_size)
+    return lm + indexer_weight * sum(kls)
 
 
 def moe_lm_loss(model: "Transformer", params: Any,

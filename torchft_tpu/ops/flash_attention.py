@@ -69,13 +69,28 @@ and dk/dv (one kernel, q innermost) in VMEM scratch. Training memory is
 O(S) residuals + O(block) workspace at any sequence length.
 Layouts: q/k/v are [B, S, H, D]; causal masks are end-aligned (queries are
 the last s_q key positions; s_k >= s_q enforced).
+
+Which masks are known before a tile is fetched, and which is data:
+
+- **Functions of position** (:func:`_visible`): causal, a causal window,
+  and a ring's ``shift`` as far as its block bounds go. A block's
+  visibility follows from the grid index (:func:`_block_visibility`), and
+  under a STATIC mask the visible band can be solved for the block index
+  (:func:`_visible_band`), which is what lets an index map stop at the
+  band so that a skipped step moves no bytes (choice 2 above).
+- **Data** (:func:`_selected`; ``sparse_flash_attention``): a learned
+  sparse attention's selection, an int8 ``[B, S, S]`` operand read a tile,
+  with one flag a tile (none / some / every pair selected) read from SMEM
+  where the others compare block bounds. ``_visible`` and ``_visible_band``
+  say nothing about it: no inequality in the block index holds a selected
+  set, and no index map of a selected kernel is clamped.
 """
 
 from __future__ import annotations
 
 import functools
 import math
-from typing import Callable, Optional, Sequence
+from typing import Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -135,11 +150,64 @@ def _block_visibility(qi, ki, bq, bk, offset, causal, shift_ref,
 
 
 def _visible(q_pos, k_pos, window):
-    """The element mask: causal, and inside the window where there is
-    one."""
+    """The element mask of the kernels whose mask is a FUNCTION OF POSITION:
+    causal, and inside the window where there is one. Known from the grid
+    index alone, so :func:`_block_visibility` skips a block and
+    :func:`_visible_band` stops an index map before a tile is fetched. The
+    selected kernels (``sparse_flash_attention``) do not come here: their
+    mask is an operand (:func:`_selected`), read a tile."""
     if window is None:
         return q_pos >= k_pos
     return jnp.logical_and(q_pos >= k_pos, q_pos - k_pos < window)
+
+
+# What a tile of a selection holds (``_with_tile_flags``), as the selected
+# kernels read it from SMEM: no selected pair (the step is skipped as a
+# masked block is), some, or every pair (the unmasked body).
+_TILE_NONE, _TILE_SOME, _TILE_FULL = 0, 1, 2
+
+
+def _selected(sel_ref):
+    """The element mask of the selected kernels: DATA, the ``[bq, bk]``
+    int8 tile of the selection that the step fetched."""
+    return sel_ref[0].astype(jnp.int32) != 0
+
+
+def _tile_visibility(flag_ref, bh, heads: int, qi, ki, nqb: int):
+    """``(diag_ok, full_vis)`` of a selected kernel's step, as
+    :func:`_block_visibility` gives them for a mask of positions: from the
+    tile's flag, one int32 a ``(batch, q-block, k-block)``."""
+    flag = flag_ref[(bh // heads) * nqb + qi, ki]
+    return flag > _TILE_NONE, flag == _TILE_FULL
+
+
+def _step_visibility(flag_ref, selected_heads: int, nqb, qi, ki, bq, bk,
+                    offset, causal, shift_ref, window):
+    """A grid step's ``(diag_ok, full_vis)`` in every kernel: the tile's
+    flag under a selection (``nqb`` query blocks a batch), else
+    :func:`_block_visibility`."""
+    if selected_heads:
+        return _tile_visibility(flag_ref, pl.program_id(0), selected_heads,
+                                qi, ki, nqb)
+    return _block_visibility(qi, ki, bq, bk, offset, causal, shift_ref,
+                             window)
+
+
+def _with_tile_flags(selection: jnp.ndarray, block_q: Optional[int],
+                     block_k: Optional[int]
+                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """An int8 selection ``[B, S, S_k]`` with the ``[B * S/bq, S_k/bk]``
+    int32 flags of what each ``[bq, bk]`` tile holds, at the blocks the
+    forward and the backward both take: one reduction of the selection a
+    call, kept with it in the residuals."""
+    b, s, sk = selection.shape
+    bq = min(block_q or _auto_block(s), s)
+    bk = min(block_k or _auto_block(sk), sk)
+    tiles = selection.reshape(b, s // bq, bq, sk // bk, bk).astype(
+        jnp.int32).sum(axis=(2, 4))
+    flags = jnp.where(tiles == 0, _TILE_NONE,
+                      jnp.where(tiles == bq * bk, _TILE_FULL, _TILE_SOME))
+    return selection, flags.reshape(b * (s // bq), sk // bk).astype(jnp.int32)
 
 
 def _visible_band(idx, bq: int, bk: int, n: int, offset: int,
@@ -228,13 +296,17 @@ def _dual_instantiate(compute, causal, shift_ref, diag_ok, full_vis):
 
 
 def _fwd_kernel(*refs, causal: bool, scale: float, nkb: int, offset: int,
-                dynamic_shift: bool, window: Optional[int] = None):
+                dynamic_shift: bool, window: Optional[int] = None,
+                selected_heads: int = 0):
+    shift_ref = sel_ref = flag_ref = None
     if dynamic_shift:
         q_ref, k_ref, v_ref, shift_ref, o_ref, lse_ref, \
             m_ref, l_ref, acc_ref = refs
+    elif selected_heads:
+        q_ref, k_ref, v_ref, sel_ref, flag_ref, o_ref, lse_ref, \
+            m_ref, l_ref, acc_ref = refs
     else:
         q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref = refs
-        shift_ref = None
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     bq = q_ref.shape[1]
@@ -246,8 +318,11 @@ def _fwd_kernel(*refs, causal: bool, scale: float, nkb: int, offset: int,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    diag_ok, full_vis = _block_visibility(
-        qi, ki, bq, bk, offset, causal, shift_ref, window)
+    # (a dense kernel's program asks for no grid size: it traces as before)
+    nqb = pl.num_programs(1) if selected_heads else 0
+    diag_ok, full_vis = _step_visibility(
+        flag_ref, selected_heads, nqb, qi, ki, bq, bk, offset, causal,
+        shift_ref, window)
 
     def _softmax_update(logits, v):
         m_prev = m_ref[:]
@@ -271,7 +346,9 @@ def _fwd_kernel(*refs, causal: bool, scale: float, nkb: int, offset: int,
         v = v_ref[0]                                      # [bk, d]
         logits = jnp.dot(q, k.T,
                          preferred_element_type=jnp.float32) * scale
-        if apply_mask:
+        if apply_mask and selected_heads:
+            logits = jnp.where(_selected(sel_ref), logits, NEG_INF)
+        elif apply_mask:
             # Mask from two 1-D iotas and ONE broadcast compare: the mask
             # is pure VPU overhead on every diagonal-adjacent block, and
             # materializing two full [bq, bk] i32 iotas costs ~3x the
@@ -289,7 +366,8 @@ def _fwd_kernel(*refs, causal: bool, scale: float, nkb: int, offset: int,
                                NEG_INF)
         _softmax_update(logits, v)
 
-    _dual_instantiate(_compute, causal, shift_ref, diag_ok, full_vis)
+    _dual_instantiate(_compute, causal or bool(selected_heads), shift_ref,
+                      diag_ok, full_vis)
 
     @pl.when(ki == nkb - 1)
     def _finalize():
@@ -314,13 +392,18 @@ def _check_window(window: Optional[int], causal: bool,
 
 
 def _kernel_name(base: str, window: Optional[int],
-                 latent: bool = False) -> Optional[str]:
+                 latent: bool = False,
+                 selected: bool = False) -> Optional[str]:
     """The windowed ``pallas_call``'s own name, by which a profile tells it
     from the full kernel, and the latent one's (a value head narrower than
     the query/key head: ``_mla``). The full kernel with one head size keeps
     none: XLA names a custom call after the innermost scope, a ``name`` is
     one more scope, and the benchmark finds the full kernel by its caller's
-    (``%attn``)."""
+    (``%attn``). The selected kernels are ``flash_fwd_sparse``,
+    ``flash_bwd_sparse`` and, split, ``flash_bwd_sparse_dq`` / ``_dkdv``."""
+    if selected:
+        stem, split, part = base.partition("_d")
+        return stem + "_sparse" + split + part
     if window is None and not latent:
         return None
     return base + ("_window" if window is not None else "") \
@@ -357,19 +440,32 @@ def _lane_tiles(d: int) -> int:
     return -(-d // _LANES)
 
 
-def _tile_vmem(d: int, itemsize: int) -> Optional[pltpu.CompilerParams]:
+def _tile_vmem(d: int, itemsize: int,
+               selected: bool = False) -> Optional[pltpu.CompilerParams]:
     """``compiler_params`` of the forward and of the split backward kernels:
     the [bq, bk] f32 score / probability buffers do not grow with the head,
     the [b*, d] operand tiles and accumulators do (a 1024-token tile at
     d=192 asked for 17.45 MB of the default's 16), so a query/key head of
     ``n`` 128-lane tiles asks for ``n`` times the default and keeps the
     1024-token tiles. ``None`` at one lane tile: Mosaic's default, and
-    such calls trace as they always did."""
-    if _lane_tiles(d) == 1:
+    such calls trace as they always did. A selected kernel holds the
+    selection's [bq, bk] int8 tile, twice, and its widened copy beside the
+    scores: one default more."""
+    if _lane_tiles(d) == 1 and not selected:
         return None
     return pltpu.CompilerParams(
-        vmem_limit_bytes=_SCOPED_VMEM_BYTES * _lane_tiles(d)
+        vmem_limit_bytes=_SCOPED_VMEM_BYTES * (_lane_tiles(d) + selected)
         * max(itemsize // 2, 1))
+
+
+def _selection_specs(bq: int, bk: int, tile_of: Callable) -> list:
+    """The two operands a selected kernel takes after its own: the
+    selection's int8 tile (``tile_of`` maps the grid to ``(batch, q-block,
+    k-block)``: one selection serves a batch's heads) and the tiles' flags,
+    whole, in SMEM. A selected kernel takes no static mask and no shift,
+    so its other index maps are the grid's own."""
+    return [pl.BlockSpec((1, bq, bk), tile_of),
+            pl.BlockSpec(memory_space=pltpu.SMEM)]
 
 
 def _to_bh(x: jnp.ndarray) -> jnp.ndarray:
@@ -382,7 +478,11 @@ def _flash_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                causal: bool, block_q: Optional[int], block_k: Optional[int],
                interpret: bool,
                shift: Optional[jnp.ndarray] = None,
-               window: Optional[int] = None) -> jnp.ndarray:
+               window: Optional[int] = None,
+               selection: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None
+               ) -> jnp.ndarray:
+    """``selection``: an int8 selection with its tiles' flags
+    (:func:`_with_tile_flags`), where visibility is data."""
     b, s, h, d = q.shape
     d_v = v.shape[-1]     # the value head may be narrower (latent attention)
     h_kv = k.shape[2]
@@ -434,10 +534,17 @@ def _flash_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         in_specs.append(pl.BlockSpec((1, _LANES), lambda bh, i, j: (0, 0)))
         inputs.append(jnp.broadcast_to(
             jnp.asarray(shift, jnp.int32).reshape(1, 1), (1, _LANES)))
+    selected = selection is not None
+    if selected:
+        in_specs += _selection_specs(
+            block_q, block_k, lambda bh, i, j: (bh // h, i, j))
+        inputs += list(selection)
+    also = dict(selected_heads=h) if selected else {}
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, causal=causal, scale=scale,
                           nkb=nkb, offset=sk - s,
-                          dynamic_shift=dynamic_shift, window=window),
+                          dynamic_shift=dynamic_shift, window=window,
+                          **also),
         out_shape=[
             jax.ShapeDtypeStruct((b * h, s, d_v), q.dtype),
             # Row stats ride in [bh, s, 128] with the value broadcast over
@@ -458,9 +565,9 @@ def _flash_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.VMEM((block_q, 1), jnp.float32),   # running sum
             pltpu.VMEM((block_q, d_v), jnp.float32),  # output accumulator
         ],
-        compiler_params=_tile_vmem(d, q.dtype.itemsize),
+        compiler_params=_tile_vmem(d, q.dtype.itemsize, selected),
         interpret=interpret,
-        name=_kernel_name("flash_fwd", window, d_v != d),
+        name=_kernel_name("flash_fwd", window, d_v != d, selected),
     )(*inputs)
     lse = lse[:, :, 0]
     if d_v != d:
@@ -482,7 +589,7 @@ def _flash_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 def _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     qi, ki, causal: bool, scale: float, offset: int,
                     shift_ref=None, apply_mask: bool = True,
-                    window: Optional[int] = None):
+                    window: Optional[int] = None, sel_ref=None):
     """Shared backward recompute: rebuild the probability tile from
     (q, k, lse) under the same end-aligned causal mask as the forward and
     form ds = p * (dp - delta). Used by both the dq and dk/dv kernels so
@@ -498,7 +605,9 @@ def _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     do = do_ref[0]                                    # [bq, d]
     logits = jnp.dot(q, k.T,
                      preferred_element_type=jnp.float32) * scale
-    if apply_mask and (causal or shift_ref is not None):
+    if apply_mask and sel_ref is not None:
+        logits = jnp.where(_selected(sel_ref), logits, NEG_INF)
+    elif apply_mask and (causal or shift_ref is not None):
         # Same broadcast-compare mask as the forward (see _fwd_kernel).
         q_pos = offset + qi * bq + jax.lax.broadcasted_iota(
             jnp.int32, (bq, 1), 0)
@@ -515,16 +624,22 @@ def _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     return p, ds, q, k, do
 
 
+def _bwd_refs(refs, dynamic_shift: bool, selected_heads: int):
+    """A backward kernel's refs as ``(the six inputs, shift_ref, sel_ref,
+    flag_ref, the rest)``: a traced shift or a selection and its flags
+    follow the inputs."""
+    extra = 1 if dynamic_shift else 2 if selected_heads else 0
+    shift_ref = refs[6] if dynamic_shift else None
+    sel_ref, flag_ref = refs[6:8] if selected_heads else (None, None)
+    return refs[:6], shift_ref, sel_ref, flag_ref, refs[6 + extra:]
+
+
 def _bwd_dq_kernel(*refs, causal: bool, scale: float, nkb: int,
                    offset: int, dynamic_shift: bool,
-                   window: Optional[int] = None):
-    if dynamic_shift:
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, shift_ref, \
-            dq_ref, acc_ref = refs
-    else:
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, \
-            dq_ref, acc_ref = refs
-        shift_ref = None
+                   window: Optional[int] = None, selected_heads: int = 0):
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), shift_ref, sel_ref, \
+        flag_ref, (dq_ref, acc_ref) = _bwd_refs(refs, dynamic_shift,
+                                                selected_heads)
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     bq = q_ref.shape[1]
@@ -534,18 +649,22 @@ def _bwd_dq_kernel(*refs, causal: bool, scale: float, nkb: int,
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    diag_ok, full_vis = _block_visibility(
-        qi, ki, bq, bk, offset, causal, shift_ref, window)
+    # (a dense kernel's program asks for no grid size: it traces as before)
+    nqb = pl.num_programs(1) if selected_heads else 0
+    diag_ok, full_vis = _step_visibility(
+        flag_ref, selected_heads, nqb, qi, ki, bq, bk, offset, causal,
+        shift_ref, window)
 
     def _compute(apply_mask: bool):
         _, ds, _, k, _ = _recompute_p_ds(
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             qi, ki, causal, scale, offset, shift_ref,
-            apply_mask=apply_mask, window=window)
+            apply_mask=apply_mask, window=window, sel_ref=sel_ref)
         acc_ref[:] += jnp.dot(ds.astype(k.dtype), k,
                               preferred_element_type=jnp.float32) * scale
 
-    _dual_instantiate(_compute, causal, shift_ref, diag_ok, full_vis)
+    _dual_instantiate(_compute, causal or bool(selected_heads), shift_ref,
+                      diag_ok, full_vis)
 
     @pl.when(ki == nkb - 1)
     def _finalize():
@@ -554,14 +673,10 @@ def _bwd_dq_kernel(*refs, causal: bool, scale: float, nkb: int,
 
 def _bwd_dkdv_kernel(*refs, causal: bool, scale: float, nqb: int,
                      offset: int, dynamic_shift: bool,
-                     window: Optional[int] = None):
-    if dynamic_shift:
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, shift_ref, \
-            dk_ref, dv_ref, dk_acc, dv_acc = refs
-    else:
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, \
-            dk_ref, dv_ref, dk_acc, dv_acc = refs
-        shift_ref = None
+                     window: Optional[int] = None, selected_heads: int = 0):
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), shift_ref, sel_ref, \
+        flag_ref, (dk_ref, dv_ref, dk_acc, dv_acc) = _bwd_refs(
+            refs, dynamic_shift, selected_heads)
     ki = pl.program_id(1)
     qi = pl.program_id(2)
     bq = q_ref.shape[1]
@@ -572,20 +687,22 @@ def _bwd_dkdv_kernel(*refs, causal: bool, scale: float, nqb: int,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    diag_ok, full_vis = _block_visibility(
-        qi, ki, bq, bk, offset, causal, shift_ref, window)
+    diag_ok, full_vis = _step_visibility(
+        flag_ref, selected_heads, nqb, qi, ki, bq, bk, offset, causal,
+        shift_ref, window)
 
     def _compute(apply_mask: bool):
         p, ds, q, _, do = _recompute_p_ds(
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             qi, ki, causal, scale, offset, shift_ref,
-            apply_mask=apply_mask, window=window)
+            apply_mask=apply_mask, window=window, sel_ref=sel_ref)
         dv_acc[:] += jnp.dot(p.astype(do.dtype).T, do,
                              preferred_element_type=jnp.float32)
         dk_acc[:] += jnp.dot(ds.astype(q.dtype).T, q,
                              preferred_element_type=jnp.float32) * scale
 
-    _dual_instantiate(_compute, causal, shift_ref, diag_ok, full_vis)
+    _dual_instantiate(_compute, causal or bool(selected_heads), shift_ref,
+                      diag_ok, full_vis)
 
     @pl.when(qi == nqb - 1)
     def _finalize():
@@ -595,7 +712,7 @@ def _bwd_dkdv_kernel(*refs, causal: bool, scale: float, nqb: int,
 
 def _bwd_fused_kernel(*refs, causal: bool, scale: float, nqb: int,
                       nkb: int, offset: int, dynamic_shift: bool,
-                      window: Optional[int] = None):
+                      window: Optional[int] = None, selected_heads: int = 0):
     """One backward kernel for dq, dk AND dv.
 
     The split kernels each recompute (logits, p, dp, ds) per block — the
@@ -614,13 +731,9 @@ def _bwd_fused_kernel(*refs, causal: bool, scale: float, nqb: int,
     it leaves VMEM once a row. No HBM buffer is read back, so there is
     nothing for the pipeline to race and no grid depth it needs.
     """
-    if dynamic_shift:
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, shift_ref, \
-            dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, dq_acc = refs
-    else:
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, \
-            dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, dq_acc = refs
-        shift_ref = None
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), shift_ref, sel_ref, \
+        flag_ref, (dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, dq_acc) = \
+        _bwd_refs(refs, dynamic_shift, selected_heads)
     ki = pl.program_id(1)
     qi = pl.program_id(2)
     bq = q_ref.shape[1]
@@ -635,14 +748,15 @@ def _bwd_fused_kernel(*refs, causal: bool, scale: float, nqb: int,
     def _init_row():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    diag_ok, full_vis = _block_visibility(
-        qi, ki, bq, bk, offset, causal, shift_ref, window)
+    diag_ok, full_vis = _step_visibility(
+        flag_ref, selected_heads, nqb, qi, ki, bq, bk, offset, causal,
+        shift_ref, window)
 
     def _compute(apply_mask: bool):
         p, ds, q, k, do = _recompute_p_ds(
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             qi, ki, causal, scale, offset, shift_ref,
-            apply_mask=apply_mask, window=window)
+            apply_mask=apply_mask, window=window, sel_ref=sel_ref)
         dv_acc[:] += jnp.dot(p.astype(do.dtype).T, do,
                              preferred_element_type=jnp.float32)
         dk_acc[:] += jnp.dot(ds.astype(q.dtype).T, q,
@@ -652,7 +766,8 @@ def _bwd_fused_kernel(*refs, causal: bool, scale: float, nqb: int,
             ds.astype(k.dtype), k,
             preferred_element_type=jnp.float32) * scale
 
-    _dual_instantiate(_compute, causal, shift_ref, diag_ok, full_vis)
+    _dual_instantiate(_compute, causal or bool(selected_heads), shift_ref,
+                      diag_ok, full_vis)
 
     @pl.when(qi == nqb - 1)
     def _finalize():
@@ -688,7 +803,7 @@ def _fused_vmem_limit(s: int, d: int, itemsize: int) -> int:
 
 def _flash_bwd(q, k, v, out, lse, g, causal: bool, block_q: Optional[int],
                block_k: Optional[int], interpret: bool, shift=None,
-               g_lse=None, window: Optional[int] = None):
+               g_lse=None, window: Optional[int] = None, selection=None):
     b, s, h, d = q.shape
     d_v = v.shape[-1]     # v, o, do and dv at the value head's size
     latent = d_v != d
@@ -765,6 +880,15 @@ def _flash_bwd(q, k, v, out, lse, g, causal: bool, block_q: Optional[int],
         in_specs, in_specs2 = in_specs + [shift_spec], in_specs2 + [shift_spec]
         inputs.append(jnp.broadcast_to(
             jnp.asarray(shift, jnp.int32).reshape(1, 1), (1, _LANES)))
+    selected = selection is not None
+    also = dict(selected_heads=h) if selected else {}
+    if selected:
+        # the selection's tile by either grid order, the flags whole
+        in_specs = in_specs + _selection_specs(
+            block_q, block_k, lambda bh, i, j: (bh // h, i, j))
+        in_specs2 = in_specs2 + _selection_specs(
+            block_q, block_k, lambda bh, j, i: (bh // h, i, j))
+        inputs += list(selection)
 
     def from_bh(x, seq):
         return x.reshape(b, h, seq, x.shape[-1]).transpose(0, 2, 1, 3)
@@ -802,7 +926,8 @@ def _flash_bwd(q, k, v, out, lse, g, causal: bool, block_q: Optional[int],
         dk, dv, dq = pl.pallas_call(
             functools.partial(_bwd_fused_kernel, causal=causal,
                               scale=scale, nqb=nqb, nkb=nkb, offset=offset,
-                              dynamic_shift=dynamic_shift, window=window),
+                              dynamic_shift=dynamic_shift, window=window,
+                              **also),
             out_shape=[
                 jax.ShapeDtypeStruct((b * h, sk, d), k.dtype),
                 jax.ShapeDtypeStruct((b * h, sk, d_v), v.dtype),
@@ -818,9 +943,10 @@ def _flash_bwd(q, k, v, out, lse, g, causal: bool, block_q: Optional[int],
                 pltpu.VMEM((s, d), jnp.float32),   # the row's whole dq
             ],
             compiler_params=pltpu.CompilerParams(
-                vmem_limit_bytes=_fused_vmem_limit(s, d, q.dtype.itemsize)),
+                vmem_limit_bytes=_fused_vmem_limit(s, d, q.dtype.itemsize)
+                + selected * _SCOPED_VMEM_BYTES),
             interpret=interpret,
-            name=_kernel_name("flash_bwd", window, latent),
+            name=_kernel_name("flash_bwd", window, latent, selected),
         )(*inputs)
         return pack(dq, dk, dv)
 
@@ -828,15 +954,16 @@ def _flash_bwd(q, k, v, out, lse, g, causal: bool, block_q: Optional[int],
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, causal=causal, scale=scale,
                           nkb=nkb, offset=offset,
-                          dynamic_shift=dynamic_shift, window=window),
+                          dynamic_shift=dynamic_shift, window=window,
+                          **also),
         out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
         grid=(b * h, nqb, nkb),
         in_specs=in_specs,
         out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_tile_vmem(d, q.dtype.itemsize),
+        compiler_params=_tile_vmem(d, q.dtype.itemsize, selected),
         interpret=interpret,
-        name=_kernel_name("flash_bwd_dq", window, latent),
+        name=_kernel_name("flash_bwd_dq", window, latent, selected),
     )(*inputs)
 
     # dk/dv: k-block outer, q-block innermost (sequential accumulation).
@@ -847,7 +974,8 @@ def _flash_bwd(q, k, v, out, lse, g, causal: bool, block_q: Optional[int],
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkdv_kernel, causal=causal, scale=scale,
                           nqb=nqb, offset=offset,
-                          dynamic_shift=dynamic_shift, window=window),
+                          dynamic_shift=dynamic_shift, window=window,
+                          **also),
         out_shape=[
             jax.ShapeDtypeStruct((b * h, sk, d), k.dtype),
             jax.ShapeDtypeStruct((b * h, sk, d_v), v.dtype),
@@ -859,9 +987,9 @@ def _flash_bwd(q, k, v, out, lse, g, causal: bool, block_q: Optional[int],
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d_v), jnp.float32),
         ],
-        compiler_params=_tile_vmem(d, q.dtype.itemsize),
+        compiler_params=_tile_vmem(d, q.dtype.itemsize, selected),
         interpret=interpret,
-        name=_kernel_name("flash_bwd_dkdv", window, latent),
+        name=_kernel_name("flash_bwd_dkdv", window, latent, selected),
     )(*inputs)
 
     return pack(dq, dk, dv)
@@ -1048,6 +1176,88 @@ def sharded_flash_attention(mesh: Mesh,
 
     attention.supports_gqa = True
     return attention
+
+
+# ------------------------------------------------------ a selected set
+#
+# Attention over a set of keys that is DATA: query ``t`` sees key ``s`` iff
+# ``selection[b, t, s]`` (a learned sparse attention's top-k of an index
+# score). The same kernels as above, which take the selection's int8 tile
+# as an operand where the others compare positions, and the tiles' flags
+# (none / some / every pair selected) where the others solve the mask's
+# inequalities for the block index: a tile with no selected pair computes
+# nothing, a tile wholly selected takes the unmasked body. No band clamps
+# an index map here (which tiles are empty is not known before the flags
+# are read), so a skipped step still moves its tiles.
+
+def sparse_flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                           selection: jnp.ndarray,
+                           block_q: Optional[int] = None,
+                           block_k: Optional[int] = None,
+                           interpret: Optional[bool] = None,
+                           return_lse: bool = False):
+    """``softmax`` attention of each query over ITS selected keys. q:
+    [B, S, H, D]; k, v: [B, S, H_kv, D] (grouped heads shared through the
+    index maps, as :func:`flash_attention`); ``selection``: [B, S, S], bool
+    or int8, nonzero where query ``t`` attends to key ``s``, one set for
+    all heads. Every row must select at least one key. No causal mask is
+    added: a causal selection is the caller's. Exactly the selected pairs'
+    softmax in the forward and in the backward (``flash_fwd_sparse``,
+    ``flash_bwd_sparse``, or ``flash_bwd_sparse_dq`` / ``_dkdv`` where the
+    dense kernels would split too); no gradient reaches ``selection``.
+    With every causal pair selected the result is
+    ``flash_attention(q, k, v)``'s.
+
+    ``return_lse=True`` also returns the rows' logsumexp over the selected
+    scaled scores, float32 ``[B, H, S]`` (differentiable: its cotangent
+    folds into the backward's delta). Lengths with no aligned tile are
+    padded at the end, the padded rows and keys unselected."""
+    from torchft_tpu import tracing
+
+    interpret = _resolve_interpret(interpret)
+    b, s, h, _ = q.shape
+    if k.shape[1] != s or selection.shape != (b, s, s):
+        raise ValueError(
+            f"sparse_flash_attention: q {q.shape}, k {k.shape} and "
+            f"selection {selection.shape} must share one sequence length "
+            f"(selection [B, S, S])")
+    selection = selection.astype(jnp.int8)
+    delta = 0 if block_q or block_k else _seq_pad(s, s)
+    if delta:
+        pad = ((0, 0), (0, delta), (0, 0), (0, 0))
+        q, k, v = jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad)
+        selection = jnp.pad(selection, ((0, 0), (0, delta), (0, delta)))
+    # counted on the host, when the call is traced: nothing in the step
+    tracing.add_program_counters(sparse_attn_traces_total=1)
+    out, lse = _sparse_core(q, k, v, selection, block_q, block_k, interpret)
+    out, lse = out[:, :s], lse.reshape(b, h, -1)[:, :, :s]
+    return (out, lse) if return_lse else out
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _sparse_core(q, k, v, selection, block_q, block_k, interpret):
+    return _flash_fwd(
+        q, k, v, False, block_q, block_k, interpret,
+        selection=_with_tile_flags(selection, block_q, block_k))
+
+
+def _sparse_fwd_rule(q, k, v, selection, block_q, block_k, interpret):
+    flagged = _with_tile_flags(selection, block_q, block_k)
+    out, lse = _flash_fwd(q, k, v, False, block_q, block_k, interpret,
+                          selection=flagged)
+    return (out, lse), (q, k, v, out, lse, flagged)
+
+
+def _sparse_bwd_rule(block_q, block_k, interpret, res, g):
+    q, k, v, out, lse, flagged = res
+    g_out, g_lse = g
+    dq, dk, dv = _flash_bwd(q, k, v, out, lse, g_out, False, block_q,
+                            block_k, interpret, g_lse=g_lse,
+                            selection=flagged)
+    return dq, dk, dv, jnp.zeros(flagged[0].shape, dtype=jax.dtypes.float0)
+
+
+_sparse_core.defvjp(_sparse_fwd_rule, _sparse_bwd_rule)
 
 
 # ------------------------------------------------------------- ring block
